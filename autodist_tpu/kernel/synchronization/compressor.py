@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from autodist_tpu.kernel.synchronization import quant_ring
-from autodist_tpu.utils import compat
 
 
 class Compressor:
@@ -77,7 +76,7 @@ class NoneCompressor(Compressor):
         return lax.pmean(grad, axis_name), state
 
     def reduce_scatter(self, vec, state, axis_name):
-        n = compat.axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         shard = lax.psum_scatter(vec, axis_name, scatter_dimension=0,
                                  tiled=True)
         return shard / n, state
@@ -100,7 +99,7 @@ class HorovodCompressor(Compressor):
         return summed.astype(orig), state
 
     def reduce_scatter(self, vec, state, axis_name):
-        n = compat.axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         shard = lax.psum_scatter(vec.astype(self._wire), axis_name,
                                  scatter_dimension=0, tiled=True)
         return (shard / n).astype(vec.dtype), state
@@ -130,7 +129,7 @@ class HorovodCompressorEF(Compressor):
         # Residual is computable locally BEFORE the scatter (it depends
         # only on this device's quantization error), so error feedback
         # composes with the ZeRO-1 leg at full-bucket state size.
-        n = compat.axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         corrected = vec + state
         compressed = corrected.astype(self._wire)
         new_state = corrected - compressed.astype(vec.dtype)
@@ -212,7 +211,7 @@ class QuantizedRingCompressor(Compressor):
         return jnp.zeros_like(var_value)
 
     def reduce(self, grad, state, axis_name):
-        n = compat.axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         flat = (grad + state).astype(jnp.float32).ravel()
         pad = (-flat.size) % n
         if pad:
@@ -230,7 +229,7 @@ class QuantizedRingCompressor(Compressor):
         # already puts 1-byte payloads on the wire; the stage-2
         # re-quantized all-gather is simply not needed (fresh params are
         # gathered instead).  No stage-2 quantization error either.
-        n = compat.axis_size(axis_name)
+        n = lax.axis_size(axis_name)
         shard, new_state, _ = self.bucket_reduce_scatter(
             vec, state, axis_name, n, alg="fused")
         return shard, new_state

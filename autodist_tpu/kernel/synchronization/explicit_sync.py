@@ -90,7 +90,7 @@ from autodist_tpu.kernel.synchronization import quant_ring
 from autodist_tpu.kernel.synchronization import schedule_ir
 from autodist_tpu.strategy.compiler import CompiledStrategy
 from autodist_tpu.telemetry.timeline import sync_span
-from autodist_tpu.utils import compat, logging
+from autodist_tpu.utils import logging
 
 
 def uses_explicit_path(compiled: CompiledStrategy) -> bool:
@@ -443,10 +443,11 @@ def make_explicit_step(gi: GraphItem, compiled: CompiledStrategy):
                       for kind, _ in sync_builders.values())
 
     # -- fused Pallas kernels (docs/kernels.md) ----------------------------
-    # Opt-in via AUTODIST_FUSED_KERNELS; every requested kernel this
-    # program cannot lower falls back to the unfused path with the
-    # SHARED drop-reason string (ops.fused_kernels.fused_drop_reason —
-    # the analysis schedule pass surfaces the same rule).  The active
+    # Opt-in via AUTODIST_FUSED_KERNELS; a requested kernel this
+    # program cannot lower raises on a TPU and, off-TPU, falls back to
+    # the unfused path, either way with the SHARED drop-reason string
+    # (ops.fused_kernels.fused_drop_reason — the analysis schedule pass
+    # surfaces the same rule).  The active
     # set is recorded in the schedule IR below, so the fingerprint, the
     # verifier, and the cost model all see the fused program.
     from autodist_tpu.ops import fused_kernels as fk
@@ -465,9 +466,7 @@ def make_explicit_step(gi: GraphItem, compiled: CompiledStrategy):
         optimizer_fusable=opt_fusable, adam_state_shaped=adam_shaped,
         f32_buckets=all(b.dtype == "float32" for b in rs_buckets))
     for kernel, why in fused_drops:
-        logging.warning(
-            "explicit sync path: fused kernel %s falls back to the "
-            "unfused lowering (%s)", kernel, why)
+        fk.drop_or_raise("explicit sync path", kernel, why)
     # Interpret-mode decision resolved HERE, at build — not at trace —
     # the ops/flash_attention.py convention (off-TPU is only reachable
     # under the AUTODIST_FUSED_INTERPRET escape hatch).
@@ -1164,7 +1163,7 @@ def make_explicit_step(gi: GraphItem, compiled: CompiledStrategy):
     # gradients would arrive pre-summed and the compressor pmean would then
     # scale them by the data-axis size (d x too large), while the real
     # collective escapes the compressor entirely.
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(param_spec_tree, opt_spec_tree, dict(sync_specs),
                   P(MESH_AXIS_DATA)),

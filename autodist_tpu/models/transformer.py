@@ -10,6 +10,7 @@ Design notes:
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional
 
 import flax.linen as nn
@@ -18,45 +19,41 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def _resolve_default_attention(mesh=None) -> Callable:
-    if jax.devices()[0].platform == "tpu":
+@functools.cache
+def _resolve_default_attention() -> Callable:
+    """Resolved once per process, and logged then with the platform, so a
+    run's log says which attention it ran."""
+    from autodist_tpu.utils import logging
+
+    platform = jax.devices()[0].platform
+    if platform == "tpu":
         from autodist_tpu.ops.flash_attention import make_flash_attention
 
-        return make_flash_attention(mesh)
+        logging.info("default attention: Pallas flash kernel (platform %s)",
+                     platform)
+        return make_flash_attention()
+    logging.info("default attention: dense softmax (platform %s)", platform)
     return dense_attention
 
 
-def default_attention(mesh=None) -> Callable:
+def default_attention() -> Callable:
     """The attention implementation for the current backend: the Pallas
     flash kernel on TPU — the hot-op fast path
     (``autodist_tpu/ops/flash_attention.py``) — and dense softmax attention
     elsewhere.  Model factories use this when no explicit ``attn_fn`` is
     passed.
 
-    Resolved at CONSTRUCTION time when the backend is already up (the
-    AOT-friendly behavior).  When no backend has been initialized yet —
-    a multi-node script building its model BEFORE
-    ``jax.distributed.initialize`` — probing devices here would initialize
-    the local backend and break the distributed bootstrap
-    (``cluster.py:128-146``), so the decision is deferred to the first
-    call and cached."""
-    try:
-        from jax._src import xla_bridge
+    Resolved at the first CALL (trace time), never at construction: a
+    multi-node script builds its model BEFORE
+    ``jax.distributed.initialize``, and probing devices here would
+    initialize the local backend and break the distributed bootstrap
+    (``cluster.py:128-146``).  Trace time is also when the mesh is known:
+    the flash kernel shards itself over the mesh context of the trace,
+    which a session's step sets (``kernel/graph_transformer.py``)."""
+    def attn(q, k, v, causal: bool):
+        return _resolve_default_attention()(q, k, v, causal)
 
-        initialized = xla_bridge.backends_are_initialized()
-    except Exception:  # pragma: no cover - private-API drift
-        initialized = True
-    if initialized:
-        return _resolve_default_attention(mesh)
-
-    resolved: list = []
-
-    def lazy_attn(q, k, v, causal: bool):
-        if not resolved:
-            resolved.append(_resolve_default_attention(mesh))
-        return resolved[0](q, k, v, causal)
-
-    return lazy_attn
+    return attn
 
 
 def dense_attention(q, k, v, causal: bool) -> jax.Array:

@@ -33,7 +33,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from autodist_tpu.const import MESH_AXIS_DATA, MESH_AXIS_MODEL
 from autodist_tpu.ops import pallas_utils
-from autodist_tpu.utils import compat
 
 _NEG_INF = -1e30  # finite -inf: keeps exp()/max() NaN-free (masked rows)
 # Tiling policy lives in ops/pallas_utils.py (shared by every Pallas
@@ -353,7 +352,13 @@ def make_flash_attention(mesh: Optional[Mesh] = None, *,
 
     With a mesh, the kernel runs inside ``shard_map`` manual over the
     ``data`` (batch dim) and ``model`` (heads dim) axes — a ``pallas_call``
-    is a compiler black box GSPMD would otherwise all-gather around.  The
+    is a compiler black box that jax refuses to partition by itself
+    ("Mosaic kernels cannot be automatically partitioned").  The
+    mesh is ``mesh`` when given, else the mesh context of the trace the
+    call happens in: a session's step sets its own
+    (``kernel/graph_transformer.py``) and jax sets one inside any
+    ``shard_map``, so the model-zoo default (built with no mesh) shards
+    wherever it is traced.  With neither, the kernel runs unsharded.  The
     ``seq`` axis is not handled here: compose with ring attention
     (``parallel/ring_attention.py``) for sequence parallelism.
 
@@ -366,7 +371,7 @@ def make_flash_attention(mesh: Optional[Mesh] = None, *,
     kw = dict(block_q=block_q, block_k=block_k, interpret=interpret)
 
     @functools.lru_cache(maxsize=None)
-    def _sharded(causal: bool, axes_key: frozenset):
+    def _sharded(causal: bool, axes_key: frozenset, over):
         spec = P(MESH_AXIS_DATA if MESH_AXIS_DATA in axes_key else None,
                  None,
                  MESH_AXIS_MODEL if MESH_AXIS_MODEL in axes_key else None,
@@ -376,27 +381,29 @@ def make_flash_attention(mesh: Optional[Mesh] = None, *,
         # metadata, and the kernel is trivially per-shard (no collectives).
         # jit: eager shard_map with partial axis_names trips JAX's internal
         # unmatch path; under jit (inlined when already tracing) it is sound.
-        return jax.jit(compat.shard_map(
-            fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        return jax.jit(jax.shard_map(
+            fn, mesh=over, in_specs=(spec, spec, spec), out_specs=spec,
             axis_names=set(axes_key), check_vma=False))
 
     def attn_fn(q, k, v, causal: bool):
+        ambient = jax.sharding.get_abstract_mesh()
+        over = mesh if mesh is not None else ambient
         manual_axes = set()
-        if mesh is not None:
-            # Axes an enclosing shard_map (the explicit-sync path) already
-            # manualized are local here — re-sharding them would double-split.
-            already_manual = set(
-                jax.sharding.get_abstract_mesh().manual_axes)
+        if not over.empty:
             # Shard only over axes that evenly divide the local dim — e.g.
             # model.init traces with a tiny batch that the data axis may not
-            # divide; that trace just runs the kernel unsharded.
+            # divide; that trace just runs the kernel unsharded.  Axes an
+            # enclosing shard_map (the explicit-sync path) already
+            # manualized are local here — re-sharding them would
+            # double-split.
             for ax, dim in ((MESH_AXIS_DATA, q.shape[0]),
                             (MESH_AXIS_MODEL, q.shape[2])):
-                size = mesh.shape.get(ax, 1)
-                if size > 1 and dim % size == 0 and ax not in already_manual:
+                size = over.shape.get(ax, 1)
+                if (size > 1 and dim % size == 0
+                        and ax not in ambient.manual_axes):
                     manual_axes.add(ax)
         if not manual_axes:
             return flash_attention(q, k, v, causal, **kw)
-        return _sharded(causal, frozenset(manual_axes))(q, k, v)
+        return _sharded(causal, frozenset(manual_axes), over)(q, k, v)
 
     return attn_fn

@@ -41,11 +41,11 @@ arithmetic by fusion:
 
 Selection is an explicit opt-in: ``AUTODIST_FUSED_KERNELS`` names the
 kernels (``all`` or a comma list of ``guard,update,quant_hop,
-paged_attention``).  Off-TPU, or on configs a kernel does not support,
-the runtime falls back to the unfused lowering with a shared
-drop-reason WARN (:func:`fused_drop_reason` — the
+paged_attention``).  A requested kernel the program cannot lower has a
+shared drop reason (:func:`fused_drop_reason` — the
 ``bucket_drop_reason`` pattern: runtime and analysis surface the same
-string).  ``AUTODIST_FUSED_INTERPRET=1`` forces Pallas interpret mode
+string): on a TPU the runtime raises with it, off-TPU it WARNs and
+falls back to the unfused lowering (:func:`drop_or_raise`).  ``AUTODIST_FUSED_INTERPRET=1`` forces Pallas interpret mode
 off-TPU — the test/bench escape hatch that lets the CPU mesh execute
 the exact fused step (slower than XLA; never the default).  Enabled
 kernels are recorded in the schedule IR (``fused_detect`` /
@@ -196,6 +196,22 @@ def resolve_fused(*, guard: bool, has_rs: bool, has_quant_ring: bool,
     return tuple(active), drops
 
 
+def drop_or_raise(where: str, kernel: str, why: str) -> None:
+    """A requested fused kernel cannot lower.  On a TPU that is an error:
+    the request names the kernel the run is meant to use, and a quiet
+    fallback would run (and time) the unfused path under its name.
+    Off-TPU, where tests and rehearsals run, it stays the shared WARN
+    and the caller falls back to the unfused lowering."""
+    from autodist_tpu.utils import logging
+
+    if _platform_tpu():
+        raise RuntimeError(
+            f"{where}: fused kernel {kernel!r} was requested "
+            f"(AUTODIST_FUSED_KERNELS) but cannot lower: {why}")
+    logging.warning("%s: fused kernel %s falls back to the unfused "
+                    "lowering (%s)", where, kernel, why)
+
+
 def paged_attention_status() -> Tuple[bool, Optional[str]]:
     """(active, drop_reason) for the serving paged-attention kernel —
     resolved at trace time by ``serving/paged_kv.py``.  ``(False,
@@ -210,6 +226,17 @@ def paged_attention_status() -> Tuple[bool, Optional[str]]:
 
 def _interpret(interpret: Optional[bool]) -> bool:
     return pallas_utils.resolve_interpret(interpret)
+
+
+def _scalar_out_spec():
+    """Whole-array SMEM spec for a ``(1, 1)`` accumulator output: Mosaic
+    stores scalars to SMEM only ("Cannot store scalars to VMEM"), and an
+    unblocked SMEM output lives across the sequential grid, which is
+    what the init-then-accumulate kernels need."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 # ---------------------------------------------------------------------------
@@ -265,8 +292,7 @@ def fused_detect_stats(vec, *, interpret: Optional[bool] = None):
         grid=(grid,),
         in_specs=[pl.BlockSpec((_BLOCK_ROWS, pallas_utils.TILE),
                                lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
+        out_specs=[_scalar_out_spec(), _scalar_out_spec()],
         out_shape=[jax.ShapeDtypeStruct((1, 1), jnp.float32),
                    jax.ShapeDtypeStruct((1, 1), jnp.float32)],
         interpret=interpret,
@@ -523,8 +549,7 @@ def _quant_specs(block: int, with_chunk: bool):
     vec_blk = pl.BlockSpec((_QROWS, block), lambda i: (i, 0))
     scale_blk = pl.BlockSpec((_QROWS, 1), lambda i: (i, 0))
     ins = [vec_blk, scale_blk, vec_blk] if with_chunk else [vec_blk]
-    outs = [vec_blk, scale_blk, vec_blk,
-            pl.BlockSpec((1, 1), lambda i: (0, 0))]
+    outs = [vec_blk, scale_blk, vec_blk, _scalar_out_spec()]
     return ins, outs
 
 
@@ -622,15 +647,23 @@ def fused_dequant_add(q_in, scales_in, chunk, fmt, block: int = 256, *,
 # ---------------------------------------------------------------------------
 
 def _paged_attn_kernel(bt_ref, rel_ref, q_ref, k_ref, v_ref, o_ref,
-                       m_ref, l_ref, acc_ref, *, bs: int, scale: float):
+                       m_ref, l_ref, acc_ref, *, bs: int, heads: int,
+                       scale: float):
     """One (slot, logical-block) program: the named block arrives via
     the scalar-prefetch index map (no gather — the DMA reads exactly
     the physical block the table points at), and an online softmax
     accumulates across the slot's logical blocks.
 
-    Refs: q [1,H,Dh]; k/v [1,BS,H,Dh] (the table-selected block);
-    o [1,H,Dh]; scratch m/l [H,1], acc [H,Dh] (f32, persistent across
-    the sequential block grid)."""
+    Heads stay side by side on the lane axis (``D = H * Dh``): Mosaic
+    takes no ``dot_general`` whose batch dimension is not leading, and a
+    ``(BS, H, Dh)`` block would need a relayout to make it so.  Per-head
+    sums and their broadcast back to the head's lanes are two small
+    matmuls against a 0/1 head-membership matrix instead, so every
+    other op is a plain 2-D elementwise or row reduction.
+
+    Refs: q/o [1,1,D]; k/v [1,BS,D] (the table-selected block);
+    scratch m/l/acc [1,D] (f32, persistent across the sequential block
+    grid; a head's m and l are repeated across its Dh lanes)."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
@@ -645,24 +678,36 @@ def _paged_attn_kernel(bt_ref, rel_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)                     # [H, Dh]
-    k = k_ref[0].astype(jnp.float32)                     # [BS, H, Dh]
+    q = q_ref[0].astype(jnp.float32) * scale             # [1, D]
+    k = k_ref[0].astype(jnp.float32)                     # [BS, D]
     v = v_ref[0].astype(jnp.float32)
-    h, _ = q.shape
-    # s[h, p] = q[h, :] . k[p, h, :]  (head is a batch dim)
-    s = lax.dot_general(q, k, (((1,), (2,)), ((0,), (1,))),
-                        preferred_element_type=jnp.float32) * scale
-    pos = j * bs + lax.broadcasted_iota(jnp.int32, (h, bs), 1)
+    d = q.shape[-1]
+    dh = d // heads
+    hp = pallas_utils.pad_to(heads, pallas_utils.TILE)
+
+    def membership(shape, lane_dim):
+        lane = lax.broadcasted_iota(jnp.int32, shape, lane_dim)
+        head = lax.broadcasted_iota(jnp.int32, shape, 1 - lane_dim)
+        return ((lane >= head * dh)
+                & (lane < (head + 1) * dh)).astype(jnp.float32)
+
+    # s[p, h] = sum_d q[h, d] * k[p, h, d], then repeated over head h's
+    # lanes.  HIGHEST: one operand is exactly 0/1, the other carries
+    # the f32 products.
+    dot = lambda a, b: jnp.dot(                          # noqa: E731
+        a, b, preferred_element_type=jnp.float32,
+        precision=lax.Precision.HIGHEST)
+    s = dot(dot(q * k, membership((d, hp), 0)),
+            membership((hp, d), 1))                      # [BS, D]
+    pos = j * bs + lax.broadcasted_iota(jnp.int32, (bs, d), 0)
     s = jnp.where(pos <= rel_ref[bi], s, _NEG_INF)
     m_prev, l_prev = m_ref[...], l_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    m_new = jnp.maximum(m_prev, s.max(axis=0, keepdims=True))
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
     m_ref[...] = m_new
-    l_ref[...] = l_prev * corr + p.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + lax.dot_general(
-        p, v, (((1,), (0,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32)
+    l_ref[...] = l_prev * corr + p.sum(axis=0, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + (p * v).sum(axis=0, keepdims=True)
 
     @pl.when(j == nb - 1)
     def _write():
@@ -689,31 +734,26 @@ def paged_attention(q, kc, vc, bt, rel, *,
 
     interpret = _interpret(interpret)
     b, h, dh = q.shape
-    _, bs, _, _ = kc.shape
+    nblk, bs, _, _ = kc.shape
     maxb = bt.shape[1]
-    scale = 1.0 / (dh ** 0.5)
-    kernel = functools.partial(_paged_attn_kernel, bs=bs, scale=scale)
+    d = h * dh
+    kernel = functools.partial(_paged_attn_kernel, bs=bs, heads=h,
+                               scale=1.0 / (dh ** 0.5))
+    row = pl.BlockSpec((1, 1, d), lambda bi, j, bt_r, rel_r: (bi, 0, 0))
+    blk = pl.BlockSpec((1, bs, d),
+                       lambda bi, j, bt_r, rel_r: (bt_r[bi, j], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, maxb),
-        in_specs=[
-            pl.BlockSpec((1, h, dh), lambda bi, j, bt_r, rel_r: (bi, 0, 0)),
-            pl.BlockSpec((1, bs, h, dh),
-                         lambda bi, j, bt_r, rel_r: (bt_r[bi, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, h, dh),
-                         lambda bi, j, bt_r, rel_r: (bt_r[bi, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, dh),
-                               lambda bi, j, bt_r, rel_r: (bi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, dh), jnp.float32),
-        ],
+        in_specs=[row, blk, blk],
+        out_specs=row,
+        scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)] * 3,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, 1, d), q.dtype),
         interpret=interpret,
-    )(bt.astype(jnp.int32), rel.astype(jnp.int32), q, kc, vc)
+    )(bt.astype(jnp.int32), rel.astype(jnp.int32), q.reshape(b, 1, d),
+      kc.reshape(nblk, bs, d), vc.reshape(nblk, bs, d))
+    return out.reshape(b, h, dh)

@@ -13,7 +13,9 @@ originals (measured defaults documented there):
 
 * :func:`use_interpret` — Pallas interpret mode is selected
   automatically whenever the first device is not a TPU, so the CPU test
-  mesh exercises the exact kernel bodies the TPU compiles;
+  mesh exercises the exact kernel bodies the TPU compiles; the resolved
+  mode is logged once, with the platform, so a run's log says whether
+  its kernels were compiled;
 * :func:`pad_len` — compiled Pallas wants (8, 128)-aligned tiles:
   lengths ≤ 128 round up to a multiple of 8 (the whole extent is one
   block), longer ones to a multiple of :data:`TILE`; interpret mode has
@@ -24,6 +26,7 @@ originals (measured defaults documented there):
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 #: MXU lane quantum: pad unit and block alignment for every TPU kernel.
@@ -33,12 +36,20 @@ TILE = 128
 SUBLANE = 8
 
 
+@functools.cache
 def use_interpret() -> bool:
     """Run Pallas in interpret mode?  Resolved from the backend — off-TPU
-    (the CPU test mesh) interprets, on TPU the kernel compiles."""
+    (the CPU test mesh) interprets, on TPU the kernel compiles.  Resolved
+    once per process, and logged then with the platform."""
     import jax
 
-    return jax.devices()[0].platform != "tpu"
+    from autodist_tpu.utils import logging
+
+    platform = jax.devices()[0].platform
+    interpret = platform != "tpu"
+    logging.info("Pallas kernels: %s (platform %s)",
+                 "interpret mode" if interpret else "compiled", platform)
+    return interpret
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:

@@ -32,6 +32,7 @@ from jax.experimental import pallas as pl
 from autodist_tpu.ops import pallas_utils, quant_scale
 
 _TILE = pallas_utils.TILE          # MXU lane quantum
+_BLOCK_M = 512                     # rows of x per program
 _DEFAULT_BLOCK_N = 512
 
 
@@ -72,15 +73,16 @@ _use_interpret = pallas_utils.use_interpret
 
 
 def _kernel(x_ref, q_ref, s_ref, o_ref):
-    """One N-block program: dequant-free int8 matmul + column scaling.
+    """One (M-block, N-block) program: dequant-free int8 matmul + column
+    scaling.
 
-    Refs: x [M, K]; q [K, bn] int8; s [1, bn] f32; o [M, bn].
+    Refs: x [bm, K]; q [K, bn] int8; s [1, bn] f32; o [bm, bn].
     ``q.astype(x.dtype)`` is exact (|q| <= 127 fits bf16's 8-bit
     mantissa); the f32 accumulator keeps the integer dot exact too.
     """
     x = x_ref[...]
     w = q_ref[...].astype(x.dtype)
-    acc = jnp.dot(x, w, preferred_element_type=jnp.float32)   # [M, bn]
+    acc = jnp.dot(x, w, preferred_element_type=jnp.float32)   # [bm, bn]
     o_ref[...] = (acc * s_ref[...]).astype(o_ref.dtype)
 
 
@@ -92,21 +94,26 @@ def _int8_matmul_2d(x, q, scale, block_n: int, interpret: bool):
     m, k = x.shape
     kq, n = q.shape
     bn = min(block_n, _pad_to(n, _TILE))
-    mp = m if interpret else _pad_to(max(m, 8), 8)
+    # M is tiled too: a prefill-sized x ([8192, 768] bf16 is 12 MB, twice
+    # that double-buffered) does not fit the 16 MB scoped VMEM whole.
+    bm = min(_BLOCK_M, m if interpret else _pad_to(max(m, 8), 8))
+    mp = _pad_to(m, bm)
     kp = k if interpret else _pad_to(k, _TILE)
     np_ = _pad_to(n, bn)
     xp = jnp.pad(x, ((0, mp - m), (0, kp - k)))
     qp = jnp.pad(q, ((0, kp - k), (0, np_ - n)))
     sp = jnp.pad(scale, ((0, 0), (0, np_ - n)))
+    # N is the inner grid axis: the x block stays resident while the
+    # (smaller) weight blocks stream past it.
     out = pl.pallas_call(
         _kernel,
-        grid=(np_ // bn,),
+        grid=(mp // bm, np_ // bn),
         in_specs=[
-            pl.BlockSpec((mp, kp), lambda j: (0, 0)),
-            pl.BlockSpec((kp, bn), lambda j: (0, j)),
-            pl.BlockSpec((1, bn), lambda j: (0, j)),
+            pl.BlockSpec((bm, kp), lambda i, j: (i, 0)),
+            pl.BlockSpec((kp, bn), lambda i, j: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((mp, bn), lambda j: (0, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         interpret=interpret,
     )(xp, qp, sp)

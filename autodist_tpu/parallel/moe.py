@@ -17,6 +17,7 @@ caller — models fold it into the training loss.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -119,6 +120,23 @@ def _top2_dispatch(probs: jax.Array, capacity: int
     return dispatch, combine, aux
 
 
+@functools.partial(jax.jit, static_argnames=("fmt", "sharding"))
+def _quantized_a2a(t: jax.Array, *, fmt, sharding) -> jax.Array:
+    """Quantize, cross the expert a2a boundary as wire payload, and
+    dequantize.  One jitted program, so the flat reshapes of the
+    expert-sharded ``t`` stay inside GSPMD: run eagerly under
+    ``jax.set_mesh``, each op must name its result's sharding on the
+    mesh, and the flattened ``[E, G, ...]`` layout has none (jax 0.9.0
+    raises from ``_gspmd_to_named_sharding_via_mesh``)."""
+    from autodist_tpu.kernel.synchronization import quant_ring
+
+    q, scales, _ = quant_ring.quantize_blocks(
+        t.astype(jnp.float32).reshape(-1), fmt)
+    q = jax.lax.with_sharding_constraint(q.reshape(t.shape), sharding)
+    deq = quant_ring.dequantize_blocks(q.reshape(-1), scales)
+    return deq.reshape(t.shape).astype(t.dtype)
+
+
 def moe_ffn(params: dict, x: jax.Array, *,
             capacity_factor: float = 2.0,
             mesh: Optional[Mesh] = None,
@@ -176,10 +194,7 @@ def moe_ffn(params: dict, x: jax.Array, *,
         # manual over pipe/data) a constraint may only name AUTO axes —
         # drop any axis the current trace has manualized (it is already
         # device-local there).
-        try:
-            manual = set(jax.sharding.get_abstract_mesh().manual_axes)
-        except Exception:  # pragma: no cover - API drift
-            manual = set()
+        manual = set(jax.sharding.get_abstract_mesh().manual_axes)
         if MESH_AXIS_EXPERT in manual:
             ep_sharding = None
         else:
@@ -198,14 +213,7 @@ def moe_ffn(params: dict, x: jax.Array, *,
             return t
         if fmt is None:
             return jax.lax.with_sharding_constraint(t, ep_sharding)
-        from autodist_tpu.kernel.synchronization import quant_ring
-
-        q, scales, _ = quant_ring.quantize_blocks(
-            t.astype(jnp.float32).reshape(-1), fmt)
-        q = jax.lax.with_sharding_constraint(
-            q.reshape(t.shape), ep_sharding)
-        deq = quant_ring.dequantize_blocks(q.reshape(-1), scales)
-        return deq.reshape(t.shape).astype(t.dtype)
+        return _quantized_a2a(t, fmt=fmt, sharding=ep_sharding)
 
     expert_in = a2a(jnp.einsum("gsec,gsm->egcm", dispatch, x))  # [E,G,C,M]
     h = activation(jnp.einsum("egcm,emf->egcf", expert_in, params["wi"]))

@@ -62,8 +62,6 @@ def make_zero1_update(mesh, lr: float, num_shards: int) -> Callable:
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from autodist_tpu.utils import compat
-
     d = max(int(num_shards), 1)
 
     def zstep(gstack, pflat):
@@ -76,7 +74,7 @@ def make_zero1_update(mesh, lr: float, num_shards: int) -> Callable:
         nsh = (psh - lr * gsh).astype(pflat.dtype)
         return lax.all_gather(nsh, MESH_AXIS_DATA, tiled=True)
 
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         zstep, mesh=mesh, in_specs=(P(MESH_AXIS_DATA), P()),
         out_specs=P(), axis_names={MESH_AXIS_DATA}, check_vma=False))
 

@@ -40,7 +40,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from autodist_tpu.const import MESH_AXIS_PIPE
-from autodist_tpu.utils import compat
 
 
 def interleaved_stage_order(num_stages: int, num_virtual_stages: int
@@ -165,7 +164,7 @@ def _jitted_pipeline(stage_fn: Callable, mesh: Mesh, num_microbatches: int,
     # caller already traces) because eager shard_map with partial axis_names
     # trips JAX's internal unmatch path — same workaround as
     # ops/flash_attention.make_flash_attention.
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis_name), P()), out_specs=P(),
         axis_names={axis_name}, check_vma=False,
@@ -182,7 +181,7 @@ def _pipeline_local(stage_fn: Callable, chunk_params: Any, x: jax.Array, *,
     Device 0 injects a fresh microbatch whenever the arriving ring slot is
     empty (``v=0``); the last device banks whenever it finishes ``v=V-1``.
     """
-    s = compat.axis_size(axis_name)
+    s = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     m = num_microbatches
     nv = num_virtual
@@ -223,7 +222,7 @@ def _pipeline_local(stage_fn: Callable, chunk_params: Any, x: jax.Array, *,
         a_next = lax.ppermute(y, axis_name, perm)
         return (acc, a_next), None
 
-    vary = lambda v_: compat.pcast(v_, axis_name, to="varying")  # noqa: E731
+    vary = lambda v_: lax.pcast(v_, axis_name, to="varying")  # noqa: E731
     acc0 = vary(jnp.zeros_like(mb))
     ticks = schedule_ticks(int(s), m, nv)
     (acc, _), _ = lax.scan(tick, (acc0, vary(zero)), jnp.arange(ticks))

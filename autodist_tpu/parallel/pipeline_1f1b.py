@@ -70,7 +70,6 @@ from autodist_tpu.kernel.synchronization.schedule_ir import (  # noqa: F401
     bubble_fraction_1f1b,
     schedule_ticks_1f1b,
 )
-from autodist_tpu.utils import compat
 
 
 def one_f_one_b(stage_fn: Callable, loss_fn: Callable, stage_params: Any,
@@ -197,7 +196,7 @@ def _jitted_1f1b(stage_fn: Callable, loss_fn: Callable, mesh: Mesh,
                               has_lp=has_loss_params, dp_axis=dp_axis)
     bspec = P(dp_axis) if dp_axis else P()
     manual = {axis_name} | ({dp_axis} if dp_axis else set())
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis_name), P(), bspec, bspec),
         out_specs=(P(), P(axis_name), P(), bspec),
@@ -219,7 +218,7 @@ def _local_1f1b(stage_fn: Callable, loss_fn: Callable, chunk_params: Any,
     ``tj + 2(SV−1) − g``.  Inverting for (tick, device) gives exactly one
     forward chunk ``vf`` and one backward chunk ``vb`` per device per
     tick — both streams ride one uniform ppermute pair."""
-    s = compat.axis_size(axis_name)
+    s = lax.axis_size(axis_name)
     d = lax.axis_index(axis_name)
     period = s * nv
     # chunk_params local shape [1, V, ...]: squeeze the device dim.
@@ -239,7 +238,7 @@ def _local_1f1b(stage_fn: Callable, loss_fn: Callable, chunk_params: Any,
 
     fwd_perm = [(i, (i + 1) % s) for i in range(s)]
     bwd_perm = [(i, (i - 1) % s) for i in range(s)]
-    vary = lambda v: compat.pcast(v, axis_name, to="varying")  # noqa: E731
+    vary = lambda v: lax.pcast(v, axis_name, to="varying")  # noqa: E731
     ticks = schedule_ticks_1f1b(int(s), m, nv)
 
     def chunk_at(v):
@@ -352,7 +351,7 @@ def _local_1f1b(stage_fn: Callable, loss_fn: Callable, chunk_params: Any,
         # Each data shard computed d(mean over ITS rows); the global loss
         # is the mean over shards, so everything averages over data —
         # except dx, whose rows are shard-local: scale by 1/D.
-        dsize = compat.axis_size(dp_axis)
+        dsize = lax.axis_size(dp_axis)
         loss = lax.pmean(loss, dp_axis)
         dparams = jax.tree_util.tree_map(
             lambda g: lax.pmean(g, dp_axis), dparams)

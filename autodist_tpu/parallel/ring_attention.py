@@ -25,14 +25,13 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from autodist_tpu.const import MESH_AXIS_SEQ
-from autodist_tpu.utils import compat
 
 _NEG_INF = -1e30  # finite "minus infinity": keeps exp()/max() NaN-free
 
 
 def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool):
     """Runs on one device inside shard_map: q/k/v are local seq shards."""
-    axis_size = compat.axis_size(axis_name)
+    axis_size = lax.axis_size(axis_name)
     axis_index = lax.axis_index(axis_name)
     b, t_q, h, d = q.shape
     t_k = k.shape[1]
@@ -70,7 +69,7 @@ def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool):
 
     # pcast-to-varying: the accumulators are per-shard values (varying over
     # the manual seq axis) even though their initial contents are constants.
-    vary = lambda x: compat.pcast(x, axis_name, to="varying")  # noqa: E731
+    vary = lambda x: lax.pcast(x, axis_name, to="varying")  # noqa: E731
     o0 = vary(jnp.zeros((b, h, t_q, d), jnp.float32))
     l0 = vary(jnp.zeros((b, h, t_q), jnp.float32))
     m0 = vary(jnp.full((b, h, t_q), _NEG_INF, jnp.float32))
@@ -99,7 +98,7 @@ def _ring_flash_local(q, k, v, *, axis_name: str, causal: bool,
     the dense ring's cost model)."""
     from autodist_tpu.ops.flash_attention import flash_attention_with_lse
 
-    axis_size = compat.axis_size(axis_name)
+    axis_size = lax.axis_size(axis_name)
     axis_index = lax.axis_index(axis_name)
     flash = functools.partial(flash_attention_with_lse, block_q=block_q,
                               block_k=block_k, interpret=interpret)
@@ -168,7 +167,7 @@ def make_ring_attention(mesh: Mesh, axis_name: str = MESH_AXIS_SEQ,
         local = functools.partial(
             _ring_flash_local, axis_name=axis_name, causal=causal,
             block_q=block_q, block_k=block_k, interpret=interpret)
-        return jax.jit(compat.shard_map(
+        return jax.jit(jax.shard_map(
             local, mesh=mesh,
             in_specs=(spec, spec, spec), out_specs=spec,
             axis_names={axis_name}, check_vma=False))
@@ -177,14 +176,11 @@ def make_ring_attention(mesh: Mesh, axis_name: str = MESH_AXIS_SEQ,
         if mesh.shape.get(axis_name, 1) <= 1:
             from autodist_tpu.models.transformer import dense_attention
             return dense_attention(q, k, v, causal)
-        # Legacy shard_map hard-aborts XLA on this ring's
-        # collective_permute — fail cleanly instead of crashing.
-        compat.require_native("shard_map", "ring attention")
         if inner == "flash":
             return _flash_ring(bool(causal))(q, k, v)
         local = functools.partial(_ring_attention_local,
                                   axis_name=axis_name, causal=causal)
-        return compat.shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(spec, spec, spec), out_specs=spec,
             axis_names={axis_name})(q, k, v)

@@ -20,7 +20,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from autodist_tpu.const import MESH_AXIS_SEQ
-from autodist_tpu.utils import compat
 
 
 def _ulysses_local(q, k, v, *, axis_name: str, causal: bool,
@@ -79,10 +78,10 @@ def make_ulysses_attention(mesh: Mesh, axis_name: str = MESH_AXIS_SEQ,
         # no vma; partial-axes eager shard_map needs the jit wrapper —
         # same workarounds as ring_attention.py).
         if inner == "flash":
-            return jax.jit(compat.shard_map(
+            return jax.jit(jax.shard_map(
                 local, mesh=mesh, in_specs=(spec, spec, spec),
                 out_specs=spec, axis_names={axis_name}, check_vma=False))
-        return compat.shard_map(
+        return jax.shard_map(
             local, mesh=mesh, in_specs=(spec, spec, spec),
             out_specs=spec, axis_names={axis_name})
 
@@ -94,9 +93,6 @@ def make_ulysses_attention(mesh: Mesh, axis_name: str = MESH_AXIS_SEQ,
             raise ValueError(
                 f"Ulysses needs num_heads ({q.shape[2]}) divisible by the "
                 f"'{axis_name}' axis size ({n}); use ring attention instead")
-        # Legacy shard_map hard-aborts XLA on the all-to-all lowering —
-        # fail cleanly instead of crashing.
-        compat.require_native("shard_map", "Ulysses attention")
         return _mapped(bool(causal))(q, k, v)
 
     return attn_fn

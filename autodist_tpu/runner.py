@@ -256,15 +256,23 @@ class DistributedSession:
         except Exception:
             return None
 
+    def lower_step(self, batch):
+        """The training step lowered for ``batch`` (placed here if it is a
+        host batch) and the current state: ``.as_text()`` is the traced
+        StableHLO, ``.compile()`` the XLA executable whose ``as_text()``
+        is what runs — sharded, fused, collectives inserted.  AOT
+        compilation does not seed jit's dispatch cache; a step that
+        already ran comes back from the persistent compilation cache
+        where one is set."""
+        return self._step.step_fn.lower(
+            self._params, self._opt_state, self._sync_state,
+            self._step.place_batch(batch))
+
     def _dump_programs(self, batch) -> None:
-        """Staged program dumps at first run, when concrete shapes exist:
-        the traced StableHLO (transformed program) and the XLA-optimized
-        HLO (what executes — sharded, fused, collectives inserted).  Note
-        AOT lower().compile() is not guaranteed to seed jit's dispatch
-        cache, so the first run may compile the step a second time —
-        a debug-only cost, paid only under AUTODIST_DUMP_GRAPHS=1."""
-        lowered = self._step.step_fn.lower(self._params, self._opt_state,
-                                           self._sync_state, batch)
+        """Staged program dumps at first run, when concrete shapes exist
+        (see :meth:`lower_step`) — a debug-only second compile, paid only
+        under AUTODIST_DUMP_GRAPHS=1."""
+        lowered = self.lower_step(batch)
         tracing.dump_stage(self._run_id, "2-step-stablehlo",
                            lowered.as_text())
         try:

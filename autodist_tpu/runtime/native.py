@@ -8,6 +8,7 @@ loader then runs in numpy, losing only throughput, not behavior.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -29,19 +30,34 @@ _build_failed = False
 _SRC_NAMES = ("runtime.cpp", "tokenizer.cpp")
 
 
-def _src_mtime() -> float:
-    """Newest mtime across the sources compiled into the library."""
-    return max(os.path.getmtime(os.path.join(_NATIVE_DIR, n))
-               for n in _SRC_NAMES if os.path.exists(
-                   os.path.join(_NATIVE_DIR, n)))
+def _src_digest() -> str:
+    """SHA-256 over the sources compiled into the library.  Kept beside
+    the ``.so`` at build time and compared at load time: unlike mtimes it
+    survives a copy of the tree, so a library is only ever loaded if it
+    was built from the sources that are there now."""
+    h = hashlib.sha256()
+    for name in _SRC_NAMES:
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(name.encode() + b"\0" + f.read())
+    return h.hexdigest()
+
+
+def _is_stale(lib_path: str, digest: str) -> bool:
+    if not os.path.exists(lib_path):
+        return True
+    try:
+        with open(lib_path + ".srchash", encoding="ascii") as f:
+            return f.read().strip() != digest
+    except FileNotFoundError:
+        return True
 
 
 def _build_and_load() -> Optional[ctypes.CDLL]:
     lib_path = os.path.join(_NATIVE_DIR, _LIB_NAME)
     if not os.path.exists(os.path.join(_NATIVE_DIR, "runtime.cpp")):
         return None
-    if (not os.path.exists(lib_path)
-            or os.path.getmtime(lib_path) < _src_mtime()):
+    digest = _src_digest()
+    if _is_stale(lib_path, digest):
         # Serialize concurrent builds across processes (several workers can
         # land on one host): flock a sidecar, then re-check staleness — the
         # loser of the race finds a fresh .so and skips its own make.
@@ -51,10 +67,15 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
         try:
             with open(lock_path, "w") as lock_f:
                 fcntl.flock(lock_f, fcntl.LOCK_EX)
-                if (not os.path.exists(lib_path)
-                        or os.path.getmtime(lib_path) < _src_mtime()):
-                    subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
-                                   capture_output=True)
+                if _is_stale(lib_path, digest):
+                    # -B: make's own staleness rule is the mtimes this
+                    # module no longer trusts.
+                    subprocess.run(["make", "-B", "-C", _NATIVE_DIR],
+                                   check=True, capture_output=True)
+                    tmp = f"{lib_path}.srchash.tmp.{os.getpid()}"
+                    with open(tmp, "w", encoding="ascii") as f:
+                        f.write(digest + "\n")
+                    os.replace(tmp, lib_path + ".srchash")
         except (subprocess.CalledProcessError, OSError) as e:
             # OSError covers missing make, unwritable or read-only
             # native/ dir (EROFS), etc. — all fall back to pure Python.
@@ -69,16 +90,7 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
         logging.warning("could not load %s: %s", lib_path, e)
         return None
 
-    try:
-        _bind_signatures(lib)
-    except AttributeError as e:
-        # A stale prebuilt .so missing newer symbols (copied artifact,
-        # mtime-preserving sync): honor the module contract — fall back
-        # to pure Python everywhere rather than raise from get_lib().
-        logging.warning("native runtime library is stale (%s); using "
-                        "pure-Python fallback — run `make -C native` to "
-                        "rebuild", e)
-        return None
+    _bind_signatures(lib)
     return lib
 
 
@@ -103,10 +115,7 @@ def _bind_signatures(lib: ctypes.CDLL) -> None:
     lib.ad_loader_num_batches.restype = ctypes.c_size_t
     lib.ad_loader_num_batches.argtypes = [ctypes.c_void_p]
     lib.ad_loader_destroy.argtypes = [ctypes.c_void_p]
-    # _v2: the pretokenize flag changed the arity; the rename makes a
-    # stale .so (which still exports the 2-arg ad_bpe_create) hit the
-    # AttributeError staleness guard above instead of silently ignoring
-    # the third argument.
+    # _v2: the pretokenize flag changed the arity of ad_bpe_create.
     lib.ad_bpe_create_v2.restype = ctypes.c_void_p
     lib.ad_bpe_create_v2.argtypes = [ctypes.POINTER(ctypes.c_int32),
                                      ctypes.c_int32, ctypes.c_int32]

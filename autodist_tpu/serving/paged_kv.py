@@ -43,7 +43,8 @@ static-shape TPU rules):
   (``ops/fused_kernels.py``, docs/kernels.md) reads K/V straight
   through the block table via scalar-prefetch index maps with the
   flash-attention online-softmax structure; off-TPU the gather path
-  stays, with a shared drop-reason WARN.
+  stays, with a shared drop-reason WARN (on a TPU a kernel that cannot
+  lower raises).
 
 Numerics are the same single-definition ``TransformerLayer`` math as
 training/decode (the ``attn_fn`` seam), so greedy paged output equals
@@ -84,18 +85,15 @@ def _use_fused_paged_attention() -> bool:
     ``AUTODIST_FUSED_KERNELS=paged_attention``)?  Resolved at TRACE
     time — the jit cache pins the decision per program, like every
     other static knob of ``_paged_chunk_program``.  A requested kernel
-    this platform cannot run falls back to the gather-per-layer path
-    with one shared drop-reason WARN."""
+    that cannot lower raises on a TPU; off-TPU it falls back to the
+    gather-per-layer path with one shared drop-reason WARN."""
     global _paged_kernel_warned
     from autodist_tpu.ops import fused_kernels as fk
-    from autodist_tpu.utils import logging
 
     active, why = fk.paged_attention_status()
     if why is not None and not _paged_kernel_warned:
         _paged_kernel_warned = True
-        logging.warning(
-            "paged decode: fused paged-attention kernel falls back to "
-            "the gather-per-layer program (%s)", why)
+        fk.drop_or_raise("paged decode", fk.KERNEL_PAGED_ATTENTION, why)
     return active
 
 

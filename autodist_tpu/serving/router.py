@@ -855,7 +855,7 @@ class SupervisedReplicaPool:
     it).  The replica must write ``{"host":..., "port":...}`` to
     ``address_file(replica_index)`` once it listens, and should write
     heartbeat beacons into ``attempt.heartbeat_dir`` — the supervisor
-    then applies the training-side failure taxonomy: process exit,
+    then applies the training-side failure classes: process exit,
     stale-beacon DEAD, fresh-beacon-no-progress WEDGED.
 
     A healthy serving replica never exits, so each supervisor's

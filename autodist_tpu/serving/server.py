@@ -280,6 +280,13 @@ class EngineServer:
     def address(self):
         return self._httpd.server_address[:2]
 
+    @property
+    def engine(self):
+        """The engine behind the server.  The driver thread owns it while
+        the server runs: read its counters, pool and trie once the
+        requests of interest have been answered."""
+        return self._engine
+
     # -- driver loop -------------------------------------------------------
 
     def _drive(self) -> None:

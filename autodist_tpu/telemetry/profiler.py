@@ -291,8 +291,6 @@ class LegProfiler:
                 and int(dict(mesh.shape).get(axis, 1)) > 1:
             from jax.sharding import PartitionSpec as P
 
-            from autodist_tpu.utils import compat
-
             d = int(dict(mesh.shape)[axis])
             n = ((n + d - 1) // d) * d
             if kind in ("reduce_scatter", "hier_reduce_scatter",
@@ -328,7 +326,7 @@ class LegProfiler:
             else:  # all_reduce / psum_guard / ps_exchange / dcn_all_reduce
                 body = lambda x: jax.lax.psum(x, axis)  # noqa: E731
                 out_spec = P()
-            fn = jax.jit(compat.shard_map(
+            fn = jax.jit(jax.shard_map(
                 body, mesh=mesh, in_specs=P(axis), out_specs=out_spec,
                 check_vma=False))
             arg = jnp.zeros((n,), dt)

@@ -13,23 +13,29 @@ import time
 from collections import deque
 from typing import Any, Dict, Optional
 
-# Peak dense bf16 FLOP/s per chip, keyed by PJRT device_kind substring.
+# Peak dense bf16 FLOP/s per chip, keyed by the exact PJRT ``device_kind``.
+# Each entry names its source; a kind is added when a run has shown the
+# string PJRT reports for it.
 PEAK_FLOPS_BY_KIND = {
-    "v5 lite": 197e12, "v5e": 197e12,
-    "v5p": 459e12, "v5": 459e12,
-    "v4": 275e12,
-    "v6 lite": 918e12, "v6e": 918e12, "trillium": 918e12,
-    "v3": 123e12, "v2": 46e12,
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip.
+    "TPU v5 lite": 197e12,
 }
 
 
-def peak_flops_per_chip(device) -> float:
-    """Peak dense bf16 FLOP/s of ``device`` (0.0 when unknown/non-TPU)."""
-    kind = (getattr(device, "device_kind", "") or "").lower()
-    for key, peak in PEAK_FLOPS_BY_KIND.items():
-        if key in kind:
-            return peak
-    return 0.0
+def peak_flops_per_chip(device) -> Optional[float]:
+    """Peak dense bf16 FLOP/s of ``device``: None for a non-TPU device
+    (no utilization is defined there); a TPU whose ``device_kind`` is not
+    in :data:`PEAK_FLOPS_BY_KIND` raises, because a guessed peak would
+    turn into a wrong utilization under the right name."""
+    if device.platform != "tpu":
+        return None
+    try:
+        return PEAK_FLOPS_BY_KIND[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak FLOP/s on record for TPU device_kind "
+            f"{device.device_kind!r}; add it to PEAK_FLOPS_BY_KIND with "
+            f"its source") from None
 
 
 class ThroughputMeter:
@@ -86,8 +92,8 @@ def step_flops(step_fn, *args) -> Optional[float]:
 
 def mfu(flops_per_step: float, step_time_s: float, devices) -> Optional[float]:
     """Model FLOPs utilization: per-step model FLOPs over what the mesh's
-    chips could do in that wall time (None for unknown chips)."""
-    peak = sum(peak_flops_per_chip(d) for d in devices)
-    if not peak or not step_time_s:
+    chips could do in that wall time (None off-TPU)."""
+    peaks = [peak_flops_per_chip(d) for d in devices]
+    if not step_time_s or None in peaks:
         return None
-    return flops_per_step / step_time_s / peak
+    return flops_per_step / step_time_s / sum(peaks)

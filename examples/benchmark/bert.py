@@ -3,7 +3,7 @@
 Parity target: reference ``examples/benchmark/bert.py`` (BERT-large
 uncased pre-training, samples/sec).
 
-Run (CPU mesh, tiny):
+Run (CPU mesh rehearsal, tiny):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/benchmark/bert.py --size tiny --batch-size 8
 """

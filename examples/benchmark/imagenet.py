@@ -5,10 +5,12 @@ DenseNet121 / InceptionV3 / VGG16 via keras.applications with a chosen
 AutoDist strategy, reporting images/sec.  Same families here (plus
 ResNet-50, the BASELINE.md headline model) from the TPU-first model zoo.
 
-Run (CPU mesh):
+Run (CPU mesh rehearsal):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/benchmark/imagenet.py --model resnet50 \
         --image-size 64 --batch-size 16
+On a machine with a TPU: python examples/benchmark/imagenet.py --model
+resnet50 (no JAX_PLATFORMS, no XLA_FLAGS; its images/sec then is the chip's).
 """
 import os
 import sys

@@ -4,7 +4,7 @@ Parity target: reference ``examples/benchmark`` NCF on MovieLens.  The
 user/item embedding tables are the sparse-gradient variables; PS-family
 strategies shard them across the mesh.
 
-Run (CPU mesh, tiny):
+Run (CPU mesh rehearsal, tiny):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/benchmark/ncf.py --num-users 1024 --num-items 512
 """

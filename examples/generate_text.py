@@ -1,7 +1,7 @@
 """Train a tiny LM through AutoDist, then decode from it with the
 KV-cache generator (``models/generate.py``) — the serving-side loop.
 
-Run (CPU mesh):
+Run (CPU mesh rehearsal):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/generate_text.py
 """

@@ -6,7 +6,7 @@ here on the TPU mesh (BASELINE.json parity config: "ResNet-50 —
 AllReduce").  For the measured benchmark loop use
 ``benchmark/imagenet.py``.
 
-Run (CPU mesh):
+Run (CPU mesh rehearsal):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/image_classifier.py
 """

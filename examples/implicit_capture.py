@@ -3,7 +3,7 @@ wrapping it in ``ad.scope()`` — no ``capture()`` call, no session plumbing
 in the model code (the reference's ``PatchTensorFlow`` promise,
 ``autodist/patch.py:40-116``; here via ``autodist_tpu/patch.py``).
 
-Run on a virtual mesh:
+Run (CPU mesh rehearsal):
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/implicit_capture.py

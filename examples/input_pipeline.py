@@ -7,7 +7,7 @@ gather + bf16-cast batches on host) feeds ``session.fit`` (device
 prefetch + async dispatch), while an ``async_save`` Saver persists
 checkpoints in the background of training.
 
-Run (CPU mesh):
+Run (CPU mesh rehearsal):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/input_pipeline.py
 """

@@ -4,7 +4,7 @@ Parity target: reference ``examples/linear_regression.py`` (TF1 graph built
 under ``ad.scope()``, trained via ``ad.create_distributed_session()``).
 TPU-native version: capture a functional program, run distributed steps.
 
-Run (CPU mesh):
+Run (CPU mesh rehearsal):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/linear_regression.py
 """

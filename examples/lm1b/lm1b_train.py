@@ -6,7 +6,7 @@ sparse-gradient / PartitionedPS workload (SURVEY §5.7).  The Parallax
 strategy reproduces its hybrid: dense grads allreduced, embedding grads
 sharded onto the owning vocab shard.
 
-Run (CPU mesh, tiny vocab):
+Run (CPU mesh rehearsal, tiny vocab):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/lm1b/lm1b_train.py --vocab-size 4096 --batch-size 16
 """

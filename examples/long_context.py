@@ -6,7 +6,7 @@ SURVEY §5.7): a causal LM whose sequence dimension is sharded over the
 attention) so max context grows linearly with chips at constant per-chip
 memory — while per-chip attention blocks use the Pallas flash kernel.
 
-Run (CPU mesh):
+Run (CPU mesh rehearsal):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/long_context.py --seq-len 512
 """

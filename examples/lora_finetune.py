@@ -4,7 +4,7 @@ Pretrains a small TransformerLM on one distribution, then LoRA-finetunes
 it onto a shifted distribution with the base frozen — optimizer state
 exists only for the adapters — and decodes from the merged weights.
 
-Run (CPU mesh):
+Run (CPU mesh rehearsal):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/lora_finetune.py
 """

@@ -5,7 +5,7 @@ stage-stacked MoE transformer — layer stack sharded over ``pipe``
 (microbatch ppermute ring), expert weights over ``expert`` (GSPMD
 all-to-all dispatch), batch over ``data``.
 
-Run (CPU mesh):
+Run (CPU mesh rehearsal):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/moe_pipeline.py
 """

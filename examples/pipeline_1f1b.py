@@ -9,7 +9,7 @@ Their losses match step for step; the memory difference is what you buy.
 (each device holds V chunks; the warmup/drain bubble shrinks — see the
 algebra in ``parallel/pipeline_1f1b.py``).
 
-Run (CPU mesh):
+Run (CPU mesh rehearsal):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/pipeline_1f1b.py --virtual-stages 2 --num-layers 8
 (num_layers must divide into pipe x virtual-stages chunks.)

@@ -4,7 +4,7 @@ trained under PartitionedPS (the vocab-sized embedding is what the
 variable partitioner is for).  Synthetic separable data stands in for
 IMDB, like the reference's random batches.
 
-Run (CPU mesh):
+Run (CPU mesh rehearsal):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/sentiment_classifier.py
 """

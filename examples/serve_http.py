@@ -6,9 +6,11 @@ streaming completion, and a stats read — the deployable serving loop
 (model → engine → HTTP) the reference framework (training-only) has no
 counterpart for.
 
-Run (CPU mesh):
+Run (CPU mesh rehearsal):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/serve_http.py
+On a machine with a TPU: python examples/serve_http.py (no JAX_PLATFORMS,
+no XLA_FLAGS; this process then holds the chip).
 Point a real client at it with --port 8000 --hold.
 """
 import argparse

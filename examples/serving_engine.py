@@ -5,7 +5,7 @@ are harvested and queued ones admitted (with parallel prompt prefill)
 without stopping the batch — the production decode loop the reference
 framework (training-only) stops short of.
 
-Run (CPU mesh):
+Run (CPU mesh rehearsal):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/serving_engine.py
 """

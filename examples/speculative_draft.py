@@ -8,7 +8,7 @@ verify pass, the target accepts a measured fraction, and the output is
 STILL token-exact target-greedy (the greedy-acceptance guarantee holds
 regardless of draft quality).
 
-Run (CPU mesh):
+Run (CPU mesh rehearsal):
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/speculative_draft.py
 """

@@ -86,14 +86,8 @@ def train() -> None:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 2)
-    except AttributeError:
-        pass
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass
+    jax.config.update("jax_num_cpu_devices", 2)
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     import numpy as np
     import optax

@@ -91,14 +91,8 @@ def stage_worker() -> None:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 1)
-    except AttributeError:
-        pass    # older jaxlibs only honor the XLA_FLAGS form above
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass
+    jax.config.update("jax_num_cpu_devices", 1)
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     # THIS stage group's own world: stage-local rendezvous, so the
     # pipeline is genuinely MPMD — two programs that never co-issue.
     jax.distributed.initialize(coordinator_address=coord,

@@ -29,17 +29,14 @@ import socket
 import subprocess
 import sys
 
-# 2 local CPU devices per process -> 4 global over 2 processes.  Set via
-# XLA_FLAGS BEFORE any jax import: unlike dist_train.py's
-# jax_num_cpu_devices config (jax >= 0.5), this works on 0.4.x jaxlibs
-# too — replacing whatever count the parent test process forced.
+# 2 local CPU devices per process -> 4 global over 2 processes, replacing
+# whatever count the parent test process forced through XLA_FLAGS.
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
                 os.environ.get("XLA_FLAGS", "")).strip()
 os.environ["XLA_FLAGS"] = \
     (_flags + " --xla_force_host_platform_device_count=2").strip()
-# Cross-process CPU collectives (0.4.x spells it via this knob; newer
-# jaxlibs default to a working CPU collectives impl).
+# Cross-process CPU collectives.
 os.environ.setdefault("JAX_CPU_COLLECTIVES_IMPLEMENTATION", "gloo")
 
 sys.path.insert(0, os.environ.get("AUTODIST_REPO_ROOT",
@@ -101,14 +98,8 @@ def train() -> None:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 2)
-    except AttributeError:
-        pass   # 0.4.x: the XLA_FLAGS form above already took effect
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass   # newer jax: CPU collectives need no explicit selection
+    jax.config.update("jax_num_cpu_devices", 2)
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     import numpy as np
     import optax

@@ -1,0 +1,95 @@
+"""chip_smoke.py's phase functions at a tiny size on the CPU mesh, the
+command's refusal to pass without a chip, and the two small repairs that
+came with it (the compile-cache helper; the peaks table is in
+test_metrics.py)."""
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+
+# The serving tests' model and engine geometry (test_serving_scheduler.py,
+# test_serving_server.py): the paged and slot programs live in module-scope
+# jit caches, so the shapes compiled here are not compiled again there.
+TINY_LM = dict(vocab_size=61, num_layers=2, num_heads=2, head_dim=8,
+               d_ff=32, max_len=48, seq_len=16)
+TINY_SIZES = dict(p=16, prefix=16, tails=(3, 5), long=20, mid=10,
+                  n=(4, 5, 6, 7))      # prompt buckets 8, 16 and 32 only
+TINY_ENGINE = dict(slots=2, window=32, block_size=8, num_blocks=24, chunk=4)
+
+
+def test_kernels_phase_interpreted():
+    chip_smoke.kernels_phase(
+        flash=[((1, 16, 2, 8), "float32", True, 1e-5)],
+        matmul_shapes=[(8, 64, 128)], bucket_elems=8192,
+        paged=dict(slots=2, heads=2, head_dim=8, block_size=8,
+                   blocks_per_slot=2),
+        interpret=True)
+
+
+@pytest.fixture(scope="module")
+def lm():
+    return chip_smoke.make_lm(TINY_LM, jnp.float32)
+
+
+def test_train_phase_on_cpu_mesh(lm):
+    facts = chip_smoke.train_phase(
+        *lm, strategy="AllReduce", mesh_axes={"data": 4}, batch_size=8,
+        steps=2)
+    assert facts["mesh"] == {"data": 4}
+    assert facts["losses"][-1] < facts["losses"][0]
+
+
+def test_serve_phases_over_http(lm):
+    facts = chip_smoke.serve_paged_phase(
+        *lm, sizes=TINY_SIZES, engine=TINY_ENGINE)
+    assert facts["requests"] == 8
+    assert facts["trie_hit_blocks"] == 1 + 1 + 2   # twins, shared prefix
+    slots = chip_smoke.serve_slots_phase(
+        *lm, sizes=TINY_SIZES, engine=dict(slots=2, window=24, chunk=4))
+    assert slots["completed"] == 2
+    exact = chip_smoke.serve_exact_phase(
+        *lm, sizes=TINY_SIZES, engine=TINY_ENGINE)
+    assert exact["token_exact"] == exact["of"] == 2   # float32 on the CPU
+
+
+def test_pallas_call_shapes_reads_compiled_hlo():
+    hlo = ('  %fwd.3 = (f32[8,6,2048,64]{3,2,1,0}, f32[8,6,2048,1]{3,2,1,0})'
+           ' custom-call(f32[8,6,2048,64]{3,2,1,0} %a), '
+           'custom_call_target="tpu_custom_call"\n'
+           '  %other = f32[4]{0} custom-call(), custom_call_target="x"\n')
+    assert chip_smoke.pallas_call_shapes(hlo) == [
+        [("f32", (8, 6, 2048, 64)), ("f32", (8, 6, 2048, 1))]]
+
+
+def test_command_refuses_the_cpu():
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=120)
+    assert proc.returncode != 0
+    assert "'cpu'" in proc.stderr
+    assert '"ok"' not in proc.stdout
+
+
+def test_compile_cache_helper(monkeypatch):
+    from autodist_tpu.utils.compile_cache import place_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+        assert place_compile_cache() == "/somewhere/else"
+        assert jax.config.jax_compilation_cache_dir == before  # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = os.path.join(REPO, ".jax_cache")
+        assert place_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

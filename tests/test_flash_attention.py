@@ -155,17 +155,48 @@ def test_pallas_call_present_in_tpu_lowering():
 
 
 def test_default_attention_resolves_by_backend():
-    """Construction-time backend decision (not trace time): dense on the
-    CPU test backend; the factory exists for TPU."""
-    from autodist_tpu.models.transformer import default_attention
+    """Resolved at the first call, not at construction (building a model
+    must not initialize a backend): dense on the CPU test backend."""
+    from autodist_tpu.models import transformer
 
-    assert default_attention() is dense_attention  # CPU test backend
+    assert transformer._resolve_default_attention() is dense_attention
+    q, k, v = _qkv(np.random.RandomState(0), 2, 16, 2, 8)
+    np.testing.assert_array_equal(
+        transformer.default_attention()(q, k, v, True),
+        dense_attention(q, k, v, True))
 
     from autodist_tpu.models.transformer_lm import transformer_lm
 
     spec = transformer_lm(vocab_size=64, num_layers=1, num_heads=2,
                           head_dim=8, d_ff=32, max_len=16)
     assert spec.config["vocab_size"] == 64  # factory accepts attn_fn=None
+
+
+def test_meshless_flash_shards_over_the_trace_mesh_context():
+    """The model-zoo default is built with no mesh.  Traced where a mesh
+    context is set (a session's step sets its own), it must run the
+    kernel per shard: in the TPU lowering the Mosaic custom call sees the
+    per-device batch and heads, not the global ones GSPMD would gather."""
+    import re
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("data", "model"))
+    attn = make_flash_attention(interpret=False)
+    sh = NamedSharding(mesh, P("data", None, "model"))
+    q = jax.ShapeDtypeStruct((8, 256, 4, 64), jnp.float32, sharding=sh)
+
+    def f(q, k, v):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return attn(q, k, v, True)
+
+    txt = jax.jit(f).trace(q, q, q).lower(
+        lowering_platforms=("tpu",)).as_text()
+    calls = [ln for ln in txt.splitlines() if "tpu_custom_call" in ln]
+    assert calls
+    for ln in calls:   # kernel layout is [B, H, T, D]: 8/4 x 4/2
+        assert re.search(r"tensor<2x2x256x64xf32>", ln), ln
+        assert "tensor<8x" not in ln, ln
 
 
 def test_block_picker_prefers_tile_multiples():

@@ -205,8 +205,6 @@ def test_fused_ring_reduce_scatter_matches_unfused():
     ring at 1e-6 (the wire payloads are the same grid)."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from autodist_tpu.utils import compat
-
     n = 8
     mesh = Mesh(np.array(jax.devices()[:n]), ("data",))
     rng = np.random.default_rng(13)
@@ -218,7 +216,7 @@ def test_fused_ring_reduce_scatter_matches_unfused():
                 v.reshape(-1), "data", n, quant_ring.WIRE_INT8,
                 fused=fused)
             return out, err, sat[None]
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=P("data"),
             out_specs=(P("data"), P("data"), P("data")),
             check_vma=False))
@@ -653,8 +651,12 @@ def test_paged_drop_reason_warns_once_off_tpu(monkeypatch):
     finally:
         logger.removeHandler(grab)
     msgs = [m for m in grab.messages
-            if "paged-attention kernel falls back" in m]
+            if "fused kernel paged_attention falls back" in m]
     assert len(msgs) == 1 and "TPU backend" in msgs[0]
+    # On a TPU the same drop is an error, not a quiet unfused run.
+    monkeypatch.setattr(fk, "_platform_tpu", lambda: True)
+    with pytest.raises(RuntimeError, match="cannot lower: no Adam"):
+        fk.drop_or_raise("explicit sync path", fk.KERNEL_UPDATE, "no Adam")
 
 
 # ---------------------------------------------------------------------------

@@ -3,6 +3,7 @@
 The reference measured throughput only in example scripts
 (``examples/benchmark/imagenet.py:85-120`` TimeHistory); here it is a
 DistributedSession feature, plus MFU from XLA cost analysis."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -71,6 +72,7 @@ def test_session_mfu_none_on_cpu():
 
 def test_peak_flops_table():
     class FakeDev:
+        platform = "tpu"
         device_kind = "TPU v5 lite"
 
     assert metrics.peak_flops_per_chip(FakeDev()) == 197e12
@@ -79,3 +81,12 @@ def test_peak_flops_table():
     # two chips halve it
     assert metrics.mfu(19.7e12, 1.0,
                        [FakeDev(), FakeDev()]) == pytest.approx(0.05)
+
+    class UnknownTpu:
+        platform = "tpu"
+        device_kind = "TPU v5"
+
+    # No substring match: an unlisted TPU kind is an error, not v5e's peak.
+    with pytest.raises(ValueError, match="TPU v5"):
+        metrics.peak_flops_per_chip(UnknownTpu())
+    assert metrics.peak_flops_per_chip(jax.devices()[0]) is None  # cpu

@@ -26,7 +26,6 @@ from autodist_tpu.autodist import AutoDist, _reset_default_autodist_for_testing
 from autodist_tpu.kernel.synchronization import overlap as ov
 from autodist_tpu.kernel.synchronization.bucketing import assign_buckets
 from autodist_tpu.strategy import AllReduce, Zero1
-from autodist_tpu.utils import compat
 
 pytestmark = [pytest.mark.sync, pytest.mark.overlap]
 
@@ -59,7 +58,7 @@ def test_ring_legs_match_lax_collectives():
                 ov.one_shot_all_reduce_mean(xs, "data", n),
                 lax.pmean(xs, "data"))
 
-    m = compat.shard_map(f, mesh=mesh, in_specs=P("data"),
+    m = jax.shard_map(f, mesh=mesh, in_specs=P("data"),
                          out_specs=(P("data"),) * 7, check_vma=False)
     rs, rs_ref, ag, ag_ref, ar, os_, ar_ref = jax.jit(m)(x)
     np.testing.assert_allclose(np.asarray(rs), np.asarray(rs_ref),
@@ -216,6 +215,29 @@ def test_compressed_modes_fall_back_and_stay_exact(compressor):
     _assert_same_trajectory(auto, off, batch, steps=4)
 
 
+def _tail_atol(rows=32, steps=6, lr=1e-2, kappa=8.0):
+    """Parameter tolerance for the uneven-tail comparisons, from float32
+    arithmetic rather than by trial.
+
+    Both schedules add the same ``rows`` row-weighted per-row gradient
+    terms into every gradient element (the weights 2/4, 1/4, 1/4 and
+    1/8 are powers of two, so the products are exact) but in another
+    order: the pipelined schedule reduces over devices inside each
+    microbatch and then adds the microbatches, the sequential loop adds
+    the microbatches first.  Reassociating an n-term float32 sum moves
+    it by at most ``(n-1) * u * sum|t|`` with ``u = 2**-24`` (Higham,
+    *Accuracy and Stability of Numerical Algorithms*, eq. 4.4): a
+    relative ``(n-1) * u * kappa``, ``kappa = sum|t| / |sum t|``.
+    Measured on this problem, the two schedules' gradients differ by at
+    most 0.33 of that bound, so the difference IS reassociation.  Adam's
+    step ``lr * m / (sqrt(v) + eps)`` is scale-free, so the relative
+    error reaches the parameter as ``lr * (n-1) * u * kappa`` per step.
+    ``kappa``: the median over this problem's gradient elements is 6 to
+    11; 8 is allowed.  The even-split tests pass at the default 1e-7
+    and keep it."""
+    return steps * lr * (rows - 1) * 2.0 ** -24 * kappa
+
+
 def test_pipelined_uneven_tail_microbatches():
     """32-row global batch over 8 devices = 4 local rows; accum_steps=3
     runs uneven [2, 1, 1] microbatches, row-weighted in both the
@@ -225,7 +247,7 @@ def test_pipelined_uneven_tail_microbatches():
                          params, loss_fn, accum=3)
     sequential = _session(AllReduce(bucket_bytes=1 << 20, overlap="none"),
                           params, loss_fn, accum=3)
-    _assert_same_trajectory(pipelined, sequential, batch)
+    _assert_same_trajectory(pipelined, sequential, batch, atol=_tail_atol())
     # ...and both match the unaccumulated full-batch step (row-mean loss)
     plain = _session(AllReduce(bucket_bytes=1 << 20), params, loss_fn)
     pipelined2 = _session(AllReduce(bucket_bytes=1 << 20, overlap="auto"),
@@ -237,7 +259,7 @@ def test_pipelined_zero1_uneven_tail():
     params, loss_fn, batch = _problem(rows=32)
     pipelined = _session(Zero1(overlap="auto"), params, loss_fn, accum=3)
     sequential = _session(Zero1(overlap="none"), params, loss_fn, accum=3)
-    _assert_same_trajectory(pipelined, sequential, batch)
+    _assert_same_trajectory(pipelined, sequential, batch, atol=_tail_atol())
 
 
 def test_single_microbatch_degenerate_case():

@@ -41,7 +41,6 @@ from autodist_tpu.kernel.synchronization import quant_ring as qr
 from autodist_tpu.kernel.synchronization import schedule_ir as sir
 from autodist_tpu.kernel.synchronization.compressor import get_compressor
 from autodist_tpu.strategy import AllReduce, Zero1
-from autodist_tpu.utils import compat
 
 pytestmark = [pytest.mark.sync, pytest.mark.quant]
 
@@ -139,7 +138,7 @@ def test_ring_and_one_shot_reduce_scatter_match_oracle(comp_name):
             xs, "data", n, fmt)
         return ring / n, shot / n, sat_r + sat_s
 
-    m = jax.jit(compat.shard_map(
+    m = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=P("data"),
         out_specs=(P("data"), P("data"), P()), check_vma=False))
     ring, shot, sat = m(x)
@@ -173,7 +172,7 @@ def test_all_reduce_bucket_paths_match_compressor_oracle(comp_name, alg):
         oracle, _ = comp.reduce(xs, jnp.zeros_like(xs), "data")
         return red, oracle, sat
 
-    m = jax.jit(compat.shard_map(
+    m = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=P("data"),
         out_specs=(P(), P(), P()), check_vma=False))
     red, oracle, sat = m(x)
@@ -196,12 +195,12 @@ def test_quantized_ring_all_gather_is_replicated_identically():
                                               qr.WIRE_INT8)
         return out
 
-    m = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=P("data"),
+    m = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"),
                                  out_specs=P(None), check_vma=False))
     # out_specs P(None): replicated output — shard_map would fail the
     # replication check if devices disagreed... but check explicitly:
     full = np.asarray(m(shard))
-    per_dev = jax.jit(compat.shard_map(
+    per_dev = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
         check_vma=False))(shard)
     per_dev = np.asarray(per_dev).reshape(n, -1)
@@ -235,7 +234,7 @@ def test_error_feedback_residual_semantics():
                                                          n, qr.WIRE_INT8)
         return red / n, err, red2 / n
 
-    m = jax.jit(compat.shard_map(
+    m = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=P("data"),
         out_specs=(P("data"), P("data"), P("data")), check_vma=False))
     red, err, red2 = m(x)

@@ -505,10 +505,10 @@ def test_engine_poisoned_after_failed_dispatch(lm, monkeypatch):
     eng.submit(np.arange(2, dtype=np.int32), 4)
 
     def boom(*a, **k):
-        raise RuntimeError("tunnel dropped")
+        raise RuntimeError("device lost")
 
     monkeypatch.setattr(eng_mod, "_chunk_program", boom)
-    with pytest.raises(RuntimeError, match="tunnel dropped"):
+    with pytest.raises(RuntimeError, match="device lost"):
         eng.run()
     monkeypatch.undo()
     with pytest.raises(RuntimeError, match="poisoned"):
