@@ -479,14 +479,21 @@ def main(argv=None) -> int:
             return 1
         return 0
 
-    report = analyze(strategy, graph_item, mesh=axes,
-                     resource_spec=resource_spec, budget_bytes=budget,
-                     passes=passes, elastic=elastic)
+    from autodist_tpu.telemetry import timeline as tl
+
+    with tl.host_span(tl.ANALYSIS_CLI) as span:
+        report = analyze(strategy, graph_item, mesh=axes,
+                         resource_spec=resource_spec, budget_bytes=budget,
+                         passes=passes, elastic=elastic)
 
     if args.json:
         print(json.dumps(report.to_dict(), indent=1))
     else:
-        print(report.format_table())
+        # the verdict line says what the verdict cost: the analysis
+        # alone, not the interpreter's start (None with telemetry off)
+        took = "" if span is None else \
+            f"  [analysis {span.end - span.start:.3f} s]"
+        print(report.format_table() + took)
     if report.has_errors():
         return 1
     if args.warn_as_error and report.warnings:

@@ -26,6 +26,7 @@ from autodist_tpu.resource_spec import ResourceSpec
 from autodist_tpu.runner import DistributedSession
 from autodist_tpu.strategy.base import Strategy, StrategyBuilder
 from autodist_tpu.strategy.compiler import StrategyCompiler
+from autodist_tpu.telemetry import timeline as tl
 from autodist_tpu.utils import logging
 
 _default_autodist: Optional["AutoDist"] = None
@@ -186,9 +187,10 @@ class AutoDist:
             logging.info("worker: loading strategy %s", strategy_id)
             self._strategy = Strategy.deserialize(strategy_id)
         else:
-            self._strategy = self._strategy_builder.build(
-                self._graph_item, self._resource_spec)
-            self._strategy.serialize()
+            with tl.host_span(tl.SETUP_BUILD_STRATEGY):
+                self._strategy = self._strategy_builder.build(
+                    self._graph_item, self._resource_spec)
+                self._strategy.serialize()
         return self._strategy
 
     @property
@@ -247,29 +249,30 @@ class AutoDist:
             mesh = mesh()
         if mesh is None:
             mesh = build_mesh(self._mesh_axes, resource_spec=self._resource_spec)
-        compiled = StrategyCompiler(
-            mesh, resource_spec=self._resource_spec).compile(
-                self._strategy, self._graph_item)
+        with tl.host_span(tl.SETUP_COMPILE_STRATEGY):
+            compiled = StrategyCompiler(
+                mesh, resource_spec=self._resource_spec).compile(
+                    self._strategy, self._graph_item)
         if validate is None:
             validate = ENV.AUTODIST_VALIDATE.val
         if validate:
             from autodist_tpu.analysis import preflight
 
-            preflight(compiled, self._graph_item,
-                      resource_spec=self._resource_spec,
-                      context=f"build:{self._strategy.id}")
-        dist_step = GraphTransformer(compiled, self._graph_item).transform(
-            extra_metrics_fn=self._graph_item.metrics_fn)
+            with tl.host_span(tl.SETUP_PREFLIGHT):
+                preflight(compiled, self._graph_item,
+                          resource_spec=self._resource_spec,
+                          context=f"build:{self._strategy.id}")
+        with tl.host_span(tl.SETUP_TRANSFORM):
+            dist_step = GraphTransformer(
+                compiled, self._graph_item).transform(
+                    extra_metrics_fn=self._graph_item.metrics_fn)
         self._session = DistributedSession(self._graph_item, dist_step)
         logging.info("distributed session created: strategy=%s mesh=%s",
                      self._strategy.id, dict(mesh.shape))
-        try:
-            from autodist_tpu.strategy.cost_model import estimate_cost
-            logging.info("estimated sync cost: %s", estimate_cost(
-                self._strategy, self._graph_item,
-                self._resource_spec).summary())
-        except Exception:  # pragma: no cover - advisory only
-            pass
+        with tl.host_span(tl.SETUP_ESTIMATE_COST):
+            report = self._session.cost_report
+        if report is not None:
+            logging.info("estimated sync cost: %s", report.summary())
         return self._session
 
     # -- TF2-style one-liner (reference autodist.py:204-289) ---------------

@@ -11,6 +11,7 @@ the object users run steps against — and the feed/fetch ``Remapper``
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Any, Callable, Dict, Optional
 
@@ -21,6 +22,7 @@ from autodist_tpu.graph_item import GraphItem
 from autodist_tpu.kernel import sharding_utils as su
 from autodist_tpu.kernel.graph_transformer import DistributedStep
 from autodist_tpu.telemetry import flightrec
+from autodist_tpu.telemetry import timeline as tl
 from autodist_tpu.utils import logging, metrics, tracing
 
 
@@ -34,12 +36,21 @@ class DistributedSession:
     def __init__(self, graph_item: GraphItem, dist_step: DistributedStep):
         self._gi = graph_item
         self._step = dist_step
-        self._params = dist_step.place_params(graph_item.params)
-        self._opt_state = dist_step.init_fn(self._params)
-        self._sync_state = dist_step.init_sync_state(self._params)
+        with tl.host_span(tl.SETUP_PLACE_PARAMS):
+            self._params = dist_step.place_params(graph_item.params)
+        with tl.host_span(tl.SETUP_INIT_OPT_STATE):
+            self._opt_state = dist_step.init_fn(self._params)
+        with tl.host_span(tl.SETUP_INIT_SYNC_STATE):
+            self._sync_state = dist_step.init_sync_state(self._params)
         self._step_count = 0
         self._meter = metrics.ThroughputMeter()
-        self._last_batch = None     # for on-demand FLOPs estimation
+        # Shapes/dtypes of the last batch (on-demand FLOPs estimation)
+        # and its (items, tokens); both rebuilt only when the shapes
+        # change.  Retaining the real batch would pin multi-GB host
+        # buffers for the session lifetime.
+        self._last_batch = None
+        self._batch_key = None
+        self._batch_sizes = (None, None)
         self._flops_per_step: Optional[float] = None
         # Tracing/dumps (SURVEY §5.1): keyed by the strategy id, the same
         # run identifier the reference used for its artifact paths.
@@ -182,46 +193,68 @@ class DistributedSession:
         back-to-back steps dispatch asynchronously without a host round-trip
         per step."""
         rec = self._telemetry
-        t0 = time.perf_counter() if rec is not None else 0.0
-        # Host-phase flight-recorder cursor: "entered step N" — the
-        # coarsest progress beacon, paired with the "exit" stamp
-        # record_step makes.  One object + one ring store when enabled.
-        flightrec.record_cursor("step", kind="phase", event="enter",
-                                step=self._step_count)
-        batch = self._step.place_batch(batch)
-        if self._step_count == 0 and tracing.dumps_enabled():
-            self._dump_programs(batch)
-        with self._tracer.step(self._step_count):
-            self._params, self._opt_state, self._sync_state, out = \
-                self._step.step_fn(self._params, self._opt_state,
-                                   self._sync_state, batch)
-        self._tracer.after_step(self._step_count)
         step_index = self._step_count
-        self._step_count += 1
-        # Shapes/dtypes only — retaining the real batch would pin multi-GB
-        # host buffers for the session lifetime.
-        self._last_batch = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype), batch)
-        self._meter.tick()
-        if rec is not None:
-            # Dispatch time is the host-side cost of issuing the step
-            # (async: excludes device execution — the wall step_time_s
-            # converges to true step time once the pipeline fills).
-            rec.add_phase("dispatch", time.perf_counter() - t0)
-            items, tokens = self._batch_sizes()
-            rec.record_step(step_index, items=items, tokens=tokens)
-        if not sync:
+        with tl.host_span(tl.SESSION_RUN, step=step_index):
+            t0 = time.perf_counter() if rec is not None else 0.0
+            # Host-phase flight-recorder cursor: "entered step N" — the
+            # coarsest progress beacon, paired with the "exit" stamp
+            # record_step makes.  One object + one ring store when enabled.
+            flightrec.record_cursor("step", kind="phase", event="enter",
+                                    step=step_index)
+            with tl.host_span(tl.SESSION_PLACE_BATCH,
+                              step=step_index) as placed:
+                batch = self._step.place_batch(batch)
+            if step_index == 0 and tracing.dumps_enabled():
+                self._dump_programs(batch)
+            with self._tracer.step(step_index), tl.host_span(
+                    tl.SESSION_ENQUEUE, step=step_index) as enqueued:
+                self._params, self._opt_state, self._sync_state, out = \
+                    self._step.step_fn(self._params, self._opt_state,
+                                       self._sync_state, batch)
+            self._tracer.after_step(step_index)
+            self._step_count += 1
+            record = None
+            with tl.host_span(tl.SESSION_RECORD, step=step_index):
+                self._note_batch(batch)
+                self._meter.tick()
+                if rec is not None:
+                    # Dispatch time is the host-side cost of issuing the
+                    # step (async: excludes device execution — the wall
+                    # step_time_s converges to true step time once the
+                    # pipeline fills); place_batch and enqueue are its
+                    # two named parts, from the spans' own clock reads.
+                    rec.add_phase("dispatch", time.perf_counter() - t0)
+                    for phase, span in (("place_batch", placed),
+                                        ("enqueue", enqueued)):
+                        if span is not None:
+                            rec.add_phase(phase, span.end - span.start)
+                    items, tokens = self._batch_sizes
+                    record = rec.record_step(step_index, items=items,
+                                             tokens=tokens)
+            if not sync:
+                return out
+            with tl.host_span(tl.SESSION_FETCH, step=step_index) as fetched:
+                out = jax.tree_util.tree_map(lambda x: np.asarray(x), out)
+            if record is not None and fetched is not None:
+                # the wait for the device, which no part of dispatch holds
+                record.phases["fetch"] = fetched.end - fetched.start
             return out
-        return jax.tree_util.tree_map(lambda x: np.asarray(x), out)
 
-    def _batch_sizes(self):
-        """(items, tokens) of the last batch from shapes alone: items =
-        leading dim; tokens = rows x seq for a 2-D integer leaf (token
-        ids) when one exists."""
-        if self._last_batch is None:
-            return None, None
+    def _note_batch(self, batch) -> None:
+        """Keep the placed batch's shapes/dtypes and its (items, tokens):
+        items = leading dim; tokens = rows x seq for a 2-D integer leaf
+        (token ids) when one exists.  A steady loop feeds one shape, so
+        the tree is rebuilt only when a leaf's shape or dtype differs."""
+        leaves, treedef = jax.tree_util.tree_flatten(batch)
+        key = (treedef, tuple((np.shape(x), x.dtype) for x in leaves))
+        if key == self._batch_key:
+            return
+        self._batch_key = key
+        abstract = [jax.ShapeDtypeStruct(shape, dtype)
+                    for shape, dtype in key[1]]
+        self._last_batch = jax.tree_util.tree_unflatten(treedef, abstract)
         items = tokens = None
-        for leaf in jax.tree_util.tree_leaves(self._last_batch):
+        for leaf in abstract:
             shape = leaf.shape
             if not shape:
                 continue
@@ -230,13 +263,15 @@ class DistributedSession:
             if (tokens is None and len(shape) == 2
                     and np.issubdtype(leaf.dtype, np.integer)):
                 tokens = int(shape[0]) * int(shape[1])
-        return items, tokens
+        self._batch_sizes = (items, tokens)
 
-    def _predict_cost(self) -> Optional[dict]:
+    @functools.cached_property
+    def cost_report(self):
         """The cost model's estimate for this session's strategy on a
-        spec synthesized from the mesh — stamped into every StepRecord
-        (measured-vs-predicted is the calibration bridge,
-        telemetry/calibration.py).  Advisory: any failure returns None."""
+        spec synthesized from the mesh it runs on.  Computed once
+        (``create_distributed_session`` logs it, every StepRecord
+        carries it).  Advisory: None when the model cannot price the
+        strategy."""
         try:
             from autodist_tpu.resource_spec import ResourceSpec
             from autodist_tpu.strategy.cost_model import estimate_cost
@@ -244,17 +279,25 @@ class DistributedSession:
             n = int(self.mesh.devices.size)
             spec = ResourceSpec(resource_info={"nodes": [
                 {"address": "localhost", "chips": n, "chief": True}]})
-            report = estimate_cost(self._step.compiled_strategy.strategy,
-                                   self._gi, spec)
-            return {
-                "time_s": report.time_s,
-                "wire_bytes": report.wire_bytes,
-                "exposed_wire_bytes": report.exposed_wire_bytes,
-                "num_collectives": report.num_collectives,
-                "schedule_fingerprint": self.schedule_fingerprint,
-            }
+            return estimate_cost(
+                self._step.compiled_strategy.strategy, self._gi, spec)
         except Exception:
             return None
+
+    def _predict_cost(self) -> Optional[dict]:
+        """:attr:`cost_report` as the fields stamped into every
+        StepRecord (measured-vs-predicted is the calibration bridge,
+        telemetry/calibration.py)."""
+        report = self.cost_report
+        if report is None:
+            return None
+        return {
+            "time_s": report.time_s,
+            "wire_bytes": report.wire_bytes,
+            "exposed_wire_bytes": report.exposed_wire_bytes,
+            "num_collectives": report.num_collectives,
+            "schedule_fingerprint": self.schedule_fingerprint,
+        }
 
     def lower_step(self, batch):
         """The training step lowered for ``batch`` (placed here if it is a
@@ -293,7 +336,9 @@ class DistributedSession:
             batches = [batches]
         acc, n = None, 0
         for b in batches:
-            out = self._step.eval_fn(self._params, self._step.place_batch(b))
+            with tl.host_span(tl.SESSION_PLACE_BATCH):
+                b = self._step.place_batch(b)
+            out = self._step.eval_fn(self._params, b)
             acc = out if acc is None else jax.tree_util.tree_map(
                 lambda a, x: a + x, acc, out)
             n += 1
@@ -324,7 +369,8 @@ class DistributedSession:
 
         q: deque = deque()
         for b in batches:
-            q.append(self.place_batch(b))
+            with tl.host_span(tl.SESSION_PLACE_BATCH):
+                q.append(self.place_batch(b))
             if len(q) >= depth:
                 yield q.popleft()
         while q:

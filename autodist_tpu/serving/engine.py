@@ -138,12 +138,16 @@ def _sample_per_slot(logits, key, temp, top_k, top_p):
     filters (``sample_next_token`` at temperature 1.0 on the pre-scaled
     logits — the single definition of the filters).  ``submit`` rejects
     temperatures in (0, TEMPERATURE_FLOOR), so the floor below only
-    guards the greedy rows' dummy divide, never alters a request."""
-    greedy = jnp.argmax(logits, axis=-1)
-    scaled = logits.astype(jnp.float32) \
-        / jnp.maximum(temp, TEMPERATURE_FLOOR)[:, None]
-    sampled = sample_next_token(scaled, key, 1.0, top_k, top_p)
-    return jnp.where(temp > 0.0, sampled, greedy)
+    guards the greedy rows' dummy divide, never alters a request.
+    The named scope prefixes the lowered ops, so a device trace names
+    sampling's share of a decode tick (as ``sync_span`` does the sync
+    legs)."""
+    with jax.named_scope("autodist_serve/sample"):
+        greedy = jnp.argmax(logits, axis=-1)
+        scaled = logits.astype(jnp.float32) \
+            / jnp.maximum(temp, TEMPERATURE_FLOOR)[:, None]
+        sampled = sample_next_token(scaled, key, 1.0, top_k, top_p)
+        return jnp.where(temp > 0.0, sampled, greedy)
 
 
 # The two compiled programs live at module scope so the jit cache is

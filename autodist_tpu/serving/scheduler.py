@@ -79,6 +79,7 @@ from autodist_tpu.serving.paged_kv import (SCRATCH_BLOCK, BlockPool,
                                            _commit_tokens_program,
                                            _paged_chunk_program,
                                            _paged_prefill_program)
+from autodist_tpu.telemetry import timeline as tl
 
 #: SLO classes, in strict admission-priority order.
 SLO_LATENCY = "latency"
@@ -328,6 +329,7 @@ class PagedDecodeEngine:
         self._next_id = 0
         self._results: Dict[int, np.ndarray] = {}
         self._timings: Dict[int, Dict[str, float]] = {}
+        self._shape_runs: Dict[tuple, int] = {}   # dispatches per shape
         self._slot_req: List[Optional[PagedRequest]] = [None] * slots
         self._prefilling: Dict[int, PagedRequest] = {}
         self._prefix_tokens: Optional[np.ndarray] = None
@@ -687,31 +689,46 @@ class PagedDecodeEngine:
         """One scheduler boundary: harvest, admit, at most one prefill
         wave, one decode chunk.  False when fully drained."""
         self._check_usable()
-        self._rebase_tick()
-        self._expire_deadlines()
-        self._harvest()
-        self._admit()
-        if self._prefilling:
-            self._dispatch_prefills()
-            # finished-at-admission requests (max_new=1 / first-token
-            # eos) free + refill immediately, before any decode chunk;
-            # requests with chunks left stay in _prefilling for later
-            # boundaries, interleaved with the decode chunks below
+        with tl.host_span(tl.ENGINE_STEP):
+            self._rebase_tick()
+            self._expire_deadlines()
+            self._harvest_and_admit()
+            if self._prefilling:
+                self._dispatch_prefills()
+                # finished-at-admission requests (max_new=1 / first-token
+                # eos) free + refill immediately, before any decode chunk;
+                # requests with chunks left stay in _prefilling for later
+                # boundaries, interleaved with the decode chunks below
+                self._harvest_and_admit()
+            if np.any(self._active & ~self._done):
+                if self._draft_spec is not None:
+                    self._run_spec_round()
+                else:
+                    self._run_chunk()
+            if self._pending_work():
+                return True
+            with tl.host_span(tl.ENGINE_HARVEST):
+                self._harvest()
+            if self._pending_work():
+                return True
+            self._tick = 0   # fully idle: free rewind (positions are
+            #                  logical per-request; nothing references tick)
+            return False
+
+    def _harvest_and_admit(self) -> None:
+        with tl.host_span(tl.ENGINE_HARVEST):
             self._harvest()
+        with tl.host_span(tl.ENGINE_ADMIT):
             self._admit()
-        if np.any(self._active & ~self._done):
-            if self._draft_spec is not None:
-                self._run_spec_round()
-            else:
-                self._run_chunk()
-        if self._pending_work():
-            return True
-        self._harvest()
-        if self._pending_work():
-            return True
-        self._tick = 0   # fully idle: free rewind (positions are
-        #                  logical per-request; nothing references tick)
-        return False
+
+    def _dispatch_span(self, name: str, **shape):
+        """The span around one program dispatch, with its compile shape
+        and how often that shape ran before (``seen``): a backend compile
+        under ``seen`` > 0 is a recompile (telemetry/timeline.py)."""
+        key = (name, *sorted(shape.items()))
+        seen = self._shape_runs.get(key, 0)
+        self._shape_runs[key] = seen + 1
+        return tl.host_span(name, seen=seen, **shape)
 
     def _pending_work(self) -> bool:
         return bool(self._prefilling
@@ -1013,14 +1030,17 @@ class PagedDecodeEngine:
             bt_rows[i] = self._bt[req.slot]
         self._rng, sub = jax.random.split(self._rng)
         try:
-            self._tokens, self._kc, self._vc, landed, _ = \
-                _paged_prefill_program(
-                    self._knobs, self._params, self._tokens, self._kc,
-                    self._vc, jnp.asarray(chunk), jnp.asarray(bt_rows),
-                    jnp.asarray(slot_ids), jnp.asarray(n_shared),
-                    jnp.asarray(c_lens), jnp.asarray(is_final),
-                    jnp.asarray(self._temp), sub)
-            landed = np.array(landed)
+            with self._dispatch_span(tl.ENGINE_PREFILL, rows=k_pad,
+                                     bucket=pb):
+                self._tokens, self._kc, self._vc, landed, _ = \
+                    _paged_prefill_program(
+                        self._knobs, self._params, self._tokens, self._kc,
+                        self._vc, jnp.asarray(chunk), jnp.asarray(bt_rows),
+                        jnp.asarray(slot_ids), jnp.asarray(n_shared),
+                        jnp.asarray(c_lens), jnp.asarray(is_final),
+                        jnp.asarray(self._temp), sub)
+            with tl.host_span(tl.ENGINE_HOST_SYNC):
+                landed = np.array(landed)
         except Exception:
             self._poisoned = True
             raise
@@ -1078,13 +1098,16 @@ class PagedDecodeEngine:
             bt_rows[i] = self._dbt[req.slot]
         self._rng, sub = jax.random.split(self._rng)
         try:
-            self._tokens, self._dkc, self._dvc, _, _ = \
-                _paged_prefill_program(
-                    self._knobs, self._draft_params, self._tokens,
-                    self._dkc, self._dvc, jnp.asarray(chunk),
-                    jnp.asarray(bt_rows), jnp.asarray(slot_ids),
-                    jnp.asarray(n_shared), jnp.asarray(c_lens),
-                    jnp.asarray(is_final), jnp.asarray(self._temp), sub)
+            with self._dispatch_span(tl.ENGINE_PREFILL, rows=k_pad,
+                                     bucket=pb, draft=1):
+                self._tokens, self._dkc, self._dvc, _, _ = \
+                    _paged_prefill_program(
+                        self._knobs, self._draft_params, self._tokens,
+                        self._dkc, self._dvc, jnp.asarray(chunk),
+                        jnp.asarray(bt_rows), jnp.asarray(slot_ids),
+                        jnp.asarray(n_shared), jnp.asarray(c_lens),
+                        jnp.asarray(is_final), jnp.asarray(self._temp),
+                        sub)
         except Exception:
             self._poisoned = True
             raise
@@ -1105,22 +1128,25 @@ class PagedDecodeEngine:
                     n = 1 << (nxt.bit_length() - 1)
         self._rng, sub = jax.random.split(self._rng)
         try:
-            self._tokens, self._kc, self._vc, done, busy = \
-                _paged_chunk_program(
-                    n, self._knobs, self._params, self._tokens,
-                    self._kc, self._vc, jnp.asarray(self._bt),
-                    jnp.asarray(self._start), jnp.asarray(self._p_end),
-                    jnp.asarray(self._end), jnp.asarray(self._done),
-                    jnp.asarray(self._active),
-                    jnp.asarray(self._temp), jnp.asarray(self._eos),
-                    jnp.int32(self._tick), sub)
-            self._done = np.array(done)
+            with self._dispatch_span(tl.ENGINE_DECODE_CHUNK, n=n):
+                self._tokens, self._kc, self._vc, done, busy = \
+                    _paged_chunk_program(
+                        n, self._knobs, self._params, self._tokens,
+                        self._kc, self._vc, jnp.asarray(self._bt),
+                        jnp.asarray(self._start), jnp.asarray(self._p_end),
+                        jnp.asarray(self._end), jnp.asarray(self._done),
+                        jnp.asarray(self._active),
+                        jnp.asarray(self._temp), jnp.asarray(self._eos),
+                        jnp.int32(self._tick), sub)
+            with tl.host_span(tl.ENGINE_HOST_SYNC):    # the chunk's ONE sync
+                self._done = np.array(done)
+                busy = int(busy)
         except Exception:
             self._poisoned = True
             raise
         self._tick += n
         self.stats.ticks += n
-        self.stats.busy_slot_ticks += int(busy)
+        self.stats.busy_slot_ticks += busy
         self.stats.chunks += 1
 
     def _retune_gamma(self) -> None:
@@ -1224,14 +1250,15 @@ class PagedDecodeEngine:
         self._rng, sub = jax.random.split(self._rng)
         t0 = time.monotonic()
         try:
-            self._tokens, self._dkc, self._dvc, _, _ = \
-                _paged_chunk_program(
-                    n, self._knobs, self._draft_params, self._tokens,
-                    self._dkc, self._dvc, jnp.asarray(self._dbt),
-                    jnp.asarray(start), jnp.asarray(p_end),
-                    jnp.asarray(end), jnp.asarray(done0),
-                    jnp.asarray(active), jnp.asarray(dtemp),
-                    jnp.asarray(deos), jnp.int32(0), sub)
+            with self._dispatch_span(tl.ENGINE_DECODE_CHUNK, n=n, draft=1):
+                self._tokens, self._dkc, self._dvc, _, _ = \
+                    _paged_chunk_program(
+                        n, self._knobs, self._draft_params, self._tokens,
+                        self._dkc, self._dvc, jnp.asarray(self._dbt),
+                        jnp.asarray(start), jnp.asarray(p_end),
+                        jnp.asarray(end), jnp.asarray(done0),
+                        jnp.asarray(active), jnp.asarray(dtemp),
+                        jnp.asarray(deos), jnp.int32(0), sub)
         except Exception:
             self._poisoned = True
             raise
@@ -1259,17 +1286,20 @@ class PagedDecodeEngine:
         try:
             # Device-side gather: the committed token + proposals are
             # already rows of the tokens buffer the draft scan wrote.
-            chunk = self._tokens[jnp.asarray(slot_ids)[:, None],
-                                 jnp.asarray(cols)]
-            self._tokens, self._kc, self._vc, _, preds = \
-                _paged_prefill_program(
-                    self._knobs, self._params, self._tokens, self._kc,
-                    self._vc, chunk, jnp.asarray(bt_rows),
-                    jnp.asarray(slot_ids), jnp.asarray(n_shared),
-                    jnp.asarray(c_lens), jnp.asarray(is_final),
-                    jnp.asarray(self._temp), sub)
-            preds = np.asarray(preds)    # the round's ONE host sync
-            toks = np.asarray(self._tokens)
+            with self._dispatch_span(tl.ENGINE_PREFILL, rows=k_pad,
+                                     bucket=pb, verify=1):
+                chunk = self._tokens[jnp.asarray(slot_ids)[:, None],
+                                     jnp.asarray(cols)]
+                self._tokens, self._kc, self._vc, _, preds = \
+                    _paged_prefill_program(
+                        self._knobs, self._params, self._tokens, self._kc,
+                        self._vc, chunk, jnp.asarray(bt_rows),
+                        jnp.asarray(slot_ids), jnp.asarray(n_shared),
+                        jnp.asarray(c_lens), jnp.asarray(is_final),
+                        jnp.asarray(self._temp), sub)
+            with tl.host_span(tl.ENGINE_HOST_SYNC):
+                preds = np.asarray(preds)    # the round's ONE host sync
+                toks = np.asarray(self._tokens)
         except Exception:
             self._poisoned = True
             raise
@@ -1357,45 +1387,36 @@ class PagedDecodeEngine:
         prefill, decode) into the telemetry span stream at harvest —
         the request is terminal here, so every boundary timestamp is
         known and the emission rides a path that already paid a host
-        sync.  Monotonic times anchor to wall clock at 'now'; never
-        raises (record_span's contract)."""
+        sync.  The after-the-fact form of the span ring
+        (``record_span``); never raises (its contract)."""
         from autodist_tpu.telemetry.profiler import record_span
 
-        now_mono = req.done_t or time.monotonic()
-        now_wall = time.time()
-
-        def wall(mono: float) -> float:
-            return now_wall - (now_mono - mono)
-
-        admit = req.admit_t or now_mono
-        record_span("queue_wait", start_unix=wall(req.submit_t),
-                    dur_s=max(admit - req.submit_t, 0.0),
-                    trace_id=req.trace_id,
-                    request_id=req.request_id, slo=req.slo)
-        first = req.first_token_t or admit
-        record_span("prefill", start_unix=wall(admit),
-                    dur_s=max(first - admit, 0.0),
-                    trace_id=req.trace_id, request_id=req.request_id,
+        # the request's stamps are time.monotonic(); the ring's clock is
+        # perf_counter (the same clock on Linux, a constant apart elsewhere)
+        shift = time.perf_counter() - time.monotonic()
+        done = (req.done_t or time.monotonic()) + shift
+        submit = req.submit_t + shift
+        admit = req.admit_t + shift if req.admit_t else done
+        first = req.first_token_t + shift if req.first_token_t else admit
+        common = dict(trace_id=req.trace_id, request_id=req.request_id)
+        record_span("queue_wait", start=submit, end=max(admit, submit),
+                    slo=req.slo, **common)
+        record_span("prefill", start=admit, end=max(first, admit),
                     prompt_tokens=int(req.prompt.size),
-                    cached_tokens=int(req.n_cached))
-        record_span("decode", start_unix=wall(first),
-                    dur_s=max(now_mono - first, 0.0),
-                    trace_id=req.trace_id, request_id=req.request_id,
-                    generated=int(gen))
+                    cached_tokens=int(req.n_cached), **common)
+        record_span("decode", start=first, end=max(done, first),
+                    generated=int(gen), **common)
         if req.spec_rounds:
             # Cumulative draft/verify windows inside the decode span,
             # so the trace export shows where speculative rounds spent
             # their time (draft proposing vs target verifying).
-            record_span("spec_draft", start_unix=wall(first),
-                        dur_s=req.draft_s, trace_id=req.trace_id,
-                        request_id=req.request_id,
+            record_span("spec_draft", start=first, end=first + req.draft_s,
                         rounds=int(req.spec_rounds),
                         proposed=int(req.spec_proposed),
-                        accepted=int(req.spec_accepted))
-            record_span("spec_verify", start_unix=wall(first),
-                        dur_s=req.verify_s, trace_id=req.trace_id,
-                        request_id=req.request_id,
-                        bonus=int(req.spec_bonus))
+                        accepted=int(req.spec_accepted), **common)
+            record_span("spec_verify", start=first,
+                        end=first + req.verify_s,
+                        bonus=int(req.spec_bonus), **common)
 
     def _free_slot(self, b: int, req: PagedRequest) -> None:
         """Return the request's blocks to the pool (shared prefix

@@ -56,6 +56,7 @@ import numpy as np
 from autodist_tpu.resilience.chaos import ServingChaos
 from autodist_tpu.serving.engine import (AdmissionError, DeadlineError,
                                          DecodeEngine)
+from autodist_tpu.telemetry import timeline as tl
 from autodist_tpu.telemetry.registry import (
     DEPTH_BUCKETS,
     MetricsRegistry,
@@ -307,7 +308,9 @@ class EngineServer:
                                           "generated_tokens", 0)))
                 if self._chaos.slow_s > 0:
                     time.sleep(self._chaos.slow_s)
-            with self._lock:
+            with tl.host_span(tl.SERVER_LOCK_WAIT):
+                self._lock.acquire()
+            try:
                 if self._stop:
                     return
                 if not self._outstanding:
@@ -339,7 +342,8 @@ class EngineServer:
                             ev = self._events.pop(rid, None)
                             if ev is not None:
                                 ev.set()
-                    self._observe_paged()
+                    with tl.host_span(tl.SERVER_OBSERVE):
+                        self._observe_paged()
                 if self._engine_error is not None:
                     # In-flight work is lost (donated buffers); fail the
                     # waiters loudly rather than hang them to timeout.
@@ -348,6 +352,8 @@ class EngineServer:
                         ev.set()
                     self._events.clear()
                     return
+            finally:
+                self._lock.release()
             if self._handler_waiters:
                 time.sleep(0.001)   # hand the lock to a waiting handler
 
@@ -898,7 +904,8 @@ class _Handler(BaseHTTPRequestHandler):
             emit({"id": rid, "done": False, "new_tokens": []})
             while True:
                 try:
-                    snap, done = srv._snapshot(rid)
+                    with tl.host_span(tl.SERVER_SSE_POLL, request_id=rid):
+                        snap, done = srv._snapshot(rid)
                 except _Unavailable:
                     count(served=False)
                     emit({"id": rid, "error": "engine unavailable"})

@@ -32,18 +32,23 @@ calibration):
     quant_ring.py) are read out of the Chrome-trace JSON and mapped to
     leg kinds — measured device time with zero extra instrumentation.
 
-* request spans (:func:`record_span` / :func:`load_spans`) — the
-  serving trace plane: router/server/scheduler record durational spans
-  (queue-wait, prefill chunk, decode, whole request) tagged with a
-  propagated trace id into ``spans-<host>-<pid>.jsonl``; the trace
-  exporter merges them into the same Chrome-trace file as training
-  steps and leg samples (docs/observability.md).
+* spans (:func:`record_span` / :func:`load_spans`) — the process span
+  ring and its ``spans-<host>-<pid>.jsonl`` stream.  Two forms, one
+  record: :func:`~autodist_tpu.telemetry.timeline.host_span` around a
+  live phase (session step, set-up, engine tick; also a
+  ``TraceAnnotation`` on the profiler's clock), and
+  :func:`record_span` after the fact for request lifecycles
+  (router/server/scheduler: queue-wait, prefill, decode, whole request,
+  tagged with a propagated trace id).  The trace exporter merges them
+  into the same Chrome-trace file as training steps and leg samples
+  (docs/observability.md).
 
-Cost discipline: nothing here rides the training step.  Micro-runs are
-explicit calls outside the step loop, trace parsing is offline, and
-span recording happens on serving completion paths that already pay a
-host sync — the <1 % profiler-overhead budget BENCH_profiler.json
-verifies.  Everything except :meth:`profile_ir` imports without jax.
+Cost discipline: micro-runs are explicit calls outside the step loop
+and trace parsing is offline (the <1 % profiler-overhead budget
+BENCH_profiler.json verifies).  The span ring is the one thing here a
+training step touches: ``host_span`` appends five records a step
+(PERF.md has the measured cost on the chip).  Everything except
+:meth:`profile_ir` imports without jax.
 """
 from __future__ import annotations
 
@@ -438,10 +443,20 @@ def _block(x):
 # -- request spans (the serving trace plane) ---------------------------------
 
 class _SpanWriter:
-    """One durational-span JSONL writer per process
+    """The process span ring and its JSONL writer
     (``spans-<host>-<pid>.jsonl``), modeled on the event journal:
     append-only, flushed per line, never raises, bounded in-memory ring
-    without a run directory."""
+    without a run directory.
+
+    ONE record shape for both span forms (docs/observability.md):
+    ``name``, ``start``/``end`` on ``time.perf_counter()``'s clock (what
+    the idle-gap readers and the step phases share), ``start_unix``/
+    ``dur_s`` (what the cross-host trace export orders by), ``parent``
+    (the enclosing live span of the same thread, None for an
+    after-the-fact record), ``ids`` (``step=`` / ``request_id=`` and
+    whatever else the site knows), ``trace_id``, ``host``, ``pid``.
+    :func:`autodist_tpu.telemetry.timeline.host_span` is the live form;
+    :func:`record_span` the after-the-fact one."""
 
     def __init__(self, directory: Optional[str] = None):
         self._dir = directory
@@ -451,6 +466,9 @@ class _SpanWriter:
         self._memory: deque = deque(maxlen=MEMORY_SPANS)
         self._fh = None
         self._path: Optional[str] = None
+        # perf_counter has no epoch; one reading of both clocks turns a
+        # stamp of either into the other for the life of the process.
+        self._unix_minus_perf = time.time() - time.perf_counter()
         if directory:
             safe = self._host.replace("/", "_").replace(":", "_")
             self._path = os.path.join(
@@ -461,14 +479,22 @@ class _SpanWriter:
         with self._lock:
             return list(self._memory)
 
-    def record(self, name: str, *, start_unix: float, dur_s: float,
-               trace_id: str = "", **args: Any) -> Optional[dict]:
+    def record(self, name: str, *, start: Optional[float] = None,
+               end: Optional[float] = None,
+               start_unix: Optional[float] = None,
+               dur_s: Optional[float] = None, parent: Optional[str] = None,
+               trace_id: str = "", **ids: Any) -> Optional[dict]:
+        """Append one span, given either ``start``/``end``
+        (``perf_counter``) or ``start_unix``/``dur_s`` (wall clock)."""
+        if start is None:
+            start = float(start_unix) - self._unix_minus_perf
+            end = start + float(dur_s)
         rec: Dict[str, Any] = {
-            "name": str(name), "trace_id": str(trace_id),
-            "start_unix": float(start_unix), "dur_s": float(dur_s),
+            "name": str(name), "start": float(start), "end": float(end),
+            "parent": parent, "ids": ids, "trace_id": str(trace_id),
+            "start_unix": float(start) + self._unix_minus_perf,
+            "dur_s": float(end) - float(start),
             "host": self._host, "pid": self._pid}
-        if args:
-            rec["args"] = args
         try:
             with self._lock:
                 self._memory.append(rec)
@@ -522,19 +548,22 @@ def configure_spans(directory: Optional[str]) -> _SpanWriter:
         return _spans
 
 
-def record_span(name: str, *, start_unix: float, dur_s: float,
-                trace_id: str = "", **args: Any) -> Optional[dict]:
-    """Record one durational span on the process writer.  No-op when
-    telemetry is disabled; never raises (a full disk must not fail a
-    request)."""
+def record_span(name: str, **span: Any) -> Optional[dict]:
+    """Record one span after the fact on the process ring
+    (:meth:`_SpanWriter.record`'s arguments): the form for intervals
+    whose ends are only known once they are over (a request's queue
+    wait, read at harvest).  ``start``/``end`` are ``perf_counter``
+    stamps; a caller that holds only a wall-clock start gives
+    ``start_unix``/``dur_s``.  Live phases use
+    :func:`~autodist_tpu.telemetry.timeline.host_span`, which writes the
+    same record.  No-op when telemetry is disabled; never raises (a full
+    disk must not fail a request)."""
     from autodist_tpu.telemetry.registry import telemetry_enabled
 
     try:
         if not telemetry_enabled():
             return None
-        return get_span_writer().record(
-            name, start_unix=start_unix, dur_s=dur_s, trace_id=trace_id,
-            **args)
+        return get_span_writer().record(name, **span)
     except Exception:  # pragma: no cover - defensive
         return None
 

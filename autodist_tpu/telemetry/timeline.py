@@ -12,10 +12,16 @@ Three instruments, one per time scale (docs/observability.md):
   regresses against.  Records ride a bounded ring buffer and flush
   periodically as JSONL (rotated) into the run directory, so bench runs
   and real runs feed the same files.
-* :func:`host_span` — ``jax.profiler.TraceAnnotation`` for HOST-side
-  phases (data load, step dispatch, blocking fetch): these show as
-  named host events in a profiler capture window next to the device
-  timeline.
+* :func:`host_span` — the LIVE span around a host-side phase (session
+  step and its parts, set-up, the serving engine's tick): one
+  ``jax.profiler.TraceAnnotation`` named ``autodist/<name>`` (so a
+  capture window shows it on ``/host:CPU``, on the clock of the device
+  planes) and one record in the process span ring
+  (:func:`autodist_tpu.telemetry.profiler.get_span_writer`), from the
+  same two ``perf_counter`` reads.  The span names are the constants
+  below: the benchmark's readers match them.  A ``jax.monitoring``
+  listener records what jax traces, lowers and compiles as
+  ``compile/*`` spans in the same ring.
 * :func:`sync_span` — ``jax.named_scope`` for code inside traced
   programs (the bucket sync legs in ``explicit_sync.py``/
   ``overlap.py``): named scopes prefix the lowered HLO ops, so a
@@ -36,12 +42,15 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import threading
 import time
 from collections import deque
 from dataclasses import asdict, dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
-from autodist_tpu.telemetry import flightrec
+from autodist_tpu.telemetry import flightrec, registry
+from autodist_tpu.telemetry.events import emit_event
+from autodist_tpu.telemetry.profiler import get_span_writer
 from autodist_tpu.telemetry.registry import telemetry_enabled
 
 #: JSONL rotation threshold: records per ``steps-*.jsonl`` segment.
@@ -312,13 +321,208 @@ _UNSET = _Unset()
 
 # -- profiler spans ----------------------------------------------------------
 
-def host_span(name: str):
-    """Named host-side span (``jax.profiler.TraceAnnotation``) for
-    phases OUTSIDE traced code — shows as a named event when a capture
-    window (AUTODIST_TRACE_STEPS / AUTODIST_TRACE_AT) is open."""
-    import jax
+#: every host span the program opens, in one place: ``host_span`` puts
+#: ``SPAN_PREFIX`` before the name in the profiler's trace and the bare
+#: name into the ring; ``benchmark/program_spans.py`` and the readers
+#: under ``benchmark/metrics/`` match these strings.
+SPAN_PREFIX = "autodist/"
+SESSION_RUN = "session/run"                  # ids: step
+SESSION_PLACE_BATCH = "session/place_batch"  # host batch -> device
+SESSION_ENQUEUE = "session/enqueue"          # step_fn(...): step 0 compiles
+SESSION_RECORD = "session/record"            # meter, StepRecord
+SESSION_FETCH = "session/fetch"              # np.asarray(metrics), sync only
+SETUP_BUILD_STRATEGY = "setup/build_strategy"
+SETUP_COMPILE_STRATEGY = "setup/compile_strategy"
+SETUP_PREFLIGHT = "setup/preflight"
+SETUP_TRANSFORM = "setup/transform"
+SETUP_PLACE_PARAMS = "setup/place_params"
+SETUP_INIT_OPT_STATE = "setup/init_opt_state"
+SETUP_INIT_SYNC_STATE = "setup/init_sync_state"
+SETUP_ESTIMATE_COST = "setup/estimate_cost"
+ENGINE_STEP = "engine/step"
+ENGINE_HARVEST = "engine/harvest"
+ENGINE_ADMIT = "engine/admit"
+ENGINE_PREFILL = "engine/prefill"            # ids: rows, bucket, seen
+ENGINE_DECODE_CHUNK = "engine/decode_chunk"  # ids: n, seen
+ENGINE_HOST_SYNC = "engine/host_sync"        # the chunk's device->host wait
+SERVER_LOCK_WAIT = "server/lock_wait"        # the driver's wait for the lock
+SERVER_OBSERVE = "server/observe"
+SERVER_SSE_POLL = "server/sse_poll"          # ids: request_id
+ANALYSIS_CLI = "analysis/cli"
+COMPILE_TRACE = "compile/trace"              # ids: fun_name
+COMPILE_LOWER = "compile/lower"
+COMPILE_BACKEND = "compile/backend"
 
-    return jax.profiler.TraceAnnotation(name)
+#: jax's monitoring events -> the span each becomes (and its ``stage``
+#: label on ``autodist_compile_seconds_total``).
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": COMPILE_TRACE,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": COMPILE_LOWER,
+    "/jax/core/compile/backend_compile_duration": COMPILE_BACKEND,
+}
+
+#: jax reports a trace for every call of a jitted function from a traced
+#: one, microseconds each when its own cache answers, thousands of them
+#: under one step function: those are counted, and only an event of a
+#: millisecond or more becomes a span (they would flush the ring)
+_COMPILE_SPAN_MIN_S = 1e-3
+
+#: how many ended traces a thread remembers while it looks for the trace
+#: that enclosed them (a step function calls some hundreds of jitted
+#: functions; each is traced once)
+_TRACES_REMEMBERED = 1024
+
+_NULL_SPAN = contextlib.nullcontext()
+_live = threading.local()        # .stack: this thread's open host spans
+_listener_lock = threading.Lock()
+_listening = False
+
+
+def _stack() -> list:
+    try:
+        return _live.stack
+    except AttributeError:
+        _live.stack = []
+        return _live.stack
+
+
+class _HostSpan:
+    """One live span: see :func:`host_span`.  ``start``/``end`` stay
+    readable after the ``with`` (the session fills its step phases from
+    them)."""
+
+    __slots__ = ("name", "ids", "start", "end", "_parent", "_annotation")
+
+    def __init__(self, name: str, ids: dict):
+        self.name = name
+        self.ids = ids
+        self.start = self.end = None
+
+    def __enter__(self):
+        import jax
+
+        stack = _stack()
+        self._parent = stack[-1].name if stack else None
+        stack.append(self)
+        self._annotation = jax.profiler.TraceAnnotation(
+            SPAN_PREFIX + self.name, **self.ids)
+        self._annotation.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.end = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        _stack().pop()
+        get_span_writer().record(self.name, start=self.start, end=self.end,
+                                 parent=self._parent, **self.ids)
+        return False
+
+
+def host_span(name: str, **ids):
+    """Live span around a host-side phase OUTSIDE traced code.
+
+    On entry it reads ``time.perf_counter()`` and opens
+    ``jax.profiler.TraceAnnotation("autodist/" + name, **ids)``: while a
+    capture window (AUTODIST_TRACE_STEPS / AUTODIST_TRACE_AT, or any
+    ``jax.profiler`` trace) is open the span is an event on
+    ``/host:CPU``, on the device planes' clock; an inactive annotation
+    is a flag test.  On exit it appends ``{name, start, end, parent,
+    ids}`` to the process span ring (``profiler.get_span_writer()``;
+    JSONL only under ``AUTODIST_TELEMETRY_DIR``).  ``parent`` is the
+    enclosing span of the same thread; ``ids`` (``step=``,
+    ``request_id=``) are what the spans of one step or request share.
+    With ``AUTODIST_TELEMETRY=0`` it is the null context (``as`` gives
+    None)."""
+    if not telemetry_enabled():
+        return _NULL_SPAN
+    if not _listening:
+        _listen_for_compiles()
+    return _HostSpan(name, ids)
+
+
+def _listen_for_compiles() -> None:
+    """Register, once a process, the ``jax.monitoring`` listener behind
+    the ``compile/*`` spans (jax has no unregister: the listener itself
+    honours the telemetry switch)."""
+    global _listening
+    import jax.monitoring
+
+    with _listener_lock:
+        if not _listening:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_compile_event)
+            _listening = True
+
+
+def _recompile_site(stack: list):
+    """The live span under which a backend compile is a REPEAT: a
+    ``session/run`` past step 0, or an engine dispatch whose shape
+    already ran (``seen`` > 0).  None for a first compile."""
+    for span in reversed(stack):
+        if span.name == SESSION_RUN:
+            return span if span.ids.get("step", 0) > 0 else None
+        if span.name in (ENGINE_PREFILL, ENGINE_DECODE_CHUNK):
+            return span if span.ids.get("seen", 0) > 0 else None
+    return None
+
+
+def _own_trace_seconds(start: float, seconds: float) -> float:
+    """jax times the tracing of a jitted function called from a traced
+    one inside its caller's time too.  The ended traces of this thread
+    that began after ``start`` ended inside this one: take them off."""
+    ended = getattr(_live, "traces", None)
+    if ended is None:
+        ended = _live.traces = []
+    own = seconds
+    while ended and ended[-1][0] >= start:
+        own -= ended.pop()[1]
+    ended.append((start, seconds))
+    del ended[:-_TRACES_REMEMBERED]
+    return max(own, 0.0)
+
+
+def _on_compile_event(event: str, seconds: float, **kwargs) -> None:
+    """What a ``backend_compile_duration`` counter cannot say: WHEN jax
+    traced, lowered and compiled WHAT.  Each event (of a millisecond or
+    more) becomes a span ending now and lasting its seconds, child of
+    the thread's innermost live span, and every event adds to
+    ``autodist_compile_seconds_total{stage}`` (a trace's OWN seconds;
+    the spans keep their full extent, so a reader takes their union).  A repeat compile (:func:`_recompile_site`)
+    counts in ``autodist_recompiles_total`` and is journalled with the
+    step and the function's name.  Never raises into jax's compile
+    path."""
+    name = _COMPILE_STAGES.get(event)
+    if name is None or not telemetry_enabled():
+        return
+    try:
+        end = time.perf_counter()
+        start = end - seconds
+        stack = _stack()
+        fun_name = str(kwargs.get("fun_name", ""))
+        if seconds >= _COMPILE_SPAN_MIN_S:
+            get_span_writer().record(
+                name, start=start, end=end,
+                parent=stack[-1].name if stack else None,
+                fun_name=fun_name)
+        registry.counter(
+            "autodist_compile_seconds_total",
+            "seconds jax spent tracing, lowering and compiling (or "
+            "fetching from the persistent cache), by stage",
+            labels={"stage": name.split("/", 1)[1]}).inc(
+                _own_trace_seconds(start, seconds)
+                if name == COMPILE_TRACE else seconds)
+        site = _recompile_site(stack) if name == COMPILE_BACKEND else None
+        if site is not None:
+            registry.counter(
+                "autodist_recompiles_total",
+                "backend compiles inside a training step after the "
+                "first, or inside an engine dispatch whose shape had "
+                "already run").inc()
+            emit_event("compile/recompile", span=site.name,
+                       fun_name=fun_name, seconds=seconds, **site.ids)
+    except Exception:  # pragma: no cover - telemetry must not fail a compile
+        pass
 
 
 def sync_span(name: str):

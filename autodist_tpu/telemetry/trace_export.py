@@ -189,7 +189,7 @@ def chrome_trace_events(records: Sequence[Any] = (),
         tid = serving_tids.setdefault(
             family, TID_SERVING_BASE + len(serving_tids))
         _thread_meta(out, pid, tid, f"serving/{family}", threads)
-        args = dict(s.get("args") or {})
+        args = dict(s.get("ids") or {})
         if s.get("trace_id"):
             args["trace_id"] = s["trace_id"]
         out.append({"name": name, "cat": "serving", "ph": "X",

@@ -552,13 +552,17 @@ def test_scheduler_emits_request_spans(lm, tmp_path):
     assert rid in results
     timings = eng.pop_timings()
     assert timings[rid]["trace_id"] == "trace-xyz"
-    spans = prof.load_spans(str(tmp_path))
+    # the engine's own tick spans (engine/...) share the stream; the
+    # request's lifecycle is the records that carry its id
+    spans = [s for s in prof.load_spans(str(tmp_path))
+             if s["ids"].get("request_id") == rid]
     by_name = {s["name"]: s for s in spans}
     assert {"queue_wait", "prefill", "decode"} <= set(by_name)
     for s in spans:
         assert s["trace_id"] == "trace-xyz"
         assert s["dur_s"] >= 0 and s["start_unix"] > 0
-    assert by_name["decode"]["args"]["generated"] == 5
+        assert s["end"] - s["start"] == pytest.approx(s["dur_s"])
+    assert by_name["decode"]["ids"]["generated"] == 5
     # spans order: queue_wait starts <= prefill starts <= decode starts
     assert by_name["queue_wait"]["start_unix"] <= \
         by_name["prefill"]["start_unix"] <= \

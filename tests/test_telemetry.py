@@ -584,3 +584,366 @@ def test_cli_subprocess_smoke(tmp_path):
         cwd="/root/repo", env=env, capture_output=True, timeout=120)
     assert proc.returncode == 0, proc.stderr.decode()
     assert b"telemetry summary" in proc.stdout
+
+
+# -- host spans: one mechanism, two clocks, one ring ------------------------
+
+from autodist_tpu.telemetry import profiler as prof  # noqa: E402
+
+
+@pytest.fixture
+def span_ring():
+    """An empty process span ring, and the registry's compile counters
+    read from zero."""
+    prof.reset_spans_for_testing()
+    yield prof.get_span_writer()
+    prof.reset_spans_for_testing()
+
+
+def _fresh_session(rows=32, builder=None):
+    """A new AutoDist and session (step counter 0) on the 8-device mesh,
+    and a batch of ``rows`` rows for it."""
+    from autodist_tpu.autodist import AutoDist, \
+        _reset_default_autodist_for_testing
+
+    _reset_default_autodist_for_testing()
+    rng = np.random.RandomState(0)
+    params = {"w": jnp.asarray(rng.randn(16, 16) * 0.1, jnp.float32)}
+    batch = {"x": rng.randn(rows, 16).astype(np.float32),
+             "ids": np.zeros((rows, 4), np.int32)}
+
+    def loss_fn(p, b):
+        return jnp.mean((b["x"] @ p["w"]) ** 2)
+
+    ad = AutoDist(strategy_builder=builder)
+    with ad.scope():
+        ad.capture(params=params, optimizer=optax.sgd(0.1),
+                   loss_fn=loss_fn)
+    return ad, batch
+
+
+def _inside(child, parent):
+    return parent["start"] <= child["start"] and child["end"] <= parent["end"]
+
+
+def test_session_run_spans_share_a_step(span_ring):
+    """Three steps: three ``session/run`` spans, each holding its four
+    children in order, all five carrying the same ``step``."""
+    ad, batch = _fresh_session()
+    sess = ad.create_distributed_session()
+    for _ in range(3):
+        sess.run(batch)
+    spans = span_ring.spans
+    runs = [s for s in spans if s["name"] == tl.SESSION_RUN]
+    assert [s["ids"]["step"] for s in runs] == [0, 1, 2]
+    order = [tl.SESSION_PLACE_BATCH, tl.SESSION_ENQUEUE,
+             tl.SESSION_RECORD, tl.SESSION_FETCH]
+    for run in runs:
+        kids = [s for s in spans if s["parent"] == tl.SESSION_RUN
+                and s["ids"].get("step") == run["ids"]["step"]]
+        assert [k["name"] for k in kids] == order
+        assert all(_inside(k, run) for k in kids)
+        assert all(a["end"] <= b["start"] for a, b in zip(kids, kids[1:]))
+        assert run["parent"] is None
+    # an async step waits for nothing: no fetch span
+    sess.run(batch, sync=False)
+    last = [s["name"] for s in span_ring.spans if s["ids"].get("step") == 3]
+    assert tl.SESSION_FETCH not in last and tl.SESSION_RUN in last
+
+
+def test_step_phases_come_from_the_span_clock(span_ring):
+    """``dispatch`` keeps its extent (entry to after the meter), so its
+    two named parts fit inside it; ``fetch`` is the wait after it."""
+    ad, batch = _fresh_session()
+    sess = ad.create_distributed_session()
+    for _ in range(3):
+        sess.run(batch)
+    rec = sess.telemetry.records[-1]
+    assert {"dispatch", "place_batch", "enqueue", "fetch"} <= set(rec.phases)
+    assert rec.phases["place_batch"] + rec.phases["enqueue"] \
+        <= rec.phases["dispatch"]
+    spans = {s["name"]: s for s in span_ring.spans
+             if s["ids"].get("step") == rec.step}
+    for phase, name in (("place_batch", tl.SESSION_PLACE_BATCH),
+                        ("enqueue", tl.SESSION_ENQUEUE),
+                        ("fetch", tl.SESSION_FETCH)):
+        assert rec.phases[phase] == pytest.approx(
+            spans[name]["end"] - spans[name]["start"])
+    run = spans[tl.SESSION_RUN]
+    assert rec.phases["dispatch"] <= spans[tl.SESSION_RECORD]["end"] \
+        - run["start"]
+    assert rec.phases["dispatch"] + rec.phases["fetch"] \
+        <= run["end"] - run["start"]
+
+
+def test_telemetry_off_leaves_the_ring_empty(span_ring, monkeypatch):
+    monkeypatch.setenv("AUTODIST_TELEMETRY", "0")
+    assert tl.host_span(tl.SESSION_RUN, step=0) is tl._NULL_SPAN
+    with tl.host_span("anything") as span:
+        assert span is None
+    ad, batch = _fresh_session()
+    sess = ad.create_distributed_session()
+    out = sess.run(batch)
+    assert np.isfinite(out["loss"]) and sess.telemetry is None
+    assert prof.record_span("request", start=0.0, end=1.0) is None
+    assert span_ring.spans == []
+
+
+def test_setup_spans_lie_inside_session_creation(span_ring):
+    """Every ``setup/*`` span falls between entry to and return from
+    ``create_distributed_session``, none overlaps another, and the cost
+    model is priced once: the same report is logged and stamped."""
+    from autodist_tpu.strategy import Zero1
+    from autodist_tpu.strategy import cost_model
+
+    ad, batch = _fresh_session(builder=Zero1(bucket_bytes=256 << 10))
+    calls = []
+    real = cost_model.estimate_cost
+    cost_model.estimate_cost = lambda *a, **k: calls.append(1) or real(*a, **k)
+    try:
+        t0 = time.perf_counter()
+        sess = ad.create_distributed_session(validate=True)
+        t1 = time.perf_counter()
+        sess.run(batch)
+    finally:
+        cost_model.estimate_cost = real
+    setup = sorted((s for s in span_ring.spans
+                    if s["name"].startswith("setup/")),
+                   key=lambda s: s["start"])
+    assert [s["name"] for s in setup] == [
+        tl.SETUP_BUILD_STRATEGY, tl.SETUP_COMPILE_STRATEGY,
+        tl.SETUP_PREFLIGHT, tl.SETUP_TRANSFORM, tl.SETUP_PLACE_PARAMS,
+        tl.SETUP_INIT_OPT_STATE, tl.SETUP_INIT_SYNC_STATE,
+        tl.SETUP_ESTIMATE_COST]
+    assert t0 <= setup[0]["start"] and setup[-1]["end"] <= t1
+    assert all(a["end"] <= b["start"] for a, b in zip(setup, setup[1:]))
+    assert len(calls) == 1
+    assert sess.telemetry.records[-1].predicted_step_time_s \
+        == sess.cost_report.time_s
+
+
+def test_shape_change_at_step_2_is_a_journalled_recompile(span_ring):
+    """Compiling at step 0 is set-up; a new batch shape at step 2 makes
+    jax compile again inside ``session/run``: one more on
+    ``autodist_recompiles_total``, and the journal says which step."""
+    reg.reset_for_testing()
+    ad, batch = _fresh_session()
+    sess = ad.create_distributed_session()
+    sess.run(batch)
+    sess.run(batch)
+
+    def recompiles():
+        return sum(m.value for m in reg.DEFAULT_REGISTRY.metrics()
+                   if m.name == "autodist_recompiles_total")
+
+    assert recompiles() == 0
+    assert sess._batch_sizes == (32, 32 * 4)
+    sess.run({k: v[:16] for k, v in batch.items()})
+    assert recompiles() == 1
+    assert sess._batch_sizes == (16, 16 * 4)
+    events = [e for e in ev.get_journal().events
+              if e["kind"] == "compile/recompile"]
+    assert len(events) == 1 and events[0]["step"] == 2
+    assert events[0]["span"] == tl.SESSION_RUN
+    assert "step" in events[0]["fun_name"]
+    backend = [s for s in span_ring.spans if s["name"] == tl.COMPILE_BACKEND
+               and s["parent"] == tl.SESSION_ENQUEUE]
+    assert len(backend) == 2          # step 0's and step 2's
+    stages = {m.labels["stage"]: m.value
+              for m in reg.DEFAULT_REGISTRY.metrics()
+              if m.name == "autodist_compile_seconds_total"}
+    assert set(stages) == {"trace", "lower", "backend"}
+    assert 'autodist_compile_seconds_total{stage="trace"}' \
+        in reg.render_prometheus()
+
+
+def test_nested_traces_count_their_own_seconds(span_ring, monkeypatch):
+    """jax times a jitted function traced from inside another within
+    the outer one's seconds too: the counter takes each trace's own
+    part, the spans keep their extent, events under a millisecond stay
+    out of the ring."""
+    reg.reset_for_testing()
+    base = 1e9      # later than any trace this thread really remembers
+    clock = [base]
+    monkeypatch.setattr(tl.time, "perf_counter", lambda: clock[0])
+    event = "/jax/core/compile/jaxpr_trace_duration"
+    clock[0] = base + 0.4
+    tl._on_compile_event(event, 0.1, fun_name="inner")      # [.3, .4]
+    clock[0] = base + 0.5
+    tl._on_compile_event(event, 0.0001, fun_name="tiny")    # in outer too
+    clock[0] = base + 1.0
+    tl._on_compile_event(event, 0.9, fun_name="outer")      # [.1, 1.0]
+    clock[0] = base + 2.0
+    tl._on_compile_event(event, 0.5, fun_name="next")       # a sibling
+    tl._on_compile_event("/jax/some/other/event", 9.0)
+    trace = reg.counter("autodist_compile_seconds_total",
+                        labels={"stage": "trace"})
+    assert trace.value == pytest.approx(0.9 + 0.5, abs=1e-5)
+    names = [s["ids"]["fun_name"] for s in span_ring.spans]
+    assert names == ["inner", "outer", "next"]
+    outer = span_ring.spans[1]
+    assert outer["end"] - outer["start"] == pytest.approx(0.9, abs=1e-5)
+
+
+def test_host_span_and_record_span_write_one_record(span_ring, tmp_path):
+    """The live and the after-the-fact form: the same keys, the same
+    ring, both clocks on both, the same JSONL under a run directory."""
+    ring = prof.configure_spans(str(tmp_path))
+    with tl.host_span("outer", request_id=7):
+        with tl.host_span("inner", request_id=7) as live:
+            pass
+    t = time.perf_counter()
+    prof.record_span("after", start=t - 0.25, end=t, trace_id="t1",
+                     request_id=7)
+    prof.record_span("walled", start_unix=time.time() - 1.0, dur_s=0.5)
+    inner, outer, after, walled = ring.spans
+    assert set(inner) == set(outer) == set(after) == set(walled)
+    assert inner["parent"] == "outer" and outer["parent"] is None
+    assert after["parent"] is None and after["trace_id"] == "t1"
+    assert inner["ids"] == outer["ids"] == after["ids"] == {"request_id": 7}
+    assert (inner["start"], inner["end"]) == (live.start, live.end)
+    for s in ring.spans:
+        assert s["dur_s"] == pytest.approx(s["end"] - s["start"])
+        assert abs(s["start_unix"] - time.time()) < 60
+    assert walled["end"] - walled["start"] == pytest.approx(0.5)
+    assert time.perf_counter() - walled["start"] == pytest.approx(1.0,
+                                                                  abs=0.2)
+    ring.close()
+    assert sorted(prof.load_spans(str(tmp_path)), key=lambda s: s["name"]) \
+        == sorted(ring.spans, key=lambda s: s["name"])
+
+
+def test_host_span_parent_is_per_thread(span_ring):
+    """``parent`` is the enclosing span of the SAME thread: a span opened
+    on another thread while this one holds ``outer`` has no parent."""
+    import threading
+
+    def other():
+        with tl.host_span("elsewhere"):
+            pass
+
+    with tl.host_span("outer"):
+        t = threading.Thread(target=other)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+        with tl.host_span("inner"):
+            pass
+    by = {s["name"]: s for s in span_ring.spans}
+    assert by["elsewhere"]["parent"] is None
+    assert by["inner"]["parent"] == "outer"
+
+
+def test_host_spans_land_on_the_profilers_host_plane(span_ring, tmp_path):
+    """In a capture window the spans are ``autodist/...`` events on
+    ``/host:CPU`` of the ``.xplane.pb``, their ids as stats: the clock
+    the device planes are on."""
+    from jax.profiler import ProfileData
+
+    ad, batch = _fresh_session()
+    sess = ad.create_distributed_session()
+    sess.run(batch)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        sess.run(batch)
+        sess.run(batch)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    events = [(e.name, dict(e.stats))
+              for plane in ProfileData.from_file(str(path)).planes
+              if plane.name == "/host:CPU"
+              for line in plane.lines for e in line.events
+              if e.name.startswith(tl.SPAN_PREFIX)]
+    for name in (tl.SESSION_RUN, tl.SESSION_PLACE_BATCH,
+                 tl.SESSION_ENQUEUE, tl.SESSION_RECORD, tl.SESSION_FETCH):
+        steps = [ids["step"] for n, ids in events
+                 if n == tl.SPAN_PREFIX + name]
+        assert steps == [1, 2], (name, events)
+
+
+def test_evaluate_and_prefetch_place_batches_under_a_span(span_ring):
+    ad, batch = _fresh_session()
+    sess = ad.create_distributed_session()
+    sess.evaluate([batch, batch])
+    placed = list(sess.prefetch([batch, batch, batch]))
+    assert len(placed) == 3
+    names = [s["name"] for s in span_ring.spans
+             if s["name"].startswith("session/")]
+    assert names == [tl.SESSION_PLACE_BATCH] * 5
+
+
+def test_last_batch_shapes_rebuilt_only_on_change(span_ring):
+    """The shape tree for on-demand FLOPs and the (items, tokens) pair
+    are rebuilt when a leaf's shape or dtype changes, not every step."""
+    ad, batch = _fresh_session()
+    sess = ad.create_distributed_session()
+    sess.run(batch)
+    tree = sess._last_batch
+    assert tree["x"].shape == (32, 16) and tree["ids"].dtype == np.int32
+    sess.run(batch)
+    assert sess._last_batch is tree
+    sess.run({k: v[:8] for k, v in batch.items()})
+    assert sess._last_batch is not tree
+    assert sess._last_batch["x"].shape == (8, 16)
+    rec = sess.telemetry.records[-1]
+    assert rec.step_time_s and rec.items_per_s == pytest.approx(
+        8 / rec.step_time_s)
+
+
+def test_engine_tick_and_sse_poll_spans(span_ring):
+    """A tiny paged engine behind the HTTP front, a few requests, one of
+    them streamed: ``engine/step`` holds its children (harvest, admit,
+    prefill with rows and bucket, decode chunk with n, the host sync),
+    the driver's lock wait and the observer are there, and a stream's
+    poll carries its ``request_id``.  A dispatch shape that ran before
+    says so (``seen``), which is what makes a compile a recompile."""
+    import http.client
+
+    from autodist_tpu.models.transformer import dense_attention
+    from autodist_tpu.models.transformer_lm import transformer_lm
+    from autodist_tpu.serving import EngineServer, PagedDecodeEngine
+
+    spec = transformer_lm(vocab_size=61, num_layers=2, num_heads=2,
+                          head_dim=8, d_ff=32, max_len=48, seq_len=16,
+                          attn_fn=dense_attention)
+    eng = PagedDecodeEngine(spec, spec.init(jax.random.PRNGKey(0)),
+                            slots=2, window=32, block_size=8,
+                            num_blocks=24, chunk=4)
+    srv = EngineServer(eng, port=0, request_timeout_s=120).start()
+    try:
+        for body in ({"prompt_tokens": [3, 5, 7], "max_new_tokens": 9},
+                     {"prompt_tokens": [2, 4, 6], "max_new_tokens": 9,
+                      "stream": True}):
+            conn = http.client.HTTPConnection(*srv.address, timeout=120)
+            conn.request("POST", "/v1/completions", json.dumps(body),
+                         {"Content-Type": "application/json"})
+            resp = conn.getresponse()
+            assert resp.status == 200
+            resp.read()
+            conn.close()
+    finally:
+        srv.close()
+    spans = span_ring.spans
+    steps = [s for s in spans if s["name"] == tl.ENGINE_STEP]
+    assert steps
+    # (in a fresh process jax also compiles under these: compile/* records)
+    kids = [s for s in spans if s["parent"] == tl.ENGINE_STEP
+            and s["name"].startswith("engine/")]
+    assert {k["name"] for k in kids} == {
+        tl.ENGINE_HARVEST, tl.ENGINE_ADMIT, tl.ENGINE_PREFILL,
+        tl.ENGINE_DECODE_CHUNK, tl.ENGINE_HOST_SYNC}
+    assert all(any(_inside(k, s) for s in steps) for k in kids)
+    prefills = [k["ids"] for k in kids if k["name"] == tl.ENGINE_PREFILL]
+    assert prefills[0] == {"rows": 1, "bucket": 4, "seen": 0}
+    assert prefills[1] == {"rows": 1, "bucket": 4, "seen": 1}
+    chunks = [k["ids"] for k in kids if k["name"] == tl.ENGINE_DECODE_CHUNK]
+    assert chunks[0] == {"n": 4, "seen": 0} and chunks[-1]["seen"] > 0
+    names = {s["name"] for s in spans}
+    assert {tl.SERVER_LOCK_WAIT, tl.SERVER_OBSERVE} <= names
+    polls = [s for s in spans if s["name"] == tl.SERVER_SSE_POLL]
+    assert polls and {p["ids"]["request_id"] for p in polls} == {1}
+    # the per-request record operators export rides the same ring
+    life = [s["name"] for s in spans if s["ids"].get("request_id") == 1
+            and s["name"] in ("queue_wait", "prefill", "decode")]
+    assert sorted(life) == ["decode", "prefill", "queue_wait"]
