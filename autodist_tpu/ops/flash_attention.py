@@ -1,4 +1,4 @@
-"""Pallas TPU flash attention (forward + backward kernels).
+"""Pallas TPU flash attention (forward kernel + ONE fused backward kernel).
 
 The hot op of every transformer in the model zoo.  Dense attention
 (``models/transformer.py:dense_attention``) materializes the [B, H, T, T]
@@ -16,7 +16,38 @@ block computation.
 
 Layout convention matches the pluggable ``attn_fn`` protocol: q/k/v are
 ``[batch, seq, heads, head_dim]``; internally the kernel runs per (batch,
-head) on ``[seq, head_dim]`` tiles.
+head) on ``[seq, head_dim]`` blocks, and q, k, v, o and the gradients cross
+the custom call's boundary in the caller's type.
+
+What the kernels do, and what was measured (PR 25, one TPU v5 lite chip,
+jax 0.9.0; each call timed alone, eight chained in one program; the table
+is ``PERF.md`` section 5):
+
+* **Transposed tiles.**  A score tile is ``[bk, bq]``: keys down the
+  sublanes, queries along the lanes.  Everything per query (running max and
+  sum, lse, Δ) is then a lane-dense row ``[1, bq]`` whose broadcast is
+  free, and every accumulator is ``[D, ·]`` with all 128 lanes full where
+  ``[·, 64]`` fills half.  Every product's result is then a block wide
+  (512 columns), never D wide, which is what keeps the chip's MXUs busy
+  at D = 64: written the other way round (dV = Pᵀ·dO as ``[bk, 64]``) the
+  fused backward took 0.542 ms for ``[4, 16, 1024, 64]`` where it takes
+  0.478 ms, and a forward with ``[bq, bk]`` tiles 0.375 ms for 0.293.
+* **One backward call** that forms S, P, dP and dS ONCE per tile and
+  accumulates dV, dK and dQ from them (7 products and 2 exponential passes
+  a layer with the forward; the dQ and dK/dV calls it replaces took 9 and
+  3).  Forward 0.340 -> 0.293 ms, backward 0.354 + 0.563 -> 0.478 ms at
+  that shape; 0.746 -> 0.655 and 0.812 + 1.241 -> 1.000 ms at
+  ``[2, 8, 4096, 64]``.
+* **Products at the stated precision.**  The operands of every product are
+  rounded to bfloat16 right before it when the kernel is compiled (one MXU
+  pass, float32 sums: XLA's default for every other product of a step, and
+  measured to be what Mosaic already did with float32 operands: same
+  errors to three digits, same times); statistics, exponentials and
+  accumulators stay float32.  Under the interpreter, or when jax is asked
+  for ``highest`` products, the operands stay float32 and the products
+  ask for ``HIGHEST`` (2.5-4 times slower on the chip, error 1e-7).
+* **Masks only where needed.**  Tiles wholly below the diagonal and inside
+  ``kv_len`` skip the iota / compare / select.
 
 Interpret mode (CPU tests) is selected automatically off-TPU.
 """
@@ -29,10 +60,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
 from autodist_tpu.const import MESH_AXIS_DATA, MESH_AXIS_MODEL
 from autodist_tpu.ops import pallas_utils
+from autodist_tpu.utils import logging
 
 _NEG_INF = -1e30  # finite -inf: keeps exp()/max() NaN-free (masked rows)
 # Tiling policy lives in ops/pallas_utils.py (shared by every Pallas
@@ -42,261 +75,364 @@ _TILE = pallas_utils.TILE
 _pick_block = pallas_utils.pick_block
 _pad_len = pallas_utils.pad_len
 _use_interpret = pallas_utils.use_interpret
-# Default q/k block edge.  Measured on TPU v5e (B=2,H=8,D=64, causal,
-# fwd+bwd, vs XLA dense attention): 512 gives ~1.0x at T=2048, ~1.8x at
-# T=4096, ~3.2x at T=8192; 128 loses to dense.  _pick_block degrades
-# gracefully for sequences 512 doesn't divide.
+# Default q/k block edge, for every caller and both kernels.  Measured in
+# PR 25 on one TPU v5 lite chip (ms a call, causal, forward / backward):
+#   [4,16,1024,64] f32: 512x512 0.293/0.478, 256x256 0.467/0.623,
+#     512 queries x 256 keys 0.360/0.531, 1024x1024 0.303/0.482
+#   [8,12,2048,64] bf16: 512x512 1.178/1.904, 256x256 2.123/2.608,
+#     1024x1024 1.176/1.983
+#   [2,8,4096,64] f32: 512x512 0.655/1.000, 256x256 1.224/1.455,
+#     1024 x 512 0.626/1.023
+# Smaller tiles compute less of the causal square (62.5 % at 256 against
+# 75 % at 512 of T = 1024) and still lose: a product narrower than 512
+# columns leaves MXUs idle.  _pick_block degrades gracefully for sequences
+# 512 doesn't divide.
 _DEFAULT_BLOCK = 512
+
+
+_VMEM_DEFAULT = 16 << 20  # Mosaic's scoped-VMEM limit on the v5e
+_VMEM_MAX = 100 << 20      # of the v5e's 128 MiB
+_VMEM_TILES = 8 << 20      # room for one tile's temporaries (512 x 512 f32)
+
+
+def _compiler_params(t: int, d: int, bytes_per_number: int):
+    """The last grid axis sequential (scratch filled at its first step is
+    read at the others), and the scoped-VMEM limit raised when the blocks
+    that stay resident over that axis would not fit the default with room
+    for a tile's temporaries.  ``bytes_per_number``: the itemsizes of the
+    [T, D] buffers that stay in VMEM, summed (an input or output block
+    counts twice: it is double-buffered; D < 128 pads to 128 lanes).
+    The backward holds 2.5 KB (bfloat16 in, D = 128) to 4 KB (float32 in)
+    a row: T = 4096 fits the 16 MiB default, T = 16384 at D = 128 asks for
+    48-72 MiB, and the v5e's VMEM ends between T = 16384 (float32 in) and
+    32768 (bfloat16 in); the forward holds about half of that."""
+    need = _VMEM_TILES + bytes_per_number * t * max(d, _TILE)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=(None if need <= _VMEM_DEFAULT
+                          else min(need, _VMEM_MAX)))
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
-                block_k: int, scale: float, kv_len: int):
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+
+
+def _block(i, size: int, count: int):
+    """Rows (or lanes) of block ``i`` of ``count``.  One block starts at a
+    static 0: a short sequence is ONE block of any multiple of 8, and a
+    dynamic lane index has to be provably a multiple of 128."""
+    return pl.ds(0 if count == 1 else pl.multiple_of(i * size, size), size)
+
+
+def _dot(a, b, dims, operand):
+    """One MXU product with float32 sums.  ``operand`` is the type both
+    sides are rounded to right before it: bfloat16 is ONE pass (what XLA's
+    default precision does to every other product of a step); float32
+    asks for ``HIGHEST``, so the request is honoured on the chip rather
+    than left to Mosaic's default for float32 operands."""
+    precision = lax.Precision.HIGHEST if operand == jnp.float32 else None
+    return lax.dot_general(a.astype(operand), b.astype(operand), dims,
+                           precision=precision,
+                           preferred_element_type=jnp.float32)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, ks_ref, vt_ref, *,
+                causal: bool, block_k: int, scale: float, kv_len: int,
+                operand):
     """One (batch, head, q-block) program: stream K/V blocks, online softmax.
 
-    Refs: q [1,1,bq,D]; k/v [1,1,T,D]; o [1,1,bq,D]; lse [1,1,bq,1]
-    (the trailing singleton keeps the block's last-two dims TPU-tileable).
+    Tiles are TRANSPOSED, ``[bk, bq]`` (keys down the sublanes, queries
+    along the lanes): the running max and sum are lane-dense rows
+    ``[1, bq]`` (a handful of registers, reduced over sublanes on the VPU)
+    and Oᵀ ``[D, bq]`` fills every lane where O ``[bq, 64]`` fills half.
+
+    Refs: q/o [1,1,bq,D]; k/v [1,1,T,D] (resident over the q-block axis,
+    which is sequential); lse [1,1,1,bq].  Scratch, filled at the first q
+    block of a (batch, head): the product operands K [T,D] and Vᵀ [D,T].
     ``kv_len`` < T means the tail is alignment padding — masked out.
+    Only tiles the diagonal or the padding edge crosses pay for the mask.
     """
-    q = q_ref[0, 0].astype(jnp.float32) * scale            # [bq, D]
-    bq, d = q.shape
+    bq, d = q_ref.shape[2], q_ref.shape[3]
     t_k = k_ref.shape[2]
     padded = kv_len < t_k
     num_kb = t_k // block_k
     qi = pl.program_id(2)
-    q_pos = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
+    q0 = qi * bq
 
-    def body(kb, carry):
-        o, l, m = carry
-        k0 = kb * block_k
-        k = k_ref[0, 0, pl.ds(k0, block_k), :].astype(jnp.float32)
-        v = v_ref[0, 0, pl.ds(k0, block_k), :].astype(jnp.float32)
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [bq, bk]
-        if causal or padded:
-            k_pos = k0 + lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-            mask = k_pos <= q_pos if causal else k_pos >= 0
+    @pl.when(qi == 0)
+    def _():
+        def fill(kb, _):
+            rows = _block(kb, block_k, num_kb)
+            ks_ref[rows, :] = k_ref[0, 0, rows, :].astype(operand)
+            vt_ref[:, rows] = v_ref[0, 0, rows, :].astype(
+                jnp.float32).T.astype(operand)
+            return 0
+        lax.fori_loop(0, num_kb, fill, 0)
+
+    q = (q_ref[0, 0].astype(jnp.float32) * scale).astype(operand)  # [bq, D]
+    # k_pos <= q_pos  <=>  row - col <= q0 - k0
+    row = lax.broadcasted_iota(jnp.int32, (block_k, bq), 0)
+    row_minus_col = row - lax.broadcasted_iota(jnp.int32, (block_k, bq), 1)
+
+    def tile(masked, kb, carry):
+        o_t, l, m = carry
+        k0, rows = kb * block_k, _block(kb, block_k, num_kb)
+        s_t = _dot(ks_ref[rows, :], q, _NT, operand)            # [bk,bq]
+        if masked:
+            mask = row_minus_col <= q0 - k0 if causal else None
             if padded:
-                mask &= k_pos < kv_len
-            s = jnp.where(mask, s, _NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))   # [bq,1]
-        p = jnp.exp(s - m_new)                                  # [bq,bk]
-        corr = jnp.exp(m - m_new)                               # [bq,1]
-        l_new = l * corr + p.sum(axis=-1, keepdims=True)
-        o_new = o * corr + lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+                live = row < kv_len - k0
+                mask = live if mask is None else mask & live
+            s_t = jnp.where(mask, s_t, _NEG_INF)
+        m_new = jnp.maximum(m, s_t.max(axis=0, keepdims=True))  # [1,bq]
+        p_t = jnp.exp(s_t - m_new)                              # [bk,bq]
+        corr = jnp.exp(m - m_new)                               # [1,bq]
+        l_new = l * corr + p_t.sum(axis=0, keepdims=True)
+        o_new = o_t * corr + _dot(vt_ref[:, rows], p_t, _NN, operand)  # [D,bq]
         return o_new, l_new, m_new
 
-    o0 = jnp.zeros((bq, d), jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    m0 = jnp.full((bq, 1), _NEG_INF, jnp.float32)
+    init = (jnp.zeros((d, bq), jnp.float32), jnp.zeros((1, bq), jnp.float32),
+            jnp.full((1, bq), _NEG_INF, jnp.float32))
+    # K blocks [0, clear) lie wholly below the diagonal and inside kv_len:
+    # no mask.  Blocks [clear, upper) cross one of the two; the rest are
+    # wholly above the diagonal and contribute nothing.
+    clear = upper = num_kb
     if causal:
-        # Only K blocks at or before this q block's last row contribute.
-        upper = lax.div(qi * bq + bq + block_k - 1, block_k)
-        upper = jnp.minimum(upper, num_kb)
-    else:
-        upper = num_kb
-    o, l, m = lax.fori_loop(0, upper, body, (o0, l0, m0))
+        clear = lax.div(q0 + 1, block_k)
+        upper = jnp.minimum(lax.div(q0 + bq + block_k - 1, block_k), num_kb)
+    if padded:
+        clear = jnp.minimum(clear, kv_len // block_k)
+    carry = lax.fori_loop(0, clear, functools.partial(tile, False), init)
+    if causal or padded:
+        carry = lax.fori_loop(clear, upper, functools.partial(tile, True),
+                              carry)
+    o_t, l, m = carry
     l = jnp.maximum(l, 1e-30)
-    o_ref[0, 0] = (o / l).astype(o_ref.dtype)
+    o_ref[0, 0] = (o_t / l).T.astype(o_ref.dtype)
     lse_ref[0, 0] = m + jnp.log(l)
 
 
-def _fwd(q, k, v, causal, block_q, block_k, interpret, kv_len):
-    """q/k/v: [B, H, T, D] → (o [B,H,T,D], lse [B,H,T])."""
+def _fwd(q, k, v, causal, block_q, block_k, interpret, kv_len,
+         operand=jnp.float32):
+    """q/k/v: [B, H, T, D] → (o [B,H,T,D], lse [B,H,T,1])."""
     b, h, t, d = q.shape
     bq = _pick_block(t, block_q)
     bk = _pick_block(t, block_k)
     scale = 1.0 / (d ** 0.5)
-    grid = (b, h, t // bq)
     kernel = functools.partial(_fwd_kernel, causal=causal, block_k=bk,
-                               scale=scale, kv_len=kv_len)
-    return pl.pallas_call(
+                               scale=scale, kv_len=kv_len, operand=operand)
+    qb_spec = pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi: (bi, hi, qi, 0))
+    full_spec = pl.BlockSpec((1, 1, t, d), lambda bi, hi, qi: (bi, hi, 0, 0))
+    o, lse = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, t, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, t, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
-        ],
+        grid=(b, h, t // bq),
+        in_specs=[qb_spec, full_spec, full_spec],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda bi, hi, qi: (bi, hi, qi, 0)),
+            qb_spec,
+            pl.BlockSpec((1, 1, 1, bq), lambda bi, hi, qi: (bi, hi, 0, qi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, t, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, 1, t), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((t, d), operand),
+                        pltpu.VMEM((d, t), operand)],
+        # k, v in (twice each); K and Vᵀ as operands
+        compiler_params=_compiler_params(
+            t, d, 4 * q.dtype.itemsize + 2 * jnp.dtype(operand).itemsize),
         interpret=interpret,
     )(q, k, v)
+    return o, lse.reshape(b, h, t, 1)
 
 
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-               causal: bool, block_k: int, scale: float, kv_len: int):
-    """dQ for one q block: dS = P∘(dPᵀV − Δ); dQ = scale · dS·K."""
-    q = q_ref[0, 0].astype(jnp.float32) * scale
-    do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]                                     # [bq,1]
-    delta = delta_ref[0, 0]                                 # [bq,1]
-    bq, d = q.shape
-    t_k = k_ref.shape[2]
-    padded = kv_len < t_k
-    num_kb = t_k // block_k
-    qi = pl.program_id(2)
-    q_pos = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, qst_ref, dot_ref, dqt_ref, *,
+                causal: bool, block_q: int, scale: float, kv_len: int,
+                operand):
+    """dQ, dK and dV in one call: each score tile is formed ONCE.
 
-    def body(kb, dq):
-        k0 = kb * block_k
-        k = k_ref[0, 0, pl.ds(k0, block_k), :].astype(jnp.float32)
-        v = v_ref[0, 0, pl.ds(k0, block_k), :].astype(jnp.float32)
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        if causal or padded:
-            k_pos = k0 + lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-            mask = k_pos <= q_pos if causal else k_pos >= 0
-            if padded:
-                mask &= k_pos < kv_len
-            s = jnp.where(mask, s, _NEG_INF)
-        p = jnp.exp(s - lse)                                # recomputed probs
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        return dq + lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
+    One (batch, head, k-block) program; the k-block axis is sequential.
+    With tiles transposed (``[bk, bq]``) and Q, dO held transposed:
 
-    if causal:
-        upper = jnp.minimum(lax.div(qi * bq + bq + block_k - 1, block_k),
-                            num_kb)
-    else:
-        upper = num_kb
-    dq = lax.fori_loop(0, upper, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0, 0] = (dq * scale).astype(dq_ref.dtype)
+        Sᵀ = K·(Qᵀ·scale)   Pᵀ = exp(Sᵀ − lse)   dPᵀ = V·dOᵀ
+        dSᵀ = Pᵀ∘(dPᵀ − Δ)
+        dVᵀ += dOᵀ·P   dKᵀ += (Qᵀ·scale)·dS   dQᵀ += Kᵀ·dSᵀ
 
-
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, *, causal: bool, block_q: int, scale: float,
-                kv_len: int):
-    """dK/dV for one k block: dV = PᵀdO; dK = scale · dSᵀQ."""
-    k = k_ref[0, 0].astype(jnp.float32)
-    v = v_ref[0, 0].astype(jnp.float32)
-    bk, d = k.shape
+    Refs: q/do/dq [1,1,T,D] (resident over the k-block axis); k/v/dk/dv
+    [1,1,bk,D]; lse/Δ [1,1,1,T].  Scratch, filled at the first k block of
+    a (batch, head): the product operands Qᵀ·scale and dOᵀ [D,T] (rounded
+    and transposed once, not once a tile) and the float32 dQᵀ [D,T],
+    scaled, transposed and written back at the last k block.
+    """
+    bk = k_ref.shape[2]
     t_q = q_ref.shape[2]
     padded = kv_len < t_q
     num_qb = t_q // block_q
     ki = pl.program_id(2)
-    k_pos = ki * bk + lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
+    k0 = ki * bk
 
-    def body(qb, carry):
-        dk, dv = carry
-        q0 = qb * block_q
-        q = q_ref[0, 0, pl.ds(q0, block_q), :].astype(jnp.float32) * scale
-        do = do_ref[0, 0, pl.ds(q0, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.ds(q0, block_q), :]          # [bq,1]
-        delta = delta_ref[0, 0, pl.ds(q0, block_q), :]      # [bq,1]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [bq,bk]
-        if causal or padded:
-            q_pos = q0 + lax.broadcasted_iota(jnp.int32, (block_q, bk), 0)
-            mask = k_pos <= q_pos if causal else k_pos >= 0
+    @pl.when(ki == 0)
+    def _():
+        def fill(qb, _):
+            rows = _block(qb, block_q, num_qb)
+            qs = q_ref[0, 0, rows, :].astype(jnp.float32) * scale
+            qst_ref[:, rows] = qs.T.astype(operand)
+            dot_ref[:, rows] = do_ref[0, 0, rows, :].astype(
+                jnp.float32).T.astype(operand)
+            return 0
+        lax.fori_loop(0, num_qb, fill, 0)
+        dqt_ref[...] = jnp.zeros_like(dqt_ref)
+
+    k = k_ref[0, 0].astype(operand)                          # [bk, D]
+    v = v_ref[0, 0].astype(operand)
+    k_t = k_ref[0, 0].astype(jnp.float32).T.astype(operand)  # [D, bk]
+    # k_pos <= q_pos  <=>  row - col <= q0 - k0
+    row = lax.broadcasted_iota(jnp.int32, (bk, block_q), 0)
+    row_minus_col = row - lax.broadcasted_iota(jnp.int32, (bk, block_q), 1)
+    live = row < kv_len - k0 if padded else None
+
+    def tile(masked, qb, carry):
+        dk_t, dv_t = carry
+        cols = _block(qb, block_q, num_qb)
+        qs_t = qst_ref[:, cols]                             # [D, bq]
+        do_t = dot_ref[:, cols]
+        s_t = _dot(k, qs_t, _NN, operand)                   # [bk, bq]
+        if masked:
+            mask = row_minus_col <= qb * block_q - k0 if causal else None
             if padded:
-                mask &= k_pos < kv_len
-            s = jnp.where(mask, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dv = dv + lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dk = dk + lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        return dk, dv
+                mask = live if mask is None else mask & live
+            s_t = jnp.where(mask, s_t, _NEG_INF)
+        p_t = jnp.exp(s_t - lse_ref[0, 0, :, cols])         # recomputed probs
+        dp_t = _dot(v, do_t, _NN, operand)
+        ds_t = (p_t * (dp_t - delta_ref[0, 0, :, cols])).astype(operand)
+        dv_t = dv_t + _dot(do_t, p_t, _NT, operand)         # [D, bk]
+        dk_t = dk_t + _dot(qs_t, ds_t, _NT, operand)
+        dqt_ref[:, cols] += _dot(k_t, ds_t, _NN, operand)   # [D, bq]
+        return dk_t, dv_t
 
+    # q blocks [lower, clear) cross the diagonal (or, every one of them,
+    # the padding edge); blocks [clear, num_qb) lie wholly below it: no
+    # mask.  Blocks before ``lower`` are wholly above: nothing to do.
+    lower, clear = 0, 0
     if causal:
-        # q rows before this k block's first column are fully masked.
-        lower = lax.div(ki * bk, block_q)
-    else:
-        lower = 0
-    dk0 = jnp.zeros((bk, d), jnp.float32)
-    dv0 = jnp.zeros((bk, d), jnp.float32)
-    dk, dv = lax.fori_loop(lower, num_qb, body, (dk0, dv0))
-    # q blocks were pre-scaled, so dSᵀQ already carries the 1/√d factor.
-    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+        lower = lax.div(k0, block_q)
+        clear = jnp.minimum(lax.div(k0 + bk + block_q - 2, block_q), num_qb)
+    if padded:
+        clear = jnp.where(k0 + bk > kv_len, num_qb, clear)
+    zero = jnp.zeros(k_t.shape, jnp.float32)
+    carry = (zero, zero)
+    if causal or padded:
+        carry = lax.fori_loop(lower, clear, functools.partial(tile, True),
+                              carry)
+    dk_t, dv_t = lax.fori_loop(clear, num_qb, functools.partial(tile, False),
+                               carry)
+    # q was pre-scaled, so dSᵀQ already carries the 1/√d factor.
+    dk_ref[0, 0] = dk_t.T.astype(dk_ref.dtype)
+    dv_ref[0, 0] = dv_t.T.astype(dv_ref.dtype)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _():
+        def write(qb, _):
+            rows = _block(qb, block_q, num_qb)
+            dq_ref[0, 0, rows, :] = (dqt_ref[:, rows].T * scale).astype(
+                dq_ref.dtype)
+            return 0
+        lax.fori_loop(0, num_qb, write, 0)
 
 
 def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret, kv_len,
-         dlse=None):
+         dlse=None, operand=jnp.float32):
     b, h, t, d = q.shape
     bq = _pick_block(t, block_q)
     bk = _pick_block(t, block_k)
     scale = 1.0 / (d ** 0.5)
     # Δ_i = Σ_d dO_id · O_id — the softmax-normalization gradient term;
-    # a cheap elementwise reduce, left to XLA fusion.  [B,H,T,1] like lse.
-    # An lse cotangent folds in here: dS_ij = P_ij (dP_ij − Δ_i + dlse_i),
-    # so passing Δ' = Δ − dlse reuses the kernels unchanged.
+    # a cheap elementwise reduce, left to XLA fusion.  An lse cotangent
+    # folds in here: dS_ij = P_ij (dP_ij − Δ_i + dlse_i), so passing
+    # Δ' = Δ − dlse reuses the kernel unchanged.  Both go in as rows
+    # [B,H,1,T]: lane-dense, where [T,1] columns pad every number to 128.
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
                     keepdims=True)
     if dlse is not None:
         delta = delta - dlse.astype(jnp.float32)
+    delta = delta.reshape(b, h, 1, t)
+    lse = lse.reshape(b, h, 1, t)
 
-    qb_spec = pl.BlockSpec((1, 1, bq, d), lambda bi, hi, i: (bi, hi, i, 0))
     kb_spec = pl.BlockSpec((1, 1, bk, d), lambda bi, hi, i: (bi, hi, i, 0))
     full_spec = pl.BlockSpec((1, 1, t, d), lambda bi, hi, i: (bi, hi, 0, 0))
-    rowq_spec = pl.BlockSpec((1, 1, bq, 1), lambda bi, hi, i: (bi, hi, i, 0))
-    rowf_spec = pl.BlockSpec((1, 1, t, 1), lambda bi, hi, i: (bi, hi, 0, 0))
-
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal, block_k=bk, scale=scale,
-                          kv_len=kv_len),
-        grid=(b, h, t // bq),
-        in_specs=[qb_spec, full_spec, full_spec, qb_spec, rowq_spec,
-                  rowq_spec],
-        out_specs=qb_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, block_q=bq,
-                          scale=scale, kv_len=kv_len),
+    row_spec = pl.BlockSpec((1, 1, 1, t), lambda bi, hi, i: (bi, hi, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, causal=causal, block_q=bq,
+                          scale=scale, kv_len=kv_len, operand=operand),
         grid=(b, h, t // bk),
-        in_specs=[full_spec, kb_spec, kb_spec, full_spec, rowf_spec,
-                  rowf_spec],
-        out_specs=[kb_spec, kb_spec],
-        out_shape=[jax.ShapeDtypeStruct((b, h, t, d), k.dtype),
+        in_specs=[full_spec, kb_spec, kb_spec, full_spec, row_spec, row_spec],
+        out_specs=[full_spec, kb_spec, kb_spec],
+        out_shape=[jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
+                   jax.ShapeDtypeStruct((b, h, t, d), k.dtype),
                    jax.ShapeDtypeStruct((b, h, t, d), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((d, t), operand),
+                        pltpu.VMEM((d, t), operand),
+                        pltpu.VMEM((d, t), jnp.float32)],
+        # q, do in and dq out (twice each); Qᵀ, dOᵀ as operands; dQᵀ
+        compiler_params=_compiler_params(
+            t, d, 6 * q.dtype.itemsize + 2 * jnp.dtype(operand).itemsize + 4),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
-    return dq, dk, dv
 
 
 # ---------------------------------------------------------------------------
 # public op ([B, T, H, D] layout, custom VJP)
 # ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, block_q, block_k, interpret, kv_len):
-    return _fwd(q, k, v, causal, block_q, block_k, interpret, kv_len)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, block_q, block_k, interpret, kv_len,
+           operand=jnp.float32):
+    return _fwd(q, k, v, causal, block_q, block_k, interpret, kv_len, operand)
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, kv_len):
-    o, lse = _fwd(q, k, v, causal, block_q, block_k, interpret, kv_len)
+def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, kv_len, operand):
+    o, lse = _fwd(q, k, v, causal, block_q, block_k, interpret, kv_len,
+                  operand)
     return (o, lse), (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, kv_len, res, cts):
+def _flash_bwd(causal, block_q, block_k, interpret, kv_len, operand, res,
+               cts):
     q, k, v, o, lse = res
     do, dlse = cts
     return _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
-                kv_len, dlse=dlse)
+                kv_len, dlse=dlse, operand=operand)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+@functools.cache
+def _log_operand(name: str, why: str) -> None:
+    logging.info("flash attention: product operands %s (%s)", name, why)
+
+
+def _product_operand(interpret: bool):
+    """The type every product's operands are rounded to, from what the
+    code can observe: compiled for the TPU, bfloat16 (one MXU pass with
+    float32 sums, the default precision of every other product of a
+    step) unless jax's ``jax_default_matmul_precision`` asks for
+    ``highest`` / ``float32``; under the interpreter, float32.  Logged
+    once per process and resolved type."""
+    asked = str(jax.config.jax_default_matmul_precision or "default").lower()
+    if interpret:
+        operand, why = jnp.float32, "interpret mode"
+    elif asked in ("highest", "float32"):
+        operand, why = jnp.float32, f"jax_default_matmul_precision={asked}"
+    else:
+        operand, why = jnp.bfloat16, f"compiled, precision {asked}"
+    _log_operand(jnp.dtype(operand).name, why)
+    return operand
 
 
 def _pad_and_run(q, k, v, causal, block_q, block_k, interpret):
@@ -308,7 +444,8 @@ def _pad_and_run(q, k, v, causal, block_q, block_k, interpret):
     if tp != t:
         pad = [(0, 0), (0, 0), (0, tp - t), (0, 0)]
         qt, kt, vt = (jnp.pad(x, pad) for x in (qt, kt, vt))
-    o, lse = _flash(qt, kt, vt, causal, block_q, block_k, interpret, t)
+    o, lse = _flash(qt, kt, vt, causal, block_q, block_k, interpret, t,
+                    _product_operand(interpret))
     if tp != t:
         o = o[:, :, :t, :]
         lse = lse[:, :, :t, :]

@@ -207,3 +207,224 @@ def test_block_picker_prefers_tile_multiples():
     assert _pick_block(2048, 512) == 512
     assert _pick_block(24, 512) == 24      # tiny interpret-mode sequence
     assert _pick_block(8192, 512) == 512
+
+
+# ---------------------------------------------------------------------------
+# the fused backward (one call, each score tile formed once)
+# ---------------------------------------------------------------------------
+def _module():
+    """The module itself: ``autodist_tpu.ops.flash_attention`` as an
+    attribute is the function of that name."""
+    import importlib
+    return importlib.import_module("autodist_tpu.ops.flash_attention")
+
+
+def _grads(attn, q, k, v, w):
+    return jax.grad(lambda q, k, v: jnp.sum(attn(q, k, v) * w),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+_FUSED_CASES = {
+    # name: (t, block_q, block_k, kv_len or None)
+    "one_tile": (16, 16, 16, None),
+    "four_blocks_a_side": (32, 8, 8, None),     # dQ sums over >= 3 k blocks
+    "wide_q_tile": (32, 16, 8, None),
+    "wide_k_tile": (32, 8, 16, None),
+    "uneven_blocks": (24, 128, 128, None),      # falls back to a divisor
+    "padded": (24, 8, 8, 21),                   # kv_len < T inside a block
+    "padded_whole_block": (32, 8, 8, 24),
+}
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("case", sorted(_FUSED_CASES))
+def test_fused_backward_matches_dense(case, causal):
+    fa = _module()
+    t, bq, bk, kv_len = _FUSED_CASES[case]
+    live = kv_len or t
+    q, k, v = _qkv(np.random.default_rng(10), t=live, d=8)
+    w = jnp.asarray(np.random.default_rng(11).standard_normal(q.shape),
+                    jnp.float32)
+    pad = [(0, 0), (0, 0), (0, t - live), (0, 0)]
+
+    def flash(q, k, v):    # the private op on [B,H,T,D], padded by hand
+        qt, kt, vt = (jnp.pad(x.transpose(0, 2, 1, 3), pad)
+                      for x in (q, k, v))
+        o = fa._flash(qt, kt, vt, causal, bq, bk, True, live)[0]
+        return o[:, :, :live, :].transpose(0, 2, 1, 3)
+
+    dense = lambda q, k, v: dense_attention(q, k, v, causal)  # noqa: E731
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(dense(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    for gf, gd, name in zip(_grads(flash, q, k, v, w),
+                            _grads(dense, q, k, v, w), "qkv"):
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gd),
+                                   rtol=1e-4, atol=1e-4, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_fused_backward_lse_cotangent(causal):
+    """A loss that uses lse: the dlse cotangent is folded into Δ, and the
+    fused kernel must carry it into dQ AND dK (ring attention merges
+    blocks through lse)."""
+    from autodist_tpu.ops.flash_attention import flash_attention_with_lse
+
+    q, k, v = _qkv(np.random.default_rng(12), t=32, d=8)
+    w = jnp.asarray(np.random.default_rng(13).standard_normal(q.shape),
+                    jnp.float32)
+    u = jnp.asarray(np.random.default_rng(14).standard_normal((2, 2, 32)),
+                    jnp.float32)
+
+    def loss_flash(q, k, v):
+        o, lse = flash_attention_with_lse(q, k, v, causal, block_q=8,
+                                          block_k=8)
+        return jnp.sum(o * w) + jnp.sum(lse * u)
+
+    def loss_dense(q, k, v):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+        if causal:
+            s = jnp.where(jnp.tril(jnp.ones((32, 32), bool)), s, -1e30)
+        lse = jax.nn.logsumexp(s, axis=-1)
+        return jnp.sum(dense_attention(q, k, v, causal) * w) + jnp.sum(
+            lse * u)
+
+    np.testing.assert_allclose(loss_flash(q, k, v), loss_dense(q, k, v),
+                               rtol=1e-5)
+    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    g_dense = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    for gf, gd, name in zip(g_flash, g_dense, "qkv"):
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gd),
+                                   rtol=1e-4, atol=1e-4, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("blocks", [(8, 8), (8, 16), (16, 8)])
+def test_unmasked_tiles_agree_with_all_masked(blocks):
+    """Tiles wholly below the diagonal take the unmasked path.  With one
+    32 x 32 tile every score goes through the mask; with 8- and 16-blocks
+    most tiles skip it: outputs and gradients must be the same."""
+    q, k, v = _qkv(np.random.default_rng(15), t=32, d=8)
+    w = jnp.asarray(np.random.default_rng(16).standard_normal(q.shape),
+                    jnp.float32)
+    bq, bk = blocks
+    tiled = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, True, block_q=bq, block_k=bk)
+    whole = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, True, block_q=32, block_k=32)
+    np.testing.assert_allclose(np.asarray(tiled(q, k, v)),
+                               np.asarray(whole(q, k, v)),
+                               rtol=1e-6, atol=1e-6)
+    for gt, gw, name in zip(_grads(tiled, q, k, v, w),
+                            _grads(whole, q, k, v, w), "qkv"):
+        np.testing.assert_allclose(np.asarray(gt), np.asarray(gw),
+                                   rtol=1e-5, atol=1e-5, err_msg=f"d{name}")
+
+
+# ---------------------------------------------------------------------------
+# products at the stated precision (operands rounded to bfloat16)
+# ---------------------------------------------------------------------------
+def _round_bf16(x):
+    return jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+
+
+@jax.custom_vjp
+def _rounded_operand(x):
+    return _round_bf16(x)
+
+
+_rounded_operand.defvjp(lambda x: (_round_bf16(x), None), lambda _, g: (g,))
+
+
+@jax.custom_vjp
+def _rounded_result(y):
+    return y
+
+
+_rounded_result.defvjp(lambda y: (y, None), lambda _, g: (_round_bf16(g),))
+
+
+def _mm_bf16(eq, a, b):
+    """``benchmark/reference/gpt2.py: _mm`` under "bfloat16": both
+    operands rounded, forward and backward (the cotangent of the result is
+    an operand of the backward products)."""
+    return _rounded_result(jnp.einsum(eq, _rounded_operand(a),
+                                      _rounded_operand(b)))
+
+
+def _dense_attention_bf16_products(q, k, v, causal):
+    t = q.shape[1]
+    s = _mm_bf16("bqhd,bkhd->bhqk", q, k) / (q.shape[-1] ** 0.5)
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -1e30)
+    return _mm_bf16("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+
+
+def _rel_err(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_rounded_operands_match_rounded_dense(causal):
+    """The compiled kernel's path (every product operand rounded to
+    bfloat16, float32 sums and statistics), run under the interpreter
+    through the private static argument, against a dense attention that
+    rounds the way the benchmark's reference does.  The two round the
+    probabilities at different moments (before and after the division by
+    their sum), so they agree to a few 1e-3, and each stands further from
+    unrounded attention than from the other."""
+    fa = _module()
+    q, k, v = _qkv(np.random.default_rng(17), t=64, d=16)
+    w = jnp.asarray(np.random.default_rng(18).standard_normal(q.shape),
+                    jnp.float32)
+
+    def flash(q, k, v):
+        qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+        o = fa._flash(qt, kt, vt, causal, 16, 16, True, 64, jnp.bfloat16)[0]
+        return o.transpose(0, 2, 1, 3)
+
+    rounded = lambda q, k, v: _dense_attention_bf16_products(  # noqa: E731
+        q, k, v, causal)
+    exact = lambda q, k, v: dense_attention(q, k, v, causal)  # noqa: E731
+    got = (flash(q, k, v),) + _grads(flash, q, k, v, w)
+    want = (rounded(q, k, v),) + _grads(rounded, q, k, v, w)
+    full = (exact(q, k, v),) + _grads(exact, q, k, v, w)
+    for g, r, e, name in zip(got, want, full, ("o", "dq", "dk", "dv")):
+        assert _rel_err(g, r) < 6e-3, (name, _rel_err(g, r))
+        assert 1e-3 < _rel_err(g, e) < 2e-2, (name, _rel_err(g, e))
+
+
+def test_product_operand_resolves_from_what_is_observable():
+    """bfloat16 when compiled, float32 under the interpreter or when jax
+    is asked for ``highest`` / ``float32`` products; nothing else decides."""
+    fa = _module()
+    assert fa._product_operand(True) == jnp.float32
+    assert fa._product_operand(False) == jnp.bfloat16
+    for asked in ("highest", "float32"):
+        with jax.default_matmul_precision(asked):
+            assert fa._product_operand(False) == jnp.float32
+    with jax.default_matmul_precision("bfloat16"):
+        assert fa._product_operand(False) == jnp.bfloat16
+
+
+def test_grad_lowers_to_two_pallas_calls_with_float32_boundaries():
+    """One attention under ``jax.grad`` is TWO Mosaic calls on the TPU
+    (forward, fused backward; it was three), and float32 inputs cross
+    both boundaries as float32: the rounding to bfloat16 happens inside
+    the kernel, which is what the benchmark's
+    ``product_operands_narrower_than_stated`` reads off these lines."""
+    import re
+
+    attn = make_flash_attention(interpret=False)
+    q = jnp.zeros((2, 256, 4, 64), jnp.float32)
+
+    def loss(q, k, v):
+        return jnp.sum(attn(q, k, v, True))
+
+    txt = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, q, q).lower(
+        lowering_platforms=("tpu",)).as_text()
+    calls = [ln for ln in txt.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 2, len(calls)
+    for ln in calls:
+        types = re.findall(r"tensor<[0-9x]*x([a-z]+[0-9]+)>", ln)
+        assert types and set(types) == {"f32"}, ln

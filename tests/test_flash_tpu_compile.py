@@ -1,0 +1,109 @@
+"""The flash kernels compiled for a TPU v5e that is described, not attached.
+
+Interpret mode cannot see what Mosaic refuses (a lane index it cannot prove
+aligned, a transpose of an odd shape, more VMEM than a kernel may hold), so
+the forward and the fused backward are compiled here at the widths the
+repo runs them at, at no chip time.  Nothing runs: this says nothing about
+results or speed.  The topology is described inside a fixture (only the
+worker that is given this file loads the TPU's library), and every such
+test lives in this one file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from autodist_tpu.ops.flash_attention import flash_attention_with_lse
+
+
+@pytest.fixture(scope="module")
+def chips():
+    """The four described devices of a v5e 2x2 host."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent
+    # cache but not read back: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(chips):
+    return SingleDeviceSharding(chips[0])
+
+
+_SHAPES = {
+    # [B, T, H, D], type, causal
+    "gpt2_medium_train": ((4, 1024, 16, 64), jnp.float32, True),
+    "chip_smoke_lm": ((8, 2048, 12, 64), jnp.bfloat16, True),
+    "full_mask": ((2, 512, 4, 64), jnp.float32, False),
+    "one_short_block": ((2, 100, 4, 64), jnp.float32, True),   # pads to 104
+    "padded_blocks": ((2, 1000, 4, 64), jnp.bfloat16, False),  # pads to 1024
+    "uneven_blocks": ((1, 2176, 2, 64), jnp.float32, True),    # 17 x 128
+    "long_wide": ((1, 8192, 8, 128), jnp.bfloat16, True),  # raised VMEM limit
+}
+
+
+# bfloat16 operands (the default) everywhere; float32 operands under
+# ``highest`` at every size something may ask them at
+_CASES = [(name, precision) for name in sorted(_SHAPES)
+          for precision in ("default", "highest")
+          if (name, precision) != ("long_wide", "highest")]
+
+
+@pytest.mark.parametrize("name,precision", _CASES)
+def test_forward_and_fused_backward_compile(one_chip, name, precision):
+    shape, dtype, causal = _SHAPES[name]
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(q, k, v):   # both outputs used: the lse cotangent path too
+        o, lse = flash_attention_with_lse(q, k, v, causal, interpret=False)
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(lse)
+
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            x, x, x).compile()
+    assert len(_pallas_calls(compiled)) == 2     # forward, fused backward
+
+
+def _pallas_calls(compiled):
+    return [ln for ln in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in ln]
+
+
+def test_data_parallel_over_four_chips_compiles(chips):
+    """The model-zoo default under a ``data=4`` mesh (the dp4 training
+    cell's attention): ``shard_map`` hands each chip its 4 of 16 rows, and
+    the kernels compile on [4, 16, 1024, 64] a chip."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from autodist_tpu.ops import make_flash_attention
+
+    mesh = Mesh(np.array(chips), ("data",))
+    attn = make_flash_attention(mesh, interpret=False)
+    x = jax.ShapeDtypeStruct((16, 1024, 16, 64), jnp.float32,
+                             sharding=NamedSharding(mesh, P("data")))
+
+    def loss(q, k, v):
+        return jnp.sum(attn(q, k, v, True))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    calls = _pallas_calls(compiled)
+    assert len(calls) == 2
+    for ln in calls:
+        assert "f32[4,16,1024,64]" in ln and "f32[16," not in ln, ln
