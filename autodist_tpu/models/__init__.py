@@ -23,6 +23,7 @@ from autodist_tpu.models.lora import (  # noqa: F401
     lora_merge,
     lora_setup,
 )
+from autodist_tpu.models.mla_moe_lm import mla_moe_lm  # noqa: F401
 from autodist_tpu.models.moe_lm import moe_transformer_lm  # noqa: F401
 from autodist_tpu.models.ncf import ncf  # noqa: F401
 from autodist_tpu.models.pipelined_lm import pipelined_transformer_lm  # noqa: F401
@@ -44,5 +45,6 @@ ALL_MODELS = {
     "ncf": ncf,
     "transformer_lm": transformer_lm,
     # pipelined_transformer_lm / moe_transformer_lm are mesh-parameterized;
-    # construct them directly.
+    # construct them directly.  mla_moe_lm's defaults are one chip's share
+    # of a 30B model (576 M parameters): construct it with your sizes.
 }

@@ -51,6 +51,17 @@ def layer_norm(x, scale, eps=1e-6) -> jax.Array:
     return (x - mu) * jax.lax.rsqrt(var + eps) * scale
 
 
+def rms_norm(x, scale, eps=1e-6) -> jax.Array:
+    """Root-mean-square norm (Zhang & Sennrich 2019): no mean taken off, no
+    bias; the mean square is formed in float32 whatever ``x`` is stored
+    in."""
+    import jax.numpy as jnp
+
+    x32 = x.astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (x32 * inv * scale.astype(jnp.float32)).astype(x.dtype)
+
+
 def cross_entropy_loss(logits, labels) -> jax.Array:
     """Mean softmax cross entropy with integer labels."""
     import jax.numpy as jnp

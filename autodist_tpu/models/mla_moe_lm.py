@@ -1,0 +1,248 @@
+"""Decoder LM with latent attention and sigmoid-routed experts.
+
+The DeepSeek-V3 block (arxiv 2412.19437; ``model_type: deepseek_v3``):
+
+* **Multi-head latent attention** without the query low-rank: keys and
+  values are decompressed from one ``kv_lora``-wide latent a token; a key
+  is ``[k_nope (from the latent), k_rope]`` where the rotary part is ONE
+  vector a token shared by all heads; queries and keys are ``nope + rope``
+  wide and values ``v_head`` wide, so the attention call gets
+  ``q, k: [B, T, H, Dk]`` and ``v: [B, T, H, Dv]`` with ``Dk != Dv``
+  (``ops/flash_attention.py`` takes both; the scale is 1/sqrt(Dk)).
+* RMSNorm before attention and FFN, on the latent, and at the end; rotary
+  positions on interleaved pairs; SwiGLU; no bias; an untied head.
+* ``first_dense`` leading layers with a dense SwiGLU, then expert layers
+  (``parallel/moe.py: routed_moe_ffn``): ``top_k`` of ``num_experts`` by
+  sigmoid score plus a selection bias, weights renormalised over the picks
+  and scaled, NO token dropped, shared experts beside the routed ones.
+  ``experts_held = (first, count)`` is this chip's share under expert
+  parallelism: it routes over all and computes its own.
+
+Functional (plain parameter dicts), like ``moe_lm.py``: every leaf has a
+strategy-addressable name, RMSNorm leaves are ``.../scale``.  The training
+path only: the latent is not cached and the decode path that absorbs
+``W_kvb`` into the query is not written (ROADMAP M3).
+"""
+from __future__ import annotations
+
+from typing import Callable, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from autodist_tpu.models.base import ModelSpec, cross_entropy_loss, rms_norm
+from autodist_tpu.parallel.moe import (
+    init_routed_moe_params,
+    routed_moe_ffn,
+    routed_rows,
+    swiglu,
+)
+from autodist_tpu.telemetry import registry, timeline
+
+_REMAT_POLICIES = {"full": None,
+                   "dots": jax.checkpoint_policies.checkpoint_dots}
+
+
+def rotary(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary positions on INTERLEAVED pairs: ``(x[2i], x[2i+1])`` of
+    position ``t`` turns by ``t * theta^(-2i/R)``.  ``x``: ``[B, T, ...,
+    R]``, positions along axis 1.  (The checkpoint's code de-interleaves
+    first and turns half against half; queries and keys get the same
+    reordering, so their products are these.)"""
+    t, r = x.shape[1], x.shape[-1]
+    inv = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv        # [T,R/2]
+    angle = angle.reshape((1, t) + (1,) * (x.ndim - 3) + (r // 2,))
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (r // 2, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def latent_attention(p: dict, x: jax.Array, attn_fn: Callable, *,
+                     qk_nope: int, qk_rope: int, theta: float,
+                     eps: float) -> jax.Array:
+    """``x [B, T, D] -> [B, T, D]``.  Leaves: ``wq [D, H, nope + rope]``,
+    ``wkv_a [D, latent + rope]``, ``kv_norm/scale [latent]``, ``wkv_b
+    [latent, H, nope + Dv]``, ``wo [H, Dv, D]``."""
+    heads = p["wq"].shape[1]
+    latent = p["wkv_b"].shape[0]
+    with jax.named_scope(timeline.SCOPE_MLA_PROJECT):
+        q = jnp.einsum("btd,dhk->bthk", x, p["wq"])
+        q = jnp.concatenate(
+            [q[..., :qk_nope], rotary(q[..., qk_nope:], theta)], axis=-1)
+        kv_a = x @ p["wkv_a"]
+        c = rms_norm(kv_a[..., :latent], p["kv_norm"]["scale"], eps)
+        k_rope = rotary(kv_a[..., latent:], theta)           # [B, T, rope]
+        kv = jnp.einsum("btc,chk->bthk", c, p["wkv_b"])
+        k = jnp.concatenate(
+            [kv[..., :qk_nope],
+             jnp.broadcast_to(k_rope[:, :, None, :],
+                              k_rope.shape[:2] + (heads, qk_rope))], axis=-1)
+        v = kv[..., qk_nope:]
+    # the kernel's HLO name is the innermost scope: ``attn``, as in the
+    # flax blocks (``MultiHeadAttention`` is named so)
+    with jax.named_scope(timeline.SCOPE_MLA_ATTENTION), \
+            jax.named_scope("attn"):
+        o = attn_fn(q, k, v, True)                           # [B,T,H,Dv]
+    with jax.named_scope(timeline.SCOPE_MLA_PROJECT):
+        return jnp.einsum("bthv,hvd->btd", o, p["wo"])
+
+
+def mla_moe_lm(vocab_size: int = 16032, num_layers: int = 5,
+               first_dense: int = 1, d_model: int = 2048,
+               num_heads: int = 32, qk_nope: int = 128, qk_rope: int = 64,
+               v_head: int = 128, kv_lora: int = 512, d_ff: int = 6144,
+               d_expert: int = 768, num_experts: int = 128,
+               experts_held: Optional[Tuple[int, int]] = None,
+               top_k: int = 6, shared_experts: int = 2,
+               routed_scale: float = 2.448, rope_theta: float = 1e6,
+               rms_eps: float = 1e-6, seq_len: int = 4096,
+               attn_fn: Optional[Callable] = None, dtype=jnp.float32,
+               xent_chunk: Optional[int] = None, remat: str = "full",
+               train_router: bool = True,
+               return_counts: bool = False) -> ModelSpec:
+    """Defaults: one chip's share of kanana-2-30b-a3b cut to five layers
+    (``benchmark/configs/kanana-2-30b-a3b.ep8-share.json`` passes
+    ``experts_held=[0, 16]``); shrink every size for tests.
+
+    ``experts_held=(first, count)``: the expert leaves lead with ``count``
+    experts; None holds all.  ``remat``: per-layer rematerialisation,
+    "none" | "dots" | "full" as in ``TransformerStack.remat``.
+    ``xent_chunk``: the head's loss through ``ops/chunked_xent.py``.
+    ``train_router=False``: the routers' weights take no gradient
+    (``routed_moe_ffn``).
+    ``return_counts``: ``loss_fn`` returns ``(loss, {"tokens_per_expert":
+    [expert layers, count]})`` for ``capture(has_aux=True)``."""
+    from autodist_tpu.models.transformer import default_attention
+
+    if remat not in ("none", "full", "dots"):
+        raise ValueError(f"remat={remat!r}: expected 'none', 'full', or "
+                         f"'dots'")
+    attn_fn = attn_fn or default_attention()
+    held = tuple(experts_held) if experts_held else (0, num_experts)
+    d_qk = qk_nope + qk_rope
+
+    def init(rng):
+        def normal(key, *shape):
+            return jax.random.normal(key, shape, dtype) * 0.02
+
+        def scale(width):
+            return {"scale": jnp.ones((width,), dtype)}
+
+        r_emb, r_head, r_layers = jax.random.split(rng, 3)
+        params = {"embed": normal(r_emb, vocab_size, d_model),
+                  "head": normal(r_head, vocab_size, d_model),
+                  "ln_final": scale(d_model)}
+        for i, r in enumerate(jax.random.split(r_layers, num_layers)):
+            k = jax.random.split(r, 8)
+            layer = {
+                "ln_attn": scale(d_model),
+                "attn": {"wq": normal(k[0], d_model, num_heads, d_qk),
+                         "wkv_a": normal(k[1], d_model, kv_lora + qk_rope),
+                         "kv_norm": scale(kv_lora),
+                         "wkv_b": normal(k[2], kv_lora, num_heads,
+                                         qk_nope + v_head),
+                         "wo": normal(k[3], num_heads, v_head, d_model)},
+                "ln_mlp": scale(d_model)}
+            if i < first_dense:
+                layer["mlp"] = {"w_gate": normal(k[4], d_model, d_ff),
+                                "w_up": normal(k[5], d_model, d_ff),
+                                "w_down": normal(k[6], d_ff, d_model)}
+            else:
+                layer["moe"] = init_routed_moe_params(
+                    k[7], d_model, d_expert, num_experts,
+                    experts_held=held[1],
+                    d_shared=shared_experts * d_expert, dtype=dtype)
+            params[f"layers_{i}"] = layer
+        return params
+
+    def layer_fn(lp, x):
+        """One sequence ``[1, T, D]`` through one layer."""
+        x = x + latent_attention(
+            lp["attn"], rms_norm(x, lp["ln_attn"]["scale"], rms_eps),
+            attn_fn, qk_nope=qk_nope, qk_rope=qk_rope, theta=rope_theta,
+            eps=rms_eps)
+        h = rms_norm(x, lp["ln_mlp"]["scale"], rms_eps)
+        if "mlp" in lp:
+            return x + swiglu(lp["mlp"], h), None
+        y, counts = routed_moe_ffn(lp["moe"], h, top_k=top_k,
+                                   experts_held=held,
+                                   routed_scale=routed_scale,
+                                   train_router=train_router)
+        return x + y, counts
+
+    if remat != "none":   # called under lax.map: no CSE barrier needed
+        layer_fn = jax.checkpoint(layer_fn, policy=_REMAT_POLICIES[remat],
+                                  prevent_cse=False)
+
+    def layer(lp, x):
+        """``x [B, T, D]`` through one layer, ONE SEQUENCE AT A TIME: what
+        a layer holds while it runs (queries, keys and values 192 and 128
+        wide in float32, the ``T * top_k`` sorted rows of the grouped
+        products and their cotangents) is then a sequence's, not the
+        batch's: 4 x 4096 tokens at the benchmark's widths ask for 20 GB
+        otherwise.  The price: each weight's gradient is summed over the
+        sequences instead of formed in one product."""
+        x, counts = jax.lax.map(lambda row: layer_fn(lp, row[None]), x)
+        return x[:, 0], None if counts is None else counts.sum(axis=0)
+
+    def features(params, tokens):
+        """Final-norm activations ``[B, T, D]`` and the expert layers'
+        ``tokens_per_expert`` ``[expert layers, count]``."""
+        computed, expected = routed_rows(tokens.size, top_k, held[1],
+                                         num_experts)
+        for kind, rows in (("computed", computed), ("expected", expected)):
+            registry.gauge(
+                "autodist_moe_rows_per_step",
+                "rows the grouped expert products are handed a step, and "
+                "rows an even router would send here",
+                {"kind": kind}).set(rows * (num_layers - first_dense))
+        x = jnp.take(params["embed"], tokens, axis=0)
+        counts = []
+        for i in range(num_layers):
+            x, c = layer(params[f"layers_{i}"], x)
+            if c is not None:
+                counts.append(c)
+        return rms_norm(x, params["ln_final"]["scale"], rms_eps), counts
+
+    def apply_fn(params, tokens):
+        return jnp.einsum("btd,vd->btv", features(params, tokens)[0],
+                          params["head"])
+
+    def loss_fn(params, batch):
+        tokens = batch["tokens"]
+        feats, counts = features(params, tokens)
+        if xent_chunk:
+            from autodist_tpu.ops.chunked_xent import \
+                chunked_softmax_cross_entropy
+
+            loss = chunked_softmax_cross_entropy(
+                feats[:, :-1], params["head"], tokens[:, 1:],
+                chunk=xent_chunk)
+        else:
+            logits = jnp.einsum("btd,vd->btv", feats, params["head"])
+            loss = cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
+        if return_counts:
+            return loss, {"tokens_per_expert": jnp.stack(counts)}
+        return loss
+
+    def make_batch(rng: np.random.RandomState, batch_size: int):
+        return {"tokens": rng.randint(
+            0, vocab_size, (batch_size, seq_len)).astype(np.int32)}
+
+    return ModelSpec(
+        name="mla_moe_lm",
+        init=init, loss_fn=loss_fn, apply_fn=apply_fn, make_batch=make_batch,
+        sparse_vars=("embed",),
+        expert_vars=("*/moe/experts/*",),
+        config=dict(vocab_size=vocab_size, num_layers=num_layers,
+                    first_dense=first_dense, d_model=d_model,
+                    num_heads=num_heads, qk_nope=qk_nope, qk_rope=qk_rope,
+                    v_head=v_head, kv_lora=kv_lora, d_ff=d_ff,
+                    d_expert=d_expert, num_experts=num_experts,
+                    experts_held=held, top_k=top_k,
+                    shared_experts=shared_experts, seq_len=seq_len),
+    )

@@ -15,7 +15,8 @@ shards the sequence *across* chips; this kernel is the fast *within-chip*
 block computation.
 
 Layout convention matches the pluggable ``attn_fn`` protocol: q/k/v are
-``[batch, seq, heads, head_dim]``; internally the kernel runs per (batch,
+``[batch, seq, heads, head_dim]`` (q and k one width, v and the output
+possibly another); internally the kernel runs per (batch,
 head) on ``[seq, head_dim]`` blocks, and q, k, v, o and the gradients cross
 the custom call's boundary in the caller's type.
 
@@ -95,22 +96,35 @@ _VMEM_MAX = 100 << 20      # of the v5e's 128 MiB
 _VMEM_TILES = 8 << 20      # room for one tile's temporaries (512 x 512 f32)
 
 
-def _compiler_params(t: int, d: int, bytes_per_number: int):
+def _compiler_params(t: int, block: int, resident, blocked, widths):
     """The last grid axis sequential (scratch filled at its first step is
-    read at the others), and the scoped-VMEM limit raised when the blocks
-    that stay resident over that axis would not fit the default with room
-    for a tile's temporaries.  ``bytes_per_number``: the itemsizes of the
-    [T, D] buffers that stay in VMEM, summed (an input or output block
-    counts twice: it is double-buffered; D < 128 pads to 128 lanes).
-    The backward holds 2.5 KB (bfloat16 in, D = 128) to 4 KB (float32 in)
-    a row: T = 4096 fits the 16 MiB default, T = 16384 at D = 128 asks for
-    48-72 MiB, and the v5e's VMEM ends between T = 16384 (float32 in) and
-    32768 (bfloat16 in); the forward holds about half of that."""
-    need = _VMEM_TILES + bytes_per_number * t * max(d, _TILE)
+    read at the others), and the scoped-VMEM limit raised when what the
+    kernel holds would not fit the default: the ``resident`` [T, D]
+    buffers (they stay over that axis), the ``blocked`` [block, D] ones,
+    and a tile's temporaries (the [block, block] ones, and some four
+    float32 [D, block] values of each of the ``widths``: operands,
+    accumulators).  Buffers are ``(width, bytes a number)`` as
+    :func:`_row_bytes` takes them; q and k are ``Dk`` wide, v and o
+    ``Dv``.  The backward holds 2.5 KB (bfloat16 in, D = 128) to 4 KB
+    (float32 in) a row: T = 4096 fits the 16 MiB default, T = 16384 at
+    D = 128 asks for 48-72 MiB, and the v5e's VMEM ends between T = 16384
+    (float32 in) and 32768 (bfloat16 in); the forward holds about half of
+    that.  At ``Dk`` 192, ``Dv`` 128 and float32 in, the backward holds
+    7 KB a row, 40 MiB in all at T = 4096 (what Mosaic asked for when
+    the limit stood at 38)."""
+    need = (_VMEM_TILES + t * _row_bytes(*resident) + block * (
+        _row_bytes(*blocked) + _row_bytes(*[(d, 4) for d in widths] * 4)))
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
         vmem_limit_bytes=(None if need <= _VMEM_DEFAULT
                           else min(need, _VMEM_MAX)))
+
+
+def _row_bytes(*buffers) -> int:
+    """Bytes one row of ``(width, bytes a number)`` buffers holds: an
+    input or output block is listed twice (it is double-buffered); a
+    width pads to whole 128-lane tiles."""
+    return sum(-(-d // _TILE) * _TILE * size for d, size in buffers)
 
 
 # ---------------------------------------------------------------------------
@@ -149,13 +163,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, ks_ref, vt_ref, *,
     ``[1, bq]`` (a handful of registers, reduced over sublanes on the VPU)
     and Oᵀ ``[D, bq]`` fills every lane where O ``[bq, 64]`` fills half.
 
-    Refs: q/o [1,1,bq,D]; k/v [1,1,T,D] (resident over the q-block axis,
-    which is sequential); lse [1,1,1,bq].  Scratch, filled at the first q
-    block of a (batch, head): the product operands K [T,D] and Vᵀ [D,T].
+    Refs: q [1,1,bq,Dk], o [1,1,bq,Dv]; k [1,1,T,Dk], v [1,1,T,Dv]
+    (resident over the q-block axis, which is sequential); lse
+    [1,1,1,bq].  Scratch, filled at the first q block of a (batch, head):
+    the product operands K [T,Dk] and Vᵀ [Dv,T].
     ``kv_len`` < T means the tail is alignment padding — masked out.
     Only tiles the diagonal or the padding edge crosses pay for the mask.
     """
-    bq, d = q_ref.shape[2], q_ref.shape[3]
+    bq, dv = q_ref.shape[2], v_ref.shape[3]
     t_k = k_ref.shape[2]
     padded = kv_len < t_k
     num_kb = t_k // block_k
@@ -172,7 +187,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, ks_ref, vt_ref, *,
             return 0
         lax.fori_loop(0, num_kb, fill, 0)
 
-    q = (q_ref[0, 0].astype(jnp.float32) * scale).astype(operand)  # [bq, D]
+    q = (q_ref[0, 0].astype(jnp.float32) * scale).astype(operand)  # [bq,Dk]
     # k_pos <= q_pos  <=>  row - col <= q0 - k0
     row = lax.broadcasted_iota(jnp.int32, (block_k, bq), 0)
     row_minus_col = row - lax.broadcasted_iota(jnp.int32, (block_k, bq), 1)
@@ -191,10 +206,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, ks_ref, vt_ref, *,
         p_t = jnp.exp(s_t - m_new)                              # [bk,bq]
         corr = jnp.exp(m - m_new)                               # [1,bq]
         l_new = l * corr + p_t.sum(axis=0, keepdims=True)
-        o_new = o_t * corr + _dot(vt_ref[:, rows], p_t, _NN, operand)  # [D,bq]
+        o_new = o_t * corr + _dot(vt_ref[:, rows], p_t, _NN, operand)  # [Dv,bq]
         return o_new, l_new, m_new
 
-    init = (jnp.zeros((d, bq), jnp.float32), jnp.zeros((1, bq), jnp.float32),
+    init = (jnp.zeros((dv, bq), jnp.float32), jnp.zeros((1, bq), jnp.float32),
             jnp.full((1, bq), _NEG_INF, jnp.float32))
     # K blocks [0, clear) lie wholly below the diagonal and inside kv_len:
     # no mask.  Blocks [clear, upper) cross one of the two; the rest are
@@ -217,32 +232,41 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, ks_ref, vt_ref, *,
 
 def _fwd(q, k, v, causal, block_q, block_k, interpret, kv_len,
          operand=jnp.float32):
-    """q/k/v: [B, H, T, D] → (o [B,H,T,D], lse [B,H,T,1])."""
-    b, h, t, d = q.shape
+    """q/k: [B, H, T, Dk], v: [B, H, T, Dv] → (o [B,H,T,Dv], lse
+    [B,H,T,1]); the scale is 1/√Dk."""
+    b, h, t, dk = q.shape
+    dv = v.shape[3]
     bq = _pick_block(t, block_q)
     bk = _pick_block(t, block_k)
-    scale = 1.0 / (d ** 0.5)
+    scale = 1.0 / (dk ** 0.5)
     kernel = functools.partial(_fwd_kernel, causal=causal, block_k=bk,
                                scale=scale, kv_len=kv_len, operand=operand)
-    qb_spec = pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi: (bi, hi, qi, 0))
-    full_spec = pl.BlockSpec((1, 1, t, d), lambda bi, hi, qi: (bi, hi, 0, 0))
+
+    in_size, op_size = q.dtype.itemsize, jnp.dtype(operand).itemsize
     o, lse = pl.pallas_call(
         kernel,
         grid=(b, h, t // bq),
-        in_specs=[qb_spec, full_spec, full_spec],
+        in_specs=[pl.BlockSpec((1, 1, bq, dk),
+                               lambda bi, hi, qi: (bi, hi, qi, 0)),
+                  pl.BlockSpec((1, 1, t, dk),
+                               lambda bi, hi, qi: (bi, hi, 0, 0)),
+                  pl.BlockSpec((1, 1, t, dv),
+                               lambda bi, hi, qi: (bi, hi, 0, 0))],
         out_specs=[
-            qb_spec,
+            pl.BlockSpec((1, 1, bq, dv), lambda bi, hi, qi: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, 1, bq), lambda bi, hi, qi: (bi, hi, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, t, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, 1, t), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((t, d), operand),
-                        pltpu.VMEM((d, t), operand)],
-        # k, v in (twice each); K and Vᵀ as operands
+        scratch_shapes=[pltpu.VMEM((t, dk), operand),
+                        pltpu.VMEM((dv, t), operand)],
+        # k, v in (twice each), K and Vᵀ as operands; q in, o out (twice)
         compiler_params=_compiler_params(
-            t, d, 4 * q.dtype.itemsize + 2 * jnp.dtype(operand).itemsize),
+            t, bq, [(dk, in_size)] * 2 + [(dv, in_size)] * 2
+            + [(dk, op_size), (dv, op_size)],
+            [(dk, in_size)] * 2 + [(dv, in_size)] * 2, (dk, dv)),
         interpret=interpret,
     )(q, k, v)
     return o, lse.reshape(b, h, t, 1)
@@ -264,11 +288,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dSᵀ = Pᵀ∘(dPᵀ − Δ)
         dVᵀ += dOᵀ·P   dKᵀ += (Qᵀ·scale)·dS   dQᵀ += Kᵀ·dSᵀ
 
-    Refs: q/do/dq [1,1,T,D] (resident over the k-block axis); k/v/dk/dv
-    [1,1,bk,D]; lse/Δ [1,1,1,T].  Scratch, filled at the first k block of
-    a (batch, head): the product operands Qᵀ·scale and dOᵀ [D,T] (rounded
-    and transposed once, not once a tile) and the float32 dQᵀ [D,T],
-    scaled, transposed and written back at the last k block.
+    Refs: q/dq [1,1,T,Dk] and do [1,1,T,Dv] (resident over the k-block
+    axis); k/dk [1,1,bk,Dk], v/dv [1,1,bk,Dv]; lse/Δ [1,1,1,T].  Scratch,
+    filled at the first k block of a (batch, head): the product operands
+    Qᵀ·scale [Dk,T] and dOᵀ [Dv,T] (rounded and transposed once, not once
+    a tile) and the float32 dQᵀ [Dk,T], scaled, transposed and written
+    back at the last k block.
     """
     bk = k_ref.shape[2]
     t_q = q_ref.shape[2]
@@ -289,9 +314,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         lax.fori_loop(0, num_qb, fill, 0)
         dqt_ref[...] = jnp.zeros_like(dqt_ref)
 
-    k = k_ref[0, 0].astype(operand)                          # [bk, D]
-    v = v_ref[0, 0].astype(operand)
-    k_t = k_ref[0, 0].astype(jnp.float32).T.astype(operand)  # [D, bk]
+    k = k_ref[0, 0].astype(operand)                          # [bk, Dk]
+    v = v_ref[0, 0].astype(operand)                          # [bk, Dv]
+    k_t = k_ref[0, 0].astype(jnp.float32).T.astype(operand)  # [Dk, bk]
     # k_pos <= q_pos  <=>  row - col <= q0 - k0
     row = lax.broadcasted_iota(jnp.int32, (bk, block_q), 0)
     row_minus_col = row - lax.broadcasted_iota(jnp.int32, (bk, block_q), 1)
@@ -300,8 +325,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def tile(masked, qb, carry):
         dk_t, dv_t = carry
         cols = _block(qb, block_q, num_qb)
-        qs_t = qst_ref[:, cols]                             # [D, bq]
-        do_t = dot_ref[:, cols]
+        qs_t = qst_ref[:, cols]                             # [Dk, bq]
+        do_t = dot_ref[:, cols]                             # [Dv, bq]
         s_t = _dot(k, qs_t, _NN, operand)                   # [bk, bq]
         if masked:
             mask = row_minus_col <= qb * block_q - k0 if causal else None
@@ -311,9 +336,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         p_t = jnp.exp(s_t - lse_ref[0, 0, :, cols])         # recomputed probs
         dp_t = _dot(v, do_t, _NN, operand)
         ds_t = (p_t * (dp_t - delta_ref[0, 0, :, cols])).astype(operand)
-        dv_t = dv_t + _dot(do_t, p_t, _NT, operand)         # [D, bk]
-        dk_t = dk_t + _dot(qs_t, ds_t, _NT, operand)
-        dqt_ref[:, cols] += _dot(k_t, ds_t, _NN, operand)   # [D, bq]
+        dv_t = dv_t + _dot(do_t, p_t, _NT, operand)         # [Dv, bk]
+        dk_t = dk_t + _dot(qs_t, ds_t, _NT, operand)        # [Dk, bk]
+        dqt_ref[:, cols] += _dot(k_t, ds_t, _NN, operand)   # [Dk, bq]
         return dk_t, dv_t
 
     # q blocks [lower, clear) cross the diagonal (or, every one of them,
@@ -325,8 +350,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         clear = jnp.minimum(lax.div(k0 + bk + block_q - 2, block_q), num_qb)
     if padded:
         clear = jnp.where(k0 + bk > kv_len, num_qb, clear)
-    zero = jnp.zeros(k_t.shape, jnp.float32)
-    carry = (zero, zero)
+    carry = (jnp.zeros(k_t.shape, jnp.float32),
+             jnp.zeros((v.shape[1], bk), jnp.float32))
     if causal or padded:
         carry = lax.fori_loop(lower, clear, functools.partial(tile, True),
                               carry)
@@ -348,10 +373,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret, kv_len,
          dlse=None, operand=jnp.float32):
-    b, h, t, d = q.shape
+    b, h, t, dk = q.shape
+    dv = v.shape[3]
     bq = _pick_block(t, block_q)
     bk = _pick_block(t, block_k)
-    scale = 1.0 / (d ** 0.5)
+    scale = 1.0 / (dk ** 0.5)
     # Δ_i = Σ_d dO_id · O_id — the softmax-normalization gradient term;
     # a cheap elementwise reduce, left to XLA fusion.  An lse cotangent
     # folds in here: dS_ij = P_ij (dP_ij − Δ_i + dlse_i), so passing
@@ -364,24 +390,33 @@ def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret, kv_len,
     delta = delta.reshape(b, h, 1, t)
     lse = lse.reshape(b, h, 1, t)
 
-    kb_spec = pl.BlockSpec((1, 1, bk, d), lambda bi, hi, i: (bi, hi, i, 0))
-    full_spec = pl.BlockSpec((1, 1, t, d), lambda bi, hi, i: (bi, hi, 0, 0))
+    def kb_spec(d):
+        return pl.BlockSpec((1, 1, bk, d), lambda bi, hi, i: (bi, hi, i, 0))
+
+    def full_spec(d):
+        return pl.BlockSpec((1, 1, t, d), lambda bi, hi, i: (bi, hi, 0, 0))
+
     row_spec = pl.BlockSpec((1, 1, 1, t), lambda bi, hi, i: (bi, hi, 0, 0))
+    in_size, op_size = q.dtype.itemsize, jnp.dtype(operand).itemsize
     return pl.pallas_call(
         functools.partial(_bwd_kernel, causal=causal, block_q=bq,
                           scale=scale, kv_len=kv_len, operand=operand),
         grid=(b, h, t // bk),
-        in_specs=[full_spec, kb_spec, kb_spec, full_spec, row_spec, row_spec],
-        out_specs=[full_spec, kb_spec, kb_spec],
-        out_shape=[jax.ShapeDtypeStruct((b, h, t, d), q.dtype),
-                   jax.ShapeDtypeStruct((b, h, t, d), k.dtype),
-                   jax.ShapeDtypeStruct((b, h, t, d), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((d, t), operand),
-                        pltpu.VMEM((d, t), operand),
-                        pltpu.VMEM((d, t), jnp.float32)],
-        # q, do in and dq out (twice each); Qᵀ, dOᵀ as operands; dQᵀ
+        in_specs=[full_spec(dk), kb_spec(dk), kb_spec(dv), full_spec(dv),
+                  row_spec, row_spec],
+        out_specs=[full_spec(dk), kb_spec(dk), kb_spec(dv)],
+        out_shape=[jax.ShapeDtypeStruct((b, h, t, dk), q.dtype),
+                   jax.ShapeDtypeStruct((b, h, t, dk), k.dtype),
+                   jax.ShapeDtypeStruct((b, h, t, dv), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((dk, t), operand),
+                        pltpu.VMEM((dv, t), operand),
+                        pltpu.VMEM((dk, t), jnp.float32)],
+        # q, do in and dq out (twice each), Qᵀ, dOᵀ as operands, dQᵀ;
+        # k, v in and dk, dv out (twice each)
         compiler_params=_compiler_params(
-            t, d, 6 * q.dtype.itemsize + 2 * jnp.dtype(operand).itemsize + 4),
+            t, bk, [(dk, in_size)] * 4 + [(dv, in_size)] * 2
+            + [(dk, op_size), (dv, op_size), (dk, 4)],
+            [(dk, in_size)] * 4 + [(dv, in_size)] * 4, (dk, dv)),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
@@ -436,8 +471,8 @@ def _product_operand(interpret: bool):
 
 
 def _pad_and_run(q, k, v, causal, block_q, block_k, interpret):
-    """[B,T,H,D] public layout → padded [B,H,T,D] kernel run → sliced
-    (o [B,T,H,D], lse [B,H,T])."""
+    """[B,T,H,D] public layout (q, k ``Dk`` wide, v ``Dv``) → padded
+    [B,H,T,D] kernel run → sliced (o [B,T,H,Dv], lse [B,H,T])."""
     t = q.shape[1]
     tp = _pad_len(t, interpret)
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))  # → [B,H,T,D]
@@ -456,7 +491,11 @@ def flash_attention(q, k, v, causal: bool = False, *,
                     block_q: int = _DEFAULT_BLOCK,
                     block_k: int = _DEFAULT_BLOCK,
                     interpret: Optional[bool] = None) -> jax.Array:
-    """Drop-in ``attn_fn(q, k, v, causal)`` on ``[B, T, H, D]`` tensors.
+    """Drop-in ``attn_fn(q, k, v, causal)``: q, k ``[B, T, H, Dk]``, v
+    ``[B, T, H, Dv]`` → ``[B, T, H, Dv]``; the scale is 1/√Dk.  The two
+    widths are usually one (D = 64 in the GPT-2 blocks); latent attention
+    decompresses keys 192 wide beside values 128 wide, and the same
+    kernels, block rule, transposed tiles and operand rounding serve both.
 
     Sequences whose length is not MXU-tileable are zero-padded to the next
     tileable length (masked inside the kernels; the pad is sliced off), so

@@ -220,3 +220,162 @@ def moe_ffn(params: dict, x: jax.Array, *,
     expert_out = a2a(jnp.einsum("egcf,efm->egcm", h, params["wo"]))
     y = jnp.einsum("gsec,egcm->gsm", combine, expert_out)
     return y, aux
+
+
+# ---------------------------------------------------------------------------
+# k of E routing with no token dropped, for the experts HELD here
+# ---------------------------------------------------------------------------
+def init_routed_moe_params(rng, d_model: int, d_expert: int,
+                           num_experts: int, *, experts_held: int = None,
+                           d_shared: int = 0, dtype=jnp.float32) -> dict:
+    """Router over all ``num_experts``, its selection bias, the SwiGLU
+    weights of the ``experts_held`` experts that live here (leading axis:
+    flag ``*/experts/*`` via ``expert_vars``) and, if ``d_shared``, one
+    dense SwiGLU of that width (the shared experts side by side)."""
+    held = num_experts if experts_held is None else experts_held
+    r = jax.random.split(rng, 8)
+
+    def normal(key, *shape, kind=dtype):
+        return jax.random.normal(key, shape, kind) * 0.02
+
+    params = {
+        "router": normal(r[0], d_model, num_experts, kind=jnp.float32),
+        "router_bias": normal(r[1], num_experts, kind=jnp.float32),
+        "experts": {"w_gate": normal(r[2], held, d_model, d_expert),
+                    "w_up": normal(r[3], held, d_model, d_expert),
+                    "w_down": normal(r[4], held, d_expert, d_model)},
+    }
+    if d_shared:
+        params["shared"] = {"w_gate": normal(r[5], d_model, d_shared),
+                            "w_up": normal(r[6], d_model, d_shared),
+                            "w_down": normal(r[7], d_shared, d_model)}
+    return params
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_rows(x, index, inverse, fan: int):
+    """``x[index // fan]`` where ``index`` is a permutation of
+    ``fan * len(x)`` places and ``inverse`` its inverse: every row goes to
+    ``fan`` places, so the cotangent comes back by a gather through
+    ``inverse`` and a sum over ``fan``, never by a scatter."""
+    return jnp.take(x, index // fan if fan > 1 else index, axis=0)
+
+
+def _take_rows_fwd(x, index, inverse, fan):
+    return _take_rows(x, index, inverse, fan), inverse
+
+
+def _take_rows_bwd(fan, inverse, g):
+    back = jnp.take(g, inverse, axis=0)
+    return (back.reshape(-1, fan, g.shape[-1]).sum(axis=1), None, None)
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def swiglu(w: dict, x: jax.Array) -> jax.Array:
+    """``w_down(silu(w_gate x) * w_up x)``."""
+    return (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"]
+
+
+def routed_rows(tokens: int, top_k: int, held: int, total: int):
+    """(rows the grouped products are handed, rows expected to be routed
+    here) for one call of :func:`routed_moe_ffn` over ``tokens`` tokens:
+    every pick of every token has its row, because all of a token's picks
+    may lie here; ``held / total`` of them do if the router spreads
+    evenly."""
+    return tokens * top_k, tokens * top_k * held / total
+
+
+def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
+                   experts_held: Optional[Tuple[int, int]] = None,
+                   routed_scale: float = 1.0, train_router: bool = True
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """``k`` of ``E`` sigmoid-routed experts with NO token dropped, for the
+    experts this chip holds (DeepSeek-V3's layer, arxiv 2412.19437 §2.1.2,
+    ``topk_method`` noaux_tc with one group):
+
+        s = sigmoid(x W_r)          over all E experts, float32, highest
+        S = top-k of (s + b)        b: the selection bias, no gradient
+        g_e = routed_scale * s_e / sum_{j in S} s_j        for e in S
+        y = Shared(x) + sum_{e in S and held} g_e E_e(x)
+
+    ``experts_held = (first, count)``: ``params["experts"]`` leaves lead
+    with ``count`` experts, which are experts ``first .. first + count``
+    of the router's ``E``; the weights are normalised over all ``k`` picks
+    and what the absent experts would add is left out (under expert
+    parallelism it arrives by the exchange, which this function does not
+    make).  None: all ``E`` are held.  ``train_router=False`` gives
+    ``W_r`` no gradient (the scores still pass theirs on to ``x``): the
+    selection then stays what the weights at hand make it, which is what
+    a job wants whose optimizer would otherwise walk every token onto the
+    same experts before any balancing could act.
+
+    Static shapes without a capacity: the ``N * k`` (token, pick) pairs
+    are sorted by local expert, the picks of absent experts last, rows are
+    gathered in that order and go through three grouped products
+    (``jax.lax.ragged_dot``, on a TPU one Mosaic kernel each that leaves
+    the row tiles past the last group alone), then come back to token
+    order by a gather and are summed with their weights.  Whatever the
+    routing, every pick of a held expert is computed: with all tokens on
+    the held experts the groups fill all ``N * k`` rows.
+
+    Returns ``(y, tokens_per_expert [count] int32)``.
+    """
+    from autodist_tpu.telemetry import registry, timeline
+
+    lead, d = x.shape[:-1], x.shape[-1]
+    h = x.reshape(-1, d)
+    n = h.shape[0]
+    total = params["router"].shape[-1]
+    experts = params["experts"]
+    first, count = experts_held or (0, total)
+    if experts["w_gate"].shape[0] != count or first + count > total:
+        raise ValueError(
+            f"experts_held={experts_held} of {total}, but the expert "
+            f"leaves lead with {experts['w_gate'].shape[0]}")
+    registry.gauge("autodist_moe_experts_held",
+                   "experts of a routed layer computed here").set(count)
+    registry.gauge("autodist_moe_experts_total",
+                   "experts its router chooses among").set(total)
+
+    router = params["router"].astype(jnp.float32)
+    if not train_router:
+        router = jax.lax.stop_gradient(router)
+    with jax.named_scope(timeline.SCOPE_MOE_ROUTE):
+        scores = jax.nn.sigmoid(jnp.dot(
+            h.astype(jnp.float32), router,
+            precision=jax.lax.Precision.HIGHEST))
+        _, chosen = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(params["router_bias"]), top_k)
+        picked = jnp.take_along_axis(scores, chosen, axis=-1)   # [N, k]
+        gates = routed_scale * picked / picked.sum(-1, keepdims=True)
+        local = chosen - first
+        here = (local >= 0) & (local < count)
+        group = jnp.where(here, local, count).reshape(-1)       # [N * k]
+        order = jnp.argsort(group, stable=True)   # rows by local expert
+        inverse = jnp.argsort(order)              # where each pick went
+        sizes = jnp.sum(group[:, None] == jnp.arange(count), axis=0,
+                        dtype=jnp.int32)
+        live = (jnp.arange(n * top_k) < sizes.sum())[:, None]
+
+    with jax.named_scope(timeline.SCOPE_MOE_EXPERTS):
+        rows = jnp.where(live, _take_rows(h, order, inverse, top_k), 0)
+        hidden = (jax.nn.silu(jax.lax.ragged_dot(
+            rows, experts["w_gate"].astype(h.dtype), sizes))
+            * jax.lax.ragged_dot(rows, experts["w_up"].astype(h.dtype),
+                                 sizes))
+        out = jax.lax.ragged_dot(hidden, experts["w_down"].astype(h.dtype),
+                                 sizes)
+
+    with jax.named_scope(timeline.SCOPE_MOE_COMBINE):
+        back = _take_rows(out, inverse, order, 1).reshape(n, top_k, d)
+        weight = jnp.where(here, gates, 0.0).astype(h.dtype)
+        # what lies past the last group is never read as a number
+        y = jnp.sum(jnp.where(here[..., None], back, 0)
+                    * weight[..., None], axis=1)
+
+    if "shared" in params:
+        with jax.named_scope(timeline.SCOPE_MOE_SHARED):
+            y = y + swiglu(params["shared"], h)
+    return y.reshape(*lead, d), sizes

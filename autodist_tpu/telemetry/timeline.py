@@ -353,6 +353,19 @@ COMPILE_TRACE = "compile/trace"              # ids: fun_name
 COMPILE_LOWER = "compile/lower"
 COMPILE_BACKEND = "compile/backend"
 
+#: scopes INSIDE traced code (``jax.named_scope``, not host spans: they
+#: name the device operations of a step, and a Pallas call takes the last
+#: part as its HLO name): the parts of a latent-attention block
+#: (``models/mla_moe_lm.py``) and of the routed expert layer
+#: (``parallel/moe.py: routed_moe_ffn``).  ``benchmark/metrics/
+#: moe_routed_device_pct.py`` matches the ``moe/`` ones but ``moe/shared``.
+SCOPE_MLA_PROJECT = "mla/project"        # q, kv_a, kv_b, rotary, out
+SCOPE_MLA_ATTENTION = "mla/attention"    # the attention call alone
+SCOPE_MOE_ROUTE = "moe/route"            # scores, top-k, sort, group sizes
+SCOPE_MOE_SHARED = "moe/shared"          # the shared experts (dense)
+SCOPE_MOE_EXPERTS = "moe/experts"        # gather, grouped products, SwiGLU
+SCOPE_MOE_COMBINE = "moe/combine"        # back to token order, weighted sum
+
 #: jax's monitoring events -> the span each becomes (and its ``stage``
 #: label on ``autodist_compile_seconds_total``).
 _COMPILE_STAGES = {

@@ -111,9 +111,10 @@ def _rel_err(got, want) -> float:
 
 def check_flash(shape, dtype, causal: bool, *, interpret: bool, tol: float,
                 ref_batch: int = 2) -> dict:
-    """Flash attention forward, dQ and dK/dV at ``shape`` ([B, T, H, D])
-    against dense attention in float32 on the first ``ref_batch`` rows (a
-    batch dense attention can hold: it materializes [B, H, T, T])."""
+    """Flash attention forward, dQ and dK/dV at ``shape`` ([B, T, H, D], or
+    [B, T, H, Dk, Dv] where values are narrower than keys) against dense
+    attention in float32 on the first ``ref_batch`` rows (a batch dense
+    attention can hold: it materializes [B, H, T, T])."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -122,8 +123,10 @@ def check_flash(shape, dtype, causal: bool, *, interpret: bool, tol: float,
     from autodist_tpu.ops.flash_attention import flash_attention
 
     rng = np.random.RandomState(SEED)
-    q, k, v, w = (rng.randn(*shape).astype(np.float32) * 0.5
-                  for _ in range(4))     # w: a fixed cotangent
+    q, k = (rng.randn(*shape[:4]).astype(np.float32) * 0.5
+            for _ in range(2))
+    v, w = (rng.randn(*shape[:3], shape[-1]).astype(np.float32) * 0.5
+            for _ in range(2))           # w: a fixed cotangent
 
     def out_and_grads(attn, q, k, v, w):
         out, pullback = jax.vjp(attn, q, k, v)
@@ -371,15 +374,11 @@ def make_lm(lm: dict, dtype):
     return spec, jax.jit(spec.init)(jax.random.PRNGKey(SEED))
 
 
-def train_phase(spec, params, *, strategy: str, mesh_axes: dict,
-                batch_size: int, steps: int) -> dict:
-    """``steps`` calls of ``sess.run`` on one fixed host batch (placed by
-    ``run``).  Every loss finite, the last lower than the first; on TPU
-    devices the compiled step must hold the Pallas attention calls at the
-    per-device batch and heads, and every device must hold state (on the
-    CPU mesh the default attention is dense: no such call may appear)."""
+def open_session(spec, params, strategy: str, mesh_axes: dict, **capture):
+    """``(AutoDist, session)`` the normal way in: ``capture`` under
+    ``adamw(1e-3)``, then ``create_distributed_session`` on the first
+    devices the mesh needs."""
     import jax
-    import numpy as np
     import optax
 
     from autodist_tpu import strategy as strategies
@@ -393,9 +392,26 @@ def train_phase(spec, params, *, strategy: str, mesh_axes: dict,
                   mesh_axes=mesh_axes)
     with ad.scope():
         ad.capture(params=params, optimizer=optax.adamw(1e-3),
-                   loss_fn=spec.loss_fn, sparse_vars=spec.sparse_vars)
-    sess = ad.create_distributed_session(
+                   loss_fn=spec.loss_fn, sparse_vars=spec.sparse_vars,
+                   **capture)
+    return ad, ad.create_distributed_session(
         mesh=build_mesh(mesh_axes, devices=devices))
+
+
+def train_phase(spec, params, *, strategy: str, mesh_axes: dict,
+                batch_size: int, steps: int) -> dict:
+    """``steps`` calls of ``sess.run`` on one fixed host batch (placed by
+    ``run``).  Every loss finite, the last lower than the first; on TPU
+    devices the compiled step must hold the Pallas attention calls at the
+    per-device batch and heads, and every device must hold state (on the
+    CPU mesh the default attention is dense: no such call may appear)."""
+    import jax
+    import numpy as np
+
+    from autodist_tpu.autodist import _reset_default_autodist_for_testing
+
+    devices = jax.devices()[:math.prod(mesh_axes.values())]
+    ad, sess = open_session(spec, params, strategy, mesh_axes)
     batch = spec.sample_batch(batch_size, seed=SEED)
     losses = [float(sess.run(batch)["loss"]) for _ in range(steps)]
     if not np.all(np.isfinite(losses)):
@@ -428,6 +444,56 @@ def train_phase(spec, params, *, strategy: str, mesh_axes: dict,
         facts["bytes_in_use"] = memory_in_use(devices)
     elif attn:
         raise AssertionError("unexpected Pallas call off the TPU")
+    del sess, ad
+    _reset_default_autodist_for_testing()
+    gc.collect()
+    return facts
+
+
+def train_moe_phase(model: dict, *, batch_size: int, steps: int) -> dict:
+    """``models/mla_moe_lm.py`` (latent attention, routed experts of which
+    this chip holds a share) through ``capture(has_aux=True)``: ``steps``
+    calls of ``sess.run`` on one fixed batch.  Every loss finite, the last
+    lower than the first; the per-expert token counts come back with every
+    step and add up to no more than every pick of every token; on a TPU the
+    compiled step holds the Pallas attention calls one sequence at a time
+    with keys wider than values."""
+    import jax
+    import numpy as np
+
+    from autodist_tpu.autodist import _reset_default_autodist_for_testing
+    from autodist_tpu.models.mla_moe_lm import mla_moe_lm
+
+    spec = mla_moe_lm(**model, return_counts=True)
+    ad, sess = open_session(
+        spec, jax.jit(spec.init)(jax.random.PRNGKey(SEED)), "AllReduce",
+        {"data": 1}, expert_vars=spec.expert_vars, has_aux=True)
+    batch = spec.sample_batch(batch_size, seed=SEED)
+    outs = [sess.run(batch) for _ in range(steps)]
+    losses = [float(o["loss"]) for o in outs]
+    if not np.all(np.isfinite(losses)) or not losses[-1] < losses[0]:
+        raise AssertionError(f"loss not finite or did not fall: {losses}")
+    cfg = spec.config
+    layers = cfg["num_layers"] - cfg["first_dense"]
+    picks = batch["tokens"].size * cfg["top_k"]
+    counts = np.asarray(outs[-1]["aux"]["tokens_per_expert"])
+    if counts.shape != (layers, cfg["experts_held"][1]) \
+            or not 0 < counts.sum() <= layers * picks:
+        raise AssertionError(f"tokens per expert: {counts.tolist()}")
+    facts = {"losses": [round(x, 4) for x in losses],
+             "tokens_per_expert": counts.tolist(),
+             "share_of_picks_here": round(
+                 float(counts.sum()) / (layers * picks), 4)}
+    if jax.devices()[0].platform == "tpu":
+        attn = [[dims for _, dims in shapes] for shapes in pallas_call_shapes(
+            sess.lower_step(batch).compile().as_text())
+            if any(len(dims) == 4 for _, dims in shapes)]
+        widths = {dims[3] for call in attn for dims in call if len(dims) == 4}
+        if not attn or not {cfg["qk_nope"] + cfg["qk_rope"],
+                            cfg["v_head"]} <= widths:
+            raise AssertionError(f"attention custom calls work on {attn}")
+        facts["attention_calls"] = len(attn)
+        facts["bytes_in_use"] = memory_in_use(jax.devices()[:1])
     del sess, ad
     _reset_default_autodist_for_testing()
     gc.collect()
@@ -664,13 +730,19 @@ def serve_slots_phase(spec, params, *, sizes: dict, engine: dict) -> dict:
 # ---------------------------------------------------------------------------
 
 FULL_KERNELS = dict(
-    # the training shape, and the full (non-causal) mask in float32
+    # the training shape, the full (non-causal) mask in float32,
+    # and latent attention's widths (keys 192, values 128), one sequence
     flash=[((8, 2048, 12, 64), "bfloat16", True, 3e-2),
-           ((2, 512, 4, 64), "float32", False, 2e-2)],
+           ((2, 512, 4, 64), "float32", False, 2e-2),
+           ((1, 4096, 32, 192, 128), "float32", True, 2e-2)],
     matmul_shapes=[(8, 768, 3072), (8 * 1024, 768, 3072)],
     bucket_elems=1 << 20,            # a 4 MiB float32 bucket
     paged=dict(slots=8, heads=12, head_dim=64, block_size=32,
                blocks_per_slot=64))
+# kanana-2-30b-a3b's widths (benchmark/configs/kanana-2-30b-a3b.ep8-share
+# .json), the leading dense layer and one expert layer, 16 of 128 experts
+FULL_MLA_MOE = dict(vocab_size=16032, num_layers=2, experts_held=(0, 16),
+                    seq_len=2048, xent_chunk=5376)
 FULL_SIZES = dict(p=64, prefix=512, tails=(40, 100), long=1024, mid=333,
                   n=(32, 48, 96, 128))
 FULL_ENGINE = dict(slots=8, window=2048, block_size=32, chunk=16)
@@ -717,6 +789,8 @@ def main() -> int:
     run_phase(watch, "train_1chip", train_phase, spec, params,
               strategy="AllReduce", mesh_axes={"data": 1}, batch_size=8,
               steps=5)
+    run_phase(watch, "train_mla_moe", train_moe_phase, FULL_MLA_MOE,
+              batch_size=2, steps=4)
     run_phase(watch, "serve_paged", serve_paged_phase, spec, params,
               sizes=FULL_SIZES, engine=FULL_ENGINE)
     run_phase(watch, "serve_slots", serve_slots_phase, spec, params,

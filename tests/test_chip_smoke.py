@@ -27,7 +27,8 @@ TINY_ENGINE = dict(slots=2, window=32, block_size=8, num_blocks=24, chunk=4)
 
 def test_kernels_phase_interpreted():
     chip_smoke.kernels_phase(
-        flash=[((1, 16, 2, 8), "float32", True, 1e-5)],
+        flash=[((1, 16, 2, 8), "float32", True, 1e-5),
+               ((1, 16, 2, 12, 8), "float32", True, 1e-5)],
         matmul_shapes=[(8, 64, 128)], bucket_elems=8192,
         paged=dict(slots=2, heads=2, head_dim=8, block_size=8,
                    blocks_per_slot=2),
@@ -45,6 +46,17 @@ def test_train_phase_on_cpu_mesh(lm):
         steps=2)
     assert facts["mesh"] == {"data": 4}
     assert facts["losses"][-1] < facts["losses"][0]
+
+
+def test_train_moe_phase_returns_the_counts():
+    facts = chip_smoke.train_moe_phase(
+        dict(vocab_size=61, num_layers=2, d_model=32, num_heads=2,
+             qk_nope=8, qk_rope=4, v_head=8, kv_lora=16, d_ff=48,
+             d_expert=12, num_experts=16, experts_held=(4, 4), top_k=3,
+             seq_len=16), batch_size=4, steps=3)
+    assert facts["losses"][-1] < facts["losses"][0]
+    assert len(facts["tokens_per_expert"]) == 1
+    assert 0 < facts["share_of_picks_here"] <= 1
 
 
 def test_serve_phases_over_http(lm):

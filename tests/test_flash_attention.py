@@ -428,3 +428,82 @@ def test_grad_lowers_to_two_pallas_calls_with_float32_boundaries():
     for ln in calls:
         types = re.findall(r"tensor<[0-9x]*x([a-z]+[0-9]+)>", ln)
         assert types and set(types) == {"f32"}, ln
+
+
+# ---------------------------------------------------------------------------
+# keys wider than values (latent attention: Dk 192, Dv 128; here 24 / 16)
+# ---------------------------------------------------------------------------
+_WIDE_KEYS = {
+    # name: (t, block_q, block_k)
+    "one_tile": (16, 16, 16),
+    "four_blocks_a_side": (32, 8, 8),
+    "padded_to_a_tile": (21, 8, 8),        # the public op pads T to 24
+}
+
+
+def _qk_wider_than_v(seed, t, dk=24, dv=16):
+    rng = np.random.default_rng(seed)
+    q, k = (jnp.asarray(rng.standard_normal((2, t, 2, dk)), jnp.float32)
+            for _ in range(2))
+    v, w = (jnp.asarray(rng.standard_normal((2, t, 2, dv)), jnp.float32)
+            for _ in range(2))
+    u = jnp.asarray(rng.standard_normal((2, 2, t)), jnp.float32)
+    return q, k, v, w, u
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("case", sorted(_WIDE_KEYS))
+def test_keys_wider_than_values_match_dense(case, causal):
+    """``q, k: [B,T,H,24]``, ``v: [B,T,H,16]`` -> ``[B,T,H,16]``: the
+    forward, ``lse`` (scaled by 1/sqrt(24)), and all three gradients of a
+    loss that uses both outputs, against dense attention."""
+    from autodist_tpu.ops.flash_attention import flash_attention_with_lse
+
+    t, bq, bk = _WIDE_KEYS[case]
+    q, k, v, w, u = _qk_wider_than_v(20, t)
+
+    def dense_lse(q, k):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+        if causal:
+            s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -1e30)
+        return jax.nn.logsumexp(s, axis=-1)
+
+    def loss_flash(q, k, v):
+        o, lse = flash_attention_with_lse(q, k, v, causal, block_q=bq,
+                                          block_k=bk)
+        return jnp.sum(o * w) + jnp.sum(lse * u)
+
+    def loss_dense(q, k, v):
+        return jnp.sum(dense_attention(q, k, v, causal) * w) + jnp.sum(
+            dense_lse(q, k) * u)
+
+    o, lse = flash_attention_with_lse(q, k, v, causal, block_q=bq,
+                                      block_k=bk)
+    assert o.shape == v.shape and lse.shape == (2, 2, t)
+    np.testing.assert_allclose(o, dense_attention(q, k, v, causal),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(lse, dense_lse(q, k), rtol=2e-5, atol=2e-5)
+    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    g_dense = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    for gf, gd, name in zip(g_flash, g_dense, "qkv"):
+        assert gf.shape == gd.shape
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gd),
+                                   rtol=1e-4, atol=1e-4, err_msg=f"d{name}")
+
+
+def test_vmem_estimate_counts_both_widths():
+    """At one width of 64 the limit stays Mosaic's default, as before the
+    kernels took two widths (the gpt2 cell compiles what it compiled); at
+    192 / 128 over 4096 rows the backward asks for the 40 MiB Mosaic
+    wanted when the estimate stood at 38."""
+    fa = _module()
+
+    def limit(t, dk, dv, block=512):
+        return fa._compiler_params(
+            t, block, [(dk, 4)] * 4 + [(dv, 4)] * 2
+            + [(dk, 2), (dv, 2), (dk, 4)],
+            [(dk, 4)] * 4 + [(dv, 4)] * 4, (dk, dv)).vmem_limit_bytes
+
+    assert limit(1024, 64, 64) is None
+    assert limit(4096, 192, 128) >= 40.1 * 2 ** 20
+    assert fa._row_bytes((64, 4), (192, 2)) == 128 * 4 + 256 * 2

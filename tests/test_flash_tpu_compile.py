@@ -54,6 +54,11 @@ _SHAPES = {
     "padded_blocks": ((2, 1000, 4, 64), jnp.bfloat16, False),  # pads to 1024
     "uneven_blocks": ((1, 2176, 2, 64), jnp.float32, True),    # 17 x 128
     "long_wide": ((1, 8192, 8, 128), jnp.bfloat16, True),  # raised VMEM limit
+    # keys wider than values ([B, T, H, Dk, Dv]): latent attention at the
+    # kanana cell's widths, one sequence (the model's call) and the batch
+    "mla_one_sequence": ((1, 4096, 32, 192, 128), jnp.float32, True),
+    "mla_batch": ((4, 4096, 32, 192, 128), jnp.float32, True),
+    "mla_padded": ((2, 1000, 4, 192, 128), jnp.float32, False),
 }
 
 
@@ -67,7 +72,9 @@ _CASES = [(name, precision) for name in sorted(_SHAPES)
 @pytest.mark.parametrize("name,precision", _CASES)
 def test_forward_and_fused_backward_compile(one_chip, name, precision):
     shape, dtype, causal = _SHAPES[name]
-    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    x = jax.ShapeDtypeStruct(shape[:4], dtype, sharding=one_chip)
+    v = jax.ShapeDtypeStruct(shape[:3] + shape[-1:], dtype,
+                             sharding=one_chip)
 
     def loss(q, k, v):   # both outputs used: the lse cotangent path too
         o, lse = flash_attention_with_lse(q, k, v, causal, interpret=False)
@@ -75,7 +82,7 @@ def test_forward_and_fused_backward_compile(one_chip, name, precision):
 
     with jax.default_matmul_precision(precision):
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-            x, x, x).compile()
+            x, x, v).compile()
     assert len(_pallas_calls(compiled)) == 2     # forward, fused backward
 
 

@@ -1,0 +1,233 @@
+"""``models/mla_moe_lm.py`` and ``parallel/moe.py: routed_moe_ffn`` against
+the plain reference ``benchmark/reference/deepseek_v3.py`` (CPU, tiny
+widths, seeded weights).
+
+Tolerances.  A CPU multiplies float32 exactly, so the program and the
+reference differ by the ORDER of their float32 sums alone (a gather and a
+grouped product against a masked sum over all tokens, a fused attention
+against row blocks): relative 1e-6 a product, a few 1e-6 after three
+layers and a backward pass.  ``RTOL`` 2e-5 is ten times what they read
+(1e-7 to 2e-6); anything left out (an expert's pick, a shared expert, the
+rotary turn, a norm) moves a leaf by 1e-2 or more.  No seed here has two
+scores within 1e-6 of a tie at the sixth place, so the selection itself is
+the same on both sides.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from autodist_tpu.models.mla_moe_lm import mla_moe_lm, rotary
+from autodist_tpu.models.transformer import dense_attention
+from autodist_tpu.parallel.moe import (
+    init_routed_moe_params,
+    routed_moe_ffn,
+    routed_rows,
+)
+from benchmark.reference import deepseek_v3 as ref
+
+RTOL = 2e-5
+TINY = dict(vocab_size=61, num_layers=3, first_dense=1, d_model=32,
+            num_heads=2, qk_nope=8, qk_rope=4, v_head=8, kv_lora=16,
+            d_ff=48, d_expert=12, num_experts=16, top_k=3,
+            shared_experts=2, seq_len=32, attn_fn=dense_attention)
+
+
+def settings(first_held=0, top_k=3, train_router=True):
+    return ref.Settings(top_k=top_k, routed_scale=2.448,
+                        first_held=first_held, qk_nope=8, theta=1e6,
+                        eps=1e-6, train_router=train_router)
+
+
+def seeded(shapes, seed):
+    """``benchmark/weights.py``'s rule: normal(0, 0.02), scales 1."""
+    from benchmark import weights
+
+    return weights.make_weights(shapes, seed)
+
+
+def rel(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.linalg.norm(got - want)
+                 / max(np.linalg.norm(want), 1e-30))
+
+
+def tokens(seed, rows=4, t=32, vocab=61):
+    return np.random.default_rng(seed).integers(
+        0, vocab, (rows, t), dtype=np.int32)
+
+
+@pytest.mark.parametrize("held,remat,chunk,train_router", [
+    # a share, rematerialised, chunked head, routers not trained: the
+    # benchmark's configuration at test size
+    ((4, 4), "full", 32, False),
+    (None, "none", None, True),      # all 16 experts held, plain head
+    ((0, 16), "dots", None, True),
+])
+def test_loss_and_every_gradient_match_the_reference(held, remat, chunk,
+                                                     train_router):
+    spec = mla_moe_lm(**TINY, experts_held=held, remat=remat,
+                      xent_chunk=chunk, train_router=train_router)
+    params = seeded(jax.eval_shape(spec.init, jax.random.key(0)), 7)
+    batch = tokens(1)
+    with jax.default_matmul_precision("highest"):
+        loss, grads = jax.jit(jax.value_and_grad(spec.loss_fn))(
+            params, {"tokens": batch})
+        want_loss, want = ref.loss_and_grads(
+            params, jnp.asarray(batch), row_block=2,
+            s=settings(held[0] if held else 0, train_router=train_router))
+    assert abs(float(loss) - float(want_loss)) < RTOL
+    got, want = ref._flat(grads, np.asarray), ref._flat(want, np.asarray)
+    assert set(got) == set(want) and len(got) == 43
+    for name in want:
+        if name.endswith("router_bias") or (   # selects, takes no gradient
+                name.endswith("/router") and not train_router):
+            assert not np.any(got[name]) and not np.any(want[name])
+        elif name.endswith("/router"):
+            assert np.any(got[name]) and rel(got[name], want[name]) < RTOL
+        else:
+            assert rel(got[name], want[name]) < RTOL, name
+
+
+def test_rotary_turns_interleaved_pairs_and_keeps_products_relative():
+    x = jax.random.normal(jax.random.key(0), (1, 6, 2, 4))
+    y = rotary(x, 1e6)
+    np.testing.assert_allclose(y[:, 0], x[:, 0], atol=1e-7)   # position 0
+    np.testing.assert_allclose(                # a turn keeps every pair's norm
+        jnp.sum(y.reshape(1, 6, 2, 2, 2) ** 2, -1),
+        jnp.sum(x.reshape(1, 6, 2, 2, 2) ** 2, -1), rtol=1e-5)
+    # q_s . k_t depends on t - s alone
+    q = jnp.broadcast_to(x[:, :1], x.shape)
+    dots = jnp.einsum("bshd,bthd->bhst", rotary(q, 1e6), rotary(q, 1e6))
+    np.testing.assert_allclose(dots[0, 0, 0, 2], dots[0, 0, 3, 5],
+                               rtol=1e-5)
+    np.testing.assert_allclose(ref._rotary(x, 1e6), y, atol=1e-6)
+
+
+def moe_layer(seed, held_count=16, d=32, f=12, total=16):
+    params = init_routed_moe_params(jax.random.key(seed), d, f, total,
+                                    experts_held=held_count, d_shared=2 * f)
+    return jax.tree_util.tree_map(lambda a: a * 8.0, params)   # lively
+
+
+def share_of(params, first, count):
+    """The leaves one chip of the deployment holds: all of the router and
+    of the shared experts, ``count`` of the experts."""
+    return dict(params, experts=jax.tree_util.tree_map(
+        lambda a: a[first:first + count], params["experts"]))
+
+
+def test_eight_shares_add_up_to_the_whole_layer():
+    """Eight chips hold 2 of 16 experts each.  What each computes for its
+    own experts, with the shared experts (which every chip computes alike)
+    counted once, adds up to the uncut reference's layer output."""
+    params = moe_layer(3)
+    x = jax.random.normal(jax.random.key(4), (2, 24, 32))
+    whole = ref.moe_ffn(x, params, s=settings(top_k=4))
+    shared = ref._swiglu(x, params["shared"], None)
+    parts, counts = [], []
+    for chip in range(8):
+        y, n = routed_moe_ffn(share_of(params, 2 * chip, 2), x, top_k=4,
+                              experts_held=(2 * chip, 2),
+                              routed_scale=2.448)
+        parts.append(y - shared)
+        counts.append(n)
+        # and each share is the reference's share
+        want = ref.moe_ffn(x, share_of(params, 2 * chip, 2),
+                           s=settings(2 * chip, top_k=4))
+        assert rel(y, want) < RTOL
+    assert rel(sum(parts) + shared, whole) < RTOL
+    assert int(jnp.concatenate(counts).sum()) == 2 * 24 * 4   # every pick
+
+
+@pytest.mark.parametrize("where", ["all_on_held", "none_on_held"])
+def test_no_token_is_dropped_whatever_the_routing(where):
+    """A selection bias that sends every pick of every token to the held
+    experts fills all ``N * k`` rows of the grouped products, and nothing
+    is dropped; one that sends every pick elsewhere leaves the routed part
+    zero.  Both match the reference."""
+    params = moe_layer(5, held_count=4)
+    bias = jnp.full((16,), -10.0).at[4:8].set(10.0)
+    if where == "none_on_held":
+        bias = -bias
+    params["router_bias"] = bias
+    x = jax.random.normal(jax.random.key(6), (48, 32))
+    y, counts = routed_moe_ffn(params, x, top_k=3, experts_held=(4, 4),
+                               routed_scale=2.448)
+    want = ref.moe_ffn(x, params, s=settings(4))
+    assert rel(y, want) < RTOL
+    if where == "all_on_held":
+        assert int(counts.sum()) == 48 * 3 == routed_rows(48, 3, 4, 16)[0]
+        assert int(counts.min()) > 0
+    else:
+        assert int(counts.sum()) == 0
+        assert rel(y, ref._swiglu(x, params["shared"], None)) < RTOL
+    # the gradient through the gathers comes back to every token
+    g = jax.grad(lambda x: jnp.sum(routed_moe_ffn(
+        params, x, top_k=3, experts_held=(4, 4),
+        routed_scale=2.448)[0] ** 2))(x)
+    g_ref = jax.grad(lambda x: jnp.sum(
+        ref.moe_ffn(x, params, s=settings(4)) ** 2))(x)
+    assert rel(g, g_ref) < RTOL
+
+
+def test_routed_layer_refuses_a_share_its_leaves_do_not_hold():
+    params = moe_layer(1, held_count=4)
+    x = jnp.zeros((8, 32))
+    with pytest.raises(ValueError, match="experts_held"):
+        routed_moe_ffn(params, x, top_k=3, experts_held=(0, 8))
+    with pytest.raises(ValueError, match="experts_held"):
+        routed_moe_ffn(params, x, top_k=3, experts_held=(14, 4))
+
+
+def test_three_session_steps_match_the_reference_adamw():
+    """Through ``AutoDist.capture(has_aux=True) -> create_distributed_
+    session -> run``: three steps' losses and the parameters after them
+    against the reference's gradients under AdamW written out; the
+    per-expert token counts come back with every step; the layer's gauges
+    are set when it is traced."""
+    from autodist_tpu import strategy as strategies
+    from autodist_tpu.autodist import (AutoDist,
+                                       _reset_default_autodist_for_testing)
+    from autodist_tpu.mesh import build_mesh
+    from autodist_tpu.telemetry.registry import DEFAULT_REGISTRY
+
+    spec = mla_moe_lm(**TINY, experts_held=(4, 4), return_counts=True)
+    params = seeded(jax.eval_shape(spec.init, jax.random.key(0)), 11)
+    batches = [jnp.asarray(tokens(20 + i)) for i in range(3)]
+    with jax.default_matmul_precision("highest"):
+        want_losses, _, want_delta, _ = ref.train_steps(
+            params, batches, row_block=4, s=settings(4))
+
+        _reset_default_autodist_for_testing()
+        ad = AutoDist(strategy_builder=strategies.AllReduce(),
+                      mesh_axes={"data": 1})
+        with ad.scope():
+            ad.capture(params=params, optimizer=optax.adamw(1e-3),
+                       loss_fn=spec.loss_fn, sparse_vars=spec.sparse_vars,
+                       expert_vars=spec.expert_vars, has_aux=True)
+        sess = ad.create_distributed_session(
+            mesh=build_mesh({"data": 1}, devices=jax.devices()[:1]))
+        outs = [sess.run({"tokens": np.asarray(b)}) for b in batches]
+        delta = ref.flatten(ref.leaf_diff_norms(
+            sess.export_state()[0], params))
+    _reset_default_autodist_for_testing()
+    for out, want in zip(outs, want_losses):
+        assert abs(float(out["loss"]) - want) < RTOL
+        counts = np.asarray(out["aux"]["tokens_per_expert"])
+        assert counts.shape == (2, 4)          # expert layers x held
+        assert 0 < counts.sum() <= 2 * 4 * 32 * 3
+    # AdamW's first steps move every element by about lr whatever its
+    # gradient, so the norms of the change agree far closer than RTOL
+    for name, want in want_delta.items():
+        assert abs(delta[name] - want) <= 1e-4 * max(want, 1e-6), name
+    gauges = {(m.name, m.labels.get("kind")): m.value
+              for m in DEFAULT_REGISTRY.metrics()
+              if m.name.startswith("autodist_moe_")}
+    assert gauges[("autodist_moe_experts_held", None)] == 4
+    assert gauges[("autodist_moe_experts_total", None)] == 16
+    computed, expected = routed_rows(4 * 32, 3, 4, 16)
+    assert gauges[("autodist_moe_rows_per_step", "computed")] == 2 * computed
+    assert gauges[("autodist_moe_rows_per_step", "expected")] == 2 * expected
+    assert computed / expected == 4.0
