@@ -60,6 +60,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
@@ -69,6 +70,13 @@ from autodist_tpu.ops import pallas_utils
 from autodist_tpu.utils import logging
 
 _NEG_INF = -1e30  # finite -inf: keeps exp()/max() NaN-free (masked rows)
+#: The forward kernel's output and row statistics (kernel layout:
+#: ``o [B,H,T,Dv]``, ``lse [B,H,T,1]`` float32) carry these names, so a
+#: ``jax.checkpoint`` policy can keep them (``save_only_these_names``):
+#: the backward is then handed what the first forward made and the kernel
+#: is not run again.  Where no policy asks for a name, a tag is an
+#: identity.
+RESIDUAL_NAMES = ("flash_attention/o", "flash_attention/lse")
 # Tiling policy lives in ops/pallas_utils.py (shared by every Pallas
 # kernel in the repo); these aliases keep this module's historical
 # private names importable (tests pin the padding policy through them).
@@ -433,6 +441,9 @@ def _flash(q, k, v, causal, block_q, block_k, interpret, kv_len,
 def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, kv_len, operand):
     o, lse = _fwd(q, k, v, causal, block_q, block_k, interpret, kv_len,
                   operand)
+    # tagged BEFORE they part into result and residual: a policy that
+    # keeps the result alone would still recompute the residual
+    o, lse = map(checkpoint_name, (o, lse), RESIDUAL_NAMES)
     return (o, lse), (q, k, v, o, lse)
 
 

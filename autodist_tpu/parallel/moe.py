@@ -22,6 +22,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from autodist_tpu.const import MESH_AXIS_DATA, MESH_AXIS_EXPERT
@@ -278,6 +279,16 @@ def swiglu(w: dict, x: jax.Array) -> jax.Array:
     return (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"]
 
 
+#: The integers a routed layer's ``top_k`` and sorts produce (the picks
+#: ``[N, k]``, the order of the ``N * k`` rows by local expert, its inverse
+#: and the group sizes) carry these names: a ``jax.checkpoint`` policy that
+#: keeps them (``save_only_these_names``) recomputes the layer without
+#: selecting or sorting again.  They carry no gradient; the float path of
+#: the router (scores, picked scores, weights) does and is not tagged.
+ROUTING_RESIDUAL_NAMES = ("routed_moe/chosen", "routed_moe/order",
+                          "routed_moe/inverse", "routed_moe/sizes")
+
+
 def routed_rows(tokens: int, top_k: int, held: int, total: int):
     """(rows the grouped products are handed, rows expected to be routed
     here) for one call of :func:`routed_moe_ffn` over ``tokens`` tokens:
@@ -348,6 +359,7 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
             precision=jax.lax.Precision.HIGHEST))
         _, chosen = jax.lax.top_k(
             scores + jax.lax.stop_gradient(params["router_bias"]), top_k)
+        chosen = checkpoint_name(chosen, ROUTING_RESIDUAL_NAMES[0])
         picked = jnp.take_along_axis(scores, chosen, axis=-1)   # [N, k]
         gates = routed_scale * picked / picked.sum(-1, keepdims=True)
         local = chosen - first
@@ -357,6 +369,8 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
         inverse = jnp.argsort(order)              # where each pick went
         sizes = jnp.sum(group[:, None] == jnp.arange(count), axis=0,
                         dtype=jnp.int32)
+        order, inverse, sizes = map(checkpoint_name, (order, inverse, sizes),
+                                    ROUTING_RESIDUAL_NAMES[1:])
         live = (jnp.arange(n * top_k) < sizes.sum())[:, None]
 
     with jax.named_scope(timeline.SCOPE_MOE_EXPERTS):

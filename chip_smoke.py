@@ -457,7 +457,9 @@ def train_moe_phase(model: dict, *, batch_size: int, steps: int) -> dict:
     lower than the first; the per-expert token counts come back with every
     step and add up to no more than every pick of every token; on a TPU the
     compiled step holds the Pallas attention calls one sequence at a time
-    with keys wider than values."""
+    with keys wider than values, a forward and a backward a layer and no
+    third: the layers' checkpoints keep ``o`` and ``lse`` by name, and the
+    gauge says how many bytes that holds."""
     import jax
     import numpy as np
 
@@ -492,8 +494,18 @@ def train_moe_phase(model: dict, *, batch_size: int, steps: int) -> dict:
         if not attn or not {cfg["qk_nope"] + cfg["qk_rope"],
                             cfg["v_head"]} <= widths:
             raise AssertionError(f"attention custom calls work on {attn}")
+        if len(attn) != 2 * cfg["num_layers"]:
+            raise AssertionError(
+                f"{len(attn)} attention custom calls in {cfg['num_layers']} "
+                f"layers: the rematerialised layer runs its forward kernel "
+                f"again")
         facts["attention_calls"] = len(attn)
         facts["bytes_in_use"] = memory_in_use(jax.devices()[:1])
+    from autodist_tpu.telemetry.registry import DEFAULT_REGISTRY
+
+    facts["remat_kept_bytes"] = {
+        m.labels["name"]: int(m.value) for m in DEFAULT_REGISTRY.metrics()
+        if m.name == "autodist_remat_kept_bytes_per_step"}
     del sess, ad
     _reset_default_autodist_for_testing()
     gc.collect()

@@ -57,6 +57,11 @@ def test_train_moe_phase_returns_the_counts():
     assert facts["losses"][-1] < facts["losses"][0]
     assert len(facts["tokens_per_expert"]) == 1
     assert 0 < facts["share_of_picks_here"] <= 1
+    # the CPU's default attention is dense and tags nothing; the one
+    # routed layer keeps its picks: 4 sequences x 16 tokens x 3 x int32
+    kept = facts["remat_kept_bytes"]
+    assert kept["flash_attention/o"] == 0
+    assert kept["routed_moe/chosen"] == kept["routed_moe/order"] == 768
 
 
 def test_serve_phases_over_http(lm):

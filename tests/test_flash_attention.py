@@ -507,3 +507,77 @@ def test_vmem_estimate_counts_both_widths():
     assert limit(1024, 64, 64) is None
     assert limit(4096, 192, 128) >= 40.1 * 2 ** 20
     assert fa._row_bytes((64, 4), (192, 2)) == 128 * 4 + 256 * 2
+
+
+# ---------------------------------------------------------------------------
+# the forward's output and row statistics carry names (PR 28)
+# ---------------------------------------------------------------------------
+def _names_in(jaxpr):
+    from autodist_tpu.models.mla_moe_lm import equations
+
+    return [eqn.params["name"] for eqn in equations(jaxpr)
+            if eqn.primitive.name == "name"]
+
+
+@pytest.mark.parametrize("with_lse", [False, True])
+def test_residual_names_are_inert_without_a_policy(with_lse, monkeypatch):
+    """A plain ``jax.grad`` through the tagged forward, the lse cotangent
+    included, gives the bits it gives with the tags taken out."""
+    from autodist_tpu.ops.flash_attention import flash_attention_with_lse
+
+    fa = _module()
+    q, k, v, w, u = _qk_wider_than_v(30, 32)
+
+    def grad():   # a new function each time: jax keeps traces by function
+        def loss(q, k, v):
+            if not with_lse:
+                return jnp.sum(flash_attention(q, k, v, True, block_q=8,
+                                               block_k=8) * w)
+            o, lse = flash_attention_with_lse(q, k, v, True, block_q=8,
+                                              block_k=8)
+            return jnp.sum(o * w) + jnp.sum(lse * u)
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))
+
+    tagged = grad()(q, k, v)
+    assert _names_in(jax.make_jaxpr(grad())(q, k, v).jaxpr) \
+        == list(fa.RESIDUAL_NAMES)
+    monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
+    assert _names_in(jax.make_jaxpr(grad())(q, k, v).jaxpr) == []
+    for got, want in zip(jax.tree.leaves(tagged),
+                         jax.tree.leaves(grad()(q, k, v))):
+        np.testing.assert_array_equal(got, want)
+
+
+def test_a_policy_that_keeps_the_names_holds_o_and_lse_and_no_q_k_v(capsys):
+    """Under ``save_only_these_names(*RESIDUAL_NAMES)`` a checkpoint that
+    projects q, k, v itself keeps its arguments, the kernel's ``o``
+    ``[B,H,T,Dv]`` and ``lse``: neither projection (they are recomputed),
+    and with them in hand the backward has no forward kernel to run."""
+    fa = _module()
+    x = jnp.asarray(np.random.default_rng(40).standard_normal((2, 32, 2, 8)),
+                    jnp.float32)
+    wk = jnp.ones((8, 24)) / 8
+    wv = jnp.ones((8, 16)) / 8
+
+    def f(x, wk, wv):
+        return jnp.sum(flash_attention(x @ wk, jnp.sin(x) @ wk, x @ wv, True,
+                                       block_q=8, block_k=8) ** 2)
+
+    kept = jax.checkpoint(
+        f, policy=jax.checkpoint_policies.save_only_these_names(
+            *fa.RESIDUAL_NAMES))
+    jax.ad_checkpoint.print_saved_residuals(kept, x, wk, wv)
+    held = [ln for ln in capsys.readouterr().out.splitlines()
+            if "from the argument" not in ln]
+    assert len(held) == 2, held
+    assert held[0].startswith("f32[2,2,32,16] ")           # o, kernel layout
+    assert "flash_attention.py" in held[0]
+    assert held[1].startswith(f"f32[2,2,32,1] named '{fa.RESIDUAL_NAMES[1]}'")
+
+    def kernels(fn):
+        return str(jax.make_jaxpr(jax.grad(fn))(x, wk, wv)).count(
+            "pallas_call")
+
+    assert kernels(kept) == kernels(f) == 2
+    assert kernels(jax.checkpoint(f)) == 3

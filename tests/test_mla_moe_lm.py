@@ -12,15 +12,26 @@ rotary turn, a norm) moves a leaf by 1e-2 or more.  No seed here has two
 scores within 1e-6 of a tie at the sixth place, so the selection itself is
 the same on both sides.
 """
+import collections
+import functools
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
 
-from autodist_tpu.models.mla_moe_lm import mla_moe_lm, rotary
+from autodist_tpu.models.mla_moe_lm import (
+    KEPT_NAMES,
+    equations,
+    mla_moe_lm,
+    rotary,
+)
 from autodist_tpu.models.transformer import dense_attention
+from autodist_tpu.ops import flash_attention
 from autodist_tpu.parallel.moe import (
+    ROUTING_RESIDUAL_NAMES,
     init_routed_moe_params,
     routed_moe_ffn,
     routed_rows,
@@ -231,3 +242,96 @@ def test_three_session_steps_match_the_reference_adamw():
     assert gauges[("autodist_moe_rows_per_step", "computed")] == 2 * computed
     assert gauges[("autodist_moe_rows_per_step", "expected")] == 2 * expected
     assert computed / expected == 4.0
+
+
+# ---------------------------------------------------------------------------
+# what the per-layer checkpoint keeps by name (PR 28)
+# ---------------------------------------------------------------------------
+FLASH = functools.partial(flash_attention, interpret=True)
+TINY_FLASH = dict(TINY, attn_fn=FLASH)
+ROUTED = TINY["num_layers"] - TINY["first_dense"]
+
+
+def remat_model(remat, monkeypatch, **kw):
+    """``remat`` "bare" is what "full" was before the names: the whole
+    layer recomputed (``policy=None``), by the module's own table."""
+    if remat == "bare":
+        monkeypatch.setitem(
+            sys.modules["autodist_tpu.models.mla_moe_lm"]._REMAT_POLICIES,
+            "full", None)
+        remat = "full"
+    return mla_moe_lm(**dict(TINY_FLASH, **kw), experts_held=(4, 4),
+                      remat=remat)
+
+
+def count_primitives(jaxpr, names):
+    found = collections.Counter(eqn.primitive.name
+                                for eqn in equations(jaxpr))
+    return {name: found[name] for name in names}
+
+
+@pytest.mark.parametrize("remat,kernels,selects", [
+    ("full", 2, 1), ("dots", 2, 1), ("none", 2, 1), ("bare", 3, 2)])
+def test_backward_runs_kernel_selection_and_sorts_once_a_layer(
+        remat, kernels, selects, monkeypatch):
+    """The gradient's jaxpr: a forward and a backward attention kernel a
+    layer, one ``top_k`` and two sorts a routed layer.  A checkpoint that
+    keeps nothing by name runs the forward kernel, the selection and both
+    sorts a second time."""
+    spec = remat_model(remat, monkeypatch)
+    params = jax.eval_shape(spec.init, jax.random.key(0))
+    jaxpr = jax.make_jaxpr(jax.grad(spec.loss_fn))(
+        params, {"tokens": tokens(0, rows=2)})
+    assert count_primitives(jaxpr.jaxpr, ("pallas_call", "top_k", "sort")) \
+        == {"pallas_call": kernels * TINY["num_layers"],
+            "top_k": selects * ROUTED, "sort": 2 * selects * ROUTED}
+
+
+@pytest.mark.parametrize("remat", ["full", "dots"])
+def test_keeping_by_name_changes_no_number(remat, monkeypatch):
+    """Loss and every gradient equal the whole-layer recomputation's to
+    the bit: the backward is handed the ``o``, ``lse`` and picks it would
+    have recomputed.  Against no rematerialisation at all the loss and
+    every leaf are equal too, but for the RMSNorm scales before attention
+    and FFN: XLA's CPU backend fuses their sum over tokens with the
+    recomputed norm in another order (a few 1e-7, under any policy)."""
+    batch = {"tokens": tokens(2, rows=2)}
+    out = {}
+    for which in (remat, "none", "bare"):
+        spec = remat_model(which, monkeypatch)
+        params = seeded(jax.eval_shape(spec.init, jax.random.key(0)), 5)
+        loss, grads = jax.jit(jax.value_and_grad(spec.loss_fn))(params,
+                                                               batch)
+        out[which] = dict(ref._flat(grads, np.asarray), loss=loss)
+    for name, got in out[remat].items():
+        np.testing.assert_array_equal(got, out["bare"][name], name)
+        if name.endswith(("ln_attn/scale", "ln_mlp/scale")):
+            assert rel(got, out["none"][name]) < 1e-6, name
+        else:
+            np.testing.assert_array_equal(got, out["none"][name], name)
+
+
+@pytest.mark.parametrize("remat,attn", [
+    ("full", FLASH), ("dots", FLASH), ("full", dense_attention),
+    ("none", FLASH)])
+def test_kept_bytes_gauge_reads_what_the_tagged_shapes_give(remat, attn,
+                                                            monkeypatch):
+    """``autodist_remat_kept_bytes_per_step{name}``, set when the model is
+    traced: the shapes the trace tags, times sequences, times layers.  An
+    attention that tags nothing keeps nothing; no checkpoint, nothing."""
+    from autodist_tpu.telemetry.registry import DEFAULT_REGISTRY
+
+    rows, t, k = 2, TINY["seq_len"], TINY["top_k"]
+    spec = remat_model(remat, monkeypatch, attn_fn=attn)
+    jax.eval_shape(spec.loss_fn, jax.eval_shape(spec.init, jax.random.key(0)),
+                   {"tokens": tokens(0, rows=rows)})
+    got = {m.labels["name"]: m.value for m in DEFAULT_REGISTRY.metrics()
+           if m.name == "autodist_remat_kept_bytes_per_step"}
+    layers = rows * TINY["num_layers"] * (attn is FLASH)
+    picks = rows * ROUTED * t * k * 4
+    want = dict(zip(KEPT_NAMES, (
+        layers * TINY["num_heads"] * t * TINY["v_head"] * 4,    # o, float32
+        layers * TINY["num_heads"] * t * 4,                     # lse
+        picks, picks, picks, rows * ROUTED * 4 * 4)))           # 4 held
+    assert KEPT_NAMES[2:] == ROUTING_RESIDUAL_NAMES
+    assert got == (want if remat != "none" else dict.fromkeys(KEPT_NAMES, 0))
