@@ -46,7 +46,7 @@ search space, at the granularity GSPMD weight-update sharding
   and what the CLI ``--watermark`` prints.
 
 Everything here is numpy-only and mesh-free — safe inside the
-pre-trace verifier gate, the beam search inner loop, and bench.
+pre-trace verifier gate and the beam search inner loop.
 
 :class:`HappensBefore` has a second consumer beyond the verifier: the
 flight recorder's hang localizer

@@ -421,7 +421,7 @@ class PeerMirror:
 
     def clear(self, owner: Optional[str] = None) -> None:
         """Delete mirrored snapshots (all owners by default) — drill
-        cleanup; the no-litter invariant in bench.py checks this."""
+        cleanup, so a finished drill leaves nothing in the peer store."""
         import shutil
 
         targets = [owner] if owner else self.owners()

@@ -112,8 +112,8 @@ class ENV(enum.Enum):
     AUTODIST_TRACE_AT = ("AUTODIST_TRACE_AT", _str)
     # telemetry master switch (docs/observability.md): metrics registry,
     # per-step StepRecords, and the event journal.  Disabled paths are
-    # near-zero-cost no-ops (BENCH_telemetry.json measures the enabled
-    # overhead)
+    # near-zero-cost no-ops; the enabled overhead is unmeasured on the
+    # chip beyond the session spans (PERF.md, PR 24)
     AUTODIST_TELEMETRY = ("AUTODIST_TELEMETRY", _bool_default_true)
     # when set, StepRecord ring buffers and the event journal flush as
     # JSONL under this run directory (one writer per process;
@@ -121,7 +121,7 @@ class ENV(enum.Enum):
     AUTODIST_TELEMETRY_DIR = ("AUTODIST_TELEMETRY_DIR", _str)
     # leg-calibrated cost-model constants (docs/observability.md): path
     # to a calibration.json written by telemetry.calibration
-    # .save_calibration / bench.py.  When set (or when
+    # .save_calibration.  When set (or when
     # AUTODIST_TELEMETRY_DIR/calibration.json exists), estimate_ir_cost
     # and AutoStrategy(search=True) load the fitted constants
     # automatically — no flags.
@@ -132,8 +132,8 @@ class ENV(enum.Enum):
     # granularity off-TPU); "legs" additionally stamps leg-group
     # host-callbacks inside the explicit sync path; "auto" (default,
     # empty) resolves to "legs" on TPU backends (callbacks ride async
-    # dispatch) and "host" elsewhere (CPU host-callbacks are not free —
-    # BENCH_flightrec.json measures both).
+    # dispatch) and "host" elsewhere (a CPU host-callback serializes
+    # the step; its cost on the chip is unmeasured).
     AUTODIST_FLIGHTREC = ("AUTODIST_FLIGHTREC", _str)
     # fused Pallas kernel opt-in (docs/kernels.md): "all" or a comma
     # list of guard,update,quant_hop,paged_attention.  Unset = every
@@ -142,7 +142,7 @@ class ENV(enum.Enum):
     # (ops.fused_kernels.fused_drop_reason).
     AUTODIST_FUSED_KERNELS = ("AUTODIST_FUSED_KERNELS", _str)
     # force Pallas interpret mode off-TPU for the fused kernels —
-    # the CPU test/bench escape hatch (slower than XLA; never default)
+    # the CPU test escape hatch (slower than XLA; never default)
     AUTODIST_FUSED_INTERPRET = ("AUTODIST_FUSED_INTERPRET", _bool)
     # dump staged program snapshots (plan table, StableHLO, optimized HLO);
     # parity with the reference's per-stage graph dumps

@@ -224,8 +224,8 @@ def plan_step_buckets(gi: GraphItem, compiled: CompiledStrategy,
                       part: Dict[str, tuple], d: int) -> List[Bucket]:
     """Bucket assignment for this program: every replicated synced var
     whose compressor composes with flat buckets, in flatten order, keyed
-    by (mode, dtype, compressor, group).  Shared with the analyzer and
-    bench byte accounting — the planner the runtime executes."""
+    by (mode, dtype, compressor, group).  Shared with the analyzer's
+    byte accounting — the planner the runtime executes."""
     entries = []
     cap = 0
     for path, leaf in jax.tree_util.tree_flatten_with_path(gi.params)[0]:

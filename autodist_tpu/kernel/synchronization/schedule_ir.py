@@ -104,7 +104,7 @@ Everything here is mesh-free and jax-free at module import (numpy
 only), so the analyzer's sub-second verdict survives, and the verifier
 is cheap enough (< 1 s on the largest fixtures, asserted in
 tests/test_schedule_ir.py) to run as a pre-trace gate on every explicit
-build and every bench mode.
+build.
 """
 from __future__ import annotations
 
@@ -459,7 +459,7 @@ class ScheduleIR:
             "gather_order": [list(kv) for kv in self.gather_order],
             "donated": list(self.donated),
             # Omitted when empty so every pre-fusion program keeps its
-            # recorded fingerprint (checkpoints, BENCH_leg_samples.jsonl,
+            # recorded fingerprint (checkpoints, recorded leg samples,
             # calibration.json all key on it).
             **({"fused_kernels": list(self.fused_kernels)}
                if self.fused_kernels else {}),
@@ -779,7 +779,7 @@ def dcn_wire_compressor_default() -> str:
     step, stateless, no error feedback; DCN is exactly where the 4x
     compression pays most); anything else is the full-precision wire.
     Read by every hier leg producer (explicit lowering,
-    ``ir_from_facts``, bench modes) so one env knob keeps all
+    ``ir_from_facts``) so one env knob keeps all
     fingerprints in agreement."""
     import os
     wire = os.environ.get("AUTODIST_DCN_WIRE", "").strip().lower()
@@ -907,8 +907,8 @@ def pipeline_wire_compressor_default() -> str:
     puts the cross-slice boundary activations on the quantized wire
     (stateless per-microbatch scale grid, like the DCN gradient wire);
     anything else is the full-precision wire.  Read by every pipeline
-    fact producer (the MPMD runtime, the ``--simulate`` sweep, bench
-    modes) so one env knob keeps all fingerprints in agreement."""
+    fact producer (the MPMD runtime, the ``--simulate`` sweep) so one
+    env knob keeps all fingerprints in agreement."""
     import os
     wire = os.environ.get("AUTODIST_PIPE_WIRE", "").strip().lower()
     return "Int8Compressor" if wire == "int8" else "NoneCompressor"
@@ -1096,7 +1096,7 @@ def build_schedule_ir(*, axes: Dict[str, int], accum_steps: int = 1,
     Pure: consumes exactly the planner's outputs (``buckets`` from
     ``bucketing.assign_buckets``, ``plan`` from
     ``overlap.resolve_overlap``) plus program facts, so the runtime,
-    the analyzer, the cost model, and the bench all construct the SAME
+    the analyzer and the cost model all construct the SAME
     IR and can never drift.  ``stateful_keys`` names buckets whose
     compressor carries sync state (probed by the runtime, mirrored by
     :func:`compressor_stateful` for mesh-free callers); ``donated``
@@ -1336,8 +1336,8 @@ def build_schedule_ir(*, axes: Dict[str, int], accum_steps: int = 1,
 
     # Guard roll-up: ONE small all-axis psum over every bucket/var
     # partial (docs/numerics.md) — depends on every reduce final.  With
-    # the fused guard kernel the per-key detection arithmetic (the
-    # measured 5-7% of BENCH_guard.json — not the psum) becomes an
+    # the fused guard kernel the per-key detection arithmetic (a second
+    # pass over every bucket; the psum is one small collective) becomes an
     # explicit fused_detect leg per key: one Pallas pass producing the
     # finite-count and sq-norm partials together, priced by its own
     # calibration kind.
@@ -2170,8 +2170,7 @@ def errors(violations: Sequence[Violation]) -> List[Violation]:
 
 def assert_verified(ir: ScheduleIR, context: str = "schedule") -> None:
     """The pre-trace gate: raise ``ValueError`` listing every ERROR rule
-    the verifier fires on ``ir`` (used by the explicit build and by
-    bench.py before timing a mode)."""
+    the verifier fires on ``ir`` (used by the explicit build)."""
     errs = errors(verify(ir))
     if errs:
         lines = "\n  ".join(str(v) for v in errs[:8])

@@ -1,14 +1,14 @@
-"""Fused Pallas TPU kernels for the measured sync/serving hot paths.
+"""Fused Pallas TPU kernels for the sync and serving paths.
 
-PR 10's leg profiler finally attributed where the step time goes
-(BENCH_profiler.json): the 5-7% numerics-guard overhead of
-BENCH_guard.json is fused-DETECTION arithmetic (the rollup psum itself
-is ~5 µs), quantize/dequantize work sits at every ring-hop boundary
-(EQuARX, arXiv:2506.17615, fuses exactly this into the collective), the
-ZeRO-1 shard update is the classic fusion target of weight-update
-sharding (arXiv:2004.13336), and serving's paged decode still gathers
-the whole KV window per layer per tick.  Four kernels delete that
-arithmetic by fusion:
+What each fuses away is arithmetic the unfused lowering does in passes
+of its own (none of the four has a time on the chip yet: ROADMAP.md S4):
+the numerics guard's detection is a second pass over every bucket (the
+rollup psum is one small collective), quantize/dequantize work sits at
+every ring-hop boundary (EQuARX, arXiv:2506.17615, fuses exactly this
+into the collective), the ZeRO-1 shard update is the classic fusion
+target of weight-update sharding (arXiv:2004.13336), and serving's paged
+decode still gathers the whole KV window per layer per tick.  Four
+kernels delete that arithmetic by fusion:
 
 1. **Fused bucket pack + finiteness detect** (:func:`fused_pack_detect`
    / :func:`fused_detect_stats`): ONE pass over the packed bucket
@@ -46,7 +46,7 @@ shared drop reason (:func:`fused_drop_reason` — the
 ``bucket_drop_reason`` pattern: runtime and analysis surface the same
 string): on a TPU the runtime raises with it, off-TPU it WARNs and
 falls back to the unfused lowering (:func:`drop_or_raise`).  ``AUTODIST_FUSED_INTERPRET=1`` forces Pallas interpret mode
-off-TPU — the test/bench escape hatch that lets the CPU mesh execute
+off-TPU — the test escape hatch that lets the CPU mesh execute
 the exact fused step (slower than XLA; never the default).  Enabled
 kernels are recorded in the schedule IR (``fused_detect`` /
 ``fused_update`` / ``fused_hop`` legs, ``docs/schedule-ir.md``) and
@@ -103,7 +103,7 @@ def requested_kernels() -> frozenset:
 
 def interpret_forced() -> bool:
     """Is the off-TPU interpret-mode escape hatch on
-    (``AUTODIST_FUSED_INTERPRET=1``)?  Test/bench only — interpret mode
+    (``AUTODIST_FUSED_INTERPRET=1``)?  Tests only — interpret mode
     executes the exact kernel bodies but slower than XLA."""
     from autodist_tpu.const import ENV
 
@@ -117,8 +117,8 @@ def fused_drop_reason(kernel: str, *, on_tpu: bool,
                       f32_buckets: bool = True) -> Optional[str]:
     """Why a REQUESTED fused kernel cannot lower on this program, or
     None when it can.  Pure — the single rule shared by the runtime
-    fallback WARN, the ``schedule/fused-fallback`` analysis WARN, and
-    the bench, so the lint can never drift from the lowering (the
+    fallback WARN and the ``schedule/fused-fallback`` analysis WARN,
+    so the lint can never drift from the lowering (the
     ``bucket_drop_reason`` pattern)."""
     if kernel not in ALL_KERNELS:
         return (f"unknown fused kernel {kernel!r}; expected one of "

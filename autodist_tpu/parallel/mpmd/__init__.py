@@ -5,8 +5,8 @@ Three pieces, one verified program:
 
 * :mod:`.partition` — the stage partitioner and
   :func:`~autodist_tpu.parallel.mpmd.partition.build_pipeline_ir`, THE
-  shared schedule-IR constructor (runtime, analyzer, ``--simulate``,
-  bench all call it, so static and runtime fingerprints agree by
+  shared schedule-IR constructor (runtime, analyzer and ``--simulate``
+  all call it, so static and runtime fingerprints agree by
   construction);
 * :mod:`.transport` — the DCN activation/gradient plane (atomic
   digest-checked blobs with an in-memory fast path, on the PR 12 retry

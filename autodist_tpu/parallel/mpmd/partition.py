@@ -4,8 +4,8 @@ Splits a layer catalog into per-stage programs — contiguous balanced
 layer runs, one disjoint slice process group per stage — and builds THE
 schedule-IR program both sides share: :func:`build_pipeline_ir` is the
 single constructor the live :class:`~autodist_tpu.parallel.mpmd.runner.
-StageRunner`, the static analyzer, the ``--simulate`` sweep, and the
-bench modes all call, so the runtime's executed fingerprint and the
+StageRunner`, the static analyzer and the ``--simulate`` sweep all
+call, so the runtime's executed fingerprint and the
 planner's predicted fingerprint are equal by construction (the
 acceptance assertion in ``tests/test_mpmd.py``).
 

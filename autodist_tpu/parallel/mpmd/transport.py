@@ -12,7 +12,7 @@ cluster's retry schedule) when the peer stage lives on another host.
 
 Two paths, one API:
 
-* **in-memory fast path** — stages in one process (tests, bench, the
+* **in-memory fast path** — stages in one process (tests, the
   thread-backed runners) rendezvous through a process-local registry
   under a condition variable: no filesystem, no polling.
 * **directory path** — stages in separate processes share

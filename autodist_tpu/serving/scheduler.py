@@ -832,7 +832,7 @@ class PagedDecodeEngine:
         return out
 
     def assert_no_leaks(self) -> None:
-        """Post-drain invariant (the bench gate): every pool block is
+        """Post-drain invariant (the tests' leak gate): every pool block is
         either free or held exactly by the prefix cache."""
         assert not self._prefilling and not np.any(self._active), \
             "assert_no_leaks needs a drained engine"

@@ -2,8 +2,7 @@
 
 Summarize a recorded run directory — the JSONL a
 :class:`~autodist_tpu.telemetry.timeline.StepRecorder` and the event
-journal flushed (``AUTODIST_TELEMETRY_DIR``), or what bench.py emitted
-next to its BENCH_*.json artifacts:
+journal flushed (``AUTODIST_TELEMETRY_DIR``):
 
 * step-time percentiles (p50/p90/p99) and throughput,
 * host-phase breakdown (data_load / dispatch / blocking_fetch ...),

@@ -9,8 +9,7 @@ MLPerf TPU-pod report (arXiv:1909.09756) both attribute search quality
 to MEASURED calibration — so every :class:`~autodist_tpu.telemetry.
 timeline.StepRecord` carries the model's prediction next to the
 measured step time, and :func:`fit_constants` regresses the constants
-from accumulated records (bench runs and real runs emit the same JSONL,
-so both feed this path).
+from accumulated records.
 
 The regression is deliberately tiny: ordinary least squares of
 ``step_time ≈ exposed_bytes · (1/bandwidth) + collectives · alpha``

@@ -304,7 +304,7 @@ def trace_stamps_enabled() -> bool:
     callbacks into the step?  ``AUTODIST_FLIGHTREC=legs`` forces on,
     ``host`` forces off; the default (``auto``) enables them only on
     TPU backends, where the callback rides async dispatch instead of
-    serializing a CPU step (BENCH_flightrec.json measures both)."""
+    serializing a CPU step (the cost on the chip is unmeasured)."""
     if not enabled():
         return False
     try:

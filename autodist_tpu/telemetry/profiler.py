@@ -5,16 +5,15 @@ bridge regresses two global constants from it; PR 7's schedule IR names
 every collective leg (kind, bytes, dtype, axis, slot) and
 ``estimate_ir_cost`` prices them individually.  Prediction happens at
 leg granularity, measurement at step granularity — so calibration
-cannot tell a slow ring hop from a slow optimizer update, and the 5-7%
-guard overhead in BENCH_guard.json stays unattributed.  This module is
+cannot tell a slow ring hop from a slow optimizer update, and the
+numerics guard's overhead stays unattributed.  This module is
 the measurement half of closing that gap (the Automap argument,
 arXiv:2112.02958: search quality tracks measured, fine-grained
 calibration):
 
 * :class:`LegSample` — one measured timing for one schedule-IR leg,
   keyed by ``schedule_fingerprint`` + ``leg_id``, JSONL-persisted as
-  ``legs-<host>-<pid>.jsonl`` next to the StepRecord stream (bench runs
-  and real runs feed the same files).
+  ``legs-<host>-<pid>.jsonl`` next to the StepRecord stream.
 * :class:`LegProfiler` — produces LegSamples two ways:
 
   - **timed micro-runs** (:meth:`LegProfiler.profile_ir`): the IR's
@@ -44,8 +43,8 @@ calibration):
   (docs/observability.md).
 
 Cost discipline: micro-runs are explicit calls outside the step loop
-and trace parsing is offline (the <1 % profiler-overhead budget
-BENCH_profiler.json verifies).  The span ring is the one thing here a
+and trace parsing is offline, so neither costs a step anything.  The
+span ring is the one thing here a
 training step touches: ``host_span`` appends five records a step
 (PERF.md has the measured cost on the chip).  Everything except
 :meth:`profile_ir` imports without jax.

@@ -10,8 +10,7 @@ Three instruments, one per time scale (docs/observability.md):
   active strategy (step time, exposed wire bytes, collective count) —
   the calibration bridge :mod:`autodist_tpu.telemetry.calibration`
   regresses against.  Records ride a bounded ring buffer and flush
-  periodically as JSONL (rotated) into the run directory, so bench runs
-  and real runs feed the same files.
+  periodically as JSONL (rotated) into the run directory.
 * :func:`host_span` — the LIVE span around a host-side phase (session
   step and its parts, set-up, the serving engine's tick): one
   ``jax.profiler.TraceAnnotation`` named ``autodist/<name>`` (so a
@@ -33,9 +32,9 @@ Three instruments, one per time scale (docs/observability.md):
 Cost discipline: when telemetry is disabled, :meth:`StepRecorder.create`
 returns None and every call site gates on that one identity check;
 enabled, the per-step work is two ``perf_counter`` reads, one dataclass,
-and two deque appends — the <1 % overhead budget BENCH_telemetry.json
-verifies.  ``sync_span`` is trace-time-only metadata and costs nothing
-per step on any path.
+and two deque appends (the session's spans cost nothing measurable on
+the chip: PERF.md, PR 24).  ``sync_span`` is trace-time-only metadata
+and costs nothing per step on any path.
 """
 from __future__ import annotations
 

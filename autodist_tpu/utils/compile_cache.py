@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives.
 
 Called by the two scripts that run on the chip (``chip_smoke.py`` and
-``bench.py``) before their first use of JAX, and by nothing else: a
+``benchmark/run.py``) before their first use of JAX, and by nothing else: a
 library import must not move a process's cache.
 """
 from __future__ import annotations

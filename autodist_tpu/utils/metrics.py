@@ -5,7 +5,8 @@ The reference measured throughput only in example scripts (TimeHistory,
 :class:`ThroughputMeter` is fed by every ``DistributedSession.run`` call,
 and :func:`session_mfu` turns XLA's compiled cost analysis into a
 model-FLOPs-utilization figure against the chip's peak — the metric TPU
-work is judged by (bench.py reports the same numbers for the headline run).
+work is judged by (``sess.throughput()`` / ``sess.mfu()``; the benchmark
+counts FLOPs from the configuration instead: ``benchmark/flops.py``).
 """
 from __future__ import annotations
 

@@ -1,7 +1,7 @@
 """Speculative decoding with a genuinely TRAINED draft model.
 
-The bench's draft==target run shows the mechanical upper bound
-(acceptance 1.0); this example shows the real pipeline: train a target
+A draft that IS the target accepts everything (acceptance 1.0, the
+mechanical upper bound); this example shows the real pipeline: train a target
 LM through the framework, train a much smaller draft on the same data,
 then decode speculatively — the draft proposes ``gamma`` tokens per
 verify pass, the target accepts a measured fraction, and the output is

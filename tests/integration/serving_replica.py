@@ -1,8 +1,8 @@
 """One serving replica for the router drill: a paged-KV engine behind
 an EngineServer, supervised from the parent via heartbeat beacons.
 
-Launched by ``SupervisedReplicaPool`` (tests/test_serving_router.py and
-the serving bench): builds the tests' tiny deterministic LM, starts the
+Launched by ``SupervisedReplicaPool`` (tests/test_serving_router.py):
+builds the tests' tiny deterministic LM, starts the
 HTTP server on an ephemeral port, publishes the address atomically to
 ``AUTODIST_REPLICA_ADDR_FILE``, and beats
 ``AUTODIST_REPLICA_HB_DIR``/``AUTODIST_REPLICA_NAME`` with the engine's

@@ -109,9 +109,8 @@ def test_auto_measured_within_tolerance_of_best_fixed():
     (the min-over-repeats measurement still jitters ~10% between
     whole-suite runs).  Integration-gated (--run-integration) because a
     wall-clock assertion on a loaded shared host is inherently noisy —
-    the default suite stays deterministic; the companion bench section
-    (auto_vs_best_pct in BENCH_r04) records the same comparison on TPU
-    hardware where the timing floor is stable."""
+    the default suite stays deterministic.  The same comparison on the
+    chip is unmeasured (ROADMAP.md S8)."""
     from test_cost_model_calibration import _measure
 
     from autodist_tpu.strategy import (AllReduce, Parallax, PartitionedAR,

@@ -15,10 +15,10 @@ against wall-clock measurements of real compiled steps on the virtual
   predicted time within a small factor of the predicted-fastest), not a
   strict order over ties.
 
-Calibration status recorded here and surfaced by bench.py's scaling
-projection: the RANKING is validated on the CPU mesh; the absolute
-times (ICI_BANDWIDTH / COLLECTIVE_ALPHA) remain hardware-uncalibrated —
-one real chip cannot measure a cross-chip collective.
+Calibration status recorded here: the RANKING is validated on the CPU
+mesh; the absolute times (ICI_BANDWIDTH / COLLECTIVE_ALPHA) remain
+hardware-uncalibrated — one real chip cannot measure a cross-chip
+collective.
 """
 import time
 

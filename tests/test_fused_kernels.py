@@ -560,7 +560,7 @@ def test_session_fused_matches_unfused(monkeypatch):
 class _LogGrabber(__import__("logging").Handler):
     """The autodist logger does not propagate (its own handlers), so
     fallback-WARN assertions attach a handler directly — the
-    test_quant_ring/bench counter idiom."""
+    test_quant_ring counter idiom."""
 
     def __init__(self):
         super().__init__()

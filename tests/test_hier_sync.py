@@ -141,6 +141,36 @@ def test_hier_zero1_parity_int8_dcn(monkeypatch):
     _assert_parity(Zero1(), Zero1(hier=True), tol=2e-2)
 
 
+def _dcn_wire(monkeypatch, hier, wire):
+    """(DCN-tier leg count, DCN-tier wire bytes) of the session's IR on
+    the 2-slice spec."""
+    from autodist_tpu.strategy.cost_model import leg_tier
+
+    monkeypatch.setenv("AUTODIST_DCN_WIRE", wire)
+    params, loss_fn, _ = _problem()
+    _, sess = _session(AllReduce(bucket_bytes=1 << 20, hier=hier),
+                       _spec(2), params, loss_fn)
+    ir = sess.schedule_ir
+    assert not sir.errors(sir.verify(ir))
+    dcn = [l for l in ir.legs if leg_tier(l, ir) == sir.TIER_DCN]
+    return len(dcn), sum(l.nbytes for l in dcn)
+
+
+# (hier, AUTODIST_DCN_WIRE) of the mode with less DCN wire, then of the other
+@pytest.mark.parametrize("smaller, larger", [
+    ((True, ""), (False, "")),          # two tiers against one ring
+    ((True, "int8"), (True, "")),       # the quantized DCN hop
+], ids=["hier_below_flat_ring", "int8_below_f32_hier"])
+def test_dcn_wire_bytes_mode_against_mode(monkeypatch, smaller, larger):
+    """On two slices the flat ring's every hop is DCN-tier; the
+    two-tier lowering crosses slices with one shard exchange, and
+    ``AUTODIST_DCN_WIRE=int8`` shrinks that exchange again."""
+    n_s, wire_s = _dcn_wire(monkeypatch, *smaller)
+    n_l, wire_l = _dcn_wire(monkeypatch, *larger)
+    assert n_s > 0 and n_l > 0
+    assert 0 < wire_s < wire_l
+
+
 def test_static_and_runtime_fingerprints_match():
     """ir_from_facts (the analysis/search side) and the runtime's
     build_schedule_ir emit the identical two-tier program."""

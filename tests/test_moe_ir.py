@@ -363,3 +363,66 @@ def test_cli_moe_watermark_exits_1_on_planted_over_budget_capacity():
     assert proc.returncode == 1, proc.stdout[-2000:] + proc.stderr[-2000:]
     assert "EXCEEDED" in proc.stdout
     assert "expert:" in proc.stdout
+
+
+# -- a live session's IR, mode against mode -----------------------------------
+
+@pytest.fixture(scope="module")
+def session_modes():
+    """The IR a live ``moe_transformer_lm`` session carries under three
+    modes (the data-only mesh, the expert mesh, the expert mesh on the
+    int8 wire): a2a leg count, a2a wire bytes, watermark peak."""
+    import jax
+    import optax
+
+    from autodist_tpu.analysis import dataflow
+    from autodist_tpu.autodist import AutoDist, \
+        _reset_default_autodist_for_testing
+    from autodist_tpu.mesh import build_mesh
+    from autodist_tpu.models.moe_lm import moe_transformer_lm
+    from autodist_tpu.strategy import Parallax
+
+    rows = {}
+    with pytest.MonkeyPatch.context() as mp:
+        for name, axes, wire in (
+                ("dense", {"data": 8}, ""),
+                ("expert", {"data": 2, "expert": 4}, ""),
+                ("expert_int8", {"data": 2, "expert": 4}, "int8")):
+            mp.setenv("AUTODIST_MOE_WIRE", wire)
+            _reset_default_autodist_for_testing()
+            mesh = build_mesh(axes)
+            spec = moe_transformer_lm(
+                mesh, vocab_size=64, num_layers=2, num_heads=2, head_dim=8,
+                d_ff=32, num_experts=4, max_len=16, seq_len=16)
+            ad = AutoDist(strategy_builder=Parallax(), mesh_axes=axes)
+            with ad.scope():
+                ad.capture(params=spec.init(jax.random.PRNGKey(0)),
+                           optimizer=optax.adam(1e-3),
+                           loss_fn=spec.loss_fn,
+                           sparse_vars=spec.sparse_vars,
+                           expert_vars=spec.expert_vars)
+            ir = ad.create_distributed_session(mesh=mesh).schedule_ir
+            assert not _errors(ir)
+            rows[name] = {
+                "n_a2a_legs": len(_a2a(ir)),
+                "a2a_wire_bytes": sum(l.nbytes for l in _a2a(ir)),
+                "watermark_peak": dataflow.watermark(ir).peak_bytes}
+    _reset_default_autodist_for_testing()
+    return rows
+
+
+def test_session_a2a_legs_only_on_the_expert_mesh(session_modes):
+    assert session_modes["dense"]["n_a2a_legs"] == 0
+    # 2 expert layers x (dispatch, combine), whatever the wire
+    assert session_modes["expert"]["n_a2a_legs"] == 4
+    assert session_modes["expert_int8"]["n_a2a_legs"] == 4
+
+
+def test_session_int8_a2a_wire_at_most_half_of_f32(session_modes):
+    assert 0 < session_modes["expert_int8"]["a2a_wire_bytes"] \
+        <= session_modes["expert"]["a2a_wire_bytes"] // 2
+
+
+def test_session_watermark_sees_capacity_buffers(session_modes):
+    assert session_modes["expert"]["watermark_peak"] \
+        > session_modes["dense"]["watermark_peak"]

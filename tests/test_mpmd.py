@@ -5,7 +5,7 @@ Covers the stage partitioner, the DCN activation transport, the
 equality, mutation goldens with DISTINCT rule ids), pipeline pricing
 (bubble fraction + exposed DCN activation bytes), stage-filtered chaos,
 hang localization naming the wedged stage, the ``stages=`` sweep
-dimension, and a 2-stage thread-backed parity run against the
+dimension, and 2- and 4-stage thread-backed parity runs against the
 single-program ``one_f_one_b`` oracle.  The live 2 stages x 2 DP procs
 drill (tests/integration/mpmd_train.py) rides at the end under the
 ``slow`` marker.
@@ -149,31 +149,40 @@ def test_preflight_stage_resize():
 
 # -- the IR: tier parity, fingerprints, mutation goldens ----------------------
 
-def test_transport_legs_tier_and_shape():
-    prog = _prog()
+# (stages, microbatches): the module's own point, then one, two and four
+# stages at eight microbatches
+STAGE_POINTS = [(S, M), (1, 8), (2, 8), (4, 8)]
+
+
+@pytest.mark.parametrize("s, m", STAGE_POINTS)
+def test_transport_legs_tier_and_shape(s, m):
+    prog = _prog(s, m)
     assert not sir.errors(sir.verify(prog.ir))
     transport = [l for l in prog.ir.legs if l.kind in sir.TRANSPORT_KINDS]
-    # S=2, M=4: one fwd + one bwd boundary, each M send/recv pairs
-    assert len(transport) == 2 * 2 * M
+    # each of the s-1 boundaries is crossed forward and backward, each
+    # crossing m send/recv pairs (none at one stage)
+    assert len(transport) == 4 * (s - 1) * m
+    stages = {sir.stage_name(i) for i in range(s)}
     for leg in transport:
         assert leg.tier == sir.TIER_DCN
-        assert leg.stage in ("stage0", "stage1")
+        assert leg.stage in stages
         bufs = leg.writes if leg.kind == sir.LEG_SEND_ACT else leg.reads
         assert len(bufs) == 1 and bufs[0].startswith("act:")
     sends = [l for l in transport if l.kind == sir.LEG_SEND_ACT]
-    assert len(sends) == 2 * M
+    assert len(sends) == 2 * (s - 1) * m
 
 
-def test_fingerprint_static_equals_runtime():
-    prog = _prog()
+@pytest.mark.parametrize("s, m", STAGE_POINTS)
+def test_fingerprint_static_equals_runtime(s, m):
+    prog = _prog(s, m)
     rebuilt = sir.ir_from_facts(list(prog.facts), axes=dict(prog.axes),
-                                accum_steps=M,
+                                accum_steps=int(prog.ir.accum_steps),
                                 pipeline=list(prog.pipeline))
     assert rebuilt.fingerprint() == prog.ir.fingerprint()
     # the STATIC dedupe key (a hash of the fact INPUTS, not the legs)
     # is deterministic: same facts -> same key -> same program
-    assert prog.fingerprint() == _prog().fingerprint()
-    assert _prog(m=8).fingerprint() != prog.fingerprint()
+    assert prog.fingerprint() == _prog(s, m).fingerprint()
+    assert _prog(s, 2 * m).fingerprint() != prog.fingerprint()
 
 
 def test_pre_mpmd_fingerprints_unchanged():
@@ -445,7 +454,11 @@ def test_make_zero1_update_degenerate_matches_sgd():
     assert np.allclose(out, np.asarray(p) - 0.1 * np.asarray(g))
 
 
-def test_two_stage_parity_vs_one_f_one_b_oracle():
+@pytest.mark.parametrize("S", [2, 4])
+def test_staged_parity_vs_one_f_one_b_oracle(S):
+    """Two and four thread-backed stages step like the single-program
+    1F1B loop over the same model, so every stage count agrees with
+    every other from step 0 on."""
     from autodist_tpu.mesh import build_mesh
     from autodist_tpu.parallel.pipeline_1f1b import one_f_one_b
 

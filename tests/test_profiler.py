@@ -2,7 +2,7 @@
 
 Per-leg micro-run timing + trace-span parsing (LegProfiler / LegSample),
 leg-granular calibration (fit_leg_constants round-trips on planted
-constants and on the committed bench artifacts), calibration.json
+constants and on a recorded sample set), calibration.json
 persistence + automatic consumption by estimate_ir_cost and
 AutoStrategy(search=True) (the constants provably reach the ranking),
 Chrome-trace export validated against the Trace Event Format contract
@@ -31,7 +31,8 @@ from autodist_tpu.telemetry import trace_export as tx
 
 pytestmark = pytest.mark.profiler
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                    "data", "profiler")
 
 
 @pytest.fixture(autouse=True)
@@ -217,39 +218,31 @@ def test_fit_leg_constants_record_scale_and_acceptance():
     assert fitted.ici_bandwidth > 0 and fitted.alpha >= 0
 
 
-def test_fit_on_committed_bench_artifacts():
-    """The committed bench artifacts round-trip through the fit: leg
-    samples + step records from BENCH_* produce a calibration whose
-    record error meets the acceptance bar (leg-calibrated MAE <= the
-    whole-step fit's)."""
-    samples_path = os.path.join(REPO, "BENCH_leg_samples.jsonl")
-    records_path = os.path.join(REPO, "BENCH_telemetry_steps.jsonl")
-    if not (os.path.exists(samples_path) and os.path.exists(records_path)):
-        pytest.skip("committed bench artifacts absent")
-    samples = []
-    with open(samples_path, encoding="utf-8") as f:
-        for line in f:
-            if line.strip():
-                samples.append(prof.LegSample.from_dict(json.loads(line)))
-    records = []
-    with open(records_path, encoding="utf-8") as f:
-        for line in f:
-            if line.strip():
-                records.append(tl.StepRecord.from_dict(json.loads(line)))
-    assert samples, "committed leg samples are empty"
+def _read_jsonl(name, from_dict):
+    with open(os.path.join(DATA, name), encoding="utf-8") as f:
+        return [from_dict(json.loads(line)) for line in f if line.strip()]
+
+
+def test_recorded_samples_roundtrip_through_fit_leg_constants():
+    """A recorded sample set round-trips through the fit: leg samples
+    of four schedules (every leg kind a micro-run produced) plus the
+    step records of the one fingerprint that has them (a cut of one
+    8-device CPU-mesh run, kept under ``tests/data/profiler/``; the
+    seconds in it are inputs to the arithmetic, not measurements of
+    anything) produce a calibration whose record error meets the
+    acceptance bar (leg-calibrated MAE <= the whole-step fit's)."""
+    samples = _read_jsonl("leg_samples.jsonl", prof.LegSample.from_dict)
+    records = _read_jsonl("step_records.jsonl", tl.StepRecord.from_dict)
+    assert samples and records
     fitted = cal.fit_leg_constants(samples, records)
     assert fitted is not None
-    assert set(fitted.bandwidths)
+    assert set(fitted.bandwidths) == {s.kind for s in samples}
+    assert set(fitted.fingerprints) == {s.schedule_fingerprint
+                                        for s in samples}
     step_fit = cal.fit_constants(records)
     assert step_fit is not None
-    if fitted.mean_abs_error_s is not None:
-        assert fitted.mean_abs_error_s <= step_fit.mean_abs_error_s + 1e-9
-    # and the committed calibration.json (when present) parses
-    committed = cal.load_calibration(
-        os.path.join(REPO, "calibration.json"))
-    if committed is not None:
-        assert committed.version == cal.CALIBRATION_VERSION
-        assert committed.bandwidths
+    assert fitted.n_records > 0 and fitted.mean_abs_error_s is not None
+    assert fitted.mean_abs_error_s <= step_fit.mean_abs_error_s + 1e-9
 
 
 def test_calibration_json_roundtrip_and_discovery(tmp_path, monkeypatch):
@@ -673,7 +666,7 @@ def test_profile_ir_on_real_session_mesh():
     """End to end on a live session: the session's verified IR
     micro-profiles on its own mesh, samples join records through
     fit_leg_constants, and the calibrated estimate_ir_cost prices the
-    same IR (the bench child's loop in miniature)."""
+    same IR."""
     import optax
 
     from autodist_tpu.autodist import AutoDist, \

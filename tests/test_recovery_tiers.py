@@ -152,6 +152,9 @@ def test_peer_mirror_push_fetch_retention_and_digest(tmp_path):
     assert mirror.fetch_any().step == 8
     mirror.clear()
     assert mirror.owners() == []
+    # no litter: a finished drill leaves no file in the peer store
+    assert not [f for _, _, files in os.walk(str(tmp_path / "peer"))
+                for f in files]
 
 
 # ---------------------------------------------------------------------------

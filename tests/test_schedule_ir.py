@@ -13,7 +13,8 @@ Three layers, mirroring the PR 7 acceptance criteria:
 * **integration** — both lowerings carry the IR on the compiled step,
   the fingerprint rides telemetry StepRecords and checkpoint meta, the
   CLI dumps it, and the verifier's own runtime on the largest fixture
-  stays under 1 s (the pre-trace-gate budget bench.py relies on).
+  stays under 1 s (the pre-trace-gate budget every explicit build
+  pays).
 """
 import dataclasses
 import json
@@ -523,8 +524,7 @@ def test_reduction_order_divergence_warns_for_bf16_ring():
 def test_verifier_under_one_second_on_largest_fixture():
     """The pre-trace-gate budget: a transformer-scale schedule (hundreds
     of buckets x ring hops x accum slots -> tens of thousands of legs)
-    must verify in <1s so the gate stays viable at build time and in
-    bench.py."""
+    must verify in <1s so the gate stays viable at build time."""
     entries = [(f"blk{i}/w", (512, 512), "float32", "NoneCompressor",
                 0, "reduce_scatter") for i in range(256)]
     ir = _ir(entries, bucket_bytes=1 << 20, d=8, accum=4, guard=True,

@@ -207,17 +207,21 @@ def test_spec_per_request_gamma_exact(lm, draft):
     eng.assert_no_leaks()
 
 
-@pytest.mark.slow
-def test_spec_gamma_adapts_mid_flight(lm, draft):
+@pytest.mark.parametrize(
+    "good_draft", [pytest.param(False, marks=pytest.mark.slow), True])
+def test_spec_gamma_adapts_mid_flight(lm, draft, good_draft):
     """SLO adaptation under backlog: a burst beyond the slot count
-    shrinks gamma (latency queue pressure), the drained tail regrows
-    it, and the acceptance EWMA caps it — all without breaking
-    exactness."""
+    shrinks gamma (latency queue pressure); after the drain (idle slot,
+    empty queue) a GOOD draft's gamma regrows, while a bad draft's
+    acceptance EWMA caps it — all without breaking exactness."""
     spec, params = lm
     rng = np.random.RandomState(9)
-    reqs = [(rng.randint(0, VOCAB, 4).astype(np.int32), 8)
-            for _ in range(8)]
-    eng = _spec_engine(lm, draft, gamma=6, adapt_gamma=True)
+    # the last request outlives the rest: the tail has an idle slot
+    reqs = [(rng.randint(0, VOCAB, 4).astype(np.int32), n)
+            for n in (8, 8, 8, 8, 8, 8, 8, 20)]
+    # the good draft IS the target: every proposal is accepted
+    eng = _spec_engine(lm, lm if good_draft else draft, gamma=6,
+                       adapt_gamma=True)
     ids = [eng.submit(p, n) for p, n in reqs]     # 8 requests, 2 slots
     trace = []
     while eng.step():
@@ -227,15 +231,18 @@ def test_spec_gamma_adapts_mid_flight(lm, draft):
         np.testing.assert_array_equal(
             results[rid], _oracle(spec, params, prompt, n))
     assert min(trace) < 6, f"gamma never shrank under backlog: {trace}"
-    # The tail (idle slot, empty queue) wants to regrow gamma, but a
-    # bad draft's acceptance EWMA caps it — degradation toward plain
-    # decode wins over the utilization signal.  (The regrow leg with a
-    # GOOD draft is the bench child's load-spike drill.)
     sp = eng.scheduler_stats()["speculative"]
-    assert sp["accept_ewma"] < 6.0
-    cap = max(1, int(round(2 * sp["accept_ewma"])))
-    assert trace[-1] <= min(6, cap), \
-        f"tail gamma {trace[-1]} exceeds the EWMA cap {cap}"
+    if good_draft:
+        assert trace[-1] > min(trace), \
+            f"gamma never regrew after the drain: {trace}"
+    else:
+        # The tail wants to regrow gamma, but a bad draft's acceptance
+        # EWMA caps it — degradation toward plain decode wins over the
+        # utilization signal.
+        assert sp["accept_ewma"] < 6.0
+        cap = max(1, int(round(2 * sp["accept_ewma"])))
+        assert trace[-1] <= min(6, cap), \
+            f"tail gamma {trace[-1]} exceeds the EWMA cap {cap}"
     assert len(sp["gamma_hist"]) > 1      # adaptation actually moved
     eng.assert_no_leaks()
 
