@@ -10,7 +10,9 @@ The DeepSeek-V3 block (arxiv 2412.19437; ``model_type: deepseek_v3``):
   ``q, k: [B, T, H, Dk]`` and ``v: [B, T, H, Dv]`` with ``Dk != Dv``
   (``ops/flash_attention.py`` takes both; the scale is 1/sqrt(Dk)).
 * RMSNorm before attention and FFN, on the latent, and at the end; rotary
-  positions on interleaved pairs; SwiGLU; no bias; an untied head.
+  positions on interleaved pairs of the WEIGHTS' columns, which the
+  kernel's queries and keys hold de-interleaved (``attention_operands``);
+  SwiGLU; no bias; an untied head.
 * ``first_dense`` leading layers with a dense SwiGLU, then expert layers
   (``parallel/moe.py: routed_moe_ffn``): ``top_k`` of ``num_experts`` by
   sigmoid score plus a selection bias, weights renormalised over the picks
@@ -77,44 +79,94 @@ def named_bytes(fn: Callable, *args) -> dict:
     return found
 
 
-def rotary(x: jax.Array, theta: float) -> jax.Array:
-    """Rotary positions on INTERLEAVED pairs: ``(x[2i], x[2i+1])`` of
-    position ``t`` turns by ``t * theta^(-2i/R)``.  ``x``: ``[B, T, ...,
-    R]``, positions along axis 1.  (The checkpoint's code de-interleaves
-    first and turns half against half; queries and keys get the same
-    reordering, so their products are these.)"""
+def _rotary(x: jax.Array, theta: float, split: Tuple[int, int]):
+    """``x [B, T, ..., R]``, positions along axis 1: the pair ``(a, b)`` of
+    position ``t`` and frequency ``i`` turns by ``t * theta^(-2i/R)``.
+    ``split`` says where the last axis keeps its pairs: ``(-1, 2)``
+    interleaved, ``(2, -1)`` as two halves."""
     t, r = x.shape[1], x.shape[-1]
     inv = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
     angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv        # [T,R/2]
     angle = angle.reshape((1, t) + (1,) * (x.ndim - 3) + (r // 2,))
     cos, sin = jnp.cos(angle), jnp.sin(angle)
-    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (r // 2, 2))
-    a, b = pairs[..., 0], pairs[..., 1]
-    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    axis = split.index(2) - 2
+    parts = x.astype(jnp.float32).reshape(x.shape[:-1] + split)
+    a, b = (jax.lax.index_in_dim(parts, i, axis, keepdims=False)
+            for i in (0, 1))
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=axis)
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def latent_attention(p: dict, x: jax.Array, attn_fn: Callable, *,
-                     qk_nope: int, qk_rope: int, theta: float,
-                     eps: float) -> jax.Array:
-    """``x [B, T, D] -> [B, T, D]``.  Leaves: ``wq [D, H, nope + rope]``,
-    ``wkv_a [D, latent + rope]``, ``kv_norm/scale [latent]``, ``wkv_b
-    [latent, H, nope + Dv]``, ``wo [H, Dv, D]``."""
-    heads = p["wq"].shape[1]
+def rotary(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary positions on INTERLEAVED pairs ``(x[2i], x[2i+1])``: what
+    the weights' columns mean.  The model turns ``rotary_halves`` of the
+    same columns de-interleaved."""
+    return _rotary(x, theta, (-1, 2))
+
+
+def rotary_halves(x: jax.Array, theta: float) -> jax.Array:
+    """``rotary`` on DE-INTERLEAVED columns: ``x[..., :R/2]`` holds the
+    pairs' first members and ``x[..., R/2:]`` their second, and they come
+    back so (the checkpoint's own code does this).  The same products and
+    sums as ``rotary`` on the same numbers.  The halves are taken as a
+    DIMENSION of two, not as two slices: the compiler then folds the turn
+    into the product that feeds it; sliced, each half is a 32-wide array
+    padded to the chip's 128 lanes and crosses memory four times over."""
+    return _rotary(x, theta, (2, -1))
+
+
+def _halves_first(w: jax.Array) -> jax.Array:
+    """Interleaved pairs along the last axis to half-split order: the even
+    columns, then the odd ones."""
+    return jnp.concatenate(
+        [jax.lax.slice_in_dim(w, first, None, stride=2, axis=-1)
+         for first in (0, 1)], axis=-1)
+
+
+def attention_operands(p: dict, qk_nope: int) -> dict:
+    """The attention leaves cut the way ``latent_attention`` multiplies
+    them, so that no per-head activation is sliced after its product wrote
+    it: ``wq [D, H, nope + rope]`` into its nope and rope columns and
+    ``wkv_b [latent, H, nope + Dv]`` into keys and values; the rope
+    columns of ``wq`` and ``wkv_a`` gathered to half-split order
+    (``rotary_halves``).  ``wkv_a`` stays one product: what it writes is
+    one vector a token, not one a head.  Weight-sized work: done once a
+    layer, outside the map over sequences and its checkpoint."""
     latent = p["wkv_b"].shape[0]
+    return {"wq_nope": p["wq"][..., :qk_nope],
+            "wq_rope": _halves_first(p["wq"][..., qk_nope:]),
+            "wkv_a": jnp.concatenate(
+                [p["wkv_a"][:, :latent],
+                 _halves_first(p["wkv_a"][:, latent:])], axis=-1),
+            "kv_norm": p["kv_norm"],
+            "wk_b": p["wkv_b"][..., :qk_nope],
+            "wv_b": p["wkv_b"][..., qk_nope:],
+            "wo": p["wo"]}
+
+
+def latent_attention(p: dict, x: jax.Array, attn_fn: Callable, *,
+                     theta: float, eps: float) -> jax.Array:
+    """``x [B, T, D] -> [B, T, D]``.  ``p``: ``attention_operands`` of the
+    leaves ``wq [D, H, nope + rope]``, ``wkv_a [D, latent + rope]``,
+    ``kv_norm/scale [latent]``, ``wkv_b [latent, H, nope + Dv]``, ``wo [H,
+    Dv, D]``.  The kernel's queries and keys hold their rope columns
+    de-interleaved, both alike: every score is the sum of the same
+    products as with interleaved pairs."""
+    heads, latent = p["wo"].shape[0], p["wk_b"].shape[0]
     with jax.named_scope(timeline.SCOPE_MLA_PROJECT):
-        q = jnp.einsum("btd,dhk->bthk", x, p["wq"])
         q = jnp.concatenate(
-            [q[..., :qk_nope], rotary(q[..., qk_nope:], theta)], axis=-1)
+            [jnp.einsum("btd,dhk->bthk", x, p["wq_nope"]),
+             rotary_halves(jnp.einsum("btd,dhk->bthk", x, p["wq_rope"]),
+                           theta)], axis=-1)
         kv_a = x @ p["wkv_a"]
         c = rms_norm(kv_a[..., :latent], p["kv_norm"]["scale"], eps)
-        k_rope = rotary(kv_a[..., latent:], theta)           # [B, T, rope]
-        kv = jnp.einsum("btc,chk->bthk", c, p["wkv_b"])
+        k_rope = rotary_halves(kv_a[..., latent:], theta)    # [B, T, rope]
         k = jnp.concatenate(
-            [kv[..., :qk_nope],
+            [jnp.einsum("btc,chk->bthk", c, p["wk_b"]),
              jnp.broadcast_to(k_rope[:, :, None, :],
-                              k_rope.shape[:2] + (heads, qk_rope))], axis=-1)
-        v = kv[..., qk_nope:]
+                              k_rope.shape[:2] + (heads,)
+                              + k_rope.shape[2:])], axis=-1)
+        v = jnp.einsum("btc,chk->bthk", c, p["wv_b"])
     # the kernel's HLO name is the innermost scope: ``attn``, as in the
     # flax blocks (``MultiHeadAttention`` is named so)
     with jax.named_scope(timeline.SCOPE_MLA_ATTENTION), \
@@ -199,12 +251,15 @@ def mla_moe_lm(vocab_size: int = 16032, num_layers: int = 5,
             params[f"layers_{i}"] = layer
         return params
 
+    def operands(lp):
+        return dict(lp, attn=attention_operands(lp["attn"], qk_nope))
+
     def layer_fn(lp, x):
-        """One sequence ``[1, T, D]`` through one layer."""
+        """One sequence ``[1, T, D]`` through one layer; ``lp``: the
+        layer's leaves with the attention's as ``attention_operands``."""
         x = x + latent_attention(
             lp["attn"], rms_norm(x, lp["ln_attn"]["scale"], rms_eps),
-            attn_fn, qk_nope=qk_nope, qk_rope=qk_rope, theta=rope_theta,
-            eps=rms_eps)
+            attn_fn, theta=rope_theta, eps=rms_eps)
         h = rms_norm(x, lp["ln_mlp"]["scale"], rms_eps)
         if "mlp" in lp:
             return x + swiglu(lp["mlp"], h), None
@@ -230,7 +285,8 @@ def mla_moe_lm(vocab_size: int = 16032, num_layers: int = 5,
             lp = params[f"layers_{i}"]
             dense = "mlp" in lp
             if dense not in kinds:
-                kinds[dense] = named_bytes(one_layer, lp, x[:1])
+                kinds[dense] = named_bytes(
+                    one_layer, jax.eval_shape(operands, lp), x[:1])
             for name in KEPT_NAMES:
                 kept[name] += kinds[dense].get(name, 0) * x.shape[0]
         return kept
@@ -242,7 +298,11 @@ def mla_moe_lm(vocab_size: int = 16032, num_layers: int = 5,
         products and their cotangents) is then a sequence's, not the
         batch's: 4 x 4096 tokens at the benchmark's widths ask for 20 GB
         otherwise.  The price: each weight's gradient is summed over the
-        sequences instead of formed in one product."""
+        sequences instead of formed in one product.  The attention's
+        weights are cut for their products here, once, and not under the
+        map: inside it the cuts' transposes would pad and add weight-sized
+        buffers every sequence of the backward."""
+        lp = operands(lp)
         x, counts = jax.lax.map(lambda row: layer_fn(lp, row[None]), x)
         return x[:, 0], None if counts is None else counts.sum(axis=0)
 

@@ -459,7 +459,11 @@ def train_moe_phase(model: dict, *, batch_size: int, steps: int) -> dict:
     compiled step holds the Pallas attention calls one sequence at a time
     with keys wider than values, a forward and a backward a layer and no
     third: the layers' checkpoints keep ``o`` and ``lse`` by name, and the
-    gauge says how many bytes that holds."""
+    gauge says how many bytes that holds.  The kernel's q and k hold their
+    rope columns DE-INTERLEAVED (the weights' even columns, then the odd
+    ones; ``mla_moe_lm.attention_operands``): scores are unchanged, but a
+    decode path or a latent cache must not assume interleaved pairs at the
+    kernel's boundary."""
     import jax
     import numpy as np
 
@@ -500,6 +504,7 @@ def train_moe_phase(model: dict, *, batch_size: int, steps: int) -> dict:
                 f"layers: the rematerialised layer runs its forward kernel "
                 f"again")
         facts["attention_calls"] = len(attn)
+        facts["rope_columns_at_kernel"] = "de-interleaved, q and k alike"
         facts["bytes_in_use"] = memory_in_use(jax.devices()[:1])
     from autodist_tpu.telemetry.registry import DEFAULT_REGISTRY
 

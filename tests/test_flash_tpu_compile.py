@@ -1,4 +1,5 @@
-"""The flash kernels compiled for a TPU v5e that is described, not attached.
+"""The flash kernels, and the latent attention around them, compiled for a
+TPU v5e that is described, not attached.
 
 Interpret mode cannot see what Mosaic refuses (a lane index it cannot prove
 aligned, a transpose of an odd shape, more VMEM than a kernel may hold), so
@@ -8,7 +9,10 @@ results or speed.  The topology is described inside a fixture (only the
 worker that is given this file loads the TPU's library), and every such
 test lives in this one file.
 """
+import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -89,6 +93,59 @@ def test_forward_and_fused_backward_compile(one_chip, name, precision):
 def _pallas_calls(compiled):
     return [ln for ln in compiled.as_text().splitlines()
             if 'custom_call_target="tpu_custom_call"' in ln]
+
+
+def test_latent_attention_writes_each_kernel_operand_once(one_chip):
+    """One layer's latent attention at the kanana cell's widths, value and
+    gradient, four sequences mapped under the layer's checkpoint as
+    ``mla_moe_lm`` runs them.  The mechanism of PR 30 is static, so its
+    guard is this compile and no runtime counter: XLA's own count of bytes
+    (8.29e9 with the weights' products sliced after they were written and
+    the rotary turn on a minor dimension of two; 7.24e9 as shipped), and
+    no ``slice_bitcast`` or ``pad`` fusion that writes an activation of
+    32 MB or more to the chip's main memory (what is left by those names
+    joins a weight's gradient once a layer, or lives in the fast memory,
+    ``S(1)``, and takes microseconds)."""
+    from autodist_tpu.models.mla_moe_lm import (
+        _KEEP_NAMED,
+        attention_operands,
+        latent_attention,
+    )
+    from autodist_tpu.ops import flash_attention
+
+    d, heads, nope, rope, dv, latent, t = 2048, 32, 128, 64, 128, 512, 4096
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    leaves = {"wq": shape(d, heads, nope + rope),
+              "wkv_a": shape(d, latent + rope),
+              "kv_norm": {"scale": shape(latent)},
+              "wkv_b": shape(latent, heads, nope + dv),
+              "wo": shape(heads, dv, d)}
+
+    @functools.partial(jax.checkpoint, policy=_KEEP_NAMED, prevent_cse=False)
+    def one_sequence(p, row):
+        return latent_attention(
+            p, row[None], functools.partial(flash_attention, interpret=False),
+            theta=1e6, eps=1e-6)[0]
+
+    def loss(p, x, cotangent):
+        p = attention_operands(p, nope)
+        return jnp.vdot(jax.lax.map(lambda row: one_sequence(p, row), x),
+                        cotangent)
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        leaves, shape(4, t, d), shape(4, t, d)).compile()
+    assert len(_pallas_calls(compiled)) == 2
+    assert compiled.cost_analysis()["bytes accessed"] <= 7.3e9
+    written = re.compile(
+        r"^\s*(?:ROOT )?%((?:slice_bitcast|pad_)[\w.\-]*) = "
+        r"\(?f32\[([\d,]+)\]\{([^}]*)\}", re.M)
+    for name, dims, layout in written.findall(compiled.as_text()):
+        dims = tuple(map(int, dims.split(",")))
+        assert not (t in dims and "S(1)" not in layout
+                    and 4 * math.prod(dims) >= 32e6), (name, dims, layout)
 
 
 def test_data_parallel_over_four_chips_compiles(chips):

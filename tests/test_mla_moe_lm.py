@@ -22,11 +22,15 @@ import numpy as np
 import optax
 import pytest
 
+from autodist_tpu.models.base import rms_norm
 from autodist_tpu.models.mla_moe_lm import (
     KEPT_NAMES,
+    attention_operands,
     equations,
+    latent_attention,
     mla_moe_lm,
     rotary,
+    rotary_halves,
 )
 from autodist_tpu.models.transformer import dense_attention
 from autodist_tpu.ops import flash_attention
@@ -114,6 +118,163 @@ def test_rotary_turns_interleaved_pairs_and_keeps_products_relative():
     np.testing.assert_allclose(dots[0, 0, 0, 2], dots[0, 0, 3, 5],
                                rtol=1e-5)
     np.testing.assert_allclose(ref._rotary(x, 1e6), y, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the attention's products write the kernel's operands (PR 30)
+# ---------------------------------------------------------------------------
+def parent_latent_attention(p, x, attn_fn, *, qk_nope, qk_rope, theta, eps):
+    """Latent attention as it was written up to PR 29: one product a
+    weight, its activation sliced after, interleaved ``rotary``.  The
+    definition the rewritten one is held to."""
+    heads, latent = p["wq"].shape[1], p["wkv_b"].shape[0]
+    q = jnp.einsum("btd,dhk->bthk", x, p["wq"])
+    q = jnp.concatenate(
+        [q[..., :qk_nope], rotary(q[..., qk_nope:], theta)], axis=-1)
+    kv_a = x @ p["wkv_a"]
+    c = rms_norm(kv_a[..., :latent], p["kv_norm"]["scale"], eps)
+    k_rope = rotary(kv_a[..., latent:], theta)
+    kv = jnp.einsum("btc,chk->bthk", c, p["wkv_b"])
+    k = jnp.concatenate(
+        [kv[..., :qk_nope],
+         jnp.broadcast_to(k_rope[:, :, None, :],
+                          k_rope.shape[:2] + (heads, qk_rope))], axis=-1)
+    return jnp.einsum("bthv,hvd->btd",
+                      attn_fn(q, k, kv[..., qk_nope:], True), p["wo"])
+
+
+# distinct sizes everywhere, so that a shape names its array
+MLA = dict(d=40, heads=4, nope=24, rope=8, dv=16, latent=48, t=32)
+
+
+def attention_leaves(seed, w=MLA):
+    def normal(k, *shape):
+        return jax.random.normal(k, shape) * 0.3
+
+    k = jax.random.split(jax.random.key(seed), 5)
+    return {"wq": normal(k[0], w["d"], w["heads"], w["nope"] + w["rope"]),
+            "wkv_a": normal(k[1], w["d"], w["latent"] + w["rope"]),
+            "kv_norm": {"scale": 1.0 + normal(k[2], w["latent"])},
+            "wkv_b": normal(k[3], w["latent"], w["heads"],
+                            w["nope"] + w["dv"]),
+            "wo": normal(k[4], w["heads"], w["dv"], w["d"])}
+
+
+def test_rewritten_attention_is_the_parents_in_outputs_and_gradients():
+    """Outputs and the gradient of every leaf, under the leaves' own names
+    and shapes, within 1e-6 relative of the parent's formulation: the same
+    dot products over the same terms, the rope columns in another order
+    on queries and keys alike."""
+    p = attention_leaves(0)
+    x = jax.random.normal(jax.random.key(1), (2, MLA["t"], MLA["d"]))
+    ct = jax.random.normal(jax.random.key(2), x.shape)
+    kw = dict(theta=1e4, eps=1e-6)
+
+    def new(p, x):
+        return latent_attention(attention_operands(p, MLA["nope"]), x,
+                                dense_attention, **kw)
+
+    def old(p, x):
+        return parent_latent_attention(
+            p, x, dense_attention, qk_nope=MLA["nope"], qk_rope=MLA["rope"],
+            **kw)
+
+    with jax.default_matmul_precision("highest"):
+        got, want = new(p, x), old(p, x)
+        g_got = jax.grad(lambda p, x: jnp.vdot(new(p, x), ct), (0, 1))(p, x)
+        g_want = jax.grad(lambda p, x: jnp.vdot(old(p, x), ct), (0, 1))(p, x)
+    assert rel(got, want) < 1e-6
+    got, want = ref._flat(g_got[0], np.asarray), ref._flat(g_want[0],
+                                                            np.asarray)
+    assert {k: v.shape for k, v in got.items()} \
+        == {k: v.shape for k, v in ref._flat(p, np.asarray).items()}
+    for name in want:
+        assert rel(got[name], want[name]) < 1e-6, name
+    assert rel(g_got[1], g_want[1]) < 1e-6
+
+
+@pytest.mark.parametrize("shape", [(1, 6, 2, 8), (2, 5, 12)])
+def test_half_split_rotary_is_rotary_of_the_interleaved_columns(shape):
+    """De-interleave, turn half against half: ``rotary`` of the interleaved
+    columns after the same permutation, to the bit."""
+    x = jax.random.normal(jax.random.key(3), shape)
+    r = shape[-1]
+    order = np.concatenate([np.arange(0, r, 2), np.arange(1, r, 2)])
+    np.testing.assert_array_equal(rotary_halves(x[..., order], 1e4),
+                                  rotary(x, 1e4)[..., order])
+
+
+def layer_gradient_jaxpr(remat, **sizes):
+    spec = mla_moe_lm(**dict(TINY, **sizes), experts_held=(4, 4),
+                      remat=remat)
+    params = jax.eval_shape(spec.init, jax.random.key(0))
+    return params, jax.make_jaxpr(jax.grad(spec.loss_fn))(
+        params, {"tokens": tokens(0, rows=2)}).jaxpr
+
+
+def test_gradient_holds_no_pair_dimension_and_slices_no_kv_activation():
+    """One dense and one expert layer: no intermediate whose minor
+    dimension is a pair, and nothing cut from (or padded back to) an
+    activation as wide as ``wkv_b``'s or ``wq``'s product."""
+    sizes = dict(num_layers=2, num_heads=4, qk_nope=24, qk_rope=8,
+                 v_head=16, kv_lora=20)
+    _, jaxpr = layer_gradient_jaxpr("full", **sizes)
+    t, heads = TINY["seq_len"], 4
+    whole = {(1, t, heads, 24 + 16), (1, t, heads, 24 + 8)}
+    for eqn in equations(jaxpr):
+        for var in eqn.outvars:
+            assert not (var.aval.ndim >= 3 and var.aval.shape[-1] == 2), eqn
+        if eqn.primitive.name in ("slice", "pad"):    # a cut, or its transpose
+            assert not whole.intersection(
+                {eqn.invars[0].aval.shape, eqn.outvars[0].aval.shape}), eqn
+    # the parent's formulation trips both
+    p = jax.eval_shape(lambda: attention_leaves(0))
+    old = jax.make_jaxpr(lambda p, x: parent_latent_attention(
+        p, x, dense_attention, qk_nope=24, qk_rope=8, theta=1e4,
+        eps=1e-6))(p, jnp.zeros((1, 32, 40))).jaxpr
+    shapes = [v.aval.shape for e in equations(old) for v in e.outvars]
+    assert any(s[-1] == 2 for s in shapes if len(s) >= 3)
+    assert any(e.primitive.name == "slice"
+               and e.invars[0].aval.shape == (1, 32, 4, 24 + 16)
+               for e in equations(old))
+
+
+def inside_and_outside_the_map(jaxpr, inside=False):
+    """``(equation, under a scan)`` for every equation of a jaxpr."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from inside_and_outside_the_map(
+                inner, inside or eqn.primitive.name == "scan")
+
+
+@pytest.mark.parametrize("remat", ["none", "full", "dots"])
+def test_weights_are_cut_once_a_layer_outside_the_mapped_body(remat):
+    """Outside the body mapped over sequences the forward cuts each
+    attention weight twice a layer (``wq``: nope, rope; ``wkv_a``: latent,
+    rope; ``wkv_b``: keys, values) and de-interleaves the two rope parts,
+    and the backward pads the parts' gradients back, once.  Inside the
+    body (and its transpose) nothing is de-interleaved, and ``wq`` and
+    ``wkv_b`` never appear whole (``wkv_a`` stays one product)."""
+    params, jaxpr = layer_gradient_jaxpr(remat)
+    attn = params["layers_0"]["attn"]
+    whole = {attn[name].shape for name in ("wq", "wkv_a", "wkv_b")}
+    assert len(whole) == 3
+    cuts, strided, joins = 0, 0, 0
+    for eqn, mapped in inside_and_outside_the_map(jaxpr):
+        stride = eqn.primitive.name == "slice" and any(
+            s > 1 for s in eqn.params["strides"] or ())
+        if mapped:
+            shapes = {v.aval.shape for v in eqn.invars + eqn.outvars}
+            assert not stride and not shapes.intersection(
+                {attn["wq"].shape, attn["wkv_b"].shape}), eqn
+        elif eqn.primitive.name == "slice":
+            cuts += eqn.invars[0].aval.shape in whole
+            strided += stride
+        elif eqn.primitive.name == "pad":
+            joins += eqn.outvars[0].aval.shape in whole
+    layers = TINY["num_layers"]
+    assert (cuts, strided, joins) == (6 * layers, 4 * layers, 6 * layers)
 
 
 def moe_layer(seed, held_count=16, d=32, f=12, total=16):
@@ -293,7 +454,8 @@ def test_keeping_by_name_changes_no_number(remat, monkeypatch):
     the bit: the backward is handed the ``o``, ``lse`` and picks it would
     have recomputed.  Against no rematerialisation at all the loss and
     every leaf are equal too, but for the RMSNorm scales before attention
-    and FFN: XLA's CPU backend fuses their sum over tokens with the
+    and FFN and (since PR 30 cut the product after it in two) on the
+    latent: XLA's CPU backend fuses their sum over tokens with the
     recomputed norm in another order (a few 1e-7, under any policy)."""
     batch = {"tokens": tokens(2, rows=2)}
     out = {}
@@ -305,7 +467,8 @@ def test_keeping_by_name_changes_no_number(remat, monkeypatch):
         out[which] = dict(ref._flat(grads, np.asarray), loss=loss)
     for name, got in out[remat].items():
         np.testing.assert_array_equal(got, out["bare"][name], name)
-        if name.endswith(("ln_attn/scale", "ln_mlp/scale")):
+        if name.endswith(("ln_attn/scale", "ln_mlp/scale",
+                          "kv_norm/scale")):
             assert rel(got, out["none"][name]) < 1e-6, name
         else:
             np.testing.assert_array_equal(got, out["none"][name], name)
