@@ -88,14 +88,21 @@ def _mu_leaves(opt_state):
     return found[0].mu
 
 
-def worst_leaf_gap(got: dict, want: dict) -> float:
+def worst_leaf(got: dict, want: dict) -> tuple:
     """Largest |got - want| over leaves, against the reference's norm of
-    that leaf or of the median leaf, whichever is larger."""
+    that leaf or of the median leaf, whichever is larger, and that leaf's
+    name."""
     if set(got) != set(want):
         raise SystemExit("benchmark: the program's and the reference's "
                          "parameter trees differ in their leaves")
     floor = statistics.median(want.values())
-    return max(abs(got[k] - want[k]) / max(want[k], floor) for k in want)
+    gap = {k: abs(got[k] - want[k]) / max(want[k], floor) for k in want}
+    worst = max(gap, key=gap.get)
+    return gap[worst], worst
+
+
+def worst_leaf_gap(got: dict, want: dict) -> float:
+    return worst_leaf(got, want)[0]
 
 
 def sample_errors(got: dict, want: dict) -> tuple:
@@ -213,7 +220,10 @@ def slowest_steps(run, step_s, keep: int = 3):
           f"slowest {rows}", flush=True)
 
 
-def run(run):
+def run(run, reference=None):
+    """``reference``: what :func:`reference_steps` gave for this seed,
+    where the caller has it already (``control.py`` judges a sound and a
+    broken program on one seed against one reading of the reference)."""
     import jax
 
     from benchmark import traffic, weights
@@ -228,13 +238,14 @@ def run(run):
     check_batches = [next(batches) for _ in range(CHECK_STEPS)]
 
     # -- the reference first: its state is gone before the program's is made
-    with run.outside_setup(), run.spans("bench/reference"):
-        t0 = time.perf_counter()
-        ref_losses, ref_grad, ref_delta, ref_sample = reference_steps(
-            run, shapes, check_batches)
-        run.counters["reference_s"] = time.perf_counter() - t0
-    print(f"reference: {CHECK_STEPS} steps in "
-          f"{run.counters['reference_s']:.1f} s (not set-up)", flush=True)
+    if reference is None:
+        with run.outside_setup(), run.spans("bench/reference"):
+            t0 = time.perf_counter()
+            reference = reference_steps(run, shapes, check_batches)
+            run.counters["reference_s"] = time.perf_counter() - t0
+        print(f"reference: {CHECK_STEPS} steps in "
+              f"{run.counters['reference_s']:.1f} s (not set-up)", flush=True)
+    ref_losses, ref_grad, ref_delta, ref_sample = reference
 
     # -- the program: one session, checked, then measured
     params = weights.make_weights(shapes, run.seed)
@@ -267,17 +278,19 @@ def run(run):
     with run.spans("bench/warm"):       # one more step, off the record
         _step(run, sess, batches)
 
+    loss_gaps = [abs(a - b) for a, b in zip(losses, ref_losses)]
     run.counters["check_losses"] = {"program": losses,
-                                    "reference": ref_losses}
-    run.check("loss_gap_max",
-              max(abs(a - b) for a, b in zip(losses, ref_losses)),
-              limits["loss_gap_max"])
-    run.check("first_grad_norm_gap_worst_leaf",
-              worst_leaf_gap(first_grad, ref_grad),
+                                    "reference": ref_losses,
+                                    "gaps": loss_gaps}
+    run.check("loss_gap_max", max(loss_gaps), limits["loss_gap_max"])
+    grad_gap, grad_leaf = worst_leaf(first_grad, ref_grad)
+    run.check("first_grad_norm_gap_worst_leaf", grad_gap,
               limits["first_grad_norm_gap_worst_leaf"])
-    run.check("param_change_norm_gap_worst_leaf",
-              worst_leaf_gap(delta, ref_delta),
+    delta_gap, delta_leaf = worst_leaf(delta, ref_delta)
+    run.check("param_change_norm_gap_worst_leaf", delta_gap,
               limits["param_change_norm_gap_worst_leaf"])
+    print(f"worst leaves: first gradient {grad_leaf}, change {delta_leaf}; "
+          f"loss gap by step {loss_gaps}", flush=True)
     run.check("product_operands_narrower_than_stated", narrow, 0)
     pooled, worst = sample_errors(first_sample, ref_sample)
     run.check("first_grad_sample_rel_err", pooled,

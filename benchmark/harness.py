@@ -389,9 +389,20 @@ def result_line(run: Run) -> dict:
     else:
         line["metrics"] = end_to_end_metrics(run)
     line["device"] = device
+    # every number compared beside its limit, last in the line
+    line["checks"] = {
+        name: {"value": value if value is None or value == value
+               else "nan", "limit": limit, "ok": ok}
+        for name, value, limit, ok in run.checks}
     return line
 
 
 def emit(line: dict):
+    """The compared numbers as the last lines of standard error, then the
+    result as the last line of standard output."""
     sys.stdout.flush()
+    for name, c in line.get("checks", {}).items():
+        print(f"check {name}: {c['value']!r} limit {c['limit']!r} "
+              f"{'ok' if c['ok'] else 'NOT CORRECT'}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)

@@ -1,10 +1,12 @@
 """Cut a recorded ``.xplane.pb`` down to a file small enough to keep.
 
     python benchmark/tests/cut_trace.py <in.xplane.pb> <out.xplane.pb> \
-        [--runs 2] [--min-us 30] [--keep REGEX]
+        [--runs 2] [--min-us 30] [--keep REGEX] [--skip 1]
 
 Keeps, for every TPU plane, the first ``--runs`` whole program runs of the
-``XLA Modules`` line and the ops of ``XLA Ops`` inside them that last at
+``XLA Modules`` line after the first ``--skip`` (the trace's edge may have
+cut the first; 0 for a file that is a cut already) and the ops of
+``XLA Ops`` inside them that last at
 least ``--min-us`` or whose name matches ``--keep`` (kernels,
 collectives), and the host's ``bench/...`` spans that overlap them.
 Names, starts and durations are the recorded ones; nothing is invented.
@@ -26,8 +28,9 @@ def main(argv=None) -> int:
     ap.add_argument("dst")
     ap.add_argument("--runs", type=int, default=2)
     ap.add_argument("--min-us", type=float, default=30.0)
-    ap.add_argument("--keep", default=r"custom_call_target|all-reduce|"
-                    r"reduce-scatter|all-gather|collective-permute")
+    ap.add_argument("--skip", type=int, default=1)
+    ap.add_argument("--keep", default=r"tpu_custom_call|^%?(?:all-reduce|"
+                    r"reduce-scatter|all-gather|collective-permute)")
     args = ap.parse_args(argv)
     keep = re.compile(args.keep)
     data = ProfileData.from_file(args.src)
@@ -37,7 +40,7 @@ def main(argv=None) -> int:
             continue
         lines = {ln.name: list(ln.events) for ln in plane.lines}
         mods = sorted(lines.get("XLA Modules", []),
-                      key=lambda e: e.start_ns)[1:1 + args.runs]
+                      key=lambda e: e.start_ns)[args.skip:args.skip + args.runs]
         if not mods:
             continue
         lo, hi = mods[0].start_ns, mods[-1].start_ns + mods[-1].duration_ns

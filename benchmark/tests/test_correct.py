@@ -8,9 +8,12 @@ Two kinds of test, at a size a test run can hold (the cells under
   leaves the tolerance;
 * the timed path BROKEN underneath a whole run (the chip check skipped,
   the rest of ``run.py`` driven): a step that returns its state
-  unchanged, a step that leaves out half of the batch, a token altered
-  where it is produced.
+  unchanged, a step that leaves out half of the batch (on four devices:
+  one device's rows), a token altered where it is produced.  The broken
+  sessions are ``control.py``'s, which reads them on the chip at a cell's
+  own size.
 """
+import json
 import time
 
 import jax
@@ -19,6 +22,8 @@ import numpy as np
 import pytest
 
 import benchmark.run as brun
+from benchmark import harness
+from benchmark.control import Frozen, RowsLeftOut
 from benchmark.entries import serve, train
 
 
@@ -44,6 +49,12 @@ def test_train_sound_run_is_correct(tiny_cell, capsys):
                  "param_change_norm_gap_worst_leaf",
                  "first_grad_sample_rel_err"):
         assert f"check {name}:" in out
+        assert line["checks"][name]["ok"] is True
+    assert list(line)[-1] == "checks"   # and last in the result's line
+    harness.emit(line)                  # and as the last lines of stderr
+    io = capsys.readouterr()
+    assert io.err.splitlines()[-1].startswith("check window_losses")
+    assert json.loads(io.out.splitlines()[-1]) == line
 
 
 def test_train_on_a_data_axis_of_four_is_correct(tiny_cell):
@@ -54,43 +65,40 @@ def test_train_on_a_data_axis_of_four_is_correct(tiny_cell):
     assert line["device"]["count"] == 4
 
 
-class _Frozen:
-    """A session whose step computes its loss and returns its state
-    unchanged."""
-
-    def __init__(self, sess):
-        self._sess = sess
-
-    def run(self, batch):
-        return self._sess.evaluate(batch)
-
-    def __getattr__(self, name):
-        return getattr(self._sess, name)
-
-
-class _HalfBatch(_Frozen):
-    """A session whose step leaves out the second half of the batch."""
-
-    def run(self, batch):
-        rows = batch["tokens"].shape[0]
-        half = batch["tokens"][:rows // 2]
-        return self._sess.run({"tokens": np.concatenate([half, half])})
-
-
+@pytest.mark.parametrize("cell", ["train.gpt2-tiny.cpu",
+                                  "train.gpt2-tiny.cpu4"])
 @pytest.mark.parametrize("broken,failing", [
-    (_Frozen, "param_change_norm_gap_worst_leaf"),
-    (_HalfBatch, "loss_gap_max"),
+    (Frozen, "param_change_norm_gap_worst_leaf"),
+    (RowsLeftOut, "loss_gap_max"),
 ])
 def test_train_broken_step_is_not_correct(tiny_cell, monkeypatch, capsys,
-                                          broken, failing):
+                                          broken, failing, cell):
     build = train.build_session
     monkeypatch.setattr(train, "build_session",
                         lambda *a, **k: broken(build(*a, **k)))
-    line = drive(tiny_cell("train.gpt2-tiny.cpu"))
+    line = drive(tiny_cell(cell))
     assert line["correct"] is False
     out = capsys.readouterr().out
     bad = [ln for ln in out.splitlines() if "NOT CORRECT" in ln]
     assert any(failing in ln for ln in bad), out
+    assert line["checks"][failing]["ok"] is False
+
+
+def test_rows_left_out_are_one_devices_rows():
+    """On one device the second half of the batch, on four the last
+    device's rows; the first rows take their place."""
+    class Sess:
+        def __init__(self, n):
+            self.mesh = type("M", (), {"size": n})
+
+        def run(self, batch):
+            return batch["tokens"][:, 0].tolist()
+
+    tokens = np.arange(8)[:, None] * np.ones((1, 3), np.int32)
+    assert RowsLeftOut(Sess(1)).run({"tokens": tokens}) == [
+        0, 1, 2, 3, 0, 1, 2, 3]
+    assert RowsLeftOut(Sess(4)).run({"tokens": tokens}) == [
+        0, 1, 2, 3, 4, 5, 0, 1]
 
 
 CONTROLS = pytest.mark.parametrize(

@@ -1,10 +1,15 @@
 """The trace reduction: interval arithmetic on a hand-made trace, and the
-recorded cuts of the benchmark's own cells."""
+recorded cuts of the benchmark's own cells with the gpt2 cells' readers
+on them (one chip: PR 23's traced run; four chips: seed 3100000303 of
+PR 31, one whole step a chip, operations of 200 us or more, every kernel
+and every collective)."""
 import os
+import time
 
+import jax
 import pytest
 
-from benchmark import xplane
+from benchmark import harness, xplane
 
 TRACES = os.path.join(os.path.dirname(os.path.abspath(__file__)), "traces")
 
@@ -59,6 +64,10 @@ def test_exposed_collective_overlap(hand):
     assert hand.exposed_seconds() == pytest.approx((10e-6 + 0) / 2)
     assert hand.op_seconds(xplane.COLLECTIVE) == pytest.approx(
         (12e-6 + 10e-6) / 2)
+    # by the operation's own name: one that reads an all-reduce is none
+    assert xplane.COLLECTIVE.search("%all-reduce-start.4 = f32[8] all-red")
+    assert not xplane.COLLECTIVE.search(
+        "%convert_fusion.2 = bf16[8]{0} fusion(f32[8]{0} %all-reduce.3)")
 
 
 def test_kernel_time_by_name(hand):
@@ -96,17 +105,62 @@ def test_union_and_subtract():
 def test_recorded_cut(name):
     """A cut of a trace recorded on the v5e reduces to sane numbers: the
     chip is busy most of a training step, the flash kernels are found by
-    name three to a layer and step, exposed collective time is within the
-    collectives' own time."""
+    name two or three to a layer and step, exposed collective time is
+    within the collectives' own time."""
     red = xplane.reduce(os.path.join(TRACES, name))
     assert red.chips and red.window_s > 0
     assert 0 < red.busy_s <= red.window_s
     assert 0 <= red.idle_share < 1
     runs, calls, seconds = red.ops_in_module_runs(
-        r"^jit_step\b", r'^%?attn[\w.\-]* = .*tpu_custom_call')
-    assert runs >= 1 and calls == runs * 24 * 3 and seconds > 0
+        r"^jit_step\b",
+        r'^%?(?:attn|shard_map)[\w.\-]* = .*tpu_custom_call')
+    # a forward and a backward kernel a layer, two backward before PR 25
+    assert runs >= 1 and calls in (runs * 24 * 2, runs * 24 * 3)
+    assert seconds > 0
     assert red.exposed_seconds() <= red.op_seconds(xplane.COLLECTIVE) + 1e-12
     if len(red.chips) > 1:
         assert red.op_seconds(xplane.COLLECTIVE) > 0
-    assert any(n.startswith("attn ") for n, _ in red.top_ops())
+    assert any(n.startswith(("attn ", "shard_map "))
+               for n, _ in red.top_ops())
     assert red.idle_gaps()[0][0].startswith("bench/")
+
+
+def traced_run(cell, chips):
+    """A run of ``cell`` that has traced the recorded cut of its name."""
+    run = harness.Run(harness.Cell(cell), 1, 1.0, True,
+                      jax.devices()[:chips], time.perf_counter())
+    run.peaks = {"flops_per_s_bf16": 197e12, "hbm_bytes_per_s": 819e9}
+    run.trace_reduction = xplane.reduce(
+        os.path.join(TRACES, cell + ".cut.xplane.pb"), chips)
+    run.counters.update(global_batch=4 * chips, seq_len=1024)
+    return run, lambda metric: harness._load_reader(run.cell, metric)(run)
+
+
+def test_one_chip_cut_reads_what_it_read(capsys):
+    """The kernel is ``%attn`` on one chip: 72 calls a step, 21.37 % of a
+    roofline bound by bytes (the trace is older than PR 25's fused
+    backward kernel); no second chip, so no exposed collective."""
+    run, read = traced_run("train.gpt2-medium.1chip", 1)
+    assert read("flash_attention_roofline") == pytest.approx(21.36794, abs=1e-4)
+    assert "144 calls in 2 steps" in capsys.readouterr().out
+    assert read("collective_exposed_pct") is None
+    assert read("device_idle_pct.train") == pytest.approx(28.116, abs=1e-2)
+
+
+def test_four_chip_cut_finds_the_kernel_and_the_exposed_all_reduce(capsys):
+    """Under ``shard_map`` XLA names the Pallas call ``%shard_map``; the
+    reader finds it by either name and by ONE CHIP'S rows in its first
+    result, and reads what the one-chip cell reads of the same kernel.  A
+    kernel over the global 16 rows is not this chip's and is not read."""
+    run, read = traced_run("train.gpt2-medium.dp4", 4)
+    assert len(run.trace_reduction.chips) == 4
+    share = read("flash_attention_roofline")
+    assert "48 calls in 1 steps" in capsys.readouterr().out
+    assert share == pytest.approx(35.016, abs=0.01)     # one chip: 36.09
+    # twelve all-reduces a step, 26.5 ms of 125.2, nothing beside them
+    red = run.trace_reduction
+    assert red.exposed_seconds() == pytest.approx(
+        red.op_seconds(xplane.COLLECTIVE))
+    assert read("collective_exposed_pct") == pytest.approx(21.164, abs=0.01)
+    run.counters["global_batch"] = 64
+    assert read("flash_attention_roofline") is None

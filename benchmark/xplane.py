@@ -29,9 +29,11 @@ HOST_PLANE = "/host:CPU"
 SPAN_PREFIX = "bench/"
 #: control-flow ops whose event spans the events of their own bodies
 CONTAINER = re.compile(r"^%?(while|conditional|call)[\w.\-]* = ")
+#: by the operation's own name, at the start: the text of an event also
+#: names its operands, and an operation that READS ``%all-reduce.7`` is none
 COLLECTIVE = re.compile(
-    r"all-reduce|reduce-scatter|all-gather|all-to-all|collective-permute",
-    re.I)
+    r"^%?(?:all-reduce|reduce-scatter|all-gather|all-to-all|"
+    r"collective-permute)", re.I)
 
 
 def stable_name(name: str) -> str:
