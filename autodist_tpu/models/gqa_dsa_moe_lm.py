@@ -1,0 +1,376 @@
+"""Decoder LM with grouped-query heads that attend only to the keys a
+learned indexer selects, and softmax-routed experts.
+
+The language model of Keye-VL-2.0 (``model_type: KeyeVL2``): a Qwen3-MoE
+block whose attention is DeepSeek sparse attention (DeepSeek-AI 2025, the
+DeepSeek-V3.2-Exp report).  One layer, ``h = RMSNorm(x)``:
+
+* **Main heads.**  ``q = h W_q [T, H, Dh]``, ``k = h W_k``, ``v = h W_v``
+  ``[T, G, Dh]``, no bias; RMSNorm over ``Dh`` of every query and key
+  head, then rotary on half-split pairs (``rotary_halves``: what the
+  weights' columns mean here).  Query head ``j`` reads key/value head
+  ``j // (H // G)``; ``ops/flash_attention.py`` repeats the index, never
+  the keys.
+* **Indexer.**  ``qI = h W_qI [T, J, Di]``, ``kI = LayerNorm(h W_kI)
+  [T, Di]``, ``w = h W_w [T, J]``, rotary on both;
+  ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])`` for ``s <= t``, in
+  float32 at ``highest`` so that the selection does not turn on rounding.
+  ``S_t``: the ``topk`` largest of row ``t`` (all of ``0..t`` before row
+  ``topk``), ties to the lower position: ``ops/topk_select.py`` finds each
+  row's threshold without sorting, one block of ``index_rows`` queries at
+  a time against the keys at or before the block's last row, and
+  ``pack_selection`` writes one bit a pair.  Rows that select everything
+  form no score.  The cross-entropy has no path into the indexer (its own
+  alignment loss is not written): its leaves get a zero gradient.
+* **Attention** over ``S_t`` alone, by a mask inside the flash kernel:
+  every causal tile is computed (``autodist_dsa_pairs_per_step`` says how
+  many pairs that is beside the pairs selected).
+* **Experts** (``parallel/moe.py: routed_moe_ffn``, ``scoring="softmax"``):
+  top-``k`` of a softmax over all experts, weights renormalised over the
+  picks, no bias, none shared, no token dropped; ``experts_held`` is this
+  chip's share.  The layer takes ``moe_slice`` tokens at a time: the
+  ``slice * k`` static rows of the grouped products are then a slice's.
+
+Functional, like ``mla_moe_lm.py``; the training path only.
+"""
+from __future__ import annotations
+
+from typing import Callable, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.ad_checkpoint import checkpoint_name
+
+from autodist_tpu.models.base import ModelSpec, cross_entropy_loss, rms_norm
+from autodist_tpu.models.mla_moe_lm import named_bytes, rotary_halves
+from autodist_tpu.ops.flash_attention import (
+    _DEFAULT_BLOCK,
+    RESIDUAL_NAMES,
+    flash_attention,
+    pack_selection,
+    pairs_computed,
+    unpack_selection,
+)
+from autodist_tpu.ops.pallas_utils import pick_block, use_interpret
+from autodist_tpu.ops.topk_select import top_k_mask
+from autodist_tpu.parallel.moe import (
+    ROUTING_RESIDUAL_NAMES,
+    init_routed_moe_params,
+    routed_moe_ffn,
+    routed_rows,
+)
+from autodist_tpu.telemetry import registry, timeline
+
+#: a layer's selection, as ``pack_selection``'s words: kept by name, so
+#: the backward neither scores nor selects again
+SELECTION_NAME = "dsa/selection"
+KEPT_NAMES = RESIDUAL_NAMES + (SELECTION_NAME,) + ROUTING_RESIDUAL_NAMES
+_KEEP_NAMED = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
+
+
+def layer_norm(x, p, eps):
+    x32 = x.astype(jnp.float32)
+    x32 = x32 - x32.mean(-1, keepdims=True)
+    out = x32 * jax.lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + eps)
+    return (out * p["scale"] + p["bias"]).astype(x.dtype)
+
+
+def index_scores(qi, ki, w):
+    """``I [rows, keys] = sum_j w[:, j] relu(qI[:, j] . kI)``: ``qi [rows,
+    J, Di]``, ``ki [keys, Di]``, ``w [rows, J]``, one head at a time (all
+    ``J`` at once are ``J`` times the result), float32 at ``highest``."""
+    def head(acc, qw):
+        q_j, w_j = qw
+        s = jnp.dot(q_j, ki.T, precision=jax.lax.Precision.HIGHEST)
+        return acc + w_j[:, None] * jax.nn.relu(s), None
+
+    acc = jnp.zeros((qi.shape[0], ki.shape[0]), jnp.float32)
+    return jax.lax.scan(head, acc, (jnp.moveaxis(qi, 1, 0).astype(
+        jnp.float32), w.T.astype(jnp.float32)))[0]
+
+
+def select_keys(qi, ki, w, *, topk: int, rows: int, block_k: int):
+    """The selection of one sequence as ``pack_selection``'s words ``[T //
+    32, T]`` (keys down, queries along): ``qi [T, J, Di]``, ``ki [T,
+    Di]``, ``w [T, J]``.  ``rows`` queries at a time, each block against
+    keys ``0 .. its last row``; a block that ends at or before row
+    ``topk`` selects all earlier keys and forms no score."""
+    t = qi.shape[0]
+    if t % rows or rows % block_k:
+        raise ValueError(f"{t} rows in blocks of {rows} over key blocks "
+                         f"of {block_k}")
+    out = []
+    for start in range(0, t, rows):
+        keys = start + rows
+        seen = jnp.arange(keys)[None, :] \
+            <= (start + jnp.arange(rows))[:, None]
+        if keys <= topk:
+            picked = seen
+        else:
+            with jax.named_scope(timeline.SCOPE_DSA_INDEX):
+                scores = index_scores(qi[start:keys], ki[:keys],
+                                      w[start:keys])
+            with jax.named_scope(timeline.SCOPE_DSA_SELECT):
+                # + 0.0: the one zero a row of relus can produce twice
+                picked = top_k_mask(scores + 0.0, topk, seen)
+        with jax.named_scope(timeline.SCOPE_DSA_SELECT):
+            words = pack_selection(picked, block_k=block_k)
+            out.append(jnp.pad(words, ((0, (t - keys) // 32), (0, 0))))
+    return jnp.concatenate(out, axis=1)
+
+
+def dense_selected_attention(q, k, v, causal, *, selection=None,
+                             select_from=None, block_k=_DEFAULT_BLOCK):
+    """What the kernel computes, by the plain softmax over all pairs (off
+    the TPU, at a size a test holds): ``selection`` as the kernel takes
+    it."""
+    t, group = q.shape[1], q.shape[2] // k.shape[2]
+    mask = jnp.tril(jnp.ones((t, t), bool))[None] if selection is None \
+        else unpack_selection(selection, block_k=block_k)
+    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (q.shape[-1] ** 0.5)
+    logits = jnp.where(mask[:, None], logits.astype(jnp.float32), -1e30)
+    return jnp.einsum("bhqk,bkhd->bqhd",
+                      jax.nn.softmax(logits, axis=-1).astype(q.dtype), v)
+
+
+def default_sparse_attention(block_k: int = _DEFAULT_BLOCK) -> Callable:
+    """The flash kernel on a TPU, the dense softmax elsewhere; resolved at
+    the first call, as ``transformer.default_attention`` is."""
+    def attn(q, k, v, causal, **selection):
+        if use_interpret():
+            return dense_selected_attention(q, k, v, causal, block_k=block_k,
+                                            **selection)
+        return flash_attention(q, k, v, causal, block_k=block_k, **selection)
+
+    return attn
+
+
+def gqa_dsa_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
+                   d_model: int = 2048, num_heads: int = 32,
+                   num_kv_heads: int = 4, head_dim: int = 128,
+                   index_heads: int = 16, index_dim: int = 64,
+                   topk: int = 2048, d_expert: int = 768,
+                   num_experts: int = 128,
+                   experts_held: Optional[Tuple[int, int]] = None,
+                   top_k: int = 8, rope_theta: float = 1e7,
+                   rms_eps: float = 1e-6, seq_len: int = 16384,
+                   attn_fn: Optional[Callable] = None,
+                   block_k: int = _DEFAULT_BLOCK, index_rows: int = 1024,
+                   moe_slice: int = 4096, dtype=jnp.float32,
+                   xent_chunk: Optional[int] = None, remat: str = "full",
+                   train_router: bool = True,
+                   return_counts: bool = False) -> ModelSpec:
+    """Defaults: one chip's share of Keye-VL-2.0-30B-A3B's language model
+    cut to four layers (``benchmark/configs/keye-vl-2.0-30b-a3b.ep8-share
+    .json`` passes ``experts_held=[0, 16]``); shrink every size for tests.
+
+    ``attn_fn(q, k, v, True, selection=words, select_from=topk)`` (no
+    keywords where the sequence is no longer than ``topk``); ``block_k``
+    is the key block the words are packed for, the attention's own.
+    ``remat``: "none" | "full": the attention half and every ``moe_slice``
+    of the expert half of a layer are recomputed in the backward EXCEPT
+    what a kernel, a selection or a sort produced (``KEPT_NAMES``).
+    ``experts_held``, ``xent_chunk``, ``train_router``, ``return_counts``:
+    as ``mla_moe_lm``."""
+    if remat not in ("none", "full"):
+        raise ValueError(f"remat={remat!r}: expected 'none' or 'full'")
+    if num_heads % num_kv_heads:
+        raise ValueError(f"{num_heads} query heads over {num_kv_heads}")
+    attn_fn = attn_fn or default_sparse_attention(block_k)
+    held = tuple(experts_held) if experts_held else (0, num_experts)
+
+    def init(rng):
+        def normal(key, *shape):
+            return jax.random.normal(key, shape, dtype) * 0.02
+
+        def scale(width):
+            return {"scale": jnp.ones((width,), dtype)}
+
+        r_emb, r_head, r_layers = jax.random.split(rng, 3)
+        params = {"embed": normal(r_emb, vocab_size, d_model),
+                  "head": normal(r_head, vocab_size, d_model),
+                  "ln_final": scale(d_model)}
+        for i, r in enumerate(jax.random.split(r_layers, num_layers)):
+            k = jax.random.split(r, 8)
+            params[f"layers_{i}"] = {
+                "ln_attn": scale(d_model),
+                "attn": {"wq": normal(k[0], d_model, num_heads, head_dim),
+                         "wk": normal(k[1], d_model, num_kv_heads, head_dim),
+                         "wv": normal(k[2], d_model, num_kv_heads, head_dim),
+                         "q_norm": scale(head_dim),
+                         "k_norm": scale(head_dim),
+                         "wo": normal(k[3], num_heads, head_dim, d_model)},
+                "indexer": {"wq": normal(k[4], d_model, index_heads,
+                                         index_dim),
+                            "wk": normal(k[5], d_model, index_dim),
+                            "k_norm": dict(scale(index_dim),
+                                           bias=jnp.zeros((index_dim,),
+                                                          dtype)),
+                            "weights": normal(k[6], d_model, index_heads)},
+                "ln_mlp": scale(d_model),
+                "moe": init_routed_moe_params(
+                    k[7], d_model, d_expert, num_experts,
+                    experts_held=held[1], selection_bias=False, dtype=dtype)}
+        return params
+
+    def selection_of(p, h):
+        """``pack_selection``'s words ``[B, T // 32, T]`` of ``h [B, T,
+        D]`` (the layer's normed input), or None where nothing is left
+        out.  Integers: no gradient passes."""
+        t = h.shape[1]
+        if t <= topk:
+            return None
+        # projections and scores alike in float32 at ``highest``: a score
+        # a bfloat16 pass moved by 3e-3 changes places with its neighbours
+        with jax.named_scope(timeline.SCOPE_DSA_INDEX), \
+                jax.default_matmul_precision("highest"):
+            h = h.astype(jnp.float32)
+            qi = rotary_halves(jnp.einsum("btd,djk->btjk", h, p["wq"]),
+                               rope_theta)
+            ki = rotary_halves(layer_norm(h @ p["wk"], p["k_norm"], rms_eps),
+                               rope_theta)
+            w = h @ p["weights"]
+        bk = pick_block(t, block_k)
+        rows = max(bk, min(index_rows, t) // bk * bk)
+        # a map, not a vmap: top_k_mask's rare branch stays a branch
+        words = jax.lax.map(lambda row: select_keys(
+            *row, topk=topk, rows=rows, block_k=bk), (qi, ki, w))
+        return checkpoint_name(words, SELECTION_NAME)
+
+    def attention_half(lp, x):
+        """``x [B, T, D]`` plus its attention."""
+        h = rms_norm(x, lp["ln_attn"]["scale"], rms_eps)
+        p = lp["attn"]
+        with jax.named_scope(timeline.SCOPE_GQA_PROJECT):
+            q, k = (rotary_halves(rms_norm(
+                jnp.einsum("btd,dhk->bthk", h, p[w]), p[n]["scale"],
+                rms_eps), rope_theta)
+                for w, n in (("wq", "q_norm"), ("wk", "k_norm")))
+            v = jnp.einsum("btd,dhk->bthk", h, p["wv"])
+        selection = selection_of(lp["indexer"], jax.lax.stop_gradient(h))
+        # the kernel's HLO name is the innermost scope
+        with jax.named_scope(timeline.SCOPE_DSA_ATTENTION), \
+                jax.named_scope("sparse_attn"):
+            o = attn_fn(q, k, v, True) if selection is None else attn_fn(
+                q, k, v, True, selection=selection, select_from=topk)
+        with jax.named_scope(timeline.SCOPE_GQA_PROJECT):
+            return x + jnp.einsum("bthv,hvd->btd", o, p["wo"])
+
+    def expert_half(lp, x):
+        """``x [N, D]`` plus its experts' output, and the tokens each held
+        expert was sent."""
+        y, counts = routed_moe_ffn(
+            lp["moe"], rms_norm(x, lp["ln_mlp"]["scale"], rms_eps),
+            top_k=top_k, experts_held=held, train_router=train_router,
+            scoring="softmax")
+        return x + y, counts
+
+    halves = attention_half, expert_half
+    if remat != "none":   # both run under lax.map: no CSE barrier needed
+        attention_half, expert_half = (
+            jax.checkpoint(f, policy=_KEEP_NAMED, prevent_cse=False)
+            for f in halves)
+
+    def slices(x):
+        """``[B, T, D]`` as ``[n, moe_slice, D]``."""
+        tokens = x.shape[0] * x.shape[1]
+        size = moe_slice if tokens % moe_slice == 0 else tokens
+        return x.reshape(tokens // size, size, x.shape[-1])
+
+    def kept_bytes(params, x):
+        """What the layers' checkpoints hold by name over a step of ``x
+        [B, T, D]``: the tagged shapes of one sequence's attention half
+        and of one slice's expert half, times how many of each."""
+        if remat == "none":
+            return dict.fromkeys(KEPT_NAMES, 0)
+        lp, parts = params["layers_0"], slices(x)
+        per = [(named_bytes(halves[0], lp, x[:1]), x.shape[0]),
+               (named_bytes(halves[1], lp, parts[0]), parts.shape[0])]
+        return {name: num_layers * sum(found.get(name, 0) * times
+                                       for found, times in per)
+                for name in KEPT_NAMES}
+
+    def layer(lp, x):
+        """``x [B, T, D]`` through one layer: attention one sequence at a
+        time, the experts one slice at a time."""
+        x = jax.lax.map(lambda row: attention_half(lp, row[None])[0], x)
+        y, counts = jax.lax.map(lambda part: expert_half(lp, part),
+                                slices(x))
+        return y.reshape(x.shape), counts.sum(axis=0)
+
+    def set_gauges(params, tokens, x):
+        batch, t = tokens.shape
+        computed, expected = routed_rows(tokens.size, top_k, held[1],
+                                         num_experts)
+        for kind, rows in (("computed", computed), ("expected", expected)):
+            registry.gauge(
+                "autodist_moe_rows_per_step",
+                "rows the grouped expert products are handed a step, and "
+                "rows an even router would send here",
+                {"kind": kind}).set(rows * num_layers)
+        whole = min(t, topk)       # rows that attend to all before them
+        selected = whole * (whole + 1) // 2 + (t - whole) * topk
+        for kind, pairs in (("selected", selected), ("computed",
+                            pairs_computed(t, block_k=block_k))):
+            registry.gauge(
+                "autodist_dsa_pairs_per_step",
+                "pairs of query and key a step's attention is asked for "
+                "(a head, forward), and pairs whose score its kernel "
+                "forms", {"kind": kind}).set(pairs * batch * num_layers)
+        for name, held_bytes in kept_bytes(params, x).items():
+            registry.gauge(
+                "autodist_remat_kept_bytes_per_step",
+                "bytes the layers' checkpoints keep from forward to "
+                "backward instead of recomputing, by the value's name",
+                {"name": name}).set(held_bytes)
+
+    def features(params, tokens):
+        """Final-norm activations ``[B, T, D]`` and the layers'
+        ``tokens_per_expert`` ``[layers, count]``."""
+        x = jnp.take(params["embed"], tokens, axis=0)
+        set_gauges(params, tokens, x)
+        counts = []
+        for i in range(num_layers):
+            x, c = layer(params[f"layers_{i}"], x)
+            counts.append(c)
+        return rms_norm(x, params["ln_final"]["scale"], rms_eps), counts
+
+    def apply_fn(params, tokens):
+        return jnp.einsum("btd,vd->btv", features(params, tokens)[0],
+                          params["head"])
+
+    def loss_fn(params, batch):
+        tokens = batch["tokens"]
+        feats, counts = features(params, tokens)
+        if xent_chunk:
+            from autodist_tpu.ops.chunked_xent import \
+                chunked_softmax_cross_entropy
+
+            loss = chunked_softmax_cross_entropy(
+                feats[:, :-1], params["head"], tokens[:, 1:],
+                chunk=xent_chunk)
+        else:
+            logits = jnp.einsum("btd,vd->btv", feats, params["head"])
+            loss = cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
+        if return_counts:
+            return loss, {"tokens_per_expert": jnp.stack(counts)}
+        return loss
+
+    def make_batch(rng: np.random.RandomState, batch_size: int):
+        return {"tokens": rng.randint(
+            0, vocab_size, (batch_size, seq_len)).astype(np.int32)}
+
+    return ModelSpec(
+        name="gqa_dsa_moe_lm",
+        init=init, loss_fn=loss_fn, apply_fn=apply_fn, make_batch=make_batch,
+        sparse_vars=("embed",),
+        expert_vars=("*/moe/experts/*",),
+        config=dict(vocab_size=vocab_size, num_layers=num_layers,
+                    d_model=d_model, num_heads=num_heads,
+                    num_kv_heads=num_kv_heads, head_dim=head_dim,
+                    index_heads=index_heads, index_dim=index_dim, topk=topk,
+                    d_expert=d_expert, num_experts=num_experts,
+                    experts_held=held, top_k=top_k, seq_len=seq_len),
+    )

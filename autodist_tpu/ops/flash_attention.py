@@ -49,6 +49,14 @@ is ``PERF.md`` section 5):
   ask for ``HIGHEST`` (2.5-4 times slower on the chip, error 1e-7).
 * **Masks only where needed.**  Tiles wholly below the diagonal and inside
   ``kv_len`` skip the iota / compare / select.
+* **Grouped heads and a selection of keys** (PR 32).  ``H // Hkv`` query
+  heads in a row read one key/value head through the index map, with no
+  copy; the backward writes each query head's dK and dV and the group's
+  are summed outside the kernel.  A learned top-k selection comes in as
+  ONE BIT a (query, key) pair, packed into int32 words with keys down and
+  queries along (``pack_selection``), and masks every tile of the q
+  blocks that reach past row ``select_from``: every causal tile is still
+  computed.  Callers without either trace the kernels they always did.
 
 Interpret mode (CPU tests) is selected automatically off-TPU.
 """
@@ -104,7 +112,8 @@ _VMEM_MAX = 100 << 20      # of the v5e's 128 MiB
 _VMEM_TILES = 8 << 20      # room for one tile's temporaries (512 x 512 f32)
 
 
-def _compiler_params(t: int, block: int, resident, blocked, widths):
+def _compiler_params(t: int, block: int, resident, blocked, widths,
+                     extra: int = 0):
     """The last grid axis sequential (scratch filled at its first step is
     read at the others), and the scoped-VMEM limit raised when what the
     kernel holds would not fit the default: the ``resident`` [T, D]
@@ -119,8 +128,9 @@ def _compiler_params(t: int, block: int, resident, blocked, widths):
     (float32 in) and 32768 (bfloat16 in); the forward holds about half of
     that.  At ``Dk`` 192, ``Dv`` 128 and float32 in, the backward holds
     7 KB a row, 40 MiB in all at T = 4096 (what Mosaic asked for when
-    the limit stood at 38)."""
-    need = (_VMEM_TILES + t * _row_bytes(*resident) + block * (
+    the limit stood at 38).  ``extra``: bytes of what else is held (a
+    selection's words)."""
+    need = (extra + _VMEM_TILES + t * _row_bytes(*resident) + block * (
         _row_bytes(*blocked) + _row_bytes(*[(d, 4) for d in widths] * 4)))
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -161,9 +171,62 @@ def _dot(a, b, dims, operand):
                            preferred_element_type=jnp.float32)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, ks_ref, vt_ref, *,
-                causal: bool, block_k: int, scale: float, kv_len: int,
-                operand):
+_WORD = 32    # keys a word of a packed selection holds
+
+
+def pack_selection(selected: jax.Array, *,
+                   block_k: int = _DEFAULT_BLOCK) -> jax.Array:
+    """``selected [..., Tq, Tk]`` bool (query ``t`` attends to key ``s``)
+    to the words the kernels read: ``[..., Tk // 32, Tq]`` int32, keys
+    down, queries along.  Inside a block of ``bk`` keys (the kernels'
+    own, ``pick_block(Tk, block_k)``) key ``r`` is bit ``r // W`` of word
+    ``r % W``, ``W = bk // 32``: a tile's mask is then its ``[W, bq]``
+    words shifted 32 ways and stacked, with no lane or sublane moved."""
+    tq, tk = selected.shape[-2:]
+    bk = _pick_block(tk, block_k)
+    if bk % _WORD:
+        raise ValueError(f"a selection needs key blocks of a multiple of "
+                         f"{_WORD}; {tk} keys give blocks of {bk}")
+    words = bk // _WORD
+    bits = selected.reshape(selected.shape[:-1] + (tk // bk, _WORD, words))
+    packed = jnp.sum(bits.astype(jnp.uint32)
+                     << jnp.arange(_WORD, dtype=jnp.uint32)[:, None],
+                     axis=-2, dtype=jnp.uint32)
+    packed = jax.lax.bitcast_convert_type(packed, jnp.int32)
+    return jnp.swapaxes(packed.reshape(selected.shape[:-1] + (tk // _WORD,)),
+                        -1, -2)
+
+
+def unpack_selection(words: jax.Array, *,
+                     block_k: int = _DEFAULT_BLOCK) -> jax.Array:
+    """:func:`pack_selection` undone: ``[..., Tk // 32, Tq]`` words to
+    ``[..., Tq, Tk]`` bool (for an attention that is not the kernel)."""
+    tk, tq = words.shape[-2] * _WORD, words.shape[-1]
+    per_block = _pick_block(tk, block_k) // _WORD
+    w = jnp.swapaxes(words, -1, -2).reshape(
+        words.shape[:-2] + (tq, -1, 1, per_block))
+    bits = (w >> jnp.arange(_WORD, dtype=jnp.int32)[:, None]) & 1
+    return bits.reshape(words.shape[:-2] + (tq, tk)) != 0
+
+
+def pairs_computed(t: int, *, block_q: int = _DEFAULT_BLOCK,
+                   block_k: int = _DEFAULT_BLOCK) -> int:
+    """(query, key) pairs whose score ONE causal forward call forms for
+    one head: every tile at or below the diagonal, whole."""
+    bq, bk = _pick_block(t, block_q), _pick_block(t, block_k)
+    return sum(min(-(-(q0 + bq) // bk), t // bk) * bk * bq
+               for q0 in range(0, t, bq))
+
+
+def _unpack(words):
+    """``[W, bq]`` int32 words of one key block to its mask ``[32 W, bq]``
+    (:func:`pack_selection`)."""
+    return jnp.concatenate([(words >> b) & 1 for b in range(_WORD)],
+                           axis=0) != 0
+
+
+def _fwd_kernel(*refs, causal: bool, block_k: int, scale: float,
+                kv_len: int, operand, select_from: Optional[int] = None):
     """One (batch, head, q-block) program: stream K/V blocks, online softmax.
 
     Tiles are TRANSPOSED, ``[bk, bq]`` (keys down the sublanes, queries
@@ -177,7 +240,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, ks_ref, vt_ref, *,
     the product operands K [T,Dk] and Vᵀ [Dv,T].
     ``kv_len`` < T means the tail is alignment padding — masked out.
     Only tiles the diagonal or the padding edge crosses pay for the mask.
+
+    With ``select_from`` (the ``topk`` of a learned selection) a further
+    ref follows v: sel [1,T/32,bq], the packed words of
+    :func:`pack_selection` for this block's queries.  A q block that ends
+    at or before row ``select_from`` attends to every earlier key and
+    takes the tiles above; any other masks EVERY tile up to the diagonal
+    by its words (a selection lies inside the causal triangle).
     """
+    if select_from is None:
+        q_ref, k_ref, v_ref, o_ref, lse_ref, ks_ref, vt_ref = refs
+    else:
+        q_ref, k_ref, v_ref, sel_ref, o_ref, lse_ref, ks_ref, vt_ref = refs
     bq, dv = q_ref.shape[2], v_ref.shape[3]
     t_k = k_ref.shape[2]
     padded = kv_len < t_k
@@ -204,7 +278,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, ks_ref, vt_ref, *,
         o_t, l, m = carry
         k0, rows = kb * block_k, _block(kb, block_k, num_kb)
         s_t = _dot(ks_ref[rows, :], q, _NT, operand)            # [bk,bq]
-        if masked:
+        if masked == "selected":
+            s_t = jnp.where(_unpack(sel_ref[0, _block(
+                kb, block_k // _WORD, num_kb), :]), s_t, _NEG_INF)
+        elif masked:
             mask = row_minus_col <= q0 - k0 if causal else None
             if padded:
                 live = row < kv_len - k0
@@ -228,7 +305,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, ks_ref, vt_ref, *,
         upper = jnp.minimum(lax.div(q0 + bq + block_k - 1, block_k), num_kb)
     if padded:
         clear = jnp.minimum(clear, kv_len // block_k)
-    carry = lax.fori_loop(0, clear, functools.partial(tile, False), init)
+    first = 0
+    if select_from is not None:
+        first = jnp.where(q0 + bq > select_from, upper, 0)
+        init = lax.fori_loop(0, first, functools.partial(tile, "selected"),
+                             init)
+        clear = jnp.maximum(clear, first)
+    carry = lax.fori_loop(first, clear, functools.partial(tile, False), init)
     if causal or padded:
         carry = lax.fori_loop(clear, upper, functools.partial(tile, True),
                               carry)
@@ -238,17 +321,30 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, ks_ref, vt_ref, *,
     lse_ref[0, 0] = m + jnp.log(l)
 
 
+def _kv_head(group: int):
+    """The key/value head a query head reads: itself, or one of every
+    ``group`` in a row."""
+    return (lambda hi: hi) if group == 1 else (lambda hi: hi // group)
+
+
 def _fwd(q, k, v, causal, block_q, block_k, interpret, kv_len,
-         operand=jnp.float32):
-    """q/k: [B, H, T, Dk], v: [B, H, T, Dv] → (o [B,H,T,Dv], lse
-    [B,H,T,1]); the scale is 1/√Dk."""
+         operand=jnp.float32, sel=None, select_from=None):
+    """q: [B, H, T, Dk], k: [B, Hkv, T, Dk], v: [B, Hkv, T, Dv] → (o
+    [B,H,T,Dv], lse [B,H,T,1]); the scale is 1/√Dk.  ``H // Hkv`` query
+    heads in a row read one key/value head: the index map repeats, and
+    nothing is copied.  ``sel``: ``pack_selection``'s words [B, T/32,
+    T]."""
     b, h, t, dk = q.shape
     dv = v.shape[3]
+    kv_head = _kv_head(h // k.shape[1])
     bq = _pick_block(t, block_q)
     bk = _pick_block(t, block_k)
     scale = 1.0 / (dk ** 0.5)
     kernel = functools.partial(_fwd_kernel, causal=causal, block_k=bk,
-                               scale=scale, kv_len=kv_len, operand=operand)
+                               scale=scale, kv_len=kv_len, operand=operand,
+                               select_from=select_from)
+    selection = [] if sel is None else [pl.BlockSpec(
+        (1, t // _WORD, bq), lambda bi, hi, qi: (bi, 0, qi))]
 
     in_size, op_size = q.dtype.itemsize, jnp.dtype(operand).itemsize
     o, lse = pl.pallas_call(
@@ -257,9 +353,10 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret, kv_len,
         in_specs=[pl.BlockSpec((1, 1, bq, dk),
                                lambda bi, hi, qi: (bi, hi, qi, 0)),
                   pl.BlockSpec((1, 1, t, dk),
-                               lambda bi, hi, qi: (bi, hi, 0, 0)),
+                               lambda bi, hi, qi: (bi, kv_head(hi), 0, 0)),
                   pl.BlockSpec((1, 1, t, dv),
-                               lambda bi, hi, qi: (bi, hi, 0, 0))],
+                               lambda bi, hi, qi: (bi, kv_head(hi), 0, 0))]
+        + selection,
         out_specs=[
             pl.BlockSpec((1, 1, bq, dv), lambda bi, hi, qi: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, 1, bq), lambda bi, hi, qi: (bi, hi, 0, qi)),
@@ -274,19 +371,18 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret, kv_len,
         compiler_params=_compiler_params(
             t, bq, [(dk, in_size)] * 2 + [(dv, in_size)] * 2
             + [(dk, op_size), (dv, op_size)],
-            [(dk, in_size)] * 2 + [(dv, in_size)] * 2, (dk, dv)),
+            [(dk, in_size)] * 2 + [(dv, in_size)] * 2, (dk, dv),
+            extra=len(selection) * 2 * (t // _WORD) * bq * 4),
         interpret=interpret,
-    )(q, k, v)
+    )(q, k, v, *([] if sel is None else [sel]))
     return o, lse.reshape(b, h, t, 1)
 
 
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
-def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref, qst_ref, dot_ref, dqt_ref, *,
-                causal: bool, block_q: int, scale: float, kv_len: int,
-                operand):
+def _bwd_kernel(*refs, causal: bool, block_q: int, scale: float,
+                kv_len: int, operand, select_from: Optional[int] = None):
     """dQ, dK and dV in one call: each score tile is formed ONCE.
 
     One (batch, head, k-block) program; the k-block axis is sequential.
@@ -302,7 +398,17 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     Qᵀ·scale [Dk,T] and dOᵀ [Dv,T] (rounded and transposed once, not once
     a tile) and the float32 dQᵀ [Dk,T], scaled, transposed and written
     back at the last k block.
+
+    With ``select_from`` a further input follows Δ: sel [1,bk/32,T], this
+    key block's words for every query (:func:`_fwd_kernel` says which q
+    blocks read them).
     """
+    if select_from is None:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+         dq_ref, dk_ref, dv_ref, qst_ref, dot_ref, dqt_ref) = refs
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, sel_ref,
+         dq_ref, dk_ref, dv_ref, qst_ref, dot_ref, dqt_ref) = refs
     bk = k_ref.shape[2]
     t_q = q_ref.shape[2]
     padded = kv_len < t_q
@@ -336,7 +442,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         qs_t = qst_ref[:, cols]                             # [Dk, bq]
         do_t = dot_ref[:, cols]                             # [Dv, bq]
         s_t = _dot(k, qs_t, _NN, operand)                   # [bk, bq]
-        if masked:
+        if masked == "selected":
+            s_t = jnp.where(_unpack(sel_ref[0, :, cols]), s_t, _NEG_INF)
+        elif masked:
             mask = row_minus_col <= qb * block_q - k0 if causal else None
             if padded:
                 mask = live if mask is None else mask & live
@@ -360,10 +468,17 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         clear = jnp.where(k0 + bk > kv_len, num_qb, clear)
     carry = (jnp.zeros(k_t.shape, jnp.float32),
              jnp.zeros((v.shape[1], bk), jnp.float32))
+    last, masked_to = num_qb, clear
+    if select_from is not None:
+        # q blocks from ``last`` on hold a row that selects
+        last = min(select_from // block_q, num_qb)
+        masked_to = jnp.minimum(clear, last)
+        carry = lax.fori_loop(jnp.maximum(lower, last), num_qb,
+                              functools.partial(tile, "selected"), carry)
     if causal or padded:
-        carry = lax.fori_loop(lower, clear, functools.partial(tile, True),
-                              carry)
-    dk_t, dv_t = lax.fori_loop(clear, num_qb, functools.partial(tile, False),
+        carry = lax.fori_loop(lower, masked_to,
+                              functools.partial(tile, True), carry)
+    dk_t, dv_t = lax.fori_loop(clear, last, functools.partial(tile, False),
                                carry)
     # q was pre-scaled, so dSᵀQ already carries the 1/√d factor.
     dk_ref[0, 0] = dk_t.T.astype(dk_ref.dtype)
@@ -380,9 +495,14 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret, kv_len,
-         dlse=None, operand=jnp.float32):
+         dlse=None, operand=jnp.float32, sel=None, select_from=None):
+    """(dq, dk, dv).  Where ``group = H // Hkv`` query heads share a
+    key/value head, the kernel writes each query head's dK and dV and
+    the group's are summed here: 2 x [B,H,T,D] crosses memory once more,
+    and no program waits for another's block."""
     b, h, t, dk = q.shape
     dv = v.shape[3]
+    group = h // k.shape[1]
     bq = _pick_block(t, block_q)
     bk = _pick_block(t, block_k)
     scale = 1.0 / (dk ** 0.5)
@@ -398,20 +518,25 @@ def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret, kv_len,
     delta = delta.reshape(b, h, 1, t)
     lse = lse.reshape(b, h, 1, t)
 
-    def kb_spec(d):
-        return pl.BlockSpec((1, 1, bk, d), lambda bi, hi, i: (bi, hi, i, 0))
+    def kb_spec(d, group=1):
+        head = _kv_head(group)
+        return pl.BlockSpec((1, 1, bk, d),
+                            lambda bi, hi, i: (bi, head(hi), i, 0))
 
     def full_spec(d):
         return pl.BlockSpec((1, 1, t, d), lambda bi, hi, i: (bi, hi, 0, 0))
 
     row_spec = pl.BlockSpec((1, 1, 1, t), lambda bi, hi, i: (bi, hi, 0, 0))
     in_size, op_size = q.dtype.itemsize, jnp.dtype(operand).itemsize
-    return pl.pallas_call(
+    selection = [] if sel is None else [pl.BlockSpec(
+        (1, bk // _WORD, t), lambda bi, hi, i: (bi, i, 0))]
+    dq, dk_heads, dv_heads = pl.pallas_call(
         functools.partial(_bwd_kernel, causal=causal, block_q=bq,
-                          scale=scale, kv_len=kv_len, operand=operand),
+                          scale=scale, kv_len=kv_len, operand=operand,
+                          select_from=select_from),
         grid=(b, h, t // bk),
-        in_specs=[full_spec(dk), kb_spec(dk), kb_spec(dv), full_spec(dv),
-                  row_spec, row_spec],
+        in_specs=[full_spec(dk), kb_spec(dk, group), kb_spec(dv, group),
+                  full_spec(dv), row_spec, row_spec] + selection,
         out_specs=[full_spec(dk), kb_spec(dk), kb_spec(dv)],
         out_shape=[jax.ShapeDtypeStruct((b, h, t, dk), q.dtype),
                    jax.ShapeDtypeStruct((b, h, t, dk), k.dtype),
@@ -424,9 +549,15 @@ def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret, kv_len,
         compiler_params=_compiler_params(
             t, bk, [(dk, in_size)] * 4 + [(dv, in_size)] * 2
             + [(dk, op_size), (dv, op_size), (dk, 4)],
-            [(dk, in_size)] * 4 + [(dv, in_size)] * 4, (dk, dv)),
+            [(dk, in_size)] * 4 + [(dv, in_size)] * 4, (dk, dv),
+            extra=len(selection) * 2 * (bk // _WORD) * t * 4),
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, lse, delta, *([] if sel is None else [sel]))
+    if group == 1:
+        return dq, dk_heads, dv_heads
+    return (dq,) + tuple(
+        g.reshape(b, h // group, group, t, g.shape[-1]).sum(axis=2)
+        for g in (dk_heads, dv_heads))
 
 
 # ---------------------------------------------------------------------------
@@ -458,6 +589,35 @@ def _flash_bwd(causal, block_q, block_k, interpret, kv_len, operand, res,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_selected(q, k, v, sel, block_q, block_k, interpret, operand,
+                    select_from):
+    """:func:`_flash`, causal, where query ``t`` attends only to the keys
+    ``sel`` (``pack_selection``'s words, no gradient) marks for it."""
+    return _fwd(q, k, v, True, block_q, block_k, interpret, q.shape[2],
+                operand, sel, select_from)
+
+
+def _flash_selected_fwd(q, k, v, sel, block_q, block_k, interpret, operand,
+                        select_from):
+    o, lse = map(checkpoint_name, _fwd(
+        q, k, v, True, block_q, block_k, interpret, q.shape[2], operand, sel,
+        select_from), RESIDUAL_NAMES)
+    return (o, lse), (q, k, v, sel, o, lse)
+
+
+def _flash_selected_bwd(block_q, block_k, interpret, operand, select_from,
+                        res, cts):
+    q, k, v, sel, o, lse = res
+    do, dlse = cts
+    return _bwd(q, k, v, o, lse, do, True, block_q, block_k, interpret,
+                q.shape[2], dlse=dlse, operand=operand, sel=sel,
+                select_from=select_from) + (None,)
+
+
+_flash_selected.defvjp(_flash_selected_fwd, _flash_selected_bwd)
+
+
 @functools.cache
 def _log_operand(name: str, why: str) -> None:
     logging.info("flash attention: product operands %s (%s)", name, why)
@@ -481,12 +641,26 @@ def _product_operand(interpret: bool):
     return operand
 
 
-def _pad_and_run(q, k, v, causal, block_q, block_k, interpret):
+def _pad_and_run(q, k, v, causal, block_q, block_k, interpret,
+                 selection=None, select_from=None):
     """[B,T,H,D] public layout (q, k ``Dk`` wide, v ``Dv``) → padded
     [B,H,T,D] kernel run → sliced (o [B,T,H,Dv], lse [B,H,T])."""
     t = q.shape[1]
     tp = _pad_len(t, interpret)
+    if q.shape[2] % k.shape[2] or k.shape[2] != v.shape[2]:
+        raise ValueError(f"{q.shape[2]} query heads over {k.shape[2]} key "
+                         f"and {v.shape[2]} value heads")
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))  # → [B,H,T,D]
+    if selection is not None:
+        if not causal or tp != t or select_from is None:
+            raise ValueError("a selection is causal, says from which row on "
+                             "it selects (select_from) and takes a sequence "
+                             f"that needs no padding; got causal={causal}, "
+                             f"select_from={select_from}, {t} rows")
+        o, lse = _flash_selected(qt, kt, vt, selection, block_q, block_k,
+                                 interpret, _product_operand(interpret),
+                                 int(select_from))
+        return o.transpose(0, 2, 1, 3), lse[..., 0]
     if tp != t:
         pad = [(0, 0), (0, 0), (0, tp - t), (0, 0)]
         qt, kt, vt = (jnp.pad(x, pad) for x in (qt, kt, vt))
@@ -501,19 +675,32 @@ def _pad_and_run(q, k, v, causal, block_q, block_k, interpret):
 def flash_attention(q, k, v, causal: bool = False, *,
                     block_q: int = _DEFAULT_BLOCK,
                     block_k: int = _DEFAULT_BLOCK,
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    interpret: Optional[bool] = None,
+                    selection: Optional[jax.Array] = None,
+                    select_from: Optional[int] = None) -> jax.Array:
     """Drop-in ``attn_fn(q, k, v, causal)``: q, k ``[B, T, H, Dk]``, v
     ``[B, T, H, Dv]`` → ``[B, T, H, Dv]``; the scale is 1/√Dk.  The two
     widths are usually one (D = 64 in the GPT-2 blocks); latent attention
     decompresses keys 192 wide beside values 128 wide, and the same
     kernels, block rule, transposed tiles and operand rounding serve both.
 
+    Grouped queries: k and v may have ``Hkv`` heads where q has ``H``;
+    query head ``j`` reads key/value head ``j // (H // Hkv)``.
+    ``selection`` (:func:`pack_selection` of ``[B, T, T]``, with this
+    call's ``block_k``): query ``t`` attends only to the keys marked for
+    it, all of them at or before ``t``; ``select_from``: rows before it
+    select every earlier key (a learned top-k's ``k``), so q blocks that
+    end there take the causal tiles and never read the words.  EVERY
+    causal tile of a later block is computed and masked: the kernel does
+    not skip what was not chosen.
+
     Sequences whose length is not MXU-tileable are zero-padded to the next
     tileable length (masked inside the kernels; the pad is sliced off), so
     any length compiles on real TPU."""
     if interpret is None:
         interpret = _use_interpret()
-    return _pad_and_run(q, k, v, causal, block_q, block_k, interpret)[0]
+    return _pad_and_run(q, k, v, causal, block_q, block_k, interpret,
+                        selection, select_from)[0]
 
 
 def flash_attention_with_lse(q, k, v, causal: bool = False, *,

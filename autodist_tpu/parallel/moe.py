@@ -228,8 +228,10 @@ def moe_ffn(params: dict, x: jax.Array, *,
 # ---------------------------------------------------------------------------
 def init_routed_moe_params(rng, d_model: int, d_expert: int,
                            num_experts: int, *, experts_held: int = None,
-                           d_shared: int = 0, dtype=jnp.float32) -> dict:
-    """Router over all ``num_experts``, its selection bias, the SwiGLU
+                           d_shared: int = 0, selection_bias: bool = True,
+                           dtype=jnp.float32) -> dict:
+    """Router over all ``num_experts``, its selection bias (unless
+    ``selection_bias`` is off: a softmax router has none), the SwiGLU
     weights of the ``experts_held`` experts that live here (leading axis:
     flag ``*/experts/*`` via ``expert_vars``) and, if ``d_shared``, one
     dense SwiGLU of that width (the shared experts side by side)."""
@@ -246,6 +248,8 @@ def init_routed_moe_params(rng, d_model: int, d_expert: int,
                     "w_up": normal(r[3], held, d_model, d_expert),
                     "w_down": normal(r[4], held, d_expert, d_model)},
     }
+    if not selection_bias:
+        del params["router_bias"]
     if d_shared:
         params["shared"] = {"w_gate": normal(r[5], d_model, d_shared),
                             "w_up": normal(r[6], d_model, d_shared),
@@ -300,9 +304,10 @@ def routed_rows(tokens: int, top_k: int, held: int, total: int):
 
 def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
                    experts_held: Optional[Tuple[int, int]] = None,
-                   routed_scale: float = 1.0, train_router: bool = True
+                   routed_scale: float = 1.0, train_router: bool = True,
+                   scoring: str = "sigmoid"
                    ) -> Tuple[jax.Array, jax.Array]:
-    """``k`` of ``E`` sigmoid-routed experts with NO token dropped, for the
+    """``k`` of ``E`` routed experts with NO token dropped, for the
     experts this chip holds (DeepSeek-V3's layer, arxiv 2412.19437 §2.1.2,
     ``topk_method`` noaux_tc with one group):
 
@@ -310,6 +315,11 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
         S = top-k of (s + b)        b: the selection bias, no gradient
         g_e = routed_scale * s_e / sum_{j in S} s_j        for e in S
         y = Shared(x) + sum_{e in S and held} g_e E_e(x)
+
+    ``scoring="softmax"``: ``s = softmax(x W_r)`` over all ``E`` (the
+    Qwen3-MoE router with ``norm_topk_prob``); where ``params`` has no
+    ``router_bias`` the selection is by the scores alone, and no
+    ``shared`` leaves means no shared expert.
 
     ``experts_held = (first, count)``: ``params["experts"]`` leaves lead
     with ``count`` experts, which are experts ``first .. first + count``
@@ -353,12 +363,18 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
     router = params["router"].astype(jnp.float32)
     if not train_router:
         router = jax.lax.stop_gradient(router)
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"scoring={scoring!r}: expected 'sigmoid' or "
+                         f"'softmax'")
     with jax.named_scope(timeline.SCOPE_MOE_ROUTE):
-        scores = jax.nn.sigmoid(jnp.dot(
-            h.astype(jnp.float32), router,
-            precision=jax.lax.Precision.HIGHEST))
-        _, chosen = jax.lax.top_k(
-            scores + jax.lax.stop_gradient(params["router_bias"]), top_k)
+        logits = jnp.dot(h.astype(jnp.float32), router,
+                         precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
+            else jax.nn.softmax(logits, axis=-1)
+        ranked = scores
+        if "router_bias" in params:
+            ranked = scores + jax.lax.stop_gradient(params["router_bias"])
+        _, chosen = jax.lax.top_k(ranked, top_k)
         chosen = checkpoint_name(chosen, ROUTING_RESIDUAL_NAMES[0])
         picked = jnp.take_along_axis(scores, chosen, axis=-1)   # [N, k]
         gates = routed_scale * picked / picked.sum(-1, keepdims=True)

@@ -360,6 +360,10 @@ COMPILE_BACKEND = "compile/backend"
 #: moe_routed_device_pct.py`` matches the ``moe/`` ones but ``moe/shared``.
 SCOPE_MLA_PROJECT = "mla/project"        # q, kv_a, kv_b, rotary, out
 SCOPE_MLA_ATTENTION = "mla/attention"    # the attention call alone
+SCOPE_GQA_PROJECT = "gqa/project"        # q, k, v, their norms, rotary, out
+SCOPE_DSA_INDEX = "dsa/index"            # the indexer's projections, scores
+SCOPE_DSA_SELECT = "dsa/select"          # top-k of every row, packed words
+SCOPE_DSA_ATTENTION = "dsa/attention"    # the attention call alone
 SCOPE_MOE_ROUTE = "moe/route"            # scores, top-k, sort, group sizes
 SCOPE_MOE_SHARED = "moe/shared"          # the shared experts (dense)
 SCOPE_MOE_EXPERTS = "moe/experts"        # gather, grouped products, SwiGLU

@@ -517,6 +517,66 @@ def train_moe_phase(model: dict, *, batch_size: int, steps: int) -> dict:
     return facts
 
 
+def train_dsa_moe_phase(model: dict, *, batch_size: int, steps: int) -> dict:
+    """``models/gqa_dsa_moe_lm.py`` (grouped-query heads over the keys a
+    learned indexer selects, softmax-routed experts of which this chip
+    holds a share) through ``capture(has_aux=True)``: ``steps`` calls of
+    ``sess.run`` on one fixed batch.  Every loss finite; the per-expert
+    token counts come back; the gauges say how many pairs the attention
+    was asked for and how many its kernel scores; on a TPU the compiled
+    step holds a forward and a backward Pallas call a layer, none run
+    twice, and the forward's result has the QUERY heads while its keys
+    went in with their own (``num_kv_heads``)."""
+    import jax
+    import numpy as np
+
+    from autodist_tpu.autodist import _reset_default_autodist_for_testing
+    from autodist_tpu.models.gqa_dsa_moe_lm import gqa_dsa_moe_lm
+    from autodist_tpu.telemetry.registry import DEFAULT_REGISTRY
+
+    spec = gqa_dsa_moe_lm(**model, return_counts=True)
+    ad, sess = open_session(
+        spec, jax.jit(spec.init)(jax.random.PRNGKey(SEED)), "AllReduce",
+        {"data": 1}, expert_vars=spec.expert_vars, has_aux=True)
+    batch = spec.sample_batch(batch_size, seed=SEED)
+    outs = [sess.run(batch) for _ in range(steps)]
+    losses = [float(o["loss"]) for o in outs]
+    if not np.all(np.isfinite(losses)):
+        raise AssertionError(f"loss not finite: {losses}")
+    cfg = spec.config
+    counts = np.asarray(outs[-1]["aux"]["tokens_per_expert"])
+    picks = batch["tokens"].size * cfg["top_k"]
+    if counts.shape != (cfg["num_layers"], cfg["experts_held"][1]) \
+            or not 0 < counts.sum() <= cfg["num_layers"] * picks:
+        raise AssertionError(f"tokens per expert: {counts.tolist()}")
+    pairs = {m.labels["kind"]: int(m.value)
+             for m in DEFAULT_REGISTRY.metrics()
+             if m.name == "autodist_dsa_pairs_per_step"}
+    if not 0 < pairs["selected"] <= pairs["computed"]:
+        raise AssertionError(f"pairs a step: {pairs}")
+    facts = {"losses": [round(x, 4) for x in losses],
+             "tokens_per_expert": counts.tolist(), "pairs_per_step": pairs}
+    if jax.devices()[0].platform == "tpu":
+        text = sess.lower_step(batch).compile().as_text()
+        attn = [[dims for _, dims in shapes]
+                for shapes in pallas_call_shapes(text)
+                if any(len(dims) == 4 for _, dims in shapes)]
+        heads = {dims[1] for call in attn for dims in call if len(dims) == 4}
+        if len(attn) != 2 * cfg["num_layers"] \
+                or heads != {cfg["num_heads"]}:
+            raise AssertionError(f"attention custom calls work on {attn}")
+        kv = f"f32[1,{cfg['num_kv_heads']},{cfg['seq_len']},"
+        if kv not in text:
+            raise AssertionError(f"no kernel operand {kv}...]: the keys "
+                                 f"were repeated for their query heads")
+        facts["attention_calls"] = len(attn)
+        facts["bytes_in_use"] = memory_in_use(jax.devices()[:1])
+    del sess, ad
+    _reset_default_autodist_for_testing()
+    gc.collect()
+    return facts
+
+
 # ---------------------------------------------------------------------------
 # server
 # ---------------------------------------------------------------------------
@@ -760,6 +820,11 @@ FULL_KERNELS = dict(
 # .json), the leading dense layer and one expert layer, 16 of 128 experts
 FULL_MLA_MOE = dict(vocab_size=16032, num_layers=2, experts_held=(0, 16),
                     seq_len=2048, xent_chunk=5376)
+# Keye-VL-2.0-30B-A3B's language model at its published widths (benchmark/
+# configs/keye-vl-2.0-30b-a3b.ep8-share.json), one layer, 16 of 128
+# experts, twice the 2,048 keys a row may select
+FULL_DSA_MOE = dict(vocab_size=18992, num_layers=1, experts_held=(0, 16),
+                    seq_len=4096, xent_chunk=6400)
 FULL_SIZES = dict(p=64, prefix=512, tails=(40, 100), long=1024, mid=333,
                   n=(32, 48, 96, 128))
 FULL_ENGINE = dict(slots=8, window=2048, block_size=32, chunk=16)
@@ -808,6 +873,8 @@ def main() -> int:
               steps=5)
     run_phase(watch, "train_mla_moe", train_moe_phase, FULL_MLA_MOE,
               batch_size=2, steps=4)
+    run_phase(watch, "train_dsa_moe", train_dsa_moe_phase, FULL_DSA_MOE,
+              batch_size=1, steps=2)
     run_phase(watch, "serve_paged", serve_paged_phase, spec, params,
               sizes=FULL_SIZES, engine=FULL_ENGINE)
     run_phase(watch, "serve_slots", serve_slots_phase, spec, params,

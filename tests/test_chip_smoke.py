@@ -8,6 +8,7 @@ import sys
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -62,6 +63,20 @@ def test_train_moe_phase_returns_the_counts():
     kept = facts["remat_kept_bytes"]
     assert kept["flash_attention/o"] == 0
     assert kept["routed_moe/chosen"] == kept["routed_moe/order"] == 768
+
+
+def test_train_dsa_moe_phase_returns_counts_and_pairs():
+    facts = chip_smoke.train_dsa_moe_phase(
+        dict(vocab_size=61, num_layers=2, d_model=32, num_heads=4,
+             num_kv_heads=2, head_dim=16, index_heads=2, index_dim=8,
+             topk=32, d_expert=12, num_experts=16, experts_held=(4, 4),
+             top_k=3, seq_len=64, block_k=32, index_rows=32, moe_slice=64),
+        batch_size=2, steps=2)
+    assert all(np.isfinite(facts["losses"]))
+    assert len(facts["tokens_per_expert"]) == 2
+    # two layers x two sequences x (32 x 33 / 2 + 32 x 32) selected pairs
+    assert facts["pairs_per_step"] == {"selected": 4 * 1552,
+                                       "computed": 4 * 64 * 64}
 
 
 def test_serve_phases_over_http(lm):

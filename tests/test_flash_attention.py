@@ -581,3 +581,110 @@ def test_a_policy_that_keeps_the_names_holds_o_and_lse_and_no_q_k_v(capsys):
 
     assert kernels(kept) == kernels(f) == 2
     assert kernels(jax.checkpoint(f)) == 3
+
+
+# ---------------------------------------------------------------------------
+# grouped-query heads and a selection of keys (PR 32)
+# ---------------------------------------------------------------------------
+
+def _grouped_dense(q, k, v, mask):
+    """Plain masked softmax: query head j reads key/value head j // group;
+    ``mask [B, Tq, Tk]``."""
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    s = jnp.where(mask[:, None], s, -1e30)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+
+
+def _grouped_qkv(rng, b, t, heads, kv_heads, d=16):
+    return tuple(jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
+                 for h in (heads, kv_heads, kv_heads))
+
+
+def _top_k_rows(rng, b, t, topk):
+    """A causal selection as a learned top-k gives it: row ``r`` keeps the
+    ``topk`` largest of ``r + 1`` random scores, all of them where it has
+    no more."""
+    score = np.where(np.tril(np.ones((t, t), bool)),
+                     rng.standard_normal((b, t, t)), -np.inf)
+    kth = np.sort(score, -1)[..., ::-1][..., min(topk, t) - 1][..., None]
+    return jnp.asarray((score >= kth) & np.isfinite(score))
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(4, 4), (4, 2), (8, 1)])
+@pytest.mark.parametrize("causal", [False, True])
+def test_grouped_query_heads_match_dense(heads, kv_heads, causal):
+    """Forward and gradients where ``heads // kv_heads`` query heads share
+    a key/value head: the index map repeats, dK and dV are the group's
+    sums."""
+    rng = np.random.default_rng(heads + kv_heads)
+    q, k, v = _grouped_qkv(rng, 2, 48, heads, kv_heads)
+    mask = jnp.broadcast_to(jnp.tril(jnp.ones((48, 48), bool)) if causal
+                            else jnp.ones((48, 48), bool), (2, 48, 48))
+    w = jnp.asarray(rng.standard_normal((2, 48, heads, 16)), jnp.float32)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal, block_q=16, block_k=16,
+                               interpret=True)
+
+    np.testing.assert_allclose(flash(q, k, v), _grouped_dense(q, k, v, mask),
+                               rtol=1e-5, atol=1e-5)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(_grouped_dense(*a, mask) * w),
+                    (0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        assert g.shape == r.shape
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("t,topk,block", [
+    (64, 16, 32),     # every q block holds a row that selects
+    (128, 64, 32),    # two q blocks end before topk: the causal tiles
+    (128, 48, 32),    # topk inside a q block
+    (64, 64, 32),     # T at topk: the last row is the first to select
+    (96, 200, 32),    # T under topk: the selection is the causal triangle
+    (128, 32, 64),    # two words a key block
+])
+def test_selected_keys_match_plain_masked_attention(t, topk, block):
+    """Grouped heads attending only to the keys chosen for each row,
+    forward and in the fused backward, against the plain masked softmax."""
+    from autodist_tpu.ops.flash_attention import pack_selection
+
+    rng = np.random.default_rng(t + topk)
+    q, k, v = _grouped_qkv(rng, 2, t, 4, 2)
+    mask = _top_k_rows(rng, 2, t, topk)
+    assert int(mask[0, -1].sum()) == min(topk, t)
+    words = pack_selection(mask, block_k=block)
+    w = jnp.asarray(rng.standard_normal((2, t, 4, 16)), jnp.float32)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, True, block_q=block, block_k=block,
+                               interpret=True, selection=words,
+                               select_from=topk)
+
+    np.testing.assert_allclose(flash(q, k, v), _grouped_dense(q, k, v, mask),
+                               rtol=1e-5, atol=1e-5)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(_grouped_dense(*a, mask) * w),
+                    (0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5)
+
+
+def test_selection_is_refused_where_it_cannot_be_honoured():
+    from autodist_tpu.ops.flash_attention import pack_selection
+
+    q, k, v = _grouped_qkv(np.random.default_rng(0), 1, 64, 2, 2)
+    words = pack_selection(jnp.tril(jnp.ones((1, 64, 64), bool)),
+                           block_k=32)
+    with pytest.raises(ValueError, match="selection is causal"):
+        flash_attention(q, k, v, False, interpret=True, selection=words,
+                        select_from=8)
+    with pytest.raises(ValueError, match="select_from"):
+        flash_attention(q, k, v, True, interpret=True, selection=words)
+    with pytest.raises(ValueError, match="query heads over"):
+        flash_attention(q, k[:, :, :1].repeat(3, 2)[:, :, :3], v, True,
+                        interpret=True)
+    with pytest.raises(ValueError, match="multiple of 32"):
+        pack_selection(jnp.ones((1, 48, 48), bool), block_k=16)

@@ -95,6 +95,63 @@ def _pallas_calls(compiled):
             if 'custom_call_target="tpu_custom_call"' in ln]
 
 
+def _reader_pattern(metric):
+    """``KERNEL`` of ``benchmark/metrics/<metric>.py``: the name and shape
+    its reader finds the Pallas call by in a trace."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(metric, os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "metrics", metric + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.KERNEL
+
+
+_GROUPED = {
+    # [B, T, H, Hkv, D], the selection's topk (None: grouped heads alone)
+    "keye_one_sequence": ((1, 16384, 32, 4, 128), 2048),
+    "selected_short": ((1, 4096, 8, 2, 128), 2048),
+    "selected_batch": ((2, 2048, 4, 1, 64), 512),
+    "grouped_alone": ((2, 2048, 16, 4, 64), None),
+}
+
+
+@pytest.mark.parametrize("name,precision", [
+    (name, precision) for name in sorted(_GROUPED)
+    for precision in ("default", "highest")
+    if (name, precision) != ("keye_one_sequence", "highest")])
+def test_grouped_heads_and_selection_compile(one_chip, name, precision):
+    """The kernels with ``H // Hkv`` query heads a key/value head and a
+    packed selection, at the keye cell's 16,384 tokens (float32 in, 64 MiB
+    of VMEM asked for the backward) and at shorter ones: value and
+    gradient, one forward and one fused backward call."""
+    from autodist_tpu.ops import flash_attention
+
+    (b, t, h, g, d), topk = _GROUPED[name]
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    args = [shape(b, t, h, d), shape(b, t, g, d), shape(b, t, g, d)]
+    if topk:
+        args.append(shape(b, t // 32, t, dtype=jnp.int32))
+
+    def loss(q, k, v, words=None):
+        selection = {} if words is None else dict(selection=words,
+                                                  select_from=topk)
+        return jnp.sum(flash_attention(q, k, v, True, interpret=False,
+                                       **selection))
+
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            *args).compile()
+    calls = _pallas_calls(compiled)
+    assert len(calls) == 2
+    # keys and values go in with their own heads: nothing was repeated
+    assert all(f"f32[{b},{g},{t},{d}]" in ln for ln in calls)
+
+
 def test_latent_attention_writes_each_kernel_operand_once(one_chip):
     """One layer's latent attention at the kanana cell's widths, value and
     gradient, four sequences mapped under the layer's checkpoint as
@@ -169,5 +226,11 @@ def test_data_parallel_over_four_chips_compiles(chips):
         x, x, x).compile()
     calls = _pallas_calls(compiled)
     assert len(calls) == 2
+    # by the name and the shape ``flash_attention_roofline`` reads a traced
+    # dp4 run by: a rename of the call fails here, not a reader in silence
+    found = re.compile(_reader_pattern("flash_attention_roofline").format(
+        4, 16, 1024, 64))
     for ln in calls:
         assert "f32[4,16,1024,64]" in ln and "f32[16," not in ln, ln
+        assert ln.strip().startswith("%shard_map") and found.search(
+            ln.strip()), ln

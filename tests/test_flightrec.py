@@ -474,9 +474,17 @@ def test_supervisor_attaches_bundle_on_failure(tmp_path, monkeypatch):
     assert fails and fails[0].get("bundle") == bundle
 
 
-def test_install_fatal_handlers(tmp_path):
+def test_install_fatal_handlers(tmp_path, monkeypatch):
     """Arming writes the faulthandler log target and an excepthook that
-    dumps a bundle — exercised in-process by invoking the hook."""
+    dumps a bundle — exercised in-process by invoking the hook.
+
+    Arming is once a PROCESS, and a worker runs other files' tests in the
+    same process: where one of them armed the handlers for its own run
+    directory first (a supervised session does), this test's call was a
+    no-op and its bundle went there.  What was armed before is set aside
+    for the test and comes back after it, hook and all."""
+    monkeypatch.setattr(fr, "_fatal_armed", None)
+    monkeypatch.setattr(sys, "excepthook", sys.excepthook)
     run_dir = str(tmp_path / "fatal")
     assert fr.install_fatal_handlers(run_dir)
     assert fr.install_fatal_handlers(run_dir)   # idempotent

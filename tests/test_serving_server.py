@@ -260,8 +260,20 @@ def test_cancel_unknown_and_queued(server):
 
 def test_backpressure_answers_429_with_retry_after(lm):
     """A full engine queue surfaces the typed AdmissionError as HTTP
-    429 with a Retry-After header — the bounded-queue satellite."""
+    429 with a Retry-After header — the bounded-queue satellite.
+
+    Each request is sent when the engine SHOWS the state the one before it
+    was to bring about (the slot taken, then the queue full), not after a
+    fixed sleep: under the suite's six workers the first step's compile
+    alone outlasts any sleep short enough to keep, and the third request
+    then found the slot free or the queue empty."""
     import time as _time
+
+    def until(state, what, timeout_s=120.0):
+        deadline = _time.monotonic() + timeout_s
+        while not state():
+            assert _time.monotonic() < deadline, f"never saw: {what}"
+            _time.sleep(0.01)
 
     spec, params = lm
     eng = DecodeEngine(spec, params, slots=1, window=24, chunk=2,
@@ -272,15 +284,16 @@ def test_backpressure_answers_429_with_retry_after(lm):
         t1 = threading.Thread(
             target=_post, args=(srv.address, "/v1/completions",
                                 {"prompt_tokens": [1, 2],
-                                 "max_new_tokens": 8}))
+                                 "max_new_tokens": 16}))
         t1.start()
-        _time.sleep(0.3)       # in flight: slot busy, queue empty
+        until(lambda: any(eng._active) and not eng._queue,
+              "the first request in the slot, the queue empty")
         t2 = threading.Thread(
             target=_post, args=(srv.address, "/v1/completions",
                                 {"prompt_tokens": [3],
                                  "max_new_tokens": 8}))
-        t2.start()             # queued: queue now full
-        _time.sleep(0.3)
+        t2.start()
+        until(lambda: len(eng._queue) == 1, "the second request queued")
         conn = http.client.HTTPConnection(*srv.address, timeout=30)
         conn.request("POST", "/v1/completions",
                      json.dumps({"prompt_tokens": [4],
