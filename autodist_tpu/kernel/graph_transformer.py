@@ -32,6 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from autodist_tpu.graph_item import GraphItem
 from autodist_tpu.kernel import sharding_utils as su
 from autodist_tpu.strategy.compiler import CompiledStrategy
+from autodist_tpu.telemetry import step_values
 from autodist_tpu.utils import logging
 
 
@@ -59,6 +60,9 @@ class DistributedStep:
     mesh: Any
     compiled_strategy: CompiledStrategy
     eval_fn: Optional[Callable] = None  # (params, batch) -> metrics; no update
+    # what the loss function emits beside its loss (telemetry/
+    # step_values.py), to publish after a fetched step; None: nothing
+    step_values: Any = None
     pad_info: Any = None             # params-shaped info tree, or None
     opt_pad_info: Any = None         # opt-state-shaped info tree, or None
     logical_param_shardings: Any = None  # pad axis dropped; None = physical
@@ -290,6 +294,13 @@ class GraphTransformer:
         opt_spec_tree = su.opt_spec_tree(opt_shape, phys_params, grad_spec_tree)
         opt_sh = su.sharding_tree(mesh, opt_spec_tree)
 
+        # a loss function that emits step values hands them back as aux,
+        # beside the user's own (telemetry/step_values.py)
+        reports = None if gi.grad_fn is not None \
+            else step_values.Collector.wanted_by(gi.loss_fn)
+        train_loss_fn = loss_fn if reports is None \
+            else reports.wrap(loss_fn, gi.has_aux)
+        has_aux = gi.has_aux or reports is not None
         if gi.grad_fn is not None:
             # Manual value-and-grad (e.g. the 1F1B pipeline backward):
             # the contract is LOGICAL params in, LOGICAL grads out — under
@@ -304,8 +315,7 @@ class GraphTransformer:
             else:
                 vg = user_grad
         else:
-            vg = jax.value_and_grad(loss_fn, has_aux=gi.has_aux)
-        has_aux = gi.has_aux
+            vg = jax.value_and_grad(train_loss_fn, has_aux=has_aux)
         if gi.accum_steps > 1 and extra_metrics_fn is not None:
             logging.warning(
                 "accum_steps=%d with metrics_fn: metrics run one "
@@ -370,9 +380,9 @@ class GraphTransformer:
         if num_active and num_ls is not None:
             def _scaled_loss(p, batch, scale):
                 if has_aux:
-                    loss_, aux_ = loss_fn(p, batch)
+                    loss_, aux_ = train_loss_fn(p, batch)
                     return loss_ * scale, aux_
-                return loss_fn(p, batch) * scale
+                return train_loss_fn(p, batch) * scale
             vg_scaled = jax.value_and_grad(_scaled_loss, has_aux=has_aux)
         else:
             vg_scaled = None
@@ -493,6 +503,10 @@ class GraphTransformer:
                     loss_scale=ns["scale"],
                     skipped_steps=new_ns["skipped"],
                     per_bucket=per_bucket)
+            if reports is not None:
+                aux, emitted = aux
+                if emitted:
+                    metrics[step_values.KEY] = emitted
             if aux is not None:
                 metrics["aux"] = aux
             if extra_metrics_fn is not None:
@@ -533,7 +547,7 @@ class GraphTransformer:
         # Same loss_fn as training (the pad-aware wrapper), so padded rows
         # contribute nothing to evaluation.
         eval_fn = jax.jit(
-            _make_eval_step(loss_fn, has_aux, extra_metrics_fn, mesh),
+            _make_eval_step(loss_fn, gi.has_aux, extra_metrics_fn, mesh),
             in_shardings=(param_sh, None))
         init_fn = jax.jit(optimizer.init, out_shardings=opt_sh)
         if stale is None and num_active:
@@ -582,7 +596,7 @@ class GraphTransformer:
             init_sync_state=init_sync_state,
             param_shardings=param_sh, opt_shardings=opt_sh,
             mesh=mesh, compiled_strategy=self.compiled,
-            eval_fn=eval_fn,
+            eval_fn=eval_fn, step_values=reports,
             pad_info=pad_info, opt_pad_info=opt_pad_info,
             logical_param_shardings=logical_param_sh,
             logical_opt_shardings=logical_opt_sh,

@@ -57,10 +57,10 @@ from autodist_tpu.ops.topk_select import top_k_mask
 from autodist_tpu.parallel.moe import (
     ROUTING_RESIDUAL_NAMES,
     init_routed_moe_params,
+    record_row_budgets,
     routed_moe_ffn,
-    routed_rows,
 )
-from autodist_tpu.telemetry import registry, timeline
+from autodist_tpu.telemetry import registry, step_values, timeline
 
 #: a layer's selection, as ``pack_selection``'s words: kept by name, so
 #: the backward neither scores nor selects again
@@ -294,22 +294,16 @@ def gqa_dsa_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
 
     def layer(lp, x):
         """``x [B, T, D]`` through one layer: attention one sequence at a
-        time, the experts one slice at a time."""
+        time, the experts one slice at a time (maps, not vmaps: the
+        expert layer's ``switch`` stays a branch).  Returns the slices'
+        ``tokens_per_expert`` ``[slices, count]`` beside ``x``."""
         x = jax.lax.map(lambda row: attention_half(lp, row[None])[0], x)
         y, counts = jax.lax.map(lambda part: expert_half(lp, part),
                                 slices(x))
-        return y.reshape(x.shape), counts.sum(axis=0)
+        return y.reshape(x.shape), counts
 
     def set_gauges(params, tokens, x):
         batch, t = tokens.shape
-        computed, expected = routed_rows(tokens.size, top_k, held[1],
-                                         num_experts)
-        for kind, rows in (("computed", computed), ("expected", expected)):
-            registry.gauge(
-                "autodist_moe_rows_per_step",
-                "rows the grouped expert products are handed a step, and "
-                "rows an even router would send here",
-                {"kind": kind}).set(rows * num_layers)
         whole = min(t, topk)       # rows that attend to all before them
         selected = whole * (whole + 1) // 2 + (t - whole) * topk
         for kind, pairs in (("selected", selected), ("computed",
@@ -335,7 +329,11 @@ def gqa_dsa_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
         for i in range(num_layers):
             x, c = layer(params[f"layers_{i}"], x)
             counts.append(c)
-        return rms_norm(x, params["ln_final"]["scale"], rms_eps), counts
+        # here, outside the layers' maps and checkpoints
+        record_row_budgets(jnp.stack(counts), slices(x).shape[1] * top_k,
+                           num_experts)
+        return (rms_norm(x, params["ln_final"]["scale"], rms_eps),
+                [c.sum(axis=0) for c in counts])
 
     def apply_fn(params, tokens):
         return jnp.einsum("btd,vd->btv", features(params, tokens)[0],
@@ -364,7 +362,8 @@ def gqa_dsa_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
 
     return ModelSpec(
         name="gqa_dsa_moe_lm",
-        init=init, loss_fn=loss_fn, apply_fn=apply_fn, make_batch=make_batch,
+        init=init, loss_fn=step_values.reporting(loss_fn), apply_fn=apply_fn,
+        make_batch=make_batch,
         sparse_vars=("embed",),
         expert_vars=("*/moe/experts/*",),
         config=dict(vocab_size=vocab_size, num_layers=num_layers,

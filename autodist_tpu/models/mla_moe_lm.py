@@ -38,11 +38,11 @@ from autodist_tpu.ops.flash_attention import RESIDUAL_NAMES
 from autodist_tpu.parallel.moe import (
     ROUTING_RESIDUAL_NAMES,
     init_routed_moe_params,
+    record_row_budgets,
     routed_moe_ffn,
-    routed_rows,
     swiglu,
 )
-from autodist_tpu.telemetry import registry, timeline
+from autodist_tpu.telemetry import registry, step_values, timeline
 
 # What a layer's checkpoint keeps besides its inputs: what a kernel or a
 # sort produced (dear to recompute, cheap to hold) under both policies.
@@ -301,22 +301,17 @@ def mla_moe_lm(vocab_size: int = 16032, num_layers: int = 5,
         sequences instead of formed in one product.  The attention's
         weights are cut for their products here, once, and not under the
         map: inside it the cuts' transposes would pad and add weight-sized
-        buffers every sequence of the backward."""
+        buffers every sequence of the backward.  A map and not a vmap
+        for the expert layer's sake too: its ``switch`` stays a branch.
+        Returns the sequences' ``tokens_per_expert`` ``[B, count]`` (None
+        from a dense layer) beside ``x``."""
         lp = operands(lp)
         x, counts = jax.lax.map(lambda row: layer_fn(lp, row[None]), x)
-        return x[:, 0], None if counts is None else counts.sum(axis=0)
+        return x[:, 0], counts
 
     def features(params, tokens):
         """Final-norm activations ``[B, T, D]`` and the expert layers'
         ``tokens_per_expert`` ``[expert layers, count]``."""
-        computed, expected = routed_rows(tokens.size, top_k, held[1],
-                                         num_experts)
-        for kind, rows in (("computed", computed), ("expected", expected)):
-            registry.gauge(
-                "autodist_moe_rows_per_step",
-                "rows the grouped expert products are handed a step, and "
-                "rows an even router would send here",
-                {"kind": kind}).set(rows * (num_layers - first_dense))
         x = jnp.take(params["embed"], tokens, axis=0)
         for name, held_bytes in kept_bytes(params, x).items():
             registry.gauge(
@@ -329,7 +324,11 @@ def mla_moe_lm(vocab_size: int = 16032, num_layers: int = 5,
             x, c = layer(params[f"layers_{i}"], x)
             if c is not None:
                 counts.append(c)
-        return rms_norm(x, params["ln_final"]["scale"], rms_eps), counts
+        if counts:     # here, outside the layers' maps and checkpoints
+            record_row_budgets(jnp.stack(counts), tokens.shape[1] * top_k,
+                               num_experts)
+        return (rms_norm(x, params["ln_final"]["scale"], rms_eps),
+                [c.sum(axis=0) for c in counts])
 
     def apply_fn(params, tokens):
         return jnp.einsum("btd,vd->btv", features(params, tokens)[0],
@@ -358,7 +357,8 @@ def mla_moe_lm(vocab_size: int = 16032, num_layers: int = 5,
 
     return ModelSpec(
         name="mla_moe_lm",
-        init=init, loss_fn=loss_fn, apply_fn=apply_fn, make_batch=make_batch,
+        init=init, loss_fn=step_values.reporting(loss_fn), apply_fn=apply_fn,
+        make_batch=make_batch,
         sparse_vars=("embed",),
         expert_vars=("*/moe/experts/*",),
         config=dict(vocab_size=vocab_size, num_layers=num_layers,

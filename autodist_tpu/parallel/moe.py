@@ -257,27 +257,6 @@ def init_routed_moe_params(rng, d_model: int, d_expert: int,
     return params
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_rows(x, index, inverse, fan: int):
-    """``x[index // fan]`` where ``index`` is a permutation of
-    ``fan * len(x)`` places and ``inverse`` its inverse: every row goes to
-    ``fan`` places, so the cotangent comes back by a gather through
-    ``inverse`` and a sum over ``fan``, never by a scatter."""
-    return jnp.take(x, index // fan if fan > 1 else index, axis=0)
-
-
-def _take_rows_fwd(x, index, inverse, fan):
-    return _take_rows(x, index, inverse, fan), inverse
-
-
-def _take_rows_bwd(fan, inverse, g):
-    back = jnp.take(g, inverse, axis=0)
-    return (back.reshape(-1, fan, g.shape[-1]).sum(axis=1), None, None)
-
-
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
-
-
 def swiglu(w: dict, x: jax.Array) -> jax.Array:
     """``w_down(silu(w_gate x) * w_up x)``."""
     return (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"]
@@ -294,12 +273,207 @@ ROUTING_RESIDUAL_NAMES = ("routed_moe/chosen", "routed_moe/order",
 
 
 def routed_rows(tokens: int, top_k: int, held: int, total: int):
-    """(rows the grouped products are handed, rows expected to be routed
-    here) for one call of :func:`routed_moe_ffn` over ``tokens`` tokens:
-    every pick of every token has its row, because all of a token's picks
-    may lie here; ``held / total`` of them do if the router spreads
-    evenly."""
+    """(rows the grouped products are handed AT MOST, rows expected to be
+    routed here) for one call of :func:`routed_moe_ffn` over ``tokens``
+    tokens: every pick of every token can have its row, because all of a
+    token's picks may lie here (the top rung of :func:`row_budgets`);
+    ``held / total`` of them do if the router spreads evenly.  What a call
+    IS handed is the rung its routing takes (:func:`budgets_taken`)."""
     return tokens * top_k, tokens * top_k * held / total
+
+
+#: rows a tile of the grouped product (``jax.lax.ragged_dot`` on a TPU)
+_GROUPED_TILE = 512
+
+
+def row_budgets(rows: int, held: int, total: int) -> Tuple[int, ...]:
+    """The ladder of static row budgets of one call of
+    :func:`routed_moe_ffn` over ``rows = tokens * top_k`` picks, from the
+    shapes alone: twice and four times the ``held / total`` of them an
+    even router sends here, then all of them: at most three rungs,
+    ascending, the last always ``rows`` (the budget that holds whatever the
+    routing: no capacity).  A rung is a whole number of the grouped
+    product's 512-row tiles where ``rows`` is (of 8 rows otherwise).  All
+    experts held, or half: ``(rows,)``."""
+    tile = _GROUPED_TILE if rows % _GROUPED_TILE == 0 else 8
+    rungs = {-(-(factor * rows * held) // (total * tile)) * tile
+             for factor in (2, 4)}
+    return tuple(sorted(c for c in rungs if c < rows)) + (rows,)
+
+
+def _rung(rungs, routed):
+    """Index of the smallest of ``rungs`` that holds ``routed`` rows
+    (any shape of counts)."""
+    return jnp.sum(jnp.asarray(routed)[..., None]
+                   > jnp.asarray(rungs[:-1], jnp.int32), axis=-1)
+
+
+def budgets_taken(tokens_per_expert: jax.Array, rows: int, total: int
+                  ) -> Tuple[Tuple[int, ...], jax.Array]:
+    """``(rungs, calls [len(rungs)] int32)``: the ladder of calls of
+    :func:`routed_moe_ffn` over ``rows`` picks each, and how many of the
+    calls whose ``tokens_per_expert`` are stacked in ``[..., count]`` took
+    each rung.  The same rule the layer applies to the same integers: a
+    model sums this over its calls OUTSIDE their checkpoints and maps."""
+    rungs = row_budgets(rows, tokens_per_expert.shape[-1], total)
+    rung = _rung(rungs, tokens_per_expert.sum(axis=-1)).reshape(-1)
+    return rungs, jnp.sum(rung[:, None] == jnp.arange(len(rungs)), axis=0,
+                          dtype=jnp.int32)
+
+
+_ROWS_HELP = ("rows the grouped expert products were handed in the last "
+              "step (the row budgets its calls took), and rows an even "
+              "router would send here")
+
+
+def record_row_budgets(tokens_per_expert: jax.Array, rows: int, total: int
+                       ) -> None:
+    """For a model, once a step, from the top level of its loss function:
+    ``tokens_per_expert [..., count]`` of ALL the step's calls of
+    :func:`routed_moe_ffn` over ``rows`` picks each, stacked outside their
+    checkpoints and maps.  Sets ``autodist_moe_rows_per_step{kind=
+    "expected"}`` now, while tracing, and emits the step's calls by rung
+    as a step value (``telemetry/step_values.py``: out with the step's
+    metrics, no host callback, so the step program stays in the
+    persistent compilation cache): after every step a session fetched,
+    ``{kind="computed"}`` is the rows of the budgets that step's calls
+    took and ``autodist_moe_row_budget_calls_total{rung=<rows>}`` has
+    counted them.  The loss function is to be marked ``step_values.
+    reporting``."""
+    from autodist_tpu.telemetry import registry, step_values
+
+    rungs, calls = budgets_taken(tokens_per_expert, rows, total)
+    # calls x rows x held / total, and size = calls x held
+    registry.gauge("autodist_moe_rows_per_step", _ROWS_HELP,
+                   {"kind": "expected"}).set(
+        tokens_per_expert.size * rows / total)
+
+    def publish(calls):     # [len(rungs)], stacked over microbatches if any
+        calls = calls.reshape(-1, len(rungs)).sum(axis=0).tolist()
+        registry.gauge("autodist_moe_rows_per_step", _ROWS_HELP,
+                       {"kind": "computed"}).set(
+            sum(c * r for c, r in zip(calls, rungs)))
+        for taken, rung in zip(calls, rungs):
+            registry.counter(
+                "autodist_moe_row_budget_calls_total",
+                "calls of the routed expert layer by the row budget they "
+                "took", {"rung": str(rung)}).inc(taken)
+
+    step_values.emit("moe_row_budget_calls", calls, publish)
+
+
+def _grouped_swiglu(experts, rows, sizes):
+    """:func:`swiglu` of each group of ``rows`` (``sizes`` rows each, in
+    order) under its own expert's weights.  Rows past the last group come
+    back unwritten."""
+    hidden = (jax.nn.silu(jax.lax.ragged_dot(rows, experts["w_gate"], sizes))
+              * jax.lax.ragged_dot(rows, experts["w_up"], sizes))
+    return jax.lax.ragged_dot(hidden, experts["w_down"], sizes)
+
+
+def _sorted_rows(budget: int, top_k: int, h, order, inverse, sizes):
+    """The first ``budget`` places of the sorted order: ``(their tokens'
+    rows of h or zeros past the last group [budget, d], which of them hold
+    a pick routed here [budget, 1], their picks [budget], their tokens
+    [budget], where each of the N * k picks went [N * k])``; a pick past
+    the budget is not held here and any place will do for it."""
+    index = order[:budget]
+    token = index // top_k
+    live = (jnp.arange(budget) < sizes.sum())[:, None]
+    rows = jnp.where(live, jnp.take(h, token, axis=0), 0)
+    return rows, live, index, token, jnp.minimum(inverse, budget - 1)
+
+
+def _to_tokens(sorted_rows, place, here):
+    """``[budget, d]`` in sorted order back to ``[N, k, d]`` by a gather,
+    zeros for the picks not held here (what lies past the last group is
+    never read as a number).  Token order is ``N * k`` rows wide by
+    nature."""
+    back = jnp.take(sorted_rows, place, axis=0)
+    return jnp.where(here[..., None], back.reshape(*here.shape, -1), 0)
+
+
+def _experts_on(budget: int, top_k: int, h, experts, weight, order, inverse,
+                sizes, here):
+    """The held experts' part of the layer on the first ``budget`` rows of
+    the sorted order, which hold every pick routed here (``sizes.sum() <=
+    budget``), back in token order and summed over the picks: ``[N, d]``."""
+    from autodist_tpu.telemetry import timeline
+
+    with jax.named_scope(timeline.SCOPE_MOE_EXPERTS):
+        rows, _, _, _, place = _sorted_rows(budget, top_k, h, order, inverse,
+                                            sizes)
+        out = _grouped_swiglu(experts, rows, sizes)
+    with jax.named_scope(timeline.SCOPE_MOE_COMBINE):
+        return jnp.sum(_to_tokens(out, place, here) * weight[..., None],
+                       axis=1)
+
+
+def _experts_on_transposed(budget: int, top_k: int, g, h, experts, weight,
+                           order, inverse, sizes, here):
+    """The cotangents of ``(h, experts, weight)`` under :func:`_experts_on`
+    for the cotangent ``g [N, d]`` of its result, on ``budget`` rows too:
+    the cotangent of a sorted row is its token's row of ``g`` times its
+    pick's weight, a pick's weight takes the dot of its sorted row with its
+    token's ``g``, and only the cotangent of ``h`` is gathered back to
+    token order (never scattered) and summed over the picks."""
+    from autodist_tpu.telemetry import timeline
+
+    with jax.named_scope(timeline.SCOPE_MOE_EXPERTS):
+        rows, live, index, token, place = _sorted_rows(
+            budget, top_k, h, order, inverse, sizes)
+        out, transpose = jax.vjp(
+            lambda experts, rows: _grouped_swiglu(experts, rows, sizes),
+            experts, rows)
+    with jax.named_scope(timeline.SCOPE_MOE_COMBINE):
+        g_rows = jnp.take(g, token, axis=0)
+        d_out = jnp.where(live, g_rows * jnp.take(
+            weight.reshape(-1), index)[:, None], 0)
+        d_weight = _to_tokens(
+            jnp.sum(jnp.where(live, out, 0) * g_rows, axis=-1,
+                    keepdims=True), place, here)[..., 0]
+    with jax.named_scope(timeline.SCOPE_MOE_EXPERTS):
+        d_experts, d_rows = transpose(d_out)
+        d_h = jnp.sum(_to_tokens(jnp.where(live, d_rows, 0), place, here),
+                      axis=1)
+    return d_h, d_experts, d_weight
+
+
+def _switch(rungs, sizes, branch, *operands):
+    """``branch(budget)(*operands)`` for the smallest of ``rungs`` that
+    holds ``sizes.sum()`` rows, chosen on the device (one rung: no
+    ``switch``, the branch itself)."""
+    return jax.lax.switch(_rung(rungs, sizes.sum()),
+                          [branch(c) for c in rungs], *operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _budgeted_experts(top_k: int, rungs, h, experts, weight, order, inverse,
+                      sizes, here):
+    """:func:`_experts_on` the smallest of ``rungs`` that holds the picks
+    routed here.  Differentiated as written, ``switch`` would make every
+    branch hand back every branch's residuals, zeros for those not taken:
+    each call would write the top rung's as zeros.  So the residuals are
+    the INPUTS, which the branches share, and the backward switches on the
+    same rung (recomputed from ``sizes``) and runs that branch's forward
+    again and its transpose inside it."""
+    return _switch(rungs, sizes, lambda c: functools.partial(
+        _experts_on, c, top_k), h, experts, weight, order, inverse, sizes,
+        here)
+
+
+def _budgeted_experts_fwd(top_k, rungs, *operands):
+    return _budgeted_experts(top_k, rungs, *operands), operands
+
+
+def _budgeted_experts_bwd(top_k, rungs, operands, g):
+    # h, the experts' leaves and the weights; no cotangent for the integers
+    sizes = operands[5]
+    return _switch(rungs, sizes, lambda c: functools.partial(
+        _experts_on_transposed, c, top_k), g, *operands) + (None,) * 4
+
+
+_budgeted_experts.defvjp(_budgeted_experts_fwd, _budgeted_experts_bwd)
 
 
 def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
@@ -333,15 +507,24 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
     same experts before any balancing could act.
 
     Static shapes without a capacity: the ``N * k`` (token, pick) pairs
-    are sorted by local expert, the picks of absent experts last, rows are
-    gathered in that order and go through three grouped products
-    (``jax.lax.ragged_dot``, on a TPU one Mosaic kernel each that leaves
-    the row tiles past the last group alone), then come back to token
-    order by a gather and are summed with their weights.  Whatever the
-    routing, every pick of a held expert is computed: with all tokens on
-    the held experts the groups fill all ``N * k`` rows.
+    are sorted by local expert, the picks of absent experts last.  The
+    first ``C`` rows of that order are gathered and go through three
+    grouped products (``jax.lax.ragged_dot``, on a TPU one Mosaic kernel
+    each that leaves the row tiles past the last group alone), then come
+    back to token order by a gather and are summed with their weights.
+    ``C`` is the smallest rung of :func:`row_budgets` (from the shapes
+    alone: twice and four times what an even router sends here, then
+    ``N * k``) that holds the picks routed here, chosen ON THE DEVICE from
+    ``sizes.sum()`` by one ``jax.lax.switch``, forward and backward alike.
+    Whatever the routing, every pick of a held expert is computed: with
+    all tokens on the held experts the call takes the top rung and the
+    groups fill all ``N * k`` rows.  Nothing but the routing chooses a
+    rung.  CALL THIS UNDER ``jax.lax.map``, NOT ``jax.vmap``: batched, a
+    ``switch`` becomes a ``select`` and every rung runs.  A budget is a
+    call's: a heavy sequence costs its own call one step of the ladder.
 
-    Returns ``(y, tokens_per_expert [count] int32)``.
+    Returns ``(y, tokens_per_expert [count] int32)``;
+    :func:`budgets_taken` of the second says which rung the call took.
     """
     from autodist_tpu.telemetry import registry, timeline
 
@@ -387,23 +570,13 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
                         dtype=jnp.int32)
         order, inverse, sizes = map(checkpoint_name, (order, inverse, sizes),
                                     ROUTING_RESIDUAL_NAMES[1:])
-        live = (jnp.arange(n * top_k) < sizes.sum())[:, None]
-
-    with jax.named_scope(timeline.SCOPE_MOE_EXPERTS):
-        rows = jnp.where(live, _take_rows(h, order, inverse, top_k), 0)
-        hidden = (jax.nn.silu(jax.lax.ragged_dot(
-            rows, experts["w_gate"].astype(h.dtype), sizes))
-            * jax.lax.ragged_dot(rows, experts["w_up"].astype(h.dtype),
-                                 sizes))
-        out = jax.lax.ragged_dot(hidden, experts["w_down"].astype(h.dtype),
-                                 sizes)
-
     with jax.named_scope(timeline.SCOPE_MOE_COMBINE):
-        back = _take_rows(out, inverse, order, 1).reshape(n, top_k, d)
         weight = jnp.where(here, gates, 0.0).astype(h.dtype)
-        # what lies past the last group is never read as a number
-        y = jnp.sum(jnp.where(here[..., None], back, 0)
-                    * weight[..., None], axis=1)
+
+    y = _budgeted_experts(
+        top_k, row_budgets(n * top_k, count, total), h,
+        jax.tree_util.tree_map(lambda w: w.astype(h.dtype), experts),
+        weight, order, inverse, sizes, here)
 
     if "shared" in params:
         with jax.named_scope(timeline.SCOPE_MOE_SHARED):

@@ -21,7 +21,7 @@ import numpy as np
 from autodist_tpu.graph_item import GraphItem
 from autodist_tpu.kernel import sharding_utils as su
 from autodist_tpu.kernel.graph_transformer import DistributedStep
-from autodist_tpu.telemetry import flightrec
+from autodist_tpu.telemetry import flightrec, step_values
 from autodist_tpu.telemetry import timeline as tl
 from autodist_tpu.utils import logging, metrics, tracing
 
@@ -231,10 +231,16 @@ class DistributedSession:
                     items, tokens = self._batch_sizes
                     record = rec.record_step(step_index, items=items,
                                              tokens=tokens)
+            # what the loss function emitted beside its loss goes to its
+            # publishers with the fetch, never to the caller
+            emitted = out.pop(step_values.KEY, None)
             if not sync:
                 return out
             with tl.host_span(tl.SESSION_FETCH, step=step_index) as fetched:
-                out = jax.tree_util.tree_map(lambda x: np.asarray(x), out)
+                out, emitted = jax.tree_util.tree_map(
+                    lambda x: np.asarray(x), (out, emitted))
+            if emitted:
+                self._step.step_values.publish(emitted)
             if record is not None and fetched is not None:
                 # the wait for the device, which no part of dispatch holds
                 record.phases["fetch"] = fetched.end - fetched.start

@@ -511,9 +511,68 @@ def train_moe_phase(model: dict, *, batch_size: int, steps: int) -> dict:
     facts["remat_kept_bytes"] = {
         m.labels["name"]: int(m.value) for m in DEFAULT_REGISTRY.metrics()
         if m.name == "autodist_remat_kept_bytes_per_step"}
+    facts["row_budgets"] = forced_router_takes_the_top_rung(cfg)
     del sess, ad
     _reset_default_autodist_for_testing()
     gc.collect()
+    return facts
+
+
+def forced_router_takes_the_top_rung(cfg: dict, tokens: int = 512) -> dict:
+    """One routed layer at the model's widths over ``tokens`` tokens, twice:
+    under the router as seeded (an even one: the low rung of the ladder of
+    row budgets holds its picks) and under a selection bias that sends
+    every pick to the held experts (the top rung: every pick has its row).
+    Both against the layer written out plainly (every held expert over
+    every token, weighted by the router's weights), within what a bfloat16
+    pass of three products leaves."""
+    import jax
+    import jax.numpy as jnp
+
+    from autodist_tpu.parallel import moe
+
+    first, count = cfg["experts_held"]
+    total, top_k = cfg["num_experts"], cfg["top_k"]
+    if count < top_k:
+        return {"skipped": f"{count} held experts cannot take {top_k} picks"}
+    params = moe.init_routed_moe_params(
+        jax.random.PRNGKey(SEED), cfg["d_model"], cfg["d_expert"], total,
+        experts_held=count)
+    x = jax.random.normal(jax.random.PRNGKey(SEED + 1),
+                          (tokens, cfg["d_model"]))
+    biases = {"even": params["router_bias"],
+              "forced": jnp.full((total,), -10.0).at[
+                  first:first + count].set(10.0)}
+
+    def plain(p, x):
+        scores = jax.nn.sigmoid(jnp.dot(
+            x, p["router"], precision=jax.lax.Precision.HIGHEST))
+        _, chosen = jax.lax.top_k(scores + p["router_bias"], top_k)
+        picked = jnp.take_along_axis(scores, chosen, axis=-1)
+        gates = jnp.zeros_like(scores).at[
+            jnp.arange(tokens)[:, None], chosen].set(
+                picked / picked.sum(-1, keepdims=True))
+        each = jax.vmap(lambda w: moe.swiglu(w, x))(p["experts"])
+        return jnp.einsum("ne,end->nd", gates[:, first:first + count], each)
+
+    rungs = moe.row_budgets(tokens * top_k, count, total)
+    facts = {"rungs": list(rungs)}
+    for name, bias in biases.items():
+        p = dict(params, router_bias=bias)
+        y, counts = jax.jit(lambda p, x: moe.routed_moe_ffn(
+            p, x, top_k=top_k, experts_held=(first, count)))(p, x)
+        want = jax.jit(plain)(p, x)
+        gap = float(jnp.linalg.norm(y - want) / jnp.linalg.norm(want))
+        taken = rungs[int(jnp.argmax(moe.budgets_taken(
+            counts, tokens * top_k, total)[1]))]
+        if gap > 2e-2 or (name == "forced" and (
+                taken != rungs[-1] or int(counts.sum()) != tokens * top_k)):
+            raise AssertionError(
+                f"{name} router: {int(counts.sum())} rows routed here took "
+                f"the budget of {taken} of {rungs}, {gap:.2e} from the "
+                f"layer written out")
+        facts[name] = {"rows_routed_here": int(counts.sum()),
+                       "budget_taken": taken, "gap": round(gap, 6)}
     return facts
 
 

@@ -234,3 +234,69 @@ def test_data_parallel_over_four_chips_compiles(chips):
         assert "f32[4,16,1024,64]" in ln and "f32[16," not in ln, ln
         assert ln.strip().startswith("%shard_map") and found.search(
             ln.strip()), ln
+
+
+_EXPERT_LAYERS = {
+    # the model's call at a cell's widths: calls a step of a layer, picks
+    # a token, router, shared experts' width; then GB of the layer's value
+    # and gradient compiled for this chip AT THE PARENT OF PR 34 (one
+    # budget of every pick), and how far over it the ladder may go
+    "kanana": (dict(top_k=6, scoring="sigmoid", routed_scale=2.448),
+               dict(selection_bias=True, d_shared=2 * 768), 2.418, 0.3),
+    # the step's need FELL 0.30 GB (15.855 -> 15.554 GB); a layer alone
+    # reads 0.44 GB more with the switch than without
+    "keye": (dict(top_k=8, scoring="softmax"),
+             dict(selection_bias=False), 2.213, 0.5),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_EXPERT_LAYERS))
+def test_expert_layer_compiles_with_three_row_budgets(one_chip, cell):
+    """One routed expert layer at an expert cell's widths (4,096 tokens a
+    call, 16 of 128 experts of 2,048 x 768 held, four calls mapped under
+    the layer's checkpoint that keeps the routing's integers), value and
+    gradient: ``jax.lax.switch`` over the three row budgets survives to
+    the compiled program as ONE conditional of three branches forward and
+    one backward (XLA neither flattened them into selects nor lost one),
+    each branch holds its own three (forward) or nine (backward) grouped
+    products, and the compiler's reading of the layer's memory stays near
+    the parent's: the branches share their inputs as residuals, and no
+    branch zero-fills another's."""
+    from autodist_tpu.parallel import moe
+
+    call, init, parent_gb, over_gb = _EXPERT_LAYERS[cell]
+    keep = jax.checkpoint_policies.save_only_these_names(
+        *moe.ROUTING_RESIDUAL_NAMES)
+    assert moe.row_budgets(4096 * call["top_k"], 16, 128) == tuple(
+        4096 * call["top_k"] // part for part in (4, 2, 1))
+
+    @functools.partial(jax.checkpoint, policy=keep, prevent_cse=False)
+    def one_call(params, x):
+        return moe.routed_moe_ffn(params, x, experts_held=(0, 16),
+                                  train_router=False, **call)[0]
+
+    def loss(params, x):
+        y = jax.lax.map(lambda part: one_call(params, part), x)
+        return jnp.sum(y * y)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = jax.eval_shape(lambda: moe.init_routed_moe_params(
+        jax.random.key(0), 2048, 768, 128, experts_held=16, **init))
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        on_chip(params),
+        on_chip(jax.ShapeDtypeStruct((4, 4096, 2048), jnp.float32))
+    ).compile()
+    text = compiled.as_text()
+    switches = re.findall(r" conditional\(.*branch_computations=\{([^}]*)\}",
+                          text)
+    assert [len(found.split(",")) for found in switches] == [3, 3]
+    # three products forward, nine backward, a rung; ragged-dot-metadata
+    # calls aside
+    assert len(re.findall(r"%ragged-dot-none[\w.]* = ", text)) == 3 * (3 + 9)
+    m = compiled.memory_analysis()
+    need = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes) / 1e9
+    assert need < parent_gb + over_gb, need

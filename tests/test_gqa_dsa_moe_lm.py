@@ -35,8 +35,14 @@ from autodist_tpu.ops.flash_attention import (
     unpack_selection,
 )
 from autodist_tpu.ops.topk_select import ordered_bits, top_k_mask
-from autodist_tpu.parallel.moe import init_routed_moe_params, routed_moe_ffn
+from autodist_tpu.parallel.moe import (
+    init_routed_moe_params,
+    routed_moe_ffn,
+    row_budgets,
+)
 from benchmark.reference import keye_vl2 as ref
+
+import _routed_cases as routed_cases
 
 RTOL = 2e-5
 GAIN = 8.0
@@ -134,25 +140,41 @@ def test_another_selection_is_another_loss(topk):
     assert gap > 1e-3
 
 
-def test_three_session_steps_match_the_reference_adamw():
+@pytest.mark.parametrize("router", ["even", "collapsed"])
+def test_three_session_steps_match_the_reference_adamw(router):
     """Through ``AutoDist.capture(has_aux=True) -> create_distributed_
     session -> run``: three steps' losses and the parameters after them
     against the reference under AdamW written out (the indexer's leaves
     move by the decoupled decay alone); the gauges are set at trace
-    time."""
+    time, but the ``computed`` rows of the expert layers: those are the
+    budgets the LAST STEP's calls took (2 layers x 2 slices of 96 tokens x
+    3 picks, a quarter of them expected here).  An even router leaves
+    every call on the low rung of 144 rows; a router that has collapsed
+    (all weights zero, not trained: every token picks experts 0, 1 and 2,
+    which are held) puts every call on the top rung."""
     from autodist_tpu import strategy as strategies
     from autodist_tpu.autodist import (AutoDist,
                                        _reset_default_autodist_for_testing)
     from autodist_tpu.mesh import build_mesh
     from autodist_tpu.telemetry.registry import DEFAULT_REGISTRY
 
-    spec = gqa_dsa_moe_lm(**TINY, experts_held=(4, 4), return_counts=True,
-                          attn_fn=FLASH)
+    collapsed = router == "collapsed"
+    first = 0 if collapsed else 4
+    spec = gqa_dsa_moe_lm(**TINY, experts_held=(first, 4),
+                          return_counts=True, attn_fn=FLASH,
+                          train_router=not collapsed)
     params = seeded(jax.eval_shape(spec.init, jax.random.key(0)), 11)
+    if collapsed:
+        for i in range(TINY["num_layers"]):
+            moe = params[f"layers_{i}"]["moe"]
+            moe["router"] = jnp.zeros_like(moe["router"])
     batches = [jnp.asarray(tokens(20 + i)) for i in range(3)]
+    rungs = row_budgets(96 * 3, 4, 16)
+    assert rungs == (144, 288)
     with jax.default_matmul_precision("highest"):
         want_losses, _, want_delta, _ = ref.train_steps(
-            params, batches, row_block=2, s=settings(4))
+            params, batches, row_block=2,
+            s=settings(first, train_router=not collapsed))
         _reset_default_autodist_for_testing()
         ad = AutoDist(strategy_builder=strategies.AllReduce(),
                       mesh_axes={"data": 1})
@@ -162,13 +184,24 @@ def test_three_session_steps_match_the_reference_adamw():
                        expert_vars=spec.expert_vars, has_aux=True)
         sess = ad.create_distributed_session(
             mesh=build_mesh({"data": 1}, devices=jax.devices()[:1]))
-        outs = [sess.run({"tokens": np.asarray(b)}) for b in batches]
+        _, before = routed_cases.budget_gauges()
+        outs = [sess.run({"tokens": np.asarray(b)}) for b in batches[:2]]
+        _, two_steps = routed_cases.budget_gauges()
+        outs.append(sess.run({"tokens": np.asarray(batches[2])}))
+        rows, three_steps = routed_cases.budget_gauges()
         delta = ref.flatten(ref.leaf_diff_norms(
             sess.export_state()[0], params))
     _reset_default_autodist_for_testing()
     for out, want in zip(outs, want_losses):
         assert abs(float(out["loss"]) - want) < RTOL
         assert np.asarray(out["aux"]["tokens_per_expert"]).shape == (2, 4)
+    # 2 layers x 2 slices a step, all on one rung
+    taken = rungs[collapsed]
+    assert {r: three_steps[r] - two_steps.get(r, 0) for r in rungs} \
+        == {r: 4 * (r == taken) for r in rungs}
+    assert {r: three_steps[r] - before.get(r, 0) for r in rungs} \
+        == {r: 12 * (r == taken) for r in rungs}
+    assert rows == 4 * taken
     for name, want in want_delta.items():
         assert abs(delta[name] - want) <= 1e-4 * max(want, 1e-6), name
     gauges = {(m.name, m.labels.get("kind") or m.labels.get("name")): m.value
@@ -183,8 +216,9 @@ def test_three_session_steps_match_the_reference_adamw():
     # q blocks of 32 against 1, 2 and 3 key blocks of 32
     assert pairs_computed(96, block_q=32, block_k=32) == 6 * 32 * 32
     assert pairs_computed(16384) == 528 * 512 * 512
-    assert gauges[("autodist_moe_rows_per_step", "computed")] \
-        == 2 * 2 * 96 * 3
+    assert gauges[("autodist_moe_rows_per_step", "computed")] == rows
+    assert gauges[("autodist_moe_rows_per_step", "expected")] \
+        == 2 * 2 * 96 * 3 / 4
     # the selection's words: [1, 96 / 32, 96] int32 a sequence and layer
     assert gauges[("autodist_remat_kept_bytes_per_step", SELECTION_NAME)] \
         == 2 * 2 * 3 * 96 * 4
@@ -334,6 +368,38 @@ def test_eight_shares_add_up_to_the_whole_layer():
         assert rel(y, want) < RTOL
     assert rel(sum(parts), whole) < RTOL
     assert int(jnp.concatenate(counts).sum()) == 2 * 24 * 4   # every pick
+
+
+# the ladder of row budgets (PR 34) under the softmax router; the sigmoid
+# router's cases and the ladder's own are in test_mla_moe_lm.py
+@pytest.mark.parametrize("load", sorted(routed_cases.LOADS))
+def test_every_rung_equals_the_top_rung_to_the_bit(load):
+    routed_cases.assert_rung_equals_the_top_rung("softmax", load)
+
+
+@pytest.mark.parametrize("load", [16, 40, 100])
+def test_compiled_rungs_match_the_reference(load):
+    """Jitted, on each rung, the layer and the gradient through it match
+    the plain reference."""
+    params = routed_cases.layer("softmax")
+    x = routed_cases.tokens_routing(load)
+    s = settings(routed_cases.HELD[0], top_k=routed_cases.TOP_K)
+    value, counts, grads = jax.jit(
+        lambda p, x: routed_cases.value_and_gradients(p, x, "softmax"))(
+        params, x)
+    assert int(counts.sum()) == load
+    # routed_cases scales the routed part; the softmax router has no scale
+    want, want_grads = jax.value_and_grad(
+        lambda p, x: jnp.sum((2.448 * ref.experts(x, p, s=s)) ** 2),
+        argnums=(0, 1))(params, x)
+    assert rel(value, want) < RTOL
+    for name, leaf in flat(want_grads[0]).items():
+        assert rel(flat(grads[0])[name], leaf) < RTOL, name
+    assert rel(grads[1], want_grads[1]) < RTOL
+
+
+def test_gradient_holds_one_switch_a_direction_and_fills_no_rows():
+    routed_cases.assert_gradient_switches_once_and_fills_no_rows("softmax")
 
 
 def test_dense_fallback_is_the_kernel():

@@ -37,10 +37,13 @@ from autodist_tpu.ops import flash_attention
 from autodist_tpu.parallel.moe import (
     ROUTING_RESIDUAL_NAMES,
     init_routed_moe_params,
+    row_budgets,
     routed_moe_ffn,
     routed_rows,
 )
 from benchmark.reference import deepseek_v3 as ref
+
+import _routed_cases as routed_cases
 
 RTOL = 2e-5
 TINY = dict(vocab_size=61, num_layers=3, first_dense=1, d_model=32,
@@ -353,12 +356,18 @@ def test_routed_layer_refuses_a_share_its_leaves_do_not_hold():
         routed_moe_ffn(params, x, top_k=3, experts_held=(14, 4))
 
 
-def test_three_session_steps_match_the_reference_adamw():
+@pytest.mark.parametrize("router", ["even", "skewed"])
+def test_three_session_steps_match_the_reference_adamw(router):
     """Through ``AutoDist.capture(has_aux=True) -> create_distributed_
     session -> run``: three steps' losses and the parameters after them
     against the reference's gradients under AdamW written out; the
     per-expert token counts come back with every step; the layer's gauges
-    are set when it is traced."""
+    are set when it is traced, but the ``computed`` rows: those are the
+    budgets the LAST STEP's calls took, carried out of the step when it
+    runs.  An even router leaves every call (2 expert layers x 4
+    sequences of 32 tokens x 3 picks, a quarter of them expected here) on
+    the low rung of 48 rows; a selection bias that sends
+    every pick to the held experts puts every call on the top rung."""
     from autodist_tpu import strategy as strategies
     from autodist_tpu.autodist import (AutoDist,
                                        _reset_default_autodist_for_testing)
@@ -367,7 +376,13 @@ def test_three_session_steps_match_the_reference_adamw():
 
     spec = mla_moe_lm(**TINY, experts_held=(4, 4), return_counts=True)
     params = seeded(jax.eval_shape(spec.init, jax.random.key(0)), 11)
+    if router == "skewed":
+        for i in range(TINY["first_dense"], TINY["num_layers"]):
+            params[f"layers_{i}"]["moe"]["router_bias"] = jnp.full(
+                (16,), -10.0).at[4:8].set(10.0)
     batches = [jnp.asarray(tokens(20 + i)) for i in range(3)]
+    rungs = row_budgets(32 * 3, 4, 16)
+    assert rungs == (48, 96)
     with jax.default_matmul_precision("highest"):
         want_losses, _, want_delta, _ = ref.train_steps(
             params, batches, row_block=4, s=settings(4))
@@ -381,7 +396,11 @@ def test_three_session_steps_match_the_reference_adamw():
                        expert_vars=spec.expert_vars, has_aux=True)
         sess = ad.create_distributed_session(
             mesh=build_mesh({"data": 1}, devices=jax.devices()[:1]))
-        outs = [sess.run({"tokens": np.asarray(b)}) for b in batches]
+        _, before = routed_cases.budget_gauges()
+        outs = [sess.run({"tokens": np.asarray(b)}) for b in batches[:2]]
+        _, two_steps = routed_cases.budget_gauges()
+        outs.append(sess.run({"tokens": np.asarray(batches[2])}))
+        rows, three_steps = routed_cases.budget_gauges()
         delta = ref.flatten(ref.leaf_diff_norms(
             sess.export_state()[0], params))
     _reset_default_autodist_for_testing()
@@ -400,9 +419,69 @@ def test_three_session_steps_match_the_reference_adamw():
     assert gauges[("autodist_moe_experts_held", None)] == 4
     assert gauges[("autodist_moe_experts_total", None)] == 16
     computed, expected = routed_rows(4 * 32, 3, 4, 16)
-    assert gauges[("autodist_moe_rows_per_step", "computed")] == 2 * computed
     assert gauges[("autodist_moe_rows_per_step", "expected")] == 2 * expected
-    assert computed / expected == 4.0
+    # 2 expert layers x 4 sequences a step, all on one rung
+    taken = rungs[router == "skewed"]
+    last = {r: three_steps[r] - two_steps.get(r, 0) for r in rungs}
+    assert last == {r: 8 * (r == taken) for r in rungs}
+    assert {r: three_steps[r] - before.get(r, 0) for r in rungs} \
+        == {r: 24 * (r == taken) for r in rungs}
+    assert rows == 8 * taken
+    assert rows / (2 * expected) == (4.0 if router == "skewed" else 2.0)
+    assert computed == rungs[-1] * 4        # the top rung is every pick
+
+
+# ---------------------------------------------------------------------------
+# the ladder of row budgets (PR 34); the softmax router's cases are in
+# test_gqa_dsa_moe_lm.py
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("load", sorted(routed_cases.LOADS))
+def test_every_rung_equals_the_top_rung_to_the_bit(load):
+    """Value and every gradient on the rung a load takes against the top
+    rung's (the path before the ladder): none routed here, a load of
+    exactly a rung and of one row more, every pick routed here."""
+    routed_cases.assert_rung_equals_the_top_rung("sigmoid", load)
+
+
+@pytest.mark.parametrize("load", [16, 40, 100])
+def test_compiled_rungs_match_the_reference(load):
+    """Jitted, on each rung, the layer and the gradient through it match
+    the plain reference."""
+    params = routed_cases.layer("sigmoid")
+    x = routed_cases.tokens_routing(load)
+    s = ref.Settings(top_k=routed_cases.TOP_K, routed_scale=2.448,
+                     first_held=routed_cases.HELD[0], qk_nope=8, theta=1e6,
+                     eps=1e-6, train_router=True)
+    value, counts, grads = jax.jit(
+        lambda p, x: routed_cases.value_and_gradients(p, x, "sigmoid"))(
+        params, x)
+    assert int(counts.sum()) == load
+    want, want_grads = jax.value_and_grad(
+        lambda p, x: jnp.sum(ref.moe_ffn(x, p, s=s) ** 2), argnums=(0, 1))(
+        params, x)
+    assert rel(value, want) < RTOL
+    got, want = ref._flat(grads[0], np.asarray), ref._flat(want_grads[0],
+                                                           np.asarray)
+    for name in want:
+        if not name.endswith("router_bias"):
+            assert rel(got[name], want[name]) < RTOL, name
+    assert rel(grads[1], want_grads[1]) < RTOL
+
+
+def test_gradient_holds_one_switch_a_direction_and_fills_no_rows():
+    routed_cases.assert_gradient_switches_once_and_fills_no_rows("sigmoid")
+
+
+@pytest.mark.parametrize("rows,held,total,want", [
+    (24576, 16, 128, (6144, 12288, 24576)),     # the kanana cell's call
+    (32768, 16, 128, (8192, 16384, 32768)),     # the keye cell's
+    (96, 4, 16, (48, 96)),                      # four times expected is all
+    (96, 16, 16, (96,)), (96, 8, 16, (96,)),    # all held, half held
+    (144, 2, 16, (40, 72, 144)),                # whole 8-row tiles
+    (4096 * 6, 2, 256, (512, 1024, 24576)),     # never more than three
+])
+def test_row_budgets_follow_the_shapes_alone(rows, held, total, want):
+    assert row_budgets(rows, held, total) == want
 
 
 # ---------------------------------------------------------------------------
@@ -488,8 +567,10 @@ def test_kept_bytes_gauge_reads_what_the_tagged_shapes_give(remat, attn,
     spec = remat_model(remat, monkeypatch, attn_fn=attn)
     jax.eval_shape(spec.loss_fn, jax.eval_shape(spec.init, jax.random.key(0)),
                    {"tokens": tokens(0, rows=rows)})
+    # (the registry is the process's: another model's names may be there)
     got = {m.labels["name"]: m.value for m in DEFAULT_REGISTRY.metrics()
-           if m.name == "autodist_remat_kept_bytes_per_step"}
+           if m.name == "autodist_remat_kept_bytes_per_step"
+           and m.labels["name"] in KEPT_NAMES}
     layers = rows * TINY["num_layers"] * (attn is FLASH)
     picks = rows * ROUTED * t * k * 4
     want = dict(zip(KEPT_NAMES, (

@@ -947,3 +947,84 @@ def test_engine_tick_and_sse_poll_spans(span_ring):
     life = [s["name"] for s in spans if s["ids"].get("request_id") == 1
             and s["name"] in ("queue_wait", "prefill", "decode")]
     assert sorted(life) == ["decode", "prefill", "queue_wait"]
+
+
+# -- step values (telemetry/step_values.py) ------------------------------------
+def _linear_session(loss_fn, **capture):
+    from autodist_tpu import strategy as strategies
+    from autodist_tpu.autodist import (AutoDist,
+                                       _reset_default_autodist_for_testing)
+    from autodist_tpu.mesh import build_mesh
+
+    _reset_default_autodist_for_testing()
+    ad = AutoDist(strategy_builder=strategies.AllReduce(),
+                  mesh_axes={"data": 1})
+    with ad.scope():
+        ad.capture(params={"w": jnp.ones((4,))}, optimizer=optax.sgd(0.1),
+                   loss_fn=loss_fn, **capture)
+    return ad.create_distributed_session(
+        mesh=build_mesh({"data": 1}, devices=jax.devices()[:1]))
+
+
+@pytest.mark.parametrize("has_aux,accum", [(False, 1), (True, 1), (False, 2)])
+def test_step_values_leave_with_the_metrics_and_reach_their_publisher(
+        has_aux, accum):
+    """A marked loss function's emitted value comes to its publisher as
+    host numpy after every fetched step (stacked over the microbatches
+    under gradient accumulation), the caller's metrics do not show it,
+    the user's own aux stays what it was, and the step program holds no
+    host callback (it would keep the program out of the persistent
+    compilation cache)."""
+    from autodist_tpu.autodist import _reset_default_autodist_for_testing
+    from autodist_tpu.telemetry import step_values
+
+    seen = []
+
+    @step_values.reporting
+    def loss_fn(params, batch):
+        rows = jnp.sum(batch["x"], axis=-1)
+        step_values.emit("rows", {"sum": rows.sum(), "n": rows.shape[0]},
+                         seen.append)
+        loss = jnp.mean((batch["x"] @ params["w"]) ** 2)
+        return (loss, {"rows": rows}) if has_aux else loss
+
+    sess = _linear_session(loss_fn, has_aux=has_aux, accum_steps=accum)
+    batches = [{"x": np.full((4, 4), float(i + 1), np.float32)}
+               for i in range(2)]
+    outs = [sess.run(b) for b in batches]
+    assert "callback" not in sess.lower_step(batches[0]).as_text().lower()
+    assert step_values.KEY not in sess.evaluate(batches[0])
+    assert not sess.run(batches[0], sync=False).keys() - {"loss", "aux"}
+    _reset_default_autodist_for_testing()
+    assert [set(o) for o in outs] == [{"loss", "aux"} if has_aux
+                                      else {"loss"}] * 2
+    assert len(seen) == 2                  # evaluate and sync=False: none
+    for i, got in enumerate(seen):
+        assert isinstance(got["sum"], np.ndarray)
+        assert got["sum"].shape == got["n"].shape == (() if accum == 1
+                                                      else (accum,))
+        assert float(got["sum"].sum()) == 16.0 * (i + 1)
+        assert int(got["n"].sum()) == 4
+    if has_aux:
+        np.testing.assert_array_equal(outs[1]["aux"]["rows"], [8.0] * 4)
+
+
+def test_emit_outside_a_collecting_trace_does_nothing():
+    """An unmarked loss function, or a marked one called or differentiated
+    directly, traces and runs as if ``emit`` were not there."""
+    from autodist_tpu.autodist import _reset_default_autodist_for_testing
+    from autodist_tpu.telemetry import step_values
+
+    seen = []
+
+    def loss_fn(params, batch):
+        step_values.emit("x", batch["x"].sum(), seen.append)
+        return jnp.mean((batch["x"] @ params["w"]) ** 2)
+
+    batch = {"x": np.ones((4, 4), np.float32)}
+    sess = _linear_session(loss_fn)
+    assert set(sess.run(batch)) == {"loss"}
+    _reset_default_autodist_for_testing()
+    marked = step_values.reporting(loss_fn)
+    jax.jit(jax.grad(marked))({"w": jnp.ones((4,))}, batch)
+    assert seen == []
