@@ -35,6 +35,7 @@ Functional, like ``mla_moe_lm.py``; the training path only.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -66,7 +67,6 @@ from autodist_tpu.telemetry import registry, step_values, timeline
 #: the backward neither scores nor selects again
 SELECTION_NAME = "dsa/selection"
 KEPT_NAMES = RESIDUAL_NAMES + (SELECTION_NAME,) + ROUTING_RESIDUAL_NAMES
-_KEEP_NAMED = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
 
 
 def layer_norm(x, p, eps):
@@ -121,13 +121,16 @@ def select_keys(qi, ki, w, *, topk: int, rows: int, block_k: int):
 
 
 def dense_selected_attention(q, k, v, causal, *, selection=None,
-                             select_from=None, block_k=_DEFAULT_BLOCK):
+                             select_from=None, window=None,
+                             block_k=_DEFAULT_BLOCK):
     """What the kernel computes, by the plain softmax over all pairs (off
-    the TPU, at a size a test holds): ``selection`` as the kernel takes
-    it."""
+    the TPU, at a size a test holds): ``selection`` and ``window`` as the
+    kernel takes them."""
     t, group = q.shape[1], q.shape[2] // k.shape[2]
     mask = jnp.tril(jnp.ones((t, t), bool))[None] if selection is None \
         else unpack_selection(selection, block_k=block_k)
+    if window is not None:
+        mask = mask & ~jnp.tril(jnp.ones((t, t), bool), -window)
     k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (q.shape[-1] ** 0.5)
     logits = jnp.where(mask[:, None], logits.astype(jnp.float32), -1e30)
@@ -145,6 +148,142 @@ def default_sparse_attention(block_k: int = _DEFAULT_BLOCK) -> Callable:
         return flash_attention(q, k, v, causal, block_k=block_k, **selection)
 
     return attn
+
+
+def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
+                   kept_names: Tuple[str, ...], set_pairs_gauges: Callable,
+                   vocab_size: int, num_layers: int, seq_len: int,
+                   moe_slice: int, top_k: int, num_experts: int,
+                   rms_eps: float, xent_chunk: Optional[int], remat: str,
+                   return_counts: bool, config: dict,
+                   router_reads_input: bool = False,
+                   embed_scale: float = 1.0) -> ModelSpec:
+    """What the decoders of this file and of ``swa_moe_lm.py`` share: the
+    embedding, ``num_layers`` layers of an attention half (one sequence at
+    a time) and an expert half (``moe_slice`` tokens at a time), each
+    under its own checkpoint that keeps ``kept_names``, the final norm,
+    the (chunked) loss, the gauges of what the checkpoints keep and of the
+    row budgets, and the batch.  ``halves_of(i)``: layer ``i``'s
+    ``(attention_half(lp, x [1, T, D]) -> [1, T, D], expert_half(lp, part
+    [N, D]) -> (part, tokens_per_expert))``, the same functions for layers
+    of one kind.  ``router_reads_input``: the expert half is also handed
+    the layer's INPUT, slice by slice (``expert_half(lp, part, input_part)``:
+    a router placed before attention).  ``embed_scale``: the stream
+    enters layer 0 as this times the table's rows.  ``set_pairs_gauges(
+    tokens)``: the model's own gauges, set while tracing."""
+    keep = jax.checkpoint_policies.save_only_these_names(*kept_names)
+
+    @functools.cache
+    def as_run(halves):
+        """A kind's halves under their checkpoints (both run under
+        ``lax.map``: no CSE barrier needed)."""
+        return halves if remat == "none" else tuple(
+            jax.checkpoint(f, policy=keep, prevent_cse=False)
+            for f in halves)
+
+    def slices(x):
+        """``[B, T, D]`` as ``[n, moe_slice, D]``."""
+        tokens = x.shape[0] * x.shape[1]
+        size = moe_slice if tokens % moe_slice == 0 else tokens
+        return x.reshape(tokens // size, size, x.shape[-1])
+
+    def kept_bytes(params, x):
+        """What the layers' checkpoints hold by name over a step of ``x
+        [B, T, D]``: the tagged shapes of one sequence's attention half
+        and of one slice's expert half, times how many of each, over the
+        layers (one trace a kind of layer)."""
+        total = dict.fromkeys(kept_names, 0)
+        if remat == "none":
+            return total
+        parts, found = slices(x), {}
+        for i in range(num_layers):
+            halves = halves_of(i)
+            if halves not in found:
+                lp = params[f"layers_{i}"]
+                found[halves] = [
+                    (named_bytes(halves[0], lp, x[:1]), x.shape[0]),
+                    (named_bytes(halves[1], lp, *[parts[0]] * (
+                        1 + router_reads_input)), parts.shape[0])]
+            for name in kept_names:
+                total[name] += sum(tagged.get(name, 0) * times
+                                   for tagged, times in found[halves])
+        return total
+
+    def layer(lp, x, halves):
+        """``x [B, T, D]`` through one layer: attention one sequence at a
+        time, the experts one slice at a time (maps, not vmaps: the
+        expert layer's ``switch`` stays a branch).  Returns the slices'
+        ``tokens_per_expert`` ``[slices, count]`` beside ``x``."""
+        attention_half, expert_half = halves
+        entered = x
+        x = jax.lax.map(lambda row: attention_half(lp, row[None])[0], x)
+        if router_reads_input:
+            y, counts = jax.lax.map(lambda part: expert_half(lp, *part),
+                                    (slices(x), slices(entered)))
+        else:
+            y, counts = jax.lax.map(lambda part: expert_half(lp, part),
+                                    slices(x))
+        return y.reshape(x.shape), counts
+
+    def set_gauges(params, tokens, x):
+        set_pairs_gauges(tokens)
+        for kept, held_bytes in kept_bytes(params, x).items():
+            registry.gauge(
+                "autodist_remat_kept_bytes_per_step",
+                "bytes the layers' checkpoints keep from forward to "
+                "backward instead of recomputing, by the value's name",
+                {"name": kept}).set(held_bytes)
+
+    def features(params, tokens):
+        """Final-norm activations ``[B, T, D]`` and the layers'
+        ``tokens_per_expert`` ``[layers, count]``."""
+        x = jnp.take(params["embed"], tokens, axis=0)
+        if embed_scale != 1.0:
+            x = x * embed_scale
+        set_gauges(params, tokens, x)
+        counts = []
+        for i in range(num_layers):
+            x, c = layer(params[f"layers_{i}"], x, as_run(halves_of(i)))
+            counts.append(c)
+        # here, outside the layers' maps and checkpoints
+        record_row_budgets(jnp.stack(counts), slices(x).shape[1] * top_k,
+                           num_experts)
+        return (rms_norm(x, params["ln_final"]["scale"], rms_eps),
+                [c.sum(axis=0) for c in counts])
+
+    def apply_fn(params, tokens):
+        return jnp.einsum("btd,vd->btv", features(params, tokens)[0],
+                          params["head"])
+
+    def loss_fn(params, batch):
+        tokens = batch["tokens"]
+        feats, counts = features(params, tokens)
+        if xent_chunk:
+            from autodist_tpu.ops.chunked_xent import \
+                chunked_softmax_cross_entropy
+
+            loss = chunked_softmax_cross_entropy(
+                feats[:, :-1], params["head"], tokens[:, 1:],
+                chunk=xent_chunk)
+        else:
+            logits = jnp.einsum("btd,vd->btv", feats, params["head"])
+            loss = cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
+        if return_counts:
+            return loss, {"tokens_per_expert": jnp.stack(counts)}
+        return loss
+
+    def make_batch(rng: np.random.RandomState, batch_size: int):
+        return {"tokens": rng.randint(
+            0, vocab_size, (batch_size, seq_len)).astype(np.int32)}
+
+    return ModelSpec(
+        name=name,
+        init=init, loss_fn=step_values.reporting(loss_fn), apply_fn=apply_fn,
+        make_batch=make_batch,
+        sparse_vars=("embed",),
+        expert_vars=("*/moe/experts/*",),
+        config=config,
+    )
 
 
 def gqa_dsa_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
@@ -267,42 +406,7 @@ def gqa_dsa_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
             scoring="softmax")
         return x + y, counts
 
-    halves = attention_half, expert_half
-    if remat != "none":   # both run under lax.map: no CSE barrier needed
-        attention_half, expert_half = (
-            jax.checkpoint(f, policy=_KEEP_NAMED, prevent_cse=False)
-            for f in halves)
-
-    def slices(x):
-        """``[B, T, D]`` as ``[n, moe_slice, D]``."""
-        tokens = x.shape[0] * x.shape[1]
-        size = moe_slice if tokens % moe_slice == 0 else tokens
-        return x.reshape(tokens // size, size, x.shape[-1])
-
-    def kept_bytes(params, x):
-        """What the layers' checkpoints hold by name over a step of ``x
-        [B, T, D]``: the tagged shapes of one sequence's attention half
-        and of one slice's expert half, times how many of each."""
-        if remat == "none":
-            return dict.fromkeys(KEPT_NAMES, 0)
-        lp, parts = params["layers_0"], slices(x)
-        per = [(named_bytes(halves[0], lp, x[:1]), x.shape[0]),
-               (named_bytes(halves[1], lp, parts[0]), parts.shape[0])]
-        return {name: num_layers * sum(found.get(name, 0) * times
-                                       for found, times in per)
-                for name in KEPT_NAMES}
-
-    def layer(lp, x):
-        """``x [B, T, D]`` through one layer: attention one sequence at a
-        time, the experts one slice at a time (maps, not vmaps: the
-        expert layer's ``switch`` stays a branch).  Returns the slices'
-        ``tokens_per_expert`` ``[slices, count]`` beside ``x``."""
-        x = jax.lax.map(lambda row: attention_half(lp, row[None])[0], x)
-        y, counts = jax.lax.map(lambda part: expert_half(lp, part),
-                                slices(x))
-        return y.reshape(x.shape), counts
-
-    def set_gauges(params, tokens, x):
+    def set_pairs_gauges(tokens):
         batch, t = tokens.shape
         whole = min(t, topk)       # rows that attend to all before them
         selected = whole * (whole + 1) // 2 + (t - whole) * topk
@@ -313,59 +417,15 @@ def gqa_dsa_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
                 "pairs of query and key a step's attention is asked for "
                 "(a head, forward), and pairs whose score its kernel "
                 "forms", {"kind": kind}).set(pairs * batch * num_layers)
-        for name, held_bytes in kept_bytes(params, x).items():
-            registry.gauge(
-                "autodist_remat_kept_bytes_per_step",
-                "bytes the layers' checkpoints keep from forward to "
-                "backward instead of recomputing, by the value's name",
-                {"name": name}).set(held_bytes)
 
-    def features(params, tokens):
-        """Final-norm activations ``[B, T, D]`` and the layers'
-        ``tokens_per_expert`` ``[layers, count]``."""
-        x = jnp.take(params["embed"], tokens, axis=0)
-        set_gauges(params, tokens, x)
-        counts = []
-        for i in range(num_layers):
-            x, c = layer(params[f"layers_{i}"], x)
-            counts.append(c)
-        # here, outside the layers' maps and checkpoints
-        record_row_budgets(jnp.stack(counts), slices(x).shape[1] * top_k,
-                           num_experts)
-        return (rms_norm(x, params["ln_final"]["scale"], rms_eps),
-                [c.sum(axis=0) for c in counts])
-
-    def apply_fn(params, tokens):
-        return jnp.einsum("btd,vd->btv", features(params, tokens)[0],
-                          params["head"])
-
-    def loss_fn(params, batch):
-        tokens = batch["tokens"]
-        feats, counts = features(params, tokens)
-        if xent_chunk:
-            from autodist_tpu.ops.chunked_xent import \
-                chunked_softmax_cross_entropy
-
-            loss = chunked_softmax_cross_entropy(
-                feats[:, :-1], params["head"], tokens[:, 1:],
-                chunk=xent_chunk)
-        else:
-            logits = jnp.einsum("btd,vd->btv", feats, params["head"])
-            loss = cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
-        if return_counts:
-            return loss, {"tokens_per_expert": jnp.stack(counts)}
-        return loss
-
-    def make_batch(rng: np.random.RandomState, batch_size: int):
-        return {"tokens": rng.randint(
-            0, vocab_size, (batch_size, seq_len)).astype(np.int32)}
-
-    return ModelSpec(
-        name="gqa_dsa_moe_lm",
-        init=init, loss_fn=step_values.reporting(loss_fn), apply_fn=apply_fn,
-        make_batch=make_batch,
-        sparse_vars=("embed",),
-        expert_vars=("*/moe/experts/*",),
+    return routed_decoder(
+        name="gqa_dsa_moe_lm", init=init,
+        halves_of=lambda i: (attention_half, expert_half),
+        kept_names=KEPT_NAMES, set_pairs_gauges=set_pairs_gauges,
+        vocab_size=vocab_size, num_layers=num_layers, seq_len=seq_len,
+        moe_slice=moe_slice, top_k=top_k, num_experts=num_experts,
+        rms_eps=rms_eps, xent_chunk=xent_chunk, remat=remat,
+        return_counts=return_counts,
         config=dict(vocab_size=vocab_size, num_layers=num_layers,
                     d_model=d_model, num_heads=num_heads,
                     num_kv_heads=num_kv_heads, head_dim=head_dim,

@@ -57,6 +57,14 @@ is ``PERF.md`` section 5):
   queries along (``pack_selection``), and masks every tile of the q
   blocks that reach past row ``select_from``: every causal tile is still
   computed.  Callers without either trace the kernels they always did.
+* **A window** (PR 36).  With a static ``window`` a query attends to its
+  own position and the ``window - 1`` before it.  The forward's loop over
+  key blocks starts at the block the window's trailing edge crosses and
+  the fused backward's loop over query blocks ends there: tiles wholly
+  behind the window are never visited, and only the tile that edge
+  crosses and the diagonal tile pay for a mask.  At 16,384 tokens, tiles
+  of 512 and a window of 4,096 that is 252 tiles a head of the causal
+  triangle's 528.  ``window=None`` traces the kernels it always did.
 
 Interpret mode (CPU tests) is selected automatically off-TPU.
 """
@@ -210,12 +218,16 @@ def unpack_selection(words: jax.Array, *,
 
 
 def pairs_computed(t: int, *, block_q: int = _DEFAULT_BLOCK,
-                   block_k: int = _DEFAULT_BLOCK) -> int:
+                   block_k: int = _DEFAULT_BLOCK,
+                   window: Optional[int] = None) -> int:
     """(query, key) pairs whose score ONE causal forward call forms for
-    one head: every tile at or below the diagonal, whole."""
+    one head: every tile at or below the diagonal, whole; under a
+    ``window``, from the tile its trailing edge crosses on (the kernel's
+    own first key block)."""
     bq, bk = _pick_block(t, block_q), _pick_block(t, block_k)
-    return sum(min(-(-(q0 + bq) // bk), t // bk) * bk * bq
-               for q0 in range(0, t, bq))
+    return sum((min(-(-(q0 + bq) // bk), t // bk)
+                - (0 if window is None else max(q0 - window + 1, 0) // bk))
+               * bk * bq for q0 in range(0, t, bq))
 
 
 def _unpack(words):
@@ -226,7 +238,8 @@ def _unpack(words):
 
 
 def _fwd_kernel(*refs, causal: bool, block_k: int, scale: float,
-                kv_len: int, operand, select_from: Optional[int] = None):
+                kv_len: int, operand, select_from: Optional[int] = None,
+                window: Optional[int] = None):
     """One (batch, head, q-block) program: stream K/V blocks, online softmax.
 
     Tiles are TRANSPOSED, ``[bk, bq]`` (keys down the sublanes, queries
@@ -247,6 +260,11 @@ def _fwd_kernel(*refs, causal: bool, block_k: int, scale: float,
     at or before row ``select_from`` attends to every earlier key and
     takes the tiles above; any other masks EVERY tile up to the diagonal
     by its words (a selection lies inside the causal triangle).
+
+    With ``window`` (causal) query ``t`` attends to keys ``t - window <
+    s <= t``: K blocks wholly behind the window of the block's first
+    query are never visited, and those its trailing edge crosses are
+    masked by it.
     """
     if select_from is None:
         q_ref, k_ref, v_ref, o_ref, lse_ref, ks_ref, vt_ref = refs
@@ -273,6 +291,8 @@ def _fwd_kernel(*refs, causal: bool, block_k: int, scale: float,
     # k_pos <= q_pos  <=>  row - col <= q0 - k0
     row = lax.broadcasted_iota(jnp.int32, (block_k, bq), 0)
     row_minus_col = row - lax.broadcasted_iota(jnp.int32, (block_k, bq), 1)
+    # a window shorter than two tiles can cross a diagonal tile as well
+    near = window is not None and window < bq + block_k
 
     def tile(masked, kb, carry):
         o_t, l, m = carry
@@ -281,8 +301,12 @@ def _fwd_kernel(*refs, causal: bool, block_k: int, scale: float,
         if masked == "selected":
             s_t = jnp.where(_unpack(sel_ref[0, _block(
                 kb, block_k // _WORD, num_kb), :]), s_t, _NEG_INF)
+        elif masked == "edge":
+            s_t = jnp.where(row_minus_col > q0 - k0 - window, s_t, _NEG_INF)
         elif masked:
             mask = row_minus_col <= q0 - k0 if causal else None
+            if near:
+                mask &= row_minus_col > q0 - k0 - window
             if padded:
                 live = row < kv_len - k0
                 mask = live if mask is None else mask & live
@@ -311,6 +335,15 @@ def _fwd_kernel(*refs, causal: bool, block_k: int, scale: float,
         init = lax.fori_loop(0, first, functools.partial(tile, "selected"),
                              init)
         clear = jnp.maximum(clear, first)
+    if window is not None:
+        # K blocks before ``first`` lie wholly behind the window; blocks
+        # [first, inside) are crossed by its trailing edge
+        first = lax.div(jnp.maximum(q0 - window + 1, 0), block_k)
+        inside = jnp.minimum(lax.div(
+            jnp.maximum(q0 + bq - window, 0) + block_k - 1, block_k), clear)
+        init = lax.fori_loop(first, inside, functools.partial(tile, "edge"),
+                             init)
+        first = inside
     carry = lax.fori_loop(first, clear, functools.partial(tile, False), init)
     if causal or padded:
         carry = lax.fori_loop(clear, upper, functools.partial(tile, True),
@@ -328,7 +361,7 @@ def _kv_head(group: int):
 
 
 def _fwd(q, k, v, causal, block_q, block_k, interpret, kv_len,
-         operand=jnp.float32, sel=None, select_from=None):
+         operand=jnp.float32, sel=None, select_from=None, window=None):
     """q: [B, H, T, Dk], k: [B, Hkv, T, Dk], v: [B, Hkv, T, Dv] → (o
     [B,H,T,Dv], lse [B,H,T,1]); the scale is 1/√Dk.  ``H // Hkv`` query
     heads in a row read one key/value head: the index map repeats, and
@@ -342,7 +375,7 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret, kv_len,
     scale = 1.0 / (dk ** 0.5)
     kernel = functools.partial(_fwd_kernel, causal=causal, block_k=bk,
                                scale=scale, kv_len=kv_len, operand=operand,
-                               select_from=select_from)
+                               select_from=select_from, window=window)
     selection = [] if sel is None else [pl.BlockSpec(
         (1, t // _WORD, bq), lambda bi, hi, qi: (bi, 0, qi))]
 
@@ -382,7 +415,8 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret, kv_len,
 # backward
 # ---------------------------------------------------------------------------
 def _bwd_kernel(*refs, causal: bool, block_q: int, scale: float,
-                kv_len: int, operand, select_from: Optional[int] = None):
+                kv_len: int, operand, select_from: Optional[int] = None,
+                window: Optional[int] = None):
     """dQ, dK and dV in one call: each score tile is formed ONCE.
 
     One (batch, head, k-block) program; the k-block axis is sequential.
@@ -402,6 +436,10 @@ def _bwd_kernel(*refs, causal: bool, block_q: int, scale: float,
     With ``select_from`` a further input follows Δ: sel [1,bk/32,T], this
     key block's words for every query (:func:`_fwd_kernel` says which q
     blocks read them).
+
+    With ``window`` the q blocks of a key block end where the window of
+    its last key does, ``(k0 + bk + window - 2) // block_q``; those the
+    window's trailing edge crosses are masked by it.
     """
     if select_from is None:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -435,6 +473,9 @@ def _bwd_kernel(*refs, causal: bool, block_q: int, scale: float,
     row = lax.broadcasted_iota(jnp.int32, (bk, block_q), 0)
     row_minus_col = row - lax.broadcasted_iota(jnp.int32, (bk, block_q), 1)
     live = row < kv_len - k0 if padded else None
+    # a window shorter than two tiles can cross a diagonal tile as well,
+    # and every q block of a key block that holds padding takes that mask
+    near = window is not None and (window < bk + block_q or padded)
 
     def tile(masked, qb, carry):
         dk_t, dv_t = carry
@@ -444,8 +485,13 @@ def _bwd_kernel(*refs, causal: bool, block_q: int, scale: float,
         s_t = _dot(k, qs_t, _NN, operand)                   # [bk, bq]
         if masked == "selected":
             s_t = jnp.where(_unpack(sel_ref[0, :, cols]), s_t, _NEG_INF)
+        elif masked == "edge":
+            s_t = jnp.where(row_minus_col > qb * block_q - k0 - window, s_t,
+                            _NEG_INF)
         elif masked:
             mask = row_minus_col <= qb * block_q - k0 if causal else None
+            if near:
+                mask &= row_minus_col > qb * block_q - k0 - window
             if padded:
                 mask = live if mask is None else mask & live
             s_t = jnp.where(mask, s_t, _NEG_INF)
@@ -475,6 +521,17 @@ def _bwd_kernel(*refs, causal: bool, block_q: int, scale: float,
         masked_to = jnp.minimum(clear, last)
         carry = lax.fori_loop(jnp.maximum(lower, last), num_qb,
                               functools.partial(tile, "selected"), carry)
+    if window is not None:
+        # q blocks from ``last`` on lie wholly past the window of this
+        # block's last key; blocks [inside, last) are crossed by its edge
+        last = jnp.minimum(lax.div(k0 + bk + window - 2, block_q) + 1,
+                           num_qb)
+        masked_to = jnp.minimum(clear, last)
+        inside = jnp.minimum(jnp.maximum(lax.div(k0 + window, block_q),
+                                         clear), last)
+        carry = lax.fori_loop(inside, last, functools.partial(tile, "edge"),
+                              carry)
+        last = inside
     if causal or padded:
         carry = lax.fori_loop(lower, masked_to,
                               functools.partial(tile, True), carry)
@@ -495,7 +552,8 @@ def _bwd_kernel(*refs, causal: bool, block_q: int, scale: float,
 
 
 def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret, kv_len,
-         dlse=None, operand=jnp.float32, sel=None, select_from=None):
+         dlse=None, operand=jnp.float32, sel=None, select_from=None,
+         window=None):
     """(dq, dk, dv).  Where ``group = H // Hkv`` query heads share a
     key/value head, the kernel writes each query head's dK and dV and
     the group's are summed here: 2 x [B,H,T,D] crosses memory once more,
@@ -533,7 +591,7 @@ def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret, kv_len,
     dq, dk_heads, dv_heads = pl.pallas_call(
         functools.partial(_bwd_kernel, causal=causal, block_q=bq,
                           scale=scale, kv_len=kv_len, operand=operand,
-                          select_from=select_from),
+                          select_from=select_from, window=window),
         grid=(b, h, t // bk),
         in_specs=[full_spec(dk), kb_spec(dk, group), kb_spec(dv, group),
                   full_spec(dv), row_spec, row_spec] + selection,
@@ -618,6 +676,34 @@ def _flash_selected_bwd(block_q, block_k, interpret, operand, select_from,
 _flash_selected.defvjp(_flash_selected_fwd, _flash_selected_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_windowed(q, k, v, block_q, block_k, interpret, kv_len, operand,
+                    window):
+    """:func:`_flash`, causal, where query ``t`` attends to keys ``t -
+    window < s <= t``."""
+    return _fwd(q, k, v, True, block_q, block_k, interpret, kv_len, operand,
+                window=window)
+
+
+def _flash_windowed_fwd(q, k, v, block_q, block_k, interpret, kv_len,
+                        operand, window):
+    o, lse = map(checkpoint_name, _fwd(
+        q, k, v, True, block_q, block_k, interpret, kv_len, operand,
+        window=window), RESIDUAL_NAMES)
+    return (o, lse), (q, k, v, o, lse)
+
+
+def _flash_windowed_bwd(block_q, block_k, interpret, kv_len, operand, window,
+                        res, cts):
+    q, k, v, o, lse = res
+    do, dlse = cts
+    return _bwd(q, k, v, o, lse, do, True, block_q, block_k, interpret,
+                kv_len, dlse=dlse, operand=operand, window=window)
+
+
+_flash_windowed.defvjp(_flash_windowed_fwd, _flash_windowed_bwd)
+
+
 @functools.cache
 def _log_operand(name: str, why: str) -> None:
     logging.info("flash attention: product operands %s (%s)", name, why)
@@ -642,10 +728,16 @@ def _product_operand(interpret: bool):
 
 
 def _pad_and_run(q, k, v, causal, block_q, block_k, interpret,
-                 selection=None, select_from=None):
+                 selection=None, select_from=None, window=None):
     """[B,T,H,D] public layout (q, k ``Dk`` wide, v ``Dv``) → padded
     [B,H,T,D] kernel run → sliced (o [B,T,H,Dv], lse [B,H,T])."""
     t = q.shape[1]
+    if window is not None and (not causal or selection is not None
+                               or int(window) < 1):
+        raise ValueError("a window is causal, holds at least the query's "
+                         "own position and comes without a selection; got "
+                         f"causal={causal}, window={window}, selection "
+                         f"{'given' if selection is not None else 'None'}")
     tp = _pad_len(t, interpret)
     if q.shape[2] % k.shape[2] or k.shape[2] != v.shape[2]:
         raise ValueError(f"{q.shape[2]} query heads over {k.shape[2]} key "
@@ -664,8 +756,12 @@ def _pad_and_run(q, k, v, causal, block_q, block_k, interpret,
     if tp != t:
         pad = [(0, 0), (0, 0), (0, tp - t), (0, 0)]
         qt, kt, vt = (jnp.pad(x, pad) for x in (qt, kt, vt))
-    o, lse = _flash(qt, kt, vt, causal, block_q, block_k, interpret, t,
-                    _product_operand(interpret))
+    if window is None:
+        o, lse = _flash(qt, kt, vt, causal, block_q, block_k, interpret, t,
+                        _product_operand(interpret))
+    else:
+        o, lse = _flash_windowed(qt, kt, vt, block_q, block_k, interpret, t,
+                                 _product_operand(interpret), int(window))
     if tp != t:
         o = o[:, :, :t, :]
         lse = lse[:, :, :t, :]
@@ -677,7 +773,8 @@ def flash_attention(q, k, v, causal: bool = False, *,
                     block_k: int = _DEFAULT_BLOCK,
                     interpret: Optional[bool] = None,
                     selection: Optional[jax.Array] = None,
-                    select_from: Optional[int] = None) -> jax.Array:
+                    select_from: Optional[int] = None,
+                    window: Optional[int] = None) -> jax.Array:
     """Drop-in ``attn_fn(q, k, v, causal)``: q, k ``[B, T, H, Dk]``, v
     ``[B, T, H, Dv]`` → ``[B, T, H, Dv]``; the scale is 1/√Dk.  The two
     widths are usually one (D = 64 in the GPT-2 blocks); latent attention
@@ -694,13 +791,18 @@ def flash_attention(q, k, v, causal: bool = False, *,
     causal tile of a later block is computed and masked: the kernel does
     not skip what was not chosen.
 
+    ``window`` (static, causal only, not with a selection): query ``t``
+    attends to keys ``t - window < s <= t``, its own position and the
+    ``window - 1`` before it; tiles wholly behind the window are skipped,
+    forward and backward.  None: every earlier key, as ever.
+
     Sequences whose length is not MXU-tileable are zero-padded to the next
     tileable length (masked inside the kernels; the pad is sliced off), so
     any length compiles on real TPU."""
     if interpret is None:
         interpret = _use_interpret()
     return _pad_and_run(q, k, v, causal, block_q, block_k, interpret,
-                        selection, select_from)[0]
+                        selection, select_from, window)[0]
 
 
 def flash_attention_with_lse(q, k, v, causal: bool = False, *,
@@ -721,8 +823,10 @@ def flash_attention_with_lse(q, k, v, causal: bool = False, *,
 def make_flash_attention(mesh: Optional[Mesh] = None, *,
                          block_q: int = _DEFAULT_BLOCK,
                          block_k: int = _DEFAULT_BLOCK,
-                         interpret: Optional[bool] = None) -> Callable:
-    """Factory returning an ``attn_fn``.
+                         interpret: Optional[bool] = None,
+                         window: Optional[int] = None) -> Callable:
+    """Factory returning an ``attn_fn``; with ``window``, one whose causal
+    calls attend to the last ``window`` positions (:func:`flash_attention`).
 
     With a mesh, the kernel runs inside ``shard_map`` manual over the
     ``data`` (batch dim) and ``model`` (heads dim) axes — a ``pallas_call``
@@ -742,7 +846,8 @@ def make_flash_attention(mesh: Optional[Mesh] = None, *,
     """
     if interpret is None:
         interpret = _use_interpret()
-    kw = dict(block_q=block_q, block_k=block_k, interpret=interpret)
+    kw = dict(block_q=block_q, block_k=block_k, interpret=interpret,
+              window=window)
 
     @functools.lru_cache(maxsize=None)
     def _sharded(causal: bool, axes_key: frozenset, over):

@@ -362,11 +362,11 @@ def record_row_budgets(tokens_per_expert: jax.Array, rows: int, total: int
     step_values.emit("moe_row_budget_calls", calls, publish)
 
 
-def _grouped_swiglu(experts, rows, sizes):
+def _grouped_swiglu(experts, rows, sizes, activation=jax.nn.silu):
     """:func:`swiglu` of each group of ``rows`` (``sizes`` rows each, in
-    order) under its own expert's weights.  Rows past the last group come
-    back unwritten."""
-    hidden = (jax.nn.silu(jax.lax.ragged_dot(rows, experts["w_gate"], sizes))
+    order) under its own expert's weights, the gate's ``activation`` the
+    caller's.  Rows past the last group come back unwritten."""
+    hidden = (activation(jax.lax.ragged_dot(rows, experts["w_gate"], sizes))
               * jax.lax.ragged_dot(rows, experts["w_up"], sizes))
     return jax.lax.ragged_dot(hidden, experts["w_down"], sizes)
 
@@ -393,8 +393,8 @@ def _to_tokens(sorted_rows, place, here):
     return jnp.where(here[..., None], back.reshape(*here.shape, -1), 0)
 
 
-def _experts_on(budget: int, top_k: int, h, experts, weight, order, inverse,
-                sizes, here):
+def _experts_on(budget: int, top_k: int, activation, h, experts, weight,
+                order, inverse, sizes, here):
     """The held experts' part of the layer on the first ``budget`` rows of
     the sorted order, which hold every pick routed here (``sizes.sum() <=
     budget``), back in token order and summed over the picks: ``[N, d]``."""
@@ -403,14 +403,14 @@ def _experts_on(budget: int, top_k: int, h, experts, weight, order, inverse,
     with jax.named_scope(timeline.SCOPE_MOE_EXPERTS):
         rows, _, _, _, place = _sorted_rows(budget, top_k, h, order, inverse,
                                             sizes)
-        out = _grouped_swiglu(experts, rows, sizes)
+        out = _grouped_swiglu(experts, rows, sizes, activation)
     with jax.named_scope(timeline.SCOPE_MOE_COMBINE):
         return jnp.sum(_to_tokens(out, place, here) * weight[..., None],
                        axis=1)
 
 
-def _experts_on_transposed(budget: int, top_k: int, g, h, experts, weight,
-                           order, inverse, sizes, here):
+def _experts_on_transposed(budget: int, top_k: int, activation, g, h,
+                           experts, weight, order, inverse, sizes, here):
     """The cotangents of ``(h, experts, weight)`` under :func:`_experts_on`
     for the cotangent ``g [N, d]`` of its result, on ``budget`` rows too:
     the cotangent of a sorted row is its token's row of ``g`` times its
@@ -423,7 +423,8 @@ def _experts_on_transposed(budget: int, top_k: int, g, h, experts, weight,
         rows, live, index, token, place = _sorted_rows(
             budget, top_k, h, order, inverse, sizes)
         out, transpose = jax.vjp(
-            lambda experts, rows: _grouped_swiglu(experts, rows, sizes),
+            lambda experts, rows: _grouped_swiglu(experts, rows, sizes,
+                                                  activation),
             experts, rows)
     with jax.named_scope(timeline.SCOPE_MOE_COMBINE):
         g_rows = jnp.take(g, token, axis=0)
@@ -447,9 +448,9 @@ def _switch(rungs, sizes, branch, *operands):
                           [branch(c) for c in rungs], *operands)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _budgeted_experts(top_k: int, rungs, h, experts, weight, order, inverse,
-                      sizes, here):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _budgeted_experts(top_k: int, rungs, activation, h, experts, weight,
+                      order, inverse, sizes, here):
     """:func:`_experts_on` the smallest of ``rungs`` that holds the picks
     routed here.  Differentiated as written, ``switch`` would make every
     branch hand back every branch's residuals, zeros for those not taken:
@@ -458,19 +459,20 @@ def _budgeted_experts(top_k: int, rungs, h, experts, weight, order, inverse,
     same rung (recomputed from ``sizes``) and runs that branch's forward
     again and its transpose inside it."""
     return _switch(rungs, sizes, lambda c: functools.partial(
-        _experts_on, c, top_k), h, experts, weight, order, inverse, sizes,
-        here)
+        _experts_on, c, top_k, activation), h, experts, weight, order,
+        inverse, sizes, here)
 
 
-def _budgeted_experts_fwd(top_k, rungs, *operands):
-    return _budgeted_experts(top_k, rungs, *operands), operands
+def _budgeted_experts_fwd(top_k, rungs, activation, *operands):
+    return _budgeted_experts(top_k, rungs, activation, *operands), operands
 
 
-def _budgeted_experts_bwd(top_k, rungs, operands, g):
+def _budgeted_experts_bwd(top_k, rungs, activation, operands, g):
     # h, the experts' leaves and the weights; no cotangent for the integers
     sizes = operands[5]
     return _switch(rungs, sizes, lambda c: functools.partial(
-        _experts_on_transposed, c, top_k), g, *operands) + (None,) * 4
+        _experts_on_transposed, c, top_k, activation), g, *operands) \
+        + (None,) * 4
 
 
 _budgeted_experts.defvjp(_budgeted_experts_fwd, _budgeted_experts_bwd)
@@ -479,7 +481,9 @@ _budgeted_experts.defvjp(_budgeted_experts_fwd, _budgeted_experts_bwd)
 def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
                    experts_held: Optional[Tuple[int, int]] = None,
                    routed_scale: float = 1.0, train_router: bool = True,
-                   scoring: str = "sigmoid"
+                   scoring: str = "sigmoid",
+                   router_input: Optional[jax.Array] = None,
+                   activation=jax.nn.silu
                    ) -> Tuple[jax.Array, jax.Array]:
     """``k`` of ``E`` routed experts with NO token dropped, for the
     experts this chip holds (DeepSeek-V3's layer, arxiv 2412.19437 §2.1.2,
@@ -493,7 +497,20 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
     ``scoring="softmax"``: ``s = softmax(x W_r)`` over all ``E`` (the
     Qwen3-MoE router with ``norm_topk_prob``); where ``params`` has no
     ``router_bias`` the selection is by the scores alone, and no
-    ``shared`` leaves means no shared expert.
+    ``shared`` leaves means no shared expert.  ``scoring=
+    "softmax_of_picked"``: the same router computed without the softmax
+    over all ``E``: the top-``k`` LOGITS are the top-``k`` of ``s`` and a
+    softmax over them is ``s_e / sum_{j in S} s_j``; where the logits
+    stand more than 87 apart float32 underflows ``s`` to 0 and a selection
+    by ``s`` is a tie that hands every token the lowest-numbered experts.
+
+    ``router_input`` (``x``'s leading shape, ``W_r``'s rows wide): the
+    tensor the ROUTER reads where it is not the one the experts read (a
+    router placed before attention reads the layer's input, its experts
+    the normed stream after attention); the scores then pass their
+    gradient to it and not to ``x``.  ``activation``: the experts' gate
+    (``silu``: SwiGLU; ``jax.nn.relu``: ReGLU); the shared experts, where
+    there are any, keep ``silu``.
 
     ``experts_held = (first, count)``: ``params["experts"]`` leaves lead
     with ``count`` experts, which are experts ``first .. first + count``
@@ -546,21 +563,28 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
     router = params["router"].astype(jnp.float32)
     if not train_router:
         router = jax.lax.stop_gradient(router)
-    if scoring not in ("sigmoid", "softmax"):
-        raise ValueError(f"scoring={scoring!r}: expected 'sigmoid' or "
-                         f"'softmax'")
+    if scoring not in ("sigmoid", "softmax", "softmax_of_picked"):
+        raise ValueError(f"scoring={scoring!r}: expected 'sigmoid', "
+                         f"'softmax' or 'softmax_of_picked'")
+    read = h if router_input is None else router_input.reshape(n, -1)
     with jax.named_scope(timeline.SCOPE_MOE_ROUTE):
-        logits = jnp.dot(h.astype(jnp.float32), router,
+        logits = jnp.dot(read.astype(jnp.float32), router,
                          precision=jax.lax.Precision.HIGHEST)
-        scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
-            else jax.nn.softmax(logits, axis=-1)
+        if scoring == "softmax_of_picked":
+            scores = logits         # normalised over the picks below
+        else:
+            scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
+                else jax.nn.softmax(logits, axis=-1)
         ranked = scores
         if "router_bias" in params:
             ranked = scores + jax.lax.stop_gradient(params["router_bias"])
         _, chosen = jax.lax.top_k(ranked, top_k)
         chosen = checkpoint_name(chosen, ROUTING_RESIDUAL_NAMES[0])
         picked = jnp.take_along_axis(scores, chosen, axis=-1)   # [N, k]
-        gates = routed_scale * picked / picked.sum(-1, keepdims=True)
+        if scoring == "softmax_of_picked":
+            gates = routed_scale * jax.nn.softmax(picked, axis=-1)
+        else:
+            gates = routed_scale * picked / picked.sum(-1, keepdims=True)
         local = chosen - first
         here = (local >= 0) & (local < count)
         group = jnp.where(here, local, count).reshape(-1)       # [N * k]
@@ -574,7 +598,7 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
         weight = jnp.where(here, gates, 0.0).astype(h.dtype)
 
     y = _budgeted_experts(
-        top_k, row_budgets(n * top_k, count, total), h,
+        top_k, row_budgets(n * top_k, count, total), activation, h,
         jax.tree_util.tree_map(lambda w: w.astype(h.dtype), experts),
         weight, order, inverse, sizes, here)
 

@@ -364,6 +364,7 @@ SCOPE_GQA_PROJECT = "gqa/project"        # q, k, v, their norms, rotary, out
 SCOPE_DSA_INDEX = "dsa/index"            # the indexer's projections, scores
 SCOPE_DSA_SELECT = "dsa/select"          # top-k of every row, packed words
 SCOPE_DSA_ATTENTION = "dsa/attention"    # the attention call alone
+SCOPE_SWA_ATTENTION = "swa/attention"    # window_attn or global_attn alone
 SCOPE_MOE_ROUTE = "moe/route"            # scores, top-k, sort, group sizes
 SCOPE_MOE_SHARED = "moe/shared"          # the shared experts (dense)
 SCOPE_MOE_EXPERTS = "moe/experts"        # gather, grouped products, SwiGLU

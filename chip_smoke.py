@@ -576,24 +576,18 @@ def forced_router_takes_the_top_rung(cfg: dict, tokens: int = 512) -> dict:
     return facts
 
 
-def train_dsa_moe_phase(model: dict, *, batch_size: int, steps: int) -> dict:
-    """``models/gqa_dsa_moe_lm.py`` (grouped-query heads over the keys a
-    learned indexer selects, softmax-routed experts of which this chip
-    holds a share) through ``capture(has_aux=True)``: ``steps`` calls of
-    ``sess.run`` on one fixed batch.  Every loss finite; the per-expert
-    token counts come back; the gauges say how many pairs the attention
-    was asked for and how many its kernel scores; on a TPU the compiled
-    step holds a forward and a backward Pallas call a layer, none run
-    twice, and the forward's result has the QUERY heads while its keys
-    went in with their own (``num_kv_heads``)."""
+def expert_model_steps(spec, *, batch_size: int, steps: int, pairs: str):
+    """``steps`` calls of ``sess.run`` on one fixed batch of a decoder with
+    routed experts through ``capture(has_aux=True)``: every loss finite,
+    the per-expert token counts back, and the gauge ``pairs`` (pairs of
+    query and key its attention is ASKED for, the first kind, and pairs
+    its kernels compute) in order.  Returns ``(ad, sess, batch, facts)``;
+    the caller ends with :func:`close_session`."""
     import jax
     import numpy as np
 
-    from autodist_tpu.autodist import _reset_default_autodist_for_testing
-    from autodist_tpu.models.gqa_dsa_moe_lm import gqa_dsa_moe_lm
     from autodist_tpu.telemetry.registry import DEFAULT_REGISTRY
 
-    spec = gqa_dsa_moe_lm(**model, return_counts=True)
     ad, sess = open_session(
         spec, jax.jit(spec.init)(jax.random.PRNGKey(SEED)), "AllReduce",
         {"data": 1}, expert_vars=spec.expert_vars, has_aux=True)
@@ -608,13 +602,43 @@ def train_dsa_moe_phase(model: dict, *, batch_size: int, steps: int) -> dict:
     if counts.shape != (cfg["num_layers"], cfg["experts_held"][1]) \
             or not 0 < counts.sum() <= cfg["num_layers"] * picks:
         raise AssertionError(f"tokens per expert: {counts.tolist()}")
-    pairs = {m.labels["kind"]: int(m.value)
-             for m in DEFAULT_REGISTRY.metrics()
-             if m.name == "autodist_dsa_pairs_per_step"}
-    if not 0 < pairs["selected"] <= pairs["computed"]:
-        raise AssertionError(f"pairs a step: {pairs}")
-    facts = {"losses": [round(x, 4) for x in losses],
-             "tokens_per_expert": counts.tolist(), "pairs_per_step": pairs}
+    found = {m.labels["kind"]: int(m.value)
+             for m in DEFAULT_REGISTRY.metrics() if m.name == pairs}
+    asked = found[next(k for k in found if k != "computed")]
+    if not 0 < asked <= found["computed"]:
+        raise AssertionError(f"pairs a step: {found}")
+    return ad, sess, batch, {
+        "losses": [round(x, 4) for x in losses],
+        "tokens_per_expert": counts.tolist(), "pairs_per_step": found}
+
+
+def close_session(ad, sess):
+    from autodist_tpu.autodist import _reset_default_autodist_for_testing
+
+    del sess, ad
+    _reset_default_autodist_for_testing()
+    gc.collect()
+
+
+def train_dsa_moe_phase(model: dict, *, batch_size: int, steps: int) -> dict:
+    """``models/gqa_dsa_moe_lm.py`` (grouped-query heads over the keys a
+    learned indexer selects, softmax-routed experts of which this chip
+    holds a share) through ``capture(has_aux=True)``: ``steps`` calls of
+    ``sess.run`` on one fixed batch.  Every loss finite; the per-expert
+    token counts come back; the gauges say how many pairs the attention
+    was asked for and how many its kernel scores; on a TPU the compiled
+    step holds a forward and a backward Pallas call a layer, none run
+    twice, and the forward's result has the QUERY heads while its keys
+    went in with their own (``num_kv_heads``)."""
+    import jax
+
+    from autodist_tpu.models.gqa_dsa_moe_lm import gqa_dsa_moe_lm
+
+    spec = gqa_dsa_moe_lm(**model, return_counts=True)
+    ad, sess, batch, facts = expert_model_steps(
+        spec, batch_size=batch_size, steps=steps,
+        pairs="autodist_dsa_pairs_per_step")
+    cfg = spec.config
     if jax.devices()[0].platform == "tpu":
         text = sess.lower_step(batch).compile().as_text()
         attn = [[dims for _, dims in shapes]
@@ -624,15 +648,118 @@ def train_dsa_moe_phase(model: dict, *, batch_size: int, steps: int) -> dict:
         if len(attn) != 2 * cfg["num_layers"] \
                 or heads != {cfg["num_heads"]}:
             raise AssertionError(f"attention custom calls work on {attn}")
-        kv = f"f32[1,{cfg['num_kv_heads']},{cfg['seq_len']},"
-        if kv not in text:
-            raise AssertionError(f"no kernel operand {kv}...]: the keys "
-                                 f"were repeated for their query heads")
+        keys_with_their_own_heads(text, cfg)
         facts["attention_calls"] = len(attn)
         facts["bytes_in_use"] = memory_in_use(jax.devices()[:1])
-    del sess, ad
-    _reset_default_autodist_for_testing()
-    gc.collect()
+    close_session(ad, sess)
+    return facts
+
+
+def keys_with_their_own_heads(compiled_text: str, cfg: dict) -> None:
+    kv = f"f32[1,{cfg['num_kv_heads']},{cfg['seq_len']},"
+    if kv not in compiled_text:
+        raise AssertionError(f"no kernel operand {kv}...]: the keys were "
+                             f"repeated for their query heads")
+
+
+def check_windowed_rows(shape, window, *, interpret: bool, tol: float,
+                        rows: int = 256, **blocks) -> dict:
+    """Flash attention at ``shape`` ([T, H, Hkv, D]: one sequence, grouped
+    heads), causal, under ``window`` (None: every earlier key), forward and
+    gradient, against the plain masked softmax ON A BLOCK OF ROWS: the loss
+    reads the last ``rows`` query rows alone, so dQ, dK and dV of the whole
+    call are those rows' and the plain formula forms ``rows x T`` scores,
+    not ``T x T``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from autodist_tpu.ops.flash_attention import flash_attention
+
+    t, h, g, d = shape
+    rng = np.random.RandomState(SEED)
+    q = jnp.asarray(rng.randn(1, t, h, d) * 0.5, jnp.float32)
+    k, v = (jnp.asarray(rng.randn(1, t, g, d) * 0.5, jnp.float32)
+            for _ in range(2))
+    w = jnp.asarray(rng.randn(1, rows, h, d) * 0.5, jnp.float32)
+    pos = np.arange(t - rows, t)
+    keep = np.arange(t)[None, :] <= pos[:, None]
+    if window is not None:
+        keep &= np.arange(t)[None, :] > pos[:, None] - window
+
+    def kernel(q, k, v):
+        o = flash_attention(q, k, v, True, interpret=interpret,
+                            window=window, **blocks)
+        return jnp.sum(o[:, -rows:] * w)
+
+    def plain(q, k, v):
+        kk, vv = (jnp.repeat(x, h // g, axis=2) for x in (k, v))
+        s = jnp.einsum("bqhd,bkhd->bhqk", q[:, -rows:], kk) / d ** 0.5
+        p = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
+        return jnp.sum(jnp.einsum("bhqk,bkhd->bqhd", p, vv) * w)
+
+    got = jax.jit(jax.value_and_grad(kernel, (0, 1, 2)))(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(jax.value_and_grad(plain, (0, 1, 2)))(q, k, v)
+    errs = {"value": abs(float(got[0]) - float(want[0]))
+            / max(abs(float(want[0])), 1e-30)}
+    errs.update({name: _rel_err(a, b) for name, a, b in
+                 zip(("dq", "dk", "dv"), got[1], want[1])})
+    for name, err in errs.items():
+        if not err <= tol:
+            raise AssertionError(
+                f"flash attention {name} at {shape} window={window}: "
+                f"relative error {err:.3g} > {tol}")
+    return errs
+
+
+def train_swa_moe_phase(model: dict, *, batch_size: int, steps: int,
+                        tol: float, **blocks) -> dict:
+    """``models/swa_moe_lm.py`` with ONE GLOBAL AND ONE WINDOW LAYER
+    (every earlier key and no rotary; rotary and the window), a router that
+    reads the layer's input and ReLU-gated experts, through
+    ``capture(has_aux=True)``: ``steps`` calls of ``sess.run`` on one fixed
+    batch, every loss finite, the per-expert token counts back, the gauges
+    of the pairs attended and computed.  Before it, the two kinds'
+    attention calls at the model's own shape, forward and gradient,
+    against the plain masked softmax on a block of rows.  On a TPU the
+    compiled step's Pallas calls are counted BY NAME: a forward and a
+    backward ``global_attn`` and ``window_attn`` each, none run twice, the
+    keys with their own heads."""
+    import jax
+
+    from autodist_tpu.models.swa_moe_lm import swa_moe_lm
+
+    spec = swa_moe_lm(**model, return_counts=True)
+    cfg = spec.config
+    if tuple(cfg["window_layout"]) != (0, 1):
+        raise AssertionError("one global and one window layer are asked")
+    interpret = jax.devices()[0].platform != "tpu"
+    shape = (cfg["seq_len"], cfg["num_heads"], cfg["num_kv_heads"],
+             cfg["head_dim"])
+    facts = {"rows_against_the_plain_formula": {
+        name: check_windowed_rows(shape, window, interpret=interpret,
+                                  tol=tol, rows=min(256, cfg["seq_len"]),
+                                  **blocks)
+        for name, window in (("global_attn", None),
+                             ("window_attn", cfg["window"]))}}
+    ad, sess, batch, stepped = expert_model_steps(
+        spec, batch_size=batch_size, steps=steps,
+        pairs="autodist_swa_pairs_per_step")
+    facts.update(stepped)
+    if not interpret:
+        text = sess.lower_step(batch).compile().as_text()
+        calls = {name: len(re.findall(
+            rf"%{name}[\w.\-]* = .*custom_call_target=\"tpu_custom_call\"",
+            text)) for name in ("global_attn", "window_attn")}
+        print(f"  attention kernels of the step by name: {calls}",
+              flush=True)
+        if calls != {"global_attn": 2, "window_attn": 2}:
+            raise AssertionError(f"attention custom calls: {calls}")
+        keys_with_their_own_heads(text, cfg)
+        facts["attention_calls"] = calls
+        facts["bytes_in_use"] = memory_in_use(jax.devices()[:1])
+    close_session(ad, sess)
     return facts
 
 
@@ -884,6 +1011,12 @@ FULL_MLA_MOE = dict(vocab_size=16032, num_layers=2, experts_held=(0, 16),
 # experts, twice the 2,048 keys a row may select
 FULL_DSA_MOE = dict(vocab_size=18992, num_layers=1, experts_held=(0, 16),
                     seq_len=4096, xent_chunk=6400)
+# SmallThinker-21BA3B-Instruct at its published widths (benchmark/configs/
+# smallthinker-21b-a3b.ep8-share.json): one global and one window layer,
+# 8 of 64 experts, all 16,384 positions
+FULL_SWA_MOE = dict(vocab_size=18992, num_layers=2, window_layout=(0, 1),
+                    rope_layout=(0, 1), experts_held=(0, 8), seq_len=16384,
+                    xent_chunk=6400)
 FULL_SIZES = dict(p=64, prefix=512, tails=(40, 100), long=1024, mid=333,
                   n=(32, 48, 96, 128))
 FULL_ENGINE = dict(slots=8, window=2048, block_size=32, chunk=16)
@@ -934,6 +1067,8 @@ def main() -> int:
               batch_size=2, steps=4)
     run_phase(watch, "train_dsa_moe", train_dsa_moe_phase, FULL_DSA_MOE,
               batch_size=1, steps=2)
+    run_phase(watch, "train_swa_moe", train_swa_moe_phase, FULL_SWA_MOE,
+              batch_size=1, steps=2, tol=2e-2)
     run_phase(watch, "serve_paged", serve_paged_phase, spec, params,
               sizes=FULL_SIZES, engine=FULL_ENGINE)
     run_phase(watch, "serve_slots", serve_slots_phase, spec, params,

@@ -88,6 +88,32 @@ def test_train_dsa_moe_phase_returns_counts_and_pairs():
                                        "computed": 4 * 64 * 64}
 
 
+def test_train_swa_moe_phase_checks_both_kinds_of_layer():
+    facts = chip_smoke.train_swa_moe_phase(
+        dict(vocab_size=61, num_layers=2, d_model=32, num_heads=4,
+             num_kv_heads=2, head_dim=16, window=24, window_layout=(0, 1),
+             rope_layout=(0, 1), d_expert=12, num_experts=16,
+             experts_held=(4, 4), top_k=3, seq_len=64, block_k=32,
+             moe_slice=64),
+        batch_size=2, steps=2, tol=1e-4, block_q=32, block_k=32)
+    assert all(np.isfinite(facts["losses"]))
+    assert len(facts["tokens_per_expert"]) == 2
+    assert set(facts["rows_against_the_plain_formula"]) == {
+        "global_attn", "window_attn"}
+    # 2 sequences x 4 heads x (64 x 65 / 2 + 24 x 25 / 2 + 40 x 24) pairs
+    # attended; off the TPU attention is dense and the gauge counts the
+    # kernel's tiles all the same: one q block against 2 + 2 key blocks
+    assert facts["pairs_per_step"] == {
+        "attended": 8 * (2080 + 300 + 960), "computed": 8 * 2 * 64 * 64}
+    with pytest.raises(AssertionError, match="one global and one window"):
+        chip_smoke.train_swa_moe_phase(
+            dict(vocab_size=61, num_layers=2, d_model=32, num_heads=4,
+                 num_kv_heads=2, head_dim=16, window=24,
+                 window_layout=(1, 1), rope_layout=(1, 1), d_expert=12,
+                 num_experts=16, seq_len=64, block_k=32, moe_slice=64),
+            batch_size=1, steps=1, tol=1e-4)
+
+
 def test_serve_phases_over_http(lm):
     facts = chip_smoke.serve_paged_phase(
         *lm, sizes=TINY_SIZES, engine=TINY_ENGINE)

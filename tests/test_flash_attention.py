@@ -688,3 +688,123 @@ def test_selection_is_refused_where_it_cannot_be_honoured():
                         interpret=True)
     with pytest.raises(ValueError, match="multiple of 32"):
         pack_selection(jnp.ones((1, 48, 48), bool), block_k=16)
+
+
+# ---------------------------------------------------------------------------
+# a window (PR 36): query t attends to keys t - window < s <= t
+# ---------------------------------------------------------------------------
+
+def _window_mask(b, t, window):
+    pos = np.arange(t)
+    return jnp.broadcast_to(jnp.asarray(
+        (pos[None, :] <= pos[:, None])
+        & (pos[None, :] > pos[:, None] - window)), (b, t, t))
+
+
+@pytest.mark.parametrize("t,window,blocks,heads,kv_heads", [
+    (128, 48, (16, 16), 4, 2),     # three tiles wide, grouped heads
+    (128, 50, (16, 16), 2, 2),     # no multiple of the tile
+    (128, 16, (16, 16), 2, 1),     # one tile: the edge crosses the diagonal's
+    (128, 7, (16, 16), 2, 2),      # shorter than a tile
+    (128, 1, (32, 32), 2, 2),      # the query's own position alone
+    (96, 96, (32, 32), 4, 2),      # the sequence's length: nothing is cut
+    (96, 500, (32, 32), 2, 2),     # longer than the sequence
+    (128, 40, (32, 16), 4, 1),     # q blocks of two key blocks
+    (128, 40, (16, 32), 4, 1),     # key blocks of two q blocks
+    (100, 24, (16, 16), 4, 2),     # padded to 104: blocks of 8
+    (200, 77, (16, 16), 2, 1),     # padded, grouped, no multiple
+])
+def test_window_matches_plain_masked_attention(t, window, blocks, heads,
+                                               kv_heads):
+    """Forward and fused backward under a window against the masked dense
+    formula: tiles behind the window skipped, the edge and the diagonal
+    masked."""
+    rng = np.random.default_rng(t + window)
+    q, k, v = _grouped_qkv(rng, 2, t, heads, kv_heads)
+    mask = _window_mask(2, t, window)
+    w = jnp.asarray(rng.standard_normal((2, t, heads, 16)), jnp.float32)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, True, block_q=blocks[0],
+                               block_k=blocks[1], interpret=True,
+                               window=window)
+
+    np.testing.assert_allclose(flash(q, k, v), _grouped_dense(q, k, v, mask),
+                               rtol=1e-5, atol=1e-5)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(_grouped_dense(*a, mask) * w),
+                    (0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        assert g.shape == r.shape
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5)
+
+
+def test_window_differs_from_causal_and_from_another_window():
+    q, k, v = _grouped_qkv(np.random.default_rng(5), 1, 128, 2, 2)
+    out = {w: flash_attention(q, k, v, True, block_q=16, block_k=16,
+                              interpret=True, window=w)
+           for w in (None, 32, 64)}
+    # rows inside every window are the causal rows
+    np.testing.assert_allclose(out[32][:, :32], out[None][:, :32],
+                               rtol=1e-6, atol=1e-6)
+    assert float(jnp.abs(out[32][:, 32:] - out[None][:, 32:]).max()) > 1e-2
+    assert float(jnp.abs(out[32][:, 64:] - out[64][:, 64:]).max()) > 1e-2
+
+
+@pytest.mark.parametrize("t,block,window,tiles", [
+    (16384, 512, 4096, 252),     # the cell's: 36 + 24 x 9 of the 528
+    (16384, 512, None, 528),
+    (16384, 512, 16384, 528),
+    (16384, 512, 2048, 10 + 28 * 5),
+    (128, 16, 48, 1 + 2 + 3 + 5 * 4),
+    (128, 16, 50, 1 + 2 + 3 + 4 + 4 * 5),
+])
+def test_pairs_computed_counts_the_tiles_the_window_visits(t, block, window,
+                                                           tiles):
+    from autodist_tpu.ops.flash_attention import pairs_computed
+
+    assert pairs_computed(t, block_q=block, block_k=block, window=window) \
+        == tiles * block * block
+
+
+def test_window_visits_the_tiles_it_counts():
+    """The forward's loops run over the key blocks ``pairs_computed``
+    counts and no other: the score products of one head, counted through
+    the kernel's own loop bounds by poisoning what lies behind the window
+    (a key block the kernel visited would turn the output NaN)."""
+    from autodist_tpu.ops.flash_attention import pairs_computed
+
+    t, window, block = 128, 40, 16
+    q, k, v = _grouped_qkv(np.random.default_rng(9), 1, t, 2, 2)
+    clean = flash_attention(q, k, v, True, block_q=block, block_k=block,
+                            interpret=True, window=window)
+    # rows of the last q block see key blocks (112 - 39) // 16 = 4 onward
+    poison = jnp.arange(t)[None, :, None, None] < 4 * block
+    k_bad, v_bad = (jnp.where(poison, jnp.nan, x) for x in (k, v))
+    dirty = flash_attention(q, k_bad, v_bad, True, block_q=block,
+                            block_k=block, interpret=True, window=window)
+    assert np.isfinite(np.asarray(dirty[:, -block:])).all()
+    np.testing.assert_allclose(dirty[:, -block:], clean[:, -block:],
+                               rtol=1e-6, atol=1e-6)
+    assert pairs_computed(t, block_q=block, block_k=block, window=window) \
+        == (1 + 2 + 3 + 5 * 4) * block * block
+
+
+def test_window_is_refused_where_it_cannot_be_honoured():
+    from autodist_tpu.ops.flash_attention import pack_selection
+
+    q, k, v = _grouped_qkv(np.random.default_rng(0), 1, 64, 2, 2)
+    with pytest.raises(ValueError, match="a window is causal"):
+        flash_attention(q, k, v, False, interpret=True, window=16)
+    with pytest.raises(ValueError, match="a window is causal"):
+        flash_attention(q, k, v, True, interpret=True, window=0)
+    words = pack_selection(jnp.tril(jnp.ones((1, 64, 64), bool)),
+                           block_k=32)
+    with pytest.raises(ValueError, match="without a selection"):
+        flash_attention(q, k, v, True, interpret=True, window=16,
+                        selection=words, select_from=8)
+    attn = make_flash_attention(interpret=True, window=16, block_q=16,
+                                block_k=16)
+    np.testing.assert_allclose(
+        attn(q, k, v, True), _grouped_dense(q, k, v, _window_mask(1, 64, 16)),
+        rtol=1e-5, atol=1e-5)

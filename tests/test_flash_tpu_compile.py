@@ -152,6 +152,48 @@ def test_grouped_heads_and_selection_compile(one_chip, name, precision):
     assert all(f"f32[{b},{g},{t},{d}]" in ln for ln in calls)
 
 
+_WINDOWED = {
+    # [B, T, H, Hkv, D], window
+    "smallthinker_one_sequence": ((1, 16384, 28, 4, 128), 4096),
+    "window_short": ((2, 2048, 8, 2, 128), 640),     # no multiple of 512
+    "window_in_a_tile": ((1, 4096, 4, 4, 64), 100),   # crosses the diagonal's
+    "window_padded": ((2, 1000, 4, 1, 64), 300),      # pads to 1024
+}
+
+
+@pytest.mark.parametrize("name,precision", [
+    (name, precision) for name in sorted(_WINDOWED)
+    for precision in ("default", "highest")
+    if (name, precision) != ("smallthinker_one_sequence", "highest")])
+def test_window_compiles(one_chip, name, precision):
+    """The kernels under a static window at the smallthinker cell's shape
+    (28 query heads over 4 key/value heads of 128, 16,384 tokens, a window
+    of 4,096, float32 in) and at shorter ones whose window ends inside a
+    tile: value and gradient, one forward and one fused backward call, the
+    keys and values with their own heads."""
+    import importlib
+
+    flash_attention = importlib.import_module(
+        "autodist_tpu.ops.flash_attention")
+    (b, t, h, g, d), window = _WINDOWED[name]
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention.flash_attention(
+            q, k, v, True, interpret=False, window=window))
+
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            shape(b, t, h, d), shape(b, t, g, d), shape(b, t, g, d)
+        ).compile()
+    calls = _pallas_calls(compiled)
+    assert len(calls) == 2
+    tp = flash_attention._pad_len(t, False)
+    assert all(f"f32[{b},{g},{tp},{d}]" in ln for ln in calls)
+
+
 def test_latent_attention_writes_each_kernel_operand_once(one_chip):
     """One layer's latent attention at the kanana cell's widths, value and
     gradient, four sequences mapped under the layer's checkpoint as
