@@ -55,11 +55,13 @@ _REMAT_POLICIES = {
 
 
 def equations(jaxpr):
-    """Every equation of a jaxpr, those of its inner jaxprs included."""
+    """Every equation of a jaxpr, those of its inner jaxprs included; a
+    kernel's own body is the kernel's and is left out."""
     for eqn in jaxpr.eqns:
         yield eqn
-        for inner in jax.core.jaxprs_in_params(eqn.params):
-            yield from equations(inner)
+        if eqn.primitive.name != "pallas_call":
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                yield from equations(inner)
 
 
 def named_bytes(fn: Callable, *args) -> dict:

@@ -115,8 +115,6 @@ _use_interpret = pallas_utils.use_interpret
 _DEFAULT_BLOCK = 512
 
 
-_VMEM_DEFAULT = 16 << 20  # Mosaic's scoped-VMEM limit on the v5e
-_VMEM_MAX = 100 << 20      # of the v5e's 128 MiB
 _VMEM_TILES = 8 << 20      # room for one tile's temporaries (512 x 512 f32)
 
 
@@ -142,8 +140,7 @@ def _compiler_params(t: int, block: int, resident, blocked, widths,
         _row_bytes(*blocked) + _row_bytes(*[(d, 4) for d in widths] * 4)))
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=(None if need <= _VMEM_DEFAULT
-                          else min(need, _VMEM_MAX)))
+        vmem_limit_bytes=pallas_utils.vmem_limit(need))
 
 
 def _row_bytes(*buffers) -> int:

@@ -58,6 +58,17 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
     return use_interpret() if interpret is None else bool(interpret)
 
 
+_VMEM_DEFAULT = 16 << 20   # Mosaic's scoped-VMEM limit on the v5e
+_VMEM_MAX = 100 << 20      # of the v5e's 128 MiB
+
+
+def vmem_limit(need: int) -> Optional[int]:
+    """``vmem_limit_bytes`` for a kernel that holds ``need`` bytes: None
+    (Mosaic's default) where they fit it, else ``need`` up to what the
+    v5e's VMEM leaves a kernel."""
+    return None if need <= _VMEM_DEFAULT else min(need, _VMEM_MAX)
+
+
 def pad_to(n: int, m: int) -> int:
     """``n`` rounded up to the next multiple of ``m``."""
     return -(-int(n) // int(m)) * int(m)

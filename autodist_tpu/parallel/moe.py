@@ -26,6 +26,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from autodist_tpu.const import MESH_AXIS_DATA, MESH_AXIS_EXPERT
+from autodist_tpu.ops.rows_to_tokens import rows_to_tokens
 from autodist_tpu.utils import logging
 
 #: capacity configs already warned about (one line per distinct config,
@@ -371,42 +372,37 @@ def _grouped_swiglu(experts, rows, sizes, activation=jax.nn.silu):
     return jax.lax.ragged_dot(hidden, experts["w_down"], sizes)
 
 
-def _sorted_rows(budget: int, top_k: int, h, order, inverse, sizes):
+def _sorted_rows(budget: int, top_k: int, h, order, sizes):
     """The first ``budget`` places of the sorted order: ``(their tokens'
     rows of h or zeros past the last group [budget, d], which of them hold
     a pick routed here [budget, 1], their picks [budget], their tokens
-    [budget], where each of the N * k picks went [N * k])``; a pick past
-    the budget is not held here and any place will do for it."""
+    [budget])``."""
     index = order[:budget]
     token = index // top_k
     live = (jnp.arange(budget) < sizes.sum())[:, None]
     rows = jnp.where(live, jnp.take(h, token, axis=0), 0)
-    return rows, live, index, token, jnp.minimum(inverse, budget - 1)
-
-
-def _to_tokens(sorted_rows, place, here):
-    """``[budget, d]`` in sorted order back to ``[N, k, d]`` by a gather,
-    zeros for the picks not held here (what lies past the last group is
-    never read as a number).  Token order is ``N * k`` rows wide by
-    nature."""
-    back = jnp.take(sorted_rows, place, axis=0)
-    return jnp.where(here[..., None], back.reshape(*here.shape, -1), 0)
+    return rows, live, index, token
 
 
 def _experts_on(budget: int, top_k: int, activation, h, experts, weight,
                 order, inverse, sizes, here):
     """The held experts' part of the layer on the first ``budget`` rows of
     the sorted order, which hold every pick routed here (``sizes.sum() <=
-    budget``), back in token order and summed over the picks: ``[N, d]``."""
+    budget``), back in token order and summed over the picks: ``[N, d]``.
+    The rows that hold a pick are read once, each times its pick's weight,
+    and every token is written once (``ops/rows_to_tokens.py``): nothing
+    is ``N * top_k`` rows wide, a pick that is not held here is no row at
+    all, and what lies past the last group, which a grouped product leaves
+    UNWRITTEN, is never read.  A token's terms are added in the order of
+    their experts, on every rung."""
     from autodist_tpu.telemetry import timeline
 
     with jax.named_scope(timeline.SCOPE_MOE_EXPERTS):
-        rows, _, _, _, place = _sorted_rows(budget, top_k, h, order, inverse,
-                                            sizes)
+        rows, _, index, token = _sorted_rows(budget, top_k, h, order, sizes)
         out = _grouped_swiglu(experts, rows, sizes, activation)
     with jax.named_scope(timeline.SCOPE_MOE_COMBINE):
-        return jnp.sum(_to_tokens(out, place, here) * weight[..., None],
-                       axis=1)
+        return rows_to_tokens(out, token, jnp.take(weight.reshape(-1), index),
+                              sizes.sum(), h.shape[0])
 
 
 def _experts_on_transposed(budget: int, top_k: int, activation, g, h,
@@ -415,13 +411,13 @@ def _experts_on_transposed(budget: int, top_k: int, activation, g, h,
     for the cotangent ``g [N, d]`` of its result, on ``budget`` rows too:
     the cotangent of a sorted row is its token's row of ``g`` times its
     pick's weight, a pick's weight takes the dot of its sorted row with its
-    token's ``g``, and only the cotangent of ``h`` is gathered back to
-    token order (never scattered) and summed over the picks."""
+    token's ``g`` (one number a pick, gathered back), and the cotangent of
+    ``h`` is the sorted rows' summed by token as the forward's are."""
     from autodist_tpu.telemetry import timeline
 
     with jax.named_scope(timeline.SCOPE_MOE_EXPERTS):
-        rows, live, index, token, place = _sorted_rows(
-            budget, top_k, h, order, inverse, sizes)
+        rows, live, index, token = _sorted_rows(budget, top_k, h, order,
+                                                sizes)
         out, transpose = jax.vjp(
             lambda experts, rows: _grouped_swiglu(experts, rows, sizes,
                                                   activation),
@@ -430,13 +426,14 @@ def _experts_on_transposed(budget: int, top_k: int, activation, g, h,
         g_rows = jnp.take(g, token, axis=0)
         d_out = jnp.where(live, g_rows * jnp.take(
             weight.reshape(-1), index)[:, None], 0)
-        d_weight = _to_tokens(
-            jnp.sum(jnp.where(live, out, 0) * g_rows, axis=-1,
-                    keepdims=True), place, here)[..., 0]
+        dots = jnp.sum(jnp.where(live, out, 0) * g_rows, axis=-1)
+        # a pick past the budget is not held here: any place will do
+        d_weight = jnp.where(here, jnp.take(dots, jnp.minimum(
+            inverse, budget - 1)).reshape(here.shape), 0)
     with jax.named_scope(timeline.SCOPE_MOE_EXPERTS):
         d_experts, d_rows = transpose(d_out)
-        d_h = jnp.sum(_to_tokens(jnp.where(live, d_rows, 0), place, here),
-                      axis=1)
+        d_h = rows_to_tokens(d_rows, token, jnp.ones_like(dots), sizes.sum(),
+                             h.shape[0])
     return d_h, d_experts, d_weight
 
 
@@ -527,8 +524,10 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
     are sorted by local expert, the picks of absent experts last.  The
     first ``C`` rows of that order are gathered and go through three
     grouped products (``jax.lax.ragged_dot``, on a TPU one Mosaic kernel
-    each that leaves the row tiles past the last group alone), then come
-    back to token order by a gather and are summed with their weights.
+    each that leaves the row tiles past the last group alone), then are
+    added, each times its pick's weight, to their tokens' rows by one
+    kernel that reads those ``C`` rows and writes the ``N`` tokens once
+    (``ops/rows_to_tokens.py``; the backward's cotangent of ``x`` alike).
     ``C`` is the smallest rung of :func:`row_budgets` (from the shapes
     alone: twice and four times what an even router sends here, then
     ``N * k``) that holds the picks routed here, chosen ON THE DEVICE from

@@ -555,24 +555,40 @@ def forced_router_takes_the_top_rung(cfg: dict, tokens: int = 512) -> dict:
         each = jax.vmap(lambda w: moe.swiglu(w, x))(p["experts"])
         return jnp.einsum("ne,end->nd", gates[:, first:first + count], each)
 
+    def layer(p, x):
+        return moe.routed_moe_ffn(p, x, top_k=top_k,
+                                  experts_held=(first, count))
+
+    def with_gradient(f):
+        """``(y, its other result, the gradient of sum(y * x) by x)``: the
+        cotangent of ``x`` comes back to token order as ``y`` does."""
+        def probe(p, x):
+            y, aux = f(p, x)
+            return jnp.sum(y * jax.lax.stop_gradient(x)), (y, aux)
+        return jax.jit(jax.grad(probe, argnums=1, has_aux=True))
+
+    def gap(got, want):
+        return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
     rungs = moe.row_budgets(tokens * top_k, count, total)
     facts = {"rungs": list(rungs)}
     for name, bias in biases.items():
         p = dict(params, router_bias=bias)
-        y, counts = jax.jit(lambda p, x: moe.routed_moe_ffn(
-            p, x, top_k=top_k, experts_held=(first, count)))(p, x)
-        want = jax.jit(plain)(p, x)
-        gap = float(jnp.linalg.norm(y - want) / jnp.linalg.norm(want))
+        d_x, (y, counts) = with_gradient(layer)(p, x)
+        want_d_x, (want, _) = with_gradient(lambda p, x: (plain(p, x), 0))(
+            p, x)
+        gaps = gap(y, want), gap(d_x, want_d_x)
         taken = rungs[int(jnp.argmax(moe.budgets_taken(
             counts, tokens * top_k, total)[1]))]
-        if gap > 2e-2 or (name == "forced" and (
+        if max(gaps) > 2e-2 or (name == "forced" and (
                 taken != rungs[-1] or int(counts.sum()) != tokens * top_k)):
             raise AssertionError(
                 f"{name} router: {int(counts.sum())} rows routed here took "
-                f"the budget of {taken} of {rungs}, {gap:.2e} from the "
-                f"layer written out")
+                f"the budget of {taken} of {rungs}, value and gradient "
+                f"{gaps} from the layer written out")
         facts[name] = {"rows_routed_here": int(counts.sum()),
-                       "budget_taken": taken, "gap": round(gap, 6)}
+                       "budget_taken": taken, "gap": round(gaps[0], 6),
+                       "gradient_gap": round(gaps[1], 6)}
     return facts
 
 

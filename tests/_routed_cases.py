@@ -8,10 +8,13 @@ token's first three columns say its KIND and the router reads nothing else:
 kind 0 picks experts 4 and 5 (two picks here), kind 1 picks 4 and 9 (one),
 kind 2 picks 8 and 9 (none).  The other columns are noise, so that the
 experts' outputs differ from token to token."""
+import collections
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from autodist_tpu.models.mla_moe_lm import equations
 from autodist_tpu.parallel import moe
 
 TOKENS, TOP_K, HELD, TOTAL, D = 64, 2, (4, 2), 16, 32
@@ -51,12 +54,12 @@ def tokens_routing(load: int, seed: int = 1) -> jax.Array:
     return jnp.asarray(x)
 
 
-def value_and_gradients(params, x, scoring, rungs=None):
+def value_and_gradients(params, x, scoring, rungs=None, top_k=TOP_K):
     """``sum(y ** 2)``, the tokens an expert was sent and the gradient of
     every leaf and of ``x``; ``rungs``: the ladder in the shapes' place."""
     def loss(params, x):
         y, counts = moe.routed_moe_ffn(
-            params, x, top_k=TOP_K, experts_held=HELD, routed_scale=2.448,
+            params, x, top_k=top_k, experts_held=HELD, routed_scale=2.448,
             scoring=scoring)
         return jnp.sum(y ** 2), counts
 
@@ -96,6 +99,23 @@ def assert_rung_equals_the_top_rung(scoring: str, load: int):
             assert np.any(np.asarray(got)), name
 
 
+def primitives(jaxpr) -> collections.Counter:
+    """How often each primitive stands in a jaxpr, the kernels that bring
+    sorted rows back to token order under their own name: what is left
+    under ``pallas_call`` are a model's attention kernels."""
+    return collections.Counter(
+        "rows_to_tokens" if eqn.primitive.name == "pallas_call"
+        and eqn.params["name"] == "rows_to_tokens" else eqn.primitive.name
+        for eqn in equations(jaxpr))
+
+
+def rung_switches(jaxpr):
+    """The forward's and the backward's ``switch`` over the rungs."""
+    found = [eqn for eqn in equations(jaxpr) if eqn.primitive.name == "cond"]
+    assert [len(eqn.params["branches"]) for eqn in found] == [3, 3]
+    return found
+
+
 def assert_gradient_switches_once_and_fills_no_rows(scoring: str):
     """The gradient's jaxpr: one ``switch`` of three branches for the
     forward and one for the backward, whose results are token-shaped or
@@ -103,8 +123,6 @@ def assert_gradient_switches_once_and_fills_no_rows(scoring: str):
     all ``N * k`` rows, nothing broadcasts into an ``[N * k, d]`` array
     (differentiated as written, ``switch`` would zero-fill the top rung's
     residuals in every other branch)."""
-    from autodist_tpu.models.mla_moe_lm import equations
-
     def fills(jaxpr):
         return sum(eqn.primitive.name == "broadcast_in_dim"
                    and eqn.outvars[0].aval.shape == (TOKENS * TOP_K, D)
@@ -113,14 +131,92 @@ def assert_gradient_switches_once_and_fills_no_rows(scoring: str):
     params, x = layer(scoring), tokens_routing(16)
     jaxpr = jax.make_jaxpr(lambda p, x: value_and_gradients(
         p, x, scoring)[2])(params, x).jaxpr
-    found = [eqn for eqn in equations(jaxpr) if eqn.primitive.name == "cond"]
-    assert [len(eqn.params["branches"]) for eqn in found] == [3, 3]
     in_top = 0
-    for eqn in found:
+    for eqn in rung_switches(jaxpr):
         assert all(var.aval.shape[0] in (TOKENS, HELD[1])
                    for var in eqn.outvars), eqn.outvars
         in_top += fills(eqn.params["branches"][-1].jaxpr)
     assert fills(jaxpr) == in_top > 0
+
+
+def assert_nothing_below_the_top_rung_is_as_wide_as_the_picks(
+        scoring: str, top_k: int):
+    """96 tokens pick ``top_k`` of 16 experts (rungs of 2, 4 and 8 times
+    the 12 an even router sends to the two held): in the branches of the
+    gradient's two ``switch`` es below the top rung NO equation yields
+    ``N * top_k * d`` elements or more: no ``[N, k, d]`` array (at k = 6 a
+    relayout on the chip), no select or fill of every pick's row; sorted
+    rows come back by ``rows_to_tokens``, once a branch."""
+    tokens = 96
+    params = jax.eval_shape(lambda: layer(scoring))
+    x = jax.ShapeDtypeStruct((tokens, D), jnp.float32)
+    assert len(moe.row_budgets(tokens * top_k, HELD[1], TOTAL)) == 3
+    jaxpr = jax.make_jaxpr(lambda p, x: value_and_gradients(
+        p, x, scoring, top_k=top_k)[2])(params, x).jaxpr
+    for eqn in rung_switches(jaxpr):
+        for branch in eqn.params["branches"][:-1]:
+            for inner in equations(branch.jaxpr):
+                for var in inner.outvars:
+                    assert var.aval.size < tokens * top_k * D, inner
+            assert primitives(branch.jaxpr)["rows_to_tokens"] == 1
+
+
+def poisoned(grouped, traced: list):
+    """``parallel/moe.py: _grouped_swiglu`` as a chip runs it: rows past
+    the last group come back UNWRITTEN, from the products and from their
+    transposes (a CPU's ``ragged_dot`` writes zeros there): NaN here.
+    ``traced`` takes the rows of every call traced."""
+    def unwritten(rows, sizes):
+        past = jnp.arange(rows.shape[0]) >= sizes.sum()
+        return jnp.where(past[:, None], jnp.nan, rows)
+
+    def on_a_chip(experts, rows, sizes, activation=jax.nn.silu):
+        traced.append(rows.shape[0])
+
+        @jax.custom_vjp
+        def products(experts, rows, sizes):
+            return unwritten(grouped(experts, rows, sizes, activation),
+                             sizes)
+
+        def forward(experts, rows, sizes):
+            out, transpose = jax.vjp(lambda e, r: grouped(
+                e, r, sizes, activation), experts, rows)
+            return unwritten(out, sizes), (transpose, sizes)
+
+        def backward(kept, g):
+            transpose, sizes = kept
+            d_experts, d_rows = transpose(g)
+            return d_experts, unwritten(d_rows, sizes), None
+
+        products.defvjp(forward, backward)
+        return products(experts, rows, sizes)
+
+    return on_a_chip
+
+
+def assert_unwritten_rows_are_never_read(scoring: str, load: int):
+    """Value and every gradient with the rows past the last group NaN:
+    finite, and what they are with zeros there.  ``load`` 16 fills half
+    of the first rung; 32 and 64 fill a rung EXACTLY with picks of absent
+    experts present, whose clipped places fall on a live row."""
+    params, x = layer(scoring), tokens_routing(load)
+    run = jax.jit(lambda p, x: value_and_gradients(p, x, scoring))
+    want = run(params, x)
+    real, traced = moe._grouped_swiglu, []
+    moe._grouped_swiglu = poisoned(real, traced)
+    try:
+        got = jax.jit(lambda p, x: value_and_gradients(p, x, scoring))(
+            params, x)
+    finally:
+        moe._grouped_swiglu = real
+    # forward and backward of every rung
+    assert int(got[1].sum()) == load and sorted(traced) == sorted(2 * RUNGS)
+    for (path, leaf), other in zip(
+            jax.tree_util.tree_leaves_with_path(got),
+            jax.tree_util.tree_leaves(want)):
+        assert np.all(np.isfinite(leaf)), jax.tree_util.keystr(path)
+        np.testing.assert_array_equal(leaf, other,
+                                      jax.tree_util.keystr(path))
 
 
 def budget_gauges():

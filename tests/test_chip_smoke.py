@@ -71,7 +71,8 @@ def test_train_moe_phase_returns_the_counts():
     assert budgets["forced"]["rows_routed_here"] == 512 * 3 \
         == budgets["forced"]["budget_taken"]
     assert budgets["even"]["budget_taken"] == 1024
-    assert max(budgets[r]["gap"] for r in ("even", "forced")) < 1e-5
+    assert max(budgets[r][gap] for r in ("even", "forced")
+               for gap in ("gap", "gradient_gap")) < 1e-5
 
 
 def test_train_dsa_moe_phase_returns_counts_and_pairs():

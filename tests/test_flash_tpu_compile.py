@@ -280,41 +280,71 @@ def test_data_parallel_over_four_chips_compiles(chips):
 
 _EXPERT_LAYERS = {
     # the model's call at a cell's widths: calls a step of a layer, picks
-    # a token, router, shared experts' width; then GB of the layer's value
-    # and gradient compiled for this chip AT THE PARENT OF PR 34 (one
-    # budget of every pick), and how far over it the ladder may go
+    # a token, router; the layer's width, its experts held and in all,
+    # the shared experts' width; then GB of the layer's value and gradient
+    # compiled for this chip AT THE PARENT OF PR 34 (one budget of every
+    # pick), and how far over it the ladder may go
     "kanana": (dict(top_k=6, scoring="sigmoid", routed_scale=2.448),
-               dict(selection_bias=True, d_shared=2 * 768), 2.418, 0.3),
+               (2048, 16, 128), dict(selection_bias=True, d_shared=2 * 768),
+               2.418, 0.3),
     # the step's need FELL 0.30 GB (15.855 -> 15.554 GB); a layer alone
     # reads 0.44 GB more with the switch than without
-    "keye": (dict(top_k=8, scoring="softmax"),
+    "keye": (dict(top_k=8, scoring="softmax"), (2048, 16, 128),
              dict(selection_bias=False), 2.213, 0.5),
+    # PR 37's entry: GB at ITS parent (the ladder, rows back to token
+    # order by a gather), which the kernel that brings them back may not
+    # pass
+    "smallthinker": (dict(top_k=6, scoring="softmax_of_picked",
+                          activation=jax.nn.relu), (2560, 8, 64),
+                     dict(selection_bias=False), 2.197, 0.0),
 }
 
 
+def _computations(text):
+    """``{name: its instructions}`` of compiled HLO text."""
+    found, name = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            name = line.split()[1 if line.startswith("ENTRY") else 0]
+            found[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            found[name].append(line.strip())
+    return found
+
+
 @pytest.mark.parametrize("cell", sorted(_EXPERT_LAYERS))
-def test_expert_layer_compiles_with_three_row_budgets(one_chip, cell):
+def test_expert_layer_compiles_with_three_row_budgets(one_chip, cell,
+                                                      monkeypatch):
     """One routed expert layer at an expert cell's widths (4,096 tokens a
-    call, 16 of 128 experts of 2,048 x 768 held, four calls mapped under
-    the layer's checkpoint that keeps the routing's integers), value and
+    call, an eighth of the experts held, four calls mapped under the
+    layer's checkpoint that keeps the routing's integers), value and
     gradient: ``jax.lax.switch`` over the three row budgets survives to
     the compiled program as ONE conditional of three branches forward and
     one backward (XLA neither flattened them into selects nor lost one),
     each branch holds its own three (forward) or nine (backward) grouped
-    products, and the compiler's reading of the layer's memory stays near
+    products and ONE kernel that brings the sorted rows back to token
+    order, and the compiler's reading of the layer's memory stays near
     the parent's: the branches share their inputs as residuals, and no
-    branch zero-fills another's."""
+    branch zero-fills another's.  Below the top rung nothing a branch
+    computes is as large as every pick's row (``N * k * d``): no gather,
+    relayout (``reshape f32[4096,6,d]`` was one at k = 6), copy or select
+    of them."""
+    from autodist_tpu.ops import rows_to_tokens
     from autodist_tpu.parallel import moe
 
-    call, init, parent_gb, over_gb = _EXPERT_LAYERS[cell]
+    call, (d, held, total), init, parent_gb, over_gb = _EXPERT_LAYERS[cell]
+    monkeypatch.setattr(rows_to_tokens, "_use_interpret", lambda: False)
     keep = jax.checkpoint_policies.save_only_these_names(
         *moe.ROUTING_RESIDUAL_NAMES)
-    assert moe.row_budgets(4096 * call["top_k"], 16, 128) == tuple(
-        4096 * call["top_k"] // part for part in (4, 2, 1))
+    picks = 4096 * call["top_k"]
+    assert moe.row_budgets(picks, held, total) == tuple(
+        picks // part for part in (4, 2, 1))
 
     @functools.partial(jax.checkpoint, policy=keep, prevent_cse=False)
     def one_call(params, x):
-        return moe.routed_moe_ffn(params, x, experts_held=(0, 16),
+        return moe.routed_moe_ffn(params, x, experts_held=(0, held),
                                   train_router=False, **call)[0]
 
     def loss(params, x):
@@ -326,10 +356,10 @@ def test_expert_layer_compiles_with_three_row_budgets(one_chip, cell):
             s.shape, s.dtype, sharding=one_chip), tree)
 
     params = jax.eval_shape(lambda: moe.init_routed_moe_params(
-        jax.random.key(0), 2048, 768, 128, experts_held=16, **init))
+        jax.random.key(0), d, 768, total, experts_held=held, **init))
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
         on_chip(params),
-        on_chip(jax.ShapeDtypeStruct((4, 4096, 2048), jnp.float32))
+        on_chip(jax.ShapeDtypeStruct((4, 4096, d), jnp.float32))
     ).compile()
     text = compiled.as_text()
     switches = re.findall(r" conditional\(.*branch_computations=\{([^}]*)\}",
@@ -338,6 +368,19 @@ def test_expert_layer_compiles_with_three_row_budgets(one_chip, cell):
     # three products forward, nine backward, a rung; ragged-dot-metadata
     # calls aside
     assert len(re.findall(r"%ragged-dot-none[\w.]* = ", text)) == 3 * (3 + 9)
+    computations = _computations(text)
+    for found in switches:
+        branches = [name.strip() for name in found.split(",")]
+        for name in branches:
+            assert sum('custom_call_target="tpu_custom_call"' in line
+                       and line.startswith("%rows_to_tokens")
+                       for line in computations[name]) == 1, name
+        for name in branches[:-1]:
+            for line in computations[name]:
+                result = line.split("=", 1)[1].split("(")[0]
+                for dims in re.findall(r"\w+\[([\d,]+)\]", result):
+                    assert math.prod(map(int, dims.split(","))) \
+                        < picks * d, line
     m = compiled.memory_analysis()
     need = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes) / 1e9

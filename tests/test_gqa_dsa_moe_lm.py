@@ -10,7 +10,6 @@ that the indexer's scores spread and a changed selection moves the loss by
 1e-3 or more; no seed here has two index scores within 1e-6 of a tie at
 the ``topk``-th place.
 """
-import collections
 import functools
 
 import jax
@@ -27,7 +26,6 @@ from autodist_tpu.models.gqa_dsa_moe_lm import (
     index_scores,
     select_keys,
 )
-from autodist_tpu.models.mla_moe_lm import equations
 from autodist_tpu.ops import flash_attention
 from autodist_tpu.ops.flash_attention import (
     pack_selection,
@@ -235,8 +233,7 @@ def test_backward_neither_scores_nor_selects_again():
         params = jax.eval_shape(spec.init, jax.random.key(0))
         jaxpr = jax.make_jaxpr(jax.grad(spec.loss_fn))(
             params, {"tokens": tokens(1)})
-        found = collections.Counter(e.primitive.name
-                                    for e in equations(jaxpr.jaxpr))
+        found = routed_cases.primitives(jaxpr.jaxpr)
         return found["pallas_call"], found["top_k"]
 
     # two layers: a forward and a backward kernel each; the routers' top-k
@@ -400,6 +397,17 @@ def test_compiled_rungs_match_the_reference(load):
 
 def test_gradient_holds_one_switch_a_direction_and_fills_no_rows():
     routed_cases.assert_gradient_switches_once_and_fills_no_rows("softmax")
+
+
+@pytest.mark.parametrize("load", [16, 32, 64])
+def test_rows_past_the_last_group_are_never_read(load):
+    routed_cases.assert_unwritten_rows_are_never_read("softmax", load)
+
+
+@pytest.mark.parametrize("top_k", [6, 8])
+def test_nothing_below_the_top_rung_is_as_wide_as_the_picks(top_k):
+    routed_cases.assert_nothing_below_the_top_rung_is_as_wide_as_the_picks(
+        "softmax", top_k)
 
 
 def test_dense_fallback_is_the_kernel():

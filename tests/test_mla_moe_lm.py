@@ -12,7 +12,6 @@ rotary turn, a norm) moves a leaf by 1e-2 or more.  No seed here has two
 scores within 1e-6 of a tie at the sixth place, so the selection itself is
 the same on both sides.
 """
-import collections
 import functools
 import sys
 
@@ -472,6 +471,17 @@ def test_gradient_holds_one_switch_a_direction_and_fills_no_rows():
     routed_cases.assert_gradient_switches_once_and_fills_no_rows("sigmoid")
 
 
+@pytest.mark.parametrize("load", [16, 32, 64])
+def test_rows_past_the_last_group_are_never_read(load):
+    routed_cases.assert_unwritten_rows_are_never_read("sigmoid", load)
+
+
+@pytest.mark.parametrize("top_k", [6, 8])
+def test_nothing_below_the_top_rung_is_as_wide_as_the_picks(top_k):
+    routed_cases.assert_nothing_below_the_top_rung_is_as_wide_as_the_picks(
+        "sigmoid", top_k)
+
+
 @pytest.mark.parametrize("rows,held,total,want", [
     (24576, 16, 128, (6144, 12288, 24576)),     # the kanana cell's call
     (32768, 16, 128, (8192, 16384, 32768)),     # the keye cell's
@@ -505,8 +515,7 @@ def remat_model(remat, monkeypatch, **kw):
 
 
 def count_primitives(jaxpr, names):
-    found = collections.Counter(eqn.primitive.name
-                                for eqn in equations(jaxpr))
+    found = routed_cases.primitives(jaxpr)
     return {name: found[name] for name in names}
 
 

@@ -12,7 +12,6 @@ fourth score within 1e-6 of each other) and a changed window, a rotary
 in the wrong layers or a router on another tensor moves the loss by 1e-3
 or more.
 """
-import collections
 import functools
 
 import jax
@@ -22,7 +21,7 @@ import optax
 import pytest
 
 from autodist_tpu.models.gqa_dsa_moe_lm import dense_selected_attention
-from autodist_tpu.models.mla_moe_lm import equations, rotary_halves
+from autodist_tpu.models.mla_moe_lm import rotary_halves
 from autodist_tpu.models.swa_moe_lm import (
     KEPT_NAMES,
     attended_pairs,
@@ -413,8 +412,7 @@ def test_backward_runs_no_kernel_twice_and_sorts_once():
         params = jax.eval_shape(spec.init, jax.random.key(0))
         jaxpr = jax.make_jaxpr(jax.grad(spec.loss_fn))(
             params, {"tokens": tokens(1)})
-        found = collections.Counter(e.primitive.name
-                                    for e in equations(jaxpr.jaxpr))
+        found = routed_cases.primitives(jaxpr.jaxpr)
         return found["pallas_call"], found["top_k"]
 
     assert count("none") == count("full") == (8, 4)
@@ -555,6 +553,18 @@ def test_every_rung_is_the_relu_gated_layer_of_the_reference(load):
         assert rel(flat(grads[0])[name], leaf) < RTOL, name
     assert rel(grads[1], want_grads[1]) < RTOL
     assert rel(grads[2], want_grads[2]) < RTOL
+
+
+@pytest.mark.parametrize("load", [16, 32, 64])
+def test_rows_past_the_last_group_are_never_read(load):
+    routed_cases.assert_unwritten_rows_are_never_read("softmax_of_picked",
+                                                      load)
+
+
+@pytest.mark.parametrize("top_k", [6, 8])
+def test_nothing_below_the_top_rung_is_as_wide_as_the_picks(top_k):
+    routed_cases.assert_nothing_below_the_top_rung_is_as_wide_as_the_picks(
+        "softmax_of_picked", top_k)
 
 
 # ---------------------------------------------------------------------------
