@@ -32,7 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from autodist_tpu.graph_item import GraphItem
 from autodist_tpu.kernel import sharding_utils as su
 from autodist_tpu.strategy.compiler import CompiledStrategy
-from autodist_tpu.telemetry import step_values
+from autodist_tpu.telemetry import step_values, timeline
 from autodist_tpu.utils import logging
 
 
@@ -458,30 +458,35 @@ class GraphTransformer:
                 # per-variable analog of the bucketed guard; frozen vars
                 # are excluded (their updates are masked to zero anyway).
                 from autodist_tpu.graph_item import path_name as _pn
-                health = guard_mod.HealthAccumulator(1)
-                for path, g in \
-                        jax.tree_util.tree_flatten_with_path(grads)[0]:
-                    if _pn(path) not in frozen_names:
-                        health.add(_pn(path), g)
-                inv_scale = jnp.float32(1.0) if scale is None \
-                    else jnp.float32(1.0) / scale
-                all_finite, gnorm, per_bucket = health.finalize(
-                    (), loss, inv_scale)
-                mult = inv_scale
-                clip = guard_mod.clip_multiplier(gnorm, num_cfg.clip_norm)
-                if clip is not None:
-                    mult = mult * clip
-                if clip is not None or scale is not None:
-                    grads = jax.tree_util.tree_map_with_path(
-                        lambda p, g: g if _pn(p) in frozen_names
-                        else (g.astype(jnp.float32) * mult).astype(g.dtype),
-                        grads)
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
-            if pad_info is not None:
-                # Keep pad rows exactly zero even for optimizers whose
-                # update is not zero-preserving (noise, non-zero decay).
-                params = su.mask_pad_tree(params, pad_info)
+                with jax.named_scope(timeline.SCOPE_STEP_GRAD_HEALTH):
+                    health = guard_mod.HealthAccumulator(1)
+                    for path, g in \
+                            jax.tree_util.tree_flatten_with_path(grads)[0]:
+                        if _pn(path) not in frozen_names:
+                            health.add(_pn(path), g)
+                    inv_scale = jnp.float32(1.0) if scale is None \
+                        else jnp.float32(1.0) / scale
+                    all_finite, gnorm, per_bucket = health.finalize(
+                        (), loss, inv_scale)
+                    mult = inv_scale
+                    clip = guard_mod.clip_multiplier(gnorm,
+                                                     num_cfg.clip_norm)
+                    if clip is not None:
+                        mult = mult * clip
+                    if clip is not None or scale is not None:
+                        grads = jax.tree_util.tree_map_with_path(
+                            lambda p, g: g if _pn(p) in frozen_names
+                            else (g.astype(jnp.float32)
+                                  * mult).astype(g.dtype),
+                            grads)
+            with jax.named_scope(timeline.SCOPE_STEP_OPTIMIZER):
+                updates, opt_state = optimizer.update(grads, opt_state,
+                                                      params)
+                params = optax.apply_updates(params, updates)
+                if pad_info is not None:
+                    # Keep pad rows exactly zero even for optimizers whose
+                    # update is not zero-preserving (noise, non-zero decay).
+                    params = su.mask_pad_tree(params, pad_info)
             # Fresh params return to their compute layout (all-gather for
             # WUS variables — "broadcast from the PS").
             params = su.constrain(params, param_sh)
@@ -490,9 +495,11 @@ class GraphTransformer:
             metrics = {"loss": loss}
             if num_active:
                 from autodist_tpu.numerics import loss_scale as _lsm
-                params = guard_mod.tree_select(all_finite, params, params_in)
-                opt_state = guard_mod.tree_select(all_finite, opt_state,
-                                                  opt_in)
+                with jax.named_scope(timeline.SCOPE_STEP_GRAD_HEALTH):
+                    params = guard_mod.tree_select(all_finite, params,
+                                                   params_in)
+                    opt_state = guard_mod.tree_select(all_finite, opt_state,
+                                                      opt_in)
                 new_ns = _lsm.update_state(ns, all_finite, num_ls)
                 sync_state = dict(sync_state)
                 sync_state[NUMERICS_KEY] = new_ns

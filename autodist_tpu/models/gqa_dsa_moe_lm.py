@@ -237,37 +237,43 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
     def features(params, tokens):
         """Final-norm activations ``[B, T, D]`` and the layers'
         ``tokens_per_expert`` ``[layers, count]``."""
-        x = jnp.take(params["embed"], tokens, axis=0)
-        if embed_scale != 1.0:
-            x = x * embed_scale
+        with jax.named_scope(timeline.SCOPE_LM_EMBED):
+            x = jnp.take(params["embed"], tokens, axis=0)
+            if embed_scale != 1.0:
+                x = x * embed_scale
         set_gauges(params, tokens, x)
         counts = []
-        for i in range(num_layers):
-            x, c = layer(params[f"layers_{i}"], x, as_run(halves_of(i)))
-            counts.append(c)
-        # here, outside the layers' maps and checkpoints
-        record_row_budgets(jnp.stack(counts), slices(x).shape[1] * top_k,
-                           num_experts)
-        return (rms_norm(x, params["ln_final"]["scale"], rms_eps),
-                [c.sum(axis=0) for c in counts])
+        with jax.named_scope(timeline.SCOPE_LM_LAYERS):
+            for i in range(num_layers):
+                x, c = layer(params[f"layers_{i}"], x, as_run(halves_of(i)))
+                counts.append(c)
+            # here, outside the layers' maps and checkpoints
+            record_row_budgets(jnp.stack(counts),
+                               slices(x).shape[1] * top_k, num_experts)
+        with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
+            feats = rms_norm(x, params["ln_final"]["scale"], rms_eps)
+        with jax.named_scope(timeline.SCOPE_LM_LAYERS):
+            return feats, [c.sum(axis=0) for c in counts]
 
     def apply_fn(params, tokens):
-        return jnp.einsum("btd,vd->btv", features(params, tokens)[0],
-                          params["head"])
+        feats = features(params, tokens)[0]
+        with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
+            return jnp.einsum("btd,vd->btv", feats, params["head"])
 
     def loss_fn(params, batch):
         tokens = batch["tokens"]
         feats, counts = features(params, tokens)
-        if xent_chunk:
-            from autodist_tpu.ops.chunked_xent import \
-                chunked_softmax_cross_entropy
+        with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
+            if xent_chunk:
+                from autodist_tpu.ops.chunked_xent import \
+                    chunked_softmax_cross_entropy
 
-            loss = chunked_softmax_cross_entropy(
-                feats[:, :-1], params["head"], tokens[:, 1:],
-                chunk=xent_chunk)
-        else:
-            logits = jnp.einsum("btd,vd->btv", feats, params["head"])
-            loss = cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
+                loss = chunked_softmax_cross_entropy(
+                    feats[:, :-1], params["head"], tokens[:, 1:],
+                    chunk=xent_chunk)
+            else:
+                logits = jnp.einsum("btd,vd->btv", feats, params["head"])
+                loss = cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
         if return_counts:
             return loss, {"tokens_per_expert": jnp.stack(counts)}
         return loss

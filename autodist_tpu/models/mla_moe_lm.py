@@ -264,7 +264,8 @@ def mla_moe_lm(vocab_size: int = 16032, num_layers: int = 5,
             attn_fn, theta=rope_theta, eps=rms_eps)
         h = rms_norm(x, lp["ln_mlp"]["scale"], rms_eps)
         if "mlp" in lp:
-            return x + swiglu(lp["mlp"], h), None
+            with jax.named_scope(timeline.SCOPE_FFN_DENSE):
+                return x + swiglu(lp["mlp"], h), None
         y, counts = routed_moe_ffn(lp["moe"], h, top_k=top_k,
                                    experts_held=held,
                                    routed_scale=routed_scale,
@@ -314,7 +315,8 @@ def mla_moe_lm(vocab_size: int = 16032, num_layers: int = 5,
     def features(params, tokens):
         """Final-norm activations ``[B, T, D]`` and the expert layers'
         ``tokens_per_expert`` ``[expert layers, count]``."""
-        x = jnp.take(params["embed"], tokens, axis=0)
+        with jax.named_scope(timeline.SCOPE_LM_EMBED):
+            x = jnp.take(params["embed"], tokens, axis=0)
         for name, held_bytes in kept_bytes(params, x).items():
             registry.gauge(
                 "autodist_remat_kept_bytes_per_step",
@@ -322,33 +324,38 @@ def mla_moe_lm(vocab_size: int = 16032, num_layers: int = 5,
                 "backward instead of recomputing, by the value's name",
                 {"name": name}).set(held_bytes)
         counts = []
-        for i in range(num_layers):
-            x, c = layer(params[f"layers_{i}"], x)
-            if c is not None:
-                counts.append(c)
-        if counts:     # here, outside the layers' maps and checkpoints
-            record_row_budgets(jnp.stack(counts), tokens.shape[1] * top_k,
-                               num_experts)
-        return (rms_norm(x, params["ln_final"]["scale"], rms_eps),
-                [c.sum(axis=0) for c in counts])
+        with jax.named_scope(timeline.SCOPE_LM_LAYERS):
+            for i in range(num_layers):
+                x, c = layer(params[f"layers_{i}"], x)
+                if c is not None:
+                    counts.append(c)
+            if counts:     # here, outside the layers' maps and checkpoints
+                record_row_budgets(jnp.stack(counts),
+                                   tokens.shape[1] * top_k, num_experts)
+        with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
+            feats = rms_norm(x, params["ln_final"]["scale"], rms_eps)
+        with jax.named_scope(timeline.SCOPE_LM_LAYERS):
+            return feats, [c.sum(axis=0) for c in counts]
 
     def apply_fn(params, tokens):
-        return jnp.einsum("btd,vd->btv", features(params, tokens)[0],
-                          params["head"])
+        feats = features(params, tokens)[0]
+        with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
+            return jnp.einsum("btd,vd->btv", feats, params["head"])
 
     def loss_fn(params, batch):
         tokens = batch["tokens"]
         feats, counts = features(params, tokens)
-        if xent_chunk:
-            from autodist_tpu.ops.chunked_xent import \
-                chunked_softmax_cross_entropy
+        with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
+            if xent_chunk:
+                from autodist_tpu.ops.chunked_xent import \
+                    chunked_softmax_cross_entropy
 
-            loss = chunked_softmax_cross_entropy(
-                feats[:, :-1], params["head"], tokens[:, 1:],
-                chunk=xent_chunk)
-        else:
-            logits = jnp.einsum("btd,vd->btv", feats, params["head"])
-            loss = cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
+                loss = chunked_softmax_cross_entropy(
+                    feats[:, :-1], params["head"], tokens[:, 1:],
+                    chunk=xent_chunk)
+            else:
+                logits = jnp.einsum("btd,vd->btv", feats, params["head"])
+                loss = cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
         if return_counts:
             return loss, {"tokens_per_expert": jnp.stack(counts)}
         return loss

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from autodist_tpu.telemetry import timeline
+
 
 @functools.cache
 def _resolve_default_attention() -> Callable:
@@ -79,10 +81,16 @@ class MultiHeadAttention(nn.Module):
         d = self.num_heads * self.head_dim
         proj = lambda name: nn.DenseGeneral(  # noqa: E731
             (self.num_heads, self.head_dim), use_bias=False, name=name)
-        q, k, v = proj("query")(x), proj("key")(x), proj("value")(x)
-        out = self.attn_fn(q, k, v, self.causal)
-        return nn.DenseGeneral(x.shape[-1], axis=(-2, -1), use_bias=False,
-                               name="out")(out)
+        with jax.named_scope(timeline.SCOPE_MHA_PROJECT):
+            q, k, v = proj("query")(x), proj("key")(x), proj("value")(x)
+        # the kernel's HLO name is the innermost scope, and the benchmark's
+        # readers find it by ``attn``
+        with jax.named_scope(timeline.SCOPE_MHA_ATTENTION), \
+                jax.named_scope("attn"):
+            out = self.attn_fn(q, k, v, self.causal)
+        with jax.named_scope(timeline.SCOPE_MHA_PROJECT):
+            return nn.DenseGeneral(x.shape[-1], axis=(-2, -1),
+                                   use_bias=False, name="out")(out)
 
 
 class MlpBlock(nn.Module):
@@ -90,9 +98,10 @@ class MlpBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        h = nn.Dense(self.d_ff, use_bias=False, name="wi")(x)
-        h = nn.gelu(h)
-        return nn.Dense(x.shape[-1], use_bias=False, name="wo")(h)
+        with jax.named_scope(timeline.SCOPE_FFN_DENSE):
+            h = nn.Dense(self.d_ff, use_bias=False, name="wi")(x)
+            h = nn.gelu(h)
+            return nn.Dense(x.shape[-1], use_bias=False, name="wo")(h)
 
 
 class TransformerLayer(nn.Module):
@@ -139,8 +148,10 @@ class TransformerStack(nn.Module):
             }[self.remat]
             layer_cls = nn.remat(TransformerLayer, policy=policy,
                                  prevent_cse=False)
-        for i in range(self.num_layers):
-            x = layer_cls(self.num_heads, self.head_dim, self.d_ff,
-                          self.causal, attn_fn=self.attn_fn,
-                          name=f"layers_{i}")(x)
-        return nn.LayerNorm(name="ln_final", use_bias=False)(x)
+        with jax.named_scope(timeline.SCOPE_LM_LAYERS):
+            for i in range(self.num_layers):
+                x = layer_cls(self.num_heads, self.head_dim, self.d_ff,
+                              self.causal, attn_fn=self.attn_fn,
+                              name=f"layers_{i}")(x)
+        with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
+            return nn.LayerNorm(name="ln_final", use_bias=False)(x)

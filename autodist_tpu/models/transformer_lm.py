@@ -15,11 +15,13 @@ from __future__ import annotations
 from typing import Callable, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from autodist_tpu.models.base import ModelSpec, cross_entropy_loss
 from autodist_tpu.models.transformer import TransformerStack, dense_attention
+from autodist_tpu.telemetry import timeline
 
 
 class TransformerLM(nn.Module):
@@ -47,14 +49,17 @@ class TransformerLM(nn.Module):
         """Pre-logits activations ``[B, T, D]`` — paired with the tied
         embedding through the chunked cross entropy when the training
         loss must not materialize ``[B, T, vocab]`` logits."""
-        x = (jnp.take(self.embed, tokens, axis=0)
-             + self.pos_embed[None, :tokens.shape[1]])
+        with jax.named_scope(timeline.SCOPE_LM_EMBED):
+            x = (jnp.take(self.embed, tokens, axis=0)
+                 + self.pos_embed[None, :tokens.shape[1]])
         return self.decoder(x)
 
     def __call__(self, tokens):
         # Tied output head: logits against the embedding table — keeps the
         # only vocab-sized variable the (sparse) embedding.
-        return jnp.einsum("btd,vd->btv", self.features(tokens), self.embed)
+        feats = self.features(tokens)
+        with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
+            return jnp.einsum("btd,vd->btv", feats, self.embed)
 
 
 def transformer_lm(vocab_size: int = 32128, num_layers: int = 12,
@@ -96,13 +101,16 @@ def transformer_lm(vocab_size: int = 32128, num_layers: int = 12,
         def loss_fn(params, batch):
             feats = model.apply({"params": params}, batch["tokens"],
                                 method=TransformerLM.features)
-            return chunked_softmax_cross_entropy(
-                feats[:, :-1], params["embed"], batch["tokens"][:, 1:],
-                chunk=xent_chunk)
+            with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
+                return chunked_softmax_cross_entropy(
+                    feats[:, :-1], params["embed"], batch["tokens"][:, 1:],
+                    chunk=xent_chunk)
     else:
         def loss_fn(params, batch):
             logits = apply_fn(params, batch["tokens"])
-            return cross_entropy_loss(logits[:, :-1], batch["tokens"][:, 1:])
+            with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
+                return cross_entropy_loss(logits[:, :-1],
+                                          batch["tokens"][:, 1:])
 
     def make_batch(rng: np.random.RandomState, batch_size: int):
         return {"tokens": rng.randint(

@@ -352,12 +352,25 @@ COMPILE_TRACE = "compile/trace"              # ids: fun_name
 COMPILE_LOWER = "compile/lower"
 COMPILE_BACKEND = "compile/backend"
 
-#: scopes INSIDE traced code (``jax.named_scope``, not host spans: they
-#: name the device operations of a step, and a Pallas call takes the last
-#: part as its HLO name): the parts of a latent-attention block
-#: (``models/mla_moe_lm.py``) and of the routed expert layer
-#: (``parallel/moe.py: routed_moe_ffn``).  ``benchmark/metrics/
-#: moe_routed_device_pct.py`` matches the ``moe/`` ones but ``moe/shared``.
+#: scopes INSIDE traced code (``jax.named_scope``, not host spans): the
+#: ONE list of names under which every device operation of a train step
+#: falls.  A scope is a string in the lowered operations' metadata (XLA's
+#: ``op_name``, the profiler's ``tf_op``) and costs a step nothing; a
+#: Pallas call takes the last part of the INNERMOST scope as its HLO name,
+#: so a kernel's own name (``attn``, ``sparse_attn``, ``window_attn``,
+#: ``global_attn``) is entered inside ``*/attention``.  An operation
+#: belongs to the innermost name of this list in its ``op_name``
+#: (``benchmark/step_scopes.py`` reads the list from here): what the
+#: layers' maps, checkpoints and autodiff add between the named parts
+#: reads ``lm/layers`` and nothing deeper.  Every model factory enters
+#: them (``tests/test_step_scopes.py`` walks the step's jaxpr); a new
+#: part of a model gets a new name HERE.
+SCOPE_LM_EMBED = "lm/embed"              # table lookup (and learned positions)
+SCOPE_LM_LAYERS = "lm/layers"            # the whole stack, outside the rest
+SCOPE_LM_HEAD_LOSS = "lm/head_loss"      # final norm, head, (chunked) loss
+SCOPE_FFN_DENSE = "ffn/dense"            # a dense FFN: SwiGLU or GELU MLP
+SCOPE_MHA_PROJECT = "mha/project"        # q, k, v, out of the flax block
+SCOPE_MHA_ATTENTION = "mha/attention"    # its attention call alone
 SCOPE_MLA_PROJECT = "mla/project"        # q, kv_a, kv_b, rotary, out
 SCOPE_MLA_ATTENTION = "mla/attention"    # the attention call alone
 SCOPE_GQA_PROJECT = "gqa/project"        # q, k, v, their norms, rotary, out
@@ -369,6 +382,8 @@ SCOPE_MOE_ROUTE = "moe/route"            # scores, top-k, sort, group sizes
 SCOPE_MOE_SHARED = "moe/shared"          # the shared experts (dense)
 SCOPE_MOE_EXPERTS = "moe/experts"        # gather, grouped products, SwiGLU
 SCOPE_MOE_COMBINE = "moe/combine"        # back to token order, weighted sum
+SCOPE_STEP_OPTIMIZER = "step/optimizer"  # optimizer.update, apply_updates
+SCOPE_STEP_GRAD_HEALTH = "step/grad_health"  # the guard: health, clip, select
 
 #: jax's monitoring events -> the span each becomes (and its ``stage``
 #: label on ``autodist_compile_seconds_total``).
