@@ -28,8 +28,9 @@ DeepSeek-V3.2-Exp report).  One layer, ``h = RMSNorm(x)``:
 * **Experts** (``parallel/moe.py: routed_moe_ffn``, ``scoring="softmax"``):
   top-``k`` of a softmax over all experts, weights renormalised over the
   picks, no bias, none shared, no token dropped; ``experts_held`` is this
-  chip's share.  The layer takes ``moe_slice`` tokens at a time: the
-  ``slice * k`` static rows of the grouped products are then a slice's.
+  chip's share.  One call a layer over all of a step's tokens, handed in
+  as slices of ``moe_slice`` tokens: no buffer of the layer is wider than
+  a slice's ``slice * k`` picks.
 
 Functional, like ``mla_moe_lm.py``; the training path only.
 """
@@ -160,26 +161,28 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
                    embed_scale: float = 1.0) -> ModelSpec:
     """What the decoders of this file and of ``swa_moe_lm.py`` share: the
     embedding, ``num_layers`` layers of an attention half (one sequence at
-    a time) and an expert half (``moe_slice`` tokens at a time), each
-    under its own checkpoint that keeps ``kept_names``, the final norm,
-    the (chunked) loss, the gauges of what the checkpoints keep and of the
-    row budgets, and the batch.  ``halves_of(i)``: layer ``i``'s
-    ``(attention_half(lp, x [1, T, D]) -> [1, T, D], expert_half(lp, part
-    [N, D]) -> (part, tokens_per_expert))``, the same functions for layers
-    of one kind.  ``router_reads_input``: the expert half is also handed
-    the layer's INPUT, slice by slice (``expert_half(lp, part, input_part)``:
-    a router placed before attention).  ``embed_scale``: the stream
+    a time) and an expert half (all of the step's tokens at once, as
+    slices of ``moe_slice``), each under its own checkpoint that keeps
+    ``kept_names``, the final norm, the (chunked) loss, the gauges of what
+    the checkpoints keep and of the row budgets, and the batch.
+    ``halves_of(i)``: layer ``i``'s ``(attention_half(lp, x [1, T, D]) ->
+    [1, T, D], expert_half(lp, parts [slices, slice, D]) -> (parts,
+    tokens_per_expert))``, the same functions for layers of one kind.
+    ``router_reads_input``: the expert half is also handed the layer's
+    INPUT, cut alike (``expert_half(lp, parts, input_parts)``: a router
+    placed before attention).  ``embed_scale``: the stream
     enters layer 0 as this times the table's rows.  ``set_pairs_gauges(
     tokens)``: the model's own gauges, set while tracing."""
     keep = jax.checkpoint_policies.save_only_these_names(*kept_names)
 
     @functools.cache
     def as_run(halves):
-        """A kind's halves under their checkpoints (both run under
-        ``lax.map``: no CSE barrier needed)."""
-        return halves if remat == "none" else tuple(
-            jax.checkpoint(f, policy=keep, prevent_cse=False)
-            for f in halves)
+        """A kind's halves under their checkpoints (the attention half
+        runs under ``lax.map``: no CSE barrier needed)."""
+        if remat == "none":
+            return halves
+        return (jax.checkpoint(halves[0], policy=keep, prevent_cse=False),
+                jax.checkpoint(halves[1], policy=keep))
 
     def slices(x):
         """``[B, T, D]`` as ``[n, moe_slice, D]``."""
@@ -190,7 +193,7 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
     def kept_bytes(params, x):
         """What the layers' checkpoints hold by name over a step of ``x
         [B, T, D]``: the tagged shapes of one sequence's attention half
-        and of one slice's expert half, times how many of each, over the
+        and of the step's expert half, times how many of each, over the
         layers (one trace a kind of layer)."""
         total = dict.fromkeys(kept_names, 0)
         if remat == "none":
@@ -202,8 +205,8 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
                 lp = params[f"layers_{i}"]
                 found[halves] = [
                     (named_bytes(halves[0], lp, x[:1]), x.shape[0]),
-                    (named_bytes(halves[1], lp, *[parts[0]] * (
-                        1 + router_reads_input)), parts.shape[0])]
+                    (named_bytes(halves[1], lp, *[parts] * (
+                        1 + router_reads_input)), 1)]
             for name in kept_names:
                 total[name] += sum(tagged.get(name, 0) * times
                                    for tagged, times in found[halves])
@@ -211,18 +214,13 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
 
     def layer(lp, x, halves):
         """``x [B, T, D]`` through one layer: attention one sequence at a
-        time, the experts one slice at a time (maps, not vmaps: the
-        expert layer's ``switch`` stays a branch).  Returns the slices'
-        ``tokens_per_expert`` ``[slices, count]`` beside ``x``."""
+        time, the experts once over all the tokens.  Returns the layer's
+        ``tokens_per_expert`` ``[count]`` beside ``x``."""
         attention_half, expert_half = halves
         entered = x
         x = jax.lax.map(lambda row: attention_half(lp, row[None])[0], x)
-        if router_reads_input:
-            y, counts = jax.lax.map(lambda part: expert_half(lp, *part),
-                                    (slices(x), slices(entered)))
-        else:
-            y, counts = jax.lax.map(lambda part: expert_half(lp, part),
-                                    slices(x))
+        y, counts = expert_half(lp, slices(x), *(
+            [slices(entered)] if router_reads_input else []))
         return y.reshape(x.shape), counts
 
     def set_gauges(params, tokens, x):
@@ -247,13 +245,12 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
             for i in range(num_layers):
                 x, c = layer(params[f"layers_{i}"], x, as_run(halves_of(i)))
                 counts.append(c)
-            # here, outside the layers' maps and checkpoints
-            record_row_budgets(jnp.stack(counts),
-                               slices(x).shape[1] * top_k, num_experts)
+            # here, outside the layers' checkpoints
+            record_row_budgets(jnp.stack(counts), tokens.size * top_k,
+                               num_experts, slices(x).shape[1] * top_k)
         with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
             feats = rms_norm(x, params["ln_final"]["scale"], rms_eps)
-        with jax.named_scope(timeline.SCOPE_LM_LAYERS):
-            return feats, [c.sum(axis=0) for c in counts]
+        return feats, counts
 
     def apply_fn(params, tokens):
         feats = features(params, tokens)[0]
@@ -314,9 +311,11 @@ def gqa_dsa_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
     ``attn_fn(q, k, v, True, selection=words, select_from=topk)`` (no
     keywords where the sequence is no longer than ``topk``); ``block_k``
     is the key block the words are packed for, the attention's own.
-    ``remat``: "none" | "full": the attention half and every ``moe_slice``
-    of the expert half of a layer are recomputed in the backward EXCEPT
-    what a kernel, a selection or a sort produced (``KEPT_NAMES``).
+    ``remat``: "none" | "full": the attention half and the expert half of
+    a layer are recomputed in the backward EXCEPT what a kernel, a
+    selection or a sort produced (``KEPT_NAMES``).  ``moe_slice``: no
+    buffer of the expert layer is wider than the picks of this many
+    tokens (``routed_moe_ffn``'s chunk).
     ``experts_held``, ``xent_chunk``, ``train_router``, ``return_counts``:
     as ``mla_moe_lm``."""
     if remat not in ("none", "full"):
@@ -404,8 +403,8 @@ def gqa_dsa_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
             return x + jnp.einsum("bthv,hvd->btd", o, p["wo"])
 
     def expert_half(lp, x):
-        """``x [N, D]`` plus its experts' output, and the tokens each held
-        expert was sent."""
+        """``x [slices, slice, D]`` plus its experts' output, and the
+        tokens each held expert was sent."""
         y, counts = routed_moe_ffn(
             lp["moe"], rms_norm(x, lp["ln_mlp"]["scale"], rms_eps),
             top_k=top_k, experts_held=held, train_router=train_router,

@@ -256,31 +256,45 @@ def mla_moe_lm(vocab_size: int = 16032, num_layers: int = 5,
     def operands(lp):
         return dict(lp, attn=attention_operands(lp["attn"], qk_nope))
 
-    def layer_fn(lp, x):
-        """One sequence ``[1, T, D]`` through one layer; ``lp``: the
+    def attention_half(lp, x):
+        """One sequence ``[1, T, D]`` plus its attention; ``lp``: the
         layer's leaves with the attention's as ``attention_operands``."""
-        x = x + latent_attention(
+        return x + latent_attention(
             lp["attn"], rms_norm(x, lp["ln_attn"]["scale"], rms_eps),
             attn_fn, theta=rope_theta, eps=rms_eps)
-        h = rms_norm(x, lp["ln_mlp"]["scale"], rms_eps)
-        if "mlp" in lp:
-            with jax.named_scope(timeline.SCOPE_FFN_DENSE):
-                return x + swiglu(lp["mlp"], h), None
-        y, counts = routed_moe_ffn(lp["moe"], h, top_k=top_k,
-                                   experts_held=held,
-                                   routed_scale=routed_scale,
-                                   train_router=train_router)
+
+    def dense_layer(lp, x):
+        """One sequence through a leading layer: attention and the dense
+        SwiGLU."""
+        x = attention_half(lp, x)
+        with jax.named_scope(timeline.SCOPE_FFN_DENSE):
+            return x + swiglu(lp["mlp"], rms_norm(
+                x, lp["ln_mlp"]["scale"], rms_eps))
+
+    def expert_half(lp, x):
+        """ALL the sequences ``[B, T, D]`` plus their experts' output, and
+        the tokens each held expert was sent."""
+        y, counts = routed_moe_ffn(
+            lp["moe"], rms_norm(x, lp["ln_mlp"]["scale"], rms_eps),
+            top_k=top_k, experts_held=held, routed_scale=routed_scale,
+            train_router=train_router)
         return x + y, counts
 
-    one_layer = layer_fn
-    if remat != "none":   # called under lax.map: no CSE barrier needed
-        layer_fn = jax.checkpoint(layer_fn, policy=_REMAT_POLICIES[remat],
-                                  prevent_cse=False)
+    def as_run(fn, mapped: bool):
+        """``fn`` under the layers' checkpoint (under ``lax.map`` no CSE
+        barrier is needed)."""
+        return fn if remat == "none" else jax.checkpoint(
+            fn, policy=_REMAT_POLICIES[remat], prevent_cse=not mapped)
+
+    run_attention, run_dense, run_experts = (
+        as_run(attention_half, True), as_run(dense_layer, True),
+        as_run(expert_half, False))
 
     def kept_bytes(params, x):
         """What the layers' checkpoints hold by name over a step of ``x
-        [B, T, D]``: the tagged shapes of one traced layer of each kind,
-        times sequences, times layers."""
+        [B, T, D]``: the tagged shapes of one sequence's attention (and
+        dense SwiGLU) times the sequences, and of the step's expert half,
+        over the layers (one trace a kind of layer)."""
         kept, kinds = dict.fromkeys(KEPT_NAMES, 0), {}
         if remat == "none":
             return kept
@@ -288,29 +302,38 @@ def mla_moe_lm(vocab_size: int = 16032, num_layers: int = 5,
             lp = params[f"layers_{i}"]
             dense = "mlp" in lp
             if dense not in kinds:
-                kinds[dense] = named_bytes(
-                    one_layer, jax.eval_shape(operands, lp), x[:1])
+                cut = jax.eval_shape(operands, lp)
+                kinds[dense] = [(named_bytes(
+                    dense_layer if dense else attention_half, cut, x[:1]),
+                    x.shape[0])]
+                if not dense:
+                    kinds[dense].append((named_bytes(expert_half, lp, x), 1))
             for name in KEPT_NAMES:
-                kept[name] += kinds[dense].get(name, 0) * x.shape[0]
+                kept[name] += sum(tagged.get(name, 0) * times
+                                  for tagged, times in kinds[dense])
         return kept
 
     def layer(lp, x):
-        """``x [B, T, D]`` through one layer, ONE SEQUENCE AT A TIME: what
-        a layer holds while it runs (queries, keys and values 192 and 128
-        wide in float32, the ``T * top_k`` sorted rows of the grouped
-        products and their cotangents) is then a sequence's, not the
-        batch's: 4 x 4096 tokens at the benchmark's widths ask for 20 GB
-        otherwise.  The price: each weight's gradient is summed over the
-        sequences instead of formed in one product.  The attention's
+        """``x [B, T, D]`` through one layer, attention (and a leading
+        layer's dense SwiGLU) ONE SEQUENCE AT A TIME: what attention holds
+        while it runs (queries, keys and values 192 and 128 wide in
+        float32) is then a sequence's, not the batch's: 4 x 4096 tokens at
+        the benchmark's widths ask for 20 GB otherwise.  The attention's
         weights are cut for their products here, once, and not under the
         map: inside it the cuts' transposes would pad and add weight-sized
-        buffers every sequence of the backward.  A map and not a vmap
-        for the expert layer's sake too: its ``switch`` stays a branch.
-        Returns the sequences' ``tokens_per_expert`` ``[B, count]`` (None
-        from a dense layer) beside ``x``."""
-        lp = operands(lp)
-        x, counts = jax.lax.map(lambda row: layer_fn(lp, row[None]), x)
-        return x[:, 0], counts
+        buffers every sequence of the backward.  The experts take ALL the
+        sequences in one call, outside the map and under a checkpoint of
+        their own: one sort a layer, each expert weight's gradient one
+        product, and no buffer wider than a sequence's picks
+        (``routed_moe_ffn``'s chunk).  Returns the layer's
+        ``tokens_per_expert`` ``[count]`` (None from a dense layer) beside
+        ``x``."""
+        cut = operands(lp)
+        if "mlp" in lp:
+            return jax.lax.map(lambda row: run_dense(cut, row[None])[0],
+                               x), None
+        x = jax.lax.map(lambda row: run_attention(cut, row[None])[0], x)
+        return run_experts(lp, x)
 
     def features(params, tokens):
         """Final-norm activations ``[B, T, D]`` and the expert layers'
@@ -329,13 +352,12 @@ def mla_moe_lm(vocab_size: int = 16032, num_layers: int = 5,
                 x, c = layer(params[f"layers_{i}"], x)
                 if c is not None:
                     counts.append(c)
-            if counts:     # here, outside the layers' maps and checkpoints
-                record_row_budgets(jnp.stack(counts),
-                                   tokens.shape[1] * top_k, num_experts)
+            if counts:     # here, outside the layers' checkpoints
+                record_row_budgets(jnp.stack(counts), tokens.size * top_k,
+                                   num_experts, tokens.shape[1] * top_k)
         with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
             feats = rms_norm(x, params["ln_final"]["scale"], rms_eps)
-        with jax.named_scope(timeline.SCOPE_LM_LAYERS):
-            return feats, [c.sum(axis=0) for c in counts]
+        return feats, counts
 
     def apply_fn(params, tokens):
         feats = features(params, tokens)[0]

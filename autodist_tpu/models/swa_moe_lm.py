@@ -166,9 +166,9 @@ def swa_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
         return attention_half
 
     def expert_half(lp, x, entered=None):
-        """``x [N, D]`` plus its experts' output, and the tokens each held
-        expert was sent; ``entered``: the same tokens as they entered the
-        layer, which the router reads."""
+        """``x [slices, slice, D]`` plus its experts' output, and the
+        tokens each held expert was sent; ``entered``: the same tokens as
+        they entered the layer, which the router reads."""
         y, counts = routed_moe_ffn(
             lp["moe"], rms_norm(x, lp["ln_mlp"]["scale"], rms_eps),
             top_k=top_k, experts_held=held, train_router=train_router,

@@ -277,9 +277,9 @@ def routed_rows(tokens: int, top_k: int, held: int, total: int):
     """(rows the grouped products are handed AT MOST, rows expected to be
     routed here) for one call of :func:`routed_moe_ffn` over ``tokens``
     tokens: every pick of every token can have its row, because all of a
-    token's picks may lie here (the top rung of :func:`row_budgets`);
+    token's picks may lie here (every chunk of the sorted order taken);
     ``held / total`` of them do if the router spreads evenly.  What a call
-    IS handed is the rung its routing takes (:func:`budgets_taken`)."""
+    IS handed is the chunks its routing takes (:func:`budgets_taken`)."""
     return tokens * top_k, tokens * top_k * held / total
 
 
@@ -287,63 +287,60 @@ def routed_rows(tokens: int, top_k: int, held: int, total: int):
 _GROUPED_TILE = 512
 
 
-def row_budgets(rows: int, held: int, total: int) -> Tuple[int, ...]:
-    """The ladder of static row budgets of one call of
-    :func:`routed_moe_ffn` over ``rows = tokens * top_k`` picks, from the
-    shapes alone: twice and four times the ``held / total`` of them an
-    even router sends here, then all of them: at most three rungs,
-    ascending, the last always ``rows`` (the budget that holds whatever the
-    routing: no capacity).  A rung is a whole number of the grouped
-    product's 512-row tiles where ``rows`` is (of 8 rows otherwise).  All
-    experts held, or half: ``(rows,)``."""
+def chunk_rows(rows: int, held: int, total: int,
+               cap: Optional[int] = None) -> int:
+    """``C``: the places of the sorted order one chunk of a call of
+    :func:`routed_moe_ffn` over ``rows = tokens * top_k`` picks takes, from
+    the shapes alone: twice the ``held / total`` of them an even router
+    sends here, at most ``cap`` (the picks of one slice or sequence of the
+    call: no buffer of the layer is wider) and at most ``rows``, a whole
+    number of the grouped product's 512-row tiles where ``rows`` is (of 8
+    rows otherwise).  All experts held, or half: ``min(cap, rows)``."""
     tile = _GROUPED_TILE if rows % _GROUPED_TILE == 0 else 8
-    rungs = {-(-(factor * rows * held) // (total * tile)) * tile
-             for factor in (2, 4)}
-    return tuple(sorted(c for c in rungs if c < rows)) + (rows,)
+    chunk = min(-(-2 * rows * held // total), rows if cap is None else cap)
+    return min(-(-chunk // tile) * tile, rows)
 
 
-def _rung(rungs, routed):
-    """Index of the smallest of ``rungs`` that holds ``routed`` rows
-    (any shape of counts)."""
-    return jnp.sum(jnp.asarray(routed)[..., None]
-                   > jnp.asarray(rungs[:-1], jnp.int32), axis=-1)
-
-
-def budgets_taken(tokens_per_expert: jax.Array, rows: int, total: int
+def budgets_taken(tokens_per_expert: jax.Array, rows: int, total: int,
+                  cap: Optional[int] = None
                   ) -> Tuple[Tuple[int, ...], jax.Array]:
-    """``(rungs, calls [len(rungs)] int32)``: the ladder of calls of
-    :func:`routed_moe_ffn` over ``rows`` picks each, and how many of the
-    calls whose ``tokens_per_expert`` are stacked in ``[..., count]`` took
-    each rung.  The same rule the layer applies to the same integers: a
-    model sums this over its calls OUTSIDE their checkpoints and maps."""
-    rungs = row_budgets(rows, tokens_per_expert.shape[-1], total)
-    rung = _rung(rungs, tokens_per_expert.sum(axis=-1)).reshape(-1)
-    return rungs, jnp.sum(rung[:, None] == jnp.arange(len(rungs)), axis=0,
+    """``(rungs, calls [len(rungs)] int32)``: the rows the chunks of a call
+    of :func:`routed_moe_ffn` over ``rows`` picks can cover (``C, 2 C, ..``
+    up to every pick; ``cap`` as :func:`chunk_rows` takes it), and how
+    many of the calls whose ``tokens_per_expert`` are stacked in ``[...,
+    count]`` covered each.  The same rule the layer applies to the same
+    integers: a model sums this over its calls OUTSIDE their checkpoints
+    and maps."""
+    chunk = chunk_rows(rows, tokens_per_expert.shape[-1], total, cap)
+    rungs = tuple(range(chunk, rows + chunk, chunk))
+    taken = _further_chunks(tokens_per_expert.sum(axis=-1), chunk).reshape(-1)
+    return rungs, jnp.sum(taken[:, None] == jnp.arange(len(rungs)), axis=0,
                           dtype=jnp.int32)
 
 
 _ROWS_HELP = ("rows the grouped expert products were handed in the last "
-              "step (the row budgets its calls took), and rows an even "
-              "router would send here")
+              "step (the chunks of the sorted order its calls took), and "
+              "rows an even router would send here")
 
 
-def record_row_budgets(tokens_per_expert: jax.Array, rows: int, total: int
-                       ) -> None:
+def record_row_budgets(tokens_per_expert: jax.Array, rows: int, total: int,
+                       cap: Optional[int] = None) -> None:
     """For a model, once a step, from the top level of its loss function:
     ``tokens_per_expert [..., count]`` of ALL the step's calls of
-    :func:`routed_moe_ffn` over ``rows`` picks each, stacked outside their
-    checkpoints and maps.  Sets ``autodist_moe_rows_per_step{kind=
-    "expected"}`` now, while tracing, and emits the step's calls by rung
-    as a step value (``telemetry/step_values.py``: out with the step's
-    metrics, no host callback, so the step program stays in the
-    persistent compilation cache): after every step a session fetched,
-    ``{kind="computed"}`` is the rows of the budgets that step's calls
-    took and ``autodist_moe_row_budget_calls_total{rung=<rows>}`` has
-    counted them.  The loss function is to be marked ``step_values.
-    reporting``."""
+    :func:`routed_moe_ffn` over ``rows`` picks each (``cap``: the picks of
+    one slice or sequence of a call, as :func:`chunk_rows` takes it),
+    stacked outside their checkpoints and maps.  Sets
+    ``autodist_moe_rows_per_step{kind="expected"}`` now, while tracing, and
+    emits the step's calls by the rows their chunks covered as a step value
+    (``telemetry/step_values.py``: out with the step's metrics, no host
+    callback, so the step program stays in the persistent compilation
+    cache): after every step a session fetched, ``{kind="computed"}`` is
+    the rows of the chunks that step's calls took and
+    ``autodist_moe_row_budget_calls_total{rung=<rows>}`` has counted them.
+    The loss function is to be marked ``step_values.reporting``."""
     from autodist_tpu.telemetry import registry, step_values
 
-    rungs, calls = budgets_taken(tokens_per_expert, rows, total)
+    rungs, calls = budgets_taken(tokens_per_expert, rows, total, cap)
     # calls x rows x held / total, and size = calls x held
     registry.gauge("autodist_moe_rows_per_step", _ROWS_HELP,
                    {"kind": "expected"}).set(
@@ -357,8 +354,9 @@ def record_row_budgets(tokens_per_expert: jax.Array, rows: int, total: int
         for taken, rung in zip(calls, rungs):
             registry.counter(
                 "autodist_moe_row_budget_calls_total",
-                "calls of the routed expert layer by the row budget they "
-                "took", {"rung": str(rung)}).inc(taken)
+                "calls of the routed expert layer by the rows of the "
+                "sorted order their chunks covered", {"rung": str(rung)}
+            ).inc(taken)
 
     step_values.emit("moe_row_budget_calls", calls, publish)
 
@@ -372,107 +370,159 @@ def _grouped_swiglu(experts, rows, sizes, activation=jax.nn.silu):
     return jax.lax.ragged_dot(hidden, experts["w_down"], sizes)
 
 
-def _sorted_rows(budget: int, top_k: int, h, order, sizes):
-    """The first ``budget`` places of the sorted order: ``(their tokens'
-    rows of h or zeros past the last group [budget, d], which of them hold
-    a pick routed here [budget, 1], their picks [budget], their tokens
-    [budget])``."""
-    index = order[:budget]
-    token = index // top_k
-    live = (jnp.arange(budget) < sizes.sum())[:, None]
-    rows = jnp.where(live, jnp.take(h, token, axis=0), 0)
-    return rows, live, index, token
+def _further_chunks(routed, chunk: int):
+    """Chunks of ``chunk`` places past the first that ``routed`` rows of
+    the sorted order reach into (any shape of counts)."""
+    return jnp.maximum(-(-jnp.asarray(routed) // chunk) - 1, 0)
 
 
-def _experts_on(budget: int, top_k: int, activation, h, experts, weight,
-                order, inverse, sizes, here):
-    """The held experts' part of the layer on the first ``budget`` rows of
-    the sorted order, which hold every pick routed here (``sizes.sum() <=
-    budget``), back in token order and summed over the picks: ``[N, d]``.
-    The rows that hold a pick are read once, each times its pick's weight,
-    and every token is written once (``ops/rows_to_tokens.py``): nothing
-    is ``N * top_k`` rows wide, a pick that is not held here is no row at
-    all, and what lies past the last group, which a grouped product leaves
-    UNWRITTEN, is never read.  A token's terms are added in the order of
-    their experts, on every rung."""
+def _sorted_rows(chunk: int, top_k: int, start, h, order, sizes):
+    """Places ``[start, start + chunk)`` of the sorted order: ``(their
+    tokens' rows of h [chunk, d], which of them hold a pick routed here
+    [chunk, 1], their tokens [chunk], the rows of each group that lie
+    among them [count], how many of them are live)``.  A place past the
+    last group holds the row of some pick that is not held here: the
+    grouped products leave such rows alone, so they are not cleared (a
+    pass over ``[chunk, d]`` of its own)."""
+    token = jax.lax.dynamic_slice_in_dim(order, start, chunk) // top_k
+    ends = jnp.cumsum(sizes)
+    within = jnp.clip(jnp.minimum(ends, start + chunk)
+                      - jnp.maximum(ends - sizes, start), 0)
+    count = within.sum()
+    live = (jnp.arange(chunk) < count)[:, None]
+    # every place names a token: nothing to clamp or fill
+    return (h.at[token].get(mode="promise_in_bounds"), live, token, within,
+            count)
+
+
+def _padded(order, chunk: int):
+    """``order`` with places added up to a whole number of chunks (never
+    live: they name pick 0)."""
+    return jnp.pad(order, (0, -order.shape[0] % chunk))
+
+
+def _in_sorted_order(keys, values, chunk: int):
+    """``values`` (one number a pick) moved to where ``keys`` (a
+    permutation of the places) puts each, and padded to whole chunks: one
+    sort of pairs.  (A gather of single numbers out of an array of a
+    hundred thousand costs a TPU 25 ns each: 3 ms a call for the sort's
+    0.1.)"""
+    return _padded(jax.lax.sort_key_val(keys, values.reshape(-1))[1], chunk)
+
+
+def _experts_on(chunk: int, top_k: int, activation, start, onto, h, experts,
+                scale, order, sizes):
+    """The held experts' part of the layer on places ``[start, start +
+    chunk)`` of the sorted order (``scale``: the picks' weights in that
+    order), back in token order and summed over the picks onto ``onto``
+    (None: zeros): ``[N, d]``.  The rows that hold a pick are read once,
+    each times its pick's weight, and every token is written once
+    (``ops/rows_to_tokens.py``): nothing is ``N * top_k`` rows wide, a pick
+    that is not held here is no row at all, and what lies past the last
+    group, which a grouped product leaves UNWRITTEN, is never read.  A
+    token's terms are added in the order of their experts, one after the
+    other, however many chunks they lie in."""
     from autodist_tpu.telemetry import timeline
 
     with jax.named_scope(timeline.SCOPE_MOE_EXPERTS):
-        rows, _, index, token = _sorted_rows(budget, top_k, h, order, sizes)
-        out = _grouped_swiglu(experts, rows, sizes, activation)
+        rows, _, token, within, count = _sorted_rows(
+            chunk, top_k, start, h, order, sizes)
+        out = _grouped_swiglu(experts, rows, within, activation)
     with jax.named_scope(timeline.SCOPE_MOE_COMBINE):
-        return rows_to_tokens(out, token, jnp.take(weight.reshape(-1), index),
-                              sizes.sum(), h.shape[0])
+        return rows_to_tokens(
+            out, token, jax.lax.dynamic_slice_in_dim(scale, start, chunk),
+            count, h.shape[0], onto=onto)
 
 
-def _experts_on_transposed(budget: int, top_k: int, activation, g, h,
-                           experts, weight, order, inverse, sizes, here):
-    """The cotangents of ``(h, experts, weight)`` under :func:`_experts_on`
-    for the cotangent ``g [N, d]`` of its result, on ``budget`` rows too:
-    the cotangent of a sorted row is its token's row of ``g`` times its
-    pick's weight, a pick's weight takes the dot of its sorted row with its
-    token's ``g`` (one number a pick, gathered back), and the cotangent of
-    ``h`` is the sorted rows' summed by token as the forward's are."""
+def _experts_on_transposed(chunk: int, top_k: int, activation, start, onto,
+                           g, h, experts, scale, order, sizes):
+    """The cotangents under :func:`_experts_on` for the cotangent ``g [N,
+    d]`` of its result, on the same ``chunk`` places: ``(of h, added onto
+    ``onto`` (None: zeros); of the experts; the dot of each sorted row with
+    its token's g [chunk])``.  The cotangent of a sorted row is its token's
+    row of ``g`` times its pick's weight, a pick's weight takes the dot of
+    its sorted row with its token's ``g`` (one number a pick), and the
+    cotangent of ``h`` is the sorted rows' summed by token as the
+    forward's are."""
     from autodist_tpu.telemetry import timeline
 
     with jax.named_scope(timeline.SCOPE_MOE_EXPERTS):
-        rows, live, index, token = _sorted_rows(budget, top_k, h, order,
-                                                sizes)
+        rows, live, token, within, count = _sorted_rows(
+            chunk, top_k, start, h, order, sizes)
         out, transpose = jax.vjp(
-            lambda experts, rows: _grouped_swiglu(experts, rows, sizes,
+            lambda experts, rows: _grouped_swiglu(experts, rows, within,
                                                   activation),
             experts, rows)
     with jax.named_scope(timeline.SCOPE_MOE_COMBINE):
-        g_rows = jnp.take(g, token, axis=0)
-        d_out = jnp.where(live, g_rows * jnp.take(
-            weight.reshape(-1), index)[:, None], 0)
+        g_rows = g.at[token].get(mode="promise_in_bounds")
+        d_out = jnp.where(live, g_rows * jax.lax.dynamic_slice_in_dim(
+            scale, start, chunk)[:, None], 0)
         dots = jnp.sum(jnp.where(live, out, 0) * g_rows, axis=-1)
-        # a pick past the budget is not held here: any place will do
-        d_weight = jnp.where(here, jnp.take(dots, jnp.minimum(
-            inverse, budget - 1)).reshape(here.shape), 0)
     with jax.named_scope(timeline.SCOPE_MOE_EXPERTS):
         d_experts, d_rows = transpose(d_out)
-        d_h = rows_to_tokens(d_rows, token, jnp.ones_like(dots), sizes.sum(),
-                             h.shape[0])
-    return d_h, d_experts, d_weight
-
-
-def _switch(rungs, sizes, branch, *operands):
-    """``branch(budget)(*operands)`` for the smallest of ``rungs`` that
-    holds ``sizes.sum()`` rows, chosen on the device (one rung: no
-    ``switch``, the branch itself)."""
-    return jax.lax.switch(_rung(rungs, sizes.sum()),
-                          [branch(c) for c in rungs], *operands)
+        d_h = rows_to_tokens(d_rows, token, jnp.ones_like(dots), count,
+                             h.shape[0], onto=onto)
+    return d_h, d_experts, dots
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _budgeted_experts(top_k: int, rungs, activation, h, experts, weight,
-                      order, inverse, sizes, here):
-    """:func:`_experts_on` the smallest of ``rungs`` that holds the picks
-    routed here.  Differentiated as written, ``switch`` would make every
-    branch hand back every branch's residuals, zeros for those not taken:
-    each call would write the top rung's as zeros.  So the residuals are
-    the INPUTS, which the branches share, and the backward switches on the
-    same rung (recomputed from ``sizes``) and runs that branch's forward
-    again and its transpose inside it."""
-    return _switch(rungs, sizes, lambda c: functools.partial(
-        _experts_on, c, top_k, activation), h, experts, weight, order,
-        inverse, sizes, here)
+def _chunked_experts(top_k: int, chunk: int, activation, h, experts, weight,
+                     order, inverse, sizes, here):
+    """:func:`_experts_on` the first ``chunk`` places of the sorted order
+    and, where the picks routed here reach past them, on each further
+    ``chunk`` places in turn, by a loop whose trip count is read on the
+    device (``sizes.sum()``): none in the common call.  Such a loop cannot
+    be differentiated through, and a chunk's residuals would be as wide as
+    the chunk: so the residuals are the INPUTS, and the backward walks the
+    same chunks (the count recomputed from ``sizes``), running each one's
+    forward again and its transpose."""
+    from autodist_tpu.telemetry import timeline
+
+    with jax.named_scope(timeline.SCOPE_MOE_COMBINE):
+        scale = _in_sorted_order(inverse, weight, chunk)
+    order = _padded(order, chunk)
+    on = functools.partial(_experts_on, chunk, top_k, activation)
+    return jax.lax.fori_loop(
+        1, 1 + _further_chunks(sizes.sum(), chunk),
+        lambda i, y: on(i * chunk, y, h, experts, scale, order, sizes),
+        on(0, None, h, experts, scale, order, sizes))
 
 
-def _budgeted_experts_fwd(top_k, rungs, activation, *operands):
-    return _budgeted_experts(top_k, rungs, activation, *operands), operands
+def _chunked_experts_fwd(top_k, chunk, activation, *operands):
+    return _chunked_experts(top_k, chunk, activation, *operands), operands
 
 
-def _budgeted_experts_bwd(top_k, rungs, activation, operands, g):
+def _chunked_experts_bwd(top_k, chunk, activation, operands, g):
+    from autodist_tpu.telemetry import timeline
+
+    h, experts, weight, order, inverse, sizes, here = operands
+    with jax.named_scope(timeline.SCOPE_MOE_COMBINE):
+        scale = _in_sorted_order(inverse, weight, chunk)
+    places = order.shape[0]
+    order = _padded(order, chunk)
+    on = functools.partial(_experts_on_transposed, chunk, top_k, activation)
+
+    def further(i, carry):
+        d_h, d_experts, dots = carry
+        d_h, more, part = on(i * chunk, d_h, g, h, experts, scale, order,
+                             sizes)
+        return (d_h, jax.tree_util.tree_map(jnp.add, d_experts, more),
+                jax.lax.dynamic_update_slice_in_dim(dots, part, i * chunk, 0))
+
+    d_h, d_experts, part = on(0, None, g, h, experts, scale, order, sizes)
+    d_h, d_experts, dots = jax.lax.fori_loop(
+        1, 1 + _further_chunks(sizes.sum(), chunk), further,
+        (d_h, d_experts, jnp.pad(part, (0, order.shape[0] - chunk))))
+    with jax.named_scope(timeline.SCOPE_MOE_COMBINE):
+        # one number a pick, back in the picks' order; a pick that is not
+        # held here lies past the last group: whatever stands there
+        d_weight = jnp.where(here, jax.lax.sort_key_val(
+            order[:places], dots[:places])[1].reshape(here.shape), 0)
     # h, the experts' leaves and the weights; no cotangent for the integers
-    sizes = operands[5]
-    return _switch(rungs, sizes, lambda c: functools.partial(
-        _experts_on_transposed, c, top_k, activation), g, *operands) \
-        + (None,) * 4
+    return (d_h, d_experts, d_weight) + (None,) * 4
 
 
-_budgeted_experts.defvjp(_budgeted_experts_fwd, _budgeted_experts_bwd)
+_chunked_experts.defvjp(_chunked_experts_fwd, _chunked_experts_bwd)
 
 
 def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
@@ -521,26 +571,32 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
     same experts before any balancing could act.
 
     Static shapes without a capacity: the ``N * k`` (token, pick) pairs
-    are sorted by local expert, the picks of absent experts last.  The
-    first ``C`` rows of that order are gathered and go through three
-    grouped products (``jax.lax.ragged_dot``, on a TPU one Mosaic kernel
-    each that leaves the row tiles past the last group alone), then are
-    added, each times its pick's weight, to their tokens' rows by one
-    kernel that reads those ``C`` rows and writes the ``N`` tokens once
-    (``ops/rows_to_tokens.py``; the backward's cotangent of ``x`` alike).
-    ``C`` is the smallest rung of :func:`row_budgets` (from the shapes
-    alone: twice and four times what an even router sends here, then
-    ``N * k``) that holds the picks routed here, chosen ON THE DEVICE from
-    ``sizes.sum()`` by one ``jax.lax.switch``, forward and backward alike.
-    Whatever the routing, every pick of a held expert is computed: with
-    all tokens on the held experts the call takes the top rung and the
-    groups fill all ``N * k`` rows.  Nothing but the routing chooses a
-    rung.  CALL THIS UNDER ``jax.lax.map``, NOT ``jax.vmap``: batched, a
-    ``switch`` becomes a ``select`` and every rung runs.  A budget is a
-    call's: a heavy sequence costs its own call one step of the ladder.
+    of ALL the call's tokens are sorted by local expert, the picks of
+    absent experts last.  The first ``C`` places of that order are gathered
+    and go through three grouped products (``jax.lax.ragged_dot``, on a TPU
+    one Mosaic kernel each that leaves the row tiles past the last group
+    alone), then are added, each times its pick's weight, to their tokens'
+    rows by one kernel that reads those ``C`` rows and writes the ``N``
+    tokens once (``ops/rows_to_tokens.py``; the backward's cotangent of
+    ``x`` alike).  ``C`` is :func:`chunk_rows`, from the shapes alone:
+    twice what an even router sends here, and no more than the picks of
+    ``x.shape[-2]`` tokens: hand the layer ``[slices, slice, d]`` or ``[B,
+    T, d]`` and no buffer of it is wider than one slice's or sequence's
+    picks, whatever the routing.  Where more than ``C`` picks are routed
+    here, a loop whose trip count is read ON THE DEVICE (``ceil(sizes.sum()
+    / C) - 1`` further turns) takes the next ``C`` places of the order in
+    turn, each with its own cut of the group sizes, and adds its part onto
+    the tokens; the hand-written backward walks the same chunks.  What is
+    one NUMBER a pick (the weights into sorted order, their cotangents
+    back, the picked scores) moves by a sort of pairs or a comparison with
+    every expert, never by a gather of single numbers.  So every
+    pick of a held expert is computed: with all tokens on the held experts
+    the call takes all ``N * k / C`` chunks.  Nothing but the routing
+    chooses how many.  Call it ONCE for all of a step's tokens: each call
+    sorts once, and each expert weight's gradient is one product a chunk.
 
     Returns ``(y, tokens_per_expert [count] int32)``;
-    :func:`budgets_taken` of the second says which rung the call took.
+    :func:`budgets_taken` of the second says which chunks the call took.
     """
     from autodist_tpu.telemetry import registry, timeline
 
@@ -579,7 +635,13 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
             ranked = scores + jax.lax.stop_gradient(params["router_bias"])
         _, chosen = jax.lax.top_k(ranked, top_k)
         chosen = checkpoint_name(chosen, ROUTING_RESIDUAL_NAMES[0])
-        picked = jnp.take_along_axis(scores, chosen, axis=-1)   # [N, k]
+        # the picks' scores [N, k] by a comparison with every expert and a
+        # sum whose terms but one are zero: exact, value and transpose; as
+        # a gather of single numbers and its scatter-add a TPU takes ~10 ns
+        # a pick for each
+        picked = jnp.sum(jnp.where(
+            chosen[..., None] == jnp.arange(total), scores[:, None], 0),
+            axis=-1)
         if scoring == "softmax_of_picked":
             gates = routed_scale * jax.nn.softmax(picked, axis=-1)
         else:
@@ -596,8 +658,10 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
     with jax.named_scope(timeline.SCOPE_MOE_COMBINE):
         weight = jnp.where(here, gates, 0.0).astype(h.dtype)
 
-    y = _budgeted_experts(
-        top_k, row_budgets(n * top_k, count, total), activation, h,
+    y = _chunked_experts(
+        top_k, chunk_rows(n * top_k, count, total,
+                          x.shape[-2] * top_k if x.ndim > 1 else None),
+        activation, h,
         jax.tree_util.tree_map(lambda w: w.astype(h.dtype), experts),
         weight, order, inverse, sizes, here)
 

@@ -511,21 +511,22 @@ def train_moe_phase(model: dict, *, batch_size: int, steps: int) -> dict:
     facts["remat_kept_bytes"] = {
         m.labels["name"]: int(m.value) for m in DEFAULT_REGISTRY.metrics()
         if m.name == "autodist_remat_kept_bytes_per_step"}
-    facts["row_budgets"] = forced_router_takes_the_top_rung(cfg)
+    facts["chunks"] = forced_router_takes_every_chunk(cfg)
     del sess, ad
     _reset_default_autodist_for_testing()
     gc.collect()
     return facts
 
 
-def forced_router_takes_the_top_rung(cfg: dict, tokens: int = 512) -> dict:
+def forced_router_takes_every_chunk(cfg: dict, tokens: int = 512) -> dict:
     """One routed layer at the model's widths over ``tokens`` tokens, twice:
-    under the router as seeded (an even one: the low rung of the ladder of
-    row budgets holds its picks) and under a selection bias that sends
-    every pick to the held experts (the top rung: every pick has its row).
-    Both against the layer written out plainly (every held expert over
-    every token, weighted by the router's weights), within what a bfloat16
-    pass of three products leaves."""
+    under the router as seeded (an even one: the first chunk of the sorted
+    order holds its picks and the loop over further chunks makes no turn)
+    and under a selection bias that sends every pick to the held experts
+    (every chunk: every pick has its row).  Both against the layer written
+    out plainly (every held expert over every token, weighted by the
+    router's weights), within what a bfloat16 pass of three products
+    leaves."""
     import jax
     import jax.numpy as jnp
 
@@ -570,24 +571,24 @@ def forced_router_takes_the_top_rung(cfg: dict, tokens: int = 512) -> dict:
     def gap(got, want):
         return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
 
-    rungs = moe.row_budgets(tokens * top_k, count, total)
-    facts = {"rungs": list(rungs)}
+    facts = {}
     for name, bias in biases.items():
         p = dict(params, router_bias=bias)
         d_x, (y, counts) = with_gradient(layer)(p, x)
         want_d_x, (want, _) = with_gradient(lambda p, x: (plain(p, x), 0))(
             p, x)
         gaps = gap(y, want), gap(d_x, want_d_x)
-        taken = rungs[int(jnp.argmax(moe.budgets_taken(
-            counts, tokens * top_k, total)[1]))]
+        rungs, calls = moe.budgets_taken(counts, tokens * top_k, total)
+        taken = rungs[int(jnp.argmax(calls))]
         if max(gaps) > 2e-2 or (name == "forced" and (
                 taken != rungs[-1] or int(counts.sum()) != tokens * top_k)):
             raise AssertionError(
                 f"{name} router: {int(counts.sum())} rows routed here took "
-                f"the budget of {taken} of {rungs}, value and gradient "
+                f"chunks over {taken} of {rungs} places, value and gradient "
                 f"{gaps} from the layer written out")
+        facts["rungs"] = list(rungs)
         facts[name] = {"rows_routed_here": int(counts.sum()),
-                       "budget_taken": taken, "gap": round(gaps[0], 6),
+                       "rows_covered": taken, "gap": round(gaps[0], 6),
                        "gradient_gap": round(gaps[1], 6)}
     return facts
 

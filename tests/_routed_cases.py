@@ -1,13 +1,14 @@
 """A routed expert layer whose load is chosen to the row, for the tests of
-``parallel/moe.py``'s ladder of row budgets (``test_mla_moe_lm.py`` for the
-sigmoid router, ``test_gqa_dsa_moe_lm.py`` for the softmax one).
+``parallel/moe.py``'s chunks of the sorted order (``test_mla_moe_lm.py``
+for the sigmoid router, ``test_gqa_dsa_moe_lm.py`` for the softmax one).
 
 64 tokens pick 2 of 16 experts, experts 4 and 5 are held: 128 picks a call,
-an even router sends 16 here, so the rungs are 32, 64 and 128 rows.  A
-token's first three columns say its KIND and the router reads nothing else:
-kind 0 picks experts 4 and 5 (two picks here), kind 1 picks 4 and 9 (one),
-kind 2 picks 8 and 9 (none).  The other columns are noise, so that the
-experts' outputs differ from token to token."""
+an even router sends 16 here, so a chunk is 32 places of the sorted order
+and a call takes one to four of them.  A token's first three columns say
+its KIND and the router reads nothing else: kind 0 picks experts 4 and 5
+(two picks here), kind 1 picks 4 and 9 (one), kind 2 picks 8 and 9 (none).
+The other columns are noise, so that the experts' outputs differ from token
+to token."""
 import collections
 
 import jax
@@ -18,11 +19,18 @@ from autodist_tpu.models.mla_moe_lm import equations
 from autodist_tpu.parallel import moe
 
 TOKENS, TOP_K, HELD, TOTAL, D = 64, 2, (4, 2), 16, 32
-RUNGS = (32, 64, 128)
+CHUNK = 32
+RUNGS = (32, 64, 96, 128)
 PICKS = {0: (4, 5), 1: (4, 9), 2: (8, 9)}
-#: rows routed here -> the rung that holds them: the boundaries (a load
-#: of exactly a rung takes it, one more takes the next), none and all
-LOADS = {0: 0, 32: 0, 33: 1, 64: 1, 65: 2, 128: 2}
+#: rows routed here -> the chunks that hold them: no pick held (the first
+#: chunk runs all the same), inside the first chunk, exactly a chunk, one
+#: row more (expert 4's 17 rows, then expert 5's 16 CUT across the
+#: boundary), two chunks exactly (the boundary is the groups'), one row
+#: more, and every pick held: all ``N * k / C`` chunks
+LOADS = {0: 1, 16: 1, 32: 1, 33: 2, 64: 2, 65: 3, 128: 4}
+#: the loads at which a chunk's boundary cuts an expert's group: that
+#: expert's gradient is then the sum of two products' and not one's
+CUT = (33, 65, 128)
 
 
 def layer(scoring: str, seed: int = 0) -> dict:
@@ -54,47 +62,91 @@ def tokens_routing(load: int, seed: int = 1) -> jax.Array:
     return jnp.asarray(x)
 
 
-def value_and_gradients(params, x, scoring, rungs=None, top_k=TOP_K):
+def written_out(params, x, scoring, top_k=TOP_K):
+    """The layer written out plainly: every held expert over every token,
+    weighted by the router's weights; no sort, no chunk, no kernel."""
+    first, count = HELD
+    scores = jnp.dot(x, params["router"],
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(scores) if scoring == "sigmoid" \
+        else jax.nn.softmax(scores, axis=-1)
+    ranked = scores + jax.lax.stop_gradient(params.get("router_bias", 0.0))
+    _, chosen = jax.lax.top_k(ranked, top_k)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    gates = jnp.zeros_like(scores).at[
+        jnp.arange(x.shape[0])[:, None], chosen].set(
+            2.448 * picked / picked.sum(-1, keepdims=True))
+    each = jax.vmap(lambda w: moe.swiglu(w, x))(params["experts"])
+    y = jnp.einsum("ne,end->nd", gates[:, first:first + count], each)
+    if "shared" in params:
+        y = y + moe.swiglu(params["shared"], x)
+    return y, jnp.sum(chosen.reshape(-1)[:, None]
+                      == first + jnp.arange(count), axis=0)
+
+
+def value_and_gradients(params, x, scoring, chunk=None, top_k=TOP_K,
+                        layer_fn=None):
     """``sum(y ** 2)``, the tokens an expert was sent and the gradient of
-    every leaf and of ``x``; ``rungs``: the ladder in the shapes' place."""
+    every leaf and of ``x``; ``chunk``: the places of a chunk in the
+    shapes' place; ``layer_fn``: another layer in ``routed_moe_ffn``'s."""
     def loss(params, x):
         y, counts = moe.routed_moe_ffn(
             params, x, top_k=top_k, experts_held=HELD, routed_scale=2.448,
-            scoring=scoring)
+            scoring=scoring) if layer_fn is None else layer_fn(
+            params, x, scoring, top_k)
         return jnp.sum(y ** 2), counts
 
-    real = moe.row_budgets
-    if rungs is not None:
-        moe.row_budgets = lambda *shapes: rungs
+    real = moe.chunk_rows
+    if chunk is not None:
+        moe.chunk_rows = lambda *shapes: chunk
     try:
         (value, counts), grads = jax.value_and_grad(
             loss, argnums=(0, 1), has_aux=True)(params, x)
     finally:
-        moe.row_budgets = real
+        moe.chunk_rows = real
     return value, counts, grads
 
 
-def assert_rung_equals_the_top_rung(scoring: str, load: int):
-    """The call with ``load`` picks routed here takes ``LOADS[load]``, and
-    its value and every gradient are the top rung's TO THE BIT.  Operation
-    by operation (``disable_jit``): compiled, XLA's CPU backend fuses a
-    branch's body and the same operations outside a ``switch`` in another
-    order of float32 sums (a few 1e-7)."""
+def rel(got, want):
+    return float(jnp.linalg.norm(got - want)
+                 / jnp.maximum(jnp.linalg.norm(want), 1e-12))
+
+
+def assert_chunks_equal_one_wide_chunk(scoring: str, load: int):
+    """The call with ``load`` picks routed here takes ``LOADS[load]``
+    chunks, and its value and every gradient are those of ONE chunk as
+    wide as all the picks (the path before the chunks) TO THE BIT: a
+    token's terms are added one after the other in the order of their
+    experts, across chunks too.  But an expert whose group a boundary cuts:
+    its gradient is the sum of two products' where the wide chunk forms
+    one, a few 1e-7 apart.  Operation by operation (``disable_jit``):
+    compiled, XLA's CPU backend fuses a loop's body and the same
+    operations outside it in another order of float32 sums.  And both are
+    the layer written out, within the order of its sums."""
     params, x = layer(scoring), tokens_routing(load)
     with jax.disable_jit():
         value, counts, grads = value_and_gradients(params, x, scoring)
-        top = value_and_gradients(params, x, scoring, rungs=RUNGS[-1:])
-    assert moe.row_budgets(TOKENS * TOP_K, HELD[1], TOTAL) == RUNGS
+        wide = value_and_gradients(params, x, scoring, chunk=TOKENS * TOP_K)
+        plain = value_and_gradients(params, x, scoring, layer_fn=written_out)
+    assert moe.chunk_rows(TOKENS * TOP_K, HELD[1], TOTAL) == CHUNK
     assert int(counts.sum()) == load
+    np.testing.assert_array_equal(counts, plain[1])
     rungs, calls = moe.budgets_taken(counts, TOKENS * TOP_K, TOTAL)
     assert rungs == RUNGS
-    assert calls.tolist() == [int(i == LOADS[load]) for i in range(3)]
-    np.testing.assert_array_equal(value, top[0])
+    assert calls.tolist() == [int(i + 1 == LOADS[load]) for i in range(4)]
+    np.testing.assert_array_equal(value, wide[0])
+    assert abs(float(value) - float(plain[0])) <= 2e-5 * float(plain[0])
     leaves = jax.tree_util.tree_leaves_with_path(grads)
     assert len(leaves) == len(jax.tree_util.tree_leaves(params)) + 1
-    for (path, got), want in zip(leaves, jax.tree_util.tree_leaves(top[2])):
+    for (path, got), want, written in zip(
+            leaves, jax.tree_util.tree_leaves(wide[2]),
+            jax.tree_util.tree_leaves(plain[2])):
         name = jax.tree_util.keystr(path)
-        np.testing.assert_array_equal(got, want, name)
+        if load in CUT and "experts" in name:
+            assert rel(got, want) < 1e-6, name
+        else:
+            np.testing.assert_array_equal(got, want, name)
+        assert rel(got, written) < 2e-5 or not np.any(written), name
         if load and "router_bias" not in name:
             assert np.any(np.asarray(got)), name
 
@@ -109,56 +161,62 @@ def primitives(jaxpr) -> collections.Counter:
         for eqn in equations(jaxpr))
 
 
-def rung_switches(jaxpr):
-    """The forward's and the backward's ``switch`` over the rungs."""
-    found = [eqn for eqn in equations(jaxpr) if eqn.primitive.name == "cond"]
-    assert [len(eqn.params["branches"]) for eqn in found] == [3, 3]
-    return found
+def chunk_loops(jaxpr):
+    """The forward's and the backward's loop over the further chunks; no
+    conditional beside them."""
+    found = primitives(jaxpr)
+    assert found["cond"] == 0 and found["while"] == 2, found
+    return [eqn for eqn in equations(jaxpr) if eqn.primitive.name == "while"]
 
 
-def assert_gradient_switches_once_and_fills_no_rows(scoring: str):
-    """The gradient's jaxpr: one ``switch`` of three branches for the
-    forward and one for the backward, whose results are token-shaped or
-    weight-shaped alone; and but for the top rung's branches, which work on
-    all ``N * k`` rows, nothing broadcasts into an ``[N * k, d]`` array
-    (differentiated as written, ``switch`` would zero-fill the top rung's
-    residuals in every other branch)."""
-    def fills(jaxpr):
-        return sum(eqn.primitive.name == "broadcast_in_dim"
-                   and eqn.outvars[0].aval.shape == (TOKENS * TOP_K, D)
-                   for eqn in equations(jaxpr))
-
+def assert_gradient_loops_once_a_direction_and_fills_no_rows(scoring: str):
+    """The gradient's jaxpr: NO ``cond`` or ``switch``, one ``while`` for
+    the forward's further chunks and one for the backward's, whose carries
+    are token-shaped, weight-shaped or one number a pick alone; nothing in
+    it, inside the loops or outside, is an ``[N * k, d]`` array, and each
+    direction returns to token order by one kernel for the first chunk and
+    one in the loop."""
     params, x = layer(scoring), tokens_routing(16)
     jaxpr = jax.make_jaxpr(lambda p, x: value_and_gradients(
         p, x, scoring)[2])(params, x).jaxpr
-    in_top = 0
-    for eqn in rung_switches(jaxpr):
-        assert all(var.aval.shape[0] in (TOKENS, HELD[1])
-                   for var in eqn.outvars), eqn.outvars
-        in_top += fills(eqn.params["branches"][-1].jaxpr)
-    assert fills(jaxpr) == in_top > 0
+    for loop in chunk_loops(jaxpr):
+        for var in loop.outvars:
+            shape = var.aval.shape
+            assert len(shape) < 2 or shape[0] in (TOKENS, HELD[1]), shape
+        assert primitives(loop.params["body_jaxpr"].jaxpr)[
+            "rows_to_tokens"] == 1
+    for eqn in equations(jaxpr):
+        for var in eqn.outvars:
+            assert getattr(var.aval, "shape", None) != (TOKENS * TOP_K, D), \
+                eqn
+    assert primitives(jaxpr)["rows_to_tokens"] == 4
 
 
-def assert_nothing_below_the_top_rung_is_as_wide_as_the_picks(
-        scoring: str, top_k: int):
-    """96 tokens pick ``top_k`` of 16 experts (rungs of 2, 4 and 8 times
-    the 12 an even router sends to the two held): in the branches of the
-    gradient's two ``switch`` es below the top rung NO equation yields
+def assert_nothing_is_as_wide_as_the_picks(scoring: str, top_k: int):
+    """96 tokens pick ``top_k`` of 16 experts (a chunk is twice the 12 an
+    even router sends to the two held, in whole 8-row tiles): NO equation
+    of the gradient, in the first chunk's path or in the loops', yields
     ``N * top_k * d`` elements or more: no ``[N, k, d]`` array (at k = 6 a
-    relayout on the chip), no select or fill of every pick's row; sorted
-    rows come back by ``rows_to_tokens``, once a branch."""
+    relayout on the chip), no select or fill of every pick's row; the
+    grouped products work on a chunk's rows."""
     tokens = 96
     params = jax.eval_shape(lambda: layer(scoring))
     x = jax.ShapeDtypeStruct((tokens, D), jnp.float32)
-    assert len(moe.row_budgets(tokens * top_k, HELD[1], TOTAL)) == 3
+    chunk = moe.chunk_rows(tokens * top_k, HELD[1], TOTAL)
+    assert chunk == 24 * top_k and tokens * top_k // chunk == 4
     jaxpr = jax.make_jaxpr(lambda p, x: value_and_gradients(
         p, x, scoring, top_k=top_k)[2])(params, x).jaxpr
-    for eqn in rung_switches(jaxpr):
-        for branch in eqn.params["branches"][:-1]:
-            for inner in equations(branch.jaxpr):
-                for var in inner.outvars:
-                    assert var.aval.size < tokens * top_k * D, inner
-            assert primitives(branch.jaxpr)["rows_to_tokens"] == 1
+    chunk_loops(jaxpr)
+    grouped = 0
+    for eqn in equations(jaxpr):
+        for var in eqn.outvars:
+            assert var.aval.size < tokens * top_k * D, eqn
+        if eqn.primitive.name == "ragged_dot_general":
+            grouped += 1
+            assert chunk in eqn.invars[0].aval.shape, eqn
+    # a body's three and their six transposes, the first chunk's and the
+    # loop's, and the forward's three twice
+    assert grouped == 2 * 3 + 2 * 9
 
 
 def poisoned(grouped, traced: list):
@@ -195,10 +253,11 @@ def poisoned(grouped, traced: list):
 
 
 def assert_unwritten_rows_are_never_read(scoring: str, load: int):
-    """Value and every gradient with the rows past the last group NaN:
-    finite, and what they are with zeros there.  ``load`` 16 fills half
-    of the first rung; 32 and 64 fill a rung EXACTLY with picks of absent
-    experts present, whose clipped places fall on a live row."""
+    """Value and every gradient with the rows past the last group NaN in
+    every chunk: finite, and what they are with zeros there.  ``load`` 16
+    fills half of the first chunk, 32 fills it EXACTLY, 64 two chunks, and
+    picks of absent experts are present, whose places lie past the live
+    rows."""
     params, x = layer(scoring), tokens_routing(load)
     run = jax.jit(lambda p, x: value_and_gradients(p, x, scoring))
     want = run(params, x)
@@ -209,8 +268,8 @@ def assert_unwritten_rows_are_never_read(scoring: str, load: int):
             params, x)
     finally:
         moe._grouped_swiglu = real
-    # forward and backward of every rung
-    assert int(got[1].sum()) == load and sorted(traced) == sorted(2 * RUNGS)
+    # the first chunk and the loop's body, forward and backward
+    assert int(got[1].sum()) == load and traced == [CHUNK] * 4
     for (path, leaf), other in zip(
             jax.tree_util.tree_leaves_with_path(got),
             jax.tree_util.tree_leaves(want)):
@@ -220,8 +279,8 @@ def assert_unwritten_rows_are_never_read(scoring: str, load: int):
 
 
 def budget_gauges():
-    """(rows of the budgets the last fetched step took, calls by rung so
-    far)."""
+    """(rows of the chunks the last fetched step's calls took, calls by
+    the rows their chunks covered so far)."""
     from autodist_tpu.telemetry.registry import DEFAULT_REGISTRY
 
     rows, calls = None, {}

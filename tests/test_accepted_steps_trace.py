@@ -7,12 +7,16 @@ stripped.  gpt2-medium's hash was taken on commit ``c059ab2`` (PR 34) and
 has held since: PR 36's options (``flash_attention(window=)``,
 ``routed_moe_ffn(router_input=, activation=)``, the decoder skeleton cut
 out of ``gqa_dsa_moe_lm``) and PR 37's return of the routed layer's rows
-left alone change NOTHING of it, to the character.  The three expert
-models' were recorded anew by PR 37, ON PURPOSE: their routed layers bring
-the sorted rows back to token order by ``ops/rows_to_tokens.py`` (nine
-more kernels in kanana's and keye's text; smallthinker's is that PR's
-first record).  A PR that changes one of these models' traces on purpose
-records the new hash here and says so in ``CHANGES.md``.
+left alone and PR 39's one call of it a layer change NOTHING of it, to
+the character.  The three expert models' were recorded anew by PR 37
+(their routed layers bring the sorted rows back to token order by
+``ops/rows_to_tokens.py``) and again by PR 39, ON PURPOSE: the expert half
+of a layer is one call over all of a step's tokens outside the map, the
+ladder's three branches a direction are a first chunk and a loop's body
+(a fifth to a quarter less text, one ``pallas_call`` less in each), and
+what is one number a pick moves by sorts and comparisons.  A PR that
+changes one of these models' traces on purpose records the new hash here
+and says so in ``CHANGES.md``.
 """
 import hashlib
 import importlib
@@ -34,14 +38,14 @@ TRACES = {
         4, 1024, 869756, 48,
         "33aeee217cc7412cf3b23c92a314f5eaeab4ac537a96f824a79bfda0f11ec301"),
     "kanana-2-30b-a3b.ep8-share": (
-        4, 4096, 446819, 16,
-        "ac41e22ef4ed81700035667b44186b20bbddccd628568a0eaab596dd30210d14"),
+        4, 4096, 345630, 15,
+        "bde23815b46d978c798415175820ecae82aca6165261e3d3a04105512a831e00"),
     "keye-vl-2.0-30b-a3b.ep8-share": (
-        1, 16384, 1152964, 14,
-        "2ddd2fca1e3eeea4c68ccda838d65ac3a3d2e9f6c3ee9751c8bb48c5f27dbb11"),
+        1, 16384, 1054039, 13,
+        "0a0a437f51802112d34ad10eb1dba9364890f06f1285af369fd9aa55f77c4cd3"),
     "smallthinker-21b-a3b.ep8-share": (
-        1, 16384, 383594, 15,
-        "c220915a842201c103ab03a2288d3d1816eddfd0d17e31abb5b07c434a70ff71"),
+        1, 16384, 286681, 14,
+        "a72c5f085fb2840068171a70c53911d5943ef75dbc4a46c21add6cb171dd8f4e"),
 }
 
 

@@ -63,14 +63,14 @@ def test_train_moe_phase_returns_the_counts():
     kept = facts["remat_kept_bytes"]
     assert kept["flash_attention/o"] == 0
     assert kept["routed_moe/chosen"] == kept["routed_moe/order"] == 768
-    # a router forced onto the four held experts takes the top rung of the
-    # ladder of row budgets, the seeded one a lower rung; both are the
-    # layer written out
-    budgets = facts["row_budgets"]
-    assert budgets["rungs"] == [1024, 1536]     # whole 512-row tiles
-    assert budgets["forced"]["rows_routed_here"] == 512 * 3 \
-        == budgets["forced"]["budget_taken"]
-    assert budgets["even"]["budget_taken"] == 1024
+    # a router forced onto the four held experts takes every chunk of the
+    # sorted order, the seeded one the first alone; both are the layer
+    # written out
+    budgets = facts["chunks"]
+    assert budgets["rungs"] == [1024, 2048]     # whole 512-row tiles
+    assert budgets["forced"]["rows_routed_here"] == 512 * 3
+    assert budgets["forced"]["rows_covered"] == 2048
+    assert budgets["even"]["rows_covered"] == 1024
     assert max(budgets[r][gap] for r in ("even", "forced")
                for gap in ("gap", "gradient_gap")) < 1e-5
 

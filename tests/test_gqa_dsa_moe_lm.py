@@ -36,7 +36,7 @@ from autodist_tpu.ops.topk_select import ordered_bits, top_k_mask
 from autodist_tpu.parallel.moe import (
     init_routed_moe_params,
     routed_moe_ffn,
-    row_budgets,
+    chunk_rows,
 )
 from benchmark.reference import keye_vl2 as ref
 
@@ -145,11 +145,12 @@ def test_three_session_steps_match_the_reference_adamw(router):
     against the reference under AdamW written out (the indexer's leaves
     move by the decoupled decay alone); the gauges are set at trace
     time, but the ``computed`` rows of the expert layers: those are the
-    budgets the LAST STEP's calls took (2 layers x 2 slices of 96 tokens x
-    3 picks, a quarter of them expected here).  An even router leaves
-    every call on the low rung of 144 rows; a router that has collapsed
-    (all weights zero, not trained: every token picks experts 0, 1 and 2,
-    which are held) puts every call on the top rung."""
+    chunks the LAST STEP's calls took (2 layers, each ONE call over 2
+    slices of 96 tokens x 3 picks, a quarter of them expected here).  An
+    even router leaves every call inside its first chunk of 288 places
+    (twice the even load, and a slice's picks); a router that has
+    collapsed (all weights zero, not trained: every token picks experts 0,
+    1 and 2, which are held) makes every call take both chunks."""
     from autodist_tpu import strategy as strategies
     from autodist_tpu.autodist import (AutoDist,
                                        _reset_default_autodist_for_testing)
@@ -167,8 +168,8 @@ def test_three_session_steps_match_the_reference_adamw(router):
             moe = params[f"layers_{i}"]["moe"]
             moe["router"] = jnp.zeros_like(moe["router"])
     batches = [jnp.asarray(tokens(20 + i)) for i in range(3)]
-    rungs = row_budgets(96 * 3, 4, 16)
-    assert rungs == (144, 288)
+    assert chunk_rows(2 * 96 * 3, 4, 16, 96 * 3) == 288
+    rungs = (288, 576)
     with jax.default_matmul_precision("highest"):
         want_losses, _, want_delta, _ = ref.train_steps(
             params, batches, row_block=2,
@@ -193,13 +194,13 @@ def test_three_session_steps_match_the_reference_adamw(router):
     for out, want in zip(outs, want_losses):
         assert abs(float(out["loss"]) - want) < RTOL
         assert np.asarray(out["aux"]["tokens_per_expert"]).shape == (2, 4)
-    # 2 layers x 2 slices a step, all on one rung
+    # 2 layers a step, one call each, all taking as many chunks
     taken = rungs[collapsed]
     assert {r: three_steps[r] - two_steps.get(r, 0) for r in rungs} \
-        == {r: 4 * (r == taken) for r in rungs}
+        == {r: 2 * (r == taken) for r in rungs}
     assert {r: three_steps[r] - before.get(r, 0) for r in rungs} \
-        == {r: 12 * (r == taken) for r in rungs}
-    assert rows == 4 * taken
+        == {r: 6 * (r == taken) for r in rungs}
+    assert rows == 2 * taken
     for name, want in want_delta.items():
         assert abs(delta[name] - want) <= 1e-4 * max(want, 1e-6), name
     gauges = {(m.name, m.labels.get("kind") or m.labels.get("name")): m.value
@@ -367,17 +368,17 @@ def test_eight_shares_add_up_to_the_whole_layer():
     assert int(jnp.concatenate(counts).sum()) == 2 * 24 * 4   # every pick
 
 
-# the ladder of row budgets (PR 34) under the softmax router; the sigmoid
-# router's cases and the ladder's own are in test_mla_moe_lm.py
+# chunks of the sorted order (PR 39) under the softmax router; the sigmoid
+# router's cases and the chunk's own are in test_mla_moe_lm.py
 @pytest.mark.parametrize("load", sorted(routed_cases.LOADS))
-def test_every_rung_equals_the_top_rung_to_the_bit(load):
-    routed_cases.assert_rung_equals_the_top_rung("softmax", load)
+def test_chunks_equal_one_wide_chunk_to_the_bit(load):
+    routed_cases.assert_chunks_equal_one_wide_chunk("softmax", load)
 
 
 @pytest.mark.parametrize("load", [16, 40, 100])
-def test_compiled_rungs_match_the_reference(load):
-    """Jitted, on each rung, the layer and the gradient through it match
-    the plain reference."""
+def test_compiled_chunks_match_the_reference(load):
+    """Jitted, over one, two and four chunks, the layer and the gradient
+    through it match the plain reference."""
     params = routed_cases.layer("softmax")
     x = routed_cases.tokens_routing(load)
     s = settings(routed_cases.HELD[0], top_k=routed_cases.TOP_K)
@@ -395,8 +396,9 @@ def test_compiled_rungs_match_the_reference(load):
     assert rel(grads[1], want_grads[1]) < RTOL
 
 
-def test_gradient_holds_one_switch_a_direction_and_fills_no_rows():
-    routed_cases.assert_gradient_switches_once_and_fills_no_rows("softmax")
+def test_gradient_holds_one_loop_a_direction_and_fills_no_rows():
+    routed_cases.assert_gradient_loops_once_a_direction_and_fills_no_rows(
+        "softmax")
 
 
 @pytest.mark.parametrize("load", [16, 32, 64])
@@ -405,9 +407,8 @@ def test_rows_past_the_last_group_are_never_read(load):
 
 
 @pytest.mark.parametrize("top_k", [6, 8])
-def test_nothing_below_the_top_rung_is_as_wide_as_the_picks(top_k):
-    routed_cases.assert_nothing_below_the_top_rung_is_as_wide_as_the_picks(
-        "softmax", top_k)
+def test_nothing_is_as_wide_as_the_picks(top_k):
+    routed_cases.assert_nothing_is_as_wide_as_the_picks("softmax", top_k)
 
 
 def test_dense_fallback_is_the_kernel():

@@ -36,7 +36,8 @@ from autodist_tpu.ops import flash_attention
 from autodist_tpu.parallel.moe import (
     ROUTING_RESIDUAL_NAMES,
     init_routed_moe_params,
-    row_budgets,
+    budgets_taken,
+    chunk_rows,
     routed_moe_ffn,
     routed_rows,
 )
@@ -362,26 +363,30 @@ def test_three_session_steps_match_the_reference_adamw(router):
     against the reference's gradients under AdamW written out; the
     per-expert token counts come back with every step; the layer's gauges
     are set when it is traced, but the ``computed`` rows: those are the
-    budgets the LAST STEP's calls took, carried out of the step when it
-    runs.  An even router leaves every call (2 expert layers x 4
-    sequences of 32 tokens x 3 picks, a quarter of them expected here) on
-    the low rung of 48 rows; a selection bias that sends
-    every pick to the held experts puts every call on the top rung."""
+    chunks the LAST STEP's calls took, carried out of the step when it
+    runs.  An even router leaves every call (2 expert layers, each ONE
+    call over 4 sequences of 32 tokens x 3 picks, an eighth of them
+    expected here as in the cells: 4 of 32 experts held) inside its first
+    chunk of 96 places (twice the even load, and a sequence's picks); a
+    selection bias that sends every pick to the held experts makes every
+    call take all four chunks."""
     from autodist_tpu import strategy as strategies
     from autodist_tpu.autodist import (AutoDist,
                                        _reset_default_autodist_for_testing)
     from autodist_tpu.mesh import build_mesh
     from autodist_tpu.telemetry.registry import DEFAULT_REGISTRY
 
-    spec = mla_moe_lm(**TINY, experts_held=(4, 4), return_counts=True)
+    spec = mla_moe_lm(**dict(TINY, num_experts=32), experts_held=(4, 4),
+                      return_counts=True)
     params = seeded(jax.eval_shape(spec.init, jax.random.key(0)), 11)
     if router == "skewed":
         for i in range(TINY["first_dense"], TINY["num_layers"]):
             params[f"layers_{i}"]["moe"]["router_bias"] = jnp.full(
-                (16,), -10.0).at[4:8].set(10.0)
+                (32,), -10.0).at[4:8].set(10.0)
     batches = [jnp.asarray(tokens(20 + i)) for i in range(3)]
-    rungs = row_budgets(32 * 3, 4, 16)
-    assert rungs == (48, 96)
+    assert chunk_rows(4 * 32 * 3, 4, 32, 32 * 3) == 96 \
+        == chunk_rows(4 * 32 * 3, 4, 32)
+    rungs = (96, 192, 288, 384)
     with jax.default_matmul_precision("highest"):
         want_losses, _, want_delta, _ = ref.train_steps(
             params, batches, row_block=4, s=settings(4))
@@ -416,36 +421,40 @@ def test_three_session_steps_match_the_reference_adamw(router):
               for m in DEFAULT_REGISTRY.metrics()
               if m.name.startswith("autodist_moe_")}
     assert gauges[("autodist_moe_experts_held", None)] == 4
-    assert gauges[("autodist_moe_experts_total", None)] == 16
-    computed, expected = routed_rows(4 * 32, 3, 4, 16)
+    assert gauges[("autodist_moe_experts_total", None)] == 32
+    computed, expected = routed_rows(4 * 32, 3, 4, 32)
     assert gauges[("autodist_moe_rows_per_step", "expected")] == 2 * expected
-    # 2 expert layers x 4 sequences a step, all on one rung
-    taken = rungs[router == "skewed"]
+    # 2 expert layers a step, one call each, all taking as many chunks
+    taken = rungs[3 * (router == "skewed")]
     last = {r: three_steps[r] - two_steps.get(r, 0) for r in rungs}
-    assert last == {r: 8 * (r == taken) for r in rungs}
+    assert last == {r: 2 * (r == taken) for r in rungs}
     assert {r: three_steps[r] - before.get(r, 0) for r in rungs} \
-        == {r: 24 * (r == taken) for r in rungs}
-    assert rows == 8 * taken
-    assert rows / (2 * expected) == (4.0 if router == "skewed" else 2.0)
-    assert computed == rungs[-1] * 4        # the top rung is every pick
+        == {r: 6 * (r == taken) for r in rungs}
+    assert rows == 2 * taken
+    assert rows / (2 * expected) == (8.0 if router == "skewed" else 2.0)
+    assert computed == rungs[-1]            # every chunk is every pick
+    # the same rule on the integers the step returned
+    assert budgets_taken(counts, 4 * 32 * 3, 32, 32 * 3)[1].tolist() \
+        == [2 * (r == taken) for r in rungs]
 
 
 # ---------------------------------------------------------------------------
-# the ladder of row budgets (PR 34); the softmax router's cases are in
-# test_gqa_dsa_moe_lm.py
+# chunks of the sorted order (PR 39; a ladder of row budgets from PR 34);
+# the softmax router's cases are in test_gqa_dsa_moe_lm.py
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("load", sorted(routed_cases.LOADS))
-def test_every_rung_equals_the_top_rung_to_the_bit(load):
-    """Value and every gradient on the rung a load takes against the top
-    rung's (the path before the ladder): none routed here, a load of
-    exactly a rung and of one row more, every pick routed here."""
-    routed_cases.assert_rung_equals_the_top_rung("sigmoid", load)
+def test_chunks_equal_one_wide_chunk_to_the_bit(load):
+    """Value and every gradient over the chunks a load takes against one
+    chunk as wide as every pick (the path before the chunks) and against
+    the layer written out: none routed here, inside the first chunk, a
+    load of exactly a chunk and of one row more, every pick routed here."""
+    routed_cases.assert_chunks_equal_one_wide_chunk("sigmoid", load)
 
 
 @pytest.mark.parametrize("load", [16, 40, 100])
-def test_compiled_rungs_match_the_reference(load):
-    """Jitted, on each rung, the layer and the gradient through it match
-    the plain reference."""
+def test_compiled_chunks_match_the_reference(load):
+    """Jitted, over one, two and four chunks, the layer and the gradient
+    through it match the plain reference."""
     params = routed_cases.layer("sigmoid")
     x = routed_cases.tokens_routing(load)
     s = ref.Settings(top_k=routed_cases.TOP_K, routed_scale=2.448,
@@ -467,8 +476,9 @@ def test_compiled_rungs_match_the_reference(load):
     assert rel(grads[1], want_grads[1]) < RTOL
 
 
-def test_gradient_holds_one_switch_a_direction_and_fills_no_rows():
-    routed_cases.assert_gradient_switches_once_and_fills_no_rows("sigmoid")
+def test_gradient_holds_one_loop_a_direction_and_fills_no_rows():
+    routed_cases.assert_gradient_loops_once_a_direction_and_fills_no_rows(
+        "sigmoid")
 
 
 @pytest.mark.parametrize("load", [16, 32, 64])
@@ -477,21 +487,57 @@ def test_rows_past_the_last_group_are_never_read(load):
 
 
 @pytest.mark.parametrize("top_k", [6, 8])
-def test_nothing_below_the_top_rung_is_as_wide_as_the_picks(top_k):
-    routed_cases.assert_nothing_below_the_top_rung_is_as_wide_as_the_picks(
-        "sigmoid", top_k)
+def test_nothing_is_as_wide_as_the_picks(top_k):
+    routed_cases.assert_nothing_is_as_wide_as_the_picks("sigmoid", top_k)
 
 
-@pytest.mark.parametrize("rows,held,total,want", [
-    (24576, 16, 128, (6144, 12288, 24576)),     # the kanana cell's call
-    (32768, 16, 128, (8192, 16384, 32768)),     # the keye cell's
-    (96, 4, 16, (48, 96)),                      # four times expected is all
-    (96, 16, 16, (96,)), (96, 8, 16, (96,)),    # all held, half held
-    (144, 2, 16, (40, 72, 144)),                # whole 8-row tiles
-    (4096 * 6, 2, 256, (512, 1024, 24576)),     # never more than three
+@pytest.mark.parametrize("rows,held,total,cap,want", [
+    (16384 * 6, 16, 128, 4096 * 6, 24576),  # the kanana cell's call
+    (16384 * 8, 16, 128, 4096 * 8, 32768),  # the keye cell's
+    (16384 * 6, 8, 64, 4096 * 6, 24576),    # the smallthinker cell's
+    (96, 4, 16, None, 48),                  # twice the even load
+    (96, 16, 16, None, 96), (96, 8, 16, None, 96),  # all held, half held
+    (144, 2, 16, None, 40),                 # whole 8-row tiles
+    (4096 * 6, 2, 256, None, 512),          # whole 512-row tiles
+    (4096 * 6, 64, 256, 1000, 1024),        # the cap in whole tiles too
 ])
-def test_row_budgets_follow_the_shapes_alone(rows, held, total, want):
-    assert row_budgets(rows, held, total) == want
+def test_chunk_rows_follow_the_shapes_alone(rows, held, total, cap, want):
+    assert chunk_rows(rows, held, total, cap) == want
+    rungs, calls = budgets_taken(jnp.zeros((3, held), jnp.int32), rows,
+                                 total, cap)
+    assert rungs[0] == want and rungs[-1] >= rows > rungs[-1] - want
+    assert calls.tolist() == [3] + [0] * (len(rungs) - 1)
+
+
+def test_a_whole_batch_call_equals_its_slices_run_one_by_one():
+    """One call over ``[4, 16, d]`` (what a model hands the layer since PR
+    39) against the same tokens a slice a call (what the parent's
+    ``lax.map`` ran): the value, the counts' sum and the gradients of the
+    tokens and of every leaf, within the order of the sums."""
+    params = moe_layer(3, held_count=4)
+    x = jax.random.normal(jax.random.key(5), (4, 16, 32))
+
+    def call(p, x):
+        return routed_moe_ffn(p, x, top_k=3, experts_held=(4, 4),
+                              routed_scale=2.448)
+
+    def whole(p, x):
+        y, counts = call(p, x)
+        return jnp.sum(y ** 2), (y, counts)
+
+    def sliced(p, x):
+        y, counts = jax.lax.map(lambda part: call(p, part), x)
+        return jnp.sum(y ** 2), (y, counts.sum(axis=0))
+
+    got, want = (jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))(
+        params, x) for f in (whole, sliced))
+    assert got[0][1][0].shape == x.shape
+    np.testing.assert_array_equal(got[0][1][1], want[0][1][1])
+    assert chunk_rows(4 * 16 * 3, 4, 16, 16 * 3) == 48
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want)):
+        if "router_bias" not in jax.tree_util.keystr(path):
+            assert rel(a, b) < RTOL, jax.tree_util.keystr(path)
 
 
 # ---------------------------------------------------------------------------
@@ -524,16 +570,20 @@ def count_primitives(jaxpr, names):
 def test_backward_runs_kernel_selection_and_sorts_once_a_layer(
         remat, kernels, selects, monkeypatch):
     """The gradient's jaxpr: a forward and a backward attention kernel a
-    layer, one ``top_k`` and two sorts a routed layer.  A checkpoint that
-    keeps nothing by name runs the forward kernel, the selection and both
-    sorts a second time."""
+    layer, one ``top_k`` and two sorts of the picks a routed layer.  A
+    checkpoint that keeps nothing by name runs the forward kernel, the
+    selection and both sorts a second time.  Three more sorts a routed
+    layer under any policy move one number a pick between the picks' order
+    and the sorted order (the weights forward and again in the backward,
+    their cotangent back): sorts of pairs where a gather of single numbers
+    would be (PR 39)."""
     spec = remat_model(remat, monkeypatch)
     params = jax.eval_shape(spec.init, jax.random.key(0))
     jaxpr = jax.make_jaxpr(jax.grad(spec.loss_fn))(
         params, {"tokens": tokens(0, rows=2)})
     assert count_primitives(jaxpr.jaxpr, ("pallas_call", "top_k", "sort")) \
         == {"pallas_call": kernels * TINY["num_layers"],
-            "top_k": selects * ROUTED, "sort": 2 * selects * ROUTED}
+            "top_k": selects * ROUTED, "sort": (2 * selects + 3) * ROUTED}
 
 
 @pytest.mark.parametrize("remat", ["full", "dots"])
@@ -585,6 +635,6 @@ def test_kept_bytes_gauge_reads_what_the_tagged_shapes_give(remat, attn,
     want = dict(zip(KEPT_NAMES, (
         layers * TINY["num_heads"] * t * TINY["v_head"] * 4,    # o, float32
         layers * TINY["num_heads"] * t * 4,                     # lse
-        picks, picks, picks, rows * ROUTED * 4 * 4)))           # 4 held
+        picks, picks, picks, ROUTED * 4 * 4)))    # 4 held, a call a layer
     assert KEPT_NAMES[2:] == ROUTING_RESIDUAL_NAMES
     assert got == (want if remat != "none" else dict.fromkeys(KEPT_NAMES, 0))

@@ -1,7 +1,7 @@
 """``ops/rows_to_tokens.py`` against a loop written out, interpreted (the
 kernel compiled for a described v5e at the expert cells' widths is in
 ``test_flash_tpu_compile.py``; on the chip, ``chip_smoke.py``'s
-``row_budgets`` phase)."""
+``chunks`` fact)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -49,8 +49,8 @@ def test_live_rows_are_added_to_their_tokens_in_order(count, d, tokens,
 def test_tiles_of_the_width_follow_the_tokens(monkeypatch):
     """The widest whole-lane divisor of ``d`` whose block of all the tokens
     fits; several tiles give what one gives."""
-    assert op._width(4096, 2560) == 640 and op._width(4096, 2048) == 1024
-    assert op._width(16384, 2048) == 256 and op._width(96, 32) == 32
+    assert op._width(4096, 2560) == 1280 and op._width(4096, 2048) == 2048
+    assert op._width(16384, 2048) == 512 and op._width(96, 32) == 32
     assert op._width(1 << 20, 256) == 128
     rows, token, scale = case(3, 256, 512, 32)
     args = (jnp.asarray(rows), jnp.asarray(token), jnp.asarray(scale),
@@ -71,3 +71,28 @@ def test_narrower_rows_are_added_up_in_float32():
                                   np.float32), token, scale, 64, 4)
     np.testing.assert_allclose(np.asarray(got, np.float32), want, rtol=1e-2,
                                atol=1e-2)
+
+
+@pytest.mark.parametrize("count,d,tokens", [(1024, 256, 64), (288, 32, 96)])
+def test_rows_added_onto_a_carried_sum_equal_one_call_to_the_bit(
+        count, d, tokens):
+    """Two chunks of the rows, the second ``onto`` the first's result, are
+    one call over all of them: a token's terms are added one after the
+    other across the chunks (a routed layer's further chunks); the carried
+    block halves the tile."""
+    rows, token, scale = (jnp.asarray(a) for a in case(7, count, d, tokens))
+    half, live = count // 2, count // 2 + count // 5
+    with jax.disable_jit():     # operation by operation: no fused product
+        whole = op.rows_to_tokens(rows, token, scale, jnp.int32(live),
+                                  tokens)
+        first = op.rows_to_tokens(rows[:half], token[:half], scale[:half],
+                                  jnp.int32(half), tokens)
+        both = op.rows_to_tokens(rows[half:], token[half:], scale[half:],
+                                 jnp.int32(live - half), tokens, onto=first)
+    np.testing.assert_array_equal(both, whole)
+    np.testing.assert_allclose(
+        whole, written_out(*(np.asarray(a) for a in (rows, token, scale)),
+                           live, tokens), rtol=1e-6, atol=1e-6)
+    # at the expert cells' 16,384 tokens: the first chunk's tile, a
+    # further chunk's
+    assert op._width(16384, 2560) == 512 and op._width(2 * 16384, 2560) == 256

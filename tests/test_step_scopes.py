@@ -117,7 +117,9 @@ def test_every_heavy_equation_is_under_a_scope(name):
     assert not bare, bare
     # a body reached through every kind of container the steps have
     stacks = "\n".join(stack for _, stack in heavy)
-    for container in ("transpose(jvp(", "<scan>", "<remat2>", "<cond>",
+    # (<while>: the routed layer's loop over further chunks, PR 39; a
+    # ``switch`` over row budgets, ``<cond>``, until then)
+    for container in ("transpose(jvp(", "<scan>", "<remat2>", "<while>",
                       "<custom_vjp_call>") \
             if name != "transformer_lm" else ("transpose(jvp(",):
         assert container in stacks, container

@@ -33,7 +33,7 @@ from autodist_tpu.parallel.moe import (
     budgets_taken,
     init_routed_moe_params,
     routed_moe_ffn,
-    row_budgets,
+    chunk_rows,
 )
 from benchmark.reference import smallthinker as ref
 
@@ -311,8 +311,9 @@ def test_three_session_steps_match_the_reference_adamw(router):
     session -> run``: three steps' losses and the parameters after them
     against the reference under AdamW written out; the gauges of the pairs
     are set at trace time, the ``computed`` rows of the expert layers are
-    the budgets the LAST STEP's calls took (4 layers x 2 slices of 96
-    tokens x 3 picks, a quarter of them expected here)."""
+    the chunks the LAST STEP's calls took (4 layers, each ONE call over 2
+    slices of 96 tokens x 3 picks, a quarter of them expected here: a
+    chunk is 288 places, twice the even load and a slice's picks)."""
     from autodist_tpu import strategy as strategies
     from autodist_tpu.autodist import (AutoDist,
                                        _reset_default_autodist_for_testing)
@@ -329,8 +330,8 @@ def test_three_session_steps_match_the_reference_adamw(router):
             moe = params[f"layers_{i}"]["moe"]
             moe["router"] = jnp.zeros_like(moe["router"])
     batches = [jnp.asarray(tokens(20 + i)) for i in range(3)]
-    rungs = row_budgets(96 * 3, 4, 16)
-    assert rungs == (144, 288)
+    assert chunk_rows(2 * 96 * 3, 4, 16, 96 * 3) == 288
+    rungs = (288, 576)
     with jax.default_matmul_precision("highest"):
         want_losses, _, want_delta, _ = ref.train_steps(
             params, batches, row_block=2,
@@ -355,14 +356,16 @@ def test_three_session_steps_match_the_reference_adamw(router):
     for out, want in zip(outs, want_losses):
         assert abs(float(out["loss"]) - want) < RTOL
         assert np.asarray(out["aux"]["tokens_per_expert"]).shape == (4, 4)
-    # 4 layers x 2 slices a step; a collapsed router (every token picks
-    # experts 0, 1 and 2, all held) puts every call on the top rung, an
-    # even one nearly all on the low one
+    # 4 layers a step, one call each; a collapsed router (every token
+    # picks experts 0, 1 and 2, all held) makes every call take both
+    # chunks, an even one leaves every call inside the first
     last = {r: after.get(r, 0) - two_steps.get(r, 0) for r in rungs}
-    assert sum(last.values()) == 8
-    assert sum(after.get(r, 0) - before.get(r, 0) for r in rungs) == 24
+    assert sum(after.get(r, 0) - before.get(r, 0) for r in rungs) == 12
     assert rows == sum(r * n for r, n in last.items())
-    assert last[rungs[1]] == 8 if collapsed else last[rungs[0]] >= 6
+    assert last == {r: 4 * (r == rungs[collapsed]) for r in rungs}
+    counts = np.asarray(outs[-1]["aux"]["tokens_per_expert"])
+    assert budgets_taken(counts, 2 * 96 * 3, 16, 96 * 3)[1].tolist() \
+        == list(last.values())
     for name, want in want_delta.items():
         assert abs(delta[name] - want) <= 1e-4 * max(want, 1e-6), name
     gauges = {(m.name, m.labels.get("kind") or m.labels.get("name")): m.value
@@ -503,28 +506,30 @@ def test_eight_shares_add_up_to_the_uncut_reference_layer():
 
 
 # ---------------------------------------------------------------------------
-# the ladder is the shapes' alone, and every rung is this model's layer
+# a chunk is the shapes' alone, and over any chunks it is this model's layer
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("rows,held,total,want", [
-    (4096 * 6, 8, 64, (6144, 12288, 24576)),     # this model's slice
-    (4096 * 6, 16, 128, (6144, 12288, 24576)),   # kanana's sequence
-    (4096 * 8, 16, 128, (8192, 16384, 32768)),   # keye's slice
-    (4096 * 6, 64, 64, (24576,)),                # every expert held
+@pytest.mark.parametrize("rows,held,total,cap,want", [
+    (16384 * 6, 8, 64, 4096 * 6, 24576),      # this model's step
+    (16384 * 6, 16, 128, 4096 * 6, 24576),    # kanana's four sequences
+    (16384 * 8, 16, 128, 4096 * 8, 32768),    # keye's step
+    (16384 * 6, 64, 64, 4096 * 6, 24576),     # every expert held: a slice's
 ])
-def test_row_budgets_are_the_shapes_alone(rows, held, total, want):
-    """Twice and four times the rows an even router sends here, in whole
-    512-row tiles, then all of them: no argument, key or model's name
-    chooses a rung (PR 34), this model's cell included."""
-    assert row_budgets(rows, held, total) == want
+def test_chunk_rows_are_the_shapes_alone(rows, held, total, cap, want):
+    """Twice the rows an even router sends here, no more than a slice's
+    picks, in whole 512-row tiles: no argument, key or model's name
+    chooses a chunk (PR 34's rungs, PR 39's chunks), this model's cell
+    included; and at the cells' shapes it is no wider than the parent's
+    top rung a slice."""
+    assert chunk_rows(rows, held, total, cap) == want <= cap
 
 
 @pytest.mark.parametrize("load", [32, 64, 128])
-def test_every_rung_is_the_relu_gated_layer_of_the_reference(load):
-    """``load`` picks routed to the two held experts fill the first, the
-    second and the top rung: on each the layer as this model calls it (the
-    router reads ANOTHER tensor than the experts, ReLU gate, softmax over
-    the picked logits) is the reference's, value and every gradient."""
+def test_over_any_chunks_it_is_the_relu_gated_layer_of_the_reference(load):
+    """``load`` picks routed to the two held experts fill one, two and all
+    four chunks: over each the layer as this model calls it (the router
+    reads ANOTHER tensor than the experts, ReLU gate, softmax over the
+    picked logits) is the reference's, value and every gradient."""
     params = routed_cases.layer("softmax")
     entered = routed_cases.tokens_routing(load)
     x = jax.random.normal(jax.random.key(41), entered.shape)
@@ -548,6 +553,7 @@ def test_every_rung_is_the_relu_gated_layer_of_the_reference(load):
                                  * routed_cases.TOP_K, routed_cases.TOTAL)
     assert rungs == routed_cases.RUNGS and int(counts.sum()) == load
     assert calls.tolist() == [int(r == load) for r in rungs]
+    assert load // routed_cases.CHUNK == routed_cases.LOADS[load]
     assert rel(got, want) < RTOL
     for name, leaf in flat(want_grads[0]).items():
         assert rel(flat(grads[0])[name], leaf) < RTOL, name
@@ -562,9 +568,9 @@ def test_rows_past_the_last_group_are_never_read(load):
 
 
 @pytest.mark.parametrize("top_k", [6, 8])
-def test_nothing_below_the_top_rung_is_as_wide_as_the_picks(top_k):
-    routed_cases.assert_nothing_below_the_top_rung_is_as_wide_as_the_picks(
-        "softmax_of_picked", top_k)
+def test_nothing_is_as_wide_as_the_picks(top_k):
+    routed_cases.assert_nothing_is_as_wide_as_the_picks("softmax_of_picked",
+                                                        top_k)
 
 
 # ---------------------------------------------------------------------------
