@@ -158,7 +158,9 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
                    rms_eps: float, xent_chunk: Optional[int], remat: str,
                    return_counts: bool, config: dict,
                    router_reads_input: bool = False,
-                   embed_scale: float = 1.0) -> ModelSpec:
+                   embed_scale: float = 1.0,
+                   final_scale: Callable = lambda p: p["scale"]
+                   ) -> ModelSpec:
     """What the decoders of this file and of ``swa_moe_lm.py`` share: the
     embedding, ``num_layers`` layers of an attention half (one sequence at
     a time) and an expert half (all of the step's tokens at once, as
@@ -171,8 +173,10 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
     ``router_reads_input``: the expert half is also handed the layer's
     INPUT, cut alike (``expert_half(lp, parts, input_parts)``: a router
     placed before attention).  ``embed_scale``: the stream
-    enters layer 0 as this times the table's rows.  ``set_pairs_gauges(
-    tokens)``: the model's own gauges, set while tracing."""
+    enters layer 0 as this times the table's rows.  ``final_scale(params[
+    "ln_final"])``: what the final norm multiplies by (a zero-centred
+    norm's ``1 + w``).  ``set_pairs_gauges(tokens)``: the model's own
+    gauges, set while tracing."""
     keep = jax.checkpoint_policies.save_only_these_names(*kept_names)
 
     @functools.cache
@@ -249,7 +253,7 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
             record_row_budgets(jnp.stack(counts), tokens.size * top_k,
                                num_experts, slices(x).shape[1] * top_k)
         with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
-            feats = rms_norm(x, params["ln_final"]["scale"], rms_eps)
+            feats = rms_norm(x, final_scale(params["ln_final"]), rms_eps)
         return feats, counts
 
     def apply_fn(params, tokens):

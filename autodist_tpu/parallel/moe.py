@@ -230,12 +230,15 @@ def moe_ffn(params: dict, x: jax.Array, *,
 def init_routed_moe_params(rng, d_model: int, d_expert: int,
                            num_experts: int, *, experts_held: int = None,
                            d_shared: int = 0, selection_bias: bool = True,
+                           shared_gate: bool = False,
                            dtype=jnp.float32) -> dict:
     """Router over all ``num_experts``, its selection bias (unless
     ``selection_bias`` is off: a softmax router has none), the SwiGLU
     weights of the ``experts_held`` experts that live here (leading axis:
     flag ``*/experts/*`` via ``expert_vars``) and, if ``d_shared``, one
-    dense SwiGLU of that width (the shared experts side by side)."""
+    dense SwiGLU of that width (the shared experts side by side);
+    ``shared_gate``: and the ``[d_model, 1]`` column whose sigmoid, one
+    number a token, its output is multiplied by."""
     held = num_experts if experts_held is None else experts_held
     r = jax.random.split(rng, 8)
 
@@ -255,6 +258,8 @@ def init_routed_moe_params(rng, d_model: int, d_expert: int,
         params["shared"] = {"w_gate": normal(r[5], d_model, d_shared),
                             "w_up": normal(r[6], d_model, d_shared),
                             "w_down": normal(r[7], d_shared, d_model)}
+    if shared_gate:
+        params["shared_gate"] = normal(jax.random.fold_in(rng, 8), d_model, 1)
     return params
 
 
@@ -544,7 +549,9 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
     ``scoring="softmax"``: ``s = softmax(x W_r)`` over all ``E`` (the
     Qwen3-MoE router with ``norm_topk_prob``); where ``params`` has no
     ``router_bias`` the selection is by the scores alone, and no
-    ``shared`` leaves means no shared expert.  ``scoring=
+    ``shared`` leaves means no shared expert; with a ``shared_gate`` leaf
+    ``[d, 1]`` the shared expert's output is multiplied by ``sigmoid(x
+    w_sg)``, one number a token (Qwen3-Next).  ``scoring=
     "softmax_of_picked"``: the same router computed without the softmax
     over all ``E``: the top-``k`` LOGITS are the top-``k`` of ``s`` and a
     softmax over them is ``s_e / sum_{j in S} s_j``; where the logits
@@ -667,5 +674,8 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
 
     if "shared" in params:
         with jax.named_scope(timeline.SCOPE_MOE_SHARED):
-            y = y + swiglu(params["shared"], h)
+            shared = swiglu(params["shared"], h)
+            if "shared_gate" in params:
+                shared = jax.nn.sigmoid(h @ params["shared_gate"]) * shared
+            y = y + shared
     return y.reshape(*lead, d), sizes

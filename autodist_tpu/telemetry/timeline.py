@@ -378,6 +378,10 @@ SCOPE_DSA_INDEX = "dsa/index"            # the indexer's projections, scores
 SCOPE_DSA_SELECT = "dsa/select"          # top-k of every row, packed words
 SCOPE_DSA_ATTENTION = "dsa/attention"    # the attention call alone
 SCOPE_SWA_ATTENTION = "swa/attention"    # window_attn or global_attn alone
+SCOPE_GATTN_ATTENTION = "gattn/attention"  # gated_attn, the call alone
+SCOPE_GDN_PROJECT = "gdn/project"        # qkvz, ba, the gated norm, out
+SCOPE_GDN_CONV = "gdn/conv"              # the causal convolution and its silu
+SCOPE_GDN_RECURRENCE = "gdn/recurrence"  # l2norm, g, beta, gdn_scan
 SCOPE_MOE_ROUTE = "moe/route"            # scores, top-k, sort, group sizes
 SCOPE_MOE_SHARED = "moe/shared"          # the shared experts (dense)
 SCOPE_MOE_EXPERTS = "moe/experts"        # gather, grouped products, SwiGLU

@@ -780,6 +780,103 @@ def train_swa_moe_phase(model: dict, *, batch_size: int, steps: int,
     return facts
 
 
+def check_gated_delta_rule(shape, *, chunk: int, interpret: bool, tol: float,
+                            grad_tokens: int = 1024) -> dict:
+    """``ops/gated_delta_rule.py`` (the chunked form with its scan kernel)
+    against the recurrence token by token, ``shape = (T, Hk, Hv, D)`` one
+    sequence: the forward over all ``T`` tokens, every input's gradient
+    over the first ``grad_tokens`` (the recurrence's backward holds a state
+    a token).  Twice: at jax's default precision (on a TPU the chunked
+    form's products take bfloat16 operands: within ``tol``) and at
+    ``highest`` (the same numbers: within 1e-4).  Decays as the model
+    makes them, 0.25 to 15.75 times a softplus."""
+    import jax
+    import jax.numpy as jnp
+
+    from autodist_tpu.models.gdn_moe_lm import decay_offsets, l2norm
+    from autodist_tpu.ops.gated_delta_rule import (gated_delta_rule,
+                                                   recurrence)
+
+    t, hk, hv, d = shape
+    ks = jax.random.split(jax.random.PRNGKey(SEED), 6)
+    q = l2norm(jax.random.normal(ks[0], (1, t, hk, d))) / math.sqrt(d)
+    k = l2norm(jax.random.normal(ks[1], (1, t, hk, d)))
+    v = jax.random.normal(ks[2], (1, t, hv, d))
+    g = -jnp.exp(decay_offsets(hv)) * jax.nn.softplus(
+        jax.random.normal(ks[3], (1, t, hv)) + 1.0)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (1, t, hv)))
+    ct = jax.random.normal(ks[5], (1, t, hv, d))
+    operands = (q, k, v, g, beta)
+    short = tuple(x[:, :min(t, grad_tokens)] for x in operands + (ct,))
+
+    def chunked(*a):
+        return gated_delta_rule(*a, chunk=chunk, interpret=interpret)
+
+    def gradients(fn):
+        return jax.jit(jax.grad(lambda *a: jnp.sum(fn(*a) * short[-1]),
+                                argnums=(0, 1, 2, 3, 4)))(*short[:-1])
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(recurrence)(*operands)
+        want_grads = gradients(recurrence)
+    facts = {}
+    for precision, limit in (("default", tol), ("highest", 1e-4)):
+        with jax.default_matmul_precision(precision):
+            errs = [_rel_err(jax.jit(chunked)(*operands), want)] + [
+                _rel_err(a, b) for a, b in zip(gradients(chunked),
+                                               want_grads)]
+        if not max(errs) <= limit:
+            raise AssertionError(
+                f"gated delta rule at {precision} precision, o and the "
+                f"gradients of q, k, v, g, beta: {errs} > {limit}")
+        facts[precision] = [float(f"{e:.3g}") for e in errs]
+    return facts
+
+
+def train_gdn_moe_phase(model: dict, *, batch_size: int, steps: int,
+                        tol: float) -> dict:
+    """``models/gdn_moe_lm.py`` with ONE LINEAR AND ONE FULL LAYER (the
+    gated delta rule; gated attention with the rotary on part of a head),
+    softmax-routed experts beside a gated shared expert, through
+    ``capture(has_aux=True)``: ``steps`` calls of ``sess.run`` on one fixed
+    batch, every loss finite, the per-expert token counts back, the gauges
+    of the recurrence's FLOPs as written and as computed.  Before it, the
+    recurrence's chunked form and kernel at the model's own shape against
+    the recurrence token by token (:func:`check_gated_delta_rule`).  On a
+    TPU the compiled step's Pallas calls are counted BY NAME: one
+    ``gdn_scan`` (the forward's; the backward is the plain form) and a
+    forward and a backward ``gated_attn``, none run twice."""
+    import jax
+
+    from autodist_tpu.models.gdn_moe_lm import gdn_moe_lm
+
+    spec = gdn_moe_lm(**model, return_counts=True)
+    cfg = spec.config
+    if (cfg["num_layers"], cfg["full_interval"]) != (2, 2):
+        raise AssertionError("one linear and one full layer are asked")
+    interpret = jax.devices()[0].platform != "tpu"
+    facts = {"recurrence_against_token_by_token": check_gated_delta_rule(
+        (cfg["seq_len"], cfg["linear_key_heads"], cfg["linear_value_heads"],
+         cfg["linear_head_dim"]), chunk=cfg["chunk"], interpret=interpret,
+        tol=tol)}
+    ad, sess, batch, stepped = expert_model_steps(
+        spec, batch_size=batch_size, steps=steps,
+        pairs="autodist_gdn_flops_per_step")
+    facts.update(stepped)
+    if not interpret:
+        text = sess.lower_step(batch).compile().as_text()
+        calls = {name: len(re.findall(
+            rf"%{name}[\w.\-]* = .*custom_call_target=\"tpu_custom_call\"",
+            text)) for name in ("gdn_scan", "gated_attn")}
+        print(f"  kernels of the step by name: {calls}", flush=True)
+        if calls != {"gdn_scan": 1, "gated_attn": 2}:
+            raise AssertionError(f"custom calls: {calls}")
+        facts["kernel_calls"] = calls
+        facts["bytes_in_use"] = memory_in_use(jax.devices()[:1])
+    close_session(ad, sess)
+    return facts
+
+
 # ---------------------------------------------------------------------------
 # server
 # ---------------------------------------------------------------------------
@@ -1034,6 +1131,11 @@ FULL_DSA_MOE = dict(vocab_size=18992, num_layers=1, experts_held=(0, 16),
 FULL_SWA_MOE = dict(vocab_size=18992, num_layers=2, window_layout=(0, 1),
                     rope_layout=(0, 1), experts_held=(0, 8), seq_len=16384,
                     xent_chunk=6400)
+# Qwen3-Next-80B-A3B-Instruct at its published widths (benchmark/configs/
+# qwen3-next-80b-a3b.ep16-share.json): one linear and one full layer, 32 of
+# 512 experts, 8,192 positions
+FULL_GDN_MOE = dict(vocab_size=18992, num_layers=2, full_interval=2,
+                    experts_held=(0, 32), seq_len=8192, xent_chunk=6400)
 FULL_SIZES = dict(p=64, prefix=512, tails=(40, 100), long=1024, mid=333,
                   n=(32, 48, 96, 128))
 FULL_ENGINE = dict(slots=8, window=2048, block_size=32, chunk=16)
@@ -1086,6 +1188,8 @@ def main() -> int:
               batch_size=1, steps=2)
     run_phase(watch, "train_swa_moe", train_swa_moe_phase, FULL_SWA_MOE,
               batch_size=1, steps=2, tol=2e-2)
+    run_phase(watch, "train_gdn_moe", train_gdn_moe_phase, FULL_GDN_MOE,
+              batch_size=2, steps=3, tol=2e-2)
     run_phase(watch, "serve_paged", serve_paged_phase, spec, params,
               sizes=FULL_SIZES, engine=FULL_ENGINE)
     run_phase(watch, "serve_slots", serve_slots_phase, spec, params,

@@ -14,10 +14,14 @@ the character.  The three expert models' were recorded anew by PR 37
 of a layer is one call over all of a step's tokens outside the map, the
 ladder's three branches a direction are a first chunk and a loop's body
 (a fifth to a quarter less text, one ``pallas_call`` less in each), and
-what is one number a pick moves by sorts and comparisons.  A PR that
+what is one number a pick moves by sorts and comparisons.  PR 40 (the
+Qwen3-Next share: ``routed_moe_ffn``'s ``shared_gate`` leaf,
+``routed_decoder``'s ``final_scale``, four scopes) changed NONE of the
+four and recorded its own model's.  A PR that
 changes one of these models' traces on purpose records the new hash here
 and says so in ``CHANGES.md``.
 """
+import functools
 import hashlib
 import importlib
 import json
@@ -29,6 +33,7 @@ import jax.numpy as jnp
 import pytest
 
 from autodist_tpu.ops.flash_attention import flash_attention
+from autodist_tpu.ops.gated_delta_rule import gated_delta_rule
 
 CONFIGS = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir,
                        "benchmark", "configs")
@@ -46,11 +51,23 @@ TRACES = {
     "smallthinker-21b-a3b.ep8-share": (
         1, 16384, 286681, 14,
         "a72c5f085fb2840068171a70c53911d5943ef75dbc4a46c21add6cb171dd8f4e"),
+    "qwen3-next-80b-a3b.ep16-share": (
+        2, 8192, 601252, 16,
+        "906875ddb503f82b248a786e866cd31dca9df9af70b007d14d47b031ebd979b1"),
 }
 
 
 def kernel(q, k, v, causal, **kw):
     return flash_attention(q, k, v, causal, interpret=False, **kw)
+
+
+def compiled_kernels(factory: str, kwargs: dict) -> dict:
+    """The factory's kernels in their compiled form: the flash kernel for
+    all, and the gated delta rule's scan for the model that has one."""
+    if factory != "gdn_moe_lm":
+        return {"attn_fn": kernel}
+    return {"attn_fn": kernel, "gdn_fn": functools.partial(
+        gated_delta_rule, chunk=kwargs["chunk"], interpret=False)}
 
 
 @pytest.mark.parametrize("name", sorted(TRACES))
@@ -65,7 +82,7 @@ def test_value_and_gradient_trace_to_the_recorded_text(name, monkeypatch):
     kwargs = dict(program["kwargs"])
     kwargs["dtype"] = getattr(jnp, kwargs["dtype"])
     spec = getattr(importlib.import_module(module), factory)(
-        **kwargs, attn_fn=kernel)
+        **kwargs, **compiled_kernels(factory, kwargs))
     shapes = jax.eval_shape(spec.init, jax.random.key(0))
     batch = {"tokens": jax.ShapeDtypeStruct((rows, t), jnp.int32)}
     text = str(jax.make_jaxpr(jax.value_and_grad(spec.loss_fn))(shapes,

@@ -115,6 +115,30 @@ def test_train_swa_moe_phase_checks_both_kinds_of_layer():
             batch_size=1, steps=1, tol=1e-4)
 
 
+def test_train_gdn_moe_phase_checks_the_recurrence_and_steps_the_model():
+    model = dict(vocab_size=61, num_layers=2, d_model=32, full_interval=2,
+                 linear_key_heads=2, linear_value_heads=4, linear_head_dim=8,
+                 num_heads=4, num_kv_heads=2, head_dim=16, rotary_dim=4,
+                 d_expert=12, d_shared=12, num_experts=16,
+                 experts_held=(4, 4), top_k=3, seq_len=64, chunk=16,
+                 block_k=32, moe_slice=64)
+    facts = chip_smoke.train_gdn_moe_phase(model, batch_size=2, steps=3,
+                                           tol=1e-4)
+    assert len(facts["losses"]) == 3 and all(np.isfinite(facts["losses"]))
+    assert len(facts["tokens_per_expert"]) == 2
+    # o and five gradients, at both precisions (a CPU's are the same)
+    gaps = facts["recurrence_against_token_by_token"]
+    assert set(gaps) == {"default", "highest"}
+    assert all(len(v) == 6 and max(v) < 1e-4 for v in gaps.values())
+    # one linear layer x 2 x 64 tokens x 4 value heads
+    per = facts["pairs_per_step"]
+    assert per["recurrence"] == 7 * 8 * 8 * 512
+    assert per["computed"] >= per["recurrence"]
+    with pytest.raises(AssertionError, match="one linear and one full"):
+        chip_smoke.train_gdn_moe_phase(dict(model, full_interval=4),
+                                       batch_size=1, steps=1, tol=1e-4)
+
+
 def test_serve_phases_over_http(lm):
     facts = chip_smoke.serve_paged_phase(
         *lm, sizes=TINY_SIZES, engine=TINY_ENGINE)

@@ -597,6 +597,50 @@ def _grouped_dense(q, k, v, mask):
     return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
 
 
+def test_sixteen_heads_over_two_at_a_width_of_256():
+    """The Qwen3-Next full layer's heads (issue 40): 16 query heads over 2
+    key/value heads of 256, forward and gradients against the plain
+    softmax; each key/value head's gradient is the sum of its eight query
+    heads'."""
+    rng = np.random.default_rng(40)
+    q, k, v = _grouped_qkv(rng, 1, 64, 16, 2, d=256)
+    mask = jnp.tril(jnp.ones((64, 64), bool))[None]
+    w = jnp.asarray(rng.standard_normal((1, 64, 16, 256)), jnp.float32)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, True, block_q=32, block_k=32,
+                               interpret=True)
+
+    np.testing.assert_allclose(flash(q, k, v), _grouped_dense(q, k, v, mask),
+                               rtol=1e-5, atol=1e-5)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(_grouped_dense(*a, mask) * w),
+                    (0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        assert g.shape == r.shape
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=2e-5)
+
+
+def test_vmem_estimate_at_a_width_of_256():
+    """By ``_compiler_params``' own arithmetic the backward at 256 wide and
+    float32 in holds 8 KB a row: 8,192 rows ask for what 16,384 rows of
+    128 ask for (the keye and smallthinker cells run that), within what
+    the v5e leaves a kernel; 16,384 rows at 256 would be the whole VMEM,
+    and the limit stops at 100 MiB."""
+    fa = _module()
+
+    def limit(t, d, block=512):
+        return fa._compiler_params(
+            t, block, [(d, 4)] * 4 + [(d, 4)] * 2
+            + [(d, 2), (d, 2), (d, 4)],
+            [(d, 4)] * 4 + [(d, 4)] * 4, (d, d)).vmem_limit_bytes
+
+    assert limit(8192, 256) - (8 << 20) == pytest.approx(
+        limit(16384, 128) - (8 << 20), rel=0.1)
+    assert 64 << 20 < limit(8192, 256) < 100 << 20
+    assert limit(16384, 256) == 100 << 20
+
+
 def _grouped_qkv(rng, b, t, heads, kv_heads, d=16):
     return tuple(jnp.asarray(rng.standard_normal((b, t, h, d)), jnp.float32)
                  for h in (heads, kv_heads, kv_heads))

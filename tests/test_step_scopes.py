@@ -22,11 +22,13 @@ from autodist_tpu.autodist import (
     _reset_default_autodist_for_testing,
 )
 from autodist_tpu.mesh import build_mesh
+from autodist_tpu.models.gdn_moe_lm import gdn_moe_lm
 from autodist_tpu.models.gqa_dsa_moe_lm import gqa_dsa_moe_lm
 from autodist_tpu.models.mla_moe_lm import mla_moe_lm
 from autodist_tpu.models.swa_moe_lm import swa_moe_lm
 from autodist_tpu.models.transformer_lm import transformer_lm
 from autodist_tpu.ops import flash_attention
+from autodist_tpu.ops.gated_delta_rule import gated_delta_rule
 from autodist_tpu.telemetry import timeline
 
 FLASH = functools.partial(flash_attention, interpret=True, block_q=32,
@@ -34,7 +36,7 @@ FLASH = functools.partial(flash_attention, interpret=True, block_q=32,
 ROUTED = dict(vocab_size=61, d_model=32, d_expert=12, num_experts=16,
               top_k=3, experts_held=(0, 4), xent_chunk=32,
               train_router=False, attn_fn=FLASH)
-#: the four factories of the benchmark's five cells, each as its cell
+#: the five factories of the benchmark's six cells, each as its cell
 #: runs it (the kernel and not the dense softmax, the chunked loss where
 #: the configuration asks for it, checkpoints, maps over sequences)
 FACTORIES = {
@@ -53,6 +55,13 @@ FACTORIES = {
         ROUTED, num_layers=2, num_heads=4, num_kv_heads=2, head_dim=16,
         window=40, window_layout=(0, 1), rope_layout=(0, 1), seq_len=96,
         block_k=32, moe_slice=96)),
+    "gdn_moe_lm": (gdn_moe_lm, dict(
+        ROUTED, num_layers=2, full_interval=2, linear_key_heads=2,
+        linear_value_heads=4, linear_head_dim=8, num_heads=4,
+        num_kv_heads=2, head_dim=16, rotary_dim=4, d_shared=12, seq_len=64,
+        chunk=16, block_k=32, moe_slice=64,
+        gdn_fn=functools.partial(gated_delta_rule, chunk=16, segment=2,
+                                 interpret=True))),
 }
 VOCABULARY = {value for name, value in vars(timeline).items()
               if name.startswith("SCOPE_")}
