@@ -48,7 +48,7 @@ sequence at a time, the experts once over the step's tokens.  Kept by
 name over the layers' checkpoints: the flash kernel's ``o`` and ``lse``,
 the routing integers, and the recurrence's ``o`` and the states its
 segments are entered with (``gated_delta_rule.RESIDUAL_NAMES``: 134 MB
-and 8 MB a sequence and layer at the published widths): with them the
+and 34 MB a sequence and layer at the published widths): with them the
 backward's recomputation of a linear mixer stops at the projections and
 the convolution and never runs the scan's forward kernel a second time.
 """

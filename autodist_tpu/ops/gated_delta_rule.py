@@ -36,14 +36,36 @@ the last, every operand read once, ``O`` written once, and ``S`` written
 out where a SEGMENT of ``segment`` chunks begins.  Elsewhere it is
 ``lax.scan`` over the same three products in ``jax.numpy``
 (:func:`_scan_plain`).  **The backward** (:func:`_rule_bwd`; the whole is
-a ``jax.custom_vjp``) walks the segments in reverse: each is the plain
-form's own transpose (``jax.vjp`` of :func:`_segment`: what no state
-enters formed again for the segment, its scan forward from the state the
-forward wrote, then back), the state's cotangent carried from segment to
-segment.  Nothing of it is wider than a segment (at 32 chunks of 64 a
-quarter of an 8,192-token sequence: ~1 GB where the whole sequence asks
-3.8), and the scan is walked three times in all, never more often for
-being cut.
+a ``jax.custom_vjp``) walks the segments in reverse from the states the
+forward wrote, the state's cotangent carried from segment to segment;
+nothing of it is wider than a segment (at 8 chunks of 64 a sixteenth of
+an 8,192-token sequence: XLA keeps more of so small a segment's batched
+lines in VMEM, and a step of the cell measured 628 ms at 8, 631 at 16
+and 644 at 32, PR 41).  Where the forward's scan is the kernel a segment
+is written out (:func:`_segment_bwd`): what no state enters formed again
+for the segment; the scan's transpose ONE Pallas kernel (HLO name
+``gdn_scan_bwd``, a scope inside ``gdn_scan``), one program a (sequence,
+value head) over twice the segment's chunks, ``_CHUNKS_A_TURN`` a turn of
+the grid, which first runs the segment forward keeping every chunk's
+entering state and ``V'`` in VMEM scratch (0.75 MB at 8 chunks of 64 x
+128), then walks the chunks back with ``dS [Dk, Dv]`` float32 in scratch,
+entered with the next segment's and written out once at the segment's
+head:
+
+    dV' = Aqk^T dO + Kd dS'    dQg = dO S^T     dAqk = dO V'^T
+    dKd = V' dS'^T             dU = dV'         dW = -dV' S^T
+    dgc = sum(S * dS')         dS = Qg^T dO + gc dS' - W^T dV'
+
+(two products a chunk forward, eight back, rounded as the forward
+kernel's; no turn's residual goes to main memory); and the cotangents it
+hands back pulled through :func:`_prepare`'s batched lines by ``jax.vjp``,
+through ``T = (I + A)^-1`` by the inverse's own rule ``dA = -(T^T dT
+T^T)``, two products a block at ``highest`` (:func:`_inverse_by_rule`),
+not by autodiff through the doublings and the joins.  Where the forward
+is the plain form (``interpret`` None off the TPU) a segment is the plain
+form's own transpose (``jax.vjp`` of :func:`_segment`): the two backwards
+share :func:`_prepare` and nothing else, and the tests hold the one to
+the other.
 
 Numbers.  ``exp`` is only ever formed of differences ``G_i - G_j`` with
 ``i >= j`` (masked BEFORE the ``exp``), of ``G_i`` and of ``G_C - G_i``,
@@ -75,7 +97,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from autodist_tpu.ops import pallas_utils
-from autodist_tpu.ops.flash_attention import _NN, _dot, _product_operand
+from autodist_tpu.ops.flash_attention import (
+    _NN,
+    _NT,
+    _dot,
+    _product_operand,
+)
 
 _HIGHEST = lax.Precision.HIGHEST
 #: The forward's output ``[B, T, Hv, Dv]`` and the states its segments are
@@ -87,6 +114,8 @@ RESIDUAL_NAMES = ("gated_delta_rule/o", "gated_delta_rule/states")
 #: the scope the chunked form runs under: the Pallas call's HLO name, and
 #: what every operation of it, forward and backward, carries in ``tf_op``
 KERNEL_NAME = "gdn_scan"
+#: the scope, inside it, of the backward's scan kernel: its HLO name
+BWD_KERNEL_NAME = "gdn_scan_bwd"
 
 
 def recurrence(q, k, v, g, beta):
@@ -168,11 +197,35 @@ def _inverse(a):
     return inv
 
 
-def _prepare(q, k, v, g, beta, chunk: int):
+@jax.custom_vjp
+def _inverse_by_rule(a):
+    """:func:`_inverse` whose cotangent is the inverse's own rule, ``dA =
+    -(T^T dT T^T)`` (two products a block at ``highest``), in place of
+    autodiff walking the doublings and the joins back; what of it lies
+    outside the strictly lower triangle is the caller's mask's to drop."""
+    return _inverse(a)
+
+
+def _inverse_fwd(a):
+    t = _inverse(a)
+    return t, t
+
+
+def _inverse_bwd(t, dt):
+    tt = jnp.swapaxes(t, -1, -2)
+    return (-jnp.matmul(jnp.matmul(tt, dt, precision=_HIGHEST), tt,
+                        precision=_HIGHEST),)
+
+
+_inverse_by_rule.defvjp(_inverse_fwd, _inverse_bwd)
+
+
+def _prepare(q, k, v, g, beta, chunk: int, inverse=_inverse):
     """What no state enters, for all chunks at once.  Returns ``(qg, w, u,
     aqk, kd, gc)`` in the scan's layout ``[P, N, ..]`` (``P = B * Hv``
     programs, ``N`` chunks): ``qg, w, kd [P, N, C, Dk]``, ``u [P, N, C,
-    Dv]``, ``aqk [P, N, C, C]``, ``gc [P, N]``."""
+    Dv]``, ``aqk [P, N, C, C]``, ``gc [P, N]``.  ``inverse`` forms ``T``
+    (the written-out backward hands :func:`_inverse_by_rule`)."""
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
     r, n, c = hv // hk, t // chunk, chunk
@@ -191,7 +244,7 @@ def _prepare(q, k, v, g, beta, chunk: int):
     kk = jnp.einsum("bhsnid,bhsnjd->bhsnij", kc, kc)
     qk = jnp.einsum("bhsnid,bhsnjd->bhsnij", qc, kc)
     a = jnp.where(cols < rows, bc[..., None] * kk * decay, 0.0)
-    inv = _inverse(a)
+    inv = inverse(a)
     e_g = jnp.exp(gs)[..., None]
     w = jnp.matmul(inv, bc[..., None] * e_g * kc)
     u = jnp.matmul(inv, bc[..., None] * vc)
@@ -268,10 +321,134 @@ def _scan_pallas(qg, w, u, aqk, kd, gc, segment: int, interpret: bool):
       jnp.broadcast_to(gc[..., None, None], (p, n, 1, dv)))
 
 
+def _scan_bwd_kernel(qg_ref, w_ref, u_ref, aqk_ref, kd_ref, gc_ref, s0_ref,
+                     do_ref, dsn_ref, dqg_ref, dw_ref, du_ref, daqk_ref,
+                     dkd_ref, dgc_ref, ds0_ref, s_ref, ds_ref, states_ref,
+                     vp_ref, *, operand):
+    """One turn of one (sequence, value head) over a segment of ``N``
+    chunks, ``H`` chunks a turn, ``2 N / H`` turns: the first half run the
+    segment forward from ``s0 [Dk, Dv]`` and keep every chunk's entering
+    state and ``V'`` in scratch (``states [N, Dk, Dv]``, ``vp [N, C,
+    Dv]``); the second half walk the chunks back, ``ds [Dk, Dv]`` float32
+    in scratch from ``dsn`` (the cotangent of the state the segment
+    leaves) to ``ds0`` (of the state it is entered with), written once.
+    Refs of a turn's chunks as :func:`_scan_kernel`'s of one, ``do`` and
+    the cotangents in their operands' shapes, ``dgc [1, Dv]`` the sums
+    over ``Dk`` of ``S * dS'``."""
+    j = pl.program_id(1)
+    turns = pl.num_programs(1) // 2
+    per = qg_ref.shape[1]
+    dot = functools.partial(_dot, operand=operand)
+
+    @pl.when(j == 0)
+    def _():
+        s_ref[...] = s0_ref[0]
+
+    @pl.when(j < turns)
+    def _():
+        s = s_ref[...]
+        for h in range(per):
+            c = j * per + h
+            states_ref[c] = s
+            vp = u_ref[0, h] - dot(w_ref[0, h], s, _NN)
+            vp_ref[c] = vp
+            s = s * gc_ref[0, h] + dot(kd_ref[0, h].T, vp, _NN)
+        s_ref[...] = s
+
+    @pl.when(j == turns)
+    def _():
+        ds_ref[...] = dsn_ref[0]
+
+    @pl.when(j >= turns)
+    def _():
+        ds = ds_ref[...]
+        for h in reversed(range(per)):
+            c = (2 * turns - 1 - j) * per + h
+            s, vp, do = states_ref[c], vp_ref[c], do_ref[0, h]
+            qg, w, kd = qg_ref[0, h], w_ref[0, h], kd_ref[0, h]
+            dvp = dot(aqk_ref[0, h].T, do, _NN) + dot(kd, ds, _NN)
+            dqg_ref[0, h] = dot(do, s, _NT)
+            dw_ref[0, h] = -dot(dvp, s, _NT)
+            du_ref[0, h] = dvp
+            daqk_ref[0, h] = dot(do, vp, _NT)
+            dkd_ref[0, h] = dot(vp, ds, _NT)
+            dgc_ref[0, h] = jnp.sum(s * ds, axis=0, keepdims=True)
+            ds = dot(qg.T, do, _NN) + ds * gc_ref[0, h] - dot(w.T, dvp, _NN)
+        ds_ref[...] = ds
+
+        @pl.when(j == 2 * turns - 1)
+        def _():
+            ds0_ref[0] = ds
+
+
+#: chunks a turn of the backward kernel's grid takes (as many of them as
+#: divide a segment): a segment of 32 alone on the chip took 1.45 ms at 1,
+#: 1.16 at 2, 1.01 at 4, 0.96 at 8 and 0.94 at 16 (PR 41)
+_CHUNKS_A_TURN = 4
+
+
+def _scan_bwd_pallas(qg, w, u, aqk, kd, gc, s0, do, dsn, interpret: bool):
+    """The scan's transpose over one segment: the cotangents of ``(qg, w,
+    u, aqk, kd, gc)`` in their shapes and of ``s0 [P, Dk, Dv]``, from ``do
+    [P, N, C, Dv]`` and ``dsn [P, Dk, Dv]``.  A block a forward turn does
+    not read (or no turn writes yet) stays at the last chunks, the first
+    the walk back takes: nothing is fetched or written back twice."""
+    p, n, c, dk = qg.shape
+    dv = u.shape[-1]
+    f32 = jnp.float32
+    per = math.gcd(n, _CHUNKS_A_TURN)
+    turns = n // per
+
+    def forth(i, j):     # turn j forward, then the last chunks
+        return i, jnp.minimum(j, turns - 1), 0, 0
+
+    def back(i, j):      # the last chunks until the walk back begins
+        return i, jnp.minimum(turns - 1, 2 * turns - 1 - j), 0, 0
+
+    def both(i, j):      # turn j forward, then the turns in reverse
+        return i, jnp.minimum(j, 2 * turns - 1 - j), 0, 0
+
+    def block(*shape, index=back):
+        return pl.BlockSpec((1, per) + shape, index)
+
+    def like(*arrays):
+        return tuple(jax.ShapeDtypeStruct(x.shape, f32) for x in arrays)
+
+    state = pl.BlockSpec((1, dk, dv), lambda i, j: (i, 0, 0))
+    gc = jnp.broadcast_to(gc[..., None, None], (p, n, 1, dv))
+    with jax.named_scope(BWD_KERNEL_NAME):
+        *cotangents, dgc, ds0 = pl.pallas_call(
+            functools.partial(_scan_bwd_kernel,
+                              operand=_product_operand(interpret)),
+            out_shape=like(qg, w, u, aqk, kd, gc, s0),
+            grid=(p, 2 * turns),
+            in_specs=[block(c, dk), block(c, dk, index=both),
+                      block(c, dv, index=forth), block(c, c),
+                      block(c, dk, index=both), block(1, dv, index=both),
+                      state, block(c, dv), state],
+            out_specs=(block(c, dk), block(c, dk), block(c, dv), block(c, c),
+                       block(c, dk), block(1, dv), state),
+            scratch_shapes=[pltpu.VMEM((dk, dv), f32),
+                            pltpu.VMEM((dk, dv), f32),
+                            pltpu.VMEM((n, dk, dv), f32),
+                            pltpu.VMEM((n, c, dv), f32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+        )(qg, w, u, aqk, kd, gc, s0, do, dsn)
+    return (*cotangents, dgc.sum(axis=(-2, -1))), ds0
+
+
 def _tokens(o, b: int):
     """``[B * Hv, N, C, Dv] -> [B, T, Hv, Dv]``."""
     p, n, c, dv = o.shape
     return o.reshape(b, p // b, n * c, dv).transpose(0, 2, 1, 3)
+
+
+def _chunks(o, chunk: int):
+    """:func:`_tokens` undone: ``[B, T, Hv, Dv] -> [B * Hv, N, C, Dv]``."""
+    b, t, hv, dv = o.shape
+    return o.transpose(0, 2, 1, 3).reshape(b * hv, t // chunk, chunk, dv)
 
 
 def _segment(q, k, v, g, beta, s, chunk: int):
@@ -327,18 +504,37 @@ def _rule_fwd(q, k, v, g, beta, chunk, segment, interpret):
     return o, (q, k, v, g, beta, states)
 
 
+def _segment_bwd(xs, s_in, do_seg, ds, chunk, interpret):
+    """One segment's transpose: ``(the cotangents of its five operands, of
+    the state it is entered with)``.  Where the forward was plain
+    (``interpret`` None), ``jax.vjp`` of the plain form.  Where its scan
+    was the kernel, written out: what no state enters formed again, the
+    scan's transpose ONE kernel (:func:`_scan_bwd_pallas`), and the
+    cotangents it hands back pulled through :func:`_prepare`'s few batched
+    lines by ``jax.vjp``, through ``T`` by the inverse's own rule."""
+    if interpret is None:
+        _, pull = jax.vjp(lambda *a: _segment(*a, chunk), *xs, s_in)
+        *dxs, ds = pull((do_seg, ds))
+        return tuple(dxs), ds
+    with jax.named_scope("prepare_again"):
+        prepared, pull = jax.vjp(functools.partial(
+            _prepare, chunk=chunk, inverse=_inverse_by_rule), *xs)
+    cotangents, ds = _scan_bwd_pallas(*prepared, s_in,
+                                      _chunks(do_seg, chunk), ds, interpret)
+    with jax.named_scope("transposes"):
+        return pull(cotangents), ds
+
+
 def _rule_bwd(chunk, segment, interpret, res, do):
-    """The segments in reverse, each the plain form's own transpose from
-    the state the forward entered it with: the cotangent of the state
-    walks back through them."""
+    """The segments in reverse from the states the forward entered them
+    with, the cotangent of the state walking back through them."""
     *operands, states = res
     count = states.shape[1]
 
     def one(ds, x):
         *xs, s_in, do_seg = x
-        _, pull = jax.vjp(lambda *a: _segment(*a, chunk), *xs, s_in)
-        *dxs, ds = pull((do_seg, ds))
-        return ds, tuple(dxs)
+        dxs, ds = _segment_bwd(xs, s_in, do_seg, ds, chunk, interpret)
+        return ds, dxs
 
     xs = tuple(_segments(x, count) for x in operands) + (
         jnp.moveaxis(states, 1, 0), _segments(do.astype(jnp.float32), count))
@@ -351,7 +547,7 @@ _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
-                     segment: int = 32, interpret: Optional[bool] = None):
+                     segment: int = 8, interpret: Optional[bool] = None):
     """``o [B, T, Hv, Dv]`` of the gated delta rule over ``q, k [B, T, Hk,
     Dk]`` (L2-normed, ``q`` scaled), ``v [B, T, Hv, Dv]``, ``g, beta [B,
     T, Hv]``; ``T % chunk == 0``, ``chunk`` a power of two.  ``segment``:

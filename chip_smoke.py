@@ -844,8 +844,9 @@ def train_gdn_moe_phase(model: dict, *, batch_size: int, steps: int,
     recurrence's chunked form and kernel at the model's own shape against
     the recurrence token by token (:func:`check_gated_delta_rule`).  On a
     TPU the compiled step's Pallas calls are counted BY NAME: one
-    ``gdn_scan`` (the forward's; the backward is the plain form) and a
-    forward and a backward ``gated_attn``, none run twice."""
+    ``gdn_scan`` (the forward's), one ``gdn_scan_bwd`` (the written-out
+    backward's scan) and a forward and a backward ``gated_attn``, none run
+    twice."""
     import jax
 
     from autodist_tpu.models.gdn_moe_lm import gdn_moe_lm
@@ -866,10 +867,10 @@ def train_gdn_moe_phase(model: dict, *, batch_size: int, steps: int,
     if not interpret:
         text = sess.lower_step(batch).compile().as_text()
         calls = {name: len(re.findall(
-            rf"%{name}[\w.\-]* = .*custom_call_target=\"tpu_custom_call\"",
-            text)) for name in ("gdn_scan", "gated_attn")}
+            rf"%{name}[.\d]* = .*custom_call_target=\"tpu_custom_call\"",
+            text)) for name in ("gdn_scan", "gdn_scan_bwd", "gated_attn")}
         print(f"  kernels of the step by name: {calls}", flush=True)
-        if calls != {"gdn_scan": 1, "gated_attn": 2}:
+        if calls != {"gdn_scan": 1, "gdn_scan_bwd": 1, "gated_attn": 2}:
             raise AssertionError(f"custom calls: {calls}")
         facts["kernel_calls"] = calls
         facts["bytes_in_use"] = memory_in_use(jax.devices()[:1])

@@ -17,9 +17,12 @@ ladder's three branches a direction are a first chunk and a loop's body
 what is one number a pick moves by sorts and comparisons.  PR 40 (the
 Qwen3-Next share: ``routed_moe_ffn``'s ``shared_gate`` leaf,
 ``routed_decoder``'s ``final_scale``, four scopes) changed NONE of the
-four and recorded its own model's.  A PR that
-changes one of these models' traces on purpose records the new hash here
-and says so in ``CHANGES.md``.
+four and recorded its own model's; PR 42 recorded that one anew, ON
+PURPOSE (the gated delta rule's backward written out, its scan a second
+kernel, segments of 8 chunks: 55,189 characters and two ``pallas_call``
+texts more), and left the four others alone.  A PR that changes one of
+these models' traces on purpose records the new hash here and says so in
+``CHANGES.md``.
 """
 import functools
 import hashlib
@@ -52,8 +55,8 @@ TRACES = {
         1, 16384, 286681, 14,
         "a72c5f085fb2840068171a70c53911d5943ef75dbc4a46c21add6cb171dd8f4e"),
     "qwen3-next-80b-a3b.ep16-share": (
-        2, 8192, 601252, 16,
-        "906875ddb503f82b248a786e866cd31dca9df9af70b007d14d47b031ebd979b1"),
+        2, 8192, 656441, 18,
+        "80ef56c7495308632ff490eba90cf0d0ed8dec5c173acebc68348ab87c8fc2ee"),
 }
 
 

@@ -441,3 +441,58 @@ def test_expert_cell_step_fits_the_chip(one_chip, cell, monkeypatch):
         {"tokens": jax.ShapeDtypeStruct((rows, kwargs["seq_len"]), jnp.int32,
                                         sharding=one_chip)}).compile()
     assert _need_gb(compiled) * 1e9 < _BYTES_LIMIT, _need_gb(compiled)
+
+
+#: what a kernel may hold in VMEM on a v5e unless it asks for more
+#: (Mosaic's default scoped limit)
+_VMEM_DEFAULT = 16 * 2 ** 20
+#: the compiler's reading of value and gradient of the rule alone at the
+#: cell's shapes stays under this (0.685 GB at the segment shipped, PR 41)
+_RULE_TEMPORARIES_GB = 0.8
+
+
+def test_gated_delta_rule_and_its_written_out_backward_compile(one_chip):
+    """Value and gradient of the gated delta rule at the qwen3-next cell's
+    shapes (one sequence of 8,192 tokens, 16 key heads under 32 value
+    heads of 128, chunk 64, the segment shipped) compiled for a described
+    v5e: the forward's scan kernel and the backward's both in the text by
+    name, under ``gdn_scan`` no loop but the walk over segments, the
+    backward kernel's VMEM (a segment's states and ``V'`` in scratch, its
+    blocks twice) under what a kernel may hold, the call's temporaries
+    under the limit above."""
+    import inspect
+
+    from autodist_tpu.ops import gated_delta_rule as gdr
+
+    t, hk, hv, d, chunk = 8192, 16, 32, 128, 64
+    segment = inspect.signature(
+        gdr.gated_delta_rule).parameters["segment"].default
+
+    def on(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def loss(*operands):
+        return jnp.sum(gdr.gated_delta_rule(*operands, chunk=chunk,
+                                            interpret=False) ** 2)
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
+                       ).lower(on(1, t, hk, d), on(1, t, hk, d),
+                               on(1, t, hv, d), on(1, t, hv),
+                               on(1, t, hv)).compile()
+    text = compiled.as_text()
+    kernels = re.findall(r"%([\w.\-]+) = .*custom_call_target="
+                         r"\"tpu_custom_call\"", text)
+    backward = [name for name in kernels
+                if name.startswith(gdr.BWD_KERNEL_NAME)]
+    forward = [name for name in kernels if gdr.KERNEL_NAME in name
+               and name not in backward]
+    assert (len(forward), len(backward), len(kernels)) == (1, 1, 2), kernels
+    assert len(re.findall(r" while\(", text)) == 1
+    scratch = 4 * ((2 + segment) * d * d + segment * chunk * d)
+    # a turn's chunks of nine [C, D] operands and cotangents, of Aqk and
+    # its, of gc and its (a row pads to eight); the three states
+    blocks = 4 * (gdr._CHUNKS_A_TURN * (
+        9 * chunk * d + 2 * chunk * chunk + 2 * 8 * d) + 3 * d * d)
+    assert scratch + 2 * blocks < _VMEM_DEFAULT, (scratch, blocks)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < _RULE_TEMPORARIES_GB * 1e9

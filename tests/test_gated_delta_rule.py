@@ -3,7 +3,13 @@ the Pallas interpreter) against the recurrence token by token, at
 ``highest``: the forward and every input's gradient, chunks of 16 and 64,
 backward segments of one chunk and of all, value heads sharing key heads,
 and decays down to -20 a token with nothing but finite numbers anywhere.
+The kernel's backward is written out (its scan a second kernel, ``T``'s
+cotangent by the inverse's own rule): held to the recurrence's gradient, to
+``jax.vjp`` of the plain form, and piece by piece to ``jax.vjp`` of
+``_scan_plain`` and of ``_inverse``.
 """
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -79,6 +85,80 @@ def test_decays_of_minus_twenty_a_token_stay_finite(kernel, chunk):
     assert max(gaps(grads, g_want)) < 5e-5
 
 
+def plain_and_written_out(args, chunk, segment):
+    """Value and the five gradients of the plain form (``jax.vjp`` of it a
+    segment at a time) and of the kernel's, written out."""
+    return [value_and_grads(lambda *a: gated_delta_rule(
+        *a, chunk=chunk, segment=segment, interpret=interpret), args)
+        for interpret in (None, True)]
+
+
+@pytest.mark.parametrize("chunk,segment", [(16, 32), (16, 2), (64, 1)],
+                         ids=["whole", "pairs", "single"])
+@pytest.mark.parametrize("rate", [1.0, 0.05, 16.0])
+def test_written_out_backward_is_the_plain_forms_transpose(chunk, segment,
+                                                           rate):
+    """Two value heads a key head (``operands``' default), a segment that
+    is the whole sequence, one of two chunks and one of a single chunk,
+    ``g`` from -0.1 to under -20 a token (``rate`` 16): every cotangent of
+    the written-out backward against ``jax.vjp`` of the plain form, with
+    which it shares ``_prepare`` and nothing of the backward, and against
+    the recurrence's, every number finite."""
+    args = operands(seed=1, rate=rate)
+    assert (float(args[3].min()) < -20.0) == (rate == 16.0)
+    (want, g_want), (got, g_got) = plain_and_written_out(args, chunk, segment)
+    assert all(bool(jnp.all(jnp.isfinite(x))) for x in (got,) + g_got)
+    assert abs(float(got - want)) < 1e-5 * (1 + abs(float(want)))
+    assert max(gaps(g_got, g_want)) < 1e-5
+    _, g_token = value_and_grads(recurrence, args)
+    assert max(gaps(g_got, g_token)) < 5e-5
+
+
+@pytest.mark.parametrize("segment", [1, 2, 4])
+def test_states_cotangent_is_carried_across_segments(segment):
+    """The gradient does not change with ``segment``: cut into eight, four
+    or two segments, ``dS`` handed from each to the one before it, the
+    written-out backward gives what it gives over the whole sequence (one
+    segment, which carries nothing)."""
+    args = operands(seed=7)
+    whole, cut = (value_and_grads(lambda *a: gated_delta_rule(
+        *a, chunk=16, segment=s, interpret=True), args)[1]
+        for s in (8, segment))
+    assert max(gaps(cut, whole)) < 2e-6
+
+
+@pytest.mark.parametrize("c,dk,dv", [(16, 32, 32), (64, 16, 128)])
+def test_scan_backward_kernel_is_the_plain_scans_transpose(c, dk, dv):
+    """One segment of five chunks entered with a state and left with a
+    state's cotangent: all seven cotangents against ``jax.vjp`` of
+    ``_scan_plain``."""
+    p, n = 3, 5
+    ks = jax.random.split(jax.random.key(2), 9)
+    shapes = [(p, n, c, dk), (p, n, c, dk), (p, n, c, dv), (p, n, c, c),
+              (p, n, c, dk), (p, n), (p, dk, dv)]
+    *xs, s0 = (0.3 * jax.random.normal(key, shape)
+               for key, shape in zip(ks, shapes))
+    xs[5] = jax.nn.sigmoid(xs[5])                        # gc in (0, 1)
+    do = jax.random.normal(ks[7], (p, n, c, dv))
+    dsn = jax.random.normal(ks[8], (p, dk, dv))
+    _, pull = jax.vjp(gdr._scan_plain, *xs, s0)
+    want = pull((do, dsn))
+    cotangents, ds0 = gdr._scan_bwd_pallas(*xs, s0, do, dsn, True)
+    assert max(gaps(cotangents + (ds0,), want)) < 1e-5
+
+
+@pytest.mark.parametrize("c", [16, 64])
+def test_inverse_by_rule_is_the_inverses_transpose(c):
+    """``dA = -(T^T dT T^T)`` against autodiff through the doublings and
+    the joins, on the strictly lower triangle (all of ``A`` there is)."""
+    ks = jax.random.split(jax.random.key(6))
+    a = jnp.tril(jax.random.normal(ks[0], (3, c, c)) * 0.2, -1)
+    dt = jax.random.normal(ks[1], (3, c, c))
+    (want,), (got,) = (jax.vjp(f, a)[1](dt)
+                       for f in (gdr._inverse, gdr._inverse_by_rule))
+    assert gaps([jnp.tril(got, -1)], [jnp.tril(want, -1)])[0] < 2e-6
+
+
 def test_sixteen_key_heads_each_serve_two_value_heads():
     """Value heads ``2j, 2j + 1`` read key head ``j``: the same numbers as
     with every key head repeated."""
@@ -111,8 +191,9 @@ def test_inverse_by_blocks_and_doublings(c):
 
 def test_the_backward_is_handed_what_the_forward_kept():
     """Under a checkpoint that keeps ``RESIDUAL_NAMES`` the differentiated
-    program holds ONE scan kernel (the forward's): the backward neither
-    runs it again nor asks for its output."""
+    program holds the forward's scan kernel ONCE (grid: 4 programs by 4
+    chunks) beside the backward's (4 by twice the segment's turns): the
+    backward neither runs the forward's again nor asks for its output."""
     args = operands(b=1, t=64, d=16)
     keep = jax.checkpoint_policies.save_only_these_names(
         *gdr.RESIDUAL_NAMES)
@@ -123,11 +204,16 @@ def test_the_backward_is_handed_what_the_forward_kept():
             policy=keep)(*a))
 
     text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(*args))
-    assert text.count("pallas_call") == 1
+    turns = 2 * 4 // math.gcd(4, gdr._CHUNKS_A_TURN)
+    forward, backward = ("GridMapping(grid=(4, 4)",
+                         f"GridMapping(grid=(4, {turns})")
+    assert (text.count("pallas_call["), text.count(forward),
+            text.count(backward)) == (2, 1, 1)
     free = str(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(jax.checkpoint(
         lambda *b: gated_delta_rule(*b, chunk=16, interpret=True) ** 2)(*a)),
         argnums=(0, 1, 2, 3, 4)))(*args))
-    assert free.count("pallas_call") == 2
+    assert (free.count("pallas_call["), free.count(forward),
+            free.count(backward)) == (3, 2, 1)
 
 
 def test_shapes_it_refuses():

@@ -366,8 +366,8 @@ def test_sixteen_shares_add_up_to_the_uncut_reference_layer():
 
 def test_backward_runs_no_kernel_twice():
     """With the kept names the differentiated step holds each layer's
-    forward kernel once (three scans, one flash forward) and the flash
-    backward; without remat the same."""
+    forward kernel once (three scans, one flash forward), each scan's
+    backward kernel and the flash backward; without remat the same."""
     def count(remat):
         spec = gdn_moe_lm(**TINY, remat=remat, attn_fn=FLASH,
                           gdn_fn=kernel_scan)
@@ -376,30 +376,38 @@ def test_backward_runs_no_kernel_twice():
             shapes, {"tokens": tokens(0)})
         return routed_cases.primitives(jaxpr.jaxpr)["pallas_call"]
 
-    # three scans, a flash forward and its backward
-    assert count("full") == count("none") == 3 + 2
+    # three scans and their backwards, a flash forward and its backward
+    assert count("full") == count("none") == 3 + 3 + 2
     assert set(gdr.RESIDUAL_NAMES) < set(model.KEPT_NAMES)
 
 
-def test_gauges_say_what_the_chunked_form_costs():
+@pytest.mark.parametrize("t", [64, 256])
+def test_gauges_say_what_the_chunked_form_costs(t):
+    import inspect
+
     from autodist_tpu.telemetry import registry
 
     registry.reset_for_testing()
-    spec = gdn_moe_lm(**TINY)
+    spec = gdn_moe_lm(**{**TINY, "seq_len": t})
     jax.eval_shape(spec.loss_fn, jax.eval_shape(
-        spec.init, jax.random.key(0)), {"tokens": tokens(0)})
+        spec.init, jax.random.key(0)), {"tokens": tokens(0, t=t)})
     got = {m.labels["kind"]: m.value
            for m in registry.DEFAULT_REGISTRY.metrics()
            if m.name == "autodist_gdn_flops_per_step"}
     per = gdr.flops_per_token(8, 8, 16, 2)
-    times = 2 * 64 * 4 * 3        # tokens, value heads, linear layers
+    times = 2 * t * 4 * 3         # tokens, value heads, linear layers
     assert got == {k: v * times for k, v in per.items()}
     assert got["computed"] / got["recurrence"] >= 1.0
     kept = {m.labels["name"]: m.value
             for m in registry.DEFAULT_REGISTRY.metrics()
             if m.name == "autodist_remat_kept_bytes_per_step"}
-    # o [64, 4, 8] float32 a sequence and linear layer; one state a
-    # segment (the whole sequence here) a value head
-    assert kept["gated_delta_rule/o"] == 3 * 2 * 64 * 4 * 8 * 4
-    assert kept["gated_delta_rule/states"] == 3 * 2 * 4 * 8 * 8 * 4
+    # o [t, 4, 8] float32 a sequence and linear layer; one state a value
+    # head and segment of the shipped length (of 64 tokens in chunks of
+    # 16 the whole sequence is one, of 256 there are two)
+    segment = inspect.signature(
+        gdr.gated_delta_rule).parameters["segment"].default
+    segments = -(-t // (16 * segment))
+    assert segments == {64: 1, 256: 2}[t]
+    assert kept["gated_delta_rule/o"] == 3 * 2 * t * 4 * 8 * 4
+    assert kept["gated_delta_rule/states"] == 3 * 2 * segments * 4 * 8 * 8 * 4
     registry.reset_for_testing()
