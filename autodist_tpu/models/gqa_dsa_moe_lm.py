@@ -159,8 +159,9 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
                    return_counts: bool, config: dict,
                    router_reads_input: bool = False,
                    embed_scale: float = 1.0,
-                   final_scale: Callable = lambda p: p["scale"]
-                   ) -> ModelSpec:
+                   final_scale: Callable = lambda p: p["scale"],
+                   dense_layers: Tuple[int, ...] = (),
+                   tie_head: bool = False) -> ModelSpec:
     """What the decoders of this file and of ``swa_moe_lm.py`` share: the
     embedding, ``num_layers`` layers of an attention half (one sequence at
     a time) and an expert half (all of the step's tokens at once, as
@@ -175,18 +176,24 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
     placed before attention).  ``embed_scale``: the stream
     enters layer 0 as this times the table's rows.  ``final_scale(params[
     "ln_final"])``: what the final norm multiplies by (a zero-centred
-    norm's ``1 + w``).  ``set_pairs_gauges(tokens)``: the model's own
-    gauges, set while tracing."""
+    norm's ``1 + w``).  ``dense_layers``: the layers whose second half is
+    a DENSE FFN and no expert half: ``halves_of(i)[1]`` is then
+    ``ffn_half(lp, part [1, slice, D]) -> [1, slice, D]``, run one slice
+    at a time under a map like the attention half, and the layer has no
+    ``tokens_per_expert``.  ``tie_head``: the head multiplies by
+    ``params["embed"]`` and there is no ``params["head"]`` (the table's
+    gradient is then dense).  ``set_pairs_gauges(tokens)``: the model's
+    own gauges, set while tracing."""
     keep = jax.checkpoint_policies.save_only_these_names(*kept_names)
 
     @functools.cache
-    def as_run(halves):
+    def as_run(halves, dense=False):
         """A kind's halves under their checkpoints (the attention half
-        runs under ``lax.map``: no CSE barrier needed)."""
+        and a dense FFN run under ``lax.map``: no CSE barrier needed)."""
         if remat == "none":
             return halves
         return (jax.checkpoint(halves[0], policy=keep, prevent_cse=False),
-                jax.checkpoint(halves[1], policy=keep))
+                jax.checkpoint(halves[1], policy=keep, prevent_cse=not dense))
 
     def slices(x):
         """``[B, T, D]`` as ``[n, moe_slice, D]``."""
@@ -209,6 +216,8 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
                 lp = params[f"layers_{i}"]
                 found[halves] = [
                     (named_bytes(halves[0], lp, x[:1]), x.shape[0]),
+                    (named_bytes(halves[1], lp, parts[:1]), parts.shape[0])
+                    if i in dense_layers else
                     (named_bytes(halves[1], lp, *[parts] * (
                         1 + router_reads_input)), 1)]
             for name in kept_names:
@@ -216,13 +225,18 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
                                    for tagged, times in found[halves])
         return total
 
-    def layer(lp, x, halves):
+    def layer(lp, x, halves, dense=False):
         """``x [B, T, D]`` through one layer: attention one sequence at a
-        time, the experts once over all the tokens.  Returns the layer's
-        ``tokens_per_expert`` ``[count]`` beside ``x``."""
+        time, the experts once over all the tokens (a dense FFN one slice
+        at a time).  Returns the layer's ``tokens_per_expert`` ``[count]``
+        beside ``x``, None for a dense layer."""
         attention_half, expert_half = halves
         entered = x
         x = jax.lax.map(lambda row: attention_half(lp, row[None])[0], x)
+        if dense:
+            y = jax.lax.map(lambda part: expert_half(lp, part[None])[0],
+                            slices(x))
+            return y.reshape(x.shape), None
         y, counts = expert_half(lp, slices(x), *(
             [slices(entered)] if router_reads_input else []))
         return y.reshape(x.shape), counts
@@ -247,8 +261,11 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
         counts = []
         with jax.named_scope(timeline.SCOPE_LM_LAYERS):
             for i in range(num_layers):
-                x, c = layer(params[f"layers_{i}"], x, as_run(halves_of(i)))
-                counts.append(c)
+                dense = i in dense_layers
+                x, c = layer(params[f"layers_{i}"], x,
+                             as_run(halves_of(i), dense), dense)
+                if not dense:
+                    counts.append(c)
             # here, outside the layers' checkpoints
             record_row_budgets(jnp.stack(counts), tokens.size * top_k,
                                num_experts, slices(x).shape[1] * top_k)
@@ -256,10 +273,12 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
             feats = rms_norm(x, final_scale(params["ln_final"]), rms_eps)
         return feats, counts
 
+    head_name = "embed" if tie_head else "head"
+
     def apply_fn(params, tokens):
         feats = features(params, tokens)[0]
         with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
-            return jnp.einsum("btd,vd->btv", feats, params["head"])
+            return jnp.einsum("btd,vd->btv", feats, params[head_name])
 
     def loss_fn(params, batch):
         tokens = batch["tokens"]
@@ -270,10 +289,10 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
                     chunked_softmax_cross_entropy
 
                 loss = chunked_softmax_cross_entropy(
-                    feats[:, :-1], params["head"], tokens[:, 1:],
+                    feats[:, :-1], params[head_name], tokens[:, 1:],
                     chunk=xent_chunk)
             else:
-                logits = jnp.einsum("btd,vd->btv", feats, params["head"])
+                logits = jnp.einsum("btd,vd->btv", feats, params[head_name])
                 loss = cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
         if return_counts:
             return loss, {"tokens_per_expert": jnp.stack(counts)}
@@ -287,7 +306,7 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
         name=name,
         init=init, loss_fn=step_values.reporting(loss_fn), apply_fn=apply_fn,
         make_batch=make_batch,
-        sparse_vars=("embed",),
+        sparse_vars=() if tie_head else ("embed",),
         expert_vars=("*/moe/experts/*",),
         config=config,
     )

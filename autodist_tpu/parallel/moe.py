@@ -535,7 +535,7 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
                    routed_scale: float = 1.0, train_router: bool = True,
                    scoring: str = "sigmoid",
                    router_input: Optional[jax.Array] = None,
-                   activation=jax.nn.silu
+                   activation=jax.nn.silu, norm_eps: float = 0.0
                    ) -> Tuple[jax.Array, jax.Array]:
     """``k`` of ``E`` routed experts with NO token dropped, for the
     experts this chip holds (DeepSeek-V3's layer, arxiv 2412.19437 §2.1.2,
@@ -543,7 +543,7 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
 
         s = sigmoid(x W_r)          over all E experts, float32, highest
         S = top-k of (s + b)        b: the selection bias, no gradient
-        g_e = routed_scale * s_e / sum_{j in S} s_j        for e in S
+        g_e = routed_scale * s_e / (sum_{j in S} s_j + norm_eps)   e in S
         y = Shared(x) + sum_{e in S and held} g_e E_e(x)
 
     ``scoring="softmax"``: ``s = softmax(x W_r)`` over all ``E`` (the
@@ -564,7 +564,9 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
     the normed stream after attention); the scores then pass their
     gradient to it and not to ``x``.  ``activation``: the experts' gate
     (``silu``: SwiGLU; ``jax.nn.relu``: ReGLU); the shared experts, where
-    there are any, keep ``silu``.
+    there are any, keep ``silu``.  ``norm_eps``: added to the sum the
+    picks' scores are divided by (0: nothing is added; LFM2's public
+    implementation adds 1e-6).
 
     ``experts_held = (first, count)``: ``params["experts"]`` leaves lead
     with ``count`` experts, which are experts ``first .. first + count``
@@ -652,7 +654,9 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
         if scoring == "softmax_of_picked":
             gates = routed_scale * jax.nn.softmax(picked, axis=-1)
         else:
-            gates = routed_scale * picked / picked.sum(-1, keepdims=True)
+            gates = routed_scale * picked
+            norm = picked.sum(-1, keepdims=True)
+            gates = gates / (norm + norm_eps if norm_eps else norm)
         local = chosen - first
         here = (local >= 0) & (local < count)
         group = jnp.where(here, local, count).reshape(-1)       # [N * k]

@@ -374,6 +374,7 @@ SCOPE_MHA_ATTENTION = "mha/attention"    # its attention call alone
 SCOPE_MLA_PROJECT = "mla/project"        # q, kv_a, kv_b, rotary, out
 SCOPE_MLA_ATTENTION = "mla/attention"    # the attention call alone
 SCOPE_GQA_PROJECT = "gqa/project"        # q, k, v, their norms, rotary, out
+SCOPE_GQA_ATTENTION = "gqa/attention"    # gqa_attn, the plain call alone
 SCOPE_DSA_INDEX = "dsa/index"            # the indexer's projections, scores
 SCOPE_DSA_SELECT = "dsa/select"          # top-k of every row, packed words
 SCOPE_DSA_ATTENTION = "dsa/attention"    # the attention call alone
@@ -382,6 +383,8 @@ SCOPE_GATTN_ATTENTION = "gattn/attention"  # gated_attn, the call alone
 SCOPE_GDN_PROJECT = "gdn/project"        # qkvz, ba, the gated norm, out
 SCOPE_GDN_CONV = "gdn/conv"              # the causal convolution and its silu
 SCOPE_GDN_RECURRENCE = "gdn/recurrence"  # l2norm, g, beta, gdn_scan
+SCOPE_SCONV_PROJECT = "sconv/project"    # W_in, W_out of the short conv
+SCOPE_SCONV_CONV = "sconv/conv"          # both gates and the three taps
 SCOPE_MOE_ROUTE = "moe/route"            # scores, top-k, sort, group sizes
 SCOPE_MOE_SHARED = "moe/shared"          # the shared experts (dense)
 SCOPE_MOE_EXPERTS = "moe/experts"        # gather, grouped products, SwiGLU

@@ -616,8 +616,10 @@ def expert_model_steps(spec, *, batch_size: int, steps: int, pairs: str):
     cfg = spec.config
     counts = np.asarray(outs[-1]["aux"]["tokens_per_expert"])
     picks = batch["tokens"].size * cfg["top_k"]
-    if counts.shape != (cfg["num_layers"], cfg["experts_held"][1]) \
-            or not 0 < counts.sum() <= cfg["num_layers"] * picks:
+    # a model's leading dense layers have no experts and return no counts
+    layers = cfg["num_layers"] - cfg.get("num_dense_layers", 0)
+    if counts.shape != (layers, cfg["experts_held"][1]) \
+            or not 0 < counts.sum() <= layers * picks:
         raise AssertionError(f"tokens per expert: {counts.tolist()}")
     found = {m.labels["kind"]: int(m.value)
              for m in DEFAULT_REGISTRY.metrics() if m.name == pairs}
@@ -872,6 +874,85 @@ def train_gdn_moe_phase(model: dict, *, batch_size: int, steps: int,
         print(f"  kernels of the step by name: {calls}", flush=True)
         if calls != {"gdn_scan": 1, "gdn_scan_bwd": 1, "gated_attn": 2}:
             raise AssertionError(f"custom calls: {calls}")
+        facts["kernel_calls"] = calls
+        facts["bytes_in_use"] = memory_in_use(jax.devices()[:1])
+    close_session(ad, sess)
+    return facts
+
+
+def check_gated_short_conv(shape, *, tol: float) -> dict:
+    """``models/sconv_moe_lm.py: gated_short_conv`` at ``shape = (T, D)``,
+    one sequence, against the same sum written with ``jnp.roll`` and a
+    mask: value and both gradients (no product in it: the chip's float32
+    elementwise arithmetic against itself in another order)."""
+    import jax
+    import jax.numpy as jnp
+
+    from autodist_tpu.models.sconv_moe_lm import gated_short_conv
+
+    t, d = shape
+    ks = jax.random.split(jax.random.PRNGKey(SEED), 3)
+    bcx = jax.random.normal(ks[0], (1, t, 3 * d))
+    taps = jax.random.normal(ks[1], (d, 3))
+    ct = jax.random.normal(ks[2], (1, t, d))
+
+    def rolled(bcx, taps):
+        b, c, x = jnp.split(bcx, 3, axis=-1)
+        z, rows = b * x, jnp.arange(t)[None, :, None]
+        return c * sum(jnp.where(rows >= lag, jnp.roll(z, lag, axis=1), 0.0)
+                       * taps[:, 2 - lag] for lag in range(3))
+
+    def out_and_grads(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a) * ct), argnums=(0, 1)))(bcx, taps)
+
+    (got, got_grads), (want, want_grads) = (out_and_grads(gated_short_conv),
+                                            out_and_grads(rolled))
+    errs = [abs(float(got - want)) / abs(float(want))] + [
+        _rel_err(a, b) for a, b in zip(got_grads, want_grads)]
+    if not max(errs) <= tol:
+        raise AssertionError(f"gated short convolution, the sum and the "
+                             f"gradients of bcx and the taps: {errs} > {tol}")
+    return {"gaps": [float(f"{e:.3g}") for e in errs]}
+
+
+def train_sconv_moe_phase(model: dict, *, batch_size: int, steps: int,
+                          tol: float) -> dict:
+    """``models/sconv_moe_lm.py`` with ONE DENSE CONV LAYER AND ONE
+    ATTENTION EXPERT LAYER (the gated short convolution and a dense FFN;
+    grouped-query attention with per-head norms and sigmoid-routed experts
+    with a selection bias; a tied head), through
+    ``capture(has_aux=True)``: ``steps`` calls of ``sess.run`` on one fixed
+    batch, every loss finite, the one expert layer's token counts back, the
+    gauges of the causal pairs asked for and computed.  Before it, the
+    gated convolution at the model's own shape against the same sum in
+    another form (:func:`check_gated_short_conv`).  On a TPU the compiled
+    step's Pallas calls are counted BY NAME: a forward and a backward
+    ``gqa_attn``, neither run twice."""
+    import jax
+
+    from autodist_tpu.models.sconv_moe_lm import sconv_moe_lm
+
+    spec = sconv_moe_lm(**model, return_counts=True)
+    cfg = spec.config
+    if (cfg["layer_types"], cfg["num_dense_layers"]) != (
+            ("conv", "full_attention"), 1) or not cfg["tie_embedding"]:
+        raise AssertionError("one dense conv layer, one attention expert "
+                             "layer and a tied head are asked")
+    facts = {"gated_conv_against_rolled_sum": check_gated_short_conv(
+        (cfg["seq_len"], cfg["d_model"]), tol=tol)}
+    ad, sess, batch, stepped = expert_model_steps(
+        spec, batch_size=batch_size, steps=steps,
+        pairs="autodist_gqa_pairs_per_step")
+    facts.update(stepped)
+    if jax.devices()[0].platform == "tpu":
+        text = sess.lower_step(batch).compile().as_text()
+        calls = len(re.findall(
+            r"%gqa_attn[.\d]* = .*custom_call_target=\"tpu_custom_call\"",
+            text))
+        print(f"  gqa_attn kernels of the step: {calls}", flush=True)
+        if calls != 2:
+            raise AssertionError(f"gqa_attn custom calls: {calls}")
         facts["kernel_calls"] = calls
         facts["bytes_in_use"] = memory_in_use(jax.devices()[:1])
     close_session(ad, sess)
@@ -1137,6 +1218,9 @@ FULL_SWA_MOE = dict(vocab_size=18992, num_layers=2, window_layout=(0, 1),
 # 512 experts, 8,192 positions
 FULL_GDN_MOE = dict(vocab_size=18992, num_layers=2, full_interval=2,
                     experts_held=(0, 32), seq_len=8192, xent_chunk=6400)
+FULL_SCONV_MOE = dict(layer_types=("conv", "full_attention"),
+                      num_dense_layers=1, experts_held=(0, 8), seq_len=8192,
+                      xent_chunk=4096)
 FULL_SIZES = dict(p=64, prefix=512, tails=(40, 100), long=1024, mid=333,
                   n=(32, 48, 96, 128))
 FULL_ENGINE = dict(slots=8, window=2048, block_size=32, chunk=16)
@@ -1191,6 +1275,8 @@ def main() -> int:
               batch_size=1, steps=2, tol=2e-2)
     run_phase(watch, "train_gdn_moe", train_gdn_moe_phase, FULL_GDN_MOE,
               batch_size=2, steps=3, tol=2e-2)
+    run_phase(watch, "train_sconv_moe", train_sconv_moe_phase,
+              FULL_SCONV_MOE, batch_size=2, steps=3, tol=1e-5)
     run_phase(watch, "serve_paged", serve_paged_phase, spec, params,
               sizes=FULL_SIZES, engine=FULL_ENGINE)
     run_phase(watch, "serve_slots", serve_slots_phase, spec, params,

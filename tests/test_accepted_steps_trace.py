@@ -20,7 +20,10 @@ Qwen3-Next share: ``routed_moe_ffn``'s ``shared_gate`` leaf,
 four and recorded its own model's; PR 42 recorded that one anew, ON
 PURPOSE (the gated delta rule's backward written out, its scan a second
 kernel, segments of 8 chunks: 55,189 characters and two ``pallas_call``
-texts more), and left the four others alone.  A PR that changes one of
+texts more), and left the four others alone.  PR 43 (the LFM2-8B-A1B share:
+``routed_decoder``'s ``dense_layers`` and ``tie_head``,
+``routed_moe_ffn``'s ``norm_eps``, three scopes) changed NONE of the five
+and recorded its own model's.  A PR that changes one of
 these models' traces on purpose records the new hash here and says so in
 ``CHANGES.md``.
 """
@@ -57,6 +60,9 @@ TRACES = {
     "qwen3-next-80b-a3b.ep16-share": (
         2, 8192, 656441, 18,
         "80ef56c7495308632ff490eba90cf0d0ed8dec5c173acebc68348ab87c8fc2ee"),
+    "lfm2-8b-a1b.ep4-share": (
+        4, 8192, 291143, 13,
+        "c0029a3dbbc4c4be3f29aea479b664fd119411902d9f7ce6f660558fc80d8ec8"),
 }
 
 

@@ -139,6 +139,27 @@ def test_train_gdn_moe_phase_checks_the_recurrence_and_steps_the_model():
                                        batch_size=1, steps=1, tol=1e-4)
 
 
+def test_train_sconv_moe_phase_checks_the_convolution_and_steps_the_model():
+    model = dict(vocab_size=61, layer_types=("conv", "full_attention"),
+                 num_dense_layers=1, d_model=32, num_heads=4, num_kv_heads=2,
+                 head_dim=8, d_ff=48, d_expert=12, num_experts=16,
+                 experts_held=(4, 4), top_k=3, seq_len=64, block_k=32,
+                 moe_slice=64)
+    facts = chip_smoke.train_sconv_moe_phase(model, batch_size=2, steps=3,
+                                             tol=1e-5)
+    assert len(facts["losses"]) == 3 and all(np.isfinite(facts["losses"]))
+    # the dense layer has no experts: one layer's counts
+    assert len(facts["tokens_per_expert"]) == 1
+    assert max(facts["gated_conv_against_rolled_sum"]["gaps"]) < 1e-5
+    # one attention layer x 2 sequences x 4 heads
+    assert facts["pairs_per_step"] == {"causal": 8 * 64 * 65 // 2,
+                                       "computed": 8 * 64 * 64}
+    with pytest.raises(AssertionError, match="one dense conv layer"):
+        chip_smoke.train_sconv_moe_phase(
+            dict(model, layer_types=("conv", "conv")), batch_size=1,
+            steps=1, tol=1e-5)
+
+
 def test_serve_phases_over_http(lm):
     facts = chip_smoke.serve_paged_phase(
         *lm, sizes=TINY_SIZES, engine=TINY_ENGINE)

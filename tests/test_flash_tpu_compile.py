@@ -117,6 +117,10 @@ _GROUPED = {
     # the Qwen3-Next full layer (issue 40): 16 heads over 2 of 256 wide at
     # 8,192 tokens, float32 in: K and V resident, 8 KB a row in the backward
     "qwen3_next_one_sequence": ((1, 8192, 16, 2, 256), None),
+    # the LFM2-8B-A1B attention layer (issue 43): gpt2's head width 64 with
+    # keye's grouping of heads for the first time together, 32 over 8 at
+    # 8,192 tokens, float32 in
+    "lfm2_one_sequence": ((1, 8192, 32, 8, 64), None),
 }
 
 
@@ -124,7 +128,8 @@ _GROUPED = {
     (name, precision) for name in sorted(_GROUPED)
     for precision in ("default", "highest")
     if (name, precision) not in (("keye_one_sequence", "highest"),
-                                 ("qwen3_next_one_sequence", "highest"))])
+                                 ("qwen3_next_one_sequence", "highest"),
+                                 ("lfm2_one_sequence", "highest"))])
 def test_grouped_heads_and_selection_compile(one_chip, name, precision):
     """The kernels with ``H // Hkv`` query heads a key/value head and a
     packed selection, at the keye cell's 16,384 tokens (float32 in, 64 MiB

@@ -25,6 +25,7 @@ from autodist_tpu.mesh import build_mesh
 from autodist_tpu.models.gdn_moe_lm import gdn_moe_lm
 from autodist_tpu.models.gqa_dsa_moe_lm import gqa_dsa_moe_lm
 from autodist_tpu.models.mla_moe_lm import mla_moe_lm
+from autodist_tpu.models.sconv_moe_lm import sconv_moe_lm
 from autodist_tpu.models.swa_moe_lm import swa_moe_lm
 from autodist_tpu.models.transformer_lm import transformer_lm
 from autodist_tpu.ops import flash_attention
@@ -36,7 +37,7 @@ FLASH = functools.partial(flash_attention, interpret=True, block_q=32,
 ROUTED = dict(vocab_size=61, d_model=32, d_expert=12, num_experts=16,
               top_k=3, experts_held=(0, 4), xent_chunk=32,
               train_router=False, attn_fn=FLASH)
-#: the five factories of the benchmark's six cells, each as its cell
+#: the six factories of the benchmark's seven cells, each as its cell
 #: runs it (the kernel and not the dense softmax, the chunked loss where
 #: the configuration asks for it, checkpoints, maps over sequences)
 FACTORIES = {
@@ -62,6 +63,10 @@ FACTORIES = {
         chunk=16, block_k=32, moe_slice=64,
         gdn_fn=functools.partial(gated_delta_rule, chunk=16, segment=2,
                                  interpret=True))),
+    "sconv_moe_lm": (sconv_moe_lm, dict(
+        ROUTED, layer_types=("conv", "full_attention"), num_dense_layers=1,
+        num_heads=4, num_kv_heads=2, head_dim=8, d_ff=48, seq_len=64,
+        block_k=32, moe_slice=64)),
 }
 VOCABULARY = {value for name, value in vars(timeline).items()
               if name.startswith("SCOPE_")}
