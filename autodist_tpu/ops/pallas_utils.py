@@ -69,6 +69,16 @@ def vmem_limit(need: int) -> Optional[int]:
     return None if need <= _VMEM_DEFAULT else min(need, _VMEM_MAX)
 
 
+def widest_tile(n: int, fits) -> int:
+    """The widest tile of ``n`` (whole :data:`TILE`-lane tiles that divide
+    it) with ``fits(tile)``, one lane tile if none does; all of ``n`` where
+    it is no whole number of lanes."""
+    if n % TILE:
+        return n
+    return max((w for w in range(TILE, n + 1, TILE)
+                if n % w == 0 and fits(w)), default=TILE)
+
+
 def pad_to(n: int, m: int) -> int:
     """``n`` rounded up to the next multiple of ``m``."""
     return -(-int(n) // int(m)) * int(m)

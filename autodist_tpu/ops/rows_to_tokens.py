@@ -48,14 +48,10 @@ _RESIDENT = 32 << 20         # bytes of the tokens' block that stays in VMEM
 
 
 def _width(tokens: int, d: int) -> int:
-    """The widest tile of ``d`` (whole 128-lane tiles that divide it) whose
-    ``[tokens, tile]`` float32 block is within :data:`_RESIDENT`; all of
-    ``d`` where it is no whole number of lanes."""
-    if d % pallas_utils.TILE:
-        return d
-    fits = [w for w in range(pallas_utils.TILE, d + 1, pallas_utils.TILE)
-            if d % w == 0 and tokens * w * 4 <= _RESIDENT]
-    return fits[-1] if fits else pallas_utils.TILE
+    """The widest tile of ``d`` whose ``[tokens, tile]`` float32 block is
+    within :data:`_RESIDENT`."""
+    return pallas_utils.widest_tile(
+        d, lambda w: tokens * w * 4 <= _RESIDENT)
 
 
 def _accumulate(live_ref, token_ref, scale_ref, rows_ref, *onto_ref_and_y_ref,

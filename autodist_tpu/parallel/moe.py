@@ -26,6 +26,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from autodist_tpu.const import MESH_AXIS_DATA, MESH_AXIS_EXPERT
+from autodist_tpu.ops import grouped_matmul
 from autodist_tpu.ops.rows_to_tokens import rows_to_tokens
 from autodist_tpu.utils import logging
 
@@ -288,8 +289,8 @@ def routed_rows(tokens: int, top_k: int, held: int, total: int):
     return tokens * top_k, tokens * top_k * held / total
 
 
-#: rows a tile of the grouped product (``jax.lax.ragged_dot`` on a TPU)
-_GROUPED_TILE = 512
+#: rows a tile of the grouped products (``ops/grouped_matmul.py``)
+_GROUPED_TILE = grouped_matmul.ROW_TILE
 
 
 def chunk_rows(rows: int, held: int, total: int,
@@ -341,7 +342,9 @@ def record_row_budgets(tokens_per_expert: jax.Array, rows: int, total: int,
     callback, so the step program stays in the persistent compilation
     cache): after every step a session fetched, ``{kind="computed"}`` is
     the rows of the chunks that step's calls took and
-    ``autodist_moe_row_budget_calls_total{rung=<rows>}`` has counted them.
+    ``autodist_moe_row_budget_calls_total{rung=<rows>}`` has counted them;
+    and ``autodist_moe_grouped_row_tiles_per_step{kind="visited"|"live"}``
+    is what ``ops/grouped_matmul.py: row_tiles`` makes of the same integers.
     The loss function is to be marked ``step_values.reporting``."""
     from autodist_tpu.telemetry import registry, step_values
 
@@ -365,14 +368,50 @@ def record_row_budgets(tokens_per_expert: jax.Array, rows: int, total: int,
 
     step_values.emit("moe_row_budget_calls", calls, publish)
 
+    # every chunk a call can take, with the cut of the group sizes the layer
+    # hands its grouped products there (:func:`_sorted_rows`): nothing of a
+    # chunk the call does not take
+    chunk = rungs[0]
+    ends = jnp.cumsum(tokens_per_expert, axis=-1)[..., None, :]
+    upto = jnp.asarray(rungs)[:, None]
+    within = jnp.clip(jnp.minimum(ends, upto) - jnp.maximum(
+        ends - tokens_per_expert[..., None, :], upto - chunk), 0)
+    tiles = jnp.stack([t.sum() for t in grouped_matmul.row_tiles(within,
+                                                                 chunk)])
+
+    def publish_tiles(tiles):           # [2], stacked over microbatches
+        for kind, n in zip(("visited", "live"),
+                           tiles.reshape(-1, 2).sum(axis=0).tolist()):
+            registry.gauge(
+                "autodist_moe_grouped_row_tiles_per_step",
+                "of ONE of the forward's three grouped products, over the "
+                "last step's calls and chunks: the (row tile, group) pairs "
+                "its kernel visited, and the row tiles that held a routed "
+                "row (visited / live is 1 plus the tiles seen twice because "
+                "two groups share them; without the skipping it would be "
+                "rows_per_step{computed} / 512 over live)",
+                {"kind": kind}).set(n)
+
+    step_values.emit("moe_grouped_row_tiles", tiles, publish_tiles)
+
+
+def _grouped_product(rows, weights, sizes):
+    """``rows [C, k] x weights [E, k, n]``, group by group: on a TPU the
+    kernels of ``ops/grouped_matmul.py`` (one bfloat16 pass, float32 sums,
+    their own transposes); off one XLA's ``ragged_dot`` at the ambient
+    matmul precision, which the models' tests on a CPU compare at."""
+    if grouped_matmul._use_interpret():
+        return jax.lax.ragged_dot(rows, weights, sizes)
+    return grouped_matmul.grouped_matmul(rows, weights, sizes)
+
 
 def _grouped_swiglu(experts, rows, sizes, activation=jax.nn.silu):
     """:func:`swiglu` of each group of ``rows`` (``sizes`` rows each, in
     order) under its own expert's weights, the gate's ``activation`` the
     caller's.  Rows past the last group come back unwritten."""
-    hidden = (activation(jax.lax.ragged_dot(rows, experts["w_gate"], sizes))
-              * jax.lax.ragged_dot(rows, experts["w_up"], sizes))
-    return jax.lax.ragged_dot(hidden, experts["w_down"], sizes)
+    hidden = (activation(_grouped_product(rows, experts["w_gate"], sizes))
+              * _grouped_product(rows, experts["w_up"], sizes))
+    return _grouped_product(hidden, experts["w_down"], sizes)
 
 
 def _further_chunks(routed, chunk: int):
@@ -582,12 +621,17 @@ def routed_moe_ffn(params: dict, x: jax.Array, *, top_k: int,
     Static shapes without a capacity: the ``N * k`` (token, pick) pairs
     of ALL the call's tokens are sorted by local expert, the picks of
     absent experts last.  The first ``C`` places of that order are gathered
-    and go through three grouped products (``jax.lax.ragged_dot``, on a TPU
-    one Mosaic kernel each that leaves the row tiles past the last group
-    alone), then are added, each times its pick's weight, to their tokens'
-    rows by one kernel that reads those ``C`` rows and writes the ``N``
-    tokens once (``ops/rows_to_tokens.py``; the backward's cotangent of
-    ``x`` alike).  ``C`` is :func:`chunk_rows`, from the shapes alone:
+    and go through three grouped products (``ops/grouped_matmul.py``: on a
+    TPU one kernel each, which reads the row tiles that hold a routed row
+    and each expert's weights once, rounds them to bfloat16 in VMEM and
+    leaves what lies past the last group alone; its transposes, the rows'
+    cotangents under the weights read transposed and each group's rows
+    contracted for the weights', are kernels of the same file; off a TPU
+    ``jax.lax.ragged_dot``), then are added, each times its pick's weight,
+    to their tokens' rows by one kernel that reads those ``C`` rows and
+    writes the ``N`` tokens once (``ops/rows_to_tokens.py``; the
+    backward's cotangent of ``x`` alike).  ``C`` is :func:`chunk_rows`,
+    from the shapes alone:
     twice what an even router sends here, and no more than the picks of
     ``x.shape[-2]`` tokens: hand the layer ``[slices, slice, d]`` or ``[B,
     T, d]`` and no buffer of it is wider than one slice's or sequence's

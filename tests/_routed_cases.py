@@ -10,12 +10,14 @@ its KIND and the router reads nothing else: kind 0 picks experts 4 and 5
 The other columns are noise, so that the experts' outputs differ from token
 to token."""
 import collections
+import contextlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from autodist_tpu.models.mla_moe_lm import equations
+from autodist_tpu.ops import grouped_matmul
 from autodist_tpu.parallel import moe
 
 TOKENS, TOP_K, HELD, TOTAL, D = 64, 2, (4, 2), 16, 32
@@ -151,13 +153,32 @@ def assert_chunks_equal_one_wide_chunk(scoring: str, load: int):
             assert np.any(np.asarray(got)), name
 
 
+@contextlib.contextmanager
+def grouped_products_by_the_kernels():
+    """The routed layer as a TPU traces it: its grouped products are the
+    kernels of ``ops/grouped_matmul.py`` (interpreted here; off a TPU the
+    layer takes ``jax.lax.ragged_dot``)."""
+    real = moe._grouped_product
+    moe._grouped_product = grouped_matmul.grouped_matmul
+    try:
+        yield
+    finally:
+        moe._grouped_product = real
+
+
+#: the routed layer's own kernels, by the name a trace prints
+LAYER_KERNELS = ("rows_to_tokens", "grouped_rows", "grouped_rows_t",
+                 "grouped_weights")
+
+
 def primitives(jaxpr) -> collections.Counter:
-    """How often each primitive stands in a jaxpr, the kernels that bring
-    sorted rows back to token order under their own name: what is left
-    under ``pallas_call`` are a model's attention kernels."""
+    """How often each primitive stands in a jaxpr, the routed layer's
+    kernels (the sorted rows' return to token order, the grouped products)
+    under their own names: what is left under ``pallas_call`` are a
+    model's attention kernels."""
     return collections.Counter(
-        "rows_to_tokens" if eqn.primitive.name == "pallas_call"
-        and eqn.params["name"] == "rows_to_tokens" else eqn.primitive.name
+        eqn.params["name"] if eqn.primitive.name == "pallas_call"
+        and eqn.params["name"] in LAYER_KERNELS else eqn.primitive.name
         for eqn in equations(jaxpr))
 
 
@@ -198,25 +219,31 @@ def assert_nothing_is_as_wide_as_the_picks(scoring: str, top_k: int):
     of the gradient, in the first chunk's path or in the loops', yields
     ``N * top_k * d`` elements or more: no ``[N, k, d]`` array (at k = 6 a
     relayout on the chip), no select or fill of every pick's row; the
-    grouped products work on a chunk's rows."""
+    grouped products, as a TPU runs them (the kernels of
+    ``ops/grouped_matmul.py``, found by name), work on a chunk's rows."""
     tokens = 96
     params = jax.eval_shape(lambda: layer(scoring))
     x = jax.ShapeDtypeStruct((tokens, D), jnp.float32)
     chunk = moe.chunk_rows(tokens * top_k, HELD[1], TOTAL)
     assert chunk == 24 * top_k and tokens * top_k // chunk == 4
-    jaxpr = jax.make_jaxpr(lambda p, x: value_and_gradients(
-        p, x, scoring, top_k=top_k)[2])(params, x).jaxpr
+    with grouped_products_by_the_kernels():
+        jaxpr = jax.make_jaxpr(lambda p, x: value_and_gradients(
+            p, x, scoring, top_k=top_k)[2])(params, x).jaxpr
     chunk_loops(jaxpr)
-    grouped = 0
     for eqn in equations(jaxpr):
         for var in eqn.outvars:
             assert var.aval.size < tokens * top_k * D, eqn
-        if eqn.primitive.name == "ragged_dot_general":
-            grouped += 1
-            assert chunk in eqn.invars[0].aval.shape, eqn
-    # a body's three and their six transposes, the first chunk's and the
-    # loop's, and the forward's three twice
-    assert grouped == 2 * 3 + 2 * 9
+        if eqn.primitive.name == "pallas_call" \
+                and eqn.params["name"].startswith("grouped_"):
+            # after the visits' four arrays of integers: the rows
+            assert eqn.invars[4].aval.shape[0] == chunk, eqn
+    # the first chunk's and the loop's, each: the forward's three, and
+    # backward the forward's three again, the rows' three cotangents and
+    # the weights' three; XLA's own grouped product is gone
+    found = primitives(jaxpr)
+    assert (found["grouped_rows"], found["grouped_rows_t"],
+            found["grouped_weights"], found["ragged_dot_general"]) == (
+        2 * 3 + 2 * 3, 2 * 3, 2 * 3, 0), found
 
 
 def poisoned(grouped, traced: list):
@@ -276,6 +303,30 @@ def assert_unwritten_rows_are_never_read(scoring: str, load: int):
         assert np.all(np.isfinite(leaf)), jax.tree_util.keystr(path)
         np.testing.assert_array_equal(leaf, other,
                                       jax.tree_util.keystr(path))
+
+
+def tiles_gauges():
+    """``autodist_moe_grouped_row_tiles_per_step``: (visited, live)."""
+    from autodist_tpu.telemetry.registry import DEFAULT_REGISTRY
+
+    found = {m.labels["kind"]: m.value for m in DEFAULT_REGISTRY.metrics()
+             if m.name == "autodist_moe_grouped_row_tiles_per_step"}
+    return found.get("visited"), found.get("live")
+
+
+def tiles_written_out(counts, chunk: int):
+    """(visited, live) of calls whose ``tokens_per_expert`` are the rows of
+    ``counts``, plainly: the chunks a call takes, the tiles of each that
+    hold a routed row (a chunk is whole tiles), and those once more for
+    every further group that has rows in them."""
+    tile, _ = grouped_matmul._row_tile(chunk)
+    visited = live = 0
+    for sizes in np.asarray(counts).reshape(-1, np.shape(counts)[-1]):
+        group = np.repeat(np.arange(len(sizes)), sizes)
+        for start in range(0, len(group), tile):
+            live += 1
+            visited += len(set(group[start:start + tile]))
+    return visited, live
 
 
 def budget_gauges():

@@ -23,7 +23,12 @@ kernel, segments of 8 chunks: 55,189 characters and two ``pallas_call``
 texts more), and left the four others alone.  PR 43 (the LFM2-8B-A1B share:
 ``routed_decoder``'s ``dense_layers`` and ``tie_head``,
 ``routed_moe_ffn``'s ``norm_eps``, three scopes) changed NONE of the five
-and recorded its own model's.  A PR that changes one of
+and recorded its own model's.  PR 44 recorded the five expert models'
+anew, ON PURPOSE (the routed layer's grouped products are the kernels of
+``ops/grouped_matmul.py`` as a TPU traces them, their bodies and the
+integers of their visits in the text, each kind a jitted function that
+is traced once a shape: 24 calls an expert layer's gradient in
+``jax.lax.ragged_dot``'s place), and left gpt2-medium's alone.  A PR that changes one of
 these models' traces on purpose records the new hash here and says so in
 ``CHANGES.md``.
 """
@@ -49,20 +54,20 @@ TRACES = {
         4, 1024, 869756, 48,
         "33aeee217cc7412cf3b23c92a314f5eaeab4ac537a96f824a79bfda0f11ec301"),
     "kanana-2-30b-a3b.ep8-share": (
-        4, 4096, 345630, 15,
-        "bde23815b46d978c798415175820ecae82aca6165261e3d3a04105512a831e00"),
+        4, 4096, 1026117, 44,
+        "3fe6eb364a4e3b271463035190f31dccc48856dee57ab8ceeff750dc1f3cbedc"),
     "keye-vl-2.0-30b-a3b.ep8-share": (
-        1, 16384, 1054039, 13,
-        "0a0a437f51802112d34ad10eb1dba9364890f06f1285af369fd9aa55f77c4cd3"),
+        1, 16384, 1734266, 42,
+        "abf903076b0cd68d460681b4e3020c9aaadea97ea6d800026ad06ef708e42c30"),
     "smallthinker-21b-a3b.ep8-share": (
-        1, 16384, 286681, 14,
-        "a72c5f085fb2840068171a70c53911d5943ef75dbc4a46c21add6cb171dd8f4e"),
+        1, 16384, 964590, 43,
+        "4bdd8a2e2743d69baa536d7845d1f87febdec9ee5fd92dcf45c8e5523c4ab936"),
     "qwen3-next-80b-a3b.ep16-share": (
-        2, 8192, 656441, 18,
-        "80ef56c7495308632ff490eba90cf0d0ed8dec5c173acebc68348ab87c8fc2ee"),
+        2, 8192, 1328871, 47,
+        "82f089a9af39b53309c908c4f0b1fb781c28fc89d3e0173b9eace9325d157d27"),
     "lfm2-8b-a1b.ep4-share": (
-        4, 8192, 291143, 13,
-        "c0029a3dbbc4c4be3f29aea479b664fd119411902d9f7ce6f660558fc80d8ec8"),
+        4, 8192, 967221, 42,
+        "256f5023c2f3b6830753b48e74da809d5c418afec7f41c62cc5447373465ac50"),
 }
 
 
@@ -81,9 +86,10 @@ def compiled_kernels(factory: str, kwargs: dict) -> dict:
 
 @pytest.mark.parametrize("name", sorted(TRACES))
 def test_value_and_gradient_trace_to_the_recorded_text(name, monkeypatch):
-    from autodist_tpu.ops import rows_to_tokens
+    from autodist_tpu.ops import grouped_matmul, rows_to_tokens
 
     monkeypatch.setattr(rows_to_tokens, "_use_interpret", lambda: False)
+    monkeypatch.setattr(grouped_matmul, "_use_interpret", lambda: False)
     rows, t, characters, kernels, digest = TRACES[name]
     with open(os.path.join(CONFIGS, name + ".json")) as f:
         program = json.load(f)["program"]

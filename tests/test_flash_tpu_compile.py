@@ -9,6 +9,7 @@ results or speed.  The topology is described inside a fixture (only the
 worker that is given this file loads the TPU's library), and every such
 test lives in this one file.
 """
+import collections
 import functools
 import math
 import os
@@ -297,7 +298,10 @@ _EXPERT_LAYERS = {
     # expert leaf, 0.063 GB: without the gathers' fill pass XLA holds one
     # more copy of a leaf at the layer's peak; its whole STEP reads 11.10 GB
     # for the parent's 12.08); the configuration and the sequences of a
-    # step
+    # step; and, where they are not the first three cells', the experts'
+    # width and the step's tokens in the slices the model hands the layer.
+    # The last two cells' ceilings are the compiler's reading AT THE PARENT
+    # OF PR 44 (``jax.lax.ragged_dot`` for the grouped products)
     "kanana": (dict(top_k=6, scoring="sigmoid", routed_scale=2.448),
                (2048, 16, 128), dict(selection_bias=True, d_shared=2 * 768),
                2.340, "kanana-2-30b-a3b.ep8-share", 4),
@@ -308,6 +312,13 @@ _EXPERT_LAYERS = {
                           activation=jax.nn.relu), (2560, 8, 64),
                      dict(selection_bias=False), 2.196 + 0.063,
                      "smallthinker-21b-a3b.ep8-share", 1),
+    "lfm2": (dict(top_k=4, scoring="sigmoid", norm_eps=1e-6), (2048, 8, 32),
+             dict(selection_bias=True), 2.899,
+             "lfm2-8b-a1b.ep4-share", 4, 1792, (8, 4096)),
+    "qwen3_next": (dict(top_k=10, scoring="softmax"), (2048, 32, 512),
+                   dict(selection_bias=False, d_shared=512,
+                        shared_gate=True), 2.350,
+                   "qwen3-next-80b-a3b.ep16-share", 2, 512, (4, 4096)),
 }
 #: what a v5e chip gives one program (``memory_stats()["bytes_limit"]``,
 #: read on the chip in PR 28)
@@ -339,31 +350,44 @@ def _on(chip, tree):
         s.shape, s.dtype, sharding=chip), tree)
 
 
+def _compiled_kernels(monkeypatch):
+    """The routed layer's kernels as a TPU runs them, not interpreted."""
+    from autodist_tpu.ops import grouped_matmul, rows_to_tokens
+
+    monkeypatch.setattr(rows_to_tokens, "_use_interpret", lambda: False)
+    monkeypatch.setattr(grouped_matmul, "_use_interpret", lambda: False)
+
+
 @pytest.mark.parametrize("cell", sorted(_EXPERT_LAYERS))
 def test_expert_layer_compiles_as_one_call_over_chunks(one_chip, cell,
                                                        monkeypatch):
     """One routed expert layer at an expert cell's widths and ALL of a
-    step's 16,384 tokens in ONE call (handed in as four slices of 4,096,
-    under the layer's checkpoint that keeps the routing's integers), value
-    and gradient: NO conditional is left in the compiled program; the
-    loops over the further chunks survive as one ``while`` forward and one
+    step's tokens in ONE call (handed in as slices of 4,096, under the
+    layer's checkpoint that keeps the routing's integers), value and
+    gradient: NO conditional is left in the compiled program; the loops
+    over the further chunks survive as one ``while`` forward and one
     backward (XLA neither unrolled them nor lost one), each direction
-    holds its three (forward) or nine (backward) grouped products twice,
-    for the first chunk and in the loop's body, and one kernel that
-    brings the sorted rows back to token order in each; nothing the
-    program computes is as large as every pick's row (``N * k * d``); and
-    the compiler's reading of the layer's memory is no higher than at the
-    parent, which ran the same tokens as four mapped calls."""
-    from autodist_tpu.ops import rows_to_tokens
+    holds its three (forward) or nine (backward: the forward's three
+    again, the rows' three cotangents by the kernel that reads the weights
+    transposed, the weights' three gradients) grouped products twice, for
+    the first chunk and in the loop's body, every one a kernel of
+    ``ops/grouped_matmul.py`` (Mosaic took them at these widths and their
+    VMEM) and no ``ragged-dot`` of XLA's left, and one kernel that brings
+    the sorted rows back to token order in each; nothing the program
+    computes is as large as every pick's row (``N * k * d``); and the
+    compiler's reading of the layer's memory is no higher than at the
+    parent."""
     from autodist_tpu.parallel import moe
 
     call, (d, held, total), init, parent_gb = _EXPERT_LAYERS[cell][:4]
-    monkeypatch.setattr(rows_to_tokens, "_use_interpret", lambda: False)
+    f, (slices, slice_) = (_EXPERT_LAYERS[cell][6:] or (768, (4, 4096)))
+    _compiled_kernels(monkeypatch)
     keep = jax.checkpoint_policies.save_only_these_names(
         *moe.ROUTING_RESIDUAL_NAMES)
-    picks = 16384 * call["top_k"]
-    chunk = moe.chunk_rows(picks, held, total, 4096 * call["top_k"])
-    assert chunk == picks // 4
+    picks = slices * slice_ * call["top_k"]
+    chunk = moe.chunk_rows(picks, held, total, slice_ * call["top_k"])
+    # twice the even load, which at kanana, keye and lfm2 is a slice's picks
+    assert chunk == min(2 * picks * held // total, slice_ * call["top_k"])
 
     @functools.partial(jax.checkpoint, policy=keep)
     def one_call(params, x):
@@ -375,27 +399,33 @@ def test_expert_layer_compiles_as_one_call_over_chunks(one_chip, cell,
         return jnp.sum(y * y)
 
     params = jax.eval_shape(lambda: moe.init_routed_moe_params(
-        jax.random.key(0), d, 768, total, experts_held=held, **init))
+        jax.random.key(0), d, f, total, experts_held=held, **init))
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
         _on(one_chip, params),
-        _on(one_chip, jax.ShapeDtypeStruct((4, 4096, d), jnp.float32))
+        _on(one_chip, jax.ShapeDtypeStruct((slices, slice_, d), jnp.float32))
     ).compile()
     text = compiled.as_text()
-    assert " conditional(" not in text
-    # three products forward, nine backward, the first chunk's and the
-    # loop's; ragged-dot-metadata calls aside
-    assert len(re.findall(r"%ragged-dot-none[\w.]* = ", text)) == 2 * (3 + 9)
+    assert " conditional(" not in text and "ragged-dot" not in text
     computations = _computations(text)
-    bodies = re.findall(r" while\(.*body=([%\w.\-]+)", text)
-    grouped = []
-    for body in bodies:
-        grouped.append(sum(line.startswith("%ragged-dot-none")
-                           for line in computations[body]))
-    assert sorted(n for n in grouped if n) == [3, 9], grouped
-    kernels = [line for lines in computations.values() for line in lines
-               if 'custom_call_target="tpu_custom_call"' in line
-               and line.startswith("%rows_to_tokens")]
-    assert len(kernels) == 4
+    # by the name the program gave the kernel
+    kernels = collections.Counter(
+        (re.match(r"%([a-z_]+)[.\d]* = ", line).group(1), name)
+        for name, lines in computations.items() for line in lines
+        if 'custom_call_target="tpu_custom_call"' in line)
+    bodies = set(re.findall(r" while\(.*body=([%\w.\-]+)", text))
+    by_name = collections.Counter()
+    in_loops = collections.defaultdict(collections.Counter)
+    for (kernel, name), n in kernels.items():
+        by_name[kernel] += n
+        if name in bodies:
+            in_loops[name][kernel] += n
+    # the first chunk's and the loop's
+    assert by_name == {"grouped_rows": 2 * (3 + 3), "grouped_rows_t": 2 * 3,
+                       "grouped_weights": 2 * 3, "rows_to_tokens": 4}, by_name
+    assert sorted(map(dict, in_loops.values()), key=len) == [
+        {"grouped_rows": 3, "rows_to_tokens": 1},
+        {"grouped_rows": 3, "grouped_rows_t": 3, "grouped_weights": 3,
+         "rows_to_tokens": 1}], in_loops
     for lines in computations.values():
         for line in lines:
             if "=" not in line:
@@ -417,11 +447,10 @@ def test_expert_cell_step_fits_the_chip(one_chip, cell, monkeypatch):
 
     import optax
 
-    from autodist_tpu.ops import rows_to_tokens
     from autodist_tpu.ops.flash_attention import flash_attention
 
-    config, rows = _EXPERT_LAYERS[cell][4:]
-    monkeypatch.setattr(rows_to_tokens, "_use_interpret", lambda: False)
+    config, rows = _EXPERT_LAYERS[cell][4:6]
+    _compiled_kernels(monkeypatch)
     with open(os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "benchmark", "configs",
             config + ".json")) as f:
@@ -429,9 +458,14 @@ def test_expert_cell_step_fits_the_chip(one_chip, cell, monkeypatch):
     kwargs = dict(program["kwargs"])
     kwargs["dtype"] = getattr(jnp, kwargs["dtype"])
     module, factory = program["factory"].rsplit(".", 1)
-    spec = getattr(importlib.import_module(module), factory)(
-        **kwargs, attn_fn=functools.partial(flash_attention,
-                                            interpret=False))
+    kernels = {"attn_fn": functools.partial(flash_attention, interpret=False)}
+    if factory == "gdn_moe_lm":
+        from autodist_tpu.ops.gated_delta_rule import gated_delta_rule
+
+        kernels["gdn_fn"] = functools.partial(
+            gated_delta_rule, chunk=kwargs["chunk"], interpret=False)
+    spec = getattr(importlib.import_module(module), factory)(**kwargs,
+                                                             **kernels)
     opt = optax.adamw(1e-3)
     shapes = jax.eval_shape(spec.init, jax.random.key(0))
 

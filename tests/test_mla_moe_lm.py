@@ -428,6 +428,10 @@ def test_three_session_steps_match_the_reference_adamw(router):
     taken = rungs[3 * (router == "skewed")]
     last = {r: three_steps[r] - two_steps.get(r, 0) for r in rungs}
     assert last == {r: 2 * (r == taken) for r in rungs}
+    # the row tiles (of 32: chunks of 96) ONE of the forward's grouped
+    # products visited in the last step, and those that held a routed row
+    assert routed_cases.tiles_gauges() == routed_cases.tiles_written_out(
+        outs[-1]["aux"]["tokens_per_expert"], rungs[0])
     assert {r: three_steps[r] - before.get(r, 0) for r in rungs} \
         == {r: 6 * (r == taken) for r in rungs}
     assert rows == 2 * taken

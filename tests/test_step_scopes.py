@@ -16,6 +16,7 @@ import numpy as np
 import optax
 import pytest
 
+import _routed_cases as routed_cases
 from autodist_tpu import strategy as strategies
 from autodist_tpu.autodist import (
     AutoDist,
@@ -114,8 +115,11 @@ def equations(jaxpr, outer=""):
 
 @functools.cache
 def walked(name):
+    """The step as a TPU traces it (the grouped products kernels)."""
     sess, batch = session(name)
-    jaxpr = sess._step.step_fn.trace(*step_arguments(sess, batch)).jaxpr
+    with routed_cases.grouped_products_by_the_kernels():
+        jaxpr = sess._step.step_fn.trace(
+            *step_arguments(sess, batch)).jaxpr
     return [(eqn.primitive.name, stack, str(eqn.source_info.traceback))
             for eqn, stack in equations(jaxpr.jaxpr)]
 
@@ -129,6 +133,15 @@ def test_every_heavy_equation_is_under_a_scope(name):
     bare = sorted({(prim, stack) for prim, stack in heavy
                    if not NAMED.search(stack)})
     assert not bare, bare
+    # the routed layer's grouped products, forward and both transposes,
+    # are kernels under its experts' scope
+    grouped = [stack for prim, stack in heavy
+               if prim == "pallas_call" and "grouped_" in stack]
+    assert all(timeline.SCOPE_MOE_EXPERTS in stack for stack in grouped)
+    assert {stack.rsplit("/", 1)[-1] for stack in grouped} == (
+        set() if name == "transformer_lm" else
+        {"grouped_rows", "grouped_rows_t", "grouped_weights"}), grouped
+    assert "ragged_dot_general" not in {p for p, _ in heavy}
     # a body reached through every kind of container the steps have
     stacks = "\n".join(stack for _, stack in heavy)
     # (<while>: the routed layer's loop over further chunks, PR 39; a
