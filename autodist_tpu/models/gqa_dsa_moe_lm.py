@@ -16,11 +16,18 @@ DeepSeek-V3.2-Exp report).  One layer, ``h = RMSNorm(x)``:
   ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])`` for ``s <= t``, in
   float32 at ``highest`` so that the selection does not turn on rounding.
   ``S_t``: the ``topk`` largest of row ``t`` (all of ``0..t`` before row
-  ``topk``), ties to the lower position: ``ops/topk_select.py`` finds each
-  row's threshold without sorting, one block of ``index_rows`` queries at
-  a time against the keys at or before the block's last row, and
-  ``pack_selection`` writes one bit a pair.  Rows that select everything
-  form no score.  The cross-entropy has no path into the indexer (its own
+  ``topk``), ties to the lower position, one bit a pair
+  (``pack_selection``'s words).  Two forms of one selection, chosen by the
+  platform as every kernel of the repo is.  On a TPU
+  ``ops/index_select.py: dsa_select``, ONE kernel a layer and sequence: a
+  tile of 256 queries' scores against the keys at or before its last row
+  are formed, searched for each row's threshold and packed in VMEM, and
+  only the words leave (the six bfloat16 products of ``highest`` two to a
+  128-deep pass).  Elsewhere, and as what the tests hold the kernel to,
+  the plain form :func:`select_keys`: ``index_rows`` queries at a time,
+  :func:`index_scores`, ``ops/topk_select.py`` for each row's threshold
+  without sorting, ``pack_selection``.  Rows that select everything form
+  no score.  The cross-entropy has no path into the indexer (its own
   alignment loss is not written): its leaves get a zero gradient.
 * **Attention** over ``S_t`` alone, by a mask inside the flash kernel:
   every causal tile is computed (``autodist_dsa_pairs_per_step`` says how
@@ -54,6 +61,7 @@ from autodist_tpu.ops.flash_attention import (
     pairs_computed,
     unpack_selection,
 )
+from autodist_tpu.ops import index_select
 from autodist_tpu.ops.pallas_utils import pick_block, use_interpret
 from autodist_tpu.ops.topk_select import top_k_mask
 from autodist_tpu.parallel.moe import (
@@ -96,7 +104,9 @@ def select_keys(qi, ki, w, *, topk: int, rows: int, block_k: int):
     32, T]`` (keys down, queries along): ``qi [T, J, Di]``, ``ki [T,
     Di]``, ``w [T, J]``.  ``rows`` queries at a time, each block against
     keys ``0 .. its last row``; a block that ends at or before row
-    ``topk`` selects all earlier keys and forms no score."""
+    ``topk`` selects all earlier keys and forms no score.  The plain form:
+    what runs off a TPU, and what ``ops/index_select.py: dsa_select`` is
+    held to bit for bit (``tests/test_index_select.py``)."""
     t = qi.shape[0]
     if t % rows or rows % block_k:
         raise ValueError(f"{t} rows in blocks of {rows} over key blocks "
@@ -161,7 +171,8 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
                    embed_scale: float = 1.0,
                    final_scale: Callable = lambda p: p["scale"],
                    dense_layers: Tuple[int, ...] = (),
-                   tie_head: bool = False) -> ModelSpec:
+                   tie_head: bool = False,
+                   record_attention: Optional[Callable] = None) -> ModelSpec:
     """What the decoders of this file and of ``swa_moe_lm.py`` share: the
     embedding, ``num_layers`` layers of an attention half (one sequence at
     a time) and an expert half (all of the step's tokens at once, as
@@ -183,7 +194,11 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
     ``tokens_per_expert``.  ``tie_head``: the head multiplies by
     ``params["embed"]`` and there is no ``params["head"]`` (the table's
     gradient is then dense).  ``set_pairs_gauges(tokens)``: the model's
-    own gauges, set while tracing."""
+    own gauges, set while tracing.  ``record_attention(noted)``: an
+    attention half may return ``(x, noted)``; the layers' ``noted`` (each
+    stacked over the sequences, None where a half noted nothing) are handed
+    over once a step at the top level of the loss function, where a step
+    value can be emitted (``telemetry/step_values.py``)."""
     keep = jax.checkpoint_policies.save_only_these_names(*kept_names)
 
     @functools.cache
@@ -229,17 +244,24 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
         """``x [B, T, D]`` through one layer: attention one sequence at a
         time, the experts once over all the tokens (a dense FFN one slice
         at a time).  Returns the layer's ``tokens_per_expert`` ``[count]``
-        beside ``x``, None for a dense layer."""
+        beside ``x``, None for a dense layer, and what its attention half
+        noted, if anything."""
         attention_half, expert_half = halves
         entered = x
-        x = jax.lax.map(lambda row: attention_half(lp, row[None])[0], x)
+
+        def attended(row):
+            out = attention_half(lp, row[None])
+            return (out[0][0], out[1]) if isinstance(out, tuple) \
+                else (out[0], None)
+
+        x, noted = jax.lax.map(attended, x)
         if dense:
             y = jax.lax.map(lambda part: expert_half(lp, part[None])[0],
                             slices(x))
-            return y.reshape(x.shape), None
+            return y.reshape(x.shape), None, noted
         y, counts = expert_half(lp, slices(x), *(
             [slices(entered)] if router_reads_input else []))
-        return y.reshape(x.shape), counts
+        return y.reshape(x.shape), counts, noted
 
     def set_gauges(params, tokens, x):
         set_pairs_gauges(tokens)
@@ -258,17 +280,20 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
             if embed_scale != 1.0:
                 x = x * embed_scale
         set_gauges(params, tokens, x)
-        counts = []
+        counts, noted = [], []
         with jax.named_scope(timeline.SCOPE_LM_LAYERS):
             for i in range(num_layers):
                 dense = i in dense_layers
-                x, c = layer(params[f"layers_{i}"], x,
-                             as_run(halves_of(i), dense), dense)
+                x, c, n = layer(params[f"layers_{i}"], x,
+                                as_run(halves_of(i), dense), dense)
+                noted.append(n)
                 if not dense:
                     counts.append(c)
             # here, outside the layers' checkpoints
             record_row_budgets(jnp.stack(counts), tokens.size * top_k,
                                num_experts, slices(x).shape[1] * top_k)
+            if record_attention is not None:
+                record_attention(noted)
         with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
             feats = rms_norm(x, final_scale(params["ln_final"]), rms_eps)
         return feats, counts
@@ -334,6 +359,9 @@ def gqa_dsa_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
     ``attn_fn(q, k, v, True, selection=words, select_from=topk)`` (no
     keywords where the sequence is no longer than ``topk``); ``block_k``
     is the key block the words are packed for, the attention's own.
+    ``index_rows``: the queries of a block of the PLAIN form of the
+    selection (:func:`select_keys`, off a TPU); the kernel that selects on
+    a TPU takes its tile from the shapes (``index_select.tile_of``).
     ``remat``: "none" | "full": the attention half and the expert half of
     a layer are recomputed in the backward EXCEPT what a kernel, a
     selection or a sort produced (``KEPT_NAMES``).  ``moe_slice``: no
@@ -382,13 +410,20 @@ def gqa_dsa_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
                     experts_held=held[1], selection_bias=False, dtype=dtype)}
         return params
 
+    def plain_blocks(t):
+        """(the key block the words are packed for, the rows of a block of
+        the plain form) at ``t`` tokens."""
+        bk = pick_block(t, block_k)
+        return bk, max(bk, min(index_rows, t) // bk * bk)
+
     def selection_of(p, h):
         """``pack_selection``'s words ``[B, T // 32, T]`` of ``h [B, T,
         D]`` (the layer's normed input), or None where nothing is left
-        out.  Integers: no gradient passes."""
+        out, and the kernel's tiles that searched a second time ``[B,
+        tiles]`` (None off the TPU).  Integers: no gradient passes."""
         t = h.shape[1]
         if t <= topk:
-            return None
+            return None, None
         # projections and scores alike in float32 at ``highest``: a score
         # a bfloat16 pass moved by 3e-3 changes places with its neighbours
         with jax.named_scope(timeline.SCOPE_DSA_INDEX), \
@@ -399,12 +434,20 @@ def gqa_dsa_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
             ki = rotary_halves(layer_norm(h @ p["wk"], p["k_norm"], rms_eps),
                                rope_theta)
             w = h @ p["weights"]
-        bk = pick_block(t, block_k)
-        rows = max(bk, min(index_rows, t) // bk * bk)
-        # a map, not a vmap: top_k_mask's rare branch stays a branch
-        words = jax.lax.map(lambda row: select_keys(
-            *row, topk=topk, rows=rows, block_k=bk), (qi, ki, w))
-        return checkpoint_name(words, SELECTION_NAME)
+        bk, rows = plain_blocks(t)
+        # a map, not a vmap: the second search stays a branch
+        if index_select._use_interpret():
+            words, ties = jax.lax.map(lambda row: select_keys(
+                *row, topk=topk, rows=rows, block_k=bk), (qi, ki, w)), None
+        else:
+            # the kernel's HLO name is the innermost scope
+            with jax.named_scope(timeline.SCOPE_DSA_SELECT), \
+                    jax.named_scope("dsa_select"):
+                words, ties = jax.lax.map(
+                    lambda row: index_select.dsa_select(
+                        *row, topk=topk, block_k=bk),
+                    jax.lax.stop_gradient((qi, ki, w)))
+        return checkpoint_name(words, SELECTION_NAME), ties
 
     def attention_half(lp, x):
         """``x [B, T, D]`` plus its attention."""
@@ -416,14 +459,16 @@ def gqa_dsa_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
                 rms_eps), rope_theta)
                 for w, n in (("wq", "q_norm"), ("wk", "k_norm")))
             v = jnp.einsum("btd,dhk->bthk", h, p["wv"])
-        selection = selection_of(lp["indexer"], jax.lax.stop_gradient(h))
+        selection, ties = selection_of(lp["indexer"],
+                                       jax.lax.stop_gradient(h))
         # the kernel's HLO name is the innermost scope
         with jax.named_scope(timeline.SCOPE_DSA_ATTENTION), \
                 jax.named_scope("sparse_attn"):
             o = attn_fn(q, k, v, True) if selection is None else attn_fn(
                 q, k, v, True, selection=selection, select_from=topk)
         with jax.named_scope(timeline.SCOPE_GQA_PROJECT):
-            return x + jnp.einsum("bthv,hvd->btd", o, p["wo"])
+            x = x + jnp.einsum("bthv,hvd->btd", o, p["wo"])
+        return x if ties is None else (x, ties)
 
     def expert_half(lp, x):
         """``x [slices, slice, D]`` plus its experts' output, and the
@@ -445,11 +490,45 @@ def gqa_dsa_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
                 "pairs of query and key a step's attention is asked for "
                 "(a head, forward), and pairs whose score its kernel "
                 "forms", {"kind": kind}).set(pairs * batch * num_layers)
+        # the rows in the tiles (the kernel's) or blocks (the plain form's)
+        # that end past row ``topk``: the others form no score
+        plain = index_select._use_interpret()
+        tile = plain_blocks(t)[1] if plain else index_select.tile_of(t)
+        scored = t - topk // tile * tile if t > topk else 0
+        for path, rows in (("plain", scored * plain),
+                           ("kernel", scored * (not plain)),
+                           ("whole", t - scored)):
+            registry.gauge(
+                "autodist_dsa_select_rows_per_step",
+                "query rows a step's layers select keys for, by what "
+                "formed their selection: the dsa_select kernel, the plain "
+                "form (off a TPU), or nothing (a tile or block of rows "
+                "with topk or fewer keys takes them all and forms no "
+                "score)", {"path": path}).set(rows * batch * num_layers)
+
+    def record_ties(noted):
+        """``noted``: the layers' ``[B, tiles]`` flags of the kernel's
+        second search, None where the plain form selected."""
+        flags = [n for n in noted if n is not None]
+        if not flags:
+            return
+
+        def publish(searches):      # a scalar, stacked over microbatches
+            registry.gauge(
+                "autodist_dsa_tie_searches_per_step",
+                "tiles of queries of the last step's dsa_select calls in "
+                "which some row had more keys equal to its threshold than "
+                "places left, so that the search over positions ran"
+            ).set(int(np.sum(searches)))
+
+        step_values.emit("dsa_tie_searches", jnp.sum(jnp.stack(flags)),
+                         publish)
 
     return routed_decoder(
         name="gqa_dsa_moe_lm", init=init,
         halves_of=lambda i: (attention_half, expert_half),
         kept_names=KEPT_NAMES, set_pairs_gauges=set_pairs_gauges,
+        record_attention=record_ties,
         vocab_size=vocab_size, num_layers=num_layers, seq_len=seq_len,
         moe_slice=moe_slice, top_k=top_k, num_experts=num_experts,
         rms_eps=rms_eps, xent_chunk=xent_chunk, remat=remat,

@@ -8,6 +8,12 @@ compare and one count over the row (a radix select), then ``x >= it``.
 Ties at the threshold go to the lower positions, as ``top_k``'s do: a
 second search, over the 14 or so bits of a position, entered only where
 some row has more equals at its threshold than places left.
+
+As XLA operations every round reads the whole ``[rows, N]`` block from main
+memory.  The keye model (``models/gqa_dsa_moe_lm.py``) runs this form off a
+TPU, through ``select_keys``; on a TPU the same search, round for round,
+runs inside ``ops/index_select.py: dsa_select`` over a tile that stays in
+VMEM, and this file is what the tests hold that kernel to.
 """
 from __future__ import annotations
 

@@ -639,6 +639,49 @@ def close_session(ad, sess):
     gc.collect()
 
 
+def check_dsa_select(t: int, heads: int, dim: int, topk: int, *,
+                     block_k: int) -> dict:
+    """``ops/index_select.py: dsa_select`` on one seeded sequence of ``t``
+    tokens against the plain form it takes the place of on a TPU
+    (``select_keys``; the kernel interpreted off a TPU): the words equal, or
+    how many bits differ and where.
+    On a chip both form ``highest``'s six bfloat16 products, the kernel two
+    to a pass and XLA one, so their float32 sums may differ in a last bit
+    and a pick AT a row's threshold change places with the next; anything
+    else is a fault: every row takes ``min(its keys, topk)`` keys, no key
+    after the query, and fewer than one pick in a thousand moves."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from autodist_tpu.models.gqa_dsa_moe_lm import select_keys
+    from autodist_tpu.ops.flash_attention import unpack_selection
+    from autodist_tpu.ops.index_select import dsa_select
+    from autodist_tpu.ops.pallas_utils import pick_block
+
+    rng = np.random.RandomState(SEED)
+    qi = jnp.asarray(rng.randn(t, heads, dim), jnp.float32)
+    ki = jnp.asarray(rng.randn(t, dim), jnp.float32)
+    w = jnp.asarray(rng.randn(t, heads) * dim ** -0.5, jnp.float32)
+    bk = pick_block(t, block_k)
+    want = jax.jit(functools.partial(select_keys, topk=topk, rows=bk,
+                                     block_k=bk))(qi, ki, w)
+    words, ties = dsa_select(qi, ki, w, topk=topk, block_k=bk)
+    got = np.asarray(unpack_selection(words, block_k=bk))
+    moved = got != np.asarray(unpack_selection(want, block_k=bk))
+    facts = {"bits_differing": int(moved.sum()),
+             "rows_differing": int(moved.any(axis=1).sum()),
+             "first_places": np.argwhere(moved)[:8].tolist(),
+             "tiles_searched_twice": int(np.asarray(ties).sum())}
+    taken = np.minimum(np.arange(t) + 1, topk)
+    if (got.sum(axis=1) != taken).any() or np.triu(got, 1).any():
+        raise AssertionError(f"dsa_select at T={t}: a row takes another "
+                             f"number of keys than {topk}, or a later key")
+    if facts["bits_differing"] > 2e-3 * taken.sum():
+        raise AssertionError(f"dsa_select against select_keys: {facts}")
+    return facts
+
+
 def train_dsa_moe_phase(model: dict, *, batch_size: int, steps: int) -> dict:
     """``models/gqa_dsa_moe_lm.py`` (grouped-query heads over the keys a
     learned indexer selects, softmax-routed experts of which this chip
@@ -648,7 +691,10 @@ def train_dsa_moe_phase(model: dict, *, batch_size: int, steps: int) -> dict:
     was asked for and how many its kernel scores; on a TPU the compiled
     step holds a forward and a backward Pallas call a layer, none run
     twice, and the forward's result has the QUERY heads while its keys
-    went in with their own (``num_kv_heads``)."""
+    went in with their own (``num_kv_heads``), and one ``dsa_select`` call
+    a layer selects the keys; that kernel's words on one seeded sequence
+    of the model's length against the plain form's
+    (:func:`check_dsa_select`)."""
     import jax
 
     from autodist_tpu.models.gqa_dsa_moe_lm import gqa_dsa_moe_lm
@@ -668,9 +714,17 @@ def train_dsa_moe_phase(model: dict, *, batch_size: int, steps: int) -> dict:
                 or heads != {cfg["num_heads"]}:
             raise AssertionError(f"attention custom calls work on {attn}")
         keys_with_their_own_heads(text, cfg)
+        selects = len(re.findall(
+            r"^\s*(?:ROOT )?%dsa_select[.\d]* = .*tpu_custom_call", text,
+            re.M))
+        if cfg["seq_len"] > cfg["topk"] and selects != cfg["num_layers"]:
+            raise AssertionError(f"{selects} dsa_select calls in the step")
         facts["attention_calls"] = len(attn)
         facts["bytes_in_use"] = memory_in_use(jax.devices()[:1])
     close_session(ad, sess)
+    facts["select_against_the_plain_form"] = check_dsa_select(
+        cfg["seq_len"], cfg["index_heads"], cfg["index_dim"], cfg["topk"],
+        block_k=model.get("block_k", 512))
     return facts
 
 

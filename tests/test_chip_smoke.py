@@ -87,6 +87,10 @@ def test_train_dsa_moe_phase_returns_counts_and_pairs():
     # two layers x two sequences x (32 x 33 / 2 + 32 x 32) selected pairs
     assert facts["pairs_per_step"] == {"selected": 4 * 1552,
                                        "computed": 4 * 64 * 64}
+    # the kernel (interpreted here) against the plain form: the same words
+    select = facts["select_against_the_plain_form"]
+    assert select["bits_differing"] == select["rows_differing"] == 0
+    assert select["first_places"] == []
 
 
 def test_train_swa_moe_phase_checks_both_kinds_of_layer():

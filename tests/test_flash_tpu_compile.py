@@ -162,6 +162,43 @@ def test_grouped_heads_and_selection_compile(one_chip, name, precision):
     assert all(f"f32[{b},{g},{t},{d}]" in ln for ln in calls)
 
 
+_SELECTS = {
+    # T, index heads x width, topk, the attention's key block
+    "keye_one_sequence": (16384, 16, 64, 2048, 512),
+    "chip_smoke_sequence": (4096, 16, 64, 2048, 512),
+    "tile_across_topk": (1536, 4, 64, 640, 512),   # rows 512..767 of a tile
+    "one_tile": (384, 2, 128, 100, 128),            # 384 rows: no 256 tile
+}
+
+
+@pytest.mark.parametrize("name", sorted(_SELECTS))
+def test_dsa_select_compiles(one_chip, name):
+    """``ops/index_select.py`` at the keye cell's shapes (16,384 tokens, 16
+    index heads of 64, 2,048 of a row's keys, key blocks of 512: a tile's
+    ``[16384, 256]`` scores, the keys and their three terms and the tile's
+    operands in 56 MiB of VMEM) and at shorter ones: ONE kernel, by the
+    name ``dsa_index_select_device_pct`` finds it under, that reads
+    float32 operands and writes the words and the tiles' flags."""
+    from autodist_tpu.ops import index_select
+
+    t, heads, dim, topk, bk = _SELECTS[name]
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    compiled = jax.jit(functools.partial(
+        index_select.dsa_select, topk=topk, block_k=bk, interpret=False)
+    ).lower(shape(t, heads, dim), shape(t, dim), shape(t, heads)).compile()
+    calls = _pallas_calls(compiled)
+    assert len(calls) == 1
+    tiles = t // index_select.tile_of(t)
+    assert re.match(rf"\s*(ROOT )?%dsa_select[.\d]* = \(s32\[{t // 32},{t}\]"
+                    rf".*, s32\[{tiles}\]", calls[0]), calls[0]
+    # float32 in (cut into bfloat16 terms in VMEM), written twice abreast
+    assert f"f32[{heads},{t},{2 * dim}]" in calls[0]
+    assert f"f32[{t},{2 * dim}]" in calls[0] and "bf16[" not in calls[0]
+
+
 _WINDOWED = {
     # [B, T, H, Hkv, D], window
     "smallthinker_one_sequence": ((1, 16384, 28, 4, 128), 4096),
