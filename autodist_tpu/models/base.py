@@ -62,6 +62,38 @@ def rms_norm(x, scale, eps=1e-6) -> jax.Array:
     return (x32 * inv * scale.astype(jnp.float32)).astype(x.dtype)
 
 
+def _rotary(x: jax.Array, theta: float, split: Tuple[int, int]):
+    """``x [B, T, ..., R]``, positions along axis 1: the pair ``(a, b)`` of
+    position ``t`` and frequency ``i`` turns by ``t * theta^(-2i/R)``.
+    ``split`` says where the last axis keeps its pairs: ``(-1, 2)``
+    interleaved, ``(2, -1)`` as two halves."""
+    import jax.numpy as jnp
+
+    t, r = x.shape[1], x.shape[-1]
+    inv = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv        # [T,R/2]
+    angle = angle.reshape((1, t) + (1,) * (x.ndim - 3) + (r // 2,))
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    axis = split.index(2) - 2
+    parts = x.astype(jnp.float32).reshape(x.shape[:-1] + split)
+    a, b = (jax.lax.index_in_dim(parts, i, axis, keepdims=False)
+            for i in (0, 1))
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=axis)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def rotary_halves(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary positions on DE-INTERLEAVED columns: ``x[..., :R/2]`` holds
+    the pairs' first members and ``x[..., R/2:]`` their second, and they
+    come back so (the checkpoints' own code does this; where a weight's
+    columns mean interleaved pairs ``(x[2i], x[2i+1])``, as kanana's do,
+    the model gathers them to this order first).  The halves are taken as
+    a DIMENSION of two, not as two slices: the compiler then folds the turn
+    into the product that feeds it; sliced, each half is a 32-wide array
+    padded to the chip's 128 lanes and crosses memory four times over."""
+    return _rotary(x, theta, (2, -1))
+
+
 def cross_entropy_loss(logits, labels) -> jax.Array:
     """Mean softmax cross entropy with integer labels."""
     import jax.numpy as jnp

@@ -42,7 +42,7 @@ head ``h`` at the ``(h + 1/2) / Hv`` quantile of the ``log U(0, 16)`` that
 implementation draws ``A_log`` from, so that the heads forget at rates from
 0.25 to 15.75 (times ``softplus``) and not all at one.
 
-Built on ``gqa_dsa_moe_lm.routed_decoder`` (the halves under their
+Built on ``routed_decoder.routed_decoder`` (the halves under their
 checkpoints, the slices, the loss, the gauges, the batch); the mixers one
 sequence at a time, the experts once over the step's tokens.  Kept by
 name over the layers' checkpoints: the flash kernel's ``o`` and ``lse``,
@@ -60,12 +60,9 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from autodist_tpu.models.base import ModelSpec, rms_norm
-from autodist_tpu.models.gqa_dsa_moe_lm import (
-    default_sparse_attention,
-    routed_decoder,
-)
-from autodist_tpu.models.mla_moe_lm import rotary_halves
+from autodist_tpu.models.base import ModelSpec, rms_norm, rotary_halves
+from autodist_tpu.models.routed_decoder import routed_decoder
+from autodist_tpu.models.transformer import default_sparse_attention
 from autodist_tpu.ops.flash_attention import _DEFAULT_BLOCK, RESIDUAL_NAMES
 from autodist_tpu.ops.gated_delta_rule import (
     RESIDUAL_NAMES as GDN_RESIDUAL_NAMES,
@@ -139,8 +136,6 @@ def gdn_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
     elsewhere).  ``attn_fn(q, k, v, True)`` as ``gqa_dsa_moe_lm``.
     ``remat``, ``experts_held``, ``xent_chunk``, ``train_router``,
     ``return_counts``, ``moe_slice``: as ``gqa_dsa_moe_lm``."""
-    if remat not in ("none", "full"):
-        raise ValueError(f"remat={remat!r}: expected 'none' or 'full'")
     if num_heads % num_kv_heads or linear_value_heads % linear_key_heads:
         raise ValueError(f"{num_heads} query heads over {num_kv_heads}, "
                          f"{linear_value_heads} value heads over "

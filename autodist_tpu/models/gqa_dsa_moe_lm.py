@@ -39,11 +39,10 @@ DeepSeek-V3.2-Exp report).  One layer, ``h = RMSNorm(x)``:
   as slices of ``moe_slice`` tokens: no buffer of the layer is wider than
   a slice's ``slice * k`` picks.
 
-Functional, like ``mla_moe_lm.py``; the training path only.
+Built on ``models/routed_decoder.py``; functional, the training path only.
 """
 from __future__ import annotations
 
-import functools
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -51,23 +50,21 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from autodist_tpu.models.base import ModelSpec, cross_entropy_loss, rms_norm
-from autodist_tpu.models.mla_moe_lm import named_bytes, rotary_halves
+from autodist_tpu.models.base import ModelSpec, rms_norm, rotary_halves
+from autodist_tpu.models.routed_decoder import routed_decoder
+from autodist_tpu.models.transformer import default_sparse_attention
 from autodist_tpu.ops.flash_attention import (
     _DEFAULT_BLOCK,
     RESIDUAL_NAMES,
-    flash_attention,
     pack_selection,
     pairs_computed,
-    unpack_selection,
 )
 from autodist_tpu.ops import index_select
-from autodist_tpu.ops.pallas_utils import pick_block, use_interpret
+from autodist_tpu.ops.pallas_utils import pick_block
 from autodist_tpu.ops.topk_select import top_k_mask
 from autodist_tpu.parallel.moe import (
     ROUTING_RESIDUAL_NAMES,
     init_routed_moe_params,
-    record_row_budgets,
     routed_moe_ffn,
 )
 from autodist_tpu.telemetry import registry, step_values, timeline
@@ -131,212 +128,6 @@ def select_keys(qi, ki, w, *, topk: int, rows: int, block_k: int):
     return jnp.concatenate(out, axis=1)
 
 
-def dense_selected_attention(q, k, v, causal, *, selection=None,
-                             select_from=None, window=None,
-                             block_k=_DEFAULT_BLOCK):
-    """What the kernel computes, by the plain softmax over all pairs (off
-    the TPU, at a size a test holds): ``selection`` and ``window`` as the
-    kernel takes them."""
-    t, group = q.shape[1], q.shape[2] // k.shape[2]
-    mask = jnp.tril(jnp.ones((t, t), bool))[None] if selection is None \
-        else unpack_selection(selection, block_k=block_k)
-    if window is not None:
-        mask = mask & ~jnp.tril(jnp.ones((t, t), bool), -window)
-    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (q.shape[-1] ** 0.5)
-    logits = jnp.where(mask[:, None], logits.astype(jnp.float32), -1e30)
-    return jnp.einsum("bhqk,bkhd->bqhd",
-                      jax.nn.softmax(logits, axis=-1).astype(q.dtype), v)
-
-
-def default_sparse_attention(block_k: int = _DEFAULT_BLOCK) -> Callable:
-    """The flash kernel on a TPU, the dense softmax elsewhere; resolved at
-    the first call, as ``transformer.default_attention`` is."""
-    def attn(q, k, v, causal, **selection):
-        if use_interpret():
-            return dense_selected_attention(q, k, v, causal, block_k=block_k,
-                                            **selection)
-        return flash_attention(q, k, v, causal, block_k=block_k, **selection)
-
-    return attn
-
-
-def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
-                   kept_names: Tuple[str, ...], set_pairs_gauges: Callable,
-                   vocab_size: int, num_layers: int, seq_len: int,
-                   moe_slice: int, top_k: int, num_experts: int,
-                   rms_eps: float, xent_chunk: Optional[int], remat: str,
-                   return_counts: bool, config: dict,
-                   router_reads_input: bool = False,
-                   embed_scale: float = 1.0,
-                   final_scale: Callable = lambda p: p["scale"],
-                   dense_layers: Tuple[int, ...] = (),
-                   tie_head: bool = False,
-                   record_attention: Optional[Callable] = None) -> ModelSpec:
-    """What the decoders of this file and of ``swa_moe_lm.py`` share: the
-    embedding, ``num_layers`` layers of an attention half (one sequence at
-    a time) and an expert half (all of the step's tokens at once, as
-    slices of ``moe_slice``), each under its own checkpoint that keeps
-    ``kept_names``, the final norm, the (chunked) loss, the gauges of what
-    the checkpoints keep and of the row budgets, and the batch.
-    ``halves_of(i)``: layer ``i``'s ``(attention_half(lp, x [1, T, D]) ->
-    [1, T, D], expert_half(lp, parts [slices, slice, D]) -> (parts,
-    tokens_per_expert))``, the same functions for layers of one kind.
-    ``router_reads_input``: the expert half is also handed the layer's
-    INPUT, cut alike (``expert_half(lp, parts, input_parts)``: a router
-    placed before attention).  ``embed_scale``: the stream
-    enters layer 0 as this times the table's rows.  ``final_scale(params[
-    "ln_final"])``: what the final norm multiplies by (a zero-centred
-    norm's ``1 + w``).  ``dense_layers``: the layers whose second half is
-    a DENSE FFN and no expert half: ``halves_of(i)[1]`` is then
-    ``ffn_half(lp, part [1, slice, D]) -> [1, slice, D]``, run one slice
-    at a time under a map like the attention half, and the layer has no
-    ``tokens_per_expert``.  ``tie_head``: the head multiplies by
-    ``params["embed"]`` and there is no ``params["head"]`` (the table's
-    gradient is then dense).  ``set_pairs_gauges(tokens)``: the model's
-    own gauges, set while tracing.  ``record_attention(noted)``: an
-    attention half may return ``(x, noted)``; the layers' ``noted`` (each
-    stacked over the sequences, None where a half noted nothing) are handed
-    over once a step at the top level of the loss function, where a step
-    value can be emitted (``telemetry/step_values.py``)."""
-    keep = jax.checkpoint_policies.save_only_these_names(*kept_names)
-
-    @functools.cache
-    def as_run(halves, dense=False):
-        """A kind's halves under their checkpoints (the attention half
-        and a dense FFN run under ``lax.map``: no CSE barrier needed)."""
-        if remat == "none":
-            return halves
-        return (jax.checkpoint(halves[0], policy=keep, prevent_cse=False),
-                jax.checkpoint(halves[1], policy=keep, prevent_cse=not dense))
-
-    def slices(x):
-        """``[B, T, D]`` as ``[n, moe_slice, D]``."""
-        tokens = x.shape[0] * x.shape[1]
-        size = moe_slice if tokens % moe_slice == 0 else tokens
-        return x.reshape(tokens // size, size, x.shape[-1])
-
-    def kept_bytes(params, x):
-        """What the layers' checkpoints hold by name over a step of ``x
-        [B, T, D]``: the tagged shapes of one sequence's attention half
-        and of the step's expert half, times how many of each, over the
-        layers (one trace a kind of layer)."""
-        total = dict.fromkeys(kept_names, 0)
-        if remat == "none":
-            return total
-        parts, found = slices(x), {}
-        for i in range(num_layers):
-            halves = halves_of(i)
-            if halves not in found:
-                lp = params[f"layers_{i}"]
-                found[halves] = [
-                    (named_bytes(halves[0], lp, x[:1]), x.shape[0]),
-                    (named_bytes(halves[1], lp, parts[:1]), parts.shape[0])
-                    if i in dense_layers else
-                    (named_bytes(halves[1], lp, *[parts] * (
-                        1 + router_reads_input)), 1)]
-            for name in kept_names:
-                total[name] += sum(tagged.get(name, 0) * times
-                                   for tagged, times in found[halves])
-        return total
-
-    def layer(lp, x, halves, dense=False):
-        """``x [B, T, D]`` through one layer: attention one sequence at a
-        time, the experts once over all the tokens (a dense FFN one slice
-        at a time).  Returns the layer's ``tokens_per_expert`` ``[count]``
-        beside ``x``, None for a dense layer, and what its attention half
-        noted, if anything."""
-        attention_half, expert_half = halves
-        entered = x
-
-        def attended(row):
-            out = attention_half(lp, row[None])
-            return (out[0][0], out[1]) if isinstance(out, tuple) \
-                else (out[0], None)
-
-        x, noted = jax.lax.map(attended, x)
-        if dense:
-            y = jax.lax.map(lambda part: expert_half(lp, part[None])[0],
-                            slices(x))
-            return y.reshape(x.shape), None, noted
-        y, counts = expert_half(lp, slices(x), *(
-            [slices(entered)] if router_reads_input else []))
-        return y.reshape(x.shape), counts, noted
-
-    def set_gauges(params, tokens, x):
-        set_pairs_gauges(tokens)
-        for kept, held_bytes in kept_bytes(params, x).items():
-            registry.gauge(
-                "autodist_remat_kept_bytes_per_step",
-                "bytes the layers' checkpoints keep from forward to "
-                "backward instead of recomputing, by the value's name",
-                {"name": kept}).set(held_bytes)
-
-    def features(params, tokens):
-        """Final-norm activations ``[B, T, D]`` and the layers'
-        ``tokens_per_expert`` ``[layers, count]``."""
-        with jax.named_scope(timeline.SCOPE_LM_EMBED):
-            x = jnp.take(params["embed"], tokens, axis=0)
-            if embed_scale != 1.0:
-                x = x * embed_scale
-        set_gauges(params, tokens, x)
-        counts, noted = [], []
-        with jax.named_scope(timeline.SCOPE_LM_LAYERS):
-            for i in range(num_layers):
-                dense = i in dense_layers
-                x, c, n = layer(params[f"layers_{i}"], x,
-                                as_run(halves_of(i), dense), dense)
-                noted.append(n)
-                if not dense:
-                    counts.append(c)
-            # here, outside the layers' checkpoints
-            record_row_budgets(jnp.stack(counts), tokens.size * top_k,
-                               num_experts, slices(x).shape[1] * top_k)
-            if record_attention is not None:
-                record_attention(noted)
-        with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
-            feats = rms_norm(x, final_scale(params["ln_final"]), rms_eps)
-        return feats, counts
-
-    head_name = "embed" if tie_head else "head"
-
-    def apply_fn(params, tokens):
-        feats = features(params, tokens)[0]
-        with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
-            return jnp.einsum("btd,vd->btv", feats, params[head_name])
-
-    def loss_fn(params, batch):
-        tokens = batch["tokens"]
-        feats, counts = features(params, tokens)
-        with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
-            if xent_chunk:
-                from autodist_tpu.ops.chunked_xent import \
-                    chunked_softmax_cross_entropy
-
-                loss = chunked_softmax_cross_entropy(
-                    feats[:, :-1], params[head_name], tokens[:, 1:],
-                    chunk=xent_chunk)
-            else:
-                logits = jnp.einsum("btd,vd->btv", feats, params[head_name])
-                loss = cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
-        if return_counts:
-            return loss, {"tokens_per_expert": jnp.stack(counts)}
-        return loss
-
-    def make_batch(rng: np.random.RandomState, batch_size: int):
-        return {"tokens": rng.randint(
-            0, vocab_size, (batch_size, seq_len)).astype(np.int32)}
-
-    return ModelSpec(
-        name=name,
-        init=init, loss_fn=step_values.reporting(loss_fn), apply_fn=apply_fn,
-        make_batch=make_batch,
-        sparse_vars=() if tie_head else ("embed",),
-        expert_vars=("*/moe/experts/*",),
-        config=config,
-    )
-
-
 def gqa_dsa_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
                    d_model: int = 2048, num_heads: int = 32,
                    num_kv_heads: int = 4, head_dim: int = 128,
@@ -369,8 +160,6 @@ def gqa_dsa_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
     tokens (``routed_moe_ffn``'s chunk).
     ``experts_held``, ``xent_chunk``, ``train_router``, ``return_counts``:
     as ``mla_moe_lm``."""
-    if remat not in ("none", "full"):
-        raise ValueError(f"remat={remat!r}: expected 'none' or 'full'")
     if num_heads % num_kv_heads:
         raise ValueError(f"{num_heads} query heads over {num_kv_heads}")
     attn_fn = attn_fn or default_sparse_attention(block_k)

@@ -20,10 +20,15 @@ The DeepSeek-V3 block (arxiv 2412.19437; ``model_type: deepseek_v3``):
   ``experts_held = (first, count)`` is this chip's share under expert
   parallelism: it routes over all and computes its own.
 
-Functional (plain parameter dicts), like ``moe_lm.py``: every leaf has a
-strategy-addressable name, RMSNorm leaves are ``.../scale``.  The training
-path only: the latent is not cached and the decode path that absorbs
-``W_kvb`` into the query is not written (ROADMAP M3).
+Built on ``models/routed_decoder.py``: attention one sequence at a time
+(what it holds while it runs, queries, keys and values 192 and 128 wide in
+float32, is then a sequence's: 4 x 4096 tokens at the benchmark's widths
+ask for 20 GB otherwise), the experts once over ALL the sequences, a
+sequence a slice.  Functional (plain parameter dicts), like ``moe_lm.py``:
+every leaf has a strategy-addressable name, RMSNorm leaves are
+``.../scale``.  The training path only: the latent is not cached and the
+decode path that absorbs ``W_kvb`` into the query is not written (ROADMAP
+M3).
 """
 from __future__ import annotations
 
@@ -31,90 +36,21 @@ from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from autodist_tpu.models.base import ModelSpec, cross_entropy_loss, rms_norm
+from autodist_tpu.models.base import ModelSpec, rms_norm, rotary_halves
+from autodist_tpu.models.routed_decoder import routed_decoder
 from autodist_tpu.ops.flash_attention import RESIDUAL_NAMES
 from autodist_tpu.parallel.moe import (
     ROUTING_RESIDUAL_NAMES,
     init_routed_moe_params,
-    record_row_budgets,
     routed_moe_ffn,
     swiglu,
 )
-from autodist_tpu.telemetry import registry, step_values, timeline
+from autodist_tpu.telemetry import timeline
 
-# What a layer's checkpoint keeps besides its inputs: what a kernel or a
-# sort produced (dear to recompute, cheap to hold) under both policies.
+# What a layer's checkpoints keep besides their inputs: what a kernel or a
+# sort produced (dear to recompute, cheap to hold).
 KEPT_NAMES = RESIDUAL_NAMES + ROUTING_RESIDUAL_NAMES
-_KEEP_NAMED = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
-_REMAT_POLICIES = {
-    "full": _KEEP_NAMED,
-    "dots": jax.checkpoint_policies.save_from_both_policies(
-        jax.checkpoint_policies.checkpoint_dots, _KEEP_NAMED)}
-
-
-def equations(jaxpr):
-    """Every equation of a jaxpr, those of its inner jaxprs included; a
-    kernel's own body is the kernel's and is left out."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        if eqn.primitive.name != "pallas_call":
-            for inner in jax.core.jaxprs_in_params(eqn.params):
-                yield from equations(inner)
-
-
-def named_bytes(fn: Callable, *args) -> dict:
-    """``{name: bytes}`` of the values one differentiated call of ``fn``
-    tags with ``checkpoint_name`` (``args``: arrays or shapes).  Read off
-    the trace of a JVP: a custom VJP tags inside its forward rule, which a
-    plain call never runs."""
-    shapes = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
-    found = {}
-    for eqn in equations(
-            jax.make_jaxpr(lambda *a: jax.jvp(fn, a, a))(*shapes).jaxpr):
-        if eqn.primitive.name == "name":
-            aval = eqn.outvars[0].aval
-            found[eqn.params["name"]] = found.get(
-                eqn.params["name"], 0) + aval.size * aval.dtype.itemsize
-    return found
-
-
-def _rotary(x: jax.Array, theta: float, split: Tuple[int, int]):
-    """``x [B, T, ..., R]``, positions along axis 1: the pair ``(a, b)`` of
-    position ``t`` and frequency ``i`` turns by ``t * theta^(-2i/R)``.
-    ``split`` says where the last axis keeps its pairs: ``(-1, 2)``
-    interleaved, ``(2, -1)`` as two halves."""
-    t, r = x.shape[1], x.shape[-1]
-    inv = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
-    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv        # [T,R/2]
-    angle = angle.reshape((1, t) + (1,) * (x.ndim - 3) + (r // 2,))
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    axis = split.index(2) - 2
-    parts = x.astype(jnp.float32).reshape(x.shape[:-1] + split)
-    a, b = (jax.lax.index_in_dim(parts, i, axis, keepdims=False)
-            for i in (0, 1))
-    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=axis)
-    return out.reshape(x.shape).astype(x.dtype)
-
-
-def rotary(x: jax.Array, theta: float) -> jax.Array:
-    """Rotary positions on INTERLEAVED pairs ``(x[2i], x[2i+1])``: what
-    the weights' columns mean.  The model turns ``rotary_halves`` of the
-    same columns de-interleaved."""
-    return _rotary(x, theta, (-1, 2))
-
-
-def rotary_halves(x: jax.Array, theta: float) -> jax.Array:
-    """``rotary`` on DE-INTERLEAVED columns: ``x[..., :R/2]`` holds the
-    pairs' first members and ``x[..., R/2:]`` their second, and they come
-    back so (the checkpoint's own code does this).  The same products and
-    sums as ``rotary`` on the same numbers.  The halves are taken as a
-    DIMENSION of two, not as two slices: the compiler then folds the turn
-    into the product that feeds it; sliced, each half is a 32-wide array
-    padded to the chip's 128 lanes and crosses memory four times over."""
-    return _rotary(x, theta, (2, -1))
 
 
 def _halves_first(w: jax.Array) -> jax.Array:
@@ -197,13 +133,12 @@ def mla_moe_lm(vocab_size: int = 16032, num_layers: int = 5,
 
     ``experts_held=(first, count)``: the expert leaves lead with ``count``
     experts; None holds all.  ``remat``: per-layer rematerialisation,
-    "none" | "dots" | "full".  "full" here means: the backward recomputes
-    a layer EXCEPT what a kernel or a sort produced, which is kept by name
-    (``KEPT_NAMES``: the flash kernel's ``o`` and ``lse``, a routed
-    layer's picks, sort orders and group sizes), so the forward kernel,
-    ``top_k`` and the sorts run once a layer, not twice; at the
-    benchmark's widths that holds 67.9 MB a sequence and layer.  "dots"
-    keeps the same beside ``checkpoint_dots``.  The gauge
+    "none" | "full".  "full" here means: the backward recomputes a
+    layer's halves EXCEPT what a kernel or a sort produced, which is kept
+    by name (``KEPT_NAMES``: the flash kernel's ``o`` and ``lse``, a
+    routed layer's picks, sort orders and group sizes), so the forward
+    kernel, ``top_k`` and the sorts run once a layer, not twice; at the
+    benchmark's widths that holds 67.9 MB a sequence and layer.  The gauge
     ``autodist_remat_kept_bytes_per_step{name}`` says what a step holds.
     ``xent_chunk``: the head's loss through ``ops/chunked_xent.py``.
     ``train_router=False``: the routers' weights take no gradient
@@ -212,9 +147,8 @@ def mla_moe_lm(vocab_size: int = 16032, num_layers: int = 5,
     [expert layers, count]})`` for ``capture(has_aux=True)``."""
     from autodist_tpu.models.transformer import default_attention
 
-    if remat not in ("none", "full", "dots"):
-        raise ValueError(f"remat={remat!r}: expected 'none', 'full', or "
-                         f"'dots'")
+    if not 0 <= first_dense < num_layers:
+        raise ValueError(f"{first_dense} dense layers of {num_layers}")
     attn_fn = attn_fn or default_attention()
     held = tuple(experts_held) if experts_held else (0, num_experts)
     d_qk = qk_nope + qk_rope
@@ -253,145 +187,46 @@ def mla_moe_lm(vocab_size: int = 16032, num_layers: int = 5,
             params[f"layers_{i}"] = layer
         return params
 
-    def operands(lp):
-        return dict(lp, attn=attention_operands(lp["attn"], qk_nope))
-
     def attention_half(lp, x):
         """One sequence ``[1, T, D]`` plus its attention; ``lp``: the
-        layer's leaves with the attention's as ``attention_operands``."""
+        layer's leaves with the attention's as ``attention_operands``
+        (cut for their products once a layer, outside the map over
+        sequences: inside it the cuts' transposes would pad and add
+        weight-sized buffers every sequence of the backward)."""
         return x + latent_attention(
             lp["attn"], rms_norm(x, lp["ln_attn"]["scale"], rms_eps),
             attn_fn, theta=rope_theta, eps=rms_eps)
 
-    def dense_layer(lp, x):
-        """One sequence through a leading layer: attention and the dense
-        SwiGLU."""
-        x = attention_half(lp, x)
+    def ffn_half(lp, x):
+        """One sequence ``[1, T, D]`` of a leading layer plus its dense
+        SwiGLU (a slice being a sequence here, the skeleton runs it in
+        the attention half's map and under its checkpoint)."""
         with jax.named_scope(timeline.SCOPE_FFN_DENSE):
             return x + swiglu(lp["mlp"], rms_norm(
                 x, lp["ln_mlp"]["scale"], rms_eps))
 
     def expert_half(lp, x):
         """ALL the sequences ``[B, T, D]`` plus their experts' output, and
-        the tokens each held expert was sent."""
+        the tokens each held expert was sent: one sort a layer, each expert
+        weight's gradient one product, and no buffer wider than a
+        sequence's picks (``routed_moe_ffn``'s chunk)."""
         y, counts = routed_moe_ffn(
             lp["moe"], rms_norm(x, lp["ln_mlp"]["scale"], rms_eps),
             top_k=top_k, experts_held=held, routed_scale=routed_scale,
             train_router=train_router)
         return x + y, counts
 
-    def as_run(fn, mapped: bool):
-        """``fn`` under the layers' checkpoint (under ``lax.map`` no CSE
-        barrier is needed)."""
-        return fn if remat == "none" else jax.checkpoint(
-            fn, policy=_REMAT_POLICIES[remat], prevent_cse=not mapped)
-
-    run_attention, run_dense, run_experts = (
-        as_run(attention_half, True), as_run(dense_layer, True),
-        as_run(expert_half, False))
-
-    def kept_bytes(params, x):
-        """What the layers' checkpoints hold by name over a step of ``x
-        [B, T, D]``: the tagged shapes of one sequence's attention (and
-        dense SwiGLU) times the sequences, and of the step's expert half,
-        over the layers (one trace a kind of layer)."""
-        kept, kinds = dict.fromkeys(KEPT_NAMES, 0), {}
-        if remat == "none":
-            return kept
-        for i in range(num_layers):
-            lp = params[f"layers_{i}"]
-            dense = "mlp" in lp
-            if dense not in kinds:
-                cut = jax.eval_shape(operands, lp)
-                kinds[dense] = [(named_bytes(
-                    dense_layer if dense else attention_half, cut, x[:1]),
-                    x.shape[0])]
-                if not dense:
-                    kinds[dense].append((named_bytes(expert_half, lp, x), 1))
-            for name in KEPT_NAMES:
-                kept[name] += sum(tagged.get(name, 0) * times
-                                  for tagged, times in kinds[dense])
-        return kept
-
-    def layer(lp, x):
-        """``x [B, T, D]`` through one layer, attention (and a leading
-        layer's dense SwiGLU) ONE SEQUENCE AT A TIME: what attention holds
-        while it runs (queries, keys and values 192 and 128 wide in
-        float32) is then a sequence's, not the batch's: 4 x 4096 tokens at
-        the benchmark's widths ask for 20 GB otherwise.  The attention's
-        weights are cut for their products here, once, and not under the
-        map: inside it the cuts' transposes would pad and add weight-sized
-        buffers every sequence of the backward.  The experts take ALL the
-        sequences in one call, outside the map and under a checkpoint of
-        their own: one sort a layer, each expert weight's gradient one
-        product, and no buffer wider than a sequence's picks
-        (``routed_moe_ffn``'s chunk).  Returns the layer's
-        ``tokens_per_expert`` ``[count]`` (None from a dense layer) beside
-        ``x``."""
-        cut = operands(lp)
-        if "mlp" in lp:
-            return jax.lax.map(lambda row: run_dense(cut, row[None])[0],
-                               x), None
-        x = jax.lax.map(lambda row: run_attention(cut, row[None])[0], x)
-        return run_experts(lp, x)
-
-    def features(params, tokens):
-        """Final-norm activations ``[B, T, D]`` and the expert layers'
-        ``tokens_per_expert`` ``[expert layers, count]``."""
-        with jax.named_scope(timeline.SCOPE_LM_EMBED):
-            x = jnp.take(params["embed"], tokens, axis=0)
-        for name, held_bytes in kept_bytes(params, x).items():
-            registry.gauge(
-                "autodist_remat_kept_bytes_per_step",
-                "bytes the layers' checkpoints keep from forward to "
-                "backward instead of recomputing, by the value's name",
-                {"name": name}).set(held_bytes)
-        counts = []
-        with jax.named_scope(timeline.SCOPE_LM_LAYERS):
-            for i in range(num_layers):
-                x, c = layer(params[f"layers_{i}"], x)
-                if c is not None:
-                    counts.append(c)
-            if counts:     # here, outside the layers' checkpoints
-                record_row_budgets(jnp.stack(counts), tokens.size * top_k,
-                                   num_experts, tokens.shape[1] * top_k)
-        with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
-            feats = rms_norm(x, params["ln_final"]["scale"], rms_eps)
-        return feats, counts
-
-    def apply_fn(params, tokens):
-        feats = features(params, tokens)[0]
-        with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
-            return jnp.einsum("btd,vd->btv", feats, params["head"])
-
-    def loss_fn(params, batch):
-        tokens = batch["tokens"]
-        feats, counts = features(params, tokens)
-        with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
-            if xent_chunk:
-                from autodist_tpu.ops.chunked_xent import \
-                    chunked_softmax_cross_entropy
-
-                loss = chunked_softmax_cross_entropy(
-                    feats[:, :-1], params["head"], tokens[:, 1:],
-                    chunk=xent_chunk)
-            else:
-                logits = jnp.einsum("btd,vd->btv", feats, params["head"])
-                loss = cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
-        if return_counts:
-            return loss, {"tokens_per_expert": jnp.stack(counts)}
-        return loss
-
-    def make_batch(rng: np.random.RandomState, batch_size: int):
-        return {"tokens": rng.randint(
-            0, vocab_size, (batch_size, seq_len)).astype(np.int32)}
-
-    return ModelSpec(
-        name="mla_moe_lm",
-        init=init, loss_fn=step_values.reporting(loss_fn), apply_fn=apply_fn,
-        make_batch=make_batch,
-        sparse_vars=("embed",),
-        expert_vars=("*/moe/experts/*",),
+    dense, routed = (attention_half, ffn_half), (attention_half, expert_half)
+    return routed_decoder(
+        name="mla_moe_lm", init=init,
+        halves_of=lambda i: dense if i < first_dense else routed,
+        operands_of=lambda lp: dict(lp, attn=attention_operands(
+            lp["attn"], qk_nope)),
+        dense_layers=tuple(range(first_dense)), kept_names=KEPT_NAMES,
+        vocab_size=vocab_size, num_layers=num_layers, seq_len=seq_len,
+        moe_slice=seq_len, top_k=top_k, num_experts=num_experts,
+        rms_eps=rms_eps, xent_chunk=xent_chunk, remat=remat,
+        return_counts=return_counts,
         config=dict(vocab_size=vocab_size, num_layers=num_layers,
                     first_dense=first_dense, d_model=d_model,
                     num_heads=num_heads, qk_nope=qk_nope, qk_rope=qk_rope,

@@ -34,7 +34,7 @@ b`` (``b`` takes no gradient), ``g_e = routed_scale * s_e / (sum_S s +
 1e-6)``; ``experts_held`` is this chip's share.  The head multiplies by the
 embedding table where ``tie_embedding`` (the family's convention).
 
-Built on ``gqa_dsa_moe_lm.routed_decoder`` (the halves under their
+Built on ``routed_decoder.routed_decoder`` (the halves under their
 checkpoints, the slices, the loss, the gauges, the batch): the mixers one
 sequence at a time, the experts once over the step's tokens, the dense FFN
 one slice at a time.  Kept by name over the layers' checkpoints: the flash
@@ -49,13 +49,10 @@ from typing import Callable, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from autodist_tpu.models.base import ModelSpec, rms_norm
+from autodist_tpu.models.base import ModelSpec, rms_norm, rotary_halves
 from autodist_tpu.models.gdn_moe_lm import causal_conv
-from autodist_tpu.models.gqa_dsa_moe_lm import (
-    default_sparse_attention,
-    routed_decoder,
-)
-from autodist_tpu.models.mla_moe_lm import rotary_halves
+from autodist_tpu.models.routed_decoder import routed_decoder
+from autodist_tpu.models.transformer import default_sparse_attention
 from autodist_tpu.ops.flash_attention import (
     _DEFAULT_BLOCK,
     RESIDUAL_NAMES,
@@ -108,8 +105,6 @@ def sconv_moe_lm(vocab_size: int = 16384,
     ``swa_moe_lm`` (1: the model as published).  ``remat``,
     ``experts_held``, ``xent_chunk``, ``train_router``, ``return_counts``,
     ``moe_slice``: as ``gqa_dsa_moe_lm``."""
-    if remat not in ("none", "full"):
-        raise ValueError(f"remat={remat!r}: expected 'none' or 'full'")
     if num_heads % num_kv_heads:
         raise ValueError(f"{num_heads} query heads over {num_kv_heads}")
     layer_types = tuple(layer_types)

@@ -37,7 +37,7 @@ SmallThinker-21BA3B (PowerInfer, ``model_name: smallthinker_21b_instruct``).
   ``router_before_attention=False`` is the usual placement: the router
   reads what its experts read.
 
-Built on ``gqa_dsa_moe_lm.routed_decoder`` (the halves under their
+Built on ``routed_decoder.routed_decoder`` (the halves under their
 checkpoints, the slices, the loss, the gauges, the batch); functional,
 the training path only.
 """
@@ -48,12 +48,9 @@ from typing import Callable, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from autodist_tpu.models.base import ModelSpec, rms_norm
-from autodist_tpu.models.gqa_dsa_moe_lm import (
-    default_sparse_attention,
-    routed_decoder,
-)
-from autodist_tpu.models.mla_moe_lm import rotary_halves
+from autodist_tpu.models.base import ModelSpec, rms_norm, rotary_halves
+from autodist_tpu.models.routed_decoder import routed_decoder
+from autodist_tpu.models.transformer import default_sparse_attention
 from autodist_tpu.ops.flash_attention import (
     _DEFAULT_BLOCK,
     RESIDUAL_NAMES,
@@ -108,8 +105,6 @@ def swa_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
     much of what they read is the token's own row).  ``remat``,
     ``experts_held``, ``xent_chunk``, ``train_router``, ``return_counts``,
     ``moe_slice``: as ``gqa_dsa_moe_lm``."""
-    if remat not in ("none", "full"):
-        raise ValueError(f"remat={remat!r}: expected 'none' or 'full'")
     if num_heads % num_kv_heads:
         raise ValueError(f"{num_heads} query heads over {num_kv_heads}")
     if not len(window_layout) == len(rope_layout) == num_layers:

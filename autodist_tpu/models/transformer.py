@@ -18,6 +18,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from autodist_tpu.ops.flash_attention import (
+    _DEFAULT_BLOCK,
+    flash_attention,
+    unpack_selection,
+)
+from autodist_tpu.ops.pallas_utils import use_interpret
 from autodist_tpu.telemetry import timeline
 
 
@@ -68,6 +74,36 @@ def dense_attention(q, k, v, causal: bool) -> jax.Array:
         logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def dense_selected_attention(q, k, v, causal, *, selection=None,
+                             select_from=None, window=None,
+                             block_k=_DEFAULT_BLOCK):
+    """What the kernel computes, by the plain softmax over all pairs (off
+    the TPU, at a size a test holds): ``selection`` and ``window`` as the
+    kernel takes them."""
+    t, group = q.shape[1], q.shape[2] // k.shape[2]
+    mask = jnp.tril(jnp.ones((t, t), bool))[None] if selection is None \
+        else unpack_selection(selection, block_k=block_k)
+    if window is not None:
+        mask = mask & ~jnp.tril(jnp.ones((t, t), bool), -window)
+    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (q.shape[-1] ** 0.5)
+    logits = jnp.where(mask[:, None], logits.astype(jnp.float32), -1e30)
+    return jnp.einsum("bhqk,bkhd->bqhd",
+                      jax.nn.softmax(logits, axis=-1).astype(q.dtype), v)
+
+
+def default_sparse_attention(block_k: int = _DEFAULT_BLOCK) -> Callable:
+    """The flash kernel on a TPU, the dense softmax elsewhere; resolved at
+    the first call, as ``default_attention`` is."""
+    def attn(q, k, v, causal, **selection):
+        if use_interpret():
+            return dense_selected_attention(q, k, v, causal, block_k=block_k,
+                                            **selection)
+        return flash_attention(q, k, v, causal, block_k=block_k, **selection)
+
+    return attn
 
 
 class MultiHeadAttention(nn.Module):

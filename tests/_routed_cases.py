@@ -1,6 +1,12 @@
-"""A routed expert layer whose load is chosen to the row, for the tests of
-``parallel/moe.py``'s chunks of the sorted order (``test_mla_moe_lm.py``
-for the sigmoid router, ``test_gqa_dsa_moe_lm.py`` for the softmax one).
+"""What the tests of the five routed models share.
+
+First what every model's comparison with its reference is made of: the
+benchmark's seeded weights, a batch of tokens, a tree by its leaves' names
+and the comparison of every leaf's gradient with the reference's, each by
+its own norm.  Then a routed expert layer
+whose load is chosen to the row, for the tests of ``parallel/moe.py``'s
+chunks of the sorted order (``test_mla_moe_lm.py`` for the sigmoid router,
+``test_gqa_dsa_moe_lm.py`` for the softmax one).
 
 64 tokens pick 2 of 16 experts, experts 4 and 5 are held: 128 picks a call,
 an even router sends 16 here, so a chunk is 32 places of the sorted order
@@ -16,9 +22,53 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from autodist_tpu.models.mla_moe_lm import equations
+from autodist_tpu.models.routed_decoder import equations
 from autodist_tpu.ops import grouped_matmul
 from autodist_tpu.parallel import moe
+
+
+def seeded(shapes, seed, gain=1.0):
+    """``benchmark/weights.py``'s rule (normal(0, 0.02), leaves named
+    ``scale`` 1), every matrix times ``gain``."""
+    from benchmark import weights
+
+    made = weights.make_weights(shapes, seed)
+    return made if gain == 1.0 else jax.tree.map(
+        lambda a: a * gain if a.ndim > 1 else a, made)
+
+
+def tokens(seed, rows=2, t=96, vocab=61, generator=False):
+    """A batch ``[rows, t]``; ``generator``: drawn by ``default_rng``
+    (kanana's batches since PR 30), not by ``RandomState``."""
+    if generator:
+        return np.random.default_rng(seed).integers(
+            0, vocab, (rows, t), dtype=np.int32)
+    return np.random.RandomState(seed).randint(0, vocab, (rows, t)).astype(
+        np.int32)
+
+
+def flat(tree):
+    """``{"layers_0/attn/wq": leaf}``."""
+    return {"/".join(str(k.key) for k in path): leaf for path, leaf in
+            jax.tree_util.tree_leaves_with_path(tree)}
+
+
+def assert_every_gradient_matches(grads, want_grads, rtol, takes_none):
+    """The same leaves on both sides, each within ``rtol`` of the
+    reference's OWN norm; the leaves ``takes_none(name)`` names are zero
+    on both sides, every other is not zero in the reference."""
+    got, want_grads = flat(grads), flat(want_grads)
+    assert set(got) == set(want_grads)
+    for name, g in want_grads.items():
+        assert float(jnp.linalg.norm(got[name] - g)) <= rtol * float(
+            jnp.linalg.norm(g)), name
+        if takes_none(name):
+            assert not np.asarray(got[name]).any(), name
+            assert not np.asarray(g).any(), name
+        else:
+            assert np.asarray(g).any(), name
+    return got
+
 
 TOKENS, TOP_K, HELD, TOTAL, D = 64, 2, (4, 2), 16, 32
 CHUNK = 32

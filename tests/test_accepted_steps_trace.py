@@ -28,7 +28,15 @@ anew, ON PURPOSE (the routed layer's grouped products are the kernels of
 ``ops/grouped_matmul.py`` as a TPU traces them, their bodies and the
 integers of their visits in the text, each kind a jitted function that
 is traced once a shape: 24 calls an expert layer's gradient in
-``jax.lax.ragged_dot``'s place), and left gpt2-medium's alone.  A PR that changes one of
+``jax.lax.ragged_dot``'s place), and left gpt2-medium's alone.  PR 46
+(the skeleton in ``models/routed_decoder.py``, ``mla_moe_lm`` its fifth
+caller) recorded kanana's anew, ON PURPOSE, and changed NONE of the five
+others: the text is the parent's but for ONE equation that nothing reads
+(127 characters: a second ``slice`` of the embedded batch, by which the
+skeleton's ``kept_bytes`` asks for the shapes a dense FFN tags; 44
+``pallas_call`` as before).  Kanana's leading dense layer runs both its
+halves under the attention's map and checkpoint, as it did, because there
+a slice is a sequence.  A PR that changes one of
 these models' traces on purpose records the new hash here and says so in
 ``CHANGES.md``.
 """
@@ -54,8 +62,8 @@ TRACES = {
         4, 1024, 869756, 48,
         "33aeee217cc7412cf3b23c92a314f5eaeab4ac537a96f824a79bfda0f11ec301"),
     "kanana-2-30b-a3b.ep8-share": (
-        4, 4096, 1026117, 44,
-        "3fe6eb364a4e3b271463035190f31dccc48856dee57ab8ceeff750dc1f3cbedc"),
+        4, 4096, 1026244, 44,
+        "4e7caa6d48374c7ad3aa8773802aa0c16436577061aabc023e409cd96a524ef1"),
     "keye-vl-2.0-30b-a3b.ep8-share": (
         1, 16384, 1734266, 42,
         "abf903076b0cd68d460681b4e3020c9aaadea97ea6d800026ad06ef708e42c30"),

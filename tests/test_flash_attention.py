@@ -513,7 +513,7 @@ def test_vmem_estimate_counts_both_widths():
 # the forward's output and row statistics carry names (PR 28)
 # ---------------------------------------------------------------------------
 def _names_in(jaxpr):
-    from autodist_tpu.models.mla_moe_lm import equations
+    from autodist_tpu.models.routed_decoder import equations
 
     return [eqn.params["name"] for eqn in equations(jaxpr)
             if eqn.primitive.name == "name"]

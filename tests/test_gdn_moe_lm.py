@@ -46,27 +46,9 @@ def settings(first_held=0, top_k=3, train_router=True, wrong="", rotary=4,
                         wrong=wrong)
 
 
-def seeded(shapes, seed):
-    """``benchmark/weights.py``'s rule, every matrix times ``GAIN``."""
-    from benchmark import weights
-
-    return jax.tree.map(lambda a: a * GAIN if a.ndim > 1 else a,
-                        weights.make_weights(shapes, seed))
-
-
-def tokens(seed, rows=2, t=64):
-    return np.random.RandomState(seed).randint(0, 61, (rows, t)).astype(
-        np.int32)
-
-
-def rel(a, b):
-    return float(jnp.linalg.norm(a - b) / jnp.maximum(
-        jnp.linalg.norm(b), 1e-12))
-
-
-def flat(tree):
-    return {"/".join(str(k.key) for k in path): leaf for path, leaf in
-            jax.tree_util.tree_leaves_with_path(tree)}
+seeded = functools.partial(routed_cases.seeded, gain=GAIN)
+tokens = functools.partial(routed_cases.tokens, t=64)
+rel = routed_cases.rel
 
 
 def kernel_scan(*operands):
@@ -99,17 +81,9 @@ def test_loss_and_every_gradient_match_the_reference(held, remat, chunk,
         want, want_grads = ref.loss_and_grads(params, jnp.asarray(batch),
                                               row_block=2, s=s)
     assert abs(float(loss) - float(want)) < RTOL
-    got, want_grads = flat(grads), flat(want_grads)
-    assert set(got) == set(want_grads)
-    floor = float(np.median([float(jnp.linalg.norm(g))
-                             for g in want_grads.values()]))
-    for name, g in want_grads.items():
-        assert float(jnp.linalg.norm(got[name] - g)) <= RTOL * max(
-            float(jnp.linalg.norm(g)), floor), name
-        if not train_router and name.endswith("router"):
-            assert not np.asarray(got[name]).any(), name
-        else:
-            assert np.asarray(g).any(), name
+    routed_cases.assert_every_gradient_matches(
+        grads, want_grads, RTOL,
+        lambda name: not train_router and name.endswith("router"))
 
 
 #: another model in the stated one's place: the reference's ``wrong``, or

@@ -21,11 +21,11 @@ import pytest
 from autodist_tpu.models.gqa_dsa_moe_lm import (
     KEPT_NAMES,
     SELECTION_NAME,
-    dense_selected_attention,
     gqa_dsa_moe_lm,
     index_scores,
     select_keys,
 )
+from autodist_tpu.models.transformer import dense_selected_attention
 from autodist_tpu.ops import flash_attention
 from autodist_tpu.ops.flash_attention import (
     pack_selection,
@@ -58,27 +58,8 @@ def settings(first_held=0, top_k=3, topk=TOPK, train_router=True):
                         theta=1e7, eps=1e-6, train_router=train_router)
 
 
-def seeded(shapes, seed):
-    """``benchmark/weights.py``'s rule, every matrix times ``GAIN``."""
-    from benchmark import weights
-
-    return jax.tree.map(lambda a: a * GAIN if a.ndim > 1 else a,
-                        weights.make_weights(shapes, seed))
-
-
-def tokens(seed, rows=2, t=96):
-    return np.random.RandomState(seed).randint(0, 61, (rows, t)).astype(
-        np.int32)
-
-
-def rel(a, b):
-    return float(jnp.linalg.norm(a - b) / jnp.maximum(
-        jnp.linalg.norm(b), 1e-12))
-
-
-def flat(tree):
-    return {"/".join(str(k.key) for k in path): leaf for path, leaf in
-            jax.tree_util.tree_leaves_with_path(tree)}
+seeded = functools.partial(routed_cases.seeded, gain=GAIN)
+tokens, flat, rel = routed_cases.tokens, routed_cases.flat, routed_cases.rel
 
 
 # ---------------------------------------------------------------------------
@@ -105,17 +86,9 @@ def test_loss_and_every_gradient_match_the_reference(held, remat, chunk,
         want, want_grads = ref.loss_and_grads(params, jnp.asarray(batch),
                                               row_block=2, s=s)
     assert abs(float(loss) - float(want)) < RTOL
-    got, want_grads = flat(grads), flat(want_grads)
-    assert set(got) == set(want_grads)
-    floor = float(np.median([float(jnp.linalg.norm(g))
-                             for g in want_grads.values()]))
-    for name, g in want_grads.items():
-        assert float(jnp.linalg.norm(got[name] - g)) <= RTOL * max(
-            float(jnp.linalg.norm(g)), floor), name
-        if "/indexer/" in name or (not train_router and "router" in name):
-            assert not np.asarray(got[name]).any(), name
-        else:
-            assert np.asarray(g).any(), name
+    routed_cases.assert_every_gradient_matches(
+        grads, want_grads, RTOL, lambda name: "/indexer/" in name or (
+            not train_router and "router" in name))
 
 
 @pytest.mark.parametrize("topk", [16, 64, 96])

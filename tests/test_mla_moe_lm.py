@@ -13,7 +13,6 @@ scores within 1e-6 of a tie at the sixth place, so the selection itself is
 the same on both sides.
 """
 import functools
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -21,16 +20,15 @@ import numpy as np
 import optax
 import pytest
 
-from autodist_tpu.models.base import rms_norm
+from autodist_tpu.models import routed_decoder
+from autodist_tpu.models.base import _rotary, rms_norm, rotary_halves
 from autodist_tpu.models.mla_moe_lm import (
     KEPT_NAMES,
     attention_operands,
-    equations,
     latent_attention,
     mla_moe_lm,
-    rotary,
-    rotary_halves,
 )
+from autodist_tpu.models.routed_decoder import equations
 from autodist_tpu.models.transformer import dense_attention
 from autodist_tpu.ops import flash_attention
 from autodist_tpu.parallel.moe import (
@@ -58,11 +56,16 @@ def settings(first_held=0, top_k=3, train_router=True):
                         eps=1e-6, train_router=train_router)
 
 
-def seeded(shapes, seed):
-    """``benchmark/weights.py``'s rule: normal(0, 0.02), scales 1."""
-    from benchmark import weights
+seeded = routed_cases.seeded
+tokens = functools.partial(routed_cases.tokens, rows=4, t=32, generator=True)
 
-    return weights.make_weights(shapes, seed)
+
+def rotary(x, theta):
+    """Rotary positions on INTERLEAVED pairs ``(x[2i], x[2i+1])``: what
+    kanana's weights' columns mean, and what the program computed up to PR
+    29.  The model turns ``rotary_halves`` of the same columns
+    de-interleaved; no caller in the program."""
+    return _rotary(x, theta, (-1, 2))
 
 
 def rel(got, want):
@@ -71,17 +74,12 @@ def rel(got, want):
                  / max(np.linalg.norm(want), 1e-30))
 
 
-def tokens(seed, rows=4, t=32, vocab=61):
-    return np.random.default_rng(seed).integers(
-        0, vocab, (rows, t), dtype=np.int32)
-
-
 @pytest.mark.parametrize("held,remat,chunk,train_router", [
     # a share, rematerialised, chunked head, routers not trained: the
     # benchmark's configuration at test size
     ((4, 4), "full", 32, False),
     (None, "none", None, True),      # all 16 experts held, plain head
-    ((0, 16), "dots", None, True),
+    ((0, 16), "full", None, True),   # every expert held from the first
 ])
 def test_loss_and_every_gradient_match_the_reference(held, remat, chunk,
                                                      train_router):
@@ -96,16 +94,10 @@ def test_loss_and_every_gradient_match_the_reference(held, remat, chunk,
             params, jnp.asarray(batch), row_block=2,
             s=settings(held[0] if held else 0, train_router=train_router))
     assert abs(float(loss) - float(want_loss)) < RTOL
-    got, want = ref._flat(grads, np.asarray), ref._flat(want, np.asarray)
-    assert set(got) == set(want) and len(got) == 43
-    for name in want:
-        if name.endswith("router_bias") or (   # selects, takes no gradient
-                name.endswith("/router") and not train_router):
-            assert not np.any(got[name]) and not np.any(want[name])
-        elif name.endswith("/router"):
-            assert np.any(got[name]) and rel(got[name], want[name]) < RTOL
-        else:
-            assert rel(got[name], want[name]) < RTOL, name
+    got = routed_cases.assert_every_gradient_matches(
+        grads, want, RTOL, lambda name: name.endswith("router_bias") or (
+            name.endswith("/router") and not train_router))   # selects
+    assert len(got) == 43
 
 
 def test_rotary_turns_interleaved_pairs_and_keeps_products_relative():
@@ -251,7 +243,7 @@ def inside_and_outside_the_map(jaxpr, inside=False):
                 inner, inside or eqn.primitive.name == "scan")
 
 
-@pytest.mark.parametrize("remat", ["none", "full", "dots"])
+@pytest.mark.parametrize("remat", ["none", "full"])
 def test_weights_are_cut_once_a_layer_outside_the_mapped_body(remat):
     """Outside the body mapped over sequences the forward cuts each
     attention weight twice a layer (``wq``: nope, rope; ``wkv_a``: latent,
@@ -349,6 +341,11 @@ def test_no_token_is_dropped_whatever_the_routing(where):
 
 def test_routed_layer_refuses_a_share_its_leaves_do_not_hold():
     params = moe_layer(1, held_count=4)
+    # and the model a table of policies it no longer has, or no expert layer
+    with pytest.raises(ValueError, match="remat='dots'"):
+        mla_moe_lm(**TINY, remat="dots")
+    with pytest.raises(ValueError, match="3 dense layers of 3"):
+        mla_moe_lm(**dict(TINY, first_dense=3))
     x = jnp.zeros((8, 32))
     with pytest.raises(ValueError, match="experts_held"):
         routed_moe_ffn(params, x, top_k=3, experts_held=(0, 8))
@@ -553,12 +550,12 @@ ROUTED = TINY["num_layers"] - TINY["first_dense"]
 
 
 def remat_model(remat, monkeypatch, **kw):
-    """``remat`` "bare" is what "full" was before the names: the whole
-    layer recomputed (``policy=None``), by the module's own table."""
+    """``remat`` "bare" is what "full" was before the names: each half
+    recomputed whole (a policy that keeps nothing by name)."""
     if remat == "bare":
-        monkeypatch.setitem(
-            sys.modules["autodist_tpu.models.mla_moe_lm"]._REMAT_POLICIES,
-            "full", None)
+        monkeypatch.setattr(
+            routed_decoder, "save_only_these_names",
+            lambda *names: jax.checkpoint_policies.nothing_saveable)
         remat = "full"
     return mla_moe_lm(**dict(TINY_FLASH, **kw), experts_held=(4, 4),
                       remat=remat)
@@ -570,7 +567,7 @@ def count_primitives(jaxpr, names):
 
 
 @pytest.mark.parametrize("remat,kernels,selects", [
-    ("full", 2, 1), ("dots", 2, 1), ("none", 2, 1), ("bare", 3, 2)])
+    ("full", 2, 1), ("none", 2, 1), ("bare", 3, 2)])
 def test_backward_runs_kernel_selection_and_sorts_once_a_layer(
         remat, kernels, selects, monkeypatch):
     """The gradient's jaxpr: a forward and a backward attention kernel a
@@ -590,7 +587,7 @@ def test_backward_runs_kernel_selection_and_sorts_once_a_layer(
             "top_k": selects * ROUTED, "sort": (2 * selects + 3) * ROUTED}
 
 
-@pytest.mark.parametrize("remat", ["full", "dots"])
+@pytest.mark.parametrize("remat", ["full"])
 def test_keeping_by_name_changes_no_number(remat, monkeypatch):
     """Loss and every gradient equal the whole-layer recomputation's to
     the bit: the backward is handed the ``o``, ``lse`` and picks it would
@@ -617,8 +614,7 @@ def test_keeping_by_name_changes_no_number(remat, monkeypatch):
 
 
 @pytest.mark.parametrize("remat,attn", [
-    ("full", FLASH), ("dots", FLASH), ("full", dense_attention),
-    ("none", FLASH)])
+    ("full", FLASH), ("full", dense_attention), ("none", FLASH)])
 def test_kept_bytes_gauge_reads_what_the_tagged_shapes_give(remat, attn,
                                                             monkeypatch):
     """``autodist_remat_kept_bytes_per_step{name}``, set when the model is

@@ -46,27 +46,9 @@ def settings(first_held=0, top_k=3, train_router=True, wrong="",
                         embed_scale=embed_scale, wrong=wrong)
 
 
-def seeded(shapes, seed):
-    """``benchmark/weights.py``'s rule, every matrix times ``GAIN``."""
-    from benchmark import weights
-
-    return jax.tree.map(lambda a: a * GAIN if a.ndim > 1 else a,
-                        weights.make_weights(shapes, seed))
-
-
-def tokens(seed, rows=2, t=64, vocab=61):
-    return np.random.RandomState(seed).randint(0, vocab, (rows, t)).astype(
-        np.int32)
-
-
-def rel(a, b):
-    return float(jnp.linalg.norm(a - b) / jnp.maximum(
-        jnp.linalg.norm(b), 1e-12))
-
-
-def flat(tree):
-    return {"/".join(str(k.key) for k in path): leaf for path, leaf in
-            jax.tree_util.tree_leaves_with_path(tree)}
+seeded = functools.partial(routed_cases.seeded, gain=GAIN)
+tokens = functools.partial(routed_cases.tokens, t=64)
+rel = routed_cases.rel
 
 
 # ---------------------------------------------------------------------------
@@ -97,20 +79,11 @@ def test_loss_and_every_gradient_match_the_reference(held, remat, chunk,
         want, want_grads = ref.loss_and_grads(params, jnp.asarray(batch),
                                               row_block=2, s=s)
     assert abs(float(loss) - float(want)) < RTOL
-    got, want_grads = flat(grads), flat(want_grads)
-    assert set(got) == set(want_grads)
-    floor = float(np.median([float(jnp.linalg.norm(g))
-                             for g in want_grads.values()]))
-    for name, g in want_grads.items():
-        assert float(jnp.linalg.norm(got[name] - g)) <= RTOL * max(
-            float(jnp.linalg.norm(g)), floor), name
-        # the selection bias never takes a gradient, the router where the
-        # configuration does not train it
-        if name.endswith("router_bias") or (
-                not train_router and name.endswith("router")):
-            assert not np.asarray(got[name]).any(), name
-        else:
-            assert np.asarray(g).any(), name
+    # the selection bias never takes a gradient, the router where the
+    # configuration does not train it
+    routed_cases.assert_every_gradient_matches(
+        grads, want_grads, RTOL, lambda name: name.endswith("router_bias")
+        or (not train_router and name.endswith("router")))
 
 
 @pytest.mark.parametrize("remat", ["none", "full"])

@@ -20,13 +20,13 @@ import numpy as np
 import optax
 import pytest
 
-from autodist_tpu.models.gqa_dsa_moe_lm import dense_selected_attention
-from autodist_tpu.models.mla_moe_lm import rotary_halves
+from autodist_tpu.models.base import rotary_halves
 from autodist_tpu.models.swa_moe_lm import (
     KEPT_NAMES,
     attended_pairs,
     swa_moe_lm,
 )
+from autodist_tpu.models.transformer import dense_selected_attention
 from autodist_tpu.ops import flash_attention
 from autodist_tpu.ops.flash_attention import pairs_computed
 from autodist_tpu.parallel.moe import (
@@ -60,27 +60,8 @@ def settings(first_held=0, top_k=3, window=WINDOW, window_layout=LAYOUT,
                         embed_scale=embed_scale)
 
 
-def seeded(shapes, seed):
-    """``benchmark/weights.py``'s rule, every matrix times ``GAIN``."""
-    from benchmark import weights
-
-    return jax.tree.map(lambda a: a * GAIN if a.ndim > 1 else a,
-                        weights.make_weights(shapes, seed))
-
-
-def tokens(seed, rows=2, t=96):
-    return np.random.RandomState(seed).randint(0, 61, (rows, t)).astype(
-        np.int32)
-
-
-def rel(a, b):
-    return float(jnp.linalg.norm(a - b) / jnp.maximum(
-        jnp.linalg.norm(b), 1e-12))
-
-
-def flat(tree):
-    return {"/".join(str(k.key) for k in path): leaf for path, leaf in
-            jax.tree_util.tree_leaves_with_path(tree)}
+seeded = functools.partial(routed_cases.seeded, gain=GAIN)
+tokens, flat, rel = routed_cases.tokens, routed_cases.flat, routed_cases.rel
 
 
 # ---------------------------------------------------------------------------
@@ -111,17 +92,9 @@ def test_loss_and_every_gradient_match_the_reference(held, remat, chunk,
         want, want_grads = ref.loss_and_grads(params, jnp.asarray(batch),
                                               row_block=2, s=s)
     assert abs(float(loss) - float(want)) < RTOL
-    got, want_grads = flat(grads), flat(want_grads)
-    assert set(got) == set(want_grads)
-    floor = float(np.median([float(jnp.linalg.norm(g))
-                             for g in want_grads.values()]))
-    for name, g in want_grads.items():
-        assert float(jnp.linalg.norm(got[name] - g)) <= RTOL * max(
-            float(jnp.linalg.norm(g)), floor), name
-        if not train_router and "router" in name:
-            assert not np.asarray(got[name]).any(), name
-        else:
-            assert np.asarray(g).any(), name
+    routed_cases.assert_every_gradient_matches(
+        grads, want_grads, RTOL,
+        lambda name: not train_router and "router" in name)
 
 
 #: what the program computes in the stated model's place -> its kwargs
@@ -137,6 +110,20 @@ WRONG = {
 }
 
 
+def stated_weights():
+    return seeded(jax.eval_shape(swa_moe_lm(**TINY).init,
+                                 jax.random.key(0)), 3)
+
+
+@functools.cache
+def stated_loss():
+    """The stated model's loss on the weights and the batch the other
+    models are run on."""
+    with jax.default_matmul_precision("highest"):
+        return float(jax.jit(swa_moe_lm(**TINY).loss_fn)(
+            stated_weights(), {"tokens": tokens(5)}))
+
+
 @pytest.mark.parametrize("wrong", sorted(WRONG))
 def test_another_model_is_another_loss(wrong):
     """The comparison sees each mechanism: the same weights under another
@@ -144,12 +131,10 @@ def test_another_model_is_another_loss(wrong):
     stream after attention give a loss 1e-3 or more from the stated
     model's; where the reference can state the other model (all but the
     router's placement) it agrees with that program."""
-    batch = {"tokens": tokens(5)}
-    spec = swa_moe_lm(**TINY)
-    params = seeded(jax.eval_shape(spec.init, jax.random.key(0)), 3)
+    batch, params, stated = {"tokens": tokens(5)}, stated_weights(), \
+        stated_loss()
     other = swa_moe_lm(**dict(TINY, **WRONG[wrong]))
     with jax.default_matmul_precision("highest"):
-        stated = float(jax.jit(spec.loss_fn)(params, batch))
         got = float(jax.jit(other.loss_fn)(params, batch))
         if "router_before_attention" not in WRONG[wrong]:
             want = float(ref.loss_and_grads(
