@@ -62,16 +62,20 @@ def rms_norm(x, scale, eps=1e-6) -> jax.Array:
     return (x32 * inv * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def _rotary(x: jax.Array, theta: float, split: Tuple[int, int]):
+def _rotary(x: jax.Array, theta: float, split: Tuple[int, int],
+            positions=None):
     """``x [B, T, ..., R]``, positions along axis 1: the pair ``(a, b)`` of
     position ``t`` and frequency ``i`` turns by ``t * theta^(-2i/R)``.
     ``split`` says where the last axis keeps its pairs: ``(-1, 2)``
-    interleaved, ``(2, -1)`` as two halves."""
+    interleaved, ``(2, -1)`` as two halves.  ``positions [T]``: row ``j``
+    of axis 1 stands at ``positions[j]`` and not at ``j``."""
     import jax.numpy as jnp
 
     t, r = x.shape[1], x.shape[-1]
     inv = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
-    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv        # [T,R/2]
+    at = jnp.arange(t, dtype=jnp.float32) if positions is None \
+        else positions.astype(jnp.float32)
+    angle = at[:, None] * inv                                      # [T,R/2]
     angle = angle.reshape((1, t) + (1,) * (x.ndim - 3) + (r // 2,))
     cos, sin = jnp.cos(angle), jnp.sin(angle)
     axis = split.index(2) - 2
@@ -82,7 +86,7 @@ def _rotary(x: jax.Array, theta: float, split: Tuple[int, int]):
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def rotary_halves(x: jax.Array, theta: float) -> jax.Array:
+def rotary_halves(x: jax.Array, theta: float, positions=None) -> jax.Array:
     """Rotary positions on DE-INTERLEAVED columns: ``x[..., :R/2]`` holds
     the pairs' first members and ``x[..., R/2:]`` their second, and they
     come back so (the checkpoints' own code does this; where a weight's
@@ -90,14 +94,21 @@ def rotary_halves(x: jax.Array, theta: float) -> jax.Array:
     the model gathers them to this order first).  The halves are taken as
     a DIMENSION of two, not as two slices: the compiler then folds the turn
     into the product that feeds it; sliced, each half is a 32-wide array
-    padded to the chip's 128 lanes and crosses memory four times over."""
-    return _rotary(x, theta, (2, -1))
+    padded to the chip's 128 lanes and crosses memory four times over.
+    ``positions [T]``: where the rows of axis 1 stand, ``0 .. T - 1`` by
+    default."""
+    return _rotary(x, theta, (2, -1), positions)
 
 
-def cross_entropy_loss(logits, labels) -> jax.Array:
-    """Mean softmax cross entropy with integer labels."""
+def cross_entropy_loss(logits, labels, weights=None) -> jax.Array:
+    """Mean softmax cross entropy with integer labels; with ``weights``
+    (shaped as ``labels``, no gradient) each row's loss times its weight,
+    over ALL rows."""
     import jax.numpy as jnp
 
     logz = jax.nn.log_softmax(logits, axis=-1)
     onehot = jax.nn.one_hot(labels, logits.shape[-1], dtype=logz.dtype)
-    return -jnp.mean(jnp.sum(onehot * logz, axis=-1))
+    rows = jnp.sum(onehot * logz, axis=-1)
+    if weights is not None:
+        rows = rows * jax.lax.stop_gradient(weights.astype(rows.dtype))
+    return -jnp.mean(rows)

@@ -11,7 +11,7 @@ by name.  Nothing here imports a model.
 from __future__ import annotations
 
 import functools
-from typing import Callable, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +53,39 @@ def named_bytes(fn: Callable, *args) -> dict:
     return found
 
 
+class Objective(NamedTuple):
+    """What a batch asks of the layers, and what the loss is: the ONE seam
+    between the skeleton and a training objective.
+
+    ``rows(batch) -> (ids [B, R], carried)``: the table's rows that go
+    through the layers for a batch, ``R`` a sequence (at which positions is
+    the mixers' to know: a model builds its halves and its objective
+    together), and whatever ``loss`` needs besides the features.
+    ``loss(feats [B, R, D], head [V, D], carried) -> scalar``, traced
+    under ``lm/head_loss``: the final norm's rows of ALL of ``ids`` (the
+    norm is a row's own, so an objective that reads some of them cuts
+    them out itself)."""
+    rows: Callable[[dict], Tuple[jax.Array, Any]]
+    loss: Callable[[jax.Array, jax.Array, Any], jax.Array]
+
+
+def next_token(xent_chunk: Optional[int]) -> Objective:
+    """Causal next-token prediction: the batch's tokens go through the
+    layers as they are, and row ``t`` is asked for token ``t + 1``."""
+    def loss(feats, head, tokens):
+        if xent_chunk:
+            from autodist_tpu.ops.chunked_xent import \
+                chunked_softmax_cross_entropy
+
+            return chunked_softmax_cross_entropy(
+                feats[:, :-1], head, tokens[:, 1:], chunk=xent_chunk)
+        logits = jnp.einsum("btd,vd->btv", feats, head)
+        return cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
+
+    return Objective(rows=lambda batch: (batch["tokens"], batch["tokens"]),
+                     loss=loss)
+
+
 def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
                    kept_names: Tuple[str, ...], vocab_size: int,
                    num_layers: int, seq_len: int, moe_slice: int,
@@ -66,8 +99,8 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
                    tie_head: bool = False,
                    record_attention: Optional[Callable] = None,
                    operands_of: Callable = lambda lp: lp,
-                   set_pairs_gauges: Callable = lambda tokens: None
-                   ) -> ModelSpec:
+                   set_pairs_gauges: Callable = lambda tokens: None,
+                   objective: Optional[Objective] = None) -> ModelSpec:
     """What the five routed decoders share: the embedding, ``num_layers``
     layers of an attention half (one sequence at a time) and an expert
     half (all of the step's tokens at once, as slices of ``moe_slice``),
@@ -98,10 +131,15 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
     ``operands_of(lp)``: a layer's leaves as its halves are handed them,
     formed ONCE a layer outside the maps and the checkpoints (weight-sized
     work, such as cutting a weight for its products: under the map its
-    transpose would run once a sequence of the backward)."""
+    transpose would run once a sequence of the backward).
+    ``objective``: the training objective (:class:`Objective`); None is
+    :func:`next_token` over ``xent_chunk``.  ``set_pairs_gauges``, the row
+    budgets and ``apply_fn`` are handed the ids that go through the
+    layers."""
     if remat not in ("none", "full"):
         raise ValueError(f"remat={remat!r}: expected 'none' or 'full'")
     keep = save_only_these_names(*kept_names)
+    objective = objective or next_token(xent_chunk)
 
     @functools.cache
     def as_run(fn, mapped):
@@ -190,8 +228,8 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
                 {"name": kept}).set(held_bytes)
 
     def features(params, tokens):
-        """Final-norm activations ``[B, T, D]`` and the layers'
-        ``tokens_per_expert`` ``[layers, count]``."""
+        """Final-norm activations ``[B, T, D]`` of the rows ``tokens`` and
+        the layers' ``tokens_per_expert`` ``[layers, count]``."""
         with jax.named_scope(timeline.SCOPE_LM_EMBED):
             x = jnp.take(params["embed"], tokens, axis=0)
             if embed_scale != 1.0:
@@ -223,19 +261,10 @@ def routed_decoder(*, name: str, init: Callable, halves_of: Callable,
             return jnp.einsum("btd,vd->btv", feats, params[head_name])
 
     def loss_fn(params, batch):
-        tokens = batch["tokens"]
-        feats, counts = features(params, tokens)
+        ids, carried = objective.rows(batch)
+        feats, counts = features(params, ids)
         with jax.named_scope(timeline.SCOPE_LM_HEAD_LOSS):
-            if xent_chunk:
-                from autodist_tpu.ops.chunked_xent import \
-                    chunked_softmax_cross_entropy
-
-                loss = chunked_softmax_cross_entropy(
-                    feats[:, :-1], params[head_name], tokens[:, 1:],
-                    chunk=xent_chunk)
-            else:
-                logits = jnp.einsum("btd,vd->btv", feats, params[head_name])
-                loss = cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
+            loss = objective.loss(feats, params[head_name], carried)
         if return_counts:
             return loss, {"tokens_per_expert": jnp.stack(counts)}
         return loss

@@ -20,6 +20,7 @@ import numpy as np
 
 from autodist_tpu.ops.flash_attention import (
     _DEFAULT_BLOCK,
+    block_diffusion_mask,
     flash_attention,
     unpack_selection,
 )
@@ -78,13 +79,17 @@ def dense_attention(q, k, v, causal: bool) -> jax.Array:
 
 def dense_selected_attention(q, k, v, causal, *, selection=None,
                              select_from=None, window=None,
-                             block_k=_DEFAULT_BLOCK):
+                             block_diffusion=None, block_k=_DEFAULT_BLOCK):
     """What the kernel computes, by the plain softmax over all pairs (off
-    the TPU, at a size a test holds): ``selection`` and ``window`` as the
-    kernel takes them."""
+    the TPU, at a size a test holds): ``selection``, ``window`` and
+    ``block_diffusion`` as the kernel takes them."""
     t, group = q.shape[1], q.shape[2] // k.shape[2]
-    mask = jnp.tril(jnp.ones((t, t), bool))[None] if selection is None \
-        else unpack_selection(selection, block_k=block_k)
+    if block_diffusion is not None:
+        mask = block_diffusion_mask(*block_diffusion, t)[None]
+    elif selection is None:
+        mask = jnp.tril(jnp.ones((t, t), bool))[None]
+    else:
+        mask = unpack_selection(selection, block_k=block_k)
     if window is not None:
         mask = mask & ~jnp.tril(jnp.ones((t, t), bool), -window)
     k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))

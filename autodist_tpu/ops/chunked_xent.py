@@ -21,6 +21,7 @@ Peak extra memory: ``N·chunk`` fp32 instead of ``N·V`` logits.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -111,7 +112,9 @@ _xent_rows.defvjp(_xent_rows_fwd, _xent_rows_bwd)
 
 def chunked_softmax_cross_entropy(features: jax.Array, softmax_w: jax.Array,
                                   labels: jax.Array, *,
-                                  chunk: int = 8192) -> jax.Array:
+                                  chunk: int = 8192,
+                                  weights: Optional[jax.Array] = None
+                                  ) -> jax.Array:
     """Mean softmax cross entropy of ``features @ softmax_w.T`` against
     integer ``labels`` without materializing the logits.
 
@@ -124,6 +127,10 @@ def chunked_softmax_cross_entropy(features: jax.Array, softmax_w: jax.Array,
       labels: integer array matching ``features``'s leading shape.
       chunk: vocab rows per streamed block (``[N, chunk]`` fp32 is the
         peak logits footprint; keep it MXU-friendly — a multiple of 128).
+      weights: a weight a row, shaped as ``labels`` (float32, no
+        gradient): the result is ``sum_i w_i loss_i / N`` over ALL ``N``
+        rows, and a row of weight zero adds nothing to ``dW`` or ``dh``
+        (its cotangent is zero).  None: every row counts once.
 
     Exact (fp32 logit accumulation), unlike the reference's sampled
     softmax.  Matches ``cross_entropy_loss`` to fp32 tolerance.
@@ -136,4 +143,8 @@ def chunked_softmax_cross_entropy(features: jax.Array, softmax_w: jax.Array,
     vp = -(-v // chunk) * chunk
     w = softmax_w if vp == v else jnp.pad(softmax_w,
                                           ((0, vp - v), (0, 0)))
-    return jnp.mean(_xent_rows(h, w, y, chunk, v))
+    losses = _xent_rows(h, w, y, chunk, v)
+    if weights is not None:
+        losses = losses * lax.stop_gradient(
+            weights.reshape(-1).astype(jnp.float32))
+    return jnp.mean(losses)

@@ -65,13 +65,28 @@ is ``PERF.md`` section 5):
   crosses and the diagonal tile pay for a mask.  At 16,384 tokens, tiles
   of 512 and a window of 4,096 that is 252 tiles a head of the causal
   triangle's 528.  ``window=None`` traces the kernels it always did.
+* **Block diffusion** (PR 47).  With a static ``block_diffusion=(B, L)``
+  the rows are a sequence of ``L`` tokens and, where there are ``2 L`` of
+  them, its noised copy behind it, and the mask is NOT causal: a clean
+  row attends to the clean rows of its own block of ``B`` and of every
+  block before it, a noised row to the clean rows of the blocks before
+  its twin's and to the noised rows of its own block, both ways
+  (:func:`block_diffusion_mask`).  The tiles divide ``L``, so each lies
+  in one quarter of the square; forward and fused backward visit, for a
+  clean q block, the clean key blocks up to its own, and for a noised
+  one, the clean key blocks up to its twin's and the noised blocks on its
+  diagonal.  Only the tiles the rule's edge crosses pay for a mask, a
+  compare of ``index // B`` a row and a column and no longer ``key <=
+  query``: at ``L`` = 8,192, ``B`` = 4 and tiles of 512 that is 288 tiles
+  a head (32 of them masked) of the 528 a causal pass over the 16,384
+  rows visits.  ``block_diffusion=None`` traces the kernels it always did.
 
 Interpret mode (CPU tests) is selected automatically off-TPU.
 """
 from __future__ import annotations
 
 import functools
-from typing import Callable, Optional
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -214,17 +229,116 @@ def unpack_selection(words: jax.Array, *,
     return bits.reshape(words.shape[:-2] + (tq, tk)) != 0
 
 
+def _bd_blocks(t: int, block_q: int, block_k: int, bd) -> tuple:
+    """The (q, k) block edges of ``t`` rows: divisors of ``t``, and under
+    ``bd = (B, L)`` of ``L``, so that a tile lies in one quarter of the
+    square of clean and noised rows."""
+    whole = t if bd is None else bd[1]
+    return _pick_block(whole, block_q), _pick_block(whole, block_k)
+
+
+def _bd_key_blocks(q0, bq: int, bk: int, bd, *, div=lax.div, where=jnp.where):
+    """The key blocks a q block that starts at row ``q0`` visits under
+    ``bd = (B, L)``: ``(clear, upper, first, last)``: clean key blocks
+    ``[0, clear)`` hold only keys every row of the block attends to,
+    ``[clear, upper)`` are crossed by the rule's edge, and the noised key
+    blocks ``[first, last)`` hold the block's own diagonal (none for a
+    clean q block).  Scalars, traced in the kernel and plain integers in
+    :func:`pairs_computed` (``div``, ``where``)."""
+    b, l = bd
+    noised = q0 >= l
+    i0 = q0 - where(noised, l, 0)
+    # the starts of the blocks of B that hold its first and its last row
+    lo, hi = div(i0, b) * b, div(i0 + bq - 1, b) * b
+    clear = div(where(noised, lo, lo + b), bk)
+    upper = div(where(noised, hi, hi + b) + bk - 1, bk)
+    first = where(noised, div(l + lo, bk), 0)
+    last = where(noised, div(l + hi + b + bk - 1, bk), 0)
+    return clear, upper, first, last
+
+
+def _bd_query_blocks(k0, bk: int, bq: int, bd, num_qb: int) -> tuple:
+    """:func:`_bd_key_blocks` read the other way, for the fused backward:
+    the q blocks of the key block that starts at row ``k0``, as ``(edge,
+    clear, last)`` twice, for the clean q blocks and for the noised ones:
+    blocks ``[edge, clear)`` are crossed by the rule's edge and ``[clear,
+    last)`` attend to every key of the block.  A noised key block has
+    only the noised q blocks on its diagonal.  Traced scalars."""
+    b, l = bd
+    half = l // bq                      # q blocks of the clean rows
+    noised = k0 >= l
+    j0 = k0 - jnp.where(noised, l, 0)
+    lo = lax.div(j0, b) * b                         # its first key's block
+    hi = lax.div(j0 + bk + b - 1, b) * b            # the end of its last's
+    clean = (jnp.where(noised, half, lax.div(lo, bq)),
+             jnp.where(noised, half, jnp.minimum(
+                 lax.div(hi - b + bq - 1, bq), half)), half)
+    past = jnp.minimum(lax.div(l + hi + bq - 1, bq), num_qb)
+    return clean, (lax.div(l + jnp.where(noised, lo, lo + b), bq), past,
+                   jnp.where(noised, past, num_qb))
+
+
 def pairs_computed(t: int, *, block_q: int = _DEFAULT_BLOCK,
                    block_k: int = _DEFAULT_BLOCK,
-                   window: Optional[int] = None) -> int:
+                   window: Optional[int] = None,
+                   block_diffusion: Optional[Tuple[int, int]] = None) -> int:
     """(query, key) pairs whose score ONE causal forward call forms for
     one head: every tile at or below the diagonal, whole; under a
     ``window``, from the tile its trailing edge crosses on (the kernel's
-    own first key block)."""
+    own first key block).  Under ``block_diffusion=(B, L)`` (not causal;
+    ``t`` is ``L`` or ``2 L``) the tiles :func:`_bd_key_blocks` names."""
+    if block_diffusion is not None:
+        bq, bk = _bd_blocks(t, block_q, block_k, block_diffusion)
+        tiles = 0
+        for q0 in range(0, t, bq):
+            clear, upper, first, last = _bd_key_blocks(
+                q0, bq, bk, block_diffusion, div=lambda a, b: a // b,
+                where=lambda c, a, b: a if c else b)
+            tiles += upper + last - first
+        return tiles * bq * bk
     bq, bk = _pick_block(t, block_q), _pick_block(t, block_k)
     return sum((min(-(-(q0 + bq) // bk), t // bk)
                 - (0 if window is None else max(q0 - window + 1, 0) // bk))
                * bk * bq for q0 in range(0, t, bq))
+
+
+def block_diffusion_mask(block: int, length: int, rows: int) -> jax.Array:
+    """``[rows, rows]`` bool, query down and key along: what
+    ``block_diffusion=(block, length)`` lets a row attend to.  Rows ``0 ..
+    L - 1`` are a sequence (clean), rows ``L .. 2 L - 1`` its noised copy
+    (``rows`` is ``L`` or ``2 L``).  Clean to clean: the key's block is at
+    or before the query's; noised to clean: before its twin's; noised to
+    noised: its own block, both ways; clean to noised: never.  For an
+    attention that is not the kernel."""
+    r = jnp.arange(rows)
+    noised, blk = r >= length, (r % length) // block
+    q_noised, k_noised = noised[:, None], noised[None, :]
+    q_blk, k_blk = blk[:, None], blk[None, :]
+    return jnp.where(k_noised, q_noised & (k_blk == q_blk),
+                     jnp.where(q_noised, k_blk < q_blk, k_blk <= q_blk))
+
+
+def _div(x, b: int):
+    """``x // b`` of a non-negative int32 vector inside a kernel: a shift
+    where ``b`` is a power of two."""
+    if b & (b - 1) == 0:
+        return x >> (b.bit_length() - 1)
+    return lax.div(x, jnp.int32(b))
+
+
+def _bd_codes(index, bd):
+    """A row index (query or key) as the block-diffusion rule reads it:
+    ``(code, upto, twin)``.  A KEY is its ``code``: its block's number if
+    clean, that plus ``L`` (past every clean code) if noised.  A QUERY
+    attends to the keys whose code is at most ``upto`` (its own block if
+    clean, the one before its twin's if noised) or equal to ``twin`` (a
+    noised query's own block among the noised keys; no key's for a clean
+    one)."""
+    b, l = bd
+    noised = index >= l
+    blk = _div(jnp.where(noised, index - l, index), b)
+    return (jnp.where(noised, blk + l, blk), jnp.where(noised, blk - 1, blk),
+            jnp.where(noised, blk + l, -1))
 
 
 def _unpack(words):
@@ -236,7 +350,8 @@ def _unpack(words):
 
 def _fwd_kernel(*refs, causal: bool, block_k: int, scale: float,
                 kv_len: int, operand, select_from: Optional[int] = None,
-                window: Optional[int] = None):
+                window: Optional[int] = None,
+                bd: Optional[Tuple[int, int]] = None):
     """One (batch, head, q-block) program: stream K/V blocks, online softmax.
 
     Tiles are TRANSPOSED, ``[bk, bq]`` (keys down the sublanes, queries
@@ -262,6 +377,10 @@ def _fwd_kernel(*refs, causal: bool, block_k: int, scale: float,
     s <= t``: K blocks wholly behind the window of the block's first
     query are never visited, and those its trailing edge crosses are
     masked by it.
+
+    With ``bd = (B, L)`` (not causal, no padding) the K blocks are those
+    :func:`_bd_key_blocks` names, and the ones the rule's edge crosses are
+    masked by :func:`_bd_codes`.
     """
     if select_from is None:
         q_ref, k_ref, v_ref, o_ref, lse_ref, ks_ref, vt_ref = refs
@@ -290,12 +409,19 @@ def _fwd_kernel(*refs, causal: bool, block_k: int, scale: float,
     row_minus_col = row - lax.broadcasted_iota(jnp.int32, (block_k, bq), 1)
     # a window shorter than two tiles can cross a diagonal tile as well
     near = window is not None and window < bq + block_k
+    if bd is not None:
+        _, upto, twin = _bd_codes(
+            q0 + lax.broadcasted_iota(jnp.int32, (1, bq), 1), bd)   # [1,bq]
+        key = lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
 
     def tile(masked, kb, carry):
         o_t, l, m = carry
         k0, rows = kb * block_k, _block(kb, block_k, num_kb)
         s_t = _dot(ks_ref[rows, :], q, _NT, operand)            # [bk,bq]
-        if masked == "selected":
+        if masked == "rule":
+            code = _bd_codes(k0 + key, bd)[0]                   # [bk,1]
+            s_t = jnp.where((code <= upto) | (code == twin), s_t, _NEG_INF)
+        elif masked == "selected":
             s_t = jnp.where(_unpack(sel_ref[0, _block(
                 kb, block_k // _WORD, num_kb), :]), s_t, _NEG_INF)
         elif masked == "edge":
@@ -341,7 +467,13 @@ def _fwd_kernel(*refs, causal: bool, block_k: int, scale: float,
         init = lax.fori_loop(first, inside, functools.partial(tile, "edge"),
                              init)
         first = inside
+    if bd is not None:
+        clear, upper, twins, twins_end = _bd_key_blocks(q0, bq, block_k, bd)
     carry = lax.fori_loop(first, clear, functools.partial(tile, False), init)
+    if bd is not None:
+        for blocks in ((clear, upper), (twins, twins_end)):
+            carry = lax.fori_loop(*blocks, functools.partial(tile, "rule"),
+                                  carry)
     if causal or padded:
         carry = lax.fori_loop(clear, upper, functools.partial(tile, True),
                               carry)
@@ -358,7 +490,8 @@ def _kv_head(group: int):
 
 
 def _fwd(q, k, v, causal, block_q, block_k, interpret, kv_len,
-         operand=jnp.float32, sel=None, select_from=None, window=None):
+         operand=jnp.float32, sel=None, select_from=None, window=None,
+         bd=None):
     """q: [B, H, T, Dk], k: [B, Hkv, T, Dk], v: [B, Hkv, T, Dv] → (o
     [B,H,T,Dv], lse [B,H,T,1]); the scale is 1/√Dk.  ``H // Hkv`` query
     heads in a row read one key/value head: the index map repeats, and
@@ -367,12 +500,12 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret, kv_len,
     b, h, t, dk = q.shape
     dv = v.shape[3]
     kv_head = _kv_head(h // k.shape[1])
-    bq = _pick_block(t, block_q)
-    bk = _pick_block(t, block_k)
+    bq, bk = _bd_blocks(t, block_q, block_k, bd)
     scale = 1.0 / (dk ** 0.5)
     kernel = functools.partial(_fwd_kernel, causal=causal, block_k=bk,
                                scale=scale, kv_len=kv_len, operand=operand,
-                               select_from=select_from, window=window)
+                               select_from=select_from, window=window,
+                               bd=bd)
     selection = [] if sel is None else [pl.BlockSpec(
         (1, t // _WORD, bq), lambda bi, hi, qi: (bi, 0, qi))]
 
@@ -413,7 +546,8 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret, kv_len,
 # ---------------------------------------------------------------------------
 def _bwd_kernel(*refs, causal: bool, block_q: int, scale: float,
                 kv_len: int, operand, select_from: Optional[int] = None,
-                window: Optional[int] = None):
+                window: Optional[int] = None,
+                bd: Optional[Tuple[int, int]] = None):
     """dQ, dK and dV in one call: each score tile is formed ONCE.
 
     One (batch, head, k-block) program; the k-block axis is sequential.
@@ -437,6 +571,9 @@ def _bwd_kernel(*refs, causal: bool, block_q: int, scale: float,
     With ``window`` the q blocks of a key block end where the window of
     its last key does, ``(k0 + bk + window - 2) // block_q``; those the
     window's trailing edge crosses are masked by it.
+
+    With ``bd = (B, L)`` the q blocks of a key block are the forward's
+    tiles read the other way (:func:`_bd_query_blocks`).
     """
     if select_from is None:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -473,6 +610,10 @@ def _bwd_kernel(*refs, causal: bool, block_q: int, scale: float,
     # a window shorter than two tiles can cross a diagonal tile as well,
     # and every q block of a key block that holds padding takes that mask
     near = window is not None and (window < bk + block_q or padded)
+    if bd is not None:
+        code = _bd_codes(
+            k0 + lax.broadcasted_iota(jnp.int32, (bk, 1), 0), bd)[0]  # [bk,1]
+        query = lax.broadcasted_iota(jnp.int32, (1, block_q), 1)
 
     def tile(masked, qb, carry):
         dk_t, dv_t = carry
@@ -480,7 +621,10 @@ def _bwd_kernel(*refs, causal: bool, block_q: int, scale: float,
         qs_t = qst_ref[:, cols]                             # [Dk, bq]
         do_t = dot_ref[:, cols]                             # [Dv, bq]
         s_t = _dot(k, qs_t, _NN, operand)                   # [bk, bq]
-        if masked == "selected":
+        if masked == "rule":
+            _, upto, twin = _bd_codes(qb * block_q + query, bd)  # [1,bq]
+            s_t = jnp.where((code <= upto) | (code == twin), s_t, _NEG_INF)
+        elif masked == "selected":
             s_t = jnp.where(_unpack(sel_ref[0, :, cols]), s_t, _NEG_INF)
         elif masked == "edge":
             s_t = jnp.where(row_minus_col > qb * block_q - k0 - window, s_t,
@@ -532,6 +676,18 @@ def _bwd_kernel(*refs, causal: bool, block_q: int, scale: float,
     if causal or padded:
         carry = lax.fori_loop(lower, masked_to,
                               functools.partial(tile, True), carry)
+    if bd is not None:
+        # the clean q blocks the edge crosses and the same of the noised
+        # ones, then the noised ones past the edge; the clean ones past it,
+        # ``[clear, last)``, are the loop's below
+        (edge, clear, last), noised = _bd_query_blocks(k0, bk, block_q, bd,
+                                                       num_qb)
+        carry = lax.fori_loop(edge, clear, functools.partial(tile, "rule"),
+                              carry)
+        carry = lax.fori_loop(noised[0], noised[1],
+                              functools.partial(tile, "rule"), carry)
+        carry = lax.fori_loop(noised[1], noised[2],
+                              functools.partial(tile, False), carry)
     dk_t, dv_t = lax.fori_loop(clear, last, functools.partial(tile, False),
                                carry)
     # q was pre-scaled, so dSᵀQ already carries the 1/√d factor.
@@ -550,7 +706,7 @@ def _bwd_kernel(*refs, causal: bool, block_q: int, scale: float,
 
 def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret, kv_len,
          dlse=None, operand=jnp.float32, sel=None, select_from=None,
-         window=None):
+         window=None, bd=None):
     """(dq, dk, dv).  Where ``group = H // Hkv`` query heads share a
     key/value head, the kernel writes each query head's dK and dV and
     the group's are summed here: 2 x [B,H,T,D] crosses memory once more,
@@ -558,8 +714,7 @@ def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret, kv_len,
     b, h, t, dk = q.shape
     dv = v.shape[3]
     group = h // k.shape[1]
-    bq = _pick_block(t, block_q)
-    bk = _pick_block(t, block_k)
+    bq, bk = _bd_blocks(t, block_q, block_k, bd)
     scale = 1.0 / (dk ** 0.5)
     # Δ_i = Σ_d dO_id · O_id — the softmax-normalization gradient term;
     # a cheap elementwise reduce, left to XLA fusion.  An lse cotangent
@@ -588,7 +743,7 @@ def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret, kv_len,
     dq, dk_heads, dv_heads = pl.pallas_call(
         functools.partial(_bwd_kernel, causal=causal, block_q=bq,
                           scale=scale, kv_len=kv_len, operand=operand,
-                          select_from=select_from, window=window),
+                          select_from=select_from, window=window, bd=bd),
         grid=(b, h, t // bk),
         in_specs=[full_spec(dk), kb_spec(dk, group), kb_spec(dv, group),
                   full_spec(dv), row_spec, row_spec] + selection,
@@ -701,6 +856,34 @@ def _flash_windowed_bwd(block_q, block_k, interpret, kv_len, operand, window,
 _flash_windowed.defvjp(_flash_windowed_fwd, _flash_windowed_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_block_diffusion(q, k, v, block_q, block_k, interpret, operand, bd):
+    """:func:`_flash`, not causal, under the mask of ``bd = (B, L)``
+    (:func:`block_diffusion_mask`)."""
+    return _fwd(q, k, v, False, block_q, block_k, interpret, q.shape[2],
+                operand, bd=bd)
+
+
+def _flash_block_diffusion_fwd(q, k, v, block_q, block_k, interpret, operand,
+                               bd):
+    o, lse = map(checkpoint_name, _fwd(
+        q, k, v, False, block_q, block_k, interpret, q.shape[2], operand,
+        bd=bd), RESIDUAL_NAMES)
+    return (o, lse), (q, k, v, o, lse)
+
+
+def _flash_block_diffusion_bwd(block_q, block_k, interpret, operand, bd, res,
+                               cts):
+    q, k, v, o, lse = res
+    do, dlse = cts
+    return _bwd(q, k, v, o, lse, do, False, block_q, block_k, interpret,
+                q.shape[2], dlse=dlse, operand=operand, bd=bd)
+
+
+_flash_block_diffusion.defvjp(_flash_block_diffusion_fwd,
+                              _flash_block_diffusion_bwd)
+
+
 @functools.cache
 def _log_operand(name: str, why: str) -> None:
     logging.info("flash attention: product operands %s (%s)", name, why)
@@ -725,10 +908,22 @@ def _product_operand(interpret: bool):
 
 
 def _pad_and_run(q, k, v, causal, block_q, block_k, interpret,
-                 selection=None, select_from=None, window=None):
+                 selection=None, select_from=None, window=None,
+                 block_diffusion=None):
     """[B,T,H,D] public layout (q, k ``Dk`` wide, v ``Dv``) → padded
     [B,H,T,D] kernel run → sliced (o [B,T,H,Dv], lse [B,H,T])."""
     t = q.shape[1]
+    if block_diffusion is not None:
+        b, l = map(int, block_diffusion)
+        if (causal or selection is not None or window is not None
+                or b < 1 or l % b or t not in (l, 2 * l)
+                or _pad_len(l, interpret) != l):
+            raise ValueError(
+                "block diffusion is not causal, comes without a selection "
+                "or a window, and takes L or 2 L rows of a sequence of L "
+                "that needs no padding, in blocks that divide it; got "
+                f"causal={causal}, block_diffusion={block_diffusion}, {t} "
+                "rows")
     if window is not None and (not causal or selection is not None
                                or int(window) < 1):
         raise ValueError("a window is causal, holds at least the query's "
@@ -740,6 +935,11 @@ def _pad_and_run(q, k, v, causal, block_q, block_k, interpret,
         raise ValueError(f"{q.shape[2]} query heads over {k.shape[2]} key "
                          f"and {v.shape[2]} value heads")
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))  # → [B,H,T,D]
+    if block_diffusion is not None:
+        o, lse = _flash_block_diffusion(
+            qt, kt, vt, block_q, block_k, interpret,
+            _product_operand(interpret), (b, l))
+        return o.transpose(0, 2, 1, 3), lse[..., 0]
     if selection is not None:
         if not causal or tp != t or select_from is None:
             raise ValueError("a selection is causal, says from which row on "
@@ -771,7 +971,9 @@ def flash_attention(q, k, v, causal: bool = False, *,
                     interpret: Optional[bool] = None,
                     selection: Optional[jax.Array] = None,
                     select_from: Optional[int] = None,
-                    window: Optional[int] = None) -> jax.Array:
+                    window: Optional[int] = None,
+                    block_diffusion: Optional[Tuple[int, int]] = None
+                    ) -> jax.Array:
     """Drop-in ``attn_fn(q, k, v, causal)``: q, k ``[B, T, H, Dk]``, v
     ``[B, T, H, Dv]`` → ``[B, T, H, Dv]``; the scale is 1/√Dk.  The two
     widths are usually one (D = 64 in the GPT-2 blocks); latent attention
@@ -793,13 +995,19 @@ def flash_attention(q, k, v, causal: bool = False, *,
     ``window - 1`` before it; tiles wholly behind the window are skipped,
     forward and backward.  None: every earlier key, as ever.
 
+    ``block_diffusion=(B, L)`` (static, NOT causal, with neither of the
+    above): the rows are a sequence of ``L`` tokens and, where ``T = 2 L``,
+    its noised copy behind it; :func:`block_diffusion_mask` is what a row
+    attends to.  The tiles divide ``L``; those the rule lets nothing
+    through are skipped, forward and backward (:func:`pairs_computed`).
+
     Sequences whose length is not MXU-tileable are zero-padded to the next
     tileable length (masked inside the kernels; the pad is sliced off), so
     any length compiles on real TPU."""
     if interpret is None:
         interpret = _use_interpret()
     return _pad_and_run(q, k, v, causal, block_q, block_k, interpret,
-                        selection, select_from, window)[0]
+                        selection, select_from, window, block_diffusion)[0]
 
 
 def flash_attention_with_lse(q, k, v, causal: bool = False, *,

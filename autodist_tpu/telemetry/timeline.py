@@ -379,6 +379,8 @@ SCOPE_DSA_INDEX = "dsa/index"            # the indexer's projections, scores
 SCOPE_DSA_SELECT = "dsa/select"          # top-k of every row, packed words
 SCOPE_DSA_ATTENTION = "dsa/attention"    # the attention call alone
 SCOPE_SWA_ATTENTION = "swa/attention"    # window_attn or global_attn alone
+SCOPE_BD_NOISE = "bd/noise"              # levels, masks, the noised copy
+SCOPE_BD_ATTENTION = "bd/attention"      # bd_attn, the call alone
 SCOPE_GATTN_ATTENTION = "gattn/attention"  # gated_attn, the call alone
 SCOPE_GDN_PROJECT = "gdn/project"        # qkvz, ba, the gated norm, out
 SCOPE_GDN_CONV = "gdn/conv"              # the causal convolution and its silu

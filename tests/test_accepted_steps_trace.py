@@ -36,7 +36,11 @@ others: the text is the parent's but for ONE equation that nothing reads
 skeleton's ``kept_bytes`` asks for the shapes a dense FFN tags; 44
 ``pallas_call`` as before).  Kanana's leading dense layer runs both its
 halves under the attention's map and checkpoint, as it did, because there
-a slice is a sequence.  A PR that changes one of
+a slice is a sequence.  PR 47 (the SDAR-30B-A3B-Chat share:
+``routed_decoder``'s ``objective`` seam with causal next-token as its
+default, ``flash_attention(block_diffusion=)``, ``chunked_xent``'s
+``weights``, ``rotary_halves``' ``positions``, two scopes) changed NONE of
+the six and recorded its own model's.  A PR that changes one of
 these models' traces on purpose records the new hash here and says so in
 ``CHANGES.md``.
 """
@@ -76,6 +80,9 @@ TRACES = {
     "lfm2-8b-a1b.ep4-share": (
         4, 8192, 967221, 42,
         "256f5023c2f3b6830753b48e74da809d5c418afec7f41c62cc5447373465ac50"),
+    "sdar-30b-a3b-chat.ep8-share": (
+        1, 8192, 978207, 42,
+        "eb3872652f613daced00c910569a3f08b3849b8bbd8b06f8ed87649bca8a01be"),
 }
 
 

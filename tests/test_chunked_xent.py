@@ -103,3 +103,66 @@ def test_compiled_avoids_full_logits():
     db = dense.lower(h, w, y).compile().memory_analysis().temp_size_in_bytes
     cb = chunked.lower(h, w, y).compile().memory_analysis().temp_size_in_bytes
     assert cb < db / 4, (cb, db)
+
+
+@pytest.mark.parametrize("chunk", [64, 512])
+def test_weights_match_the_plain_weighted_loss(chunk):
+    """A weight a row (the block-diffusion objective's ``m / t``, zero for
+    unmasked rows): value and both gradients against the plain weighted
+    loss over ALL rows; the weights take no gradient."""
+    h, w, y = _data()
+    rng = np.random.RandomState(3)
+    weights = jnp.asarray(
+        np.where(rng.rand(24) < 0.5, 1.0 / rng.uniform(0.01, 1.0, 24), 0.0),
+        jnp.float32)
+
+    def plain(h, w, weights):
+        logz = jax.nn.log_softmax(jnp.einsum("ne,ve->nv", h, w), axis=-1)
+        return -jnp.sum(weights * logz[jnp.arange(24), y]) / 24
+
+    def chunked(h, w, weights):
+        return chunked_softmax_cross_entropy(h, w, y, chunk=chunk,
+                                             weights=weights)
+
+    np.testing.assert_allclose(chunked(h, w, weights),
+                               plain(h, w, weights), rtol=1e-6)
+    np.testing.assert_allclose(
+        chunked(h, w, weights),
+        cross_entropy_loss(jnp.einsum("ne,ve->nv", h, w), y, weights),
+        rtol=1e-6)
+    got = jax.grad(chunked, (0, 1, 2))(h, w, weights)
+    want = jax.grad(plain, (0, 1))(h, w, weights)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, rtol=1e-5, atol=1e-7)
+    assert not np.asarray(got[2]).any()
+    # shaped as the labels, whatever the leading shape
+    np.testing.assert_allclose(
+        chunked_softmax_cross_entropy(
+            h.reshape(4, 6, -1), w, y.reshape(4, 6), chunk=chunk,
+            weights=weights.reshape(4, 6)), plain(h, w, weights), rtol=1e-6)
+
+
+def test_rows_of_weight_zero_add_nothing():
+    """Zero-weight rows leave ``dW`` unchanged and take a zero ``dh``:
+    the gradients with them are the gradients of the weighted rows alone
+    (over the same count of rows)."""
+    h, w, y = _data()
+    weights = jnp.asarray([2.0, 0.0, 0.5] * 8, jnp.float32)
+    kept = np.asarray(weights) > 0
+
+    def loss(h, w, rows, weights):
+        return chunked_softmax_cross_entropy(
+            h[rows], w, y[rows], chunk=128, weights=weights[rows])
+
+    every = np.arange(24)
+    dh, dw = jax.grad(loss, (0, 1))(h, w, every, weights)
+    dh_kept, dw_kept = jax.grad(loss, (0, 1))(h, w, every[kept], weights)
+    assert not np.asarray(dh)[~kept].any()
+    np.testing.assert_allclose(dw * 24, dw_kept * kept.sum(), rtol=1e-5,
+                               atol=1e-8)
+    np.testing.assert_allclose(dh[kept] * 24, dh_kept[kept] * kept.sum(),
+                               rtol=1e-5, atol=1e-8)
+    # other rows' values do not reach dW
+    moved = h.at[1].set(h[1] * 3.0 + 1.0)
+    np.testing.assert_array_equal(
+        jax.grad(loss, 1)(moved, w, every, weights), dw)

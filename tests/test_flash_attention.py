@@ -852,3 +852,126 @@ def test_window_is_refused_where_it_cannot_be_honoured():
     np.testing.assert_allclose(
         attn(q, k, v, True), _grouped_dense(q, k, v, _window_mask(1, 64, 16)),
         rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# block diffusion (PR 47): a sequence and its noised copy under a
+# block-structured mask that is not causal
+# ---------------------------------------------------------------------------
+
+def _block_diffusion_mask(block, length, rows):
+    """The four rules, pair by pair."""
+    mask = np.zeros((rows, rows), bool)
+    for r in range(rows):
+        for c in range(rows):
+            if r < length and c < length:
+                mask[r, c] = c // block <= r // block
+            elif r >= length and c < length:
+                mask[r, c] = c // block < (r - length) // block
+            elif r >= length:
+                mask[r, c] = (c - length) // block == (r - length) // block
+    return mask
+
+
+@pytest.mark.parametrize("length,block,blocks,heads,kv_heads,copies", [
+    (128, 4, (32, 32), 2, 2, 2),     # the cell's rule at a test's size
+    (128, 1, (32, 32), 8, 1, 2),     # blocks of one; heads 8 : 1
+    (128, 128, (64, 64), 2, 1, 2),   # one block: the sequence's length
+    (192, 4, (128, 128), 4, 2, 2),   # L no multiple of the tile: tiles of 96
+    (96, 4, (32, 32), 2, 1, 1),      # the clean rows alone: block-causal
+    (60, 30, (16, 32), 2, 2, 2),     # no power of two, wider than a
+                                     # tile; tiles of 15 and 30
+])
+def test_block_diffusion_matches_plain_masked_attention(
+        length, block, blocks, heads, kv_heads, copies):
+    """Forward and fused backward under the block-diffusion mask against
+    the masked dense formula: outputs and all three gradients."""
+    from autodist_tpu.ops.flash_attention import block_diffusion_mask
+
+    t = copies * length
+    rng = np.random.default_rng(length + block)
+    q, k, v = _grouped_qkv(rng, 2, t, heads, kv_heads)
+    mask = _block_diffusion_mask(block, length, t)
+    np.testing.assert_array_equal(
+        np.asarray(block_diffusion_mask(block, length, t)), mask)
+    mask = jnp.broadcast_to(jnp.asarray(mask), (2, t, t))
+    w = jnp.asarray(rng.standard_normal((2, t, heads, 16)), jnp.float32)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, False, block_q=blocks[0],
+                               block_k=blocks[1], interpret=True,
+                               block_diffusion=(block, length))
+
+    np.testing.assert_allclose(flash(q, k, v), _grouped_dense(q, k, v, mask),
+                               rtol=1e-5, atol=1e-5)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(_grouped_dense(*a, mask) * w),
+                    (0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        assert g.shape == r.shape
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("length,block,tile", [
+    (64, 4, 16),      # the edge crosses one tile a q block
+    (64, 32, 16),     # blocks of two tiles
+])
+def test_block_diffusion_visits_the_tiles_it_counts(length, block, tile):
+    """The tiles the kernels visit are the tiles with a pair the mask lets
+    through, no more and no fewer, and ``pairs_computed`` counts them.
+    Forward: a key block turned NaN poisons the q blocks that visit it.
+    Backward: a q block's cotangent turned NaN poisons dK of the key
+    blocks whose program visits it."""
+    from autodist_tpu.ops.flash_attention import pairs_computed
+
+    t, n = 2 * length, 2 * length // tile
+    bd = (block, length)
+    mask = _block_diffusion_mask(block, length, t)
+    needed = mask.reshape(n, tile, n, tile).any(axis=(1, 3))   # [qb, kb]
+    assert pairs_computed(t, block_q=tile, block_k=tile,
+                          block_diffusion=bd) == needed.sum() * tile * tile
+    q, k, v = _grouped_qkv(np.random.default_rng(7), 1, t, 2, 1)
+
+    @jax.jit
+    def flash(q, k, v):
+        return flash_attention(q, k, v, False, block_q=tile, block_k=tile,
+                               interpret=True, block_diffusion=bd)
+
+    rows = jnp.arange(t)[None, :, None, None] // tile
+    by_block = lambda x: np.isnan(np.asarray(x)).reshape(  # noqa: E731
+        n, -1).any(axis=1)
+    forward = np.stack([by_block(flash(
+        q, *(jnp.where(rows == kb, jnp.nan, x) for x in (k, v))))
+        for kb in range(n)], axis=1)
+    np.testing.assert_array_equal(forward, needed)
+    d_k = jax.jit(lambda ct: jax.vjp(flash, q, k, v)[1](ct)[1])
+    backward = np.stack([by_block(d_k(
+        jnp.where(rows == qb, jnp.nan, jnp.ones((1, t, 2, 16)))))
+        for qb in range(n)], axis=0)
+    np.testing.assert_array_equal(backward, needed)
+
+
+def test_pairs_computed_at_the_cells_size_and_refusals():
+    from autodist_tpu.ops.flash_attention import pairs_computed
+
+    # 8,192 tokens, blocks of 4, tiles of 512: 136 tiles a head for the
+    # clean q blocks, 136 + 16 for the noised ones; the causal kernel over
+    # the 16,384 rows visits 528
+    assert pairs_computed(16384, block_diffusion=(4, 8192)) \
+        == 288 * 512 * 512
+    assert pairs_computed(8192, block_diffusion=(4, 8192)) == 136 * 512 * 512
+    assert pairs_computed(16384) == 528 * 512 * 512
+    # one block: every clean tile, every noised tile, none between
+    assert pairs_computed(256, block_q=32, block_k=32,
+                          block_diffusion=(128, 128)) == 2 * 16 * 32 * 32
+    q, k, v = _grouped_qkv(np.random.default_rng(0), 1, 64, 2, 2)
+    for kw in (dict(causal=True, block_diffusion=(4, 32)),
+               dict(causal=False, block_diffusion=(4, 32), window=8),
+               dict(causal=False, block_diffusion=(5, 32)),    # 5 ∤ 32
+               dict(causal=False, block_diffusion=(4, 48))):   # 64 rows
+        with pytest.raises(ValueError, match="block diffusion is not"):
+            flash_attention(q, k, v, interpret=True, **kw)
+    # compiled, L has to need no padding (interpreted, any L does)
+    with pytest.raises(ValueError, match="needs no padding"):
+        flash_attention(*_grouped_qkv(np.random.default_rng(0), 1, 400, 2, 2),
+                        False, interpret=False, block_diffusion=(4, 200))

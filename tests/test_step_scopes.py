@@ -24,6 +24,7 @@ from autodist_tpu.autodist import (
 )
 from autodist_tpu.mesh import build_mesh
 from autodist_tpu.models.gdn_moe_lm import gdn_moe_lm
+from autodist_tpu.models.gqa_bd_moe_lm import gqa_bd_moe_lm
 from autodist_tpu.models.gqa_dsa_moe_lm import gqa_dsa_moe_lm
 from autodist_tpu.models.mla_moe_lm import mla_moe_lm
 from autodist_tpu.models.sconv_moe_lm import sconv_moe_lm
@@ -38,7 +39,7 @@ FLASH = functools.partial(flash_attention, interpret=True, block_q=32,
 ROUTED = dict(vocab_size=61, d_model=32, d_expert=12, num_experts=16,
               top_k=3, experts_held=(0, 4), xent_chunk=32,
               train_router=False, attn_fn=FLASH)
-#: the six factories of the benchmark's seven cells, each as its cell
+#: the seven factories of the benchmark's eight cells, each as its cell
 #: runs it (the kernel and not the dense softmax, the chunked loss where
 #: the configuration asks for it, checkpoints, maps over sequences)
 FACTORIES = {
@@ -64,6 +65,9 @@ FACTORIES = {
         chunk=16, block_k=32, moe_slice=64,
         gdn_fn=functools.partial(gated_delta_rule, chunk=16, segment=2,
                                  interpret=True))),
+    "gqa_bd_moe_lm": (gqa_bd_moe_lm, dict(
+        ROUTED, num_layers=2, num_heads=4, num_kv_heads=2, head_dim=16,
+        block_length=4, seq_len=96, block_k=32, moe_slice=96)),
     "sconv_moe_lm": (sconv_moe_lm, dict(
         ROUTED, layer_types=("conv", "full_attention"), num_dense_layers=1,
         num_heads=4, num_kv_heads=2, head_dim=8, d_ff=48, seq_len=64,
