@@ -16,10 +16,16 @@ value heads ``r j .. r j + r - 1`` read key head ``j``), ``h = RMS0(x)``:
     [b a]     = h W_ba        by key head: r of b, r of a
     c_t = silu(sum_{i<K} w_conv[:, i] * [q k v]_{t-K+1+i})    depthwise over
                               the channels of q | k | v, causal, no bias
+    q = l2norm(c_q) / sqrt(Dl), k = l2norm(c_k), v = c_v
     beta = sigmoid(b)    g = -exp(A_log) softplus(a + dt_bias)    float32
-    q = l2norm(q) / sqrt(Dl), k = l2norm(k)
     o = gated_delta_rule(q, k, v, g, beta)      ops/gated_delta_rule.py
     y = (rmsnorm(o; w_n) * silu(z)) W_out       the norm over a head's Dl
+
+The two lines of ``c`` and of q, k, v with the split of ``qkvz`` are ONE
+pair of Pallas kernels on a TPU (``ops/gdn_conv.py: conv_silu_l2norm``, HLO
+names ``gdn_conv`` and ``gdn_conv_bwd``, under the scope ``gdn/conv``,
+l2norm with them: PR 48) and :func:`conv_qkvz`, the same lines in
+``jax.numpy``, elsewhere.
 
 FULL mixer (``H`` query heads over ``G`` key/value heads of ``Dh``):
 
@@ -51,6 +57,14 @@ segments are entered with (``gated_delta_rule.RESIDUAL_NAMES``: 134 MB
 and 34 MB a sequence and layer at the published widths): with them the
 backward's recomputation of a linear mixer stops at the projections and
 the convolution and never runs the scan's forward kernel a second time.
+
+What of a linear mixer still crosses main memory more often than it must
+(``ROADMAP.md`` S16, in the order of their ms a step in the qwen3-next
+cell, ``PERF.md`` section 5): the gated norm times ``silu(z)`` a pass of
+its own before ``W_out``, the weights' gradient sums ``fusion f32[8192]``,
+and ``_prepare`` formed twice in ``ops/gated_delta_rule.py``.  The
+convolution, l2norm and the copy of z out of ``qkvz``'s columns left that
+list with PR 48.
 """
 from __future__ import annotations
 
@@ -63,6 +77,7 @@ import jax.numpy as jnp
 from autodist_tpu.models.base import ModelSpec, rms_norm, rotary_halves
 from autodist_tpu.models.routed_decoder import routed_decoder
 from autodist_tpu.models.transformer import default_sparse_attention
+from autodist_tpu.ops import gdn_conv
 from autodist_tpu.ops.flash_attention import _DEFAULT_BLOCK, RESIDUAL_NAMES
 from autodist_tpu.ops.gated_delta_rule import (
     RESIDUAL_NAMES as GDN_RESIDUAL_NAMES,
@@ -99,6 +114,23 @@ def causal_conv(x, w):
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0)) + ((0, 0),) * (x.ndim - 2))
     return sum(jax.lax.slice_in_dim(padded, i, i + t, axis=1) * w[..., i]
                for i in range(taps))
+
+
+def conv_qkvz(qkvz, w_q, w_k, w_v, scale: float):
+    """``(q, k [B, T, Hk, Dl], v, z [B, T, r Hk, Dl])`` of ``qkvz [B, T, Hk,
+    (2 + 2 r) Dl]``: q, k, v convolved under their taps (``w_q, w_k [Hk, Dl,
+    K]``, ``w_v [Hk, r Dl, K]``) and through silu, q and k l2-normed, q
+    times ``scale``, z as it is.  The plain form: what runs off a TPU, and
+    what ``ops/gdn_conv.py: conv_silu_l2norm`` is held to
+    (``tests/test_gdn_conv.py``)."""
+    dl = w_q.shape[1]
+    q, k, v, z = jnp.split(qkvz, (dl, 2 * dl, 2 * dl + w_v.shape[1]),
+                           axis=-1)
+    q, k, v = (jax.nn.silu(causal_conv(y, w))
+               for y, w in ((q, w_q), (k, w_k), (v, w_v)))
+    heads = q.shape[:2] + (-1, dl)
+    return (l2norm(q) * scale, l2norm(k), v.reshape(heads),
+            z.reshape(heads))
 
 
 def partial_rotary(x, theta: float, columns: int):
@@ -203,25 +235,21 @@ def gdn_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
         with jax.named_scope(timeline.SCOPE_GDN_PROJECT):
             qkvz = jnp.einsum("btd,dhc->bthc", h, p["w_qkvz"])
             ba = jnp.einsum("btd,dhc->bthc", h, p["w_ba"])
-            q, k, v, z = jnp.split(
-                qkvz, (dl, 2 * dl, (2 + share) * dl), axis=-1)
         with jax.named_scope(timeline.SCOPE_GDN_CONV):
-            w_q, w_k, w_v = (w.reshape(hk, -1, conv_kernel) for w in
-                             jnp.split(p["conv"], (hk * dl, 2 * hk * dl)))
-            q, k, v = (jax.nn.silu(causal_conv(y, w))
-                       for y, w in ((q, w_q), (k, w_k), (v, w_v)))
+            taps = (w.reshape(hk, -1, conv_kernel) for w in
+                    jnp.split(p["conv"], (hk * dl, 2 * hk * dl)))
+            q, k, v, z = (conv_qkvz if gdn_conv._use_interpret() else
+                          gdn_conv.conv_silu_l2norm)(
+                qkvz, *taps, 1.0 / math.sqrt(dl))
         with jax.named_scope(timeline.SCOPE_GDN_RECURRENCE):
             beta = jax.nn.sigmoid(ba[..., :share].astype(jnp.float32))
             g = -jnp.exp(p["a_log"].astype(jnp.float32)
                          + decay_offsets(hk * share)) * jax.nn.softplus(
                 ba[..., share:].astype(jnp.float32).reshape(b, t, -1)
                 + p["dt_bias"]["scale"].astype(jnp.float32))
-            o = gdn_fn(l2norm(q) / math.sqrt(dl), l2norm(k),
-                       v.reshape(b, t, hk * share, dl), g,
-                       beta.reshape(b, t, -1))
+            o = gdn_fn(q, k, v, g, beta.reshape(b, t, -1))
         with jax.named_scope(timeline.SCOPE_GDN_PROJECT):
-            y = rms_norm(o, p["norm"]["scale"], rms_eps) \
-                * jax.nn.silu(z.reshape(o.shape))
+            y = rms_norm(o, p["norm"]["scale"], rms_eps) * jax.nn.silu(z)
             return x + jnp.einsum("bthv,hvd->btd", y, p["w_out"])
 
     def full_half(lp, x):

@@ -383,8 +383,8 @@ SCOPE_BD_NOISE = "bd/noise"              # levels, masks, the noised copy
 SCOPE_BD_ATTENTION = "bd/attention"      # bd_attn, the call alone
 SCOPE_GATTN_ATTENTION = "gattn/attention"  # gated_attn, the call alone
 SCOPE_GDN_PROJECT = "gdn/project"        # qkvz, ba, the gated norm, out
-SCOPE_GDN_CONV = "gdn/conv"              # the causal convolution and its silu
-SCOPE_GDN_RECURRENCE = "gdn/recurrence"  # l2norm, g, beta, gdn_scan
+SCOPE_GDN_CONV = "gdn/conv"              # conv, silu, l2norm, z: gdn_conv
+SCOPE_GDN_RECURRENCE = "gdn/recurrence"  # g, beta, gdn_scan
 SCOPE_SCONV_PROJECT = "sconv/project"    # W_in, W_out of the short conv
 SCOPE_SCONV_CONV = "sconv/conv"          # both gates and the three taps
 SCOPE_MOE_ROUTE = "moe/route"            # scores, top-k, sort, group sizes

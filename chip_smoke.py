@@ -889,6 +889,42 @@ def check_gated_delta_rule(shape, *, chunk: int, interpret: bool, tol: float,
     return facts
 
 
+def check_gdn_conv(shape, *, interpret: bool, tol: float = 1e-5) -> dict:
+    """``ops/gdn_conv.py``'s kernel pair against the plain lines
+    (``models/gdn_moe_lm.py: conv_qkvz``) at ``shape = (T, Hk, Hv, D)``, one
+    sequence: q, k, v, z and, under seeded cotangents, the cotangent of
+    ``qkvz`` and the three taps' gradients, each within ``tol`` of the plain
+    form's largest (no product in either: float32 elementwise arithmetic,
+    ``exp`` and ``rsqrt`` as Mosaic and as XLA lower them)."""
+    import jax
+
+    from autodist_tpu.models.gdn_moe_lm import conv_qkvz
+    from autodist_tpu.ops.gdn_conv import conv_silu_l2norm
+
+    t, hk, hv, d = shape
+    share = hv // hk
+    ks = jax.random.split(jax.random.PRNGKey(SEED), 8)
+    qkvz = jax.random.normal(ks[0], (1, t, hk, 2 * d * (1 + share)))
+    taps = tuple(jax.random.normal(k, (hk, n, 4)) * 0.5
+                 for k, n in zip(ks[1:4], (d, d, share * d)))
+    cts = tuple(jax.random.normal(k, (1, t, heads, d))
+                for k, heads in zip(ks[4:], (hk, hk, hv, hv)))
+
+    def both(fn):
+        out, pull = jax.vjp(lambda x, *w: fn(x, *w, d ** -0.5), qkvz, *taps)
+        return out + pull(cts)
+
+    got = jax.jit(lambda: both(functools.partial(
+        conv_silu_l2norm, interpret=interpret)))()
+    errs = [_rel_err(a, b) for a, b in zip(got, jax.jit(
+        lambda: both(conv_qkvz))())]
+    if not max(errs) <= tol:
+        raise AssertionError(
+            f"gdn_conv against the plain lines, q, k, v, z and the "
+            f"gradients of qkvz and the three taps: {errs} > {tol}")
+    return {"rel_err": [float(f"{e:.3g}") for e in errs]}
+
+
 def train_gdn_moe_phase(model: dict, *, batch_size: int, steps: int,
                         tol: float) -> dict:
     """``models/gdn_moe_lm.py`` with ONE LINEAR AND ONE FULL LAYER (the
@@ -898,11 +934,14 @@ def train_gdn_moe_phase(model: dict, *, batch_size: int, steps: int,
     batch, every loss finite, the per-expert token counts back, the gauges
     of the recurrence's FLOPs as written and as computed.  Before it, the
     recurrence's chunked form and kernel at the model's own shape against
-    the recurrence token by token (:func:`check_gated_delta_rule`).  On a
-    TPU the compiled step's Pallas calls are counted BY NAME: one
-    ``gdn_scan`` (the forward's), one ``gdn_scan_bwd`` (the written-out
-    backward's scan) and a forward and a backward ``gated_attn``, none run
-    twice."""
+    the recurrence token by token (:func:`check_gated_delta_rule`), and the
+    convolution's kernel pair against the plain lines
+    (:func:`check_gdn_conv`).  On a TPU the compiled step's Pallas calls
+    are counted BY NAME: one ``gdn_scan`` (the forward's), one
+    ``gdn_scan_bwd`` (the written-out backward's scan) and a forward and a
+    backward ``gated_attn``, none run twice; TWO ``gdn_conv`` (the forward's
+    and, the layer's checkpoint keeping nothing of it, the backward's
+    recomputation) and one ``gdn_conv_bwd``."""
     import jax
 
     from autodist_tpu.models.gdn_moe_lm import gdn_moe_lm
@@ -912,10 +951,12 @@ def train_gdn_moe_phase(model: dict, *, batch_size: int, steps: int,
     if (cfg["num_layers"], cfg["full_interval"]) != (2, 2):
         raise AssertionError("one linear and one full layer are asked")
     interpret = jax.devices()[0].platform != "tpu"
+    shape = (cfg["seq_len"], cfg["linear_key_heads"],
+             cfg["linear_value_heads"], cfg["linear_head_dim"])
     facts = {"recurrence_against_token_by_token": check_gated_delta_rule(
-        (cfg["seq_len"], cfg["linear_key_heads"], cfg["linear_value_heads"],
-         cfg["linear_head_dim"]), chunk=cfg["chunk"], interpret=interpret,
-        tol=tol)}
+        shape, chunk=cfg["chunk"], interpret=interpret, tol=tol),
+        "conv_against_the_plain_lines": check_gdn_conv(
+            shape, interpret=interpret)}
     ad, sess, batch, stepped = expert_model_steps(
         spec, batch_size=batch_size, steps=steps,
         pairs="autodist_gdn_flops_per_step")
@@ -924,9 +965,11 @@ def train_gdn_moe_phase(model: dict, *, batch_size: int, steps: int,
         text = sess.lower_step(batch).compile().as_text()
         calls = {name: len(re.findall(
             rf"%{name}[.\d]* = .*custom_call_target=\"tpu_custom_call\"",
-            text)) for name in ("gdn_scan", "gdn_scan_bwd", "gated_attn")}
+            text)) for name in ("gdn_scan", "gdn_scan_bwd", "gated_attn",
+                                "gdn_conv", "gdn_conv_bwd")}
         print(f"  kernels of the step by name: {calls}", flush=True)
-        if calls != {"gdn_scan": 1, "gdn_scan_bwd": 1, "gated_attn": 2}:
+        if calls != {"gdn_scan": 1, "gdn_scan_bwd": 1, "gated_attn": 2,
+                     "gdn_conv": 2, "gdn_conv_bwd": 1}:
             raise AssertionError(f"custom calls: {calls}")
         facts["kernel_calls"] = calls
         facts["bytes_in_use"] = memory_in_use(jax.devices()[:1])

@@ -40,7 +40,15 @@ a slice is a sequence.  PR 47 (the SDAR-30B-A3B-Chat share:
 ``routed_decoder``'s ``objective`` seam with causal next-token as its
 default, ``flash_attention(block_diffusion=)``, ``chunked_xent``'s
 ``weights``, ``rotary_halves``' ``positions``, two scopes) changed NONE of
-the six and recorded its own model's.  A PR that changes one of
+the six and recorded its own model's.  PR 48 recorded the Qwen3-Next
+share's anew, ON PURPOSE, and left the six others alone: a linear layer's
+split of ``qkvz``, its convolution (a pad and four shifted slices a
+tensor), silu and l2norm are the kernel pair of ``ops/gdn_conv.py`` as a
+TPU traces it (``gdn_conv``, ``gdn_conv_bwd``, each kind a jitted function
+traced once a shape; z passes through it and the arrays around it are a
+head's tokens one after the other): 320,308 characters and five
+``pallas_call`` texts more (three of ``gdn_conv``, two of
+``gdn_conv_bwd``).  A PR that changes one of
 these models' traces on purpose records the new hash here and says so in
 ``CHANGES.md``.
 """
@@ -75,8 +83,8 @@ TRACES = {
         1, 16384, 964590, 43,
         "4bdd8a2e2743d69baa536d7845d1f87febdec9ee5fd92dcf45c8e5523c4ab936"),
     "qwen3-next-80b-a3b.ep16-share": (
-        2, 8192, 1328871, 47,
-        "82f089a9af39b53309c908c4f0b1fb781c28fc89d3e0173b9eace9325d157d27"),
+        2, 8192, 1649179, 52,
+        "ea58fbe8861756644286a589eb846b521da5eebd9d01a3a48d9d8408a120208f"),
     "lfm2-8b-a1b.ep4-share": (
         4, 8192, 967221, 42,
         "256f5023c2f3b6830753b48e74da809d5c418afec7f41c62cc5447373465ac50"),
@@ -101,10 +109,10 @@ def compiled_kernels(factory: str, kwargs: dict) -> dict:
 
 @pytest.mark.parametrize("name", sorted(TRACES))
 def test_value_and_gradient_trace_to_the_recorded_text(name, monkeypatch):
-    from autodist_tpu.ops import grouped_matmul, rows_to_tokens
+    from autodist_tpu.ops import gdn_conv, grouped_matmul, rows_to_tokens
 
-    monkeypatch.setattr(rows_to_tokens, "_use_interpret", lambda: False)
-    monkeypatch.setattr(grouped_matmul, "_use_interpret", lambda: False)
+    for module in (rows_to_tokens, grouped_matmul, gdn_conv):
+        monkeypatch.setattr(module, "_use_interpret", lambda: False)
     rows, t, characters, kernels, digest = TRACES[name]
     with open(os.path.join(CONFIGS, name + ".json")) as f:
         program = json.load(f)["program"]

@@ -134,6 +134,9 @@ def test_train_gdn_moe_phase_checks_the_recurrence_and_steps_the_model():
     gaps = facts["recurrence_against_token_by_token"]
     assert set(gaps) == {"default", "highest"}
     assert all(len(v) == 6 and max(v) < 1e-4 for v in gaps.values())
+    # q, k, v, z and four gradients of the convolution's kernel pair
+    conv = facts["conv_against_the_plain_lines"]["rel_err"]
+    assert len(conv) == 8 and max(conv) < 1e-5
     # one linear layer x 2 x 64 tokens x 4 value heads
     per = facts["pairs_per_step"]
     assert per["recurrence"] == 7 * 8 * 8 * 512

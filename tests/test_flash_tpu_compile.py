@@ -460,11 +460,12 @@ def _on(chip, tree):
 
 
 def _compiled_kernels(monkeypatch):
-    """The routed layer's kernels as a TPU runs them, not interpreted."""
-    from autodist_tpu.ops import grouped_matmul, rows_to_tokens
+    """The routed layer's kernels, and the Gated DeltaNet mixer's
+    convolution, as a TPU runs them, not interpreted."""
+    from autodist_tpu.ops import gdn_conv, grouped_matmul, rows_to_tokens
 
-    monkeypatch.setattr(rows_to_tokens, "_use_interpret", lambda: False)
-    monkeypatch.setattr(grouped_matmul, "_use_interpret", lambda: False)
+    for module in (rows_to_tokens, grouped_matmul, gdn_conv):
+        monkeypatch.setattr(module, "_use_interpret", lambda: False)
 
 
 @pytest.mark.parametrize("cell", sorted(_EXPERT_LAYERS))
@@ -660,3 +661,40 @@ def test_gated_delta_rule_and_its_written_out_backward_compile(one_chip):
     assert scratch + 2 * blocks < _VMEM_DEFAULT, (scratch, blocks)
     assert compiled.memory_analysis().temp_size_in_bytes \
         < _RULE_TEMPORARIES_GB * 1e9
+
+
+def test_gdn_conv_and_its_backward_compile(one_chip):
+    """``ops/gdn_conv.py`` at the qwen3-next cell's shapes (one sequence of
+    8,192 tokens, 16 key heads of 128 + 128 + 256 + 256 columns, four taps)
+    compiled for a described v5e, value and cotangents: one kernel of each
+    name in the text (Mosaic takes the windows' slices off the sublanes'
+    grid and the blocks fit VMEM), and, handed ``qkvz`` a head's tokens one
+    after the other as the projection writes it, nothing else in the
+    program as wide as ``qkvz``: no copy into the kernels' layout."""
+    from autodist_tpu.ops import gdn_conv
+
+    t, hk, dl, share, taps = 8192, 16, 128, 2, 4
+
+    def on(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def both(qkvz, w_q, w_k, w_v, *cotangents):
+        out, pull = jax.vjp(
+            lambda x, *w: gdn_conv.conv_silu_l2norm(
+                jnp.swapaxes(x, 1, 2), *w, dl ** -0.5, interpret=False),
+            qkvz, w_q, w_k, w_v)
+        return out, pull(cotangents)
+
+    text = jax.jit(both).lower(
+        on(1, hk, t, 2 * dl * (1 + share)), on(hk, dl, taps),
+        on(hk, dl, taps), on(hk, share * dl, taps), on(1, t, hk, dl),
+        on(1, t, hk, dl), on(1, t, share * hk, dl),
+        on(1, t, share * hk, dl)).compile().as_text()
+    kernels = re.findall(r"%([\w.\-]+) = .*custom_call_target="
+                         r"\"tpu_custom_call\"", text)
+    assert sorted(name.split(".")[0] for name in kernels) == [
+        gdn_conv.KERNEL_NAME, gdn_conv.BWD_KERNEL_NAME], kernels
+    wide = [line for line in text.splitlines()
+            if re.search(rf"= f32\[1,(16,8192|8192,16),{2 * dl * (1 + share)}\]"
+                         r".* (copy|transpose|fusion)\(", line)]
+    assert not wide, wide
