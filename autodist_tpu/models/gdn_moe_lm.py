@@ -56,15 +56,16 @@ the routing integers, and the recurrence's ``o`` and the states its
 segments are entered with (``gated_delta_rule.RESIDUAL_NAMES``: 134 MB
 and 34 MB a sequence and layer at the published widths): with them the
 backward's recomputation of a linear mixer stops at the projections and
-the convolution and never runs the scan's forward kernel a second time.
+the convolution and never runs the rule's forward kernel a second time.
 
 What of a linear mixer still crosses main memory more often than it must
 (``ROADMAP.md`` S16, in the order of their ms a step in the qwen3-next
 cell, ``PERF.md`` section 5): the gated norm times ``silu(z)`` a pass of
-its own before ``W_out``, the weights' gradient sums ``fusion f32[8192]``,
-and ``_prepare`` formed twice in ``ops/gated_delta_rule.py``.  The
-convolution, l2norm and the copy of z out of ``qkvz``'s columns left that
-list with PR 48.
+its own before ``W_out``, and the weights' gradient sums ``fusion
+f32[8192]``.  The convolution, l2norm and the copy of z out of ``qkvz``'s
+columns left that list with PR 48; the recurrence's state-free blocks
+(``_prepare`` formed twice around the scan kernels) with PR 49: its two
+kernels form them in VMEM from q, k, v, ``G`` and ``beta``.
 """
 from __future__ import annotations
 
@@ -164,8 +165,8 @@ def gdn_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
     size for tests.
 
     ``gdn_fn(q, k, v, g, beta)``: the recurrence (default
-    ``gated_delta_rule`` at ``chunk``: its kernel on a TPU, the plain scan
-    elsewhere).  ``attn_fn(q, k, v, True)`` as ``gqa_dsa_moe_lm``.
+    ``gated_delta_rule`` at ``chunk``: its kernels on a TPU, the plain
+    form elsewhere).  ``attn_fn(q, k, v, True)`` as ``gqa_dsa_moe_lm``.
     ``remat``, ``experts_held``, ``xent_chunk``, ``train_router``,
     ``return_counts``, ``moe_slice``: as ``gqa_dsa_moe_lm``."""
     if num_heads % num_kv_heads or linear_value_heads % linear_key_heads:
@@ -284,7 +285,7 @@ def gdn_moe_lm(vocab_size: int = 18992, num_layers: int = 4,
              False: (linear_half, expert_half)}
 
     def set_flops_gauges(tokens):
-        per_token = flops_per_token(dl, dl, chunk, share)
+        per_token = flops_per_token(dl, dl, chunk)
         times = tokens.size * hk * share * (num_layers - sum(full))
         for kind, count in per_token.items():
             registry.gauge(

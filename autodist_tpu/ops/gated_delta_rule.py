@@ -22,58 +22,75 @@ diagonal, and ``S`` the state the chunk is entered with:
     S <- exp(G_C) S + (K exp(G_C - G))^T V'
 
 since ``d_i + sum_{j<i} A[i, j] d_j = beta_i (v_i - exp(G_i) S^T k_i)``.
-It is cut in three.  **What no state enters** (``D``, ``A``, ``T``,
-``W``, ``U``, ``(Q K^T) * D``, ``Q exp(G)``, ``K exp(G_C - G)``) is
-formed for ALL chunks at once by ordinary batched products
-(:func:`_prepare`): a chain of a dozen 64 x 64 products a chunk would
-otherwise stand inside the sequential part.  **The scan** over the chunks
-is what is left: four products a chunk, ``(Q exp(G)) S``, ``W S``, ``((Q
-K^T) * D) V'`` and ``(K exp(G_C - G))^T V'``.  On a TPU the forward's is one
-Pallas kernel (HLO name ``gdn_scan`` from the scope it is called under):
-one program a (sequence, value head), the chunks the sequential grid
-axis, ``S`` in VMEM scratch (64 KB at 128 x 128) from the first chunk to
-the last, every operand read once, ``O`` written once, and ``S`` written
-out where a SEGMENT of ``segment`` chunks begins.  Elsewhere it is
-``lax.scan`` over the same three products in ``jax.numpy``
-(:func:`_scan_plain`).  **The backward** (:func:`_rule_bwd`; the whole is
-a ``jax.custom_vjp``) walks the segments in reverse from the states the
-forward wrote, the state's cotangent carried from segment to segment;
-nothing of it is wider than a segment (at 8 chunks of 64 a sixteenth of
-an 8,192-token sequence: XLA keeps more of so small a segment's batched
-lines in VMEM, and a step of the cell measured 628 ms at 8, 631 at 16
-and 644 at 32, PR 41).  Where the forward's scan is the kernel a segment
-is written out (:func:`_segment_bwd`): what no state enters formed again
-for the segment; the scan's transpose ONE Pallas kernel (HLO name
-``gdn_scan_bwd``, a scope inside ``gdn_scan``), one program a (sequence,
-value head) over twice the segment's chunks, ``_CHUNKS_A_TURN`` a turn of
-the grid, which first runs the segment forward keeping every chunk's
-entering state and ``V'`` in VMEM scratch (0.75 MB at 8 chunks of 64 x
-128), then walks the chunks back with ``dS [Dk, Dv]`` float32 in scratch,
-entered with the next segment's and written out once at the segment's
-head:
+**What no state enters** (``D``, ``A``, ``T``, ``W``, ``U``, ``(Q K^T) *
+D``, ``Q exp(G)``, ``K exp(G_C - G)``) does not depend on the chunk before:
+a dozen small products a chunk, each waiting for the last.  **The scan**
+over the chunks is what is left: four products a chunk, ``(Q exp(G)) S``,
+``W S``, ``((Q K^T) * D) V'`` and ``(K exp(G_C - G))^T V'``.
+
+**On a TPU** both are ONE Pallas kernel a direction, and nothing but q, k,
+v, ``G``, ``beta`` and o crosses main memory.  The forward's (HLO name
+``gdn_scan`` from the scope it is called under): one program a (sequence,
+value head), a SEGMENT of ``segment`` chunks a grid step, the segments the
+sequential grid axis, ``S`` in VMEM scratch (64 KB at 128 x 128) from the
+first to the last.  A step reads its tiles of q, k (of the key head ``i //
+r``), v and a row a pair of chunks of ``G`` (the running sum, which stays
+outside: 1 MB a sequence) and ``beta``; forms in VMEM what no state enters
+of ALL the segment's chunks at once (:func:`_state_free`: the chunks two by
+two, a pair's ``[C, C]`` blocks side by side on the lanes and a pair a
+batch of every product, so that a vector register is full at a chunk of 64
+and the pairs' chains of products, each a dozen long, run beside each
+other; ``T`` by :func:`_inverse_doubling`); then walks the chunks with the
+four state products; writes ``O`` once and the state the segment is
+entered with.  q, k, v and o are taken and handed a head's tokens one
+after the other (``[B * H, T, D]``, tokens on the sublanes), the layout
+``ops/gdn_conv.py`` writes and the gated norm reads: XLA copies nothing
+around the calls.  The backward's (HLO name ``gdn_scan_bwd``, a scope
+inside ``gdn_scan``; the whole is a ``jax.custom_vjp``) is one program a
+(sequence, value head) whose grid walks the segments IN REVERSE from the
+states the forward wrote, the state's cotangent ``dS [Dk, Dv]`` float32 in
+scratch from segment to segment.  A step forms what no state enters of its
+segment again (the same :func:`_state_free`), runs the chunks forward
+keeping of each the entering state, ``V'``, ``W`` and ``K exp(G_C - G)``
+in scratch (1.8 MB at 8 chunks of 64 x 128), walks them back with the
+three products that ``dS`` waits for, and then, all chunks at once again:
 
     dV' = Aqk^T dO + Kd dS'    dQg = dO S^T     dAqk = dO V'^T
     dKd = V' dS'^T             dU = dV'         dW = -dV' S^T
     dgc = sum(S * dS')         dS = Qg^T dO + gc dS' - W^T dV'
+    dT = dW (beta e^G K)^T + dU (beta V)^T      dA = -(T^T dT T^T)
 
-(two products a chunk forward, eight back, rounded as the forward
-kernel's; no turn's residual goes to main memory); and the cotangents it
-hands back pulled through :func:`_prepare`'s batched lines by ``jax.vjp``,
-through ``T = (I + A)^-1`` by the inverse's own rule ``dA = -(T^T dT
-T^T)``, two products a block at ``highest`` (:func:`_inverse_by_rule`),
-not by autodiff through the doublings and the joins.  Where the forward
-is the plain form (``interpret`` None off the TPU) a segment is the plain
-form's own transpose (``jax.vjp`` of :func:`_segment`): the two backwards
-share :func:`_prepare` and nothing else, and the tests hold the one to
-the other.
+(the scan's transpose, eight products rounded as the forward's; ``T``'s by
+the inverse's own rule, two products at ``highest``, masked to the strict
+lower triangle) and the elementwise lines that formed the blocks
+transposed by hand to ``dq, dk`` (of this value head's reading of its key
+head: the sum over a key head's value heads is XLA's), ``dv``, ``dG`` (its
+running sum from the chunk's end back is XLA's) and ``dbeta``.  No
+residual but the operands, o and the segments' states; nothing of a
+segment goes to main memory.  A segment of 8 chunks of 64 is a sixteenth
+of an 8,192-token sequence; the rule alone at the cell's shapes, one
+sequence and layer on the chip (my chip runs, PR 49): forward 4.5 ms and
+forward with backward 10.6 at 8, 4.8 and 11.1 at 4; 5.8 and 13.1 with a
+chunk a batch and its blocks half a register wide; 9.1 and 20.2 with a
+segment's chunks unrolled one after the other (their chains do not
+interleave of themselves); 10.8 and 23.0 with XLA's batched lines around
+two scan kernels (PR 48).
+
+**Elsewhere** the plain form in ``jax.numpy``: :func:`_prepare` forms what
+no state enters for all chunks of a segment at once by batched products
+(``T`` by :func:`_inverse`), :func:`_scan_plain` is ``lax.scan`` over the
+four products, and the backward is ``jax.vjp`` of a segment
+(:func:`_segment`) under a reverse ``lax.scan``.  The two share no line
+but :func:`recurrence`'s definition, and the tests hold the one to the
+other.
 
 Numbers.  ``exp`` is only ever formed of differences ``G_i - G_j`` with
 ``i >= j`` (masked BEFORE the ``exp``), of ``G_i`` and of ``G_C - G_i``,
 all ``<= 0``: ``g`` may reach -20 a token, -1,300 a chunk, where
 ``exp(-G)`` would be ``inf``.  ``G``, every ``exp``, ``T`` and ``S`` are
-float32; ``T`` by block-wise forward substitution (:func:`_inverse`: the
-16-wide diagonal blocks by doublings, ``A`` being strictly lower
-triangular, joined by products) with every product at ``highest``;
+float32; ``T`` by doublings (``A`` being strictly lower triangular: of the
+whole block in the kernels, of 16-wide diagonal blocks joined by products
+in :func:`_inverse`) with every product at ``highest``;
 all other products take the default precision (on a TPU one bfloat16 pass
 with float32 sums; in the kernel the operands are rounded to bfloat16
 right before each product, as ``ops/flash_attention.py`` does, unless
@@ -87,7 +104,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -138,16 +155,16 @@ def recurrence(q, k, v, g, beta):
     return jnp.moveaxis(lax.scan(token, s0, xs)[1], 0, 1).astype(v.dtype)
 
 
-def flops_per_token(dk: int, dv: int, chunk: int, share: int) -> dict:
+def flops_per_token(dk: int, dv: int, chunk: int) -> dict:
     """FLOPs a token and value head, forward: the recurrence as written
     (``exp(g) S``, ``S^T k``, ``k d^T`` added, ``S^T q``: 7 a state
-    element) and what the chunked form at this ``chunk`` performs, a
-    product of ``[m, k] x [k, n]`` counted ``2 m k n`` whatever its
-    precision; ``share`` value heads share a key head's ``K K^T`` and ``Q
-    K^T``."""
+    element) and what the kernels' chunked form at this ``chunk``
+    performs, a product of ``[m, k] x [k, n]`` counted ``2 m k n`` whatever
+    its precision (a value head's program forms its own ``K K^T`` and ``Q
+    K^T``, ``T`` by :func:`_inverse_doubling`)."""
     c = chunk
-    computed = (4 * c * dk / share            # K K^T, Q K^T
-                + _inverse_flops(c) / c       # T
+    computed = (4 * c * dk                    # K K^T, Q K^T
+                + _doubling_flops(c) / c      # T
                 + 2 * c * dk + 2 * c * dv     # W, U
                 + 4 * dk * dv                 # (Q exp(G)) S, W S
                 + 2 * c * dv                  # ((Q K^T) * D) V'
@@ -156,15 +173,6 @@ def flops_per_token(dk: int, dv: int, chunk: int, share: int) -> dict:
 
 
 _DIAGONAL = 16      # edge of the blocks of ``T`` found by doublings
-
-
-def _inverse_flops(c: int) -> int:
-    """Products of :func:`_inverse` on one ``[c, c]`` block, ``2 m k n``
-    each."""
-    if c > _DIAGONAL:
-        h = c // 2
-        return 2 * _inverse_flops(h) + 2 * 2 * h ** 3
-    return max(2 * int(math.log2(c)) - 1, 0) * 2 * c ** 3
 
 
 def _inverse(a):
@@ -197,35 +205,11 @@ def _inverse(a):
     return inv
 
 
-@jax.custom_vjp
-def _inverse_by_rule(a):
-    """:func:`_inverse` whose cotangent is the inverse's own rule, ``dA =
-    -(T^T dT T^T)`` (two products a block at ``highest``), in place of
-    autodiff walking the doublings and the joins back; what of it lies
-    outside the strictly lower triangle is the caller's mask's to drop."""
-    return _inverse(a)
-
-
-def _inverse_fwd(a):
-    t = _inverse(a)
-    return t, t
-
-
-def _inverse_bwd(t, dt):
-    tt = jnp.swapaxes(t, -1, -2)
-    return (-jnp.matmul(jnp.matmul(tt, dt, precision=_HIGHEST), tt,
-                        precision=_HIGHEST),)
-
-
-_inverse_by_rule.defvjp(_inverse_fwd, _inverse_bwd)
-
-
-def _prepare(q, k, v, g, beta, chunk: int, inverse=_inverse):
-    """What no state enters, for all chunks at once.  Returns ``(qg, w, u,
-    aqk, kd, gc)`` in the scan's layout ``[P, N, ..]`` (``P = B * Hv``
-    programs, ``N`` chunks): ``qg, w, kd [P, N, C, Dk]``, ``u [P, N, C,
-    Dv]``, ``aqk [P, N, C, C]``, ``gc [P, N]``.  ``inverse`` forms ``T``
-    (the written-out backward hands :func:`_inverse_by_rule`)."""
+def _prepare(q, k, v, g, beta, chunk: int):
+    """What no state enters, for all chunks at once (the plain form).
+    Returns ``(qg, w, u, aqk, kd, gc)`` in the scan's layout ``[P, N, ..]``
+    (``P = B * Hv`` programs, ``N`` chunks): ``qg, w, kd [P, N, C, Dk]``,
+    ``u [P, N, C, Dv]``, ``aqk [P, N, C, C]``, ``gc [P, N]``."""
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
     r, n, c = hv // hk, t // chunk, chunk
@@ -244,7 +228,7 @@ def _prepare(q, k, v, g, beta, chunk: int, inverse=_inverse):
     kk = jnp.einsum("bhsnid,bhsnjd->bhsnij", kc, kc)
     qk = jnp.einsum("bhsnid,bhsnjd->bhsnij", qc, kc)
     a = jnp.where(cols < rows, bc[..., None] * kk * decay, 0.0)
-    inv = inverse(a)
+    inv = _inverse(a)
     e_g = jnp.exp(gs)[..., None]
     w = jnp.matmul(inv, bc[..., None] * e_g * kc)
     u = jnp.matmul(inv, bc[..., None] * vc)
@@ -270,185 +254,482 @@ def _scan_plain(qg, w, u, aqk, kd, gc, s):
     return jnp.moveaxis(o, 0, 1), s
 
 
-def _scan_kernel(qg_ref, w_ref, u_ref, aqk_ref, kd_ref, gc_ref, o_ref, st_ref,
-                 s_ref, *, segment: int, operand):
-    """One chunk of one (sequence, value head): refs ``qg, w, kd [C, Dk]``,
-    ``u, o [C, Dv]``, ``aqk [C, C]``, ``gc [1, Dv]``, ``st [Dk, Dv]`` (the
-    state this chunk's segment is entered with); scratch ``s [Dk, Dv]``
-    float32."""
-    j = pl.program_id(1)
+# Inside the kernels a segment's chunks go two by two: a PAIR's tokens one
+# after the other on the sublanes (``[2C, D]``: the tiles as they come),
+# its two ``[C, C]`` blocks side by side on the lanes (``[C, 2C]``: at a
+# chunk of 64 a whole tile of 128 lanes, where one block would leave half
+# of every vector register empty), and every product a pair a batch: so
+# many INDEPENDENT products issued one after the other (a chunk's own
+# chain, a dozen small products each waiting for the last, is bound by
+# their latency).
+_BNN = (((2,), (1,)), ((0,), (0,)))     # a @ b
+_BNT = (((2,), (2,)), ((0,), (0,)))     # a @ b^T
 
-    @pl.when(j == 0)
+
+def _t(x):
+    """The last two axes swapped."""
+    return jnp.swapaxes(x, -1, -2)
+
+
+def _lanes(c: int):
+    """Of packed blocks ``[1, C, 2C]``: ``(rows, local, left)``, the row,
+    the column inside its own block, and whether the lane is the first
+    block's."""
+    rows, cols = (lax.broadcasted_iota(jnp.int32, (1, c, 2 * c), i)
+                  for i in (1, 2))
+    return rows, jnp.where(cols < c, cols, cols - c), cols < c
+
+
+def _pack(x):
+    """``[M, 2C, 2C] -> [M, C, 2C]``: the two diagonal blocks of a pair's
+    product side by side on the lanes (nothing moves: the second is in the
+    second half of the lanes already)."""
+    c = x.shape[-1] // 2
+    return jnp.where(_lanes(c)[2], x[:, :c], x[:, c:])
+
+
+def _blocks(xp):
+    """:func:`_pack` undone: ``[M, C, 2C] -> [M, 2C, 2C]``, the two blocks
+    on the diagonal and zeros beside them."""
+    c = xp.shape[1]
+    rows, cols = (lax.broadcasted_iota(jnp.int32, (1, 2 * c, 2 * c), i)
+                  for i in (1, 2))
+    return jnp.where((rows < c) == (cols < c),
+                     jnp.concatenate([xp, xp], axis=1), 0.0)
+
+
+def _halves(xp):
+    """The sums over the lanes of each block of ``[M, C, 2C]``, the pair's
+    tokens one after the other: ``[M, 2C, 1]``."""
+    left = _lanes(xp.shape[1])[2]
+    return jnp.concatenate(
+        [jnp.sum(jnp.where(left, xp, 0.0), axis=2, keepdims=True),
+         jnp.sum(jnp.where(left, 0.0, xp), axis=2, keepdims=True)], axis=1)
+
+
+def _beside(column):
+    """``[M, 2C, 1] -> [M, C, 2C]``: a pair's column, each chunk's half
+    along the lanes of its own block."""
+    c = column.shape[1] // 2
+    return jnp.where(_lanes(c)[2], column[:, :c], column[:, c:])
+
+
+def _row(column):
+    """``[M, 2C, 1] -> [M, 1, 2C]``: a pair's column as the row it is
+    handed out as (a vector of a chunk crosses main memory as a ROW: a
+    ``[.., C, 1]`` array pads to 128 lanes there)."""
+    rows, local, _ = _lanes(column.shape[1] // 2)
+    return jnp.sum(jnp.where(rows == local, _beside(column), 0.0), axis=1,
+                   keepdims=True)
+
+
+def _column(row):
+    """:func:`_row` undone: ``[M, 1, 2C] -> [M, 2C, 1]``."""
+    rows, local, _ = _lanes(row.shape[-1] // 2)
+    return _halves(jnp.where(rows == local, row, 0.0))
+
+
+def _ends(c: int):
+    """The last lane of each block of a row ``[1, 1, 2C]``."""
+    lane = lax.broadcasted_iota(jnp.int32, (1, 1, 2 * c), 2)
+    return lane == c - 1, lane == 2 * c - 1
+
+
+def _highest(a, b):
+    """``a [M, R, 2C]`` times the block-diagonal ``b [M, 2C, 2C]``, float32
+    at ``highest``."""
+    return _dot(a, b, _BNN, jnp.float32)
+
+
+def _inverse_doubling(ap):
+    """``(I + A)^-1`` of strictly lower triangular blocks, packed ``[M, C,
+    2C]``, INSIDE a kernel, float32 with every product at ``highest``:
+    with ``P = -A`` (``P^C = 0``) the product ``(I + P)(I + P^2)(I +
+    P^4)..`` by doublings of the whole block, each turn ONE product ``[inv;
+    P^m] P^m`` of the two stacked along the sublanes (``inv P^m`` to add to
+    ``inv``, and ``P^2m``): ``2 log2(C) - 2`` products of ``[C, C]`` a
+    block in ``log2(C)`` calls of the MXU a pair.  :func:`_inverse`'s
+    16-wide blocks would be slices off the lanes' grid in a kernel; the
+    tests hold this to it to 1e-6."""
+    c = ap.shape[1]
+    if c & (c - 1):
+        raise ValueError(f"chunk={c}: expected a power of two")
+    rows, local, _ = _lanes(c)
+    p = -ap
+    inv = jnp.where(rows == local, 1.0, p)
+    if c > 2:
+        p = _highest(p, _blocks(p))
+        for _ in range(c.bit_length() - 3):
+            both = _highest(jnp.concatenate([inv, p], axis=1), _blocks(p))
+            inv, p = inv + both[:, :c], both[:, c:]
+        inv = inv + _highest(inv, _blocks(p))
+    return inv
+
+
+def _doubling_flops(c: int) -> int:
+    """Products of :func:`_inverse_doubling` on one ``[c, c]`` block, ``2
+    m k n`` each."""
+    return max(2 * (c.bit_length() - 1) - 2, 0) * 2 * c ** 3
+
+
+class _Vectors(NamedTuple):
+    """The decays of a kernel's ``M`` pairs of chunks.  A pair's tokens one
+    after the other, ``[M, 2C, 1]``: ``beta``, ``e_g`` (``exp(G)``),
+    ``e_d`` (``exp(G_C - G)``); ``gc``, two of ``[M, 1, 1]`` (``exp(G_C)``
+    of each chunk of the pair).  Packed ``[M, C, 2C]``: ``beta_p`` (a
+    token's along its row of its block), ``decay`` (``D``, zero above the
+    diagonals); ``strict [1, C, 2C]``, true below the diagonals."""
+    beta: jax.Array
+    e_g: jax.Array
+    e_d: jax.Array
+    gc: tuple
+    beta_p: jax.Array
+    decay: jax.Array
+    strict: jax.Array
+
+
+def _vectors(g_row, b_row) -> _Vectors:
+    """From the rows ``[M, 1, 2C]`` of ``G`` (the running sum inside each
+    chunk) and ``beta``, a pair's two chunks side by side."""
+    c = g_row.shape[-1] // 2
+    rows, local, _ = _lanes(c)
+    g, beta = _column(g_row), _column(b_row)
+    # the same number in every lane, which a slice off one lane is not
+    # (Mosaic broadcasts that along one axis alone)
+    last = [jnp.sum(jnp.where(end, g_row, 0.0), axis=2, keepdims=True)
+            for end in _ends(c)]                               # 2 x [M, 1, 1]
+    ends = jnp.concatenate([jnp.broadcast_to(x, g[:, :c].shape)
+                            for x in last], axis=1)            # [M, 2C, 1]
+    # masked BEFORE the exp: above a diagonal G_i - G_j is positive
+    decay = jnp.exp(jnp.where(local <= rows, _beside(g) - g_row, -jnp.inf))
+    return _Vectors(beta, jnp.exp(g), jnp.exp(ends - g),
+                    tuple(jnp.exp(x) for x in last), _beside(beta), decay,
+                    local < rows)
+
+
+def _state_free(q, k, v, d: _Vectors, operand):
+    """What no state enters, of a kernel's ``M`` pairs of chunks at once
+    (``q, k [M, 2C, Dk]``, ``v [M, 2C, Dv]``): ``(kdm, t, w, u, aqk)`` with
+    ``kdm = (K K^T) * D`` below the diagonals and ``aqk = (Q K^T) * D``
+    packed ``[M, C, 2C]`` (``A = beta kdm``), ``t = (I + A)^-1`` the two
+    blocks on the diagonal of ``[M, 2C, 2C]``, and ``W [M, 2C, Dk]``, ``U
+    [M, 2C, Dv]`` as the module's text has them."""
+    dot = functools.partial(_dot, operand=operand)
+    kdm = jnp.where(d.strict, _pack(dot(k, k, _BNT)) * d.decay, 0.0)
+    t = _blocks(_inverse_doubling(d.beta_p * kdm))
+    w = dot(t, (d.beta * d.e_g) * k, _BNN)
+    u = dot(t, d.beta * v, _BNN)
+    return kdm, t, w, u, _pack(dot(q, k, _BNT)) * d.decay
+
+
+def _pairs(x, n: int):
+    """A segment's tokens ``[N C, D]`` as its pairs of chunks ``[M, 2C,
+    D]``; an odd segment's last chunk beside zeros."""
+    c = x.shape[0] // n
+    if n % 2:
+        x = jnp.concatenate([x, jnp.zeros((c, x.shape[1]), x.dtype)], axis=0)
+    return x.reshape((n + 1) // 2, 2 * c, x.shape[1])
+
+
+def _tokens_of(x, n: int):
+    """:func:`_pairs` undone: ``[M, 2C, D] -> [N C, D]``."""
+    c = x.shape[1] // 2
+    return x.reshape(x.shape[0] * 2 * c, x.shape[2])[:n * c]
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, st_ref, s_ref,
+                qg_ref, w_ref, u_ref, kd_ref, aqk_ref, *, operand):
+    """One SEGMENT of one (sequence, value head): refs ``q, k [1, L, Dk]``
+    (of the key head), ``v, o [1, L, Dv]``, ``g, b [1, 1, M, 1, 2C]`` (a
+    pair of chunks' ``G`` and ``beta`` a row), ``st [1, 1, Dk, Dv]`` (the
+    state the segment is entered with); scratch ``s [Dk, Dv]`` float32 and
+    the segment's blocks ``qg, w, kd [L, Dk]``, ``u [L, Dv]``, ``aqk [M,
+    C, 2C]``.  First what no state enters of ALL the segment's chunks, a
+    pair a batch of every product, then the four state products a chunk,
+    in order."""
+    c = g_ref.shape[-1] // 2
+    n = q_ref.shape[1] // c
+    dot = functools.partial(_dot, dims=_NN, operand=operand)
+
+    @pl.when(pl.program_id(1) == 0)
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    dot = functools.partial(_dot, dims=_NN, operand=operand)
+    q, k = _pairs(q_ref[0], n), _pairs(k_ref[0], n)
+    d = _vectors(g_ref[0, 0], b_ref[0, 0])
+    _, _, w, u, aqk_ref[...] = _state_free(q, k, _pairs(v_ref[0], n), d,
+                                           operand)
+    w_ref[...], u_ref[...] = _tokens_of(w, n), _tokens_of(u, n)
+    qg_ref[...] = _tokens_of(q * d.e_g, n)
+    kd_ref[...] = _tokens_of(k * d.e_d, n)
     s = s_ref[...]
-
-    @pl.when(j % segment == 0)
-    def _():
-        st_ref[0, 0] = s
-
-    vp = u_ref[0, 0] - dot(w_ref[0, 0], s)                   # [C, Dv]
-    o_ref[0, 0] = dot(qg_ref[0, 0], s) + dot(aqk_ref[0, 0], vp)
-    s_ref[...] = s * gc_ref[0, 0] + dot(kd_ref[0, 0].T, vp)
-
-
-def _scan_pallas(qg, w, u, aqk, kd, gc, segment: int, interpret: bool):
-    """``(o [P, N, C, Dv], states [P, N / segment, Dk, Dv])``."""
-    p, n, c, dk = qg.shape
-    dv = u.shape[-1]
-
-    def block(*shape, every=1):
-        return pl.BlockSpec((1, 1) + shape,
-                            lambda i, j: (i, j // every, 0, 0))
-
-    return pl.pallas_call(
-        functools.partial(_scan_kernel, segment=segment,
-                          operand=_product_operand(interpret)),
-        out_shape=(jax.ShapeDtypeStruct((p, n, c, dv), jnp.float32),
-                   jax.ShapeDtypeStruct((p, n // segment, dk, dv),
-                                        jnp.float32)),
-        grid=(p, n),
-        in_specs=[block(c, dk), block(c, dk), block(c, dv), block(c, c),
-                  block(c, dk), block(1, dv)],
-        out_specs=(block(c, dv), block(dk, dv, every=segment)),
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(qg, w, u, aqk, kd,
-      jnp.broadcast_to(gc[..., None, None], (p, n, 1, dv)))
+    st_ref[0, 0] = s
+    for h in range(n):
+        at = slice(h * c, (h + 1) * c)
+        half = slice(h % 2 * c, (h % 2 + 1) * c)
+        vp = u_ref[at] - dot(w_ref[at], s)                     # [C, Dv]
+        o_ref[0, at] = dot(qg_ref[at], s) + dot(aqk_ref[h // 2][:, half], vp)
+        s = s * d.gc[h % 2][h // 2] + dot(kd_ref[at].T, vp)
+    s_ref[...] = s
 
 
-def _scan_bwd_kernel(qg_ref, w_ref, u_ref, aqk_ref, kd_ref, gc_ref, s0_ref,
-                     do_ref, dsn_ref, dqg_ref, dw_ref, du_ref, daqk_ref,
-                     dkd_ref, dgc_ref, ds0_ref, s_ref, ds_ref, states_ref,
-                     vp_ref, *, operand):
-    """One turn of one (sequence, value head) over a segment of ``N``
-    chunks, ``H`` chunks a turn, ``2 N / H`` turns: the first half run the
-    segment forward from ``s0 [Dk, Dv]`` and keep every chunk's entering
-    state and ``V'`` in scratch (``states [N, Dk, Dv]``, ``vp [N, C,
-    Dv]``); the second half walk the chunks back, ``ds [Dk, Dv]`` float32
-    in scratch from ``dsn`` (the cotangent of the state the segment
-    leaves) to ``ds0`` (of the state it is entered with), written once.
-    Refs of a turn's chunks as :func:`_scan_kernel`'s of one, ``do`` and
-    the cotangents in their operands' shapes, ``dgc [1, Dv]`` the sums
-    over ``Dk`` of ``S * dS'``."""
-    j = pl.program_id(1)
-    turns = pl.num_programs(1) // 2
-    per = qg_ref.shape[1]
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, do_ref, dq_ref,
+                dk_ref, dv_ref, dg_ref, db_ref, ds_ref, states_ref, vp_ref,
+                w_ref, kd_ref, du_ref, dkd_ref, dgc_ref, *, operand):
+    """One SEGMENT of one (sequence, value head), the segments in REVERSE:
+    refs as :func:`_fwd_kernel`'s, ``s0 [1, 1, Dk, Dv]`` the state the
+    forward entered the segment with, ``do`` and the cotangents ``dq, dk
+    [1, L, Dk]`` (of this VALUE head's reading of its key head), ``dv [1,
+    L, Dv]``, ``dg, db [1, 1, M, 1, 2C]`` (of ``G`` and ``beta``, rows);
+    scratch ``ds [Dk, Dv]`` float32, the state's cotangent walking back
+    from segment to segment, of every chunk of the segment the state it is
+    entered with ``[N, Dk, Dv]`` and ``dgc [M, 2, 1]``, and over the
+    segment's tokens ``V'``, ``W``, ``K exp(G_C - G)``, ``dU`` and
+    ``dKd``.  What no state enters of all the chunks first (the forward
+    kernel's own function); the chunks forward, two products each; the
+    chunks back, three products each on the way of ``dS``; then, all
+    chunks at once again, the rest of the scan's transpose and the
+    transposes of the lines that formed the blocks, through ``T`` by the
+    inverse's own rule ``dA = -(T^T dT T^T)`` at ``highest``."""
+    c = g_ref.shape[-1] // 2
+    n = q_ref.shape[1] // c
     dot = functools.partial(_dot, operand=operand)
 
-    @pl.when(j == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _():
-        s_ref[...] = s0_ref[0]
+        ds_ref[...] = jnp.zeros_like(ds_ref)
 
-    @pl.when(j < turns)
-    def _():
-        s = s_ref[...]
-        for h in range(per):
-            c = j * per + h
-            states_ref[c] = s
-            vp = u_ref[0, h] - dot(w_ref[0, h], s, _NN)
-            vp_ref[c] = vp
-            s = s * gc_ref[0, h] + dot(kd_ref[0, h].T, vp, _NN)
-        s_ref[...] = s
+    q, k, v, do = (_pairs(x[0], n) for x in (q_ref, k_ref, v_ref, do_ref))
+    d = _vectors(g_ref[0, 0], b_ref[0, 0])
+    kdm, t, w, u, aqk = _state_free(q, k, v, d, operand)
+    tt = _t(t)
+    w_ref[...], vp_ref[...] = _tokens_of(w, n), _tokens_of(u, n)
+    kd_ref[...] = _tokens_of(k * d.e_d, n)
+    # Aqk^T dO, the part of dV' that no state enters
+    du_ref[...] = _tokens_of(dot(_t(_blocks(aqk)), do, _BNN), n)
+    s = s0_ref[0, 0]
+    for h in range(n):
+        at = slice(h * c, (h + 1) * c)
+        states_ref[h] = s
+        vp = vp_ref[at] - dot(w_ref[at], s, _NN)       # U until now
+        vp_ref[at] = vp
+        s = s * d.gc[h % 2][h // 2] + dot(kd_ref[at].T, vp, _NN)
+    ds = ds_ref[...]
+    for h in reversed(range(n)):
+        at = slice(h * c, (h + 1) * c)
+        gc = d.gc[h % 2][h // 2]
+        dvp = du_ref[at] + dot(kd_ref[at], ds, _NN)
+        du_ref[at] = dvp
+        dkd_ref[at] = dot(vp_ref[at], ds, _NT)
+        dgc_ref[h // 2, h % 2:h % 2 + 1] = jnp.sum(
+            jnp.sum(states_ref[h] * ds, axis=0, keepdims=True), axis=1,
+            keepdims=True)
+        e_g = d.e_g[h // 2, h % 2 * c:(h % 2 + 1) * c]
+        ds = (dot((q_ref[0, at] * e_g).T, do_ref[0, at], _NN) + ds * gc
+              - dot(w_ref[at].T, dvp, _NN))
+    ds_ref[...] = ds
 
-    @pl.when(j == turns)
-    def _():
-        ds_ref[...] = dsn_ref[0]
+    # the rest of the scan's transpose, all chunks at once
+    du, dkd, vp = (_pairs(x[...], n) for x in (du_ref, dkd_ref, vp_ref))
+    s = states_ref[...]
+    if n % 2:
+        s = jnp.concatenate([s, jnp.zeros_like(s[:1])], axis=0)
+    do_c, du_c = (x.reshape(s.shape[0], c, x.shape[-1]) for x in (do, du))
+    dqg = dot(do_c, s, _BNT).reshape(q.shape)
+    dw = -dot(du_c, s, _BNT).reshape(k.shape)
+    daqk = _pack(dot(do, vp, _BNT))
+    # W = T (beta e^G K), U = T (beta V), T = (I + A)^-1
+    wr0 = k * d.e_g
+    dt = _pack(dot(dw, d.beta * wr0, _BNT) + dot(du, d.beta * v, _BNT))
+    dwr, dur = dot(tt, dw, _BNN), dot(tt, du, _BNN)
+    da = jnp.where(d.strict, -_highest(_highest(_pack(tt), _blocks(dt)), tt),
+                   0.0)
+    # A = beta (K K^T) * D, Aqk = (Q K^T) * D: the elementwise lines
+    moved = da * (d.beta_p * kdm) + daqk * aqk         # dD * D
+    dkk, dqk = _blocks(da * d.beta_p * d.decay), _blocks(daqk * d.decay)
+    dq_ref[0] = _tokens_of(dot(dqk, k, _BNN) + dqg * d.e_g, n)
+    dk_ref[0] = _tokens_of(
+        dot(dkk + _t(dkk), k, _BNN) + dot(_t(dqk), q, _BNN)
+        + dwr * (d.beta * d.e_g) + dkd * d.e_d, n)
+    dv_ref[0] = _tokens_of(dur * d.beta, n)
 
-    @pl.when(j >= turns)
-    def _():
-        ds = ds_ref[...]
-        for h in reversed(range(per)):
-            c = (2 * turns - 1 - j) * per + h
-            s, vp, do = states_ref[c], vp_ref[c], do_ref[0, h]
-            qg, w, kd = qg_ref[0, h], w_ref[0, h], kd_ref[0, h]
-            dvp = dot(aqk_ref[0, h].T, do, _NN) + dot(kd, ds, _NN)
-            dqg_ref[0, h] = dot(do, s, _NT)
-            dw_ref[0, h] = -dot(dvp, s, _NT)
-            du_ref[0, h] = dvp
-            daqk_ref[0, h] = dot(do, vp, _NT)
-            dkd_ref[0, h] = dot(vp, ds, _NT)
-            dgc_ref[0, h] = jnp.sum(s * ds, axis=0, keepdims=True)
-            ds = dot(qg.T, do, _NN) + ds * gc_ref[0, h] - dot(w.T, dvp, _NN)
-        ds_ref[...] = ds
+    def over_lanes(x):
+        return jnp.sum(x, axis=2, keepdims=True)
 
-        @pl.when(j == 2 * turns - 1)
-        def _():
-            ds0_ref[0] = ds
+    by_wr0, by_kd = over_lanes(dwr * wr0), over_lanes(dkd * k * d.e_d)
+    dg = (_halves(moved) + d.beta * by_wr0 + over_lanes(dqg * q * d.e_g)
+          - by_kd)
+    at_end = dgc_ref[...] * jnp.concatenate(d.gc, axis=1) + jnp.concatenate(
+        [jnp.sum(by_kd[:, e * c:(e + 1) * c], axis=1, keepdims=True)
+         for e in (0, 1)], axis=1)                              # [M, 2, 1]
+    dg_ref[0, 0] = (
+        _row(dg) - jnp.sum(moved, axis=1, keepdims=True)
+        + sum(jnp.where(end, at_end[:, e:e + 1], 0.0)
+              for e, end in enumerate(_ends(c))))
+    db_ref[0, 0] = _row(_halves(da * kdm) + by_wr0 + over_lanes(dur * v))
 
 
-#: chunks a turn of the backward kernel's grid takes (as many of them as
-#: divide a segment): a segment of 32 alone on the chip took 1.45 ms at 1,
-#: 1.16 at 2, 1.01 at 4, 0.96 at 8 and 0.94 at 16 (PR 41)
-_CHUNKS_A_TURN = 4
+def _by_head(x):
+    """``[B, T, H, D] -> [B * H, T, D]``: a head's tokens one after the
+    other, as ``ops/gdn_conv.py`` writes q, k, v and the gated norm reads
+    ``o`` (a change of layout XLA makes none of)."""
+    b, t, h, d = x.shape
+    return jnp.swapaxes(x, 1, 2).reshape(b * h, t, d)
 
 
-def _scan_bwd_pallas(qg, w, u, aqk, kd, gc, s0, do, dsn, interpret: bool):
-    """The scan's transpose over one segment: the cotangents of ``(qg, w,
-    u, aqk, kd, gc)`` in their shapes and of ``s0 [P, Dk, Dv]``, from ``do
-    [P, N, C, Dv]`` and ``dsn [P, Dk, Dv]``.  A block a forward turn does
-    not read (or no turn writes yet) stays at the last chunks, the first
-    the walk back takes: nothing is fetched or written back twice."""
-    p, n, c, dk = qg.shape
-    dv = u.shape[-1]
-    f32 = jnp.float32
-    per = math.gcd(n, _CHUNKS_A_TURN)
-    turns = n // per
+def _by_token(x, b: int):
+    """:func:`_by_head` undone."""
+    p, t, d = x.shape
+    return jnp.swapaxes(x.reshape(b, p // b, t, d), 1, 2)
 
-    def forth(i, j):     # turn j forward, then the last chunks
-        return i, jnp.minimum(j, turns - 1), 0, 0
 
-    def back(i, j):      # the last chunks until the walk back begins
-        return i, jnp.minimum(turns - 1, 2 * turns - 1 - j), 0, 0
+def _rows(x, chunk: int, segment: int):
+    """``[B, T, Hv] -> [B * Hv, segments, segment, chunk]``: a chunk's
+    vector a row."""
+    b, t, hv = x.shape
+    return jnp.moveaxis(x, 1, 2).reshape(b * hv, -1, segment, chunk)
 
-    def both(i, j):      # turn j forward, then the turns in reverse
-        return i, jnp.minimum(j, 2 * turns - 1 - j), 0, 0
 
-    def block(*shape, index=back):
-        return pl.BlockSpec((1, per) + shape, index)
+def _paired(x):
+    """``[P, segments, N, C] -> [P, segments, M, 1, 2C]``: the rows of a
+    pair of chunks side by side, a tile of their own in a kernel; an odd
+    segment's last beside zeros."""
+    p, segments, n, c = x.shape
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, n % 2), (0, 0)))
+    return x.reshape(p, segments, (n + 1) // 2, 1, 2 * c)
 
-    def like(*arrays):
-        return tuple(jax.ShapeDtypeStruct(x.shape, f32) for x in arrays)
 
-    state = pl.BlockSpec((1, dk, dv), lambda i, j: (i, 0, 0))
-    gc = jnp.broadcast_to(gc[..., None, None], (p, n, 1, dv))
+def _running(x, back: bool = False):
+    """The running sum along the last axis (``back``: from the end), as a
+    product with a triangle of ones at ``highest``: the three bfloat16
+    parts of a float32 times 1.0 are exact, so it is the float32 sum in
+    another order; ``jnp.cumsum`` over rows that are tiles of their own is
+    a ``reduce_window`` that took 0.24 ms a call of 1 MB (my chip run, PR
+    49)."""
+    c = x.shape[-1]
+    ones = jnp.triu(jnp.ones((c, c), x.dtype))
+    return jnp.matmul(x, ones.T if back else ones, precision=_HIGHEST)
+
+
+def _vectors_of(g, beta, chunk: int, segment: int):
+    """The kernels' ``(G, beta)``: ``G`` the running sum of ``g`` inside
+    each chunk, float32."""
+    g, beta = (_rows(x.astype(jnp.float32), chunk, segment)
+               for x in (g, beta))
+    return _paired(_running(g)), _paired(beta)
+
+
+def _params(blocks: int, scratch: int):
+    """``blocks`` bytes of a grid step's operands, each held twice, beside
+    ``scratch`` bytes and as much again for the values of a segment's
+    chunks."""
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=pallas_utils.vmem_limit(
+            2 * blocks + 2 * scratch + (2 << 20)))
+
+
+# Jitted: traced and lowered once a shape, however many layers call it
+@functools.partial(jax.jit, static_argnames=("chunk", "segment", "interpret"))
+def _forward_kernel(q, k, v, g, beta, *, chunk, segment, interpret):
+    """``(o [B, T, Hv, Dv] float32, states [B * Hv, segments, Dk, Dv])``
+    by the forward kernel, a segment a grid step."""
+    b, t, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    r, c, n, f32 = hv // hk, chunk, segment, jnp.float32
+    rows, segments, m = n * c, t // (n * c), (n + 1) // 2
+
+    def tokens(d, heads=1):
+        return pl.BlockSpec((1, rows, d), lambda i, j: (i // heads, j, 0))
+
+    vector = pl.BlockSpec((1, 1, m, 1, 2 * c), lambda i, j: (i, j, 0, 0, 0))
+    o, states = pl.pallas_call(
+        functools.partial(_fwd_kernel, operand=_product_operand(interpret)),
+        out_shape=(jax.ShapeDtypeStruct((b * hv, t, dv), f32),
+                   jax.ShapeDtypeStruct((b * hv, segments, dk, dv), f32)),
+        grid=(b * hv, segments),
+        in_specs=[tokens(dk, r), tokens(dk, r), tokens(dv), vector, vector],
+        out_specs=(tokens(dv),
+                   pl.BlockSpec((1, 1, dk, dv), lambda i, j: (i, j, 0, 0))),
+        scratch_shapes=[pltpu.VMEM((dk, dv), f32)] + [
+            pltpu.VMEM((rows, d), f32) for d in (dk, dk, dv, dk)] + [
+            pltpu.VMEM((m, c, 2 * c), f32)],
+        compiler_params=_params(
+            4 * (rows * (2 * dk + 2 * dv) + dk * dv),
+            4 * rows * (3 * dk + dv + c)),
+        interpret=interpret,
+        name=KERNEL_NAME,
+    )(*(_by_head(x.astype(f32)) for x in (q, k, v)),
+      *_vectors_of(g, beta, c, n))
+    return _by_token(o, b), states
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _backward_kernel(q, k, v, g, beta, states, do, *, chunk, interpret):
+    """The five cotangents (float32, in the operands' shapes) by the
+    backward kernel, a segment a grid step from the last to the first."""
+    b, t, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    segments = states.shape[1]
+    r, c, f32 = hv // hk, chunk, jnp.float32
+    n = t // (c * segments)
+    rows, m = n * c, (n + 1) // 2
+
+    def tokens(d, heads=1):
+        return pl.BlockSpec((1, rows, d),
+                            lambda i, j: (i // heads, segments - 1 - j, 0))
+
+    def block(*shape):
+        return pl.BlockSpec(
+            (1, 1) + shape,
+            lambda i, j: (i, segments - 1 - j) + (0,) * len(shape))
+
+    def like(d):
+        return jax.ShapeDtypeStruct((b * hv, t, d), f32)
+
+    vectors = jax.ShapeDtypeStruct((b * hv, segments, m, 1, 2 * c), f32)
     with jax.named_scope(BWD_KERNEL_NAME):
-        *cotangents, dgc, ds0 = pl.pallas_call(
-            functools.partial(_scan_bwd_kernel,
+        dq, dk_, dv_, dg, db = pl.pallas_call(
+            functools.partial(_bwd_kernel,
                               operand=_product_operand(interpret)),
-            out_shape=like(qg, w, u, aqk, kd, gc, s0),
-            grid=(p, 2 * turns),
-            in_specs=[block(c, dk), block(c, dk, index=both),
-                      block(c, dv, index=forth), block(c, c),
-                      block(c, dk, index=both), block(1, dv, index=both),
-                      state, block(c, dv), state],
-            out_specs=(block(c, dk), block(c, dk), block(c, dv), block(c, c),
-                       block(c, dk), block(1, dv), state),
+            out_shape=(like(dk), like(dk), like(dv), vectors, vectors),
+            grid=(b * hv, segments),
+            in_specs=[tokens(dk, r), tokens(dk, r), tokens(dv),
+                      block(m, 1, 2 * c), block(m, 1, 2 * c),
+                      block(dk, dv), tokens(dv)],
+            out_specs=(tokens(dk), tokens(dk), tokens(dv),
+                       block(m, 1, 2 * c), block(m, 1, 2 * c)),
             scratch_shapes=[pltpu.VMEM((dk, dv), f32),
-                            pltpu.VMEM((dk, dv), f32),
-                            pltpu.VMEM((n, dk, dv), f32),
-                            pltpu.VMEM((n, c, dv), f32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
+                            pltpu.VMEM((n, dk, dv), f32)] + [
+                pltpu.VMEM((rows, d), f32) for d in (dv, dk, dk, dv, dk)
+            ] + [pltpu.VMEM((m, 2, 1), f32)],
+            compiler_params=_params(
+                4 * (rows * (4 * dk + 3 * dv) + dk * dv),
+                4 * (dk * dv * (1 + n) + rows * (3 * dk + 2 * dv))),
             interpret=interpret,
-        )(qg, w, u, aqk, kd, gc, s0, do, dsn)
-    return (*cotangents, dgc.sum(axis=(-2, -1))), ds0
+            name=BWD_KERNEL_NAME,
+        )(*(_by_head(x.astype(f32)) for x in (q, k, v)),
+          *_vectors_of(g, beta, c, n), states, _by_head(do.astype(f32)))
+
+    def keys(x):      # the value heads of a key head read the same q, k
+        return jnp.swapaxes(x.reshape(b, hk, r, t, dk).sum(axis=2), 1, 2)
+
+    def rows_of(x):       # ``_paired`` undone
+        return x.reshape(b * hv, segments, 2 * m, c)[:, :, :n]
+
+    def tokens_of(x):     # ``_rows`` undone
+        return jnp.moveaxis(x.reshape(b, hv, t), 1, 2)
+
+    # G is a running sum inside a chunk: g's cotangent is dG's from the end
+    return (keys(dq), keys(dk_), _by_token(dv_, b),
+            tokens_of(_running(rows_of(dg), back=True)),
+            tokens_of(rows_of(db)))
 
 
 def _tokens(o, b: int):
     """``[B * Hv, N, C, Dv] -> [B, T, Hv, Dv]``."""
     p, n, c, dv = o.shape
     return o.reshape(b, p // b, n * c, dv).transpose(0, 2, 1, 3)
-
-
-def _chunks(o, chunk: int):
-    """:func:`_tokens` undone: ``[B, T, Hv, Dv] -> [B * Hv, N, C, Dv]``."""
-    b, t, hv, dv = o.shape
-    return o.transpose(0, 2, 1, 3).reshape(b * hv, t // chunk, chunk, dv)
 
 
 def _segment(q, k, v, g, beta, s, chunk: int):
@@ -477,9 +758,8 @@ def _forward(q, k, v, g, beta, chunk, segment, interpret):
     kernel (under the Pallas interpreter where true)."""
     b, t = q.shape[:2]
     if interpret is not None:
-        o, states = _scan_pallas(*_prepare(q, k, v, g, beta, chunk),
-                                 segment, interpret)
-        return _tokens(o, b), states
+        return _forward_kernel(q, k, v, g, beta, chunk=chunk,
+                               segment=segment, interpret=interpret)
     s0 = jnp.zeros((b * v.shape[2], q.shape[-1], v.shape[-1]), jnp.float32)
 
     def one(s, x):
@@ -504,37 +784,24 @@ def _rule_fwd(q, k, v, g, beta, chunk, segment, interpret):
     return o, (q, k, v, g, beta, states)
 
 
-def _segment_bwd(xs, s_in, do_seg, ds, chunk, interpret):
-    """One segment's transpose: ``(the cotangents of its five operands, of
-    the state it is entered with)``.  Where the forward was plain
-    (``interpret`` None), ``jax.vjp`` of the plain form.  Where its scan
-    was the kernel, written out: what no state enters formed again, the
-    scan's transpose ONE kernel (:func:`_scan_bwd_pallas`), and the
-    cotangents it hands back pulled through :func:`_prepare`'s few batched
-    lines by ``jax.vjp``, through ``T`` by the inverse's own rule."""
-    if interpret is None:
-        _, pull = jax.vjp(lambda *a: _segment(*a, chunk), *xs, s_in)
-        *dxs, ds = pull((do_seg, ds))
-        return tuple(dxs), ds
-    with jax.named_scope("prepare_again"):
-        prepared, pull = jax.vjp(functools.partial(
-            _prepare, chunk=chunk, inverse=_inverse_by_rule), *xs)
-    cotangents, ds = _scan_bwd_pallas(*prepared, s_in,
-                                      _chunks(do_seg, chunk), ds, interpret)
-    with jax.named_scope("transposes"):
-        return pull(cotangents), ds
-
-
 def _rule_bwd(chunk, segment, interpret, res, do):
-    """The segments in reverse from the states the forward entered them
-    with, the cotangent of the state walking back through them."""
+    """From the states the forward entered its segments with.  Where the
+    forward was the kernel, the backward kernel; where it was plain
+    (``interpret`` None), the segments in reverse under ``lax.scan``, each
+    ``jax.vjp`` of the plain form, the cotangent of the state walking back
+    through them."""
     *operands, states = res
+    if interpret is not None:
+        grads = _backward_kernel(*operands, states, do, chunk=chunk,
+                                 interpret=interpret)
+        return tuple(dx.astype(x.dtype) for dx, x in zip(grads, operands))
     count = states.shape[1]
 
     def one(ds, x):
         *xs, s_in, do_seg = x
-        dxs, ds = _segment_bwd(xs, s_in, do_seg, ds, chunk, interpret)
-        return ds, dxs
+        _, pull = jax.vjp(lambda *a: _segment(*a, chunk), *xs, s_in)
+        *dxs, ds = pull((do_seg, ds))
+        return ds, tuple(dxs)
 
     xs = tuple(_segments(x, count) for x in operands) + (
         jnp.moveaxis(states, 1, 0), _segments(do.astype(jnp.float32), count))

@@ -836,16 +836,24 @@ def train_swa_moe_phase(model: dict, *, batch_size: int, steps: int,
     return facts
 
 
+#: the worst of o and the five gradients against the recurrence on the chip
+#: when ``_prepare``'s batched lines were XLA's (PERF.md section 6, PR 40)
+PR40_WORST = {"default": 3.4e-3, "highest": 5.8e-7}
+
+
 def check_gated_delta_rule(shape, *, chunk: int, interpret: bool, tol: float,
                             grad_tokens: int = 1024) -> dict:
-    """``ops/gated_delta_rule.py`` (the chunked form with its scan kernel)
-    against the recurrence token by token, ``shape = (T, Hk, Hv, D)`` one
+    """``ops/gated_delta_rule.py`` (the chunked form: on a TPU its two
+    kernels, which form a chunk's state-free blocks in VMEM, PR 49) against
+    the recurrence token by token, ``shape = (T, Hk, Hv, D)`` one
     sequence: the forward over all ``T`` tokens, every input's gradient
     over the first ``grad_tokens`` (the recurrence's backward holds a state
     a token).  Twice: at jax's default precision (on a TPU the chunked
     form's products take bfloat16 operands: within ``tol``) and at
-    ``highest`` (the same numbers: within 1e-4).  Decays as the model
-    makes them, 0.25 to 15.75 times a softplus."""
+    ``highest`` (the same numbers: within 1e-4), printed beside what the
+    chunked form with XLA's batched lines around a scan kernel read on the
+    chip when it was built (PR 40).  Decays as the model makes them, 0.25
+    to 15.75 times a softplus."""
     import jax
     import jax.numpy as jnp
 
@@ -886,6 +894,10 @@ def check_gated_delta_rule(shape, *, chunk: int, interpret: bool, tol: float,
                 f"gated delta rule at {precision} precision, o and the "
                 f"gradients of q, k, v, g, beta: {errs} > {limit}")
         facts[precision] = [float(f"{e:.3g}") for e in errs]
+        print(f"  gated delta rule against the recurrence at {precision} "
+              f"precision, o and the gradients of q, k, v, g, beta: "
+              f"{facts[precision]} (PR 40 read {PR40_WORST[precision]:g} at "
+              f"worst)", flush=True)
     return facts
 
 
@@ -938,7 +950,8 @@ def train_gdn_moe_phase(model: dict, *, batch_size: int, steps: int,
     convolution's kernel pair against the plain lines
     (:func:`check_gdn_conv`).  On a TPU the compiled step's Pallas calls
     are counted BY NAME: one ``gdn_scan`` (the forward's), one
-    ``gdn_scan_bwd`` (the written-out backward's scan) and a forward and a
+    ``gdn_scan_bwd`` (the backward's, which runs its segments forward
+    itself) and a forward and a
     backward ``gated_attn``, none run twice; TWO ``gdn_conv`` (the forward's
     and, the layer's checkpoint keeping nothing of it, the backward's
     recomputation) and one ``gdn_conv_bwd``."""

@@ -48,8 +48,14 @@ TPU traces it (``gdn_conv``, ``gdn_conv_bwd``, each kind a jitted function
 traced once a shape; z passes through it and the arrays around it are a
 head's tokens one after the other): 320,308 characters and five
 ``pallas_call`` texts more (three of ``gdn_conv``, two of
-``gdn_conv_bwd``).  A PR that changes one of
-these models' traces on purpose records the new hash here and says so in
+``gdn_conv_bwd``).  PR 49 recorded the Qwen3-Next share's anew, ON
+PURPOSE, and left the six others alone: the gated delta rule's two kernels
+form a chunk's state-free blocks in VMEM, a segment a grid step, each a
+jitted function traced once a shape, and ``_prepare``, its ``jax.vjp`` and
+the ``lax.scan`` over segments are out of the text (two ``pallas_call``
+texts fewer, one of ``gdn_scan`` and two of ``gdn_scan_bwd`` are left;
+151,553 characters more: the kernels' own lines).  A PR that changes one
+of these models' traces on purpose records the new hash here and says so in
 ``CHANGES.md``.
 """
 import functools
@@ -83,8 +89,8 @@ TRACES = {
         1, 16384, 964590, 43,
         "4bdd8a2e2743d69baa536d7845d1f87febdec9ee5fd92dcf45c8e5523c4ab936"),
     "qwen3-next-80b-a3b.ep16-share": (
-        2, 8192, 1649179, 52,
-        "ea58fbe8861756644286a589eb846b521da5eebd9d01a3a48d9d8408a120208f"),
+        2, 8192, 1800732, 50,
+        "a8479b110bede9e992c8d8ff31f3b0a364a1b749b6de0b7ef85c228d3c051e1a"),
     "lfm2-8b-a1b.ep4-share": (
         4, 8192, 967221, 42,
         "256f5023c2f3b6830753b48e74da809d5c418afec7f41c62cc5447373465ac50"),
@@ -100,7 +106,7 @@ def kernel(q, k, v, causal, **kw):
 
 def compiled_kernels(factory: str, kwargs: dict) -> dict:
     """The factory's kernels in their compiled form: the flash kernel for
-    all, and the gated delta rule's scan for the model that has one."""
+    all, and the gated delta rule's kernels for the model that has one."""
     if factory != "gdn_moe_lm":
         return {"attn_fn": kernel}
     return {"attn_fn": kernel, "gdn_fn": functools.partial(

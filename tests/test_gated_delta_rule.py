@@ -1,15 +1,15 @@
-"""``ops/gated_delta_rule.py``: the chunked form and its scan kernel (under
+"""``ops/gated_delta_rule.py``: the chunked form and its two kernels (under
 the Pallas interpreter) against the recurrence token by token, at
 ``highest``: the forward and every input's gradient, chunks of 16 and 64,
-backward segments of one chunk and of all, value heads sharing key heads,
-and decays down to -20 a token with nothing but finite numbers anywhere.
-The kernel's backward is written out (its scan a second kernel, ``T``'s
-cotangent by the inverse's own rule): held to the recurrence's gradient, to
-``jax.vjp`` of the plain form, and piece by piece to ``jax.vjp`` of
-``_scan_plain`` and of ``_inverse``.
+segments of one chunk and of all, value heads sharing key heads, and decays
+down to -20 a token with nothing but finite numbers anywhere.  The kernels
+form what no state enters in VMEM (chunks two by two, a pair's blocks side
+by side on the lanes, ``T`` by doublings of the whole block) and the
+backward's is written out whole (the scan's transpose, ``T``'s
+cotangent by the inverse's own rule, the elementwise lines by hand): held to
+the recurrence's gradient and to ``jax.vjp`` of the plain form, the
+doublings to ``_inverse``.
 """
-import math
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -87,7 +87,7 @@ def test_decays_of_minus_twenty_a_token_stay_finite(kernel, chunk):
 
 def plain_and_written_out(args, chunk, segment):
     """Value and the five gradients of the plain form (``jax.vjp`` of it a
-    segment at a time) and of the kernel's, written out."""
+    segment at a time) and of the kernels', written out."""
     return [value_and_grads(lambda *a: gated_delta_rule(
         *a, chunk=chunk, segment=segment, interpret=interpret), args)
         for interpret in (None, True)]
@@ -101,9 +101,9 @@ def test_written_out_backward_is_the_plain_forms_transpose(chunk, segment,
     """Two value heads a key head (``operands``' default), a segment that
     is the whole sequence, one of two chunks and one of a single chunk,
     ``g`` from -0.1 to under -20 a token (``rate`` 16): every cotangent of
-    the written-out backward against ``jax.vjp`` of the plain form, with
-    which it shares ``_prepare`` and nothing of the backward, and against
-    the recurrence's, every number finite."""
+    the backward kernel against ``jax.vjp`` of the plain form, with which
+    it shares not a line, and against the recurrence's, every number
+    finite."""
     args = operands(seed=1, rate=rate)
     assert (float(args[3].min()) < -20.0) == (rate == 16.0)
     (want, g_want), (got, g_got) = plain_and_written_out(args, chunk, segment)
@@ -127,36 +127,61 @@ def test_states_cotangent_is_carried_across_segments(segment):
     assert max(gaps(cut, whole)) < 2e-6
 
 
-@pytest.mark.parametrize("c,dk,dv", [(16, 32, 32), (64, 16, 128)])
-def test_scan_backward_kernel_is_the_plain_scans_transpose(c, dk, dv):
-    """One segment of five chunks entered with a state and left with a
-    state's cotangent: all seven cotangents against ``jax.vjp`` of
-    ``_scan_plain``."""
-    p, n = 3, 5
-    ks = jax.random.split(jax.random.key(2), 9)
-    shapes = [(p, n, c, dk), (p, n, c, dk), (p, n, c, dv), (p, n, c, c),
-              (p, n, c, dk), (p, n), (p, dk, dv)]
-    *xs, s0 = (0.3 * jax.random.normal(key, shape)
-               for key, shape in zip(ks, shapes))
-    xs[5] = jax.nn.sigmoid(xs[5])                        # gc in (0, 1)
-    do = jax.random.normal(ks[7], (p, n, c, dv))
-    dsn = jax.random.normal(ks[8], (p, dk, dv))
-    _, pull = jax.vjp(gdr._scan_plain, *xs, s0)
-    want = pull((do, dsn))
-    cotangents, ds0 = gdr._scan_bwd_pallas(*xs, s0, do, dsn, True)
-    assert max(gaps(cotangents + (ds0,), want)) < 1e-5
+@pytest.mark.parametrize("c", [2, 4, 16, 64, 128])
+def test_inverse_by_doublings_is_the_inverse_by_blocks(c):
+    """What the kernels form ``T`` by (doublings of the whole block, a turn
+    ONE stacked product; two pairs of blocks, a pair's side by side on the
+    lanes) against ``_inverse`` (16-wide blocks, joined): to 1e-6 of the
+    largest entry, and nothing above the diagonals."""
+    a = jnp.tril(jax.random.normal(jax.random.key(4), (4, c, c)) * 0.2, -1)
+    packed = gdr._inverse_doubling(jnp.concatenate([a[0::2], a[1::2]], -1))
+    got = jnp.stack([packed[..., :c], packed[..., c:]], 1).reshape(a.shape)
+    assert gaps([got], [gdr._inverse(a)])[0] < 1e-6
+    assert not np.triu(np.asarray(got), 1).any()
+    np.testing.assert_allclose(got @ (jnp.eye(c) + a), jnp.broadcast_to(
+        jnp.eye(c), a.shape), atol=1e-5)
 
 
-@pytest.mark.parametrize("c", [16, 64])
-def test_inverse_by_rule_is_the_inverses_transpose(c):
-    """``dA = -(T^T dT T^T)`` against autodiff through the doublings and
-    the joins, on the strictly lower triangle (all of ``A`` there is)."""
-    ks = jax.random.split(jax.random.key(6))
-    a = jnp.tril(jax.random.normal(ks[0], (3, c, c)) * 0.2, -1)
-    dt = jax.random.normal(ks[1], (3, c, c))
-    (want,), (got,) = (jax.vjp(f, a)[1](dt)
-                       for f in (gdr._inverse, gdr._inverse_by_rule))
-    assert gaps([jnp.tril(got, -1)], [jnp.tril(want, -1)])[0] < 2e-6
+def test_a_pair_of_chunks_side_by_side():
+    """``G`` and ``beta`` reach a kernel a pair of chunks a ROW, the two
+    side by side: the columns over the pair's tokens, each chunk's last
+    entry and the packed decays made of them, masked before the ``exp``
+    (a row of -1,300 beside a mild one and nothing but finite numbers); a
+    column laid back as a row, the diagonal blocks of a pair's product
+    packed and laid back."""
+    steep = jnp.cumsum(-20.0 * jnp.ones(64) - jnp.arange(64.0) / 64)
+    g = jnp.stack([steep, steep / 400])                        # [2, 64]
+    beta = jnp.stack([jnp.linspace(0.1, 0.9, 64), jnp.linspace(0.9, 0.2, 64)])
+    d = gdr._vectors(g.reshape(1, 1, 128), beta.reshape(1, 1, 128))
+    np.testing.assert_array_equal(d.beta[0, :, 0], beta.reshape(128))
+    np.testing.assert_allclose(d.e_g[0, :, 0], jnp.exp(g).reshape(128),
+                               rtol=1e-6)
+    np.testing.assert_array_equal(gdr._row(d.beta), beta.reshape(1, 1, 128))
+    np.testing.assert_array_equal(gdr._column(gdr._row(d.e_d)), d.e_d)
+    assert [x.shape for x in d.gc] == [(1, 1, 1)] * 2
+    assert float(d.gc[0][0, 0, 0]) == float(jnp.exp(steep[-1])) == 0.0
+    assert float(d.gc[1][0, 0, 0]) == float(jnp.exp(steep[-1] / 400)) > 0.0
+    want = jnp.tril(jnp.exp(jnp.tril(g[:, :, None] - g[:, None, :])))
+    np.testing.assert_allclose(d.decay[0], jnp.concatenate(list(want), 1),
+                               rtol=1e-6)
+    assert bool(jnp.all(jnp.isfinite(d.decay)))
+    np.testing.assert_allclose(
+        d.e_d[0, :, 0], jnp.exp(g[:, -1:] - g).reshape(128), rtol=1e-6)
+    np.testing.assert_array_equal(d.beta_p[0, :, :64], jnp.broadcast_to(
+        beta[0][:, None], (64, 64)))
+    np.testing.assert_array_equal(d.beta_p[0, :, 64:], jnp.broadcast_to(
+        beta[1][:, None], (64, 64)))
+    assert np.asarray(d.strict).sum() == 2 * 64 * 63 // 2
+    whole = jax.random.normal(jax.random.key(0), (3, 128, 128))
+    packed = gdr._pack(whole)
+    np.testing.assert_array_equal(packed[..., :64], whole[:, :64, :64])
+    np.testing.assert_array_equal(packed[..., 64:], whole[:, 64:, 64:])
+    blocks = gdr._blocks(packed)
+    np.testing.assert_array_equal(gdr._pack(blocks), packed)
+    assert not np.asarray(blocks[:, :64, 64:]).any()
+    assert not np.asarray(blocks[:, 64:, :64]).any()
+    np.testing.assert_allclose(
+        gdr._halves(packed)[:, 64:, 0], whole[:, 64:, 64:].sum(-1), rtol=1e-5)
 
 
 def test_sixteen_key_heads_each_serve_two_value_heads():
@@ -191,29 +216,29 @@ def test_inverse_by_blocks_and_doublings(c):
 
 def test_the_backward_is_handed_what_the_forward_kept():
     """Under a checkpoint that keeps ``RESIDUAL_NAMES`` the differentiated
-    program holds the forward's scan kernel ONCE (grid: 4 programs by 4
-    chunks) beside the backward's (4 by twice the segment's turns): the
-    backward neither runs the forward's again nor asks for its output."""
+    program holds the forward kernel ONCE beside the backward's (both 4
+    programs by 2 segments of 2 chunks): the backward neither runs the
+    forward's again nor asks for its output; without the names it runs it
+    a second time."""
+    import re
+
     args = operands(b=1, t=64, d=16)
     keep = jax.checkpoint_policies.save_only_these_names(
         *gdr.RESIDUAL_NAMES)
 
-    def loss(*a):
-        return jnp.sum(jax.checkpoint(
-            lambda *b: gated_delta_rule(*b, chunk=16, interpret=True) ** 2,
-            policy=keep)(*a))
+    def kernels(**policy):
+        def loss(*a):
+            return jnp.sum(jax.checkpoint(lambda *b: gated_delta_rule(
+                *b, chunk=16, segment=2, interpret=True) ** 2, **policy)(*a))
 
-    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(*args))
-    turns = 2 * 4 // math.gcd(4, gdr._CHUNKS_A_TURN)
-    forward, backward = ("GridMapping(grid=(4, 4)",
-                         f"GridMapping(grid=(4, {turns})")
-    assert (text.count("pallas_call["), text.count(forward),
-            text.count(backward)) == (2, 1, 1)
-    free = str(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(jax.checkpoint(
-        lambda *b: gated_delta_rule(*b, chunk=16, interpret=True) ** 2)(*a)),
-        argnums=(0, 1, 2, 3, 4)))(*args))
-    assert (free.count("pallas_call["), free.count(forward),
-            free.count(backward)) == (3, 2, 1)
+        text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(
+            *args))
+        assert text.count("GridMapping(grid=(4, 2)") \
+            == text.count("pallas_call[")
+        return sorted(re.findall(r"name=(gdn_scan\w*)", text))
+
+    assert kernels(policy=keep) == [gdr.KERNEL_NAME, gdr.BWD_KERNEL_NAME]
+    assert kernels() == [gdr.KERNEL_NAME] * 2 + [gdr.BWD_KERNEL_NAME]
 
 
 def test_shapes_it_refuses():
@@ -226,14 +251,15 @@ def test_shapes_it_refuses():
 
 
 def test_flops_per_token_by_hand():
-    f = gdr.flops_per_token(128, 128, 64, 2)
+    f = gdr.flops_per_token(128, 128, 64)
     assert f["recurrence"] == 7 * 128 * 128 == 114_688
-    # T: four 16-wide blocks by seven products each, joined by 2 x 2 of 16
-    # and 2 of 32 wide: 393,216 a chunk of 64 tokens
-    assert gdr._inverse_flops(16) == 7 * 2 * 16 ** 3
-    assert gdr._inverse_flops(64) == 4 * 57_344 + 2 * 16_384 + 131_072 \
-        == 393_216
-    # K K^T and Q K^T shared by two heads, T, W and U, (Q exp(G)) S and
-    # W S, ((Q K^T) * D) V', (K exp(G_C - G))^T V'
-    assert f["computed"] == (16_384 + 6_144 + 32_768 + 65_536 + 16_384
-                             + 32_768) == 169_984
+    # T by doublings of the whole 64 x 64 block: P^2, four turns of the
+    # stacked pair (two products each) and the last, ten products of
+    # 2 * 64^3: 81,920 a token
+    assert gdr._doubling_flops(64) == 10 * 524_288
+    assert [gdr._doubling_flops(c) for c in (1, 2, 4, 16)] == [
+        0, 0, 2 * 128, 6 * 8_192]
+    # K K^T and Q K^T (a VALUE head forms its own), T, W and U, (Q exp(G))
+    # S and W S, ((Q K^T) * D) V', (K exp(G_C - G))^T V'
+    assert f["computed"] == (32_768 + 81_920 + 32_768 + 65_536 + 16_384
+                             + 32_768) == 262_144

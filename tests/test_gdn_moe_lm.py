@@ -368,7 +368,7 @@ def test_gauges_say_what_the_chunked_form_costs(t):
     got = {m.labels["kind"]: m.value
            for m in registry.DEFAULT_REGISTRY.metrics()
            if m.name == "autodist_gdn_flops_per_step"}
-    per = gdr.flops_per_token(8, 8, 16, 2)
+    per = gdr.flops_per_token(8, 8, 16)
     times = 2 * t * 4 * 3         # tokens, value heads, linear layers
     assert got == {k: v * times for k, v in per.items()}
     assert got["computed"] / got["recurrence"] >= 1.0
